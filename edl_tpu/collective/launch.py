@@ -100,6 +100,22 @@ def run(argv: list[str] | None = None) -> int:
     clear_stale_job_tables(store, job_env.job_id)
 
     pod = Pod(addr=local_ip(), device_ids=job_env.device_ids)
+    if pod.device_ids:
+        # joining would put two trainers on one host's chips, which
+        # libtpu cannot yet form into a correct world — and the resize
+        # would take the running pod's trainer down with ours
+        # (cluster/env.tpu_visibility_vars, ROADMAP S9c)
+        from edl_tpu.collective.resource import load_resource_pods
+        held = [p for p in load_resource_pods(store, job_env.job_id).values()
+                if p.addr == pod.addr and p.device_ids]
+        if held:
+            logger.error(
+                "refusing to join job %s with --devices %s: pod %s on this "
+                "host already holds chips %s, and trainers sharing one "
+                "host's chips cannot form one world (ROADMAP S9c)",
+                job_env.job_id, pod.device_ids, held[0].pod_id[:8],
+                held[0].device_ids)
+            return 1
     pod.make_trainers(job_env.nproc_per_node,
                       find_free_ports(job_env.nproc_per_node))
     logger.info("pod %s on %s launching job %s", pod.pod_id, pod.addr, job_env.job_id)

@@ -376,6 +376,14 @@ class DeltaReplicator:
                         _CHAIN_LEN.set(seq)
                         _LAG.observe(time.monotonic() - t0)
                         _RECORDS.labels(result="sealed").inc()
+                        if seq == 1:
+                            # once per checkpoint interval: the plane is
+                            # alive on this base
+                            logger.info(
+                                "delta: chain on base step %d opened at "
+                                "step %d (%d changed shards, %d bytes)",
+                                base, step, len(changed),
+                                sum(len(b) for b in changed.values()))
                     else:
                         _RECORDS.labels(result="failed").inc()
                 elif op[0] == "flush":

@@ -65,7 +65,7 @@ class TransformerConfig:
     num_kv_heads: int = 0
     dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"      # auto | dense | splash | flash | ring
-    mesh: Any = None                  # required for attention_impl="ring"
+    mesh: Any = None                  # ring needs it; splash shards by it
     remat: bool = True
     # lax.scan over stacked layer params (one compile for N layers) vs
     # unrolled python loop.  Scan trades ~12% step time for compile
@@ -147,11 +147,14 @@ def auto_layout(cfg: TransformerConfig, per_device_batch: int,
     from dataclasses import replace
 
     if hbm_bytes is None:
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-            hbm_bytes = float(stats.get("bytes_limit", 0)) or 16e9
-        except Exception:  # noqa: BLE001 — CPU/test backends
-            hbm_bytes = 16e9
+        dev = jax.local_devices()[0]    # peers' devices have no stats
+        stats = dev.memory_stats() or {}
+        if dev.platform == "tpu" and not stats.get("bytes_limit"):
+            raise RuntimeError(
+                f"{dev.device_kind} reports no bytes_limit "
+                f"(memory_stats()={stats!r}); pass hbm_bytes")
+        # CPU/test backends report no limit: size as a 16 GB-class chip
+        hbm_bytes = float(stats.get("bytes_limit") or 16e9)
     seq = seq or cfg.max_len
     state_bytes = 16 * param_count(cfg)     # f32 params + adam m/v + grads
     act_bytes = (2 * per_device_batch * seq * cfg.num_layers * cfg.embed_dim

@@ -9,6 +9,7 @@ pure-Python fallbacks rather than breaking the import.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -42,11 +43,26 @@ def _sources() -> list[str]:
                   if f.endswith(".cc") and f not in _STANDALONE)
 
 
-def _stale(sources: list[str]) -> bool:
-    if not os.path.exists(_OUT):
+def _digest(sources: list[str], flags: list[str]) -> str:
+    """Content key of one build: the bytes of every source plus the
+    flags.  Not mtimes — a copied or archived tree does not keep them,
+    and a binary built from other sources must never be loaded."""
+    h = hashlib.sha256("\0".join(flags).encode())
+    for src in sources:
+        with open(src, "rb") as f:
+            h.update(b"\0" + os.path.basename(src).encode() + b"\0")
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _stale(out: str, digest: str) -> bool:
+    """True unless ``out`` exists beside a digest file naming exactly
+    the sources + flags about to be built."""
+    try:
+        with open(out + ".digest") as f:
+            return f.read().strip() != digest or not os.path.exists(out)
+    except OSError:
         return True
-    out_mtime = os.path.getmtime(_OUT)
-    return any(os.path.getmtime(s) > out_mtime for s in sources)
 
 
 def ensure_built() -> ctypes.CDLL | None:
@@ -63,13 +79,13 @@ def ensure_built() -> ctypes.CDLL | None:
             _failed = True
             return None
         try:
-            if _stale(sources):
-                extra = sorted({f for s in sources
-                                for f in _OPTIONAL.get(os.path.basename(s),
-                                                       [])})
+            extra = sorted({f for s in sources
+                            for f in _OPTIONAL.get(os.path.basename(s), [])})
+            flags = ["-O3", "-shared", "-fPIC"]
+            digest = _digest(sources, flags + extra)
+            if _stale(_OUT, digest):
                 try:
-                    _compile(["-O3", "-shared", "-fPIC", *sources, *extra],
-                             _OUT)
+                    _compile([*flags, *sources, *extra], _OUT, digest)
                 except subprocess.CalledProcessError as e:
                     # retry without the optional sources (missing dep,
                     # e.g. no libjpeg): the core library must still build
@@ -81,7 +97,7 @@ def ensure_built() -> ctypes.CDLL | None:
                         "optional native sources dropped (%s); %s",
                         ", ".join(sorted(_OPTIONAL)),
                         (getattr(e, "stderr", "") or str(e)).strip()[:300])
-                    _compile(["-O3", "-shared", "-fPIC", *core], _OUT)
+                    _compile([*flags, *core], _OUT, digest)
             _lib = ctypes.CDLL(_OUT)
         except (subprocess.CalledProcessError, OSError) as e:
             detail = getattr(e, "stderr", "") or str(e)
@@ -91,9 +107,11 @@ def ensure_built() -> ctypes.CDLL | None:
         return _lib
 
 
-def _compile(flags: list[str], out: str) -> None:
+def _compile(flags: list[str], out: str, digest: str) -> None:
     """g++ to a process-unique tmp then atomic rename: concurrent
-    builders (launcher subprocesses) must never tear the output."""
+    builders (launcher subprocesses) must never tear the output.  The
+    digest file lands AFTER the binary, so a build killed in between
+    reads as stale."""
     os.makedirs(os.path.dirname(out), exist_ok=True)
     tmp = f"{out}.tmp.{os.getpid()}"
     cmd = ["g++", "-std=c++17", "-pthread", *flags, "-o", tmp]
@@ -101,6 +119,9 @@ def _compile(flags: list[str], out: str) -> None:
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
         os.replace(tmp, out)
+        with open(tmp, "w") as f:
+            f.write(digest + "\n")
+        os.replace(tmp, out + ".digest")
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
@@ -122,9 +143,9 @@ def ensure_coordd() -> str | None:
     # compile at a time is the point
     with _lock:
         try:
-            if (not os.path.exists(out)
-                    or os.path.getmtime(src) > os.path.getmtime(out)):
-                _compile(["-O2", src], out)
+            digest = _digest([src], ["-O2"])
+            if _stale(out, digest):
+                _compile(["-O2", src], out, digest)
         except (subprocess.CalledProcessError, OSError) as e:
             detail = getattr(e, "stderr", "") or str(e)
             logger.warning("coordd build failed: %s", detail.strip()[:500])

@@ -28,12 +28,9 @@ import jax.numpy as jnp
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    # edl-lint: disable=wire-error — platform probe: False is the
-    # documented answer for "no usable backend", not a swallowed error
-    except Exception:  # noqa: BLE001
-        return False
+    # a backend that cannot initialise raises here: "no usable backend"
+    # must never read as "not a TPU" and quietly select dense
+    return jax.devices()[0].platform == "tpu"
 
 
 def dense_attention(q, k, v, *, causal: bool = False,
@@ -109,19 +106,39 @@ def _splash_kernel(L: int, H: int, blk: int):
                                   block_sizes=sizes)
 
 
-def _splash(q, k, v, sm_scale):
-    """Causal splash attention; q/k same length (self-attention)."""
+def _splash(q, k, v, sm_scale, mesh=None):
+    """Causal splash attention; q/k same length (self-attention).
+
+    A Mosaic call has no partitioning rule: left to GSPMD on a
+    multi-device ``mesh`` it runs replicated, every device attending
+    the whole gathered batch.  Attention is independent per example and
+    per head, so with a mesh the kernel runs under ``shard_map`` over
+    the axes that shard those (the ring kernel's convention: batch over
+    dp x fsdp, heads over tp), each device on its own rows."""
     B, L, H, D = q.shape
     if not _splash_ok(q, k, causal=True):
         raise ValueError(
             f"impl='splash' needs causal self-attention with L % 128 == 0 "
             f"and head_dim % 64 == 0; got Lq={L}, Lk={k.shape[1]}, D={D}")
     blk = next(b for b in (512, 256, 128) if L % b == 0)
-    kernel = _splash_kernel(L, H, blk)
     scale = sm_scale if sm_scale is not None else D ** -0.5
-    # kernel wants [H, L, D] per example; vmap over batch
+
+    def local(qt, kt, vt):
+        # kernel wants [H, L, D] per example; vmap over batch
+        return jax.vmap(_splash_kernel(L, qt.shape[1], blk))(qt, kt, vt)
+
+    if mesh is not None and mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+
+        from edl_tpu.parallel.mesh import batch_divisor
+        batch = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
+        tp = mesh.shape.get("tp", 1)
+        spec = P(batch if batch and B % batch_divisor(mesh) == 0 else None,
+                 "tp" if tp > 1 and H % tp == 0 else None, None, None)
+        local = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                              out_specs=spec, check_vma=False)
     qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
-    out = jax.vmap(kernel)((qt * scale).astype(q.dtype), kt, vt)
+    out = local((qt * scale).astype(q.dtype), kt, vt)
     return out.swapaxes(1, 2)
 
 
@@ -146,20 +163,23 @@ def _flash_ok(q, k) -> bool:
     return Lq % 128 == 0 and Lk % 128 == 0 and D % 64 == 0
 
 
-_warned_shapes: set[tuple[int, int, int]] = set()
+_logged_shapes: set[tuple[int, int, int]] = set()
 
 
-def _warn_downgrade(lq: int, lk: int, d: int) -> None:
-    """Loud downgrade (perf-sensitive users must see it), but once per
-    shape — init/trace passes with tiny shapes would otherwise repeat
-    it on every model build."""
-    if (lq, lk, d) in _warned_shapes:
+def _log_auto_choice(impl: str, lq: int, lk: int, d: int) -> None:
+    """Say what ``auto`` resolved to on a TPU, once per shape (init and
+    trace passes repeat shapes).  A downgrade to dense is loud:
+    perf-sensitive users must see it."""
+    if (lq, lk, d) in _logged_shapes:
         return
-    _warned_shapes.add((lq, lk, d))
+    _logged_shapes.add((lq, lk, d))
     from edl_tpu.utils.logger import get_logger
-    get_logger(__name__).warning(
-        "attention auto: shapes L=%d/%d D=%d not tileable for the pallas "
-        "flash kernel; using dense", lq, lk, d)
+    log = get_logger(__name__)
+    if impl == "dense":
+        log.warning("attention auto: shapes L=%d/%d D=%d not tileable for "
+                    "the pallas kernels; using dense", lq, lk, d)
+    else:
+        log.info("attention auto: L=%d/%d D=%d -> %s", lq, lk, d, impl)
 
 
 def dot_product_attention(q, k, v, *, causal: bool = False,
@@ -171,16 +191,18 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     docstring).  ``mask`` (dense-only) broadcasts against [B, H, Lq, Lk];
     ``impl="ring"`` requires ``mesh`` and shards the sequence over
     ``sp_axis`` (``ring_kv_chunk`` bounds its inner logits tile; 0
-    disables chunking)."""
+    disables chunking); splash uses ``mesh``, when given, to run on
+    each device's own batch rows and heads."""
     if impl == "auto":
-        if _on_tpu() and mask is None and _splash_ok(q, k, causal):
+        kernels = _on_tpu() and mask is None
+        if kernels and _splash_ok(q, k, causal):
             impl = "splash"
-        elif _on_tpu() and mask is None and _flash_ok(q, k):
+        elif kernels and _flash_ok(q, k):
             impl = "flash"
         else:
-            if _on_tpu() and mask is None:
-                _warn_downgrade(q.shape[1], k.shape[1], q.shape[3])
             impl = "dense"
+        if kernels:
+            _log_auto_choice(impl, q.shape[1], k.shape[1], q.shape[3])
     # grouped-query attention: dense attends grouped K/V natively (no
     # repeated materialisation); the pallas kernels and ring want MHA
     # shapes, so the group expansion happens HERE, not at every caller
@@ -198,7 +220,7 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     if impl == "splash":
         if not causal:
             raise ValueError("impl='splash' is causal-only; use flash/dense")
-        return _splash(q, k, v, sm_scale)
+        return _splash(q, k, v, sm_scale, mesh)
     if impl == "flash":
         scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
         return _flash(q, k, v, causal, scale)

@@ -110,9 +110,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh,
     assert B % M == 0, f"global batch {B} not divisible by {M} microbatches"
     x_mb = x.reshape((M, B // M) + x.shape[1:])
 
-    from edl_tpu.utils.jax_compat import shard_map  # version shim
     # manual over pp only; every other axis stays automatic (GSPMD)
-    out_mb = shard_map(
+    out_mb = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

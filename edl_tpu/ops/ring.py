@@ -46,8 +46,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = False,
                               n_shards=mesh.shape[sp_axis],
                               causal=causal, scale=scale,
                               kv_chunk=kv_chunk)
-    from edl_tpu.utils.jax_compat import shard_map
-    f = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    f = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                   out_specs=spec, check_vma=False)
     return f(q, k, v)
 

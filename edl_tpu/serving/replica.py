@@ -669,6 +669,11 @@ def main(argv: list[str] | None = None) -> None:  # pragma: no cover - thin CLI
                         "parity smokes)")
     args = p.parse_args(argv)
     configure()
+    from edl_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    dev = jax.devices()
+    logger.info("devices: platform=%s device_kind=%s count=%d",
+                dev[0].platform, dev[0].device_kind, len(dev))
     obs.install_from_env("replica")
     # /profile on the replica's metrics endpoint: the gateway-p99-slo
     # alert action captures HERE (jax.profiler on real accelerators;
@@ -696,6 +701,7 @@ def main(argv: list[str] | None = None) -> None:  # pragma: no cover - thin CLI
         if restored is None:
             raise SystemExit(f"no checkpoint under {args.checkpoint_dir}")
         params = restored[0].params
+        del restored        # the optimizer moments leave the device
         ck.close()
     else:
         params = TransformerLM(cfg).init(

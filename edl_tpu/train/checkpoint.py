@@ -216,26 +216,25 @@ class CheckpointManager:
 
 
 def reset_multihost_counters() -> None:
-    """Align Orbax's process-local barrier-name counters across a world
-    whose members have divergent histories.
+    """Align Orbax's process-local operation id across a world whose
+    members have divergent histories.
 
-    Orbax derives multihost barrier names from module-level
-    ``itertools.count()`` counters (one tick per AsyncCheckpointer
-    construction, per save, per tmp directory, ...).  They normally
-    advance in lockstep on every process; after a LIVE reshard the
-    survivors have ticked them many times while a freshly spawned
-    joiner starts at zero — their barrier names would never match and
-    the first collective checkpoint op would die on
-    ``sync_global_devices name mismatch``.  Survivors therefore reset
-    every counter before constructing their post-reshard manager,
-    restoring lockstep with the joiners by construction."""
+    Orbax 0.11 names its multihost barriers statically, but the
+    directory-creation signals of an async save travel through the
+    coordination service's key-value store under
+    ``OperationIdGenerator``'s process-wide id (one tick per save).
+    Ids normally advance in lockstep on every process; after a LIVE
+    reshard the survivors have ticked theirs many times while a freshly
+    spawned joiner starts at zero — the joiner would wait for a signal
+    key the survivors never write.  Survivors therefore put the
+    generator back to its import-time state before constructing their
+    post-reshard manager, restoring lockstep with the joiners by
+    construction."""
     import itertools
-    try:
-        from orbax.checkpoint.multihost import counters
-    except Exception:  # noqa: BLE001 — older orbax: nothing to reset
-        logger.exception("orbax counters module unavailable; multihost "
-                         "checkpoint barriers may mismatch after reshard")
-        return
-    for name, value in list(vars(counters).items()):
-        if isinstance(value, itertools.count):
-            setattr(counters, name, itertools.count())
+
+    from orbax.checkpoint._src.futures.synchronization import (
+        OperationIdGenerator,
+    )
+    OperationIdGenerator._operation_id_counter = itertools.count()
+    OperationIdGenerator._operation_id = next(
+        OperationIdGenerator._operation_id_counter)

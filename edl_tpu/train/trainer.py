@@ -184,6 +184,10 @@ class ElasticTrainer:
             self._obs_register = obs_advert.advertise_installed(
                 store, tenv.job_id, "trainer", extra={"pod": tenv.pod_id})
         self.mesh = build_mesh(self.cfg.mesh_spec, devices)
+        dev = self.mesh.devices.flat[0]
+        logger.info("devices: platform=%s device_kind=%s count=%d mesh=%s",
+                    dev.platform, dev.device_kind, self.mesh.devices.size,
+                    dict(self.mesh.shape))
         self.rules = self.cfg.rules
         self.adjust = AdjustRegistry()
         # delta replication plane (memstate/delta.py): owned here, built
@@ -335,6 +339,8 @@ class ElasticTrainer:
             self._restore_source = "storage"
             RESTORE_SECONDS.labels(source="storage").observe(
                 time.perf_counter() - t0)
+            logger.info("restored step %d from storage (restore_source="
+                        "storage, %.1fs)", latest, time.perf_counter() - t0)
         if saved_meta is not None:
             meta = saved_meta
         self._t_restored = time.time()  # recovery-time instrumentation
@@ -622,6 +628,7 @@ class ElasticTrainer:
                         _EXAMPLES_TOTAL.inc(int(leaves[0].shape[0]))
                 if self._flops_per_step is None:
                     self._compute_flops(state, gbatch, step_rng)
+                    self._log_device_memory()
                 if self._t_restored is not None:
                     self._report_recovery(metrics)
                 self._heartbeat()
@@ -877,6 +884,17 @@ class ElasticTrainer:
         import threading
         threading.Thread(target=run, daemon=True,
                          name="edl-mfu-cost-analysis").start()
+
+    def _log_device_memory(self) -> None:
+        """Once per compiled step function: what every local device
+        holds after a real step (state + batch + workspace) — the HBM
+        headroom figure, and the proof that a mesh spread the job."""
+        held = {d.id: d.memory_stats() for d in jax.local_devices()}
+        if all(held.values()):   # CPU backends report nothing
+            logger.info("device memory after step (MiB in use / peak): %s",
+                        {i: (s["bytes_in_use"] >> 20,
+                             s["peak_bytes_in_use"] >> 20)
+                         for i, s in held.items()})
 
     def _publish_mfu(self) -> None:
         if not (self._flops_per_step and self._step_ema):
@@ -1343,8 +1361,8 @@ class ElasticTrainer:
             dist.reform_world(self.tenv, self.store, cluster)
             # construct the NEW manager first thing in the new world:
             # its construction sync pairs with the construction sync of
-            # freshly spawned joiner trainers, and the barrier-name
-            # counters reset so survivor and joiner names agree
+            # freshly spawned joiner trainers, and Orbax's operation id
+            # resets so survivor and joiner signal keys agree
             # (checkpoint.reset_multihost_counters)
             from edl_tpu.train.checkpoint import reset_multihost_counters
             reset_multihost_counters()

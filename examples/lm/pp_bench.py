@@ -27,14 +27,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import numpy as np
-
-from edl_tpu.train.distributed import force_platform_from_env
-
-force_platform_from_env()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 import optax  # noqa: E402
 
 from edl_tpu.models import TransformerConfig  # noqa: E402

@@ -212,7 +212,9 @@ def main() -> None:
 
     from edl_tpu.distill.teacher import TeacherServer
     from edl_tpu.models.transformer import TransformerConfig, TransformerLM
+    from edl_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = TransformerConfig(
         vocab_size=args.vocab, num_layers=args.layers, embed_dim=args.embed,
         num_heads=args.heads, num_kv_heads=args.kv_heads,

@@ -332,9 +332,10 @@ def main() -> None:
     trainer = ElasticTrainer(loss_fn, trconf, store=store, tenv=tenv)
     if args.pp > 1:
         model.mesh = trainer.mesh
-    elif args.attention == "ring":
-        import dataclasses
-        cfg = dataclasses.replace(cfg, mesh=trainer.mesh)
+    elif args.attention == "ring" or trainer.mesh.size > 1:
+        # ring needs the mesh; the splash kernel uses it to stay on each
+        # device's own rows (ops/attention._splash)
+        cfg = _dc.replace(cfg, mesh=trainer.mesh)
         model = TransformerLM(cfg)
 
     from edl_tpu.parallel.mesh import batch_divisor
@@ -353,7 +354,8 @@ def main() -> None:
     state, meta = trainer.restore_or_create(init, optax.adamw(args.lr),
                                             param_logical=logical)
     print(f"[train_lm] rank={rank}/{world} mesh={dict(trainer.mesh.shape)} "
-          f"attn={args.attention} resume_epoch={meta.next_epoch}", flush=True)
+          f"attn={args.attention} dtype={jnp.dtype(cfg.dtype).name} "
+          f"remat={cfg.remat} resume_epoch={meta.next_epoch}", flush=True)
 
     def data_fn(epoch: int):
         gen = markov_corpus(args, 1000 * (epoch + 1) + rank)

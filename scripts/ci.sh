@@ -189,72 +189,22 @@ JAX_PLATFORMS=cpu python scripts/data_throughput_smoke.py
 # and 100+ prompts bit-identical spec vs plain greedy
 JAX_PLATFORMS=cpu python scripts/serving_perf_smoke.py
 
-# bench smoke: the driver's bench entry must always produce its JSON
-# line (tiny CPU knobs; LM/pipeline sections skipped off-TPU).  bench
-# now exits 0 even on failure (partial-artifact contract), so CI must
-# assert the artifact is COMPLETE — no error/partial keys, real value
-EDL_TPU_BENCH_SIZE=32 EDL_TPU_BENCH_BS=4 EDL_TPU_BENCH_STEPS=2 \
-EDL_TPU_BENCH_WIDTH=8 EDL_TPU_BENCH_PIPELINE=0 EDL_TPU_BENCH_LM=0 \
-EDL_TPU_BENCH_MEMSTATE_MB=8 EDL_TPU_BENCH_TRANSFER_MB=8 \
-EDL_TPU_BENCH_DELIVERY_FILES=2 EDL_TPU_BENCH_DELIVERY_RECORDS=96 \
-EDL_TPU_BENCH_SERVING_REQS=6 EDL_TPU_BENCH_SERVING_LONG=96 \
-EDL_TPU_BENCH_SERVING_CHUNK=16 \
-JAX_PLATFORMS=cpu python bench.py | tail -1 \
-    | python -c "
-import json, sys
-out = json.loads(sys.stdin.read())
-assert 'error' not in out and not out.get('partial'), out
-assert out.get('value'), out
-# streamed data delivery (ISSUE 11) must land in the artifact
-assert out.get('data_delivery_samples_s'), out
-# alerting loop (ISSUE 9): detection latency must land near the rule's
-# declared window+hold, and the background scrape loop must cost the
-# step loop ~nothing (<2% target on real hosts; 5% absorbs 1-core CI
-# noise without masking a pathological regression)
-lat, bound = out['alert_detect_latency_s'], out['alert_rule_bound_s']
-assert lat <= bound * 2 + 5, (lat, bound)
-assert out['obs_scrape_overhead_pct'] < 5, out['obs_scrape_overhead_pct']
-# live resize (ISSUE 12): delta-resharding must not lose to stop-resume
-# on the same grow-by-one (it skips process respawn + jax cold import)
-dl, sr = out['resize_delta_mttr_s'], out['resize_stop_resume_mttr_s']
-assert dl <= sr, (dl, sr)
-# delta replication plane (ISSUE 17): a cadence step must ship fewer
-# bytes than a full shard set (only the hot slice changes), the chain
-# restore must work, and an induced mid-interval failure must lose
-# fewer steps on the chain path than the checkpoint rollback
-assert out['delta_bytes_per_step_mb'] < out['delta_full_shard_mb'], out
-assert out.get('delta_lag_p50_ms') is not None, out
-assert out['delta_steps_lost_per_failure'] \
-    < out['checkpoint_steps_lost_per_failure'], out
-# continuous profiling (ISSUE 13): the per-step phase ledger must cost
-# the hot loop under 2% of step time (measured directly, noise-immune)
-assert out['step_phase_overhead_pct'] < 2, out['step_phase_overhead_pct']
-# flight recorder (ISSUE 19): the always-on ring tap must cost the
-# step loop under 2% (per-event delta measured directly, noise-immune)
-# and a live bundle capture must complete and report its wall time
-assert out['flightrec_overhead_pct'] < 2, out['flightrec_overhead_pct']
-assert out.get('bundle_capture_seconds') is not None, out
-# paged KV cache (ISSUE 14): on the shared-system-prompt workload the
-# prefix-hit engine must not lose to cold prefill and must actually
-# skip most of the prompt; the drain handoff must yield a latency
-pw, pc = out['serving_prefix_tokens_s'], out['serving_cold_tokens_s']
-assert pw >= pc, (pw, pc)
-assert out['serving_prefill_skipped_frac'] > 0.5, out
-assert out.get('serving_kv_migration_ms') is not None, out
-# serving fast path (ISSUE 20): the mesh throughput, chunked-prefill
-# p99, and spec accept-rate sections must land in the artifact, and
-# the self-draft spec run must accept near-everything (bit-exactness
-# itself is gated by tests + serving_perf_smoke)
-assert out.get('serving_mesh_tokens_s'), out
-assert out.get('serving_prefill_p99_ms') is not None, out
-assert out['serving_spec_accept_rate'] > 0.9, out
-# distill fleet elasticity (ISSUE 18): three teachers must beat one on
-# the same slow-teacher stream (routing/fan-out actually helps), and a
-# published backlog record must step the autoscaler's target promptly
-s1, s3 = out['distill_student_rows_s_1'], out['distill_student_rows_s_3']
-assert s3 >= s1, (s1, s3)
-assert out.get('distill_backlog_scale_latency_s') is not None, out
-print('bench smoke OK')"
+# bench refuses the CPU: every key it prints is a device metric, and a
+# CPU run under those names would be read as one (ROADMAP S0).  It must
+# exit non-zero here and print nothing that could pass for a result.
+# (The control-plane asserts this stage used to make on a CPU bench run
+# are the smoke stages above: resize, delta failover, kv cache, serving
+# perf, alerts, postmortem, distill chaos.)
+if JAX_PLATFORMS=cpu python bench.py > /tmp/edl-bench.out 2>/dev/null; then
+    echo "bench.py exited 0 on the CPU"; exit 1
+fi
+[ ! -s /tmp/edl-bench.out ] || { echo "bench.py printed on the CPU:"; \
+    cat /tmp/edl-bench.out; exit 1; }
+if JAX_PLATFORMS=cpu python chip_smoke.py > /tmp/edl-smoke.out 2>/dev/null; then
+    echo "chip_smoke.py exited 0 on the CPU"; exit 1
+fi
+grep -q '"ok"' /tmp/edl-smoke.out && { echo "chip_smoke.py printed a result on the CPU"; exit 1; }
+echo "bench/chip_smoke refuse the CPU OK"
 
 # packaging sanity: console scripts resolve
 edl-lint --help >/dev/null 2>&1 || { echo "edl-lint missing"; exit 1; }

@@ -139,3 +139,39 @@ def test_splash_matches_dense_on_tpu():
     d = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.float32(s), np.float32(d),
                                atol=2e-2, rtol=2e-2)
+
+
+def test_splash_runs_per_shard_on_a_mesh(monkeypatch):
+    """On a mesh the Mosaic call sits under shard_map over the batch
+    (dp x fsdp) and head (tp) axes: each device's kernel sees only its
+    own rows and heads, and the pieces reassemble to dense attention.
+    The kernel itself is TPU-only, so a dense per-example stand-in with
+    the kernel's [H, L, D] contract records the shapes it was built for."""
+    from edl_tpu.ops import attention
+    from edl_tpu.parallel import MeshSpec, build_mesh
+
+    built = []
+
+    def fake_kernel(L, H, blk):
+        built.append((L, H))
+
+        def kernel(q, k, v):            # [H, L, D]; q arrives pre-scaled
+            out = dense_attention(q.swapaxes(0, 1)[None],
+                                  k.swapaxes(0, 1)[None],
+                                  v.swapaxes(0, 1)[None], causal=True,
+                                  sm_scale=1.0)
+            return out[0].swapaxes(0, 1)
+        return kernel
+
+    monkeypatch.setattr(attention, "_splash_kernel", fake_kernel)
+    mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.normal(size=(8, 128, 4, 64)), jnp.float32)
+               for _ in range(3))
+    got = jax.jit(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True, impl="splash", mesh=mesh))(q, k, v)
+    assert built == [(128, 2)]          # 4 heads over tp=2
+    np.testing.assert_allclose(got, dense_attention(q, k, v, causal=True),
+                               atol=1e-5, rtol=1e-5)
+    assert got.sharding.spec == jax.sharding.PartitionSpec(
+        ("dp", "fsdp"), None, "tp")

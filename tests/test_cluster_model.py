@@ -75,6 +75,40 @@ def test_trainer_env_abi():
     assert te.is_distributed
 
 
+def test_trainer_env_narrows_a_trainer_to_its_chips():
+    """``--devices`` must reach libtpu before the child imports jax: a
+    chip belongs to one process, and EDL_TPU_DEVICE_IDS alone narrows
+    nothing.  A lone trainer on a row of the 2x2 host gets the measured
+    layout; a trainer that owns the whole host gets nothing; trainers
+    that would share one host's chips are refused (ROADMAP S9c)."""
+    import pytest
+
+    class _A:
+        job_id = "j1"
+        coord_endpoints = "h:2379"
+
+    def env_of(pods, i=0):
+        return trainer_env_vars(JobEnv(_A()), pods[i], pods[i].trainers[0],
+                                Cluster.from_pods(pods))
+
+    env = env_of([make_pod("10.0.0.1", nproc=1, devices=(2, 3))])
+    assert env["EDL_TPU_DEVICE_IDS"] == env["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["ALLOW_MULTIPLE_LIBTPU_LOAD"] == "1"
+
+    env = env_of([make_pod(nproc=1, devices=())])
+    assert not [k for k in env if k.startswith("TPU_")]
+
+    pair = [make_pod("10.0.0.1", nproc=1, devices=(0, 1)),
+            make_pod("10.0.0.1", nproc=1, devices=(2, 3))]
+    with pytest.raises(ValueError, match="share one host's chips"):
+        env_of(pair, 1)
+    for bad in ((0, 1, 2), (0, 2), (1, 2)):     # not a row of the host
+        with pytest.raises(ValueError, match="not a chip set"):
+            env_of([make_pod("10.0.0.1", nproc=1, devices=bad)])
+
+
 def test_status_tables(memkv):
     save_pod_status(memkv, "j", "p0", Status.RUNNING)
     save_pod_status(memkv, "j", "p1", Status.FAILED)

@@ -110,3 +110,26 @@ def test_supervise_grace_turns_peer_crash_into_resize(monkeypatch):
     start = time.monotonic()
     assert lch._supervise(watcher2, None) == Status.FAILED
     assert time.monotonic() - start >= lch._fail_grace()
+
+
+def test_second_launcher_on_a_hosts_chips_is_refused(coord_server):
+    """Trainers sharing one TPU host's chips cannot form a correct
+    world on libtpu (ROADMAP S9c), and the resize a second pod triggers
+    would take the running trainer down: a launcher with ``--devices``
+    refuses to join a job that already holds chips on this host."""
+    from edl_tpu.cluster.pod import Pod
+    from edl_tpu.collective import launch
+    from edl_tpu.coord.client import CoordClient
+    from edl_tpu.utils.network import local_ip
+
+    ep = f"127.0.0.1:{coord_server.port}"
+    client = CoordClient(ep)
+    reg = register_pod(client, JOB, Pod(addr=local_ip(), device_ids=[0, 1]))
+    try:
+        rc = launch.run(["--job_id", JOB, "--coord_endpoints", ep,
+                         "--devices", "2,3", "train.py"])
+        assert rc == 1
+        assert len(load_resource_pods(client, JOB)) == 1    # never joined
+    finally:
+        reg.stop()
+        client.close()

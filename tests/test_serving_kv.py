@@ -268,3 +268,31 @@ def test_mesh_engine_accepts_paging_bit_exact(small):
     for p, out in zip(prompts, outs):
         np.testing.assert_array_equal(out, _want(cfg, params, p, 5))
     assert stats["kv_prefix_hits"] >= len(prompts) - 1, stats
+
+
+def test_engine_that_cannot_fit_is_refused_at_construction(small,
+                                                           monkeypatch):
+    """A device that reports a memory limit gets the arithmetic done
+    before anything is allocated: an engine whose slabs + self-sized
+    pool + widest prefill exceed it raises with every size named,
+    instead of an XLA allocation error on some later request."""
+    cfg, params = small
+
+    class _Chip:
+        device_kind = "toy chip"
+
+        def __init__(self, limit):
+            self._limit = limit
+
+        def memory_stats(self):
+            return {"bytes_limit": self._limit, "bytes_in_use": 1 << 20}
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Chip((1 << 20) + 4096)])
+    with pytest.raises(ValueError) as err:
+        ContinuousBatcher(cfg, params, slots=2, kv_block=8)
+    for part in ("toy chip", "2 slots x", "slot slabs", "block pool",
+                 "blocks of 8", "widest prefill", "GiB limit"):
+        assert part in str(err.value), err.value
+    monkeypatch.setattr(jax, "devices", lambda: [_Chip(1 << 40)])
+    eng = ContinuousBatcher(cfg, params, slots=2, kv_block=8)
+    eng.stop()

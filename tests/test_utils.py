@@ -105,3 +105,28 @@ def test_logger_configure_file_handler_idempotent(tmp_path):
             if h not in before:
                 root.removeHandler(h)
                 h.close()
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the helper touches nothing (jax
+    reads the variable itself).  Unset: one in-checkout path, the same
+    on every call and for every process — never a temp dir, a pid or
+    the clock, which would make a cache that cannot hit."""
+    import os
+
+    import jax
+
+    from edl_tpu.utils.compile_cache import enable_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    assert enable_compile_cache() == "/x"
+    assert updates == []
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert enable_compile_cache() == enable_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)] * 2
