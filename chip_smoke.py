@@ -36,6 +36,7 @@ import contextlib
 import json
 import os
 import re
+import resource
 import shutil
 import signal
 import subprocess
@@ -425,6 +426,15 @@ def one_chip(smoke: Smoke, device: dict) -> tuple[str, dict]:
                             extra=("--batch_size", "8"))
         if first["resume_epoch"] != 0:
             smoke.fail(f"fresh run resumed at epoch {first['resume_epoch']}")
+        # train/checkpoint.py keeps Orbax's data files under 2 x 32 MiB:
+        # the machine that checks PRs refuses a larger write (EFBIG)
+        sizes = [os.path.getsize(os.path.join(r, f))
+                 for r, _, fs in os.walk(ckpt) for f in fs]
+        print(f"[chip_smoke] checkpoints: {sum(sizes) >> 20} MiB in "
+              f"{len(sizes)} files, the largest {max(sizes) >> 20} MiB",
+              flush=True)
+        if max(sizes) >= 64 << 20:
+            smoke.fail(f"a checkpoint file of {max(sizes)} bytes")
     with smoke.phase("restore+train"):
         second = smoke.train("train-2", ckpt, 3, device,
                              extra=("--batch_size", "8"))
@@ -501,6 +511,12 @@ def main() -> None:
         print(f"[chip_smoke] platform={device['platform']} "
               f"device_kind={device['kind']} count={device['count']}; "
               f"logs in {smoke.logs}", flush=True)
+        fsize = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+        print(f"[chip_smoke] data in {smoke.data} "
+              f"({shutil.disk_usage(smoke.data).free >> 30} GiB free), "
+              f"RLIMIT_FSIZE="
+              f"{'none' if fsize == resource.RLIM_INFINITY else fsize}",
+              flush=True)
         smoke.start_coord()
         ckpt, served = one_chip(smoke, device)
         if device["count"] >= 4:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import json
 import os
+import resource
 import time
 from typing import Any
 
@@ -47,6 +48,23 @@ _TEE_STAGE_SECONDS = obs_metrics.histogram(
     "Synchronous memstate tee snapshot during save() (seconds)")
 _RESTORES_TOTAL = obs_metrics.counter(
     "edl_checkpoint_restores_total", "Checkpoint restores completed")
+
+# TensorStore's OCDBT driver batches whatever writes are pending into ONE
+# data file, up to a 2 GiB default: the 12 x 768 flagship's 1.9 GB of
+# state landed in files of 90-480 MB, and a trainer held to a smaller
+# RLIMIT_FSIZE died with EFBIG inside its first commit (PR 21, the chip
+# check's machine).  With a target T Orbax also chunks every array to
+# <= T, and a file closes once it holds T: none reaches 2 T.
+_DATA_FILE_TARGET = 32 << 20
+
+
+def _data_file_target() -> int:
+    """OCDBT target data-file size: 32 MiB, or a third of this process's
+    file-size limit where that is lower."""
+    soft, _ = resource.getrlimit(resource.RLIMIT_FSIZE)
+    if soft == resource.RLIM_INFINITY:
+        return _DATA_FILE_TARGET
+    return min(_DATA_FILE_TARGET, soft // 3)
 
 
 class CheckpointManager:
@@ -80,7 +98,10 @@ class CheckpointManager:
     # -- save ---------------------------------------------------------------
     def save(self, step: int, state: Any, meta: State | None = None,
              force: bool = False) -> bool:
-        args = {"state": ocp.args.StandardSave(state)}
+        # PyTreeSave/PyTreeRestore are what StandardSave/StandardRestore
+        # wrap; only the former take the data-file target
+        args = {"state": ocp.args.PyTreeSave(
+            state, ocdbt_target_data_file_size=_data_file_target())}
         if meta is not None:
             args["meta"] = ocp.args.JsonSave(meta.to_dict())
         t0 = time.perf_counter()
@@ -112,11 +133,14 @@ class CheckpointManager:
         if step is None:
             return None
         t0 = time.perf_counter()
+        state_args = ocp.args.PyTreeRestore(
+            item=abstract_state,
+            restore_args=ocp.checkpoint_utils.construct_restore_args(
+                abstract_state))
         if self._has_item(step, "meta"):
             restored = self._mngr.restore(
                 step, args=ocp.args.Composite(
-                    state=ocp.args.StandardRestore(abstract_state),
-                    meta=ocp.args.JsonRestore()))
+                    state=state_args, meta=ocp.args.JsonRestore()))
         else:
             # checkpoint written without a State sidecar (e.g. a served
             # model exported by save(step, state) alone).  Checked
@@ -124,8 +148,7 @@ class CheckpointManager:
             # restore: a KeyError from the state restore itself (pytree
             # mismatch) must surface, not trigger a second restore.
             restored = self._mngr.restore(
-                step, args=ocp.args.Composite(
-                    state=ocp.args.StandardRestore(abstract_state)))
+                step, args=ocp.args.Composite(state=state_args))
         meta = None
         if restored.get("meta") is not None:
             meta = State().from_dict(restored["meta"])

@@ -4,6 +4,8 @@ The linear-regression flow is the reference's fit_a_line smoke workload
 (example/fit_a_line) run TPU-natively on the 8-device CPU mesh.
 """
 
+import os
+
 import numpy as np
 import optax
 import pytest
@@ -75,6 +77,36 @@ def test_checkpoint_resume(tmp_path):
     assert int(state2.step) == 10
     assert [e.epoch_no for e in meta2.epochs] == [0, 1]
     tr2.ckpt.close()
+
+
+def test_checkpoint_data_files_stay_under_the_file_size_limit(
+        tmp_path, monkeypatch):
+    """PR 21's chip check died with EFBIG in the first commit: OCDBT had
+    batched the flagship's state into files past the machine's
+    RLIMIT_FSIZE.  The writer now sizes its data files from that limit."""
+    import resource
+
+    from edl_tpu.train import checkpoint as ckpt_mod
+
+    limit = 3 << 20
+    monkeypatch.setattr(resource, "getrlimit", lambda which: (limit, limit))
+    assert ckpt_mod._data_file_target() == limit // 3
+    state = {"w": jnp.asarray(RNG.normal(size=(16, 256, 256)), jnp.float32),
+             "step": jnp.zeros((), jnp.int32)}           # 4 MiB, one array
+    ck = CheckpointManager(str(tmp_path))
+    ck.save(1, state)
+    ck.wait()
+    sizes = [os.path.getsize(os.path.join(r, f))
+             for r, _, fs in os.walk(tmp_path) for f in fs]
+    assert sum(sizes) > limit > max(sizes)      # it had to be split
+    got, meta = ck.restore(abstract_like(state))
+    assert meta is None
+    np.testing.assert_array_equal(np.asarray(got["w"]), np.asarray(state["w"]))
+    ck.close()
+    monkeypatch.setattr(
+        resource, "getrlimit",
+        lambda which: (resource.RLIM_INFINITY, resource.RLIM_INFINITY))
+    assert ckpt_mod._data_file_target() == 32 << 20
 
 
 def test_adjust_registry_fires_on_world_change(tmp_path):
