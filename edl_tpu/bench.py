@@ -48,9 +48,10 @@ def _bench_step_ledger(step_dt: float) -> dict:
     of the measured synthetic step time.
 
     Times the EXACT per-step operations the instrumented epoch loop
-    adds — four ``phase()`` context entries, one external ``add()``
-    credit (the h2d stage), and ``step_done()``'s histogram observes +
-    coverage update — over enough iterations that the per-step figure
+    adds — five ``phase()`` context entries (the h2d stage nests in
+    data_wait; each is also a profiler annotation), and
+    ``step_done()``'s histogram observes + coverage update — over
+    enough iterations that the per-step figure
     is stable, then divides by the real step time just measured.  A
     direct measurement instead of an on/off A-B run: on a noisy 1-core
     CI box the A-B difference of two ~ms loops is dominated by
@@ -65,7 +66,8 @@ def _bench_step_ledger(step_dt: float) -> dict:
         t0 = time.perf_counter()
         for i in range(iters):
             with ledger.phase("data_wait"):
-                ledger.add("h2d", 0.0)
+                with ledger.phase("h2d"):
+                    pass
             with ledger.phase("hooks"):
                 pass
             with ledger.phase("compute"):

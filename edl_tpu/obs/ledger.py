@@ -35,13 +35,24 @@ counter tracks next to the span rows.  Outside a capture window the
 same event is emitted on a throttled cadence (~`_EMIT_EVERY_S`), so
 long-running jobs always have a coarse phase history in their trace.
 
-``EDL_TPU_STEP_LEDGER=0`` disables every phase timer (the bench gates
-the enabled cost at < 2% of step time — `step_phase_overhead_pct`).
+**One instrument, two loops.**  The class is parametrised by its
+phase tuple, histogram and component: the trainer's epoch loop uses the
+defaults above; ``ContinuousBatcher``'s engine thread builds one over
+its tick phases (``serving/engine.py``: ``edl_engine_tick_phase_seconds``,
+component ``engine``).  A ``with ledger.phase(name):`` block gives
+three things at once: exclusive host seconds into the histogram and
+into cumulative totals (:meth:`StepPhaseLedger.totals`: what
+``stats()`` and the benchmark difference), a
+``jax.profiler.TraceAnnotation("<component>/<name>")`` so the same span
+sits in a profiler capture on the device's clock (``train/compute``,
+``engine/sync``, ... in ``/profile`` or the benchmark's traced run;
+:func:`edl_tpu.obs.trace.annotation`, a no-op without JAX), and the
+coverage self-check.  There is no switch in the environment: the
+benchmark reads the ledger, and ``enabled=False`` is for tests.
 """
 
 from __future__ import annotations
 
-import os
 import time
 from contextlib import contextmanager
 
@@ -64,23 +75,45 @@ _COVERAGE_G = obs_metrics.gauge(
 _EMIT_EVERY_S = 30.0
 
 
-def enabled_from_env() -> bool:
-    return os.environ.get("EDL_TPU_STEP_LEDGER", "1") != "0"
-
-
 class StepPhaseLedger:
-    """One instance per train loop; NOT thread-safe by design — every
-    call happens on the consumer (epoch-loop) thread, including the
-    ``h2d``/``data_wait`` credits from generators the loop drives."""
+    """One instance per loop; NOT thread-safe by design — every call
+    happens on the loop's own thread (the trainer's epoch loop,
+    including the ``h2d``/``data_wait`` credits from generators it
+    drives; the engine's tick thread).  :meth:`totals` alone may be
+    called from another thread, under whatever lock the owner holds
+    around :meth:`step_done`.
 
-    def __init__(self, enabled: bool | None = None, component: str = ""):
-        self.enabled = enabled_from_env() if enabled is None else enabled
+    ``phases``/``histogram``/``coverage_gauge`` default to the
+    trainer's; ``component`` prefixes the profiler annotations and
+    names the trace event (``<component>/step_phases``).
+    ``idle_phase`` names a phase that is time BETWEEN steps (the
+    engine blocked waiting for requests): observed and totalled like
+    any other, but taken out of the step's wall time and of the
+    coverage check.  ``overhead_phase`` is charged the ledger's own
+    close-out cost."""
+
+    def __init__(self, enabled: bool = True, component: str = "train",
+                 phases: tuple[str, ...] = PHASES,
+                 histogram=PHASE_SECONDS, coverage_gauge=_COVERAGE_G,
+                 idle_phase: str | None = None,
+                 overhead_phase: str = "hooks"):
+        self.enabled = enabled
         self.component = component
-        self._acc = dict.fromkeys(PHASES, 0.0)
+        self.phases = tuple(phases)
+        self._histogram = histogram
+        self._coverage_gauge = coverage_gauge
+        self._idle_phase = idle_phase
+        self._overhead_phase = overhead_phase
+        self._event = f"{component}/step_phases"
+        self._acc = dict.fromkeys(self.phases, 0.0)
         self._open: list[list[float]] = []   # stack of [deduction] frames
         self._cover_ema: float | None = None
+        # since construction (totals()): what stats() and the benchmark
+        # difference; written in step_done only
+        self._cum = dict.fromkeys(self.phases, 0.0)
+        self._cum_wall = 0.0
         self._steps = 0
-        self._totals = dict.fromkeys(PHASES, 0.0)  # since last trace emit
+        self._totals = dict.fromkeys(self.phases, 0.0)  # since last emit
         self._totals_wall = 0.0
         self._totals_steps = 0
         self._last_emit = time.monotonic()
@@ -91,7 +124,8 @@ class StepPhaseLedger:
     def phase(self, name: str):
         """Time the block into ``name``.  Credits recorded inside the
         block (a nested phase, an external :meth:`add`) are deducted,
-        so enclosing phases report only their own exclusive time."""
+        so enclosing phases report only their own exclusive time.  The
+        block is also a profiler annotation ``<component>/<name>``."""
         if not self.enabled:
             yield
             return
@@ -99,7 +133,8 @@ class StepPhaseLedger:
         self._open.append(frame)
         t0 = time.perf_counter()
         try:
-            yield
+            with obs_trace.annotation(f"{self.component}/{name}"):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self._open.pop()
@@ -128,7 +163,7 @@ class StepPhaseLedger:
         where no inter-step interval exists yet — so the first step's
         jit compile (accumulated inside ``compute``) is never observed
         as if it were a normal step's phase split."""
-        self._acc = dict.fromkeys(PHASES, 0.0)
+        self._acc = dict.fromkeys(self.phases, 0.0)
 
     # -- per-step close ------------------------------------------------------
     def step_done(self, wall_dt: float, step: int | None = None) -> None:
@@ -139,28 +174,36 @@ class StepPhaseLedger:
         if not self.enabled:
             return
         t_self = time.perf_counter()
-        acc, self._acc = self._acc, dict.fromkeys(PHASES, 0.0)
+        acc, self._acc = self._acc, dict.fromkeys(self.phases, 0.0)
         total = 0.0
         for p, v in acc.items():
-            PHASE_SECONDS.labels(phase=p).observe(v)
+            self._histogram.labels(phase=p).observe(v)
             self._totals[p] = self._totals.get(p, 0.0) + v
+            self._cum[p] = self._cum.get(p, 0.0) + v
             total += v
+        # time between steps is no part of the step: out of its wall
+        # time and out of what the phases must account for
+        idle = acc.get(self._idle_phase, 0.0) if self._idle_phase else 0.0
+        wall_dt = max(0.0, wall_dt - idle)
+        total -= idle
         self._steps += 1
+        self._cum_wall += wall_dt
         self._totals_steps += 1
-        self._totals_wall += max(0.0, wall_dt)
+        self._totals_wall += wall_dt
         if wall_dt > 0:
             cover = min(1.0, total / wall_dt)
             self._cover_ema = (cover if self._cover_ema is None
                                else 0.9 * self._cover_ema + 0.1 * cover)
-            _COVERAGE_G.set(self._cover_ema)
+            if self._coverage_gauge is not None:
+                self._coverage_gauge.set(self._cover_ema)
         now = time.monotonic()
         if now < self._capture_until:
             # capture window: one event PER STEP, exact per-phase split
-            obs_trace.emit("train/step_phases", dur=max(0.0, wall_dt),
+            obs_trace.emit(self._event, dur=wall_dt,
                            # edl-lint: disable=clock — back-dating a TRACE
                            # ts to the span begin (merge convention: ts is
                            # begin), not deadline arithmetic
-                           at=time.time() - max(0.0, wall_dt),
+                           at=time.time() - wall_dt,
                            step=step, steps=1,
                            counters={p: round(v, 6) for p, v in acc.items()})
         elif now - self._last_emit >= _EMIT_EVERY_S:
@@ -169,7 +212,7 @@ class StepPhaseLedger:
         # emits) is real per-step overhead: charge it to the NEXT
         # step's hooks so the coverage self-check stays honest on
         # sub-millisecond steps
-        self._acc["hooks"] += time.perf_counter() - t_self
+        self._acc[self._overhead_phase] += time.perf_counter() - t_self
 
     def flush(self, now: float | None = None, step: int | None = None
               ) -> None:
@@ -183,17 +226,25 @@ class StepPhaseLedger:
         if not self.enabled or not self._totals_steps:
             return
         n = self._totals_steps
-        obs_trace.emit("train/step_phases", dur=round(self._totals_wall, 6),
+        obs_trace.emit(self._event, dur=round(self._totals_wall, 6),
                        # edl-lint: disable=clock — back-dating a TRACE ts
                        # to the window begin, not deadline arithmetic
                        at=time.time() - self._totals_wall,
                        step=step, steps=n,
                        counters={p: round(v / n, 6)
                                  for p, v in self._totals.items()})
-        self._totals = dict.fromkeys(PHASES, 0.0)
+        self._totals = dict.fromkeys(self.phases, 0.0)
         self._totals_wall = 0.0
         self._totals_steps = 0
         self._last_emit = time.monotonic() if now is None else now
+
+    def totals(self) -> dict:
+        """Since construction, in one call: ``steps`` closed, their
+        ``wall_s``, the ``coverage`` EMA, and exclusive ``phases``
+        seconds by name.  Cumulative, so a reader differences two
+        calls."""
+        return {"steps": self._steps, "wall_s": self._cum_wall,
+                "coverage": self._cover_ema, "phases": dict(self._cum)}
 
     # -- capture window (the CPU fallback of /profile) -----------------------
     def start_capture(self, duration_s: float) -> None:

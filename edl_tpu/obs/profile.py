@@ -205,8 +205,10 @@ class ProfileCapture:
         path = os.path.join(self.out_dir, manifest["name"] + ".json")
         try:
             os.makedirs(self.out_dir, exist_ok=True)
-            with open(path, "w", encoding="utf-8") as f:
+            # whole or absent: whoever polls for the file reads it next
+            with open(path + ".tmp", "w", encoding="utf-8") as f:
                 json.dump(manifest, f)
+            os.replace(path + ".tmp", path)
         except OSError:
             logger.exception("profile manifest write failed")
 

@@ -34,9 +34,10 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 from edl_tpu.obs import context as obs_context
 from edl_tpu.obs import metrics as obs_metrics
@@ -81,6 +82,24 @@ def _run_taps(rec: dict) -> None:
         # very log the flight recorder is also hooked into
         except Exception:  # noqa: BLE001 — a bad tap must not stop tracing
             pass
+
+
+_NO_ANNOTATION = nullcontext()
+
+
+def annotation(name: str):
+    """``with annotation("engine/sync"):`` puts the block in the JAX
+    profiler's capture as a ``TraceAnnotation``, on the device's clock,
+    whenever a session is on (``/profile``, the benchmark's traced
+    run); with no session it costs the TraceMe's "is anyone
+    listening" test.  A process that has not imported JAX (coord
+    server, launcher parent, load generator) gets a no-op and never
+    imports it here: the phase ledger (:mod:`edl_tpu.obs.ledger`)
+    calls this from every loop it times."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(name)
 
 
 def _build_record(name: str, component: str, dur: float | None,
@@ -286,6 +305,12 @@ def configure_from_env(component: str = "") -> Tracer | None:
             return _tracer
     path = os.path.join(d, f"trace-{component or 'proc'}-{os.getpid()}.jsonl")
     return configure(path, component)
+
+
+def active() -> bool:
+    """Would an event go anywhere (a file or a tap)?  Hot paths that
+    must BUILD an event's fields ask first."""
+    return _tracer.enabled or bool(_TAPS)
 
 
 def emit(name: str, **kw) -> None:

@@ -20,7 +20,9 @@ pool of ``slots`` decode lanes over ONE persistent KV cache:
   — and syncs the host ONCE for all of it.  Active lanes advance
   ``steps_per_sync`` tokens every tick no matter how fast requests
   arrive; ``stats()['prefill_stall_s']`` bounds the decode wall-time
-  cost of prefill dispatches;
+  cost of prefill dispatches: it is the host time of ``engine/admit``
+  (below) in those ticks where lanes were live AND something was
+  admitted — a part of ``tick_admit_s``, never more than it;
 - **chunked prefill** (``prefill_chunk``, ISSUE 20): an admission whose
   prompt exceeds the chunk size prefills into a private one-lane slab
   ONE chunk per tick, interleaved with the decode dispatches, so an
@@ -46,6 +48,26 @@ parity test in tests/test_serving_engine.py asserts exactly that).
 Thread model: callers ``submit()`` from any thread and get a Future;
 one engine thread owns the device state — the same
 single-writer/many-readers split as the TeacherServer coalescer.
+
+**Where a tick's time goes** (the tick ledger, an
+:class:`edl_tpu.obs.ledger.StepPhaseLedger` on the engine thread).
+The phases tile ``_loop``: ``idle_wait`` (blocked in ``_drain`` with no
+live slot and no chunk in flight: waiting for requests; time BETWEEN
+ticks), then inside a tick ``tasks`` (closures from other threads),
+``admit`` (queue drain, prefix matching, prefill/chunk dispatches),
+``dispatch`` (the decode step or speculative round and the inserts),
+``sync`` (the ``np.asarray`` reads: blocked on the device), ``finish``
+(tokens to slots and futures) and, nested in it and deducted from it,
+``kv_commit``.  Each is exclusive host seconds in ``stats()``
+(``tick_<phase>_s``, ``idle_wait_s``, ``ticks``, ``tick_s``,
+``tick_coverage``) and in ``edl_engine_tick_phase_seconds{phase}``, and
+an ``engine/<phase>`` span in any profiler capture.  Per request the
+engine stamps submit, admission, first token and completion:
+``queue_wait_s_sum``/``admitted``, ``ttft_s_sum``/``first_tokens``,
+``decode_s_sum``/``decode_tokens`` in ``stats()``, the
+``edl_engine_queue_wait_seconds`` / ``edl_engine_ttft_seconds`` /
+``edl_engine_intertoken_seconds`` histograms, and one ``engine/request``
+trace event under the submitter's trace.  There is no switch.
 """
 
 from __future__ import annotations
@@ -64,12 +86,42 @@ import numpy as np
 
 from edl_tpu.models.generate import _split_layer_params, sample_logits
 from edl_tpu.models.transformer import TransformerConfig, TransformerLM
+from edl_tpu.obs import context as obs_context
+from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.obs import trace as obs_trace
+from edl_tpu.obs.ledger import StepPhaseLedger
 from edl_tpu.utils import constants
 from edl_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
 
 DEFAULT_PREFILL_BUCKETS = (32, 64, 128, 256, 512)
+
+# the engine thread's time, tiled (module docstring); idle_wait is the
+# time between ticks, kv_commit nests in finish
+TICK_PHASES = ("idle_wait", "tasks", "admit", "dispatch", "sync", "finish",
+               "kv_commit")
+# a tick is 1-100 ms and a decode token 2-30 ms: finer than the default
+_FINE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+                 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
+_TICK_PHASE_SECONDS = obs_metrics.histogram(
+    "edl_engine_tick_phase_seconds",
+    "Per-tick exclusive host time of the engine thread by phase: "
+    "idle_wait / tasks / admit / dispatch / sync / finish / kv_commit "
+    "(serving/engine.py tick ledger)", ("phase",), buckets=_FINE_BUCKETS)
+_QUEUE_WAIT_SECONDS = obs_metrics.histogram(
+    "edl_engine_queue_wait_seconds",
+    "submit() to admission (popped into a prefill, reuse or chunked "
+    "admission), per request, as the engine sees it")
+_TTFT_SECONDS = obs_metrics.histogram(
+    "edl_engine_ttft_seconds",
+    "submit() to the request's first token on the host, per request "
+    "(engine-side time to first token; the client still receives whole "
+    "answers)")
+_INTERTOKEN_SECONDS = obs_metrics.histogram(
+    "edl_engine_intertoken_seconds",
+    "Mean gap between a finished request's tokens after the first: "
+    "(done - first token) / (tokens - 1)", buckets=_FINE_BUCKETS)
 
 
 @dataclass
@@ -84,7 +136,8 @@ class _Slot:
 
 
 class _Request:
-    __slots__ = ("ids", "max_new", "future", "t_submit", "session")
+    __slots__ = ("ids", "max_new", "future", "session", "ctx", "skipped",
+                 "t_submit", "t_admit", "t_first", "t_done")
 
     def __init__(self, ids: np.ndarray, max_new: int,
                  session: str | None = None):
@@ -92,7 +145,13 @@ class _Request:
         self.max_new = max_new
         self.session = session
         self.future: Future = Future()
+        # the submitter's trace (the gateway's, through serve_submit):
+        # the engine thread has none of its own
+        self.ctx = obs_context.current()
+        self.skipped = 0          # prompt tokens a prefix hit spared
+        # stages, on one monotonic clock: submit <= admit <= first <= done
         self.t_submit = time.monotonic()
+        self.t_admit = self.t_first = self.t_done = None
 
 
 @dataclass
@@ -267,6 +326,19 @@ class ContinuousBatcher:
         self._lane_steps = 0          # slot-steps actually dispatched
         self._active_lane_steps = 0   # of those, slots with live requests
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
+        # the tick ledger: engine thread only; closed (and read by
+        # stats()) under _stats_lock.  No switch.
+        self._ledger = StepPhaseLedger(
+            component="engine", phases=TICK_PHASES,
+            histogram=_TICK_PHASE_SECONDS, coverage_gauge=None,
+            idle_phase="idle_wait", overhead_phase="finish")
+        # per-request stages (sum, count): admission, first token, decode
+        self._admitted = 0
+        self._queue_wait_s = 0.0
+        self._first_tokens = 0
+        self._ttft_s = 0.0
+        self._decode_tokens = 0
+        self._decode_s = 0.0
         self._t0 = time.monotonic()
         self._prefill_cache: dict[tuple[int, int], object] = {}
         if mesh is not None:
@@ -568,9 +640,34 @@ class ContinuousBatcher:
                 "prefill_chunk": self._chunk_tokens,
                 "prefill_chunks": self._prefill_chunks,
                 "chunked_admissions": self._chunked_admissions,
+                **self._tick_stats(),
                 **self._kv_stats(),
                 **self._spec_stats(),
             }
+
+    def _tick_stats(self) -> dict:
+        """The tick ledger and the per-request stages, cumulative (a
+        reader differences two calls; ``tick_coverage`` alone is a
+        level, the ledger's EMA).  ``tick_s`` is the wall time of the
+        ticks, ``idle_wait_s`` the time between them: together the
+        engine thread's life.  The ``_sum``/count pairs give means;
+        the histograms carry the tails."""
+        tot = self._ledger.totals()
+        ph = tot["phases"]
+        return {
+            "ticks": tot["steps"],
+            "tick_s": tot["wall_s"],
+            **{f"tick_{p}_s": ph[p] for p in TICK_PHASES
+               if p != "idle_wait"},
+            "idle_wait_s": ph["idle_wait"],
+            "tick_coverage": tot["coverage"] or 0.0,
+            "admitted": self._admitted,
+            "queue_wait_s_sum": self._queue_wait_s,
+            "first_tokens": self._first_tokens,
+            "ttft_s_sum": self._ttft_s,
+            "decode_tokens": self._decode_tokens,
+            "decode_s_sum": self._decode_s,
+        }
 
     def _spec_stats(self) -> dict:
         """Speculative-decode counters (empty when spec is off, so
@@ -1012,11 +1109,15 @@ class ContinuousBatcher:
 
     # -- the loop ------------------------------------------------------------
     def _loop(self) -> None:
+        led = self._ledger
+        t0 = time.perf_counter()
         while True:
             # a mid-chunk admission is live work even with no active
             # slots and an empty queue — never block on the queue then
-            self._drain(block=not self._any_active()
-                        and self._chunking is None)
+            block = not self._any_active() and self._chunking is None
+            waiting = block and not self._pending and not self._tasks
+            with led.phase("idle_wait" if waiting else "admit"):
+                self._drain(block=block)
             if self._stopping:
                 return  # stop() fails active slots + pending
             try:
@@ -1024,6 +1125,12 @@ class ContinuousBatcher:
             except Exception as e:  # noqa: BLE001 — never die silently
                 logger.exception("engine tick failed")
                 self._fail_all(e)
+            # close the tick against the loop's own wall time (the
+            # ledger takes idle_wait out of it): the phases must tile it
+            t1 = time.perf_counter()
+            with self._stats_lock:
+                led.step_done(t1 - t0)
+            t0 = t1
 
     def _drain(self, block: bool) -> None:
         """Pull queued requests into the host-side pending list; blocks
@@ -1052,13 +1159,75 @@ class ContinuousBatcher:
         work per tick stays bounded by the free-slot count, so a burst
         of arrivals can never starve running lanes: they advance
         ``steps_per_sync`` tokens every tick regardless of the queue."""
-        while self._tasks:
-            task = self._tasks.popleft()
-            try:
-                task.future.set_result(task.fn())
-            except BaseException as e:  # noqa: BLE001 — future must resolve
-                task.future.set_exception(e)
-        active = [i for i, s in enumerate(self._slots) if not s.free]
+        led = self._ledger
+        if self._tasks:
+            with led.phase("tasks"):
+                while self._tasks:
+                    task = self._tasks.popleft()
+                    try:
+                        task.future.set_result(task.fn())
+                    except BaseException as e:  # noqa: BLE001 — must resolve
+                        task.future.set_exception(e)
+        with led.phase("admit"):
+            active = [i for i, s in enumerate(self._slots) if not s.free]
+            pres = self._admit(active)
+        # everything from here to the sync can raise with the prefill
+        # group already popped from _pending but not yet in slots —
+        # _fail_all (our caller's handler) only covers slot-resident
+        # requests, so fail the admitted futures before re-raising
+        try:
+            dec = None
+            counts = None
+            with led.phase("dispatch"):
+                if active:
+                    if self._spec_k:
+                        (self._cache, self._draft_cache, dec,
+                         counts) = self._spec_jit(
+                            self._cache, self._draft_cache,
+                            jnp.asarray(self._toks), self._params,
+                            self._draft_params)
+                    else:
+                        self._rng, key = jax.random.split(self._rng)
+                        self._cache, dec = self._step_jit(
+                            self._cache, jnp.asarray(self._toks), key,
+                            self._params)
+                for slab, _, _, slots, _, lens, dslab in pres:
+                    self._cache = self._insert_jit(
+                        self._cache, slab, jnp.asarray(slots, jnp.int32),
+                        jnp.asarray(lens, jnp.int32))
+                    if dslab is not None:
+                        self._draft_cache = self._draft_insert_jit(
+                            self._draft_cache, dslab,
+                            jnp.asarray(slots, jnp.int32),
+                            jnp.asarray(lens, jnp.int32))
+            # single sync point for decode + every admission
+            with led.phase("sync"):
+                dec_np = np.asarray(dec) if dec is not None else None
+                counts_np = (np.asarray(counts) if counts is not None
+                             else None)
+                fins = [(p[3], p[4], np.asarray(p[1]),
+                         int(np.asarray(p[2]))) for p in pres]
+        except Exception as e:  # noqa: BLE001
+            for p in pres:
+                for req in p[4]:
+                    req.future.set_exception(e)
+            with self._stats_lock:
+                self._failed_requests += sum(len(p[4]) for p in pres)
+            raise
+        with led.phase("finish"):
+            if dec_np is not None:
+                if counts_np is not None:
+                    self._finish_spec(dec_np, counts_np, len(active))
+                else:
+                    self._finish_decode(dec_np, len(active))
+            for slots, reqs, ptoks_np, drops in fins:
+                self._finish_prefill(slots, reqs, ptoks_np, drops)
+
+    def _admit(self, active: list[int]) -> list[tuple]:
+        """This tick's admissions, dispatched and not synced: every
+        consecutive prefix hit at the queue front, then one chunk of
+        the chunked admission in flight or else one cold group.
+        Returns the in-flight tuples the tick inserts and finishes."""
         pres: list[tuple] = []
         t0 = time.monotonic()
         taken: set[int] = set()       # slots claimed by THIS tick's admissions
@@ -1095,53 +1264,7 @@ class ContinuousBatcher:
         if pres and active:
             with self._stats_lock:
                 self._prefill_stall_s += time.monotonic() - t0
-        # everything from here to the sync can raise with the prefill
-        # group already popped from _pending but not yet in slots —
-        # _fail_all (our caller's handler) only covers slot-resident
-        # requests, so fail the admitted futures before re-raising
-        try:
-            dec = None
-            counts = None
-            if active:
-                if self._spec_k:
-                    (self._cache, self._draft_cache, dec,
-                     counts) = self._spec_jit(
-                        self._cache, self._draft_cache,
-                        jnp.asarray(self._toks), self._params,
-                        self._draft_params)
-                else:
-                    self._rng, key = jax.random.split(self._rng)
-                    self._cache, dec = self._step_jit(
-                        self._cache, jnp.asarray(self._toks), key,
-                        self._params)
-            for slab, _, _, slots, _, lens, dslab in pres:
-                self._cache = self._insert_jit(
-                    self._cache, slab, jnp.asarray(slots, jnp.int32),
-                    jnp.asarray(lens, jnp.int32))
-                if dslab is not None:
-                    self._draft_cache = self._draft_insert_jit(
-                        self._draft_cache, dslab,
-                        jnp.asarray(slots, jnp.int32),
-                        jnp.asarray(lens, jnp.int32))
-            # single sync point for decode + every admission
-            dec_np = np.asarray(dec) if dec is not None else None
-            counts_np = np.asarray(counts) if counts is not None else None
-            fins = [(p[3], p[4], np.asarray(p[1]), int(np.asarray(p[2])))
-                    for p in pres]
-        except Exception as e:  # noqa: BLE001
-            for p in pres:
-                for req in p[4]:
-                    req.future.set_exception(e)
-            with self._stats_lock:
-                self._failed_requests += sum(len(p[4]) for p in pres)
-            raise
-        if dec_np is not None:
-            if counts_np is not None:
-                self._finish_spec(dec_np, counts_np, len(active))
-            else:
-                self._finish_decode(dec_np, len(active))
-        for slots, reqs, ptoks_np, drops in fins:
-            self._finish_prefill(slots, reqs, ptoks_np, drops)
+        return pres
 
     def _fail_all(self, e: Exception) -> None:
         n = 0
@@ -1189,7 +1312,20 @@ class ContinuousBatcher:
         for req in reversed(reqs[K:]):                 # overflow back, FIFO
             self._pending.appendleft(req)
         reqs = reqs[:K]
+        self._stamp_admit(reqs)
         return P, free[:K], reqs
+
+    def _stamp_admit(self, reqs: list[_Request]) -> None:
+        """The requests left ``_pending`` for an admission (cold group,
+        prefix reuse or chunked): their queue wait ends here."""
+        now = time.monotonic()
+        waits = [now - r.t_submit for r in reqs]
+        for req, wait in zip(reqs, waits):
+            req.t_admit = now
+            _QUEUE_WAIT_SECONDS.observe(wait)
+        with self._stats_lock:
+            self._admitted += len(reqs)
+            self._queue_wait_s += sum(waits)
 
     def _dispatch_prefill(self, P: int, slots: list[int],
                           reqs: list[_Request]):
@@ -1250,6 +1386,7 @@ class ContinuousBatcher:
         if slot is None:
             return
         req = self._pending.popleft()
+        self._stamp_admit([req])
         if self._kv is not None:
             # one admission, counted once at start (the reuse matcher
             # already passed on it — this is the cold long-prompt path)
@@ -1395,7 +1532,9 @@ class ContinuousBatcher:
             chain.pop()
         if not chain:
             return None
-        return free, self._pending.popleft(), chain
+        req = self._pending.popleft()
+        self._stamp_admit([req])
+        return free, req, chain
 
     def _dispatch_reuse(self, slot: int, req: "_Request", chain: list):
         """Dispatch one prefix-hit admission: gather the chain's blocks
@@ -1411,6 +1550,7 @@ class ContinuousBatcher:
         self._kv_hits += 1
         self._prefill_tokens += len(req.ids)
         self._prefill_tokens_skipped += prefix_len
+        req.skipped = prefix_len
         try:
             ids = np.zeros((1, P), np.int32)
             ids[0, :len(suffix)] = suffix
@@ -1488,9 +1628,15 @@ class ContinuousBatcher:
 
     def _finish_prefill(self, slots: list[int], reqs: list[_Request],
                         toks: np.ndarray, drops: int) -> None:
-        if drops:
-            with self._stats_lock:
-                self._moe_drops += drops
+        now = time.monotonic()
+        ttfts = [now - r.t_submit for r in reqs]
+        for req, ttft in zip(reqs, ttfts):
+            req.t_first = now         # its first token is on the host
+            _TTFT_SECONDS.observe(ttft)
+        with self._stats_lock:
+            self._moe_drops += drops
+            self._first_tokens += len(reqs)
+            self._ttft_s += sum(ttfts)
         for slot, req, tok in zip(slots, reqs, toks.tolist()):
             s = self._slots[slot]
             s.request = req
@@ -1531,16 +1677,51 @@ class ContinuousBatcher:
             out = out[:s.emitted.index(self._eos) + 1]
         if self._kv is not None:
             try:
-                self._kv_commit(slot, req, s.emitted)
+                with self._ledger.phase("kv_commit"):
+                    self._kv_commit(slot, req, s.emitted)
             except Exception:  # noqa: BLE001 — the cache is an accelerator
                 logger.exception("kv commit failed for slot %d (request "
                                  "unaffected)", slot)
+        req.t_done = time.monotonic()
+        n_out = len(out)
+        # a one-token answer has no gap between tokens
+        decode_s = req.t_done - req.t_first if n_out > 1 else 0.0
+        if n_out > 1:
+            _INTERTOKEN_SECONDS.observe(decode_s / (n_out - 1))
         with self._stats_lock:
             self._done_requests += 1
-            self._emitted_tokens += len(out)
+            self._emitted_tokens += n_out
+            self._decode_tokens += n_out - 1
+            self._decode_s += decode_s
         s.request = None
         s.emitted = []
+        if obs_trace.active():
+            self._emit_request(req, n_out)
         req.future.set_result(out)
+
+    @staticmethod
+    def _emit_request(req: "_Request", n_out: int) -> None:
+        """One ``engine/request`` event per finished request, pinned
+        under the submitter's span (``ReplicaServer.serve_submit`` runs
+        in the gateway's trace), so a merged timeline reads
+        gateway/request > gateway/route > serving/submit >
+        engine/request > serving/complete."""
+        ids = {}
+        if req.ctx is not None:
+            child = req.ctx.child()
+            ids = {"trace_id": child.trace_id, "span_id": child.span_id,
+                   "parent_id": child.parent_id}
+        dur = req.t_done - req.t_submit
+        obs_trace.emit(
+            "engine/request", dur=dur,
+            # edl-lint: disable=clock — back-dating a TRACE ts to the
+            # span begin (merge convention: ts is begin)
+            at=time.time() - dur,
+            queue_wait=round(req.t_admit - req.t_submit, 6),
+            prefill=round(req.t_first - req.t_admit, 6),
+            decode=round(req.t_done - req.t_first, 6),
+            n_prompt=len(req.ids), n_out=n_out,
+            prefix_tokens_skipped=req.skipped, **ids)
 
     def _kv_commit(self, slot: int, req: "_Request",
                    emitted: list[int]) -> None:

@@ -202,10 +202,11 @@ class ElasticTrainer:
         # the mesh-free skeleton a live reshard rebuilds against
         self._reshard_seen = False
         self._state_spec = None
-        # per-step phase ledger (EDL_TPU_STEP_LEDGER) + the on-demand
-        # profiler capture it backs on CPU; /profile rides the same
-        # endpoint the process already advertises for /metrics
-        self._ledger = obs_ledger.StepPhaseLedger(component="trainer")
+        # per-step phase ledger (its phases are the train/<phase> spans
+        # of a profiler capture) + the on-demand capture it backs on
+        # CPU; /profile rides the same endpoint the process already
+        # advertises for /metrics
+        self._ledger = obs_ledger.StepPhaseLedger(component="train")
         self._profiler = obs_profile.ProfileCapture("trainer",
                                                     ledger=self._ledger)
         obs_profile.install_route(self._profiler)
@@ -727,9 +728,8 @@ class ElasticTrainer:
         if not self.cfg.prefetch_batches:
             for batch in batches:
                 batch, spans = split(batch)
-                t0 = time.perf_counter()
-                g = shard_host_batch(batch, self.mesh, self.rules)
-                ledger.add("h2d", time.perf_counter() - t0)
+                with ledger.phase("h2d"):
+                    g = shard_host_batch(batch, self.mesh, self.rules)
                 yield g, spans
             return
         from concurrent.futures import ThreadPoolExecutor
@@ -737,11 +737,9 @@ class ElasticTrainer:
         def staged(fut):
             # the wait for the staging thread IS the unhidden host->
             # device time; it runs inside the consumer's data_wait
-            # phase and credits itself out of it
-            t0 = time.perf_counter()
-            g = fut.result()
-            ledger.add("h2d", time.perf_counter() - t0)
-            return g
+            # phase and is deducted from it
+            with ledger.phase("h2d"):
+                return fut.result()
 
         with ThreadPoolExecutor(1) as pool:
             fut = None
@@ -847,9 +845,9 @@ class ElasticTrainer:
         delta path is armed — phase ledger and goodput still run; the
         bench artifact still reports MFU for the model.  The result
         lands only if the step function is still the one it was
-        computed for.  Gated with the ledger so EDL_TPU_STEP_LEDGER=0
-        disables every continuous-profiling surface at once; 0.0 =
-        pending-or-unanswerable, so there is no per-step retry."""
+        computed for.  Gated with the ledger (a test's
+        ``enabled=False``); 0.0 = pending-or-unanswerable, so there is
+        no per-step retry."""
         self._flops_per_step = 0.0
         if not self._ledger.enabled or self._delta_ready():
             return

@@ -97,13 +97,6 @@ def test_ledger_reset_discards_unobserved_phases():
     assert _phase_sum("compute") - before == pytest.approx(0.01)
 
 
-def test_ledger_env_knob(monkeypatch):
-    monkeypatch.setenv("EDL_TPU_STEP_LEDGER", "0")
-    assert StepPhaseLedger().enabled is False
-    monkeypatch.delenv("EDL_TPU_STEP_LEDGER")
-    assert StepPhaseLedger().enabled is True
-
-
 def test_ledger_capture_emits_per_step_events(tmp_path):
     path = str(tmp_path / "trace-test.jsonl")
     prev = obs_trace.install(obs_trace.Tracer(path, "test"))
@@ -144,6 +137,98 @@ def test_ledger_flush_aggregates(tmp_path):
     # Perfetto counter track stays scale-comparable); dur is the total
     assert phases[0]["counters"]["compute"] == pytest.approx(0.01)
     assert phases[0]["dur"] == pytest.approx(0.08)
+
+
+# -- the generalised ledger: other phases, cumulative totals, annotations ----
+
+def _tick_ledger():
+    from edl_tpu.obs import metrics as obs_metrics
+    hist = obs_metrics.histogram("test_tick_phase_seconds", "test",
+                                 ("phase",))
+    return StepPhaseLedger(component="tick", phases=("wait", "work", "io"),
+                           histogram=hist, coverage_gauge=None,
+                           idle_phase="wait", overhead_phase="io"), hist
+
+
+def test_ledger_parametrised_phases_and_cumulative_totals():
+    """Another loop's phases go to ITS histogram, never the trainer's,
+    and totals() returns everything since construction in one call."""
+    led, hist = _tick_ledger()
+    before = {p: _phase_sum(p) for p in obs_ledger.PHASES}
+    n0 = hist.labels(phase="work").count
+    for _ in range(3):
+        led.add("work", 0.2)
+        with led.phase("work"):
+            led.add("io", 0.05)       # nested credit, deducted
+        led.step_done(0.3)
+    tot = led.totals()
+    assert tot["steps"] == 3 and tot["wall_s"] == pytest.approx(0.9)
+    assert set(tot["phases"]) == {"wait", "work", "io"}
+    assert tot["phases"]["work"] == pytest.approx(0.6, abs=0.01)
+    assert tot["phases"]["io"] >= 0.15   # + the ledger's own close-out
+    assert hist.labels(phase="work").count - n0 == 3
+    assert {p: _phase_sum(p) for p in obs_ledger.PHASES} == before
+    assert led.coverage == pytest.approx(0.25 / 0.3, abs=0.02)
+
+
+def test_ledger_idle_phase_is_outside_the_step():
+    """Time between steps (the engine waiting for requests) is totalled
+    and observed, but is neither step wall time nor covered time."""
+    led, _ = _tick_ledger()
+    led.add("wait", 10.0)
+    led.add("work", 0.09)
+    led.step_done(10.1)               # the loop's wall, the wait in it
+    tot = led.totals()
+    assert tot["wall_s"] == pytest.approx(0.1)
+    assert tot["phases"]["wait"] == pytest.approx(10.0)
+    assert tot["coverage"] == pytest.approx(0.9)
+
+
+def test_ledger_phase_enters_component_annotation(monkeypatch):
+    """The trainer's phases are train/<phase> spans in any capture."""
+    seen = []
+    real = obs_trace.annotation
+    monkeypatch.setattr(obs_trace, "annotation",
+                        lambda name: (seen.append(name), real(name))[1])
+    led = StepPhaseLedger()
+    assert led.enabled is True        # no switch in the environment
+    for p in obs_ledger.PHASES:
+        with led.phase(p):
+            pass
+    assert seen == [f"train/{p}" for p in obs_ledger.PHASES]
+    seen.clear()
+    with StepPhaseLedger(enabled=False).phase("compute"):
+        pass
+    assert seen == []
+
+
+def test_annotation_is_a_traceme_with_jax():
+    import jax
+    with obs_trace.annotation("test/span") as a:
+        assert isinstance(a, jax.profiler.TraceAnnotation)
+
+
+def test_annotation_without_jax_is_a_noop_and_imports_nothing():
+    """Coord server, launcher parent, load generator: a ledger phase
+    there must not pull JAX into the process."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "from edl_tpu.obs import trace\n"
+        "from edl_tpu.obs.ledger import StepPhaseLedger\n"
+        "led = StepPhaseLedger()\n"
+        "with led.phase('compute'):\n"
+        "    with trace.annotation('x/y') as a:\n"
+        "        assert a is None, a\n"
+        "led.step_done(0.001)\n"
+        "assert led.totals()['steps'] == 1\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "print('ok')\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
 
 
 # -- obs/flops.py ------------------------------------------------------------

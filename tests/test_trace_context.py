@@ -250,3 +250,70 @@ def test_concurrent_handlers_never_cross_contexts(make_server):
     for t in threads:
         t.join()
     assert not errors, errors
+
+
+# -- the engine's request event joins the gateway's trace --------------------
+
+def test_engine_request_carries_gateway_trace_through_replica(memkv, tmp_path):
+    """gateway/request > gateway/route > serving/submit > engine/request
+    > serving/complete: the engine thread has no ambient context, so
+    ``submit()`` captures the RPC handler's and ``_finish`` pins it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from edl_tpu.models import TransformerConfig, TransformerLM
+    from edl_tpu.serving import ContinuousBatcher
+    from edl_tpu.serving.replica import ReplicaServer
+
+    cfg = TransformerConfig(vocab_size=53, num_layers=1, embed_dim=32,
+                            num_heads=2, mlp_dim=64, max_len=64,
+                            remat=False, dtype=jnp.float32)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = ContinuousBatcher(cfg, params, slots=2, temperature=0.0,
+                            prefill_buckets=(8,), steps_per_sync=2)
+    srv = ReplicaServer(memkv, "job", eng, replica_id="r0",
+                        host="127.0.0.1", ttl=5, advert_period=0.2)
+    tr = obs_trace.configure(str(tmp_path / "replica.jsonl"), "replica")
+    gateway = obs_context.new_trace()       # what gate_generate stamps
+    try:
+        with RpcClient(srv.endpoint, 120) as client:
+            with obs_context.use(gateway):
+                client.call("serve_submit", request_id="q1",
+                            prompt=[3, 1, 4, 1, 5], max_new=4)
+                deadline = time.monotonic() + 120
+                while not client.call("serve_wait", request_id="q1",
+                                      timeout=5.0)["done"]:
+                    assert time.monotonic() < deadline
+            # a request from nobody's trace stays outside it
+            client.call("serve_submit", request_id="q2", prompt=[2, 7],
+                        max_new=2)
+            while not client.call("serve_wait", request_id="q2",
+                                  timeout=5.0)["done"]:
+                assert time.monotonic() < deadline
+    finally:
+        obs_trace.install(obs_trace.NullTracer())
+        tr.close()
+        srv.close()
+        eng.stop()
+    events = _read_events(tmp_path / "replica.jsonl")
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    submit = by_name["serving/submit"][0]
+    traced, plain = by_name["engine/request"]
+    assert submit["trace_id"] == gateway.trace_id
+    assert traced["trace_id"] == gateway.trace_id
+    assert traced["parent_id"] == submit["span_id"]   # child of the submit
+    assert traced["span_id"] not in (submit["span_id"], gateway.span_id)
+    assert traced["n_prompt"] == 5 and traced["n_out"] == 4
+    assert "trace_id" not in plain and plain["n_prompt"] == 2
+    # in the file the engine's event lies between submit and complete
+    names = [e["name"] for e in events if e.get("request") == "q1"
+             or e is traced]
+    assert names == ["serving/submit", "engine/request", "serving/complete"]
+    from edl_tpu.obs import dump as obs_dump
+    tl = obs_dump.merge_timeline(events, gateway.trace_id)
+    assert [e["name"] for e in tl if e["name"].startswith(
+        ("serving/", "engine/"))].count("engine/request") == 1
