@@ -124,6 +124,11 @@ _INTERTOKEN_SECONDS = obs_metrics.histogram(
     "(done - first token) / (tokens - 1)", buckets=_FINE_BUCKETS)
 
 
+def _zeros_of(shapes):
+    """Zeros for a tree of ``ShapeDtypeStruct``s, traced or eager."""
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
+
 @dataclass
 class _Slot:
     request: "_Request | None" = None
@@ -162,8 +167,8 @@ class _ChunkState:
 
     req: "_Request"
     slot: int
-    slab: object          # one-lane decode cache, index == offset
     offset: int           # prompt tokens already prefilled
+    slab: object          # one-lane decode cache, index == offset
     drops: object         # device MoE-drop accumulator (traced through)
 
 
@@ -272,6 +277,10 @@ class ContinuousBatcher:
         chunk = (constants.PREFILL_CHUNK if prefill_chunk is None
                  else prefill_chunk)
         self._chunk_tokens = max(0, int(chunk))
+        # the cache's layout is fixed here: shape trees per lane count
+        # (_cache_shapes) and every compiled program keyed by its shapes
+        self._shape_memo: dict[tuple[str, int], object] = {}
+        self._prefill_cache: dict[tuple, object] = {}
         self._require_fit(slots, kv_block, pool_blocks)
         self._cache = self._fresh_cache(slots)
         self._toks = np.zeros((slots,), np.int32)   # last token per slot
@@ -340,13 +349,12 @@ class ContinuousBatcher:
         self._decode_tokens = 0
         self._decode_s = 0.0
         self._t0 = time.monotonic()
-        self._prefill_cache: dict[tuple[int, int], object] = {}
         if mesh is not None:
             # pin the pool cache's sharding on every step/insert output
             # so the layout is stable from step 1 (inference-only
             # propagation would re-specialise the jit once per layout
             # change and thrash the donation)
-            sh = self._pool_cache_shardings()
+            sh = self._cache_shardings(slots)
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(mesh, PartitionSpec())
             self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,),
@@ -407,7 +415,7 @@ class ContinuousBatcher:
                 rep = NamedSharding(mesh, PartitionSpec())
                 dsh = jax.tree.map(lambda _: rep,
                                    self._draft_cache_shapes(slots))
-                sh = self._pool_cache_shardings()
+                sh = self._cache_shardings(slots)
                 self._spec_jit = jax.jit(
                     self._spec_impl, donate_argnums=(0, 1),
                     out_shardings=(sh, dsh, rep, rep))
@@ -550,18 +558,20 @@ class ContinuousBatcher:
             jax.block_until_ready(toks)
         self._step_jit.lower(self._cache, jnp.asarray(self._toks), key,
                              self._params).compile()
-        if self._chunk_tokens and prompt_len > self._chunk_tokens:
+        if self._chunk_tokens:
+            # the program every chunked admission starts with, whatever
+            # prompt class this call warms
+            slab, drops = jax.block_until_ready(self._chunk_start())
             # chunk ladder: the mid-chunk body plus the final suffix
             # bucket this prompt class lands on (same fit guard as
             # _maybe_start_chunk — an unfittable split falls back to
             # the monolithic prefill warmed above)
             C = self._chunk_tokens
             off = C * ((prompt_len - 1) // C)
-            if off + self._bucket(prompt_len - off) <= self._dcfg.max_len:
-                slab = self._fresh_cache(1)
+            if (prompt_len > C and off + self._bucket(prompt_len - off)
+                    <= self._dcfg.max_len):
                 slab, drops = self._chunk_mid_fn(C)(
-                    self._params, slab, jnp.zeros((1, C), jnp.int32),
-                    jnp.zeros((), jnp.int32))
+                    self._params, slab, jnp.zeros((1, C), jnp.int32), drops)
                 Pf = self._bucket(prompt_len - off)
                 slab, toks, _ = self._chunk_final_fn(Pf)(
                     self._params, slab, jnp.zeros((1, Pf), jnp.int32),
@@ -805,18 +815,38 @@ class ContinuousBatcher:
                 f"set --kv_pool_blocks")
 
     def _cache_shapes(self, B: int):
-        return jax.eval_shape(
-            lambda: self._model.init(
-                jax.random.key(0), jnp.zeros((B, 1), jnp.int32),
-                positions=jnp.zeros((B, 1), jnp.int32)))["cache"]
+        """Shape tree of a ``B``-lane decode cache.  A pure function of
+        the decode configuration and ``B``, so ``model.init`` is traced
+        once per ``B`` (the constructor asks for 1 and ``slots``) and
+        never again: re-tracing it cost a chunked admission 250 ms of
+        host Python with the device idle (PERF.md, PR 25)."""
+        return self._memo_shapes("target", self._model, B)
+
+    def _memo_shapes(self, which: str, model, B: int):
+        shapes = self._shape_memo.get((which, B))
+        if shapes is None:
+            shapes = self._shape_memo[(which, B)] = jax.eval_shape(
+                lambda: model.init(
+                    jax.random.key(0), jnp.zeros((B, 1), jnp.int32),
+                    positions=jnp.zeros((B, 1), jnp.int32)))["cache"]
+        return shapes
+
+    def _zeros(self, key: tuple, shapes, shardings):
+        """A fresh zeroed cache from ONE compiled program per ``key``,
+        born with its sharding.  The program is kept, never the array:
+        the chunk programs donate their slab."""
+        fn = self._prefill_cache.get(key)
+        if fn is None:
+            def zeros():
+                return _zeros_of(shapes)
+
+            fn = self._prefill_cache[key] = jax.jit(
+                zeros, out_shardings=shardings)
+        return fn()
 
     def _fresh_cache(self, B: int):
-        shapes = self._cache_shapes(B)
-        zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-        if self._mesh is not None:
-            zeros = jax.device_put(
-                zeros, jax.tree.map(self._leaf_sharding, shapes))
-        return zeros
+        return self._zeros(("zeros", B), self._cache_shapes(B),
+                           self._cache_shardings(B))
 
     def _leaf_sharding(self, s):
         """KV buffers shard over ``tp`` on the kv-head axis (axis 1 of
@@ -829,9 +859,12 @@ class ContinuousBatcher:
             return NamedSharding(self._mesh, P(None, "tp"))
         return NamedSharding(self._mesh, P())
 
-    def _pool_cache_shardings(self):
-        return jax.tree.map(self._leaf_sharding,
-                            self._cache_shapes(len(self._slots)))
+    def _cache_shardings(self, B: int):
+        """``_leaf_sharding`` over a ``B``-lane cache; None off a mesh
+        (``jax.jit``'s "unspecified")."""
+        if self._mesh is None:
+            return None
+        return jax.tree.map(self._leaf_sharding, self._cache_shapes(B))
 
     # -- jitted pieces -------------------------------------------------------
     def _sample(self, logits, key):
@@ -851,12 +884,7 @@ class ContinuousBatcher:
 
         def prefill(params, ids, true_lens, key):
             from edl_tpu.models.generate import _sum_drops
-            cache = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, s.dtype),
-                jax.eval_shape(
-                    lambda: model.init(
-                        jax.random.key(0), jnp.zeros((K, 1), jnp.int32),
-                        positions=jnp.zeros((K, 1), jnp.int32)))["cache"])
+            cache = _zeros_of(self._cache_shapes(K))
             # pad positions are masked out of MoE routing (they must
             # not claim expert capacity ahead of real tokens' choices;
             # with ample capacity the padded prefill matches generate()
@@ -928,20 +956,16 @@ class ContinuousBatcher:
 
     # -- speculative decoding ------------------------------------------------
     def _draft_cache_shapes(self, B: int):
-        return jax.eval_shape(
-            lambda: self._draft_model.init(
-                jax.random.key(0), jnp.zeros((B, 1), jnp.int32),
-                positions=jnp.zeros((B, 1), jnp.int32)))["cache"]
+        return self._memo_shapes("draft", self._draft_model, B)
 
     def _draft_fresh_cache(self, B: int):
         shapes = self._draft_cache_shapes(B)
-        zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+        sh = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(self._mesh, PartitionSpec())
-            zeros = jax.device_put(zeros,
-                                   jax.tree.map(lambda _: rep, shapes))
-        return zeros
+            sh = jax.tree.map(lambda _: rep, shapes)
+        return self._zeros(("draft_zeros", B), shapes, sh)
 
     def _draft_prefill_fn(self, P: int, K: int):
         """Compiled per (bucket, sub-batch): the draft's prompt prefill
@@ -954,12 +978,7 @@ class ContinuousBatcher:
         draft = self._draft_model
 
         def dpre(params, ids, true_lens):
-            cache = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, s.dtype),
-                jax.eval_shape(
-                    lambda: draft.init(
-                        jax.random.key(0), jnp.zeros((K, 1), jnp.int32),
-                        positions=jnp.zeros((K, 1), jnp.int32)))["cache"])
+            cache = _zeros_of(self._draft_cache_shapes(K))
             _, mut = draft.apply(
                 {"params": params, "cache": cache}, ids,
                 positions=jnp.broadcast_to(jnp.arange(ids.shape[1]),
@@ -1392,10 +1411,25 @@ class ContinuousBatcher:
             # already passed on it — this is the cold long-prompt path)
             self._kv_misses += 1
             self._prefill_tokens += len(req.ids)
-        self._chunking = _ChunkState(req, slot, self._fresh_cache(1), 0,
-                                     jnp.zeros((), jnp.int32))
+        self._chunking = _ChunkState(req, slot, 0, *self._chunk_start())
         with self._stats_lock:
             self._chunked_admissions += 1
+
+    def _chunk_start(self):
+        """``(slab, drops)`` a chunked admission starts from: a zeroed
+        one-lane cache and its MoE-drop accumulator, out of one compiled
+        program — nothing traced, one dispatch.  On a mesh the
+        accumulator is born where the chunk programs return it (after
+        a host-made scalar, the first admission's second chunk re-traced
+        and re-compiled ``mid`` in the middle of traffic)."""
+        sh = None
+        if self._mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            sh = (self._cache_shardings(1),
+                  NamedSharding(self._mesh, PartitionSpec()))
+        return self._zeros(
+            ("chunk_start",),
+            (self._cache_shapes(1), jax.ShapeDtypeStruct((), jnp.int32)), sh)
 
     def _advance_chunk(self):
         """Dispatch ONE chunk of the in-flight chunked admission (no
@@ -1460,7 +1494,7 @@ class ContinuousBatcher:
 
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
-            sh = jax.tree.map(self._leaf_sharding, self._cache_shapes(1))
+            sh = self._cache_shardings(1)
             rep = NamedSharding(self._mesh, PartitionSpec())
             fn = jax.jit(mid, donate_argnums=(1,), out_shardings=(sh, rep))
         else:
@@ -1492,7 +1526,7 @@ class ContinuousBatcher:
 
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
-            sh = jax.tree.map(self._leaf_sharding, self._cache_shapes(1))
+            sh = self._cache_shardings(1)
             rep = NamedSharding(self._mesh, PartitionSpec())
             fn = jax.jit(fin, donate_argnums=(1,),
                          out_shardings=(sh, rep, rep))
@@ -1603,12 +1637,7 @@ class ContinuousBatcher:
         def prefill(params, pool, ids, block_ids, prefix_len, true_lens,
                     key):
             from edl_tpu.models.generate import _sum_drops
-            cache = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, s.dtype),
-                jax.eval_shape(
-                    lambda: model.init(
-                        jax.random.key(0), jnp.zeros((1, 1), jnp.int32),
-                        positions=jnp.zeros((1, 1), jnp.int32)))["cache"])
+            cache = _zeros_of(self._cache_shapes(1))
             cache = kv.load_prefix_into(cache, pool, block_ids, n_pad,
                                         prefix_len)
             logits, mut = model.apply(

@@ -200,9 +200,13 @@ class PagedKVCache:
 
     # -- host index ----------------------------------------------------------
     def _chunks(self, tokens, n: int):
+        """The first ``n`` blocks of ``tokens`` as trie keys, one at a
+        time: a walk that stops at its first miss has paid for one key,
+        not for the whole prompt (2 ms of host Python for 6k tokens, on
+        every tick a cold document waits at the queue's front)."""
         bs = self.block
-        return [tuple(int(t) for t in tokens[i * bs:(i + 1) * bs])
-                for i in range(n)]
+        return (tuple(int(t) for t in tokens[i * bs:(i + 1) * bs])
+                for i in range(n))
 
     def match(self, tokens) -> list[_Node]:
         """Longest committed chain covering full-block prefixes of
@@ -230,7 +234,7 @@ class PagedKVCache:
         the commit (counted in ``commit_skips``) rather than failing."""
         n_full = len(tokens) // self.block
         node = self._root
-        chunks = self._chunks(tokens, n_full)
+        chunks = list(self._chunks(tokens, n_full))
         i = 0
         while i < n_full:
             child = node.children.get(chunks[i])

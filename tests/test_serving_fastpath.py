@@ -172,6 +172,97 @@ def test_chunked_prefill_does_not_starve_decode(small):
     assert stats["prefill_stall_s"] >= 0.0
 
 
+@pytest.fixture(params=["one_chip", "tp2"])
+def mesh_or_none(request):
+    return (request.getfixturevalue("tp2") if request.param == "tp2"
+            else None)
+
+
+def test_chunked_admission_traces_no_model_init(small, mesh_or_none,
+                                                monkeypatch):
+    """ISSUE 25: after construction and warm(), starting a chunked
+    admission traces nothing — the cache's shape tree is kept from
+    construction and the one-lane slab comes from one compiled zeros
+    program.  Three admissions back to back each get their OWN buffer
+    (the chunk programs donate the slab) and answer as generate()."""
+    cfg, params = small
+    rng = np.random.default_rng(25)
+    prompts = [rng.integers(1, 97, (n,)).astype(np.int32)
+               for n in (40, 23, 33)]
+    eng = _engine(cfg, params, prefill_chunk=8, prefill_buckets=(8,),
+                  mesh=mesh_or_none)
+    try:
+        eng.warm(40)
+        traced = {"init": 0, "eval_shape": 0}
+        init, eval_shape = type(eng._model).init, jax.eval_shape
+
+        def counting_init(self, *a, **kw):
+            traced["init"] += 1
+            return init(self, *a, **kw)
+
+        def counting_eval_shape(*a, **kw):
+            traced["eval_shape"] += 1
+            return eval_shape(*a, **kw)
+
+        monkeypatch.setattr(type(eng._model), "init", counting_init)
+        monkeypatch.setattr(jax, "eval_shape", counting_eval_shape)
+        futs = [eng.submit(p, 5) for p in prompts]
+        outs = [f.result(120) for f in futs]
+        st = eng.stats()
+    finally:
+        eng.stop()
+    # eval_shape too: flax calls it for every parameter of a model it
+    # traces, so 0 says no program was (re-)traced in traffic at all
+    assert traced == {"init": 0, "eval_shape": 0}, traced
+    assert st["chunked_admissions"] == 3, st
+    for p, out in zip(prompts, outs):
+        np.testing.assert_array_equal(out, _want(cfg, params, p, 5))
+
+
+@pytest.mark.parametrize("which", ["target", "draft"])
+def test_fresh_cache_matches_model_init(small, mesh_or_none, which):
+    """The slab builder's output against the definition it memoises:
+    leaf for leaf the shapes and dtypes of ``model.init``'s cache, all
+    zeros, placed as ``_leaf_sharding`` says (target) or replicated
+    (draft) on the mesh — and a new buffer on every call."""
+    cfg, params = small
+    dcfg = TransformerConfig(vocab_size=97, num_layers=1, embed_dim=16,
+                             num_heads=2, mlp_dim=32, max_len=64,
+                             remat=False, dtype=jnp.float32)
+    dparams = TransformerLM(dcfg).init(
+        jax.random.key(1), jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = _engine(cfg, params, prefill_chunk=8, mesh=mesh_or_none,
+                  spec_k=2, draft_cfg=dcfg, draft_params=dparams)
+    try:
+        model, fresh = ((eng._model, eng._fresh_cache) if which == "target"
+                        else (eng._draft_model, eng._draft_fresh_cache))
+        if which == "target":       # what _maybe_start_chunk calls
+            slab, drops = eng._chunk_start()
+            assert drops.shape == () and int(drops) == 0
+            assert (jax.tree.map(lambda x: (x.shape, x.dtype, x.sharding),
+                                 slab)
+                    == jax.tree.map(lambda x: (x.shape, x.dtype, x.sharding),
+                                    fresh(1)))
+        for B in (1, 3):
+            want = jax.eval_shape(lambda: model.init(
+                jax.random.key(0), jnp.zeros((B, 1), jnp.int32),
+                positions=jnp.zeros((B, 1), jnp.int32)))["cache"]
+            got, again = fresh(B), fresh(B)
+            assert jax.tree.structure(got) == jax.tree.structure(want)
+            for w, g, g2 in zip(*map(jax.tree.leaves, (want, got, again))):
+                assert (g.shape, g.dtype) == (w.shape, w.dtype)
+                assert not np.asarray(g).any()
+                assert g is not g2
+                if mesh_or_none is not None:
+                    from jax.sharding import NamedSharding, PartitionSpec
+                    rule = (eng._leaf_sharding(w) if which == "target" else
+                            NamedSharding(mesh_or_none, PartitionSpec()))
+                    assert g.sharding.is_equivalent_to(rule, g.ndim), (
+                        g.sharding, rule)
+    finally:
+        eng.stop()
+
+
 # -- speculative decoding -------------------------------------------------
 
 

@@ -214,6 +214,37 @@ def test_export_import_roundtrip_resumes_warm(small):
         eng_b.stop()
 
 
+def test_match_pays_for_one_key_at_a_miss(small):
+    """The engine re-matches the queue's front request on every tick it
+    cannot admit it, so a walk of the trie builds its keys one at a
+    time: a cold prompt costs one block's key whatever its length, a
+    hit costs its chain plus the block that ends it."""
+    cfg, params = small
+
+    class Counting:
+        def __init__(self, ids):
+            self.ids, self.slices = ids, 0
+
+        def __len__(self):
+            return len(self.ids)
+
+        def __getitem__(self, k):
+            self.slices += 1
+            return self.ids[k]
+
+    rng = np.random.default_rng(3)
+    warm = rng.integers(1, 97, (21,)).astype(np.int32)
+    eng = _engine(cfg, params, slots=2)
+    try:
+        eng.generate(warm, 3, timeout=120)          # commits 5 blocks of 4
+        cold = Counting(rng.integers(1, 97, (60,)).astype(np.int32))
+        assert eng._kv.match(cold) == [] and cold.slices == 1
+        hit = Counting(np.concatenate([warm[:12], cold.ids[:30]]))
+        assert len(eng._kv.match(hit)) == 3 and hit.slices == 4
+    finally:
+        eng.stop()
+
+
 def test_import_refused_without_paging(small):
     cfg, params = small
     eng = _engine(cfg, params, kv_block=0)
