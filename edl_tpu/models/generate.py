@@ -138,11 +138,13 @@ def generate(cfg: TransformerConfig, params, prompt, max_new_tokens: int,
             f"{cfg.max_len} (the KV cache size)")
     if not 0.0 <= top_p <= 1.0:
         raise ValueError(f"top_p must be in [0, 1], got {top_p}")
-    # MoE configs decode with per-token expert gather (ops/moe.py
-    # decode=True): no capacity machinery, so output matches the
-    # training forward exactly whenever training capacity dropped
-    # nothing (ample capacity_factor); when training did drop overflow
-    # tokens, decode is the drop-free ideal rather than a replica.
+    # Capacity-path MoE configs decode with per-token expert gather
+    # (ops/moe.py decode=True): no capacity machinery, so output
+    # matches the training forward exactly whenever training capacity
+    # dropped nothing (ample capacity_factor); when training did drop
+    # overflow tokens, decode is the drop-free ideal rather than a
+    # replica.  Dropless configs (moe_capacity <= 0) run one path for
+    # prefill and decode and never drop.
     #
     # The KV cache is sized to THIS request (P + new, padded to the
     # 128-lane tile), not cfg.max_len: every decode step streams the
@@ -210,3 +212,28 @@ def _sum_drops(intermediates) -> "jax.Array":
         if any(getattr(k, "key", None) == "moe_drops" for k in path):
             total = total + jnp.asarray(leaf, jnp.int32).sum()
     return total
+
+
+def _moe_stats(intermediates) -> "jax.Array":
+    """What the expert layers of one program sowed, for the host.
+
+    Without a ``moe_stats`` leaf (dense configurations, and the capacity
+    path) this IS :func:`_sum_drops`, an int32 scalar - their programs
+    stay what they were.  With the dropless path it is a float32 vector
+    ``[drops, assignments, experts touched, load ratios, layer calls]``:
+    each layer call's ``moe_stats`` summed, and how many there were
+    (ops/moe.py; every entry is a count or a small ratio, exact in
+    float32 at serving sizes)."""
+    import jax.numpy as jnp
+
+    leaves = [leaf for path, leaf in
+              jax.tree_util.tree_leaves_with_path(intermediates or {})
+              if any(getattr(k, "key", None) == "moe_stats" for k in path)]
+    drops = _sum_drops(intermediates)
+    if not leaves:
+        return drops
+    total = sum(jnp.asarray(leaf, jnp.float32).reshape(-1, 3).sum(0)
+                for leaf in leaves)
+    calls = sum(leaf.size // 3 for leaf in leaves)
+    return jnp.concatenate([drops.astype(jnp.float32)[None], total,
+                            jnp.asarray([calls], jnp.float32)])

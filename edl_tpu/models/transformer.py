@@ -38,6 +38,10 @@ LOGICAL_RULES = [
     (r"layers/moe/gate", ("layers", "embed", None)),
     (r"layers/moe/w_in", ("layers", "expert", "embed", "expert_mlp")),
     (r"layers/moe/w_out", ("layers", "expert", "expert_mlp", "embed")),
+    (r"layers/moe/w_gate", ("layers", "expert", "embed", "expert_mlp")),
+    # over the whole q / k projection; replicated like the other norms
+    (r"layers/q_norm/scale", ("layers", "norm")),
+    (r"layers/k_norm/scale", ("layers", "norm")),
     (r"layers/.*norm/scale", ("layers", "norm")),
     (r"final_norm/scale", ("norm",)),
     (r"lm_head/kernel", ("embed", "vocab")),
@@ -81,7 +85,18 @@ class TransformerConfig:
     # every block's FFN over this many experts (shard over ``ep``)
     moe_experts: int = 0
     moe_top_k: int = 2
+    # <= 0 selects the dropless path (sort + grouped matmuls, one path
+    # for training, prefill and decode); > 0 is the GShard capacity
+    # factor of the dense-dispatch path (ops/moe.py says which runs when)
     moe_capacity: float = 1.25
+    # gated experts (w_gate, w_in, w_out: silu(x w_gate) * (x w_in)),
+    # and whether the top-k gates are renormalised to sum 1
+    moe_gated: bool = False
+    moe_norm_topk: bool = True
+    # RMSNorm of the whole q and k projections before the split into
+    # heads (OLMoE's q_norm / k_norm), and every RMSNorm's epsilon
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
     # autoregressive decoding: attention reads/writes a per-layer KV
     # cache ("cache" collection) instead of recomputing the prefix
     # (models/generate.py drives this)
@@ -105,17 +120,35 @@ class TransformerConfig:
         return self.num_kv_heads or self.num_heads
 
 
-def param_count(cfg: TransformerConfig) -> int:
-    """Parameter count of the config (embedding table included)."""
-    L, D, M, V = cfg.num_layers, cfg.embed_dim, cfg.mlp_dim, cfg.vocab_size
+def _layer_matmul_params(cfg: TransformerConfig, experts: int) -> int:
+    """One layer's matmul parameters with ``experts`` experts counted
+    (all of them, or the ``moe_top_k`` a token is routed to)."""
+    D, M = cfg.embed_dim, cfg.mlp_dim
     H, Hk, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     attn = D * (H + 2 * Hk) * Dh + H * Dh * D
-    if cfg.moe_experts:
-        mlp = cfg.moe_experts * 2 * D * M + D * cfg.moe_experts
-    else:
-        mlp = 3 * D * M
+    if not cfg.moe_experts:
+        return attn + 3 * D * M
+    return (attn + D * cfg.moe_experts
+            + experts * (3 if cfg.moe_gated else 2) * D * M)
+
+
+def param_count(cfg: TransformerConfig) -> int:
+    """Parameter count of the config (embedding table included)."""
+    D, V = cfg.embed_dim, cfg.vocab_size
+    norms = 2 * D + ((cfg.num_heads + cfg.kv_heads) * cfg.head_dim
+                     if cfg.qk_norm else 0)
     head = 0 if cfg.tie_embeddings else D * V
-    return V * D + L * (attn + mlp + 2 * D) + head + D
+    return (V * D + head + D + cfg.num_layers * (
+        _layer_matmul_params(cfg, cfg.moe_experts) + norms))
+
+
+def active_matmul_params(cfg: TransformerConfig) -> int:
+    """Parameters that take part in a matmul for ONE token: attention,
+    the head, and the MLP - for an expert configuration the router and
+    the ``moe_top_k`` experts a token is routed to, not all of them.
+    The embedding table is a lookup."""
+    return (cfg.num_layers * _layer_matmul_params(cfg, cfg.moe_top_k)
+            + cfg.embed_dim * cfg.vocab_size)
 
 
 # Calibrated on v5e (doc/perf.md): the flagship (12L x 768, seq 1024)
@@ -182,13 +215,14 @@ def rope(x, positions, theta: float):
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-        return (x * jax.lax.rsqrt(var + 1e-6)).astype(self.dtype) * scale
+        return (x * jax.lax.rsqrt(var + self.eps)).astype(self.dtype) * scale
 
 
 class Block(nn.Module):
@@ -293,11 +327,18 @@ class Block(nn.Module):
         H, Dh = cfg.num_heads, cfg.head_dim
         Hk = cfg.kv_heads
         assert H % Hk == 0, f"num_heads {H} not divisible by kv heads {Hk}"
-        y = RMSNorm(cfg.dtype, name="attn_norm")(x)
+        y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
         qkv = nn.DenseGeneral(((H + 2 * Hk) * Dh,), use_bias=False,
                               dtype=cfg.dtype, param_dtype=jnp.float32,
                               name="attn_qkv")(y)
         q, k, v = jnp.split(qkv, [H * Dh, (H + Hk) * Dh], axis=-1)
+        if cfg.qk_norm:
+            # over the whole projection, before the split into heads;
+            # back in the compute dtype (the f32 scale promotes)
+            q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q).astype(
+                cfg.dtype)
+            k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k).astype(
+                cfg.dtype)
         B, L = x.shape[:2]
         q = rope(q.reshape(B, L, H, Dh), positions, cfg.rope_theta)
         k = rope(k.reshape(B, L, Hk, Dh), positions, cfg.rope_theta)
@@ -313,13 +354,15 @@ class Block(nn.Module):
         attn = attn.reshape(B, L, H * Dh)
         x = x + nn.DenseGeneral(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                                 param_dtype=jnp.float32, name="attn_out")(attn)
-        y = RMSNorm(cfg.dtype, name="mlp_norm")(x)
+        y = RMSNorm(cfg.dtype, cfg.norm_eps, name="mlp_norm")(x)
         if cfg.moe_experts:
             from edl_tpu.ops.moe import MoEMLP
             y, aux = MoEMLP(num_experts=cfg.moe_experts,
                             mlp_dim=cfg.mlp_dim, top_k=cfg.moe_top_k,
                             capacity_factor=cfg.moe_capacity,
                             dtype=cfg.dtype, decode=cfg.decode,
+                            gated=cfg.moe_gated,
+                            norm_topk=cfg.moe_norm_topk,
                             name="moe")(y, token_mask)
             return x + y, aux
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
@@ -377,7 +420,7 @@ class TransformerLM(nn.Module):
                             in_axes=nn.broadcast, metadata_params={},
                             unroll=1 if cfg.scan_layers else cfg.num_layers)
             x, aux = Stack(cfg, name="layers")(x, positions, token_mask)
-        x = RMSNorm(cfg.dtype, name="final_norm")(x)
+        x = RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x)
         aux_total = (jnp.mean(aux) if aux is not None
                      else jnp.zeros((), jnp.float32))
         if return_hidden:

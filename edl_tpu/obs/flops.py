@@ -17,7 +17,11 @@ without duplicating (and drifting from) that logic.  Three helpers:
   those (the bench's LM section measured 0.70 "TFLOP"/step vs ~7 real);
 - :func:`analytic_lm_flops_per_token` — the PaLM-appendix transformer
   accounting (6·N matmul params + 6·layers·seq·d_model causal
-  attention per token).
+  attention per token) from four sizes, dense MHA only;
+- :func:`config_flops_per_token` - the same accounting from a
+  ``TransformerConfig``: GQA widths, and for an expert configuration
+  the ACTIVE parameters (router + ``moe_top_k`` gated or ungated
+  experts a token), not all of them.
 
 ``mfu = achieved_tflops / peak_tflops``; both bench sections and the
 trainer's ``edl_mfu`` / ``edl_tflops_per_chip`` gauges
@@ -90,3 +94,18 @@ def analytic_lm_flops_per_token(num_layers: int, embed_dim: int,
                               + 3 * embed_dim * mlp_dim)   # swiglu mlp
                 + embed_dim * vocab_size)                  # lm head
     return float(6 * n_matmul + 6 * num_layers * seq * embed_dim)
+
+
+def config_flops_per_token(cfg, seq: int) -> float:
+    """Analytic train FLOPs per token of a ``TransformerConfig``:
+    6 per matmul parameter a token takes part in
+    (``transformer.active_matmul_params``: attention at its GQA widths,
+    the head, and the MLP - for an expert configuration the router and
+    the ``moe_top_k`` experts a token is routed to, three matrices each
+    when gated) plus causal attention, 6 * layers * seq * heads *
+    head_dim.  Equals :func:`analytic_lm_flops_per_token` for a dense
+    MHA configuration."""
+    from edl_tpu.models.transformer import active_matmul_params
+
+    attn = 6 * cfg.num_layers * seq * cfg.num_heads * cfg.head_dim
+    return float(6 * active_matmul_params(cfg) + attn)

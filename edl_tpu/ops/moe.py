@@ -1,23 +1,55 @@
-"""Mixture-of-experts MLP with expert parallelism over the ``ep`` axis.
+"""Mixture-of-experts MLP: top-k routed expert FFN, two routed paths.
 
 Beyond-parity capability (the reference's only sparse structure is the
-CTR embedding table, example/ctr/): a GShard-style top-k-routed expert
-FFN designed for the compiler rather than hand-scheduled all-to-alls —
-routing is expressed as dense dispatch/combine einsums against expert
-weights whose leading axis carries the ``expert`` logical name (mapped
-to ``ep`` by the default sharding rules), so XLA derives the token
-shuffle collectives from the shardings the same way it derives the
-data-parallel gradient reduction.
+CTR embedding table, example/ctr/).  The router is a float32 softmax
+over ``E`` experts; each token takes its ``top_k`` largest
+probabilities as gates (renormalised to sum 1 when ``norm_topk``, left
+as they are when not: OLMoE's ``norm_topk_prob: false``).  Experts are
+``silu(x @ w_in) @ w_out`` or, with ``gated``, the gated form
+``(silu(x @ w_gate) * (x @ w_in)) @ w_out``.
 
-Shapes (per group = one batch row): tokens ``[B, S, M]``, experts
-``E``, per-expert capacity ``C = ceil(top_k * S * capacity_factor /
-E)``.  Tokens routed past an expert's capacity are dropped (their
-combine weight is zero — the standard GShard/Switch overflow rule), so
-every tensor is static-shaped for jit.
+Which path runs when:
+
+- **Dropless** (``capacity_factor <= 0``): one path for training,
+  prefill, chunked prefill and decode.  The ``B*S*K`` (token, expert)
+  assignments are sorted by expert (stable), the token rows gathered in
+  that order, and each projection is ONE grouped matmul over the sorted
+  rows (``jax.lax.ragged_dot``, group sizes from a per-expert count:
+  on TPU a Mosaic grouped-matmul kernel that visits only the tiles of
+  experts that received rows, so a decode step reads the experts its
+  batch touched and no others).  The rows are then unsorted, weighted
+  by their gates and summed per token.  Nothing is ever dropped;
+  ``token_mask``-ed (pad) positions route nowhere: zero weight, in no
+  group, in no count.  No ``[B, S, E, C]`` tensor and no per-token
+  weight gather exists on this path.  Serving an expert model through
+  it (``serving/engine.py``) no longer drops: ``moe_prefill_drops``
+  reads 0.
+- **Capacity** (``capacity_factor > 0``, the default 1.25): the
+  GShard-style path designed for the compiler rather than
+  hand-scheduled all-to-alls - routing is expressed as dense
+  dispatch/combine einsums against expert weights whose leading axis
+  carries the ``expert`` logical name (mapped to ``ep`` by the default
+  sharding rules), so XLA derives the token shuffle collectives from
+  the shardings the same way it derives the data-parallel gradient
+  reduction.  Tokens ``[B, S, M]``, per-expert capacity ``C =
+  ceil(top_k * S * capacity_factor / E)`` per batch row; assignments
+  past an expert's capacity are dropped (their combine weight is zero -
+  the standard GShard/Switch overflow rule), so every tensor is
+  static-shaped.  With ``decode=True`` its single-token steps (S <= 2)
+  gather each token's experts instead (no capacity, no drops).  This is
+  what training over an ``ep`` mesh uses today; its dispatch tensors
+  are ``[B, S, E, C]``, which no serving-sized prefill can hold.
 
 The auxiliary load-balance loss is the Switch-Transformer form
-``E * Σ_e f_e · P_e`` (fraction of tokens top-1-routed to e × mean
-router probability of e); minimised at uniform routing.
+``E * sum_e f_e * P_e`` (fraction of tokens top-1-routed to e x mean
+router probability of e); minimised at uniform routing.  Both paths
+return it (the dropless one only outside ``decode``).
+
+What the paths sow into the ``intermediates`` collection (a no-op
+unless the caller asks for it): ``moe_drops`` (capacity path, int32)
+and ``moe_stats`` (dropless path, float32 ``[assignments, experts
+touched, max load over mean load]`` of this layer's call over real
+tokens) - the serving engine sums both into ``stats()``.
 """
 
 from __future__ import annotations
@@ -30,7 +62,8 @@ import jax
 import jax.numpy as jnp
 
 
-def compute_routing(probs, top_k: int, capacity: int, valid=None):
+def compute_routing(probs, top_k: int, capacity: int, valid=None,
+                    norm_topk: bool = True):
     """Routing tensors from router probabilities ``[B, S, E]``.
 
     Returns ``(dispatch [B, S, E, C] in {0,1}, combine [B, S, E, C]
@@ -50,8 +83,7 @@ def compute_routing(probs, top_k: int, capacity: int, valid=None):
     same prompt.
     """
     B, S, E = probs.shape
-    gates, idx = jax.lax.top_k(probs, top_k)              # [B, S, K]
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, idx = top_k_gates(probs, top_k, norm_topk)     # [B, S, K]
     onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)    # [B, S, K, E]
     if valid is not None:
         onehot = onehot * valid[:, :, None, None].astype(jnp.float32)
@@ -70,7 +102,21 @@ def compute_routing(probs, top_k: int, capacity: int, valid=None):
     combine = jnp.einsum("bske,bskec->bsec",
                          onehot * gates[..., None], pos_c)
 
-    # Switch aux loss from top-1 assignments (over real tokens only)
+    return dispatch, combine, switch_aux_loss(probs, idx, valid), drops
+
+
+def top_k_gates(probs, top_k: int, norm_topk: bool):
+    """``(gates, idx)`` [B, S, K]: each token's ``top_k`` largest
+    router probabilities, renormalised to sum 1 when ``norm_topk``."""
+    gates, idx = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, idx
+
+
+def switch_aux_loss(probs, idx, valid=None):
+    """Switch aux loss from top-1 assignments (over real tokens only)."""
+    E = probs.shape[-1]
     top1 = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32)
     if valid is None:
         frac_tokens = top1.mean(axis=(0, 1))              # [E]
@@ -80,8 +126,54 @@ def compute_routing(probs, top_k: int, capacity: int, valid=None):
         n = jnp.maximum(v.sum(), 1.0)
         frac_tokens = (top1 * v).sum(axis=(0, 1)) / n
         frac_prob = (probs * v).sum(axis=(0, 1)) / n
-    aux = E * jnp.sum(frac_tokens * frac_prob)
-    return dispatch, combine, aux, drops
+    return E * jnp.sum(frac_tokens * frac_prob)
+
+
+def _activate(h, gate=None):
+    """An expert's hidden activation: ``silu(h)``, or the gated form
+    ``silu(gate) * h`` (``gate`` the x @ w_gate projection)."""
+    return nn.silu(h) if gate is None else nn.silu(gate) * h
+
+
+def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None):
+    """The dropless routed expert FFN over tokens ``x [T, M]`` with
+    gates and expert indices ``[T, K]``: sort the ``T*K`` assignments
+    by expert, one grouped matmul per projection over the sorted rows,
+    unsort, weight and sum per token.  ``w_gate`` None = ungated.
+    ``valid [T]`` bool marks real tokens; the others are sorted past
+    every group (sentinel expert ``E``), so no expert computes them.
+
+    Returns ``(y [T, M] in x's dtype, sizes [E] int32)`` - ``sizes``
+    the real assignments each expert received."""
+    T, K = idx.shape
+    E = w_in.shape[0]
+    with jax.named_scope("moe/route"):
+        flat = idx.reshape(T * K).astype(jnp.int32)
+        if valid is not None:
+            flat = jnp.where(jnp.repeat(valid, K), flat, E)
+        order = jnp.argsort(flat, stable=True)            # sorted -> flat
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+        # rows past the last group belong to pad tokens: what a grouped
+        # matmul leaves there is unspecified, so they are zeroed
+        in_group = jnp.arange(T * K) < sizes.sum()
+    with jax.named_scope("moe/experts"):
+        rows = x[order // K]                              # [T*K, M]
+        h = _activate(
+            jax.lax.ragged_dot(rows, w_in, sizes),
+            None if w_gate is None
+            else jax.lax.ragged_dot(rows, w_gate, sizes))
+        out = jax.lax.ragged_dot(h, w_out, sizes)         # [T*K, M]
+    with jax.named_scope("moe/combine"):
+        out = jnp.where(in_group[:, None], out, 0)
+        # unsort with the inverse permutation (a gather, not a scatter-
+        # add) and sum each token's K rows in float32
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * K))
+        out = out[inverse].reshape(T, K, -1).astype(jnp.float32)
+        w = gates.astype(jnp.float32)
+        if valid is not None:
+            w = w * valid[:, None]
+        y = (out * w[..., None]).sum(axis=1)
+    return y.astype(x.dtype), sizes
 
 
 class MoEMLP(nn.Module):
@@ -92,24 +184,22 @@ class MoEMLP(nn.Module):
     default rules (LOGICAL_RULES in models/transformer.py adds the
     matching param-path entries).
 
-    ``decode=True`` (incremental generation) switches to per-token
-    expert gather for the actual decode steps (S <= 2): each token
-    reads exactly its top-k experts' weights, no capacity machinery
-    and therefore no drops — identical to the training forward
-    whenever training capacity dropped nothing.  The gather
-    materialises ``[B, S, K, M, H]`` weight slices, so memory scales
-    with ``top_k``; at S <= 2 that is fine for any realistic top_k.
-    Prefill (decode=True with a long S) takes the capacity path and
-    CAN drop on overflow; the drop count is sown into the
-    ``intermediates`` collection as ``moe_drops`` so serving paths can
-    surface it (pass ``mutable=["cache", "intermediates"]``).
+    ``capacity_factor <= 0`` selects the dropless path (module
+    docstring) for every call: training, prefill, chunked prefill and
+    decode run the same sort + grouped matmuls, nothing is dropped, and
+    the layer's ``moe_stats`` are sown into ``intermediates``.
 
-    Capacity is computed from the STATIC sequence length S, so a
-    bucket-padded prefill (serving/engine.py) gets a larger capacity
-    than the same prompt unpadded through generate(): with
-    ``token_mask`` the padded path can only drop FEWER (never more)
-    real-token assignments — identical whenever capacity is ample,
-    quality-biased-up when it is tight."""
+    ``capacity_factor > 0`` keeps the capacity path.  There
+    ``decode=True`` (incremental generation) sends the single-token
+    steps (S <= 2) through a per-token expert gather - no capacity
+    machinery and therefore no drops - while prefill (decode=True with
+    a long S) takes the capacity path and CAN drop on overflow; the
+    drop count is sown as ``moe_drops`` so serving paths can surface
+    it (pass ``mutable=["cache", "intermediates"]``).  Capacity is
+    computed from the STATIC sequence length S, so a bucket-padded
+    prefill (serving/engine.py) gets a larger capacity than the same
+    prompt unpadded through generate(): with ``token_mask`` the padded
+    path can only drop FEWER (never more) real-token assignments."""
 
     num_experts: int
     mlp_dim: int
@@ -117,11 +207,13 @@ class MoEMLP(nn.Module):
     capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
     decode: bool = False
+    gated: bool = False
+    norm_topk: bool = True
 
     @nn.compact
     def __call__(self, x, token_mask=None):
         """``token_mask`` ([B, S] bool, optional): real-token mask for
-        padded prefill — see :func:`compute_routing`."""
+        padded prefill - see :func:`compute_routing`."""
         B, S, M = x.shape
         E = self.num_experts
         gate_w = self.param("gate", nn.initializers.lecun_normal(),
@@ -130,28 +222,45 @@ class MoEMLP(nn.Module):
                           (E, M, self.mlp_dim), jnp.float32)
         w_out = self.param("w_out", nn.initializers.lecun_normal(),
                            (E, self.mlp_dim, M), jnp.float32)
+        w_gate = (self.param("w_gate", nn.initializers.lecun_normal(),
+                             (E, M, self.mlp_dim), jnp.float32)
+                  if self.gated else None)
 
         # router in f32 (tiny matmul, routing decisions precision-critical)
         probs = jax.nn.softmax(x.astype(jnp.float32) @ gate_w, axis=-1)
         dtype = self.dtype
 
+        if self.capacity_factor <= 0:
+            gates, idx = top_k_gates(probs, self.top_k, self.norm_topk)
+            valid = None if token_mask is None else token_mask.reshape(B * S)
+            y, sizes = dropless_experts(
+                x.reshape(B * S, M).astype(dtype),
+                gates.reshape(B * S, -1), idx.reshape(B * S, -1),
+                None if w_gate is None else w_gate.astype(dtype),
+                w_in.astype(dtype), w_out.astype(dtype), valid)
+            total = sizes.sum().astype(jnp.float32)
+            self.sow("intermediates", "moe_stats", jnp.stack([
+                total, (sizes > 0).sum().astype(jnp.float32),
+                sizes.max() * E / jnp.maximum(total, 1.0)]),
+                init_fn=lambda: jnp.zeros((3,), jnp.float32),
+                reduce_fn=lambda a, b: a + b)
+            aux = (jnp.zeros((), jnp.float32) if self.decode
+                   else switch_aux_loss(probs, idx, token_mask))
+            return y.reshape(B, S, M), aux
+
         # per-token gather only for the incremental steps (S <= 2,
-        # whatever top_k is — gating on S*top_k silently sent
-        # large-top_k single-token steps down the capacity path,
-        # breaking the drop-free decode promise): the gather
-        # materialises [B, S, K, M, H] weights, ruinous at prefill
-        # length.  Prefill (decode=True, S = prompt) falls through to
-        # the capacity path — the training forward's exact semantics,
-        # which is what the prompt pass should be anyway.
+        # whatever top_k is): prefill (decode=True, S = prompt) falls
+        # through to the capacity path - the training forward's exact
+        # semantics
         if self.decode and S <= 2:
-            gates, idx = jax.lax.top_k(probs, self.top_k)     # [B, S, K]
-            gates = gates / jnp.maximum(
-                gates.sum(-1, keepdims=True), 1e-9)
-            sel_in = w_in[idx].astype(dtype)                  # [B,S,K,M,H]
-            sel_out = w_out[idx].astype(dtype)                # [B,S,K,H,M]
-            h = nn.silu(jnp.einsum("bsm,bskmh->bskh",
-                                   x.astype(dtype), sel_in))
-            out = jnp.einsum("bskh,bskhm->bskm", h, sel_out)
+            gates, idx = top_k_gates(probs, self.top_k, self.norm_topk)
+            xk = x.astype(dtype)
+            h = _activate(
+                jnp.einsum("bsm,bskmh->bskh", xk, w_in[idx].astype(dtype)),
+                None if w_gate is None else jnp.einsum(
+                    "bsm,bskmh->bskh", xk, w_gate[idx].astype(dtype)))
+            out = jnp.einsum("bskh,bskhm->bskm", h,
+                             w_out[idx].astype(dtype))
             y = (out * gates[..., None].astype(dtype)).sum(axis=2)
             # module dtype, not input dtype: the block's norm emits f32
             # (f32 scale param), and a f32 MoE output would promote the
@@ -161,9 +270,10 @@ class MoEMLP(nn.Module):
         capacity = max(1, math.ceil(
             self.top_k * S * self.capacity_factor / E))
         dispatch, combine, aux, drops = compute_routing(
-            probs, self.top_k, capacity, valid=token_mask)
+            probs, self.top_k, capacity, valid=token_mask,
+            norm_topk=self.norm_topk)
         # observable overflow: serving reads this via the intermediates
-        # collection (training ignores it at zero cost — sow is a no-op
+        # collection (training ignores it at zero cost - sow is a no-op
         # unless the caller asks for the collection)
         self.sow("intermediates", "moe_drops", drops,
                  init_fn=lambda: jnp.zeros((), jnp.int32),
@@ -171,8 +281,10 @@ class MoEMLP(nn.Module):
 
         expert_in = jnp.einsum("bsec,bsm->ebcm", dispatch.astype(dtype),
                                x.astype(dtype))
-        h = nn.silu(jnp.einsum("ebcm,emh->ebch", expert_in,
-                               w_in.astype(dtype)))
+        h = _activate(
+            jnp.einsum("ebcm,emh->ebch", expert_in, w_in.astype(dtype)),
+            None if w_gate is None else jnp.einsum(
+                "ebcm,emh->ebch", expert_in, w_gate.astype(dtype)))
         out = jnp.einsum("ebch,ehm->ebcm", h, w_out.astype(dtype))
         y = jnp.einsum("bsec,ebcm->bsm", combine.astype(dtype), out)
         return y.astype(dtype), aux

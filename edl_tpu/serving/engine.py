@@ -84,7 +84,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from edl_tpu.models.generate import _split_layer_params, sample_logits
+from edl_tpu.models.generate import (_moe_stats, _split_layer_params,
+                                     sample_logits)
 from edl_tpu.models.transformer import TransformerConfig, TransformerLM
 from edl_tpu.obs import context as obs_context
 from edl_tpu.obs import metrics as obs_metrics
@@ -235,6 +236,12 @@ class ContinuousBatcher:
             cfg, decode=True, attention_impl="dense", mesh=None,
             max_len=cache_len)
         self._model = TransformerLM(self._dcfg)
+        # dropless expert path (ops/moe.py): its programs carry the
+        # layers' moe_stats vector where the others carry a drop count
+        self._moe_dropless = bool(cfg.moe_experts and cfg.moe_capacity <= 0)
+        self._moe_acc_shape = (
+            jax.ShapeDtypeStruct((5,), jnp.float32) if self._moe_dropless
+            else jax.ShapeDtypeStruct((), jnp.int32))
         self._pending: "deque[_Request]" = deque()
         self._mesh = mesh
         if mesh is not None:
@@ -332,6 +339,15 @@ class ContinuousBatcher:
         self._failed_requests = 0     # futures failed while engine lives
         self._emitted_tokens = 0
         self._moe_drops = 0       # MoE prefill capacity overflow (see stats)
+        # the dropless expert path's counters (ops/moe.py moe_stats),
+        # cumulative; they reach the host with the tick's own sync
+        self._moe_assignments = 0
+        self._moe_tokens = 0      # the host's own count of what was routed
+        self._moe_decode_layer_steps = 0
+        self._moe_decode_experts_touched = 0
+        self._moe_prefill_groups = 0
+        self._moe_prefill_experts_touched = 0
+        self._moe_prefill_max_load_sum = 0.0
         self._lane_steps = 0          # slot-steps actually dispatched
         self._active_lane_steps = 0   # of those, slots with live requests
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
@@ -557,7 +573,7 @@ class ContinuousBatcher:
                                    lens).compile()
             jax.block_until_ready(toks)
         self._step_jit.lower(self._cache, jnp.asarray(self._toks), key,
-                             self._params).compile()
+                             self._params, *self._live_mask([])).compile()
         if self._chunk_tokens:
             # the program every chunked admission starts with, whatever
             # prompt class this call warms
@@ -637,6 +653,27 @@ class ContinuousBatcher:
                 # MoE prefill capacity overflow (always 0 for dense
                 # configs; nonzero = raise capacity_factor)
                 "moe_prefill_drops": self._moe_drops,
+                # real tokens the host sent through an expert model's
+                # programs (prompt tokens prefilled, live slots x token
+                # steps; 0 for dense configs): on the dropless path
+                # moe_assignments == top_k x layers x moe_tokens at
+                # every instant, and a path that drops reads less
+                "moe_tokens": self._moe_tokens,
+                # dropless expert path (0s otherwise): real (token,
+                # expert) pairs routed; layer calls of decode token
+                # steps and the distinct experts they touched (ratio:
+                # experts one layer of one step read); layer calls of
+                # prefill programs, the experts they touched and their
+                # max-over-mean expert load summed (ratio: the imbalance)
+                "moe_assignments": self._moe_assignments,
+                "moe_decode_layer_steps": self._moe_decode_layer_steps,
+                "moe_decode_experts_touched":
+                    self._moe_decode_experts_touched,
+                "moe_prefill_groups": self._moe_prefill_groups,
+                "moe_prefill_experts_touched":
+                    self._moe_prefill_experts_touched,
+                "moe_prefill_max_load_sum":
+                    round(self._moe_prefill_max_load_sum, 3),
                 # host-side time spent dispatching prefill work while
                 # decode lanes were live — the upper bound on decode
                 # wall-time lost to admissions (device work still
@@ -883,7 +920,6 @@ class ContinuousBatcher:
         model = self._model
 
         def prefill(params, ids, true_lens, key):
-            from edl_tpu.models.generate import _sum_drops
             cache = _zeros_of(self._cache_shapes(K))
             # pad positions are masked out of MoE routing (they must
             # not claim expert capacity ahead of real tokens' choices;
@@ -906,7 +942,7 @@ class ContinuousBatcher:
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
             # MoE capacity overflow at prefill (0 for dense configs)
-            return mut["cache"], toks, _sum_drops(mut.get("intermediates"))
+            return mut["cache"], toks, _moe_stats(mut.get("intermediates"))
 
         fn = jax.jit(prefill)
         self._prefill_cache[(P, K)] = fn
@@ -923,8 +959,15 @@ class ContinuousBatcher:
             return big.at[slots].set(small)
         return jax.tree.map(put, cache, slab)
 
-    def _step_impl(self, cache, toks, key, params):
+    def _step_impl(self, cache, toks, key, params, live=None):
         """Advance every slot ``self._T`` tokens (one dispatch).
+
+        With the dropless expert path ``live`` ([slots] bool) masks the
+        free slots out of the routing - their ballast tokens touch no
+        expert - and the layers' ``moe_stats`` ride back beside the
+        tokens: ``(cache, (tokens, stats))``.  Every other
+        configuration takes no ``live`` and returns ``(cache, tokens)``
+        from the program it always had.
 
         ``params`` is an ARGUMENT, not a closure capture: a captured
         param tree would be baked into the jaxpr as constants — 124M
@@ -932,19 +975,26 @@ class ContinuousBatcher:
         every compile-cache key would then have to carry."""
         model = self._model
 
+        moe = self._moe_dropless
+
         def one(carry, k):
-            cache, tok = carry
+            cache, tok, *acc = carry
             # per-slot positions come from the cache itself
             pos = self._positions(cache)
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
-                positions=pos[:, None], mutable=["cache"])
+                positions=pos[:, None],
+                token_mask=live[:, None] if moe else None,
+                mutable=["cache", "intermediates"] if moe else ["cache"])
             nxt = self._sample(logits[:, -1], k)
-            return (mut["cache"], nxt), nxt
+            acc = [a + _moe_stats(mut.get("intermediates")) for a in acc]
+            return (mut["cache"], nxt, *acc), nxt
 
         keys = jax.random.split(key, self._T)
-        (cache, _), out = jax.lax.scan(one, (cache, toks), keys)
-        return cache, out.T                            # [slots, T]
+        acc0 = [_zeros_of(self._moe_acc_shape)] if moe else []
+        (cache, _, *acc), out = jax.lax.scan(one, (cache, toks, *acc0), keys)
+        # [slots, T]
+        return cache, ((out.T, acc[0]) if moe else out.T)
 
     @staticmethod
     def _positions(cache):
@@ -1197,6 +1247,7 @@ class ContinuousBatcher:
         try:
             dec = None
             counts = None
+            moe = None
             with led.phase("dispatch"):
                 if active:
                     if self._spec_k:
@@ -1209,7 +1260,9 @@ class ContinuousBatcher:
                         self._rng, key = jax.random.split(self._rng)
                         self._cache, dec = self._step_jit(
                             self._cache, jnp.asarray(self._toks), key,
-                            self._params)
+                            self._params, *self._live_mask(active))
+                        if self._moe_dropless:
+                            dec, moe = dec
                 for slab, _, _, slots, _, lens, dslab in pres:
                     self._cache = self._insert_jit(
                         self._cache, slab, jnp.asarray(slots, jnp.int32),
@@ -1222,10 +1275,11 @@ class ContinuousBatcher:
             # single sync point for decode + every admission
             with led.phase("sync"):
                 dec_np = np.asarray(dec) if dec is not None else None
+                moe_np = np.asarray(moe) if moe is not None else None
                 counts_np = (np.asarray(counts) if counts is not None
                              else None)
                 fins = [(p[3], p[4], np.asarray(p[1]),
-                         int(np.asarray(p[2]))) for p in pres]
+                         np.asarray(p[2])) for p in pres]
         except Exception as e:  # noqa: BLE001
             for p in pres:
                 for req in p[4]:
@@ -1239,6 +1293,9 @@ class ContinuousBatcher:
                     self._finish_spec(dec_np, counts_np, len(active))
                 else:
                     self._finish_decode(dec_np, len(active))
+                if moe_np is not None:
+                    self._count_moe(moe_np, len(active) * self._T,
+                                    decode=True)
             for slots, reqs, ptoks_np, drops in fins:
                 self._finish_prefill(slots, reqs, ptoks_np, drops)
 
@@ -1429,7 +1486,7 @@ class ContinuousBatcher:
                   NamedSharding(self._mesh, PartitionSpec()))
         return self._zeros(
             ("chunk_start",),
-            (self._cache_shapes(1), jax.ShapeDtypeStruct((), jnp.int32)), sh)
+            (self._cache_shapes(1), self._moe_acc_shape), sh)
 
     def _advance_chunk(self):
         """Dispatch ONE chunk of the in-flight chunked admission (no
@@ -1483,13 +1540,12 @@ class ContinuousBatcher:
         model = self._model
 
         def mid(params, slab, ids, drops_in):
-            from edl_tpu.models.generate import _sum_drops
             idx = self._positions(slab)           # == tokens prefilled
             _, mut = model.apply(
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(C)[None, :],
                 mutable=["cache", "intermediates"])
-            return mut["cache"], drops_in + _sum_drops(
+            return mut["cache"], drops_in + _moe_stats(
                 mut.get("intermediates"))
 
         if self._mesh is not None:
@@ -1511,7 +1567,6 @@ class ContinuousBatcher:
         model = self._model
 
         def fin(params, slab, ids, rel_lens, drops_in, key):
-            from edl_tpu.models.generate import _sum_drops
             idx = self._positions(slab)
             logits, mut = model.apply(
                 {"params": params, "cache": slab}, ids,
@@ -1522,7 +1577,7 @@ class ContinuousBatcher:
                 logits, (rel_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
             return (mut["cache"], toks,
-                    drops_in + _sum_drops(mut.get("intermediates")))
+                    drops_in + _moe_stats(mut.get("intermediates")))
 
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -1636,7 +1691,6 @@ class ContinuousBatcher:
 
         def prefill(params, pool, ids, block_ids, prefix_len, true_lens,
                     key):
-            from edl_tpu.models.generate import _sum_drops
             cache = _zeros_of(self._cache_shapes(1))
             cache = kv.load_prefix_into(cache, pool, block_ids, n_pad,
                                         prefix_len)
@@ -1649,21 +1703,22 @@ class ContinuousBatcher:
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
-            return mut["cache"], toks, _sum_drops(mut.get("intermediates"))
+            return mut["cache"], toks, _moe_stats(mut.get("intermediates"))
 
         fn = jax.jit(prefill)
         self._prefill_cache[("reuse", P, n_pad)] = fn
         return fn
 
     def _finish_prefill(self, slots: list[int], reqs: list[_Request],
-                        toks: np.ndarray, drops: int) -> None:
+                        toks: np.ndarray, moe: np.ndarray) -> None:
         now = time.monotonic()
         ttfts = [now - r.t_submit for r in reqs]
         for req, ttft in zip(reqs, ttfts):
             req.t_first = now         # its first token is on the host
             _TTFT_SECONDS.observe(ttft)
+        self._count_moe(moe, sum(len(r.ids) - r.skipped for r in reqs),
+                        decode=False)
         with self._stats_lock:
-            self._moe_drops += drops
             self._first_tokens += len(reqs)
             self._ttft_s += sum(ttfts)
         for slot, req, tok in zip(slots, reqs, toks.tolist()):
@@ -1674,6 +1729,36 @@ class ContinuousBatcher:
             self._toks[slot] = int(tok)
             if s.remaining == 0 or int(tok) == self._eos:
                 self._finish(slot)
+
+    def _live_mask(self, active: list[int]) -> tuple:
+        """The decode step's ``live`` argument: one [slots] bool array
+        for the dropless expert path, nothing for any other program."""
+        if not self._moe_dropless:
+            return ()
+        live = np.zeros((len(self._slots),), bool)
+        live[active] = True
+        return (jnp.asarray(live),)
+
+    def _count_moe(self, moe: np.ndarray, tokens: int, decode: bool) -> None:
+        """Add one program's expert counters (generate._moe_stats: a
+        drop count alone, or the dropless path's vector) to stats(),
+        and beside them the ``tokens`` the host knows it routed."""
+        with self._stats_lock:
+            if self._dcfg.moe_experts:
+                self._moe_tokens += tokens
+            if moe.ndim == 0:
+                self._moe_drops += int(moe)
+                return
+            drops, assigned, touched, load, calls = moe.tolist()
+            self._moe_drops += int(drops)
+            self._moe_assignments += int(assigned)
+            if decode:
+                self._moe_decode_layer_steps += int(calls)
+                self._moe_decode_experts_touched += int(touched)
+            else:
+                self._moe_prefill_groups += int(calls)
+                self._moe_prefill_experts_touched += int(touched)
+                self._moe_prefill_max_load_sum += load
 
     def _finish_decode(self, toks: np.ndarray, n_active: int) -> None:
         """Consume one decode chunk [slots, T].  Runs BEFORE this tick's
