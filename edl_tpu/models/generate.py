@@ -154,8 +154,10 @@ def generate(cfg: TransformerConfig, params, prompt, max_new_tokens: int,
     # floor once sampling is fast).  RoPE uses absolute positions, so
     # shrinking the cache does not move any embedding.
     cache_len = min(cfg.max_len, -(-(P + max_new_tokens) // 128) * 128)
+    # cfg.mesh stays: "dense" never reads it, and a model that has one
+    # (tp-sharded serving) keeps its decode steps on the einsum path
     dcfg = dataclasses.replace(cfg, decode=True, attention_impl="dense",
-                               mesh=None, max_len=cache_len)
+                               max_len=cache_len)
     model = TransformerLM(dcfg)
     params = _split_layer_params(params, cfg.num_layers)
     rng = jax.random.key(0) if rng is None else rng

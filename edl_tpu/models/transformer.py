@@ -25,6 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from edl_tpu.ops import decode_attention
 from edl_tpu.ops.attention import dot_product_attention
 
 # param-path regex → logical axes (ElasticTrainer.create_state consumes)
@@ -69,7 +70,9 @@ class TransformerConfig:
     num_kv_heads: int = 0
     dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"      # auto | dense | splash | flash | ring
-    mesh: Any = None                  # ring needs it; splash shards by it
+    # ring needs it; splash shards by it; a decode model that has one
+    # keeps its slabs on the einsum path (ops/decode_attention.applies)
+    mesh: Any = None
     remat: bool = True
     # lax.scan over stacked layer params (one compile for N layers) vs
     # unrolled python loop.  Scan trades ~12% step time for compile
@@ -230,7 +233,7 @@ class Block(nn.Module):
 
     cfg: TransformerConfig
 
-    def _decode_attention(self, q, k, v):
+    def _decode_attention(self, q, k, v, token_mask=None):
         """Incremental attention against a persistent KV cache.  First
         call (init, or a fresh "cache" collection) creates the zeroed
         cache; subsequent mutable-apply calls append the new k/v at
@@ -254,14 +257,29 @@ class Block(nn.Module):
 
         Cache layouts match the two attention matmuls exactly — keys
         ``[B, Hk, D, max_len]`` (contraction over D, time on the lane
-        axis) and values ``[B, Hk, max_len, D]`` — so reading the cache
-        each step is a straight matmul operand with NO full-cache
-        transpose; only the tiny new slab is rearranged on write.
-        Under GQA (``num_kv_heads < num_heads``) the cache holds only
-        the Hk K/V heads — the whole point: decode streams the cache
-        every step, so the cache shrinks (and decode speeds up) by the
-        group factor — and the query heads attend in groups of
-        ``G = H // Hk`` (q head h uses kv head h // G)."""
+        axis) and values ``[B, Hk, max_len, D]`` — so the einsums need
+        no transpose of their own.  Under GQA (``num_kv_heads <
+        num_heads``) the cache holds only the Hk K/V heads — decode
+        streams the cache every step, so the cache shrinks (and decode
+        speeds up) by the group factor — and the query heads attend in
+        groups of ``G = H // Hk`` (q head h uses kv head h // G).
+
+        Single-token steps take one of two paths, by what can be
+        observed here (``ops/decode_attention.applies``: a TPU, no
+        mesh, a lane-tiled ``max_len``) and by no setting.  On the
+        chip, two Pallas kernels append and attend IN PLACE, in the
+        layout the jit boundary has, and read each slot only up to its
+        length; slots that ``token_mask`` marks free (``[B, 1]`` bool)
+        are neither written nor read, and their output is zeros.
+        Everywhere else, and for every multi-token call, the scatter
+        and the einsums below run: the off-chip path and the kernels'
+        parity reference.  Compiled for a TPU that path reads both
+        slabs WHOLE every step under the position mask, and XLA wants
+        them time-major for the scatter, so it copies every slab at the
+        program's entry and again at its exit (PERF.md section 6,
+        PR 27): the reason the kernels exist.  ``token_mask`` is not
+        used there: free slots write and read ballast, as they always
+        did."""
         cfg = self.cfg
         B, L, H, Dh = q.shape
         Hk = k.shape[2]
@@ -276,6 +294,15 @@ class Block(nn.Module):
         if not is_initialized:      # init trace: shapes only
             return dot_product_attention(q, k, v, causal=True, impl="dense")
         idx = ci.value                                    # [B]
+        if decode_attention.applies(L, cfg.mesh, cfg.max_len):
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            ck.value, cv.value = decode_attention.decode_append(
+                ck.value, cv.value, k[:, 0], v[:, 0], idx, live)
+            ci.value = idx + 1
+            lengths = jnp.where(live, jnp.minimum(idx + 1, cfg.max_len), 0)
+            return decode_attention.decode_attend(
+                q[:, 0], ck.value, cv.value, lengths)[:, None]
         if L == 1:
             # per-example scatter (tiny update: B×Hk×D elements)
             ck.value = ck.value.at[jnp.arange(B), :, :, idx].set(
@@ -344,7 +371,7 @@ class Block(nn.Module):
         k = rope(k.reshape(B, L, Hk, Dh), positions, cfg.rope_theta)
         v = v.reshape(B, L, Hk, Dh)
         if cfg.decode:
-            attn = self._decode_attention(q, k, v)
+            attn = self._decode_attention(q, k, v, token_mask)
         else:
             # GQA is handled by the dispatch: dense attends grouped
             # K/V without materialising repeats; kernels expand inside

@@ -1,0 +1,245 @@
+"""One-token decode against the KV slabs, in place: two Pallas TPU kernels.
+
+A decode step (``L == 1``) adds one key column and one value row per
+slot and attends one query per head against what the slot holds.  The
+XLA lowering of that (a scatter and two einsums over the whole slabs,
+``transformer.Block._decode_attention``) reads every slab whole each
+token step and, on the chip, re-lays the slabs out at the jitted
+program's entry and exit (the scatter wants time-major, the jit
+boundary has row-major): whole-slab copies that dwarf the live KV.
+
+Here the slabs stay in the layout the jit boundary has,
+keys ``[B, Hk, D, max_len]`` and values ``[B, Hk, max_len, D]``:
+
+- :func:`decode_append` (``pallas_call`` name ``decode_append``) writes
+  each live slot's new column and row through one aliased tile of each
+  slab: a 128-lane tile of K, one sublane tile of V, the tile chosen by
+  the scalar-prefetched ``cache_index``.  Free slots, and writes at or
+  past ``max_len``, leave their tile as it was.
+- :func:`decode_attend` (``decode_attend``) is flash-decoding over the
+  slabs as they lie (q ``[G, D]`` x K ``[D, tk]``, p ``[G, tk]`` x V
+  ``[tk, D]``: no transpose), heads inside the block, the grid over
+  (slots, ``max_len / tk``).  Lengths are scalar-prefetched and the
+  index maps clamp to each slot's last live block, so a block past the
+  length is neither fetched nor computed; a free slot (length 0) points
+  at the block its neighbour already holds and fetches nothing.
+
+Precision is the einsum path's: input-dtype matmuls accumulated in
+float32, scores and softmax statistics in float32, probabilities cast
+to the cache dtype before the second matmul.
+
+:func:`applies` is the dispatch rule, from what the caller can observe
+and nothing else.  Off a TPU the kernels run in Pallas interpret mode
+(the tier-1 parity tests); nothing selects them there.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from edl_tpu.ops.attention import _on_tpu
+
+_LANES = 128
+# bytes of one K (or V) block of the attend kernel: two slabs, double
+# buffered, is four of these in VMEM beside the f32 scores
+_BLOCK_BYTES = 2 << 20
+_VMEM_LIMIT = 48 << 20
+_NEG = -1e30    # finite: a fully masked tail must not make inf - inf
+
+
+def applies(L: int, mesh, max_len: int) -> bool:
+    """Whether a decode call takes the kernels: a one-token step on a
+    TPU, over slabs no mesh shards, whose time axis tiles by lanes.
+    Everything else (prefill, chunks, the speculative verify, a mesh
+    engine, any other backend) stays on the einsum path."""
+    return (L == 1 and mesh is None and max_len % _LANES == 0
+            and _on_tpu())
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one native tile: 8 at 32 bits, 16 at 16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _interpret(interpret) -> bool:
+    return (not _on_tpu()) if interpret is None else interpret
+
+
+# -- append ----------------------------------------------------------------
+
+def _append_kernel(idx_ref, on_ref, k_ref, v_ref, kn_ref, vn_ref,
+                   ko_ref, vo_ref, *, rows: int):
+    b = pl.program_id(0)
+    at, on = idx_ref[b], on_ref[b] != 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 2)
+    ko_ref[...] = jnp.where((lane == at % _LANES) & on, kn_ref[...],
+                            k_ref[...])
+    row = jax.lax.broadcasted_iota(jnp.int32, v_ref.shape, 1)
+    vo_ref[...] = jnp.where((row == at % rows) & on,
+                            jnp.broadcast_to(vn_ref[...], v_ref.shape),
+                            v_ref[...])
+
+
+def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
+                  interpret=None):
+    """Write ``k_new`` / ``v_new`` ``[B, Hk, D]`` at position
+    ``index[b]`` of slot b's slabs, in place (donate or carry the slabs:
+    they are aliased in and out).  Slots with ``live[b]`` false or
+    ``index[b] >= max_len`` are rewritten with what they held."""
+    B, Hk, D, T = k_slab.shape
+    rows = _sublanes(v_slab.dtype)
+    on = (live & (index < T)).astype(jnp.int32)
+    at = jnp.clip(index, 0, T - 1).astype(jnp.int32)
+    # Mosaic cannot move the [Hk, D] column onto the lane axis itself;
+    # XLA broadcasts it over one lane tile (B x Hk x D x 128 elements)
+    kn = jnp.broadcast_to(k_new.astype(k_slab.dtype)[..., None],
+                          (B, Hk, D, _LANES))
+    vn = v_new.astype(v_slab.dtype)[:, :, None, :]
+    k_spec = pl.BlockSpec((None, Hk, D, _LANES),
+                          lambda b, at, on: (b, 0, 0, at[b] // _LANES))
+    v_spec = pl.BlockSpec((None, Hk, rows, D),
+                          lambda b, at, on: (b, 0, at[b] // rows, 0))
+    return pl.pallas_call(
+        functools.partial(_append_kernel, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[
+                k_spec, v_spec,
+                pl.BlockSpec((None, Hk, D, _LANES),
+                             lambda b, at, on: (b, 0, 0, 0)),
+                pl.BlockSpec((None, Hk, 1, D),
+                             lambda b, at, on: (b, 0, 0, 0))],
+            out_specs=[k_spec, v_spec]),
+        out_shape=[jax.ShapeDtypeStruct(k_slab.shape, k_slab.dtype),
+                   jax.ShapeDtypeStruct(v_slab.shape, v_slab.dtype)],
+        # operands count the two prefetched scalars
+        input_output_aliases={2: 0, 3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(interpret), name="decode_append",
+    )(at, on, k_slab, v_slab, kn, vn)
+
+
+# -- attend ----------------------------------------------------------------
+
+def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref,
+                   o_ref, m_ref, l_ref, acc_ref, *, tk: int, scale: float):
+    b, j = pl.program_id(0), pl.program_id(1)
+    n = len_ref[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j * tk < n)
+    def _():
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        # [Hk, Gp, D] x [Hk, D, tk] -> [Hk, Gp, tk], f32
+        s = jax.lax.dot_general(
+            q, k, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(pos < n, s, _NEG)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        # a block that runs holds a live position, so m_new is a real
+        # score and the masked tail's exp(_NEG - m_new) is exactly 0
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        # [Hk, Gp, tk] x [Hk, tk, D] -> [Hk, Gp, D]
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]      # 0 for a free slot, whose acc is 0 too
+        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def _fetch_plan(lengths, tk: int):
+    """Per slot, the slot and the block range its grid steps fetch from.
+    A live slot reads its own blocks ``0 .. (len - 1) // tk``; a free
+    slot names the block the step before it left in VMEM (its last live
+    predecessor's last block, or block 0 of the first live slot when it
+    has none), which the pipeline then does not fetch again."""
+    B = lengths.shape[0]
+    live = lengths > 0
+    slot = jnp.arange(B, dtype=jnp.int32)
+    prev = jax.lax.cummax(jnp.where(live, slot, -1))
+    src = jnp.where(prev >= 0, prev, jnp.argmax(live).astype(jnp.int32))
+    last = jnp.where(live, (lengths - 1) // tk, 0)
+    hi = jnp.where(prev >= 0, last[src], 0)
+    lo = jnp.where(live, 0, hi)
+    return src, lo.astype(jnp.int32), hi.astype(jnp.int32)
+
+
+def attend_block(Hk: int, D: int, max_len: int, dtype) -> int:
+    """Time steps a grid step of the attend kernel reads: the largest
+    power-of-two multiple of 128 that divides ``max_len`` and keeps one
+    K block (all heads) within ``_BLOCK_BYTES``."""
+    tk = _LANES
+    while (max_len % (2 * tk) == 0 and 2 * tk * Hk * D
+           * jnp.dtype(dtype).itemsize <= _BLOCK_BYTES):
+        tk *= 2
+    return tk
+
+
+def decode_attend(q, k_slab, v_slab, lengths, *, block: int | None = None,
+                  interpret=None):
+    """Attention of one query per head, ``q [B, H, D]``, against the
+    first ``lengths[b]`` positions of slot b's slabs; ``[B, H, D]`` in
+    ``q``'s dtype.  Query head h reads KV head ``h // (H // Hk)``.  A
+    slot of length 0 reads nothing and returns zeros."""
+    B, H, D = q.shape
+    _, Hk, _, T = k_slab.shape
+    G = H // Hk
+    tk = block or attend_block(Hk, D, T, k_slab.dtype)
+    assert T % tk == 0, (T, tk)
+    # the group axis is the matmuls' row axis: pad it to a sublane tile
+    Gp = -(-G // _sublanes(q.dtype)) * _sublanes(q.dtype)
+    qg = jnp.pad(q.reshape(B, Hk, G, D), ((0, 0), (0, 0), (0, Gp - G),
+                                          (0, 0)))
+    lengths = lengths.astype(jnp.int32)
+    src, lo, hi = _fetch_plan(lengths, tk)
+
+    def fetched(b, j, n, src, lo, hi):
+        return src[b], jnp.clip(j, lo[b], hi[b])
+
+    def k_index(*step):
+        slot, t = fetched(*step)
+        return slot, 0, 0, t
+
+    def v_index(*step):
+        slot, t = fetched(*step)
+        return slot, 0, t, 0
+
+    q_spec = pl.BlockSpec((None, Hk, Gp, D), lambda b, *_: (b, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_attend_kernel, tk=tk, scale=D ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, T // tk),
+            in_specs=[q_spec,
+                      pl.BlockSpec((None, Hk, D, tk), k_index),
+                      pl.BlockSpec((None, Hk, tk, D), v_index)],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((Hk, Gp, 1), jnp.float32),
+                            pltpu.VMEM((Hk, Gp, 1), jnp.float32),
+                            pltpu.VMEM((Hk, Gp, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hk, Gp, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(interpret), name="decode_attend",
+    )(lengths, src, lo, hi, qg, k_slab, v_slab)
+    return out[:, :, :G].reshape(B, H, D)

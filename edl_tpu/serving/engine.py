@@ -232,8 +232,11 @@ class ContinuousBatcher:
                  draft_params=None):
         cache_len = max_len or cfg.max_len
         self.cfg = cfg
+        # attention_impl "dense" never reads the mesh; the decode step
+        # does (ops/decode_attention.applies): a mesh engine's sharded
+        # slabs stay on the einsum path
         self._dcfg = dataclasses.replace(
-            cfg, decode=True, attention_impl="dense", mesh=None,
+            cfg, decode=True, attention_impl="dense", mesh=mesh,
             max_len=cache_len)
         self._model = TransformerLM(self._dcfg)
         # dropless expert path (ops/moe.py): its programs carry the
@@ -350,6 +353,10 @@ class ContinuousBatcher:
         self._moe_prefill_max_load_sum = 0.0
         self._lane_steps = 0          # slot-steps actually dispatched
         self._active_lane_steps = 0   # of those, slots with live requests
+        # KV positions the plain decode step had to read (live slots, up
+        # to their length) and the positions its slabs hold, per token step
+        self._kv_tokens_live = 0
+        self._kv_tokens_slab = 0
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
         # the tick ledger: engine thread only; closed (and read by
         # stats()) under _stats_lock.  No switch.
@@ -407,7 +414,7 @@ class ContinuousBatcher:
             # steps_per_sync tokens at full acceptance
             self._spec_rounds = max(1, self._T // (k + 1))
             self._draft_dcfg = dataclasses.replace(
-                draft_cfg, decode=True, attention_impl="dense", mesh=None,
+                draft_cfg, decode=True, attention_impl="dense", mesh=mesh,
                 max_len=cache_len)
             self._draft_model = TransformerLM(self._draft_dcfg)
             dsplit = _split_layer_params(draft_params, draft_cfg.num_layers)
@@ -573,7 +580,7 @@ class ContinuousBatcher:
                                    lens).compile()
             jax.block_until_ready(toks)
         self._step_jit.lower(self._cache, jnp.asarray(self._toks), key,
-                             self._params, *self._live_mask([])).compile()
+                             self._params, self._live_mask([])).compile()
         if self._chunk_tokens:
             # the program every chunked admission starts with, whatever
             # prompt class this call warms
@@ -650,6 +657,12 @@ class ContinuousBatcher:
                 # fraction of dispatched lane-steps that served a live
                 # request (the rest is free-slot ballast)
                 "slot_utilization": round(self._active_lane_steps / lanes, 3),
+                # per token step of the plain decode program, summed:
+                # KV positions live slots hold (prompt + emitted: what
+                # the step has to read) and positions in the slabs
+                # (slots x max_len: what an unmasked read touches)
+                "decode_kv_tokens_live": self._kv_tokens_live,
+                "decode_kv_tokens_slab": self._kv_tokens_slab,
                 # MoE prefill capacity overflow (always 0 for dense
                 # configs; nonzero = raise capacity_factor)
                 "moe_prefill_drops": self._moe_drops,
@@ -959,15 +972,17 @@ class ContinuousBatcher:
             return big.at[slots].set(small)
         return jax.tree.map(put, cache, slab)
 
-    def _step_impl(self, cache, toks, key, params, live=None):
+    def _step_impl(self, cache, toks, key, params, live):
         """Advance every slot ``self._T`` tokens (one dispatch).
 
-        With the dropless expert path ``live`` ([slots] bool) masks the
-        free slots out of the routing - their ballast tokens touch no
-        expert - and the layers' ``moe_stats`` ride back beside the
-        tokens: ``(cache, (tokens, stats))``.  Every other
-        configuration takes no ``live`` and returns ``(cache, tokens)``
-        from the program it always had.
+        ``live`` ([slots] bool) marks the slots that hold a request.
+        It reaches every layer as the step's ``token_mask``: on the
+        chip the decode kernels neither write nor read a free slot's
+        slab (transformer.Block._decode_attention), and the dropless
+        expert path routes free slots' ballast tokens nowhere.  With
+        that path the layers' ``moe_stats`` ride back beside the
+        tokens, ``(cache, (tokens, stats))``; otherwise ``(cache,
+        tokens)``.
 
         ``params`` is an ARGUMENT, not a closure capture: a captured
         param tree would be baked into the jaxpr as constants — 124M
@@ -983,8 +998,7 @@ class ContinuousBatcher:
             pos = self._positions(cache)
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
-                positions=pos[:, None],
-                token_mask=live[:, None] if moe else None,
+                positions=pos[:, None], token_mask=live[:, None],
                 mutable=["cache", "intermediates"] if moe else ["cache"])
             nxt = self._sample(logits[:, -1], k)
             acc = [a + _moe_stats(mut.get("intermediates")) for a in acc]
@@ -1260,7 +1274,7 @@ class ContinuousBatcher:
                         self._rng, key = jax.random.split(self._rng)
                         self._cache, dec = self._step_jit(
                             self._cache, jnp.asarray(self._toks), key,
-                            self._params, *self._live_mask(active))
+                            self._params, self._live_mask(active))
                         if self._moe_dropless:
                             dec, moe = dec
                 for slab, _, _, slots, _, lens, dslab in pres:
@@ -1730,14 +1744,11 @@ class ContinuousBatcher:
             if s.remaining == 0 or int(tok) == self._eos:
                 self._finish(slot)
 
-    def _live_mask(self, active: list[int]) -> tuple:
-        """The decode step's ``live`` argument: one [slots] bool array
-        for the dropless expert path, nothing for any other program."""
-        if not self._moe_dropless:
-            return ()
+    def _live_mask(self, active: list[int]):
+        """The decode step's ``live`` argument: [slots] bool."""
         live = np.zeros((len(self._slots),), bool)
         live[active] = True
-        return (jnp.asarray(live),)
+        return jnp.asarray(live)
 
     def _count_moe(self, moe: np.ndarray, tokens: int, decode: bool) -> None:
         """Add one program's expert counters (generate._moe_stats: a
@@ -1764,9 +1775,16 @@ class ContinuousBatcher:
         """Consume one decode chunk [slots, T].  Runs BEFORE this tick's
         _finish_prefill, so lanes filled this tick are still free here
         and never consume a chunk that predates their insert."""
+        T, cap = self._T, self._dcfg.max_len
+        # step t of the program read a live slot up to the token it
+        # appended: prompt + emitted so far + t positions
+        kv_live = sum(min(len(s.request.ids) + len(s.emitted) + t, cap)
+                      for s in self._slots if not s.free for t in range(T))
         with self._stats_lock:
-            self._lane_steps += len(self._slots) * self._T
-            self._active_lane_steps += n_active * self._T
+            self._lane_steps += len(self._slots) * T
+            self._active_lane_steps += n_active * T
+            self._kv_tokens_live += kv_live
+            self._kv_tokens_slab += len(self._slots) * cap * T
         for i, s in enumerate(self._slots):
             if s.free:      # occupied slots always have remaining >= 1
                 continue

@@ -28,6 +28,7 @@ Query (see ``request()`` below, or any TeacherClient)::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import signal
 import threading
 
@@ -66,6 +67,8 @@ def build_predict_fn(cfg, params, max_new_tokens: int, temperature: float,
         # the tp collectives (tokens match the replicated run exactly
         # — tests/test_generate_sharded.py)
         params = shard_split_params(params, mesh, cfg.num_layers)
+        # the model sees its mesh: sharded slabs decode on the einsums
+        cfg = dataclasses.replace(cfg, mesh=mesh)
 
     @jax.jit
     def gen(p, ids, rng):

@@ -1,0 +1,289 @@
+"""The one-token decode kernels (edl_tpu/ops/decode_attention.py).
+
+On the CPU the two ``pallas_call``s run in interpret mode.  Nothing
+selects them there, so every test that wants the kernel path patches
+the dispatch predicate (``decode_attention.applies``) itself: there is
+no setting to flip.  The reference is the einsum branch of
+``transformer.Block._decode_attention``, reached through the same
+model with the predicate left alone.
+"""
+
+import functools
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from edl_tpu.models import TransformerConfig, TransformerLM
+from edl_tpu.ops import decode_attention
+from edl_tpu.serving import ContinuousBatcher
+
+MAX_LEN, BLOCK = 256, 128
+# slot -> (cache_index before the step, live): a free slot first, in the
+# middle and last; lengths 1, one under / at / over a block edge,
+# max_len - 1, max_len, and an index past the end (write dropped)
+SLOTS = {
+    "free_first": (5, False),
+    "len_1": (0, True),
+    "len_block_minus_1": (BLOCK - 2, True),
+    "len_block": (BLOCK - 1, True),
+    "free_middle": (BLOCK + 7, False),
+    "len_block_plus_1": (BLOCK, True),
+    "len_max_minus_1": (MAX_LEN - 2, True),
+    "len_max": (MAX_LEN - 1, True),
+    "index_past_end": (MAX_LEN, True),
+    "free_last": (MAX_LEN - 1, False),
+}
+NAMES = list(SLOTS)
+INDEX = np.array([SLOTS[n][0] for n in NAMES], np.int32)
+LIVE = np.array([SLOTS[n][1] for n in NAMES])
+
+
+def _force_kernels(monkeypatch, block=None):
+    """Take the kernel path wherever its shapes rule holds, TPU or not
+    (interpret mode here), optionally at a smaller attend block."""
+    monkeypatch.setattr(decode_attention, "applies",
+                        lambda L, mesh, max_len: L == 1 and mesh is None)
+    if block:
+        monkeypatch.setattr(decode_attention, "attend_block",
+                            lambda *a: block)
+
+
+@functools.lru_cache(maxsize=None)
+def _one_step(G: int, dtype_name: str):
+    """One decode token step of a one-layer model over a cache full of
+    stale data, on the einsum path and on the kernel path."""
+    dtype = jnp.dtype(dtype_name)
+    Hk, D, B = 2, 16, len(NAMES)
+    cfg = TransformerConfig(vocab_size=61, num_layers=1, embed_dim=Hk * G * D,
+                            num_heads=Hk * G, num_kv_heads=Hk, mlp_dim=32,
+                            max_len=MAX_LEN, remat=False, dtype=dtype,
+                            decode=True, attention_impl="dense")
+    model = TransformerLM(cfg)
+    ids = jnp.asarray(np.arange(B)[:, None] % 61, jnp.int32)
+    init = model.init(jax.random.key(0), ids,
+                      positions=jnp.zeros((B, 1), jnp.int32))
+    kk, kv = jax.random.split(jax.random.key(1))
+    layer = init["cache"]["layer_0"]
+    cache = {"layer_0": {
+        "cached_key": jax.random.normal(kk, layer["cached_key"].shape,
+                                        dtype),
+        "cached_value": jax.random.normal(kv, layer["cached_value"].shape,
+                                          dtype),
+        "cache_index": jnp.asarray(INDEX)}}
+
+    def step():
+        return model.apply(
+            {"params": init["params"], "cache": cache}, ids,
+            positions=jnp.asarray(INDEX)[:, None],
+            token_mask=jnp.asarray(LIVE)[:, None], mutable=["cache"])
+
+    want, want_mut = step()
+    with pytest.MonkeyPatch.context() as mp:
+        _force_kernels(mp, BLOCK)
+        got, got_mut = step()
+    to_np = functools.partial(jax.tree.map, lambda a: np.asarray(
+        a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a))
+    return (to_np(cache["layer_0"]), np.asarray(want),
+            to_np(want_mut["cache"]["layer_0"]), np.asarray(got),
+            to_np(got_mut["cache"]["layer_0"]))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("G", [4, 1], ids=["gqa4", "mha"])
+@pytest.mark.parametrize("slot", NAMES)
+def test_kernel_step_matches_the_einsum_step(slot, G, dtype):
+    before, want, want_cache, got, got_cache = _one_step(G, dtype)
+    b = NAMES.index(slot)
+    at, live = SLOTS[slot]
+    assert got_cache["cache_index"][b] == at + 1
+    k, v = got_cache["cached_key"][b], got_cache["cached_value"][b]
+    if not live or at >= MAX_LEN:
+        # a free slot, or a write past the end: the slab is untouched
+        np.testing.assert_array_equal(k, before["cached_key"][b])
+        np.testing.assert_array_equal(v, before["cached_value"][b])
+    else:
+        # exactly the einsum path's slab: its new column, nothing else
+        np.testing.assert_array_equal(k, want_cache["cached_key"][b])
+        np.testing.assert_array_equal(v, want_cache["cached_value"][b])
+        rest = np.arange(MAX_LEN) != at
+        np.testing.assert_array_equal(k[:, :, rest],
+                                      before["cached_key"][b][:, :, rest])
+        np.testing.assert_array_equal(v[:, rest],
+                                      before["cached_value"][b][:, rest])
+        assert (k[:, :, at] != before["cached_key"][b][:, :, at]).any()
+    assert np.isfinite(got[b]).all()
+    if live:    # a free slot's output is ignored
+        tol = 1e-5 if dtype == "float32" else 3e-2
+        np.testing.assert_allclose(got[b], want[b], atol=tol, rtol=tol)
+
+
+def test_a_batch_of_free_slots_reads_nothing_and_returns_zeros():
+    q = jnp.ones((3, 4, 16), jnp.float32)
+    k = jnp.full((3, 2, 16, MAX_LEN), jnp.nan, jnp.float32)
+    v = jnp.full((3, 2, MAX_LEN, 16), jnp.nan, jnp.float32)
+    out = decode_attention.decode_attend(
+        q, k, v, jnp.zeros((3,), jnp.int32), block=BLOCK)
+    np.testing.assert_array_equal(np.asarray(out), 0.0)
+
+
+@pytest.mark.parametrize("lengths,src,lo,hi", [
+    ([0, 300, 0, 0, 129, 0], [1, 1, 1, 1, 4, 4], [0, 0, 2, 2, 0, 1],
+     [0, 2, 2, 2, 1, 1]),
+    ([0, 0, 0], [0, 0, 0], [0, 0, 0], [0, 0, 0]),
+    ([128, 1], [0, 1], [0, 0], [0, 0]),
+])
+def test_fetch_plan_points_free_slots_at_the_block_already_held(
+        lengths, src, lo, hi):
+    got = decode_attention._fetch_plan(jnp.asarray(lengths, jnp.int32),
+                                       BLOCK)
+    assert [np.asarray(a).tolist() for a in got] == [src, lo, hi]
+
+
+@pytest.mark.parametrize("Hk,max_len,dtype,want", [
+    (8, 8192, jnp.bfloat16, 1024),      # the Mistral serve cells
+    (16, 4096, jnp.bfloat16, 512),      # the OLMoE serve cell
+    (2, 384, jnp.float32, 128),         # 384 = 3 x 128: no larger divisor
+    (6, 2048, jnp.bfloat16, 1024),      # the 12 x 768 flagship
+])
+def test_attend_block_divides_max_len_within_the_vmem_budget(
+        Hk, max_len, dtype, want):
+    assert decode_attention.attend_block(Hk, 128, max_len, dtype) == want
+
+
+def test_dispatch_rule_is_shape_mesh_and_backend_only(monkeypatch):
+    applies = decode_attention.applies
+    assert not applies(1, None, 256)            # this backend is no TPU
+    monkeypatch.setattr(decode_attention, "_on_tpu", lambda: True)
+    assert applies(1, None, 256)
+    assert not applies(2, None, 256)            # verify pass, prefill
+    assert not applies(1, object(), 256)        # a mesh engine's slabs
+    assert not applies(1, None, 200)            # time axis not lane-tiled
+
+
+def _toy_engine_tokens(monkeypatch, kernels: bool):
+    cfg = TransformerConfig(vocab_size=97, num_layers=2, embed_dim=32,
+                            num_heads=4, num_kv_heads=2, mlp_dim=64,
+                            max_len=128, remat=False, dtype=jnp.float32)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    if kernels:
+        _force_kernels(monkeypatch)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 97, (n,)).astype(np.int32)
+               for n in (5, 9, 3)]
+    eng = ContinuousBatcher(cfg, params, slots=2, prefill_buckets=(8, 16),
+                            temperature=0.0, steps_per_sync=4, kv_block=0,
+                            prefill_chunk=0)
+    try:
+        # two slots, three requests: the 3-token answer finishes inside
+        # the first program and its slot is re-admitted while the other
+        # slot is still mid-answer
+        futs = [eng.submit(p, n) for p, n in zip(prompts, (8, 3, 8))]
+        out = [f.result(timeout=300).tolist() for f in futs]
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    return out, stats
+
+
+def test_toy_engine_gives_the_einsum_paths_tokens_through_a_readmission(
+        monkeypatch):
+    want, _ = _toy_engine_tokens(monkeypatch, kernels=False)
+    got, stats = _toy_engine_tokens(monkeypatch, kernels=True)
+    assert got == want and [len(t) for t in got] == [8, 3, 8]
+    # the counters say what a step has to read, and what the slabs hold
+    assert 0 < stats["decode_kv_tokens_live"] < stats["decode_kv_tokens_slab"]
+    assert stats["decode_kv_tokens_slab"] % (2 * 128 * 4) == 0
+
+
+# -- compiled for the chip, without the chip ---------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:  # noqa: BLE001 — any failure means: not here
+        pytest.skip(f"no v5e:1x1 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_step_program_for_v5e_holds_no_whole_slab_temporary(
+        one_chip, monkeypatch):
+    """``ContinuousBatcher._step_impl`` at the Mistral serve widths (two
+    layers, 12 slots x 8192 tokens, 4 token steps), compiled ahead of
+    time for one v5e chip from abstract shapes: no engine, no slabs on
+    this host.  The einsum path compiled the same way keeps 0.81 GB of
+    temporaries, a second copy of every slab, and re-lays each slab out
+    at the program's entry and exit; a read kernel beside an XLA scatter
+    moves those copies INSIDE the token loop.  Neither may come back."""
+    import types
+
+    from edl_tpu.models.generate import sample_logits
+
+    # what applies() asks of the backend, answered for the described chip
+    monkeypatch.setattr(decode_attention, "_on_tpu", lambda: True)
+    B, T, Hk, max_len = 12, 4, 8, 8192
+    cfg = TransformerConfig(
+        vocab_size=32768, num_layers=2, embed_dim=4096, num_heads=32,
+        num_kv_heads=Hk, mlp_dim=14336, max_len=max_len, rope_theta=1e6,
+        norm_eps=1e-5, decode=True, attention_impl="dense")
+    model = TransformerLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((B, 1), jnp.int32),
+        positions=jnp.zeros((B, 1), jnp.int32)))
+
+    def on_chip(s, dtype=None):
+        return jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
+                                    sharding=one_chip)
+
+    # the part of an engine the step reads, and nothing it would allocate
+    engine = types.SimpleNamespace(
+        _model=model, _T=T, _moe_dropless=False, _moe_acc_shape=None,
+        _positions=ContinuousBatcher._positions,
+        _sample=lambda logits, key: sample_logits(logits, key,
+                                                  temperature=0.0))
+
+    def _step_impl(*args):
+        return ContinuousBatcher._step_impl(engine, *args)
+
+    # an AOT executable can be written to a compile cache but not read
+    # back without a chip: keep it out (and the warning with it)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(_step_impl, donate_argnums=(0,)).lower(
+            jax.tree.map(on_chip, shapes["cache"]),
+            on_chip(jax.ShapeDtypeStruct((B,), jnp.int32)),
+            on_chip(jax.eval_shape(lambda: jax.random.key(0))),
+            jax.tree.map(lambda s: on_chip(s, jnp.bfloat16),
+                         shapes["params"]),
+            on_chip(jax.ShapeDtypeStruct((B,), jnp.bool_))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    slab = re.compile(rf"\[{B},{Hk},(128,{max_len}|{max_len},128)\]")
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
+        if not m or not slab.search(m.group(2)):
+            continue
+        name, op = m.group(1), m.group(3)
+        # plumbing moves nothing; the append kernel's results ARE its
+        # arguments (aliased)
+        if op in ("parameter", "get-tuple-element", "tuple", "while",
+                  "bitcast") or (op == "custom-call"
+                                 and name.startswith("decode_append")):
+            continue
+        moved.append(f"{op} {name}")
+    assert not moved, moved
+    text = compiled.as_text()
+    assert "decode_append" in text and "decode_attend" in text

@@ -223,7 +223,7 @@ def test_no_dispatch_tensor_and_no_weight_gather_in_the_engines_programs(
             key = jax.random.key(0)
             step = eng._step_jit.trace(
                 eng._cache, jnp.asarray(eng._toks), key, eng._params,
-                *eng._live_mask([0])).jaxpr
+                eng._live_mask([0])).jaxpr
             prefill = eng._prefill_fn(32, 2).trace(
                 eng._params, jnp.zeros((2, 32), jnp.int32),
                 jnp.ones((2,), jnp.int32), key).jaxpr
