@@ -178,8 +178,7 @@ def spawn_subprocess(port: int, data_dir: str,
                      restart_grace: float | None = None,
                      host: str = "127.0.0.1", env: dict | None = None):
     """Spawn ``python -m edl_tpu.coord.server`` as a subprocess — the
-    SIGKILL-able real thing the chaos smoke and the coord-outage bench
-    both drill (one spawner, so they measure the same setup)."""
+    SIGKILL-able real thing the chaos smoke drills."""
     import subprocess
     import sys
 

@@ -113,9 +113,7 @@ def device_put_stream(batches: "Iterator[dict]", put: Callable[[dict], object],
     """Double-buffered device staging: run ``put`` (``jax.device_put``,
     ``shard_host_batch``, ...) on batch k+1 in a background thread
     while the caller consumes batch k, so H2D of the next batch
-    overlaps decode/compute on the current one — the
-    dispatch-pipelining trick doc/perf.md's bench applies, now on the
-    data-service input path.
+    overlaps decode/compute on the current one.
 
     Yields ``(device_batch, spans)`` with the ``SPANS_KEY`` metadata
     split out BEFORE the put: record spans must stay host-side, and

@@ -60,8 +60,7 @@ class StudentFeed:
         for batch in feed:
             ...
 
-    ``submitted_rows``/``consumed_rows`` are exposed for tests and for
-    the bench's backlog-latency measurement.  The feed counts rows as
+    ``submitted_rows``/``consumed_rows`` are exposed for tests.  The feed counts rows as
     they stream INTO the pool (the wrapped input generator) and OUT of
     it (yielded batches) — the difference is the backlog.
     """

@@ -10,7 +10,7 @@ constants, excluding docstrings: the set of names the process can
 actually read) must appear in at least one doc file, and every name a
 doc file teaches must still exist somewhere in the repo's code (tests/
 examples/scripts/k8s count — a knob may be exercised only there).
-Docs may use a trailing ``*`` wildcard (``EDL_TPU_BENCH_*``) to cover
+Docs may use a trailing ``*`` wildcard (``EDL_TPU_DEMO_*``) to cover
 a family.
 
 **metric-drift** — every metric name registered through
@@ -36,8 +36,7 @@ _DERIVED_SUFFIXES = ("_bucket", "_count", "_sum")
 # repo-wide existence scan for the stale-doc direction (a knob may be
 # exercised only by tests, smokes, or deployment manifests)
 _EXISTENCE_GLOBS = ("edl_tpu/**/*.py", "tests/**/*.py", "scripts/**/*.py",
-                    "examples/**/*.py", "bench.py", "k8s/*.yaml",
-                    "docker/*")
+                    "examples/**/*.py", "k8s/*.yaml", "docker/*")
 
 
 def _docstring_nodes(tree: ast.AST) -> set[int]:

@@ -29,13 +29,10 @@ from typing import Callable, Iterable
 
 # directories scanned for code-level checks, relative to the repo root
 PACKAGE_DIRS = ("edl_tpu",)
-# single files outside the package that still carry wire/knob surface
-EXTRA_FILES = ("bench.py",)
 # documentation set for the catalog cross-checks
 DOC_FILES = ("README.md", "doc/usage.md", "doc/observability.md",
              "doc/robustness.md", "doc/memstate.md", "doc/serving.md",
-             "doc/design.md", "doc/perf.md", "doc/lint.md",
-             "doc/distill.md")
+             "doc/design.md", "doc/lint.md", "doc/distill.md")
 
 _DISABLE_RE = re.compile(r"edl-lint:\s*disable=([a-z0-9_,\-]+|all)")
 
@@ -115,8 +112,7 @@ class Project:
     """Everything the checks need, parsed once."""
 
     def __init__(self, root: str | Path,
-                 package_dirs: Iterable[str] = PACKAGE_DIRS,
-                 extra_files: Iterable[str] = EXTRA_FILES):
+                 package_dirs: Iterable[str] = PACKAGE_DIRS):
         self.root = Path(root).resolve()
         self.sources: list[Source] = []
         self.parse_failures: list[Finding] = []
@@ -125,10 +121,6 @@ class Project:
             base = self.root / d
             if base.is_dir():
                 paths.extend(sorted(base.rglob("*.py")))
-        for f in extra_files:
-            p = self.root / f
-            if p.is_file():
-                paths.append(p)
         for p in paths:
             try:
                 self.sources.append(Source(p, self.root))

@@ -154,7 +154,7 @@ def active_matmul_params(cfg: TransformerConfig) -> int:
             + cfg.embed_dim * cfg.vocab_size)
 
 
-# Calibrated on v5e (doc/perf.md): the flagship (12L x 768, seq 1024)
+# Calibrated on v5e: the flagship (12L x 768, seq 1024)
 # trains without remat at bs 8 (~9 GB estimated, fits 16 GB) and OOMs
 # by ~0.9 GB at bs 16 (~16.5 GB estimated) — both predicted correctly
 # by ~48 bf16-equivalent activation values per token x layer x embed.

@@ -26,7 +26,7 @@ Ring capacity is ``EDL_TPU_FLIGHTREC_RING`` events (logs at half
 that); eviction is the deque dropping the oldest record, counted in
 ``edl_flightrec_evicted_total``.  ``EDL_TPU_FLIGHTREC=0`` disables the
 recorder entirely.  The hot path is one deque append + one counter
-bump per event, bench-gated under 2 % (``flightrec_overhead_pct``).
+bump per event.
 """
 
 from __future__ import annotations

@@ -1,11 +1,6 @@
-"""Shared FLOP accounting for MFU: one source of truth for bench AND
-the trainer's live gauges.
-
-Before this module, the peak-TFLOPS table, the XLA cost-analysis FLOP
-count, and the analytic transformer FLOP formula lived inside
-``edl_tpu/bench.py`` — which meant MFU existed only in the one-shot
-bench artifact and the trainer could not publish it continuously
-without duplicating (and drifting from) that logic.  Three helpers:
+"""FLOP accounting for MFU: the peak-TFLOPS table, the XLA
+cost-analysis FLOP count and the analytic transformer FLOP formula
+behind the trainer's live gauges.  Four helpers:
 
 - :func:`peak_tflops` — bf16 peak per chip from the device kind
   (longest-match against :data:`PEAK_TFLOPS`; ``EDL_TPU_PEAK_TFLOPS``
@@ -14,7 +9,7 @@ without duplicating (and drifting from) that logic.  Three helpers:
   XLA's cost analysis (the whole module, all devices), ``None`` when
   the backend can't answer.  Caveat: a model running layers under
   ``lax.scan`` counts the loop body ONCE — use the analytic count for
-  those (the bench's LM section measured 0.70 "TFLOP"/step vs ~7 real);
+  those (a scanned LM step read 0.70 "TFLOP" vs ~7 real);
 - :func:`analytic_lm_flops_per_token` — the PaLM-appendix transformer
   accounting (6·N matmul params + 6·layers·seq·d_model causal
   attention per token) from four sizes, dense MHA only;
@@ -23,9 +18,9 @@ without duplicating (and drifting from) that logic.  Three helpers:
   the ACTIVE parameters (router + ``moe_top_k`` gated or ungated
   experts a token), not all of them.
 
-``mfu = achieved_tflops / peak_tflops``; both bench sections and the
-trainer's ``edl_mfu`` / ``edl_tflops_per_chip`` gauges
-(``train/trainer.py``) compute it through here so they cannot drift.
+``mfu = achieved_tflops / peak_tflops``; the trainer's ``edl_mfu`` /
+``edl_tflops_per_chip`` gauges (``train/trainer.py``) compute it
+through here.
 """
 
 from __future__ import annotations

@@ -271,9 +271,8 @@ def get_tracer() -> NullTracer | Tracer:
 
 
 def install(tracer: NullTracer | Tracer) -> NullTracer | Tracer:
-    """Swap the process-wide tracer, returning the previous one (the
-    bench's tracing-on/off comparison and tests save/restore with this
-    instead of poking the module global)."""
+    """Swap the process-wide tracer, returning the previous one (tests
+    save/restore with this instead of poking the module global)."""
     global _tracer
     with _lock:
         prev = _tracer

@@ -22,7 +22,7 @@ The bubble is the classic GPipe (S-1)/(M+S-1); raise
 lockstep (see the in-body NOTE for why branching it away is unsound
 with tp collectives inside the stage).
 
-Why not 1F1B: measured (doc/perf.md "Pipeline schedule") — with a
+Why not 1F1B: measured (``examples/lm/pp_bench.py``) — with a
 fixed global batch the AD-unrolled schedule's activation live-set is
 FLAT-to-decreasing in M (per-tick stash shrinks as 1/M), so 1F1B's
 memory cap buys under ~20% at sensible M while sharing GPipe's bubble;

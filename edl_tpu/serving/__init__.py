@@ -10,7 +10,7 @@ __all__ = ["ContinuousBatcher", "ReplicaServer", "publish_engine_stats"]
 
 def __getattr__(name):
     # ReplicaServer pulls in the RPC/coord layers; keep `import
-    # edl_tpu.serving` light for engine-only users (bench, serve_lm)
+    # edl_tpu.serving` light for engine-only users (serve_lm)
     if name in ("ReplicaServer", "publish_engine_stats"):
         from edl_tpu.serving import replica
         return getattr(replica, name)

@@ -70,13 +70,12 @@ _EXAMPLES_TOTAL = obs_metrics.counter(
 _EPOCHS_TOTAL = obs_metrics.counter(
     "edl_train_epochs_total", "Completed epochs")
 
-# live MFU: XLA cost-analysis FLOPs (obs/flops.py — the same count
-# bench.py reports) over the step-time EMA, published continuously so
-# utilization is a scrape away instead of a bench artifact away
+# live MFU: XLA cost-analysis FLOPs (obs/flops.py) over the step-time
+# EMA, published continuously so utilization is a scrape away
 _TFLOPS_G = obs_metrics.gauge(
     "edl_tflops_per_chip",
     "Achieved TFLOP/s per chip from XLA cost analysis over the "
-    "step-time EMA (train/trainer.py; shares obs/flops.py with bench)")
+    "step-time EMA (train/trainer.py, obs/flops.py)")
 _MFU_G = obs_metrics.gauge(
     "edl_mfu",
     "Model FLOPs utilization: edl_tflops_per_chip / the chip's known "
@@ -828,7 +827,7 @@ class ElasticTrainer:
 
     def _compute_flops(self, state, gbatch, rng) -> None:
         """FLOPs of one compiled step from XLA cost analysis — once per
-        step function (obs/flops.py, the same count bench reports).
+        step function (obs/flops.py).
 
         Runs on a BACKGROUND daemon thread: the AOT ``lower().compile()``
         path does not share the jit dispatch cache (measured: a full
@@ -842,9 +841,8 @@ class ElasticTrainer:
         its old backend mid-reshard (train/distributed.leak_world —
         peers hang on our open gloo sockets otherwise), and a thread
         mid-compile cannot be swept.  So live MFU is skipped when the
-        delta path is armed — phase ledger and goodput still run; the
-        bench artifact still reports MFU for the model.  The result
-        lands only if the step function is still the one it was
+        delta path is armed — phase ledger and goodput still run.  The
+        result lands only if the step function is still the one it was
         computed for.  Gated with the ledger (a test's
         ``enabled=False``); 0.0 = pending-or-unanswerable, so there is
         no per-step retry."""

@@ -1,6 +1,6 @@
 """Pipeline-schedule measurement: step time + compiled activation
-memory vs microbatch count — the numbers behind doc/perf.md
-"Pipeline schedule: why GPipe-via-AD is the right stop".
+memory vs microbatch count — why GPipe-via-AD is the right stop
+(``edl_tpu/ops/pipeline.py``).
 
 Runs the pipelined TransformerLM (`train_lm._PipelinedLM`, GPipe over
 ppermute with the backward from jax.grad) at each requested pp and
@@ -14,7 +14,7 @@ The headline result (fixed GLOBAL batch): temp memory is
 flat-to-DECREASING in M, because the per-tick stash shrinks as 1/M
 while ticks grow as M+S-1 — so 1F1B's in-flight cap would buy little
 while sharing GPipe's bubble, and raising M amortises the bubble for
-free.  See doc/perf.md for a recorded run.
+free.
 """
 
 from __future__ import annotations

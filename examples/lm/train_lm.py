@@ -230,8 +230,7 @@ def main() -> None:
                              "ring: ring applies its own shard_map over "
                              "sp and nesting it inside the pipeline's "
                              "manual-over-pp shard_map fails jax's nested "
-                             "axis checks (measured attempt in doc/perf.md "
-                             "'Pipeline schedule'); use auto/dense/flash")
+                             "axis checks; use auto/dense/flash")
         if args.layers % args.pp:
             raise SystemExit(f"--layers {args.layers} must divide evenly "
                              f"over --pp {args.pp} stages")

@@ -50,7 +50,7 @@ JAX_PLATFORMS=cpu python scripts/gateway_smoke.py
 
 # kv cache smoke: the paged KV cache's three contracts — paged-vs-
 # unpaged greedy outputs byte-identical over a mixed shared-prefix +
-# divergent-session workload; the heavy-prefix bench section gates
+# divergent-session workload; the heavy-prefix microbench gates
 # prefix-hit tokens/s >= cold tokens/s with prefill-skipped frac > 0.5
 # and a real migration latency; and a SIGTERM-drain of a replica
 # PROCESS under sustained sessions loses zero accepted requests while
@@ -189,22 +189,15 @@ JAX_PLATFORMS=cpu python scripts/data_throughput_smoke.py
 # and 100+ prompts bit-identical spec vs plain greedy
 JAX_PLATFORMS=cpu python scripts/serving_perf_smoke.py
 
-# bench refuses the CPU: every key it prints is a device metric, and a
-# CPU run under those names would be read as one (ROADMAP S0).  It must
-# exit non-zero here and print nothing that could pass for a result.
-# (The control-plane asserts this stage used to make on a CPU bench run
-# are the smoke stages above: resize, delta failover, kv cache, serving
-# perf, alerts, postmortem, distill chaos.)
-if JAX_PLATFORMS=cpu python bench.py > /tmp/edl-bench.out 2>/dev/null; then
-    echo "bench.py exited 0 on the CPU"; exit 1
-fi
-[ ! -s /tmp/edl-bench.out ] || { echo "bench.py printed on the CPU:"; \
-    cat /tmp/edl-bench.out; exit 1; }
+# chip_smoke refuses the CPU: every key it prints is a device metric, and
+# a CPU run under those names would be read as one.  It must exit
+# non-zero here and print nothing that could pass for a result.
+# (tests/test_benchmark_entry.py holds benchmarks/run.py to the same.)
 if JAX_PLATFORMS=cpu python chip_smoke.py > /tmp/edl-smoke.out 2>/dev/null; then
     echo "chip_smoke.py exited 0 on the CPU"; exit 1
 fi
 grep -q '"ok"' /tmp/edl-smoke.out && { echo "chip_smoke.py printed a result on the CPU"; exit 1; }
-echo "bench/chip_smoke refuse the CPU OK"
+echo "chip_smoke refuses the CPU OK"
 
 # packaging sanity: console scripts resolve
 edl-lint --help >/dev/null 2>&1 || { echo "edl-lint missing"; exit 1; }
@@ -219,7 +212,7 @@ edl-gateway --help >/dev/null 2>&1 || { echo "edl-gateway missing"; exit 1; }
 edl-replica --help >/dev/null 2>&1 || { echo "edl-replica missing"; exit 1; }
 
 # doc drift: every CLI the operator guide teaches must exist
-for cmd in edl-coord edl-launch edl-controller edl-discovery edl-bench \
+for cmd in edl-coord edl-launch edl-controller edl-discovery \
            edl-obs-dump edl-obs-agg edl-obs-top edl-obs-bundle \
            edl-gateway edl-replica edl-lint; do
     grep -q "$cmd" doc/usage.md || { echo "doc/usage.md missing $cmd"; exit 1; }

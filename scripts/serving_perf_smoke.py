@@ -21,7 +21,7 @@ parity contract the base gateway smoke proves:
 
 Emits one JSON artifact line (``serving_mesh_tokens_s``,
 ``serving_prefill_p99_ms``, ``serving_spec_accept_rate``, ...) so the
-driver can track the fast path like any bench section.
+driver can track the fast path.
 
 Run by scripts/ci.sh:  JAX_PLATFORMS=cpu python scripts/serving_perf_smoke.py
 """

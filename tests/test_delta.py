@@ -394,7 +394,7 @@ def test_torn_chain_demotes_to_peer_full_then_storage(memkv, tmp_path):
 
 def test_replicator_diffs_only_changed_shards(memkv, tmp_path):
     """Record 2 carries only the keys whose CRC changed since record 1
-    (the bytes/step vs full-shard win the bench section measures)."""
+    (the bytes/step vs full-shard win)."""
     pods = _two_pods(memkv)
     try:
         state, _abstract = _state_and_abstract()

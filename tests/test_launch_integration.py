@@ -105,15 +105,30 @@ def test_elastic_recovery_overwrites_failed_flag(coord, tmp_path):
     flips the job to SUCCEED — elastic recovery must not read as failure."""
     ep, client = coord
     tmp = str(tmp_path)
+    marker_a = os.path.join(tmp, "marker-a.txt")
+    # a's two-pod trainer must outlast b's slowest path to its exit (when
+    # b leads, its launcher hosts the world service before it spawns the
+    # trainer that fails, then waits out the fail grace), or a finishes
+    # SUCCEED as a non-leader and nobody overwrites b's FAILED.  When b
+    # leads, its leaving restarts a solo at once; when a leads, a's
+    # launcher tries a live reshard first, which the inert trainer ends
+    # only by serving its sleep.
     a = spawn_launcher("j-recover", ep, tmp, "a", "1:2",
-                       {"EDL_TPU_DEMO_SLEEP": "6", "EDL_TPU_DEMO_SLEEP_SOLO": "6"})
+                       {"EDL_TPU_DEMO_SLEEP": "30", "EDL_TPU_DEMO_SLEEP_SOLO": "2",
+                        "EDL_TPU_DEMO_MARKER": marker_a})
     b = spawn_launcher("j-recover", ep, tmp, "b", "1:2",
                        {"EDL_TPU_DEMO_SLEEP": "1", "EDL_TPU_DEMO_SLEEP_SOLO": "1",
                         "EDL_TPU_DEMO_EXIT_CODE": "7"})
     rb = finish(b, 60)
     ra = finish(a, 90)
     assert rb == 1 and ra == 0, _dump_logs(tmp)
-    assert load_job_status(client, "j-recover") == Status.SUCCEED
+    assert load_job_status(client, "j-recover") == Status.SUCCEED, \
+        _dump_logs(tmp)
+    # a ran beside b, then restarted solo after b left: the docstring's
+    # scenario, whichever pod led the first stage
+    starts_a = open(marker_a).read().strip().splitlines()
+    assert len(starts_a) == 2, (starts_a, _dump_logs(tmp))
+    assert "world=2" in starts_a[0] and "world=1" in starts_a[1], starts_a
 
 
 def test_elastic_scale_out_restarts_trainers(coord, tmp_path):

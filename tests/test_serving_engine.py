@@ -274,9 +274,8 @@ def test_mixed_load_decode_not_starved(small):
     liveness, ``requests_done`` proves the churn was real, and the
     wall-clock ratio is a wide LOAD-TOLERANT backstop only (ISSUE 13
     deflake: the old 2x bound tripped under the full tier-1 suite on a
-    1-core box purely from host scheduler jitter; the >=0.8
-    device-class ratio is measured on real hardware by bench.py's
-    engine section, not here)."""
+    1-core box purely from host scheduler jitter; a device-class
+    ratio is a chip measurement, not this test's)."""
     import time as _t
 
     cfg, params = small

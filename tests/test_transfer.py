@@ -376,29 +376,6 @@ def test_restore_from_old_peer_without_streaming(memkv):
         srv.stop()
 
 
-# -- bench never measures a CPU under a device metric's name ------------------
-def test_bench_propagates_backend_init_error(monkeypatch, capsys):
-    """A backend that cannot initialise must end the benchmark, not be
-    traded for the CPU platform: ``RuntimeError: Unable to initialize
-    backend`` leaves ``bench.main`` as raised, nothing is printed that
-    could pass for a result, and a CPU-only host is refused outright."""
-    import jax
-
-    from edl_tpu import bench
-
-    def broken():
-        raise RuntimeError("Unable to initialize backend 'tpu': UNAVAILABLE")
-
-    monkeypatch.setattr(jax, "devices", broken)
-    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
-        bench.main()
-    assert capsys.readouterr().out == ""
-    monkeypatch.undo()
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench.main()
-    assert capsys.readouterr().out == ""
-
-
 # -- the connect-outside-the-lock regression ----------------------------------
 def test_dead_endpoint_does_not_serialize_concurrent_callers(monkeypatch):
     """PR-2 bug: RpcClient.call held the client lock across _connect,
