@@ -7,8 +7,10 @@ mesh from day one:
 - logical axes on every weight (megatron TP on ``tp``, zero-style
   ``fsdp``, sequence shards on ``sp``) — ``LOGICAL_RULES`` feeds
   ``ElasticTrainer.create_state``;
-- activations constrained to ("batch", "seq", "embed") so XLA places
-  the collectives, not us;
+- training activations pinned to the batch axes by logical names
+  (``_pin``: the residual stream ``("batch", "seq", None)``, projections
+  ``("batch", "seq", "heads" | "mlp")``): XLA still places the
+  collectives, but between chips it moves weights, never a whole batch;
 - ``lax.scan`` over stacked layer params (one compile for N layers) with
   optional ``jax.checkpoint`` rematerialisation;
 - attention dispatch from :mod:`edl_tpu.ops.attention` (XLA dense /
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 
 from edl_tpu.ops import decode_attention
 from edl_tpu.ops.attention import dot_product_attention
+from edl_tpu.parallel.sharding import logical_constraint
 
 # param-path regex → logical axes (ElasticTrainer.create_state consumes)
 LOGICAL_RULES = [
@@ -216,6 +219,23 @@ def rope(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _pin(cfg: "TransformerConfig", x, *axes):
+    """Say where a training activation lives, by logical axis names.
+
+    Weights carry ``embed -> fsdp``; an activation never does.  Left to
+    choose, GSPMD keeps a weight's ``fsdp`` shard in place, gathers the
+    ACTIVATION to the whole batch and all-reduces every matmul's product
+    between chips (PERF.md section 6, PR 29).  With the activation fixed
+    on the batch axes the one way left to compute ``y @ W`` is to gather
+    ``W``: ZeRO-3.  The same names give ``tp`` its Megatron layout
+    (``heads`` / ``mlp``) and ``sp`` its sequence shards.  Nothing is
+    emitted without a mesh (``parallel/sharding.logical_constraint``)
+    or in a decode model, whose layouts the engine owns."""
+    if cfg.decode:
+        return x
+    return logical_constraint(x, axes, cfg.mesh)
+
+
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
     eps: float = 1e-6
@@ -354,10 +374,12 @@ class Block(nn.Module):
         H, Dh = cfg.num_heads, cfg.head_dim
         Hk = cfg.kv_heads
         assert H % Hk == 0, f"num_heads {H} not divisible by kv heads {Hk}"
+        x = _pin(cfg, x, "batch", "seq", None)
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
         qkv = nn.DenseGeneral(((H + 2 * Hk) * Dh,), use_bias=False,
                               dtype=cfg.dtype, param_dtype=jnp.float32,
                               name="attn_qkv")(y)
+        qkv = _pin(cfg, qkv, "batch", "seq", "heads")
         q, k, v = jnp.split(qkv, [H * Dh, (H + Hk) * Dh], axis=-1)
         if cfg.qk_norm:
             # over the whole projection, before the split into heads;
@@ -378,9 +400,10 @@ class Block(nn.Module):
             attn = dot_product_attention(q, k, v, causal=True,
                                          impl=cfg.attention_impl,
                                          mesh=cfg.mesh)
-        attn = attn.reshape(B, L, H * Dh)
+        attn = _pin(cfg, attn.reshape(B, L, H * Dh), "batch", "seq", "heads")
         x = x + nn.DenseGeneral(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                                 param_dtype=jnp.float32, name="attn_out")(attn)
+        x = _pin(cfg, x, "batch", "seq", None)
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="mlp_norm")(x)
         if cfg.moe_experts:
             from edl_tpu.ops.moe import MoEMLP
@@ -391,15 +414,16 @@ class Block(nn.Module):
                             gated=cfg.moe_gated,
                             norm_topk=cfg.moe_norm_topk,
                             name="moe")(y, token_mask)
-            return x + y, aux
+            return _pin(cfg, x + y, "batch", "seq", None), aux
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                         param_dtype=jnp.float32, name="mlp_gate")(y)
         up = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                       param_dtype=jnp.float32, name="mlp_in")(y)
-        y = nn.silu(gate) * up
+        y = nn.silu(_pin(cfg, gate, "batch", "seq", "mlp")) * _pin(
+            cfg, up, "batch", "seq", "mlp")
         x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                          param_dtype=jnp.float32, name="mlp_out")(y)
-        return x, None
+        return _pin(cfg, x, "batch", "seq", None), None
 
 
 class TransformerLM(nn.Module):
@@ -423,6 +447,7 @@ class TransformerLM(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
         x = nn.Embed(cfg.vocab_size, cfg.embed_dim, param_dtype=jnp.float32,
                      dtype=cfg.dtype, name="tok_embed")(ids)
+        x = _pin(cfg, x, "batch", "seq", None)
 
         if cfg.decode:
             # unrolled layers with SEPARATE per-layer caches: inside the
@@ -447,7 +472,8 @@ class TransformerLM(nn.Module):
                             in_axes=nn.broadcast, metadata_params={},
                             unroll=1 if cfg.scan_layers else cfg.num_layers)
             x, aux = Stack(cfg, name="layers")(x, positions, token_mask)
-        x = RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x)
+        x = _pin(cfg, RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x),
+                 "batch", "seq", None)
         aux_total = (jnp.mean(aux) if aux is not None
                      else jnp.zeros((), jnp.float32))
         if return_hidden:

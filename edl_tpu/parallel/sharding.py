@@ -6,6 +6,20 @@ axes, so the same model code runs pure-DP, FSDP, TP, or any mix by
 swapping the rules — the TPU-native analog of the reference switching
 Fleet DistributedStrategy knobs (train_with_fleet.py:85-111) without
 touching model code.
+
+Weights carry ``embed -> fsdp``; activations never do.  A model names
+its activations' axes too (``logical_constraint``; the transformer's
+residual stream is ``("batch", "seq", None)``, its projections
+``("batch", "seq", "heads" | "mlp")``), because for ``y[B/n, L, D] @
+W[D/n, M]`` the partitioner is otherwise free to keep ``W``'s shard in
+place, gather ``y`` to the whole batch and all-reduce the product:
+tensor parallelism over the ``fsdp`` axis, a whole-batch all-reduce for
+every matmul (a quarter of the four-chip step, PERF.md section 6,
+PR 29).  With the activation fixed on the batch axes the only way left
+is to gather ``W`` and reduce-scatter its gradient: ZeRO-3.  The same
+names resolve to ``tp`` for Megatron's layout and to ``sp`` for
+sequence shards; on one device every spec is empty and no constraint is
+emitted.
 """
 
 from __future__ import annotations
@@ -81,9 +95,15 @@ def logical_sharding(logical_axes: tuple[str | None, ...], mesh: Mesh,
     return NamedSharding(mesh, rules.spec(logical_axes, mesh))
 
 
-def logical_constraint(x, logical_axes: tuple[str | None, ...], mesh: Mesh,
+def logical_constraint(x, logical_axes: tuple[str | None, ...],
+                       mesh: Mesh | None,
                        rules: ShardingRules | None = None):
-    """``with_sharding_constraint`` by logical names; no-op outside jit."""
+    """``with_sharding_constraint`` by logical names.  Without a mesh, or
+    on a mesh of one device, nothing is emitted: ``x`` comes back as it
+    is, so a model that names its activations' axes traces to the same
+    program there as one that does not."""
+    if mesh is None or mesh.size == 1:
+        return x
     return jax.lax.with_sharding_constraint(
         x, logical_sharding(logical_axes, mesh, rules))
 
