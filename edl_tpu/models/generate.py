@@ -225,7 +225,9 @@ def _moe_stats(intermediates) -> "jax.Array":
     ``[drops, assignments, experts touched, load ratios, layer calls]``:
     each layer call's ``moe_stats`` summed, and how many there were
     (ops/moe.py; every entry is a count or a small ratio, exact in
-    float32 at serving sizes)."""
+    float32 at serving sizes).  Layers that hold a share of their
+    experts sow a fourth entry, and the vector is ``[drops, assignments,
+    experts touched, load ratios, pairs routed, layer calls]``."""
     import jax.numpy as jnp
 
     leaves = [leaf for path, leaf in
@@ -234,8 +236,9 @@ def _moe_stats(intermediates) -> "jax.Array":
     drops = _sum_drops(intermediates)
     if not leaves:
         return drops
-    total = sum(jnp.asarray(leaf, jnp.float32).reshape(-1, 3).sum(0)
+    width = leaves[0].shape[-1]         # 3, or 4 with held experts
+    total = sum(jnp.asarray(leaf, jnp.float32).reshape(-1, width).sum(0)
                 for leaf in leaves)
-    calls = sum(leaf.size // 3 for leaf in leaves)
+    calls = sum(leaf.size // width for leaf in leaves)
     return jnp.concatenate([drops.astype(jnp.float32)[None], total,
                             jnp.asarray([calls], jnp.float32)])

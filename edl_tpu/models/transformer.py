@@ -20,6 +20,7 @@ mesh from day one:
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -43,6 +44,9 @@ LOGICAL_RULES = [
     (r"layers/moe/w_in", ("layers", "expert", "embed", "expert_mlp")),
     (r"layers/moe/w_out", ("layers", "expert", "expert_mlp", "embed")),
     (r"layers/moe/w_gate", ("layers", "expert", "embed", "expert_mlp")),
+    (r"layers/moe/gate_bias", ("layers", None)),
+    (r"layers/moe/shared_(gate|in)/kernel", ("layers", "embed", "mlp")),
+    (r"layers/moe/shared_out/kernel", ("layers", "mlp", "embed")),
     # over the whole q / k projection; replicated like the other norms
     (r"layers/q_norm/scale", ("layers", "norm")),
     (r"layers/k_norm/scale", ("layers", "norm")),
@@ -116,36 +120,126 @@ class TransformerConfig:
     # (serving/engine.py) — with out-of-bounds rows DROPPED, never
     # clamped (a clamp would smear the last position over live state).
     decode_scatter: bool = False
+    # -- layers that differ (a published ``layer_types`` /
+    # ``mlp_layer_types`` plan).  Every field below is off by default,
+    # and a configuration that leaves them off builds the modules and
+    # compiles the programs it always did.
+    # a head size that is not embed_dim // num_heads (``head_dim``)
+    attn_head_dim: int = 0
+    # sliding-window attention (``sliding_window``): a "window" layer's
+    # position i sees j with j <= i and i - j < attn_window (itself
+    # included).  layer_attn[i] is "window" or "global"; () = every
+    # layer "window" when attn_window is set, else "global"
+    attn_window: int = 0
+    layer_attn: tuple = ()
+    # False: the global layers of a windowed plan do not rotate q and k
+    # (no positional embedding there); window layers always rotate
+    rope_global: bool = True
+    # qk_norm over each head's head_dim (scale [head_dim]) instead of
+    # over the whole projection
+    qk_norm_per_head: bool = False
+    # layer_mlp[i] is "dense" (width mlp_dim) or "sparse" (moe_experts
+    # experts of width moe_mlp_dim or, when that is 0, mlp_dim);
+    # () = every layer "sparse" when moe_experts is set
+    layer_mlp: tuple = ()
+    moe_mlp_dim: int = 0
+    # the router (ops/moe.py): "softmax", or "sigmoid" scores with an
+    # optional per-expert selection bias (it chooses, the score weighs)
+    # and a factor on the gates; a shared expert of width moe_shared_dim
+    # beside the routed ones; moe_held > 0 = expert parallelism's share:
+    # the router is moe_experts wide, this device holds experts
+    # 0..moe_held-1 and computes the pairs that land on them
+    moe_router: str = "softmax"
+    moe_select_bias: bool = False
+    moe_routed_scale: float = 1.0
+    moe_shared_dim: int = 0
+    moe_held: int = 0
+    # positions a window layer's decode ring holds (0 = attn_window).
+    # The serving engine sets it: a slot's ring outlives the window by
+    # what a pool commit reads back (serving/engine.py)
+    window_ring: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.attn_head_dim or self.embed_dim // self.num_heads
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
 
+    def attn_kind(self, layer: int) -> str:
+        if self.layer_attn:
+            return self.layer_attn[layer]
+        return "window" if self.attn_window else "global"
 
-def _layer_matmul_params(cfg: TransformerConfig, experts: int) -> int:
-    """One layer's matmul parameters with ``experts`` experts counted
-    (all of them, or the ``moe_top_k`` a token is routed to)."""
-    D, M = cfg.embed_dim, cfg.mlp_dim
+    def mlp_kind(self, layer: int) -> str:
+        if self.layer_mlp:
+            return self.layer_mlp[layer]
+        return "sparse" if self.moe_experts else "dense"
+
+    @property
+    def uniform(self) -> bool:
+        """Every layer alike: the stack can be one ``nn.scan``."""
+        kinds = {(self.attn_kind(i), self.mlp_kind(i))
+                 for i in range(self.num_layers)}
+        return len(kinds) <= 1
+
+    @property
+    def ring_len(self) -> int:
+        """Positions a window layer's decode cache holds."""
+        return min(self.window_ring or self.attn_window, self.max_len)
+
+    @property
+    def expert_dim(self) -> int:
+        return self.moe_mlp_dim or self.mlp_dim
+
+    def __post_init__(self):
+        for name, plan, kinds in (
+                ("layer_attn", self.layer_attn, ("window", "global")),
+                ("layer_mlp", self.layer_mlp, ("dense", "sparse"))):
+            if plan and (len(plan) != self.num_layers
+                         or set(plan) - set(kinds)):
+                raise ValueError(
+                    f"{name} must name one of {kinds} for each of "
+                    f"{self.num_layers} layers, got {plan!r}")
+        if "window" in self.layer_attn and not self.attn_window:
+            raise ValueError("a window layer needs attn_window")
+        if "sparse" in self.layer_mlp and not self.moe_experts:
+            raise ValueError("a sparse layer needs moe_experts")
+        if self.moe_held and not 0 < self.moe_held <= self.moe_experts:
+            raise ValueError(
+                f"moe_held {self.moe_held} of {self.moe_experts} experts")
+
+
+def _layer_matmul_params(cfg: TransformerConfig, experts: int,
+                         layer: int = 0) -> int:
+    """Layer ``layer``'s matmul parameters with ``experts`` routed
+    experts counted (those held, or the ``moe_top_k`` a token is routed
+    to); a shared expert counts whole either way."""
+    D = cfg.embed_dim
     H, Hk, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     attn = D * (H + 2 * Hk) * Dh + H * Dh * D
-    if not cfg.moe_experts:
-        return attn + 3 * D * M
-    return (attn + D * cfg.moe_experts
-            + experts * (3 if cfg.moe_gated else 2) * D * M)
+    if cfg.mlp_kind(layer) == "dense":
+        return attn + 3 * D * cfg.mlp_dim
+    return (attn + D * cfg.moe_experts + 3 * D * cfg.moe_shared_dim
+            + experts * (3 if cfg.moe_gated else 2) * D * cfg.expert_dim)
 
 
 def param_count(cfg: TransformerConfig) -> int:
-    """Parameter count of the config (embedding table included)."""
+    """Parameter count of the config (embedding table included; with
+    ``moe_held``, of the experts this device holds)."""
     D, V = cfg.embed_dim, cfg.vocab_size
-    norms = 2 * D + ((cfg.num_heads + cfg.kv_heads) * cfg.head_dim
-                     if cfg.qk_norm else 0)
+    norms = 2 * D
+    if cfg.qk_norm:
+        norms += (2 * cfg.head_dim if cfg.qk_norm_per_head
+                  else (cfg.num_heads + cfg.kv_heads) * cfg.head_dim)
     head = 0 if cfg.tie_embeddings else D * V
-    return (V * D + head + D + cfg.num_layers * (
-        _layer_matmul_params(cfg, cfg.moe_experts) + norms))
+    held = cfg.moe_held or cfg.moe_experts
+    bias = cfg.moe_experts if cfg.moe_select_bias else 0
+    return V * D + head + D + sum(
+        _layer_matmul_params(cfg, held, i) + norms
+        + (bias if cfg.mlp_kind(i) == "sparse" else 0)
+        for i in range(cfg.num_layers))
 
 
 def active_matmul_params(cfg: TransformerConfig) -> int:
@@ -153,7 +247,8 @@ def active_matmul_params(cfg: TransformerConfig) -> int:
     the head, and the MLP - for an expert configuration the router and
     the ``moe_top_k`` experts a token is routed to, not all of them.
     The embedding table is a lookup."""
-    return (cfg.num_layers * _layer_matmul_params(cfg, cfg.moe_top_k)
+    return (sum(_layer_matmul_params(cfg, cfg.moe_top_k, i)
+                for i in range(cfg.num_layers))
             + cfg.embed_dim * cfg.vocab_size)
 
 
@@ -249,9 +344,105 @@ class RMSNorm(nn.Module):
 
 
 class Block(nn.Module):
-    """One decoder layer; instances are stacked by ``nn.scan``."""
+    """One decoder layer; instances are stacked by ``nn.scan`` where
+    every layer is alike.  ``layer`` is the layer's place in the
+    configuration's plan (``cfg.attn_kind`` / ``cfg.mlp_kind``): a
+    stack whose layers differ is unrolled, each ``Block`` its own."""
 
     cfg: TransformerConfig
+    layer: int = 0
+
+    def _ring_attention(self, q, k, v, token_mask=None):
+        """A window layer's incremental attention: the cache is a RING
+        of ``cfg.ring_len`` positions, position p at ring slot
+        ``p % ring``, so a slot's state is O(window) whatever
+        ``max_len`` is.  ``cache_index`` stays the absolute position
+        (every layer's agrees: the engine reads any).
+
+        The queries attend the ring as it was BEFORE this call, masked
+        by the position each ring slot holds (``< window`` back, and
+        written at all), and the call's own keys under the banded
+        causal mask: one softmax over both.  Then the ring takes the
+        call's last ``ring`` REAL tokens: ``token_mask`` ([B, L], real
+        tokens leading) keeps a padded prefill's pads, and a free
+        slot's ballast token, out of it - a pad written at position
+        ``true_len + j`` would replace position ``true_len + j - ring``,
+        which the window still needs.  Rotation is applied before the
+        cache, at absolute positions, so ring order never matters.
+
+        One-token steps on the chip take the same two Pallas kernels as
+        the slabs (``ops/decode_attention``): append at ``index %
+        ring``, attend the ring under its circular window.  The einsums
+        here are every other call's path and the kernels' parity
+        reference, as in :meth:`_decode_attention`."""
+        cfg = self.cfg
+        W = cfg.attn_window
+        B, L, H, Dh = q.shape
+        Hk = k.shape[2]
+        G = H // Hk
+        R = cfg.ring_len
+        is_initialized = self.has_variable("cache", "cached_key")
+        ck = self.variable("cache", "cached_key", jnp.zeros,
+                           (B, Hk, Dh, R), cfg.dtype)
+        cv = self.variable("cache", "cached_value", jnp.zeros,
+                           (B, Hk, R, Dh), cfg.dtype)
+        ci = self.variable("cache", "cache_index",
+                           lambda: jnp.zeros((B,), jnp.int32))
+        if not is_initialized:      # init trace: shapes only
+            return dot_product_attention(q, k, v, causal=True, impl="dense",
+                                         window=W)
+        idx = ci.value                                    # [B]
+        if decode_attention.applies(L, cfg.mesh, R):
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            ck.value, cv.value = decode_attention.decode_append(
+                ck.value, cv.value, k[:, 0], v[:, 0], idx % R, live,
+                ring=True)
+            ci.value = idx + 1
+            return decode_attention.decode_attend(
+                q[:, 0], ck.value, cv.value,
+                jnp.where(live, jnp.minimum(idx + 1, R), 0),
+                newest=idx % R,
+                visible=jnp.where(live, jnp.minimum(idx + 1, W), 0)
+            )[:, None]
+        slot = jnp.arange(R)[None, :]                     # [1, R]
+        # the position ring slot r holds: the latest p < idx, p % R == r
+        held = (idx[:, None] - 1) - (idx[:, None] - 1 - slot) % R
+        q_pos = idx[:, None] + jnp.arange(L)              # [B, L]
+        back = q_pos[:, :, None] - held[:, None, :]       # [B, L, R]
+        ring_mask = (held[:, None, :] >= 0) & (back < W)
+        i = jnp.arange(L)
+        own_mask = (i[None, :] <= i[:, None]) & (i[:, None] - i[None, :] < W)
+        seen = jnp.concatenate(
+            [ring_mask, jnp.broadcast_to(own_mask, (B, L, L))], axis=-1)
+        # the ring and the call's own keys as one operand in the slabs'
+        # layouts, and the slab path's precision recipe over it
+        k_all = jnp.concatenate(
+            [ck.value, k.transpose(0, 2, 3, 1).astype(cfg.dtype)], axis=-1)
+        v_all = jnp.concatenate(
+            [cv.value, v.transpose(0, 2, 1, 3).astype(cfg.dtype)], axis=2)
+        qg = q.reshape(B, L, Hk, G, Dh)
+        logits = jnp.einsum("blhgd,bhdk->bhglk", qg, k_all
+                            ).astype(jnp.float32) * Dh ** -0.5
+        logits = jnp.where(seen[:, None, None], logits, -jnp.inf)
+        weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bhglk,bhkd->blhgd", weights, v_all)
+        # the ring takes the call's last R real tokens: slot r the
+        # latest real position p in [idx, idx + n_real) with p % R == r
+        n_real = (jnp.full((B,), L, jnp.int32) if token_mask is None
+                  else token_mask.sum(-1).astype(jnp.int32))
+        last = (idx + n_real - 1)[:, None]
+        src = last - (last - slot) % R - idx[:, None]     # [B, R]
+        take = src >= 0
+        at = jnp.clip(src, 0, L - 1)[:, :, None, None]
+        k_new = jnp.take_along_axis(k.astype(cfg.dtype), at, axis=1)
+        v_new = jnp.take_along_axis(v.astype(cfg.dtype), at, axis=1)
+        ck.value = jnp.where(take[:, None, None, :],
+                             k_new.transpose(0, 2, 3, 1), ck.value)
+        cv.value = jnp.where(take[:, None, :, None],
+                             v_new.transpose(0, 2, 1, 3), cv.value)
+        ci.value = idx + L
+        return out.reshape(B, L, H, Dh)
 
     def _decode_attention(self, q, k, v, token_mask=None):
         """Incremental attention against a persistent KV cache.  First
@@ -374,6 +565,8 @@ class Block(nn.Module):
         H, Dh = cfg.num_heads, cfg.head_dim
         Hk = cfg.kv_heads
         assert H % Hk == 0, f"num_heads {H} not divisible by kv heads {Hk}"
+        kind = cfg.attn_kind(self.layer)
+        window = cfg.attn_window if kind == "window" else 0
         x = _pin(cfg, x, "batch", "seq", None)
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
         qkv = nn.DenseGeneral(((H + 2 * Hk) * Dh,), use_bias=False,
@@ -381,38 +574,57 @@ class Block(nn.Module):
                               name="attn_qkv")(y)
         qkv = _pin(cfg, qkv, "batch", "seq", "heads")
         q, k, v = jnp.split(qkv, [H * Dh, (H + Hk) * Dh], axis=-1)
-        if cfg.qk_norm:
+        B, L = x.shape[:2]
+        if cfg.qk_norm and cfg.qk_norm_per_head:
+            # each head over its own head_dim, one scale for all heads
+            q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(
+                q.reshape(B, L, H, Dh)).astype(cfg.dtype)
+            k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(
+                k.reshape(B, L, Hk, Dh)).astype(cfg.dtype)
+        elif cfg.qk_norm:
             # over the whole projection, before the split into heads;
             # back in the compute dtype (the f32 scale promotes)
             q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q).astype(
                 cfg.dtype)
             k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k).astype(
                 cfg.dtype)
-        B, L = x.shape[:2]
-        q = rope(q.reshape(B, L, H, Dh), positions, cfg.rope_theta)
-        k = rope(k.reshape(B, L, Hk, Dh), positions, cfg.rope_theta)
+        q, k = q.reshape(B, L, H, Dh), k.reshape(B, L, Hk, Dh)
+        if window or cfg.rope_global:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         v = v.reshape(B, L, Hk, Dh)
-        if cfg.decode:
-            attn = self._decode_attention(q, k, v, token_mask)
-        else:
-            # GQA is handled by the dispatch: dense attends grouped
-            # K/V without materialising repeats; kernels expand inside
-            attn = dot_product_attention(q, k, v, causal=True,
-                                         impl=cfg.attention_impl,
-                                         mesh=cfg.mesh)
+        # the scopes name a mixed stack's two kinds in a trace; a stack
+        # without a window keeps the op names it always had
+        with (jax.named_scope(f"attn/{kind}") if cfg.attn_window
+              else contextlib.nullcontext()):
+            if cfg.decode and window:
+                attn = self._ring_attention(q, k, v, token_mask)
+            elif cfg.decode:
+                attn = self._decode_attention(q, k, v, token_mask)
+            else:
+                # GQA is handled by the dispatch: dense attends grouped
+                # K/V without materialising repeats; kernels expand inside
+                attn = dot_product_attention(q, k, v, causal=True,
+                                             impl=cfg.attention_impl,
+                                             mesh=cfg.mesh, window=window)
         attn = _pin(cfg, attn.reshape(B, L, H * Dh), "batch", "seq", "heads")
         x = x + nn.DenseGeneral(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                                 param_dtype=jnp.float32, name="attn_out")(attn)
         x = _pin(cfg, x, "batch", "seq", None)
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="mlp_norm")(x)
-        if cfg.moe_experts:
+        if cfg.mlp_kind(self.layer) == "sparse":
             from edl_tpu.ops.moe import MoEMLP
             y, aux = MoEMLP(num_experts=cfg.moe_experts,
-                            mlp_dim=cfg.mlp_dim, top_k=cfg.moe_top_k,
+                            mlp_dim=cfg.expert_dim, top_k=cfg.moe_top_k,
                             capacity_factor=cfg.moe_capacity,
                             dtype=cfg.dtype, decode=cfg.decode,
                             gated=cfg.moe_gated,
                             norm_topk=cfg.moe_norm_topk,
+                            router=cfg.moe_router,
+                            select_bias=cfg.moe_select_bias,
+                            routed_scale=cfg.moe_routed_scale,
+                            shared_dim=cfg.moe_shared_dim,
+                            held=cfg.moe_held,
                             name="moe")(y, token_mask)
             return _pin(cfg, x + y, "batch", "seq", None), aux
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
@@ -424,6 +636,12 @@ class Block(nn.Module):
         x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                          param_dtype=jnp.float32, name="mlp_out")(y)
         return _pin(cfg, x, "batch", "seq", None), None
+
+
+def _remat(block):
+    return nn.remat(
+        block, prevent_cse=False,
+        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
 
 class TransformerLM(nn.Module):
@@ -459,13 +677,22 @@ class TransformerLM(nn.Module):
             # (models/generate.py _split_layer_params).
             aux = None
             for i in range(cfg.num_layers):
-                x, _ = Block(cfg, name=f"layer_{i}")(x, positions,
-                                                     token_mask)
+                x, _ = Block(cfg, i, name=f"layer_{i}")(x, positions,
+                                                        token_mask)
+        elif not cfg.uniform:
+            # layers that differ cannot be stacked (a dense layer has
+            # no expert matrices): unrolled, ``layer_<i>`` parameters,
+            # the layout the decode model has
+            block = _remat(Block) if cfg.remat else Block
+            auxes = []
+            for i in range(cfg.num_layers):
+                x, a = block(cfg, i, name=f"layer_{i}")(x, positions,
+                                                        token_mask)
+                if a is not None:
+                    auxes.append(a)
+            aux = jnp.stack(auxes) if auxes else None
         else:
-            block = Block
-            if cfg.remat:
-                block = nn.remat(Block, prevent_cse=False,
-                                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            block = _remat(Block) if cfg.remat else Block
             Stack = nn.scan(block, variable_axes={"params": 0, "cache": 0},
                             split_rngs={"params": True},
                             length=cfg.num_layers,

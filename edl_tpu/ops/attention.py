@@ -14,6 +14,11 @@ tensors and dispatches:
 - ``auto``: splash when causal + tileable on TPU, else flash when
   tileable, else dense.
 
+``window`` (with ``causal``) bands the mask: position i sees j with
+``j <= i`` and ``i - j < window``.  Dense applies it in its mask,
+splash as a local mask whose off-band blocks are never visited; flash
+and ring have no band and are not chosen (``auto``) or refuse.
+
 Ring sequence-parallel attention (the long-context path over the ``sp``
 mesh axis) lives in :mod:`edl_tpu.ops.ring` and composes with these as
 its per-shard inner kernel.
@@ -35,7 +40,7 @@ def _on_tpu() -> bool:
 
 def dense_attention(q, k, v, *, causal: bool = False,
                     sm_scale: float | None = None,
-                    mask=None):
+                    mask=None, window: int = 0):
     """Plain XLA attention; softmax statistics in f32 regardless of the
     input dtype (bf16-safe).
 
@@ -63,6 +68,9 @@ def dense_attention(q, k, v, *, causal: bool = False,
         logits = logits.reshape(B, H, Lq, Lk)
     if causal:
         causal_mask = jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq)
+        if window:
+            causal_mask &= ~jnp.tril(jnp.ones((Lq, Lk), bool),
+                                     k=Lk - Lq - window)
         logits = jnp.where(causal_mask, logits, -jnp.inf)
     if mask is not None:
         logits = jnp.where(mask, logits, -jnp.inf)
@@ -91,12 +99,13 @@ def _flash(q, k, v, causal, sm_scale):
 # would otherwise hold that trace's tracers and poison every later
 # trace (UnexpectedTracerError).
 @functools.cache
-def _splash_kernel(L: int, H: int, blk: int):
+def _splash_kernel(L: int, H: int, blk: int, window: int = 0):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm,
     )
-    mask = sm.MultiHeadMask(masks=[sm.CausalMask(shape=(L, L))
-                                   for _ in range(H)])
+    one = (sm.LocalMask(shape=(L, L), window_size=(window - 1, 0), offset=0)
+           if window else sm.CausalMask(shape=(L, L)))
+    mask = sm.MultiHeadMask(masks=[one for _ in range(H)])
     sizes = sk.BlockSizes(
         block_q=blk, block_kv=blk, block_kv_compute=blk,
         block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
@@ -106,7 +115,7 @@ def _splash_kernel(L: int, H: int, blk: int):
                                   block_sizes=sizes)
 
 
-def _splash(q, k, v, sm_scale, mesh=None):
+def _splash(q, k, v, sm_scale, mesh=None, window: int = 0):
     """Causal splash attention; q/k same length (self-attention).
 
     A Mosaic call has no partitioning rule: left to GSPMD on a
@@ -125,7 +134,9 @@ def _splash(q, k, v, sm_scale, mesh=None):
 
     def local(qt, kt, vt):
         # kernel wants [H, L, D] per example; vmap over batch
-        return jax.vmap(_splash_kernel(L, qt.shape[1], blk))(qt, kt, vt)
+        band = (window,) if window else ()     # causal: the kernel's key
+        return jax.vmap(_splash_kernel(L, qt.shape[1], blk, *band))(
+            qt, kt, vt)
 
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
@@ -186,18 +197,21 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
                           sm_scale: float | None = None,
                           mask=None, impl: str = "auto",
                           mesh=None, sp_axis: str = "sp",
-                          ring_kv_chunk: int = 1024):
+                          ring_kv_chunk: int = 1024, window: int = 0):
     """[B, L, H, D] attention with implementation dispatch (see module
     docstring).  ``mask`` (dense-only) broadcasts against [B, H, Lq, Lk];
     ``impl="ring"`` requires ``mesh`` and shards the sequence over
     ``sp_axis`` (``ring_kv_chunk`` bounds its inner logits tile; 0
     disables chunking); splash uses ``mesh``, when given, to run on
-    each device's own batch rows and heads."""
+    each device's own batch rows and heads.  ``window`` bands a causal
+    mask (module docstring)."""
+    if window and not causal:
+        raise ValueError("window needs causal=True")
     if impl == "auto":
         kernels = _on_tpu() and mask is None
         if kernels and _splash_ok(q, k, causal):
             impl = "splash"
-        elif kernels and _flash_ok(q, k):
+        elif kernels and not window and _flash_ok(q, k):
             impl = "flash"
         else:
             impl = "dense"
@@ -210,6 +224,9 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
         groups = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, groups, axis=2)
         v = jnp.repeat(v, groups, axis=2)
+    if window and impl in ("ring", "flash"):
+        raise ValueError(f"impl={impl!r} has no attention window; use "
+                         f"splash or dense")
     if impl == "ring":
         if mesh is None:
             raise ValueError("impl='ring' needs the mesh")
@@ -220,11 +237,11 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     if impl == "splash":
         if not causal:
             raise ValueError("impl='splash' is causal-only; use flash/dense")
-        return _splash(q, k, v, sm_scale, mesh)
+        return _splash(q, k, v, sm_scale, mesh, window)
     if impl == "flash":
         scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
         return _flash(q, k, v, causal, scale)
     if impl == "dense":
         return dense_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               mask=mask)
+                               mask=mask, window=window)
     raise ValueError(f"unknown attention impl {impl!r}")

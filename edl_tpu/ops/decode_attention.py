@@ -24,6 +24,14 @@ keys ``[B, Hk, D, max_len]`` and values ``[B, Hk, max_len, D]``:
   length is neither fetched nor computed; a free slot (length 0) points
   at the block its neighbour already holds and fetches nothing.
 
+A window layer's cache is a ring (``transformer.Block._ring_attention``:
+position p at slot ``p % T``).  The same two kernels serve it: the
+caller appends at ``index % T``, and :func:`decode_attend` with
+``newest`` / ``visible`` attends the ``visible`` most recently written
+slots ending at ``newest``, wrapping: the mask is circular, the fetch
+plan is the slab's.  In a trace a window layer's two calls are named
+``window_append`` and ``window_attend``.
+
 Precision is the einsum path's: input-dtype matmuls accumulated in
 float32, scores and softmax statistics in float32, probabilities cast
 to the cache dtype before the second matmul.
@@ -86,11 +94,13 @@ def _append_kernel(idx_ref, on_ref, k_ref, v_ref, kn_ref, vn_ref,
 
 
 def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
-                  interpret=None):
+                  interpret=None, ring: bool = False):
     """Write ``k_new`` / ``v_new`` ``[B, Hk, D]`` at position
     ``index[b]`` of slot b's slabs, in place (donate or carry the slabs:
     they are aliased in and out).  Slots with ``live[b]`` false or
-    ``index[b] >= max_len`` are rewritten with what they held."""
+    ``index[b] >= max_len`` are rewritten with what they held.
+    ``ring`` changes the kernel's name and nothing else
+    (``window_append``: a window layer's call, told apart in a trace)."""
     B, Hk, D, T = k_slab.shape
     rows = _sublanes(v_slab.dtype)
     on = (live & (index < T)).astype(jnp.int32)
@@ -121,14 +131,21 @@ def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
         input_output_aliases={2: 0, 3: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(interpret), name="decode_append",
+        interpret=_interpret(interpret),
+        name="window_append" if ring else "decode_append",
     )(at, on, k_slab, v_slab, kn, vn)
 
 
 # -- attend ----------------------------------------------------------------
 
-def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_ref, l_ref, acc_ref, *, tk: int, scale: float):
+def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, *refs, tk: int,
+                   scale: float, ring: int = 0):
+    """``ring`` (the slab's length, static) adds two prefetched scalars
+    a slot, the newest ring slot and how many slots back are visible;
+    0 is the plain slab, masked by length."""
+    if ring:
+        newest_ref, visible_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     b, j = pl.program_id(0), pl.program_id(1)
     n = len_ref[b]
 
@@ -146,13 +163,22 @@ def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref,
             q, k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
         pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < n, s, _NEG)
+        if ring:
+            back = newest_ref[b] - pos
+            seen = jnp.where(back < 0, back + ring, back) < visible_ref[b]
+        else:
+            seen = pos < n
+        s = jnp.where(seen, s, _NEG)
         m_old = m_ref[...]
         m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_old - m_new)
         # a block that runs holds a live position, so m_new is a real
         # score and the masked tail's exp(_NEG - m_new) is exactly 0
         p = jnp.exp(s - m_new)
+        if ring:
+            # a ring's block may hold no visible slot (the window lies
+            # in the other block): m_new is then _NEG and exp(0) is 1
+            p = jnp.where(seen, p, 0.0)
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
         # [Hk, Gp, tk] x [Hk, tk, D] -> [Hk, Gp, D]
         acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
@@ -195,12 +221,20 @@ def attend_block(Hk: int, D: int, max_len: int, dtype) -> int:
     return tk
 
 
-def decode_attend(q, k_slab, v_slab, lengths, *, block: int | None = None,
-                  interpret=None):
+def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
+                  block: int | None = None, interpret=None):
     """Attention of one query per head, ``q [B, H, D]``, against the
     first ``lengths[b]`` positions of slot b's slabs; ``[B, H, D]`` in
     ``q``'s dtype.  Query head h reads KV head ``h // (H // Hk)``.  A
-    slot of length 0 reads nothing and returns zeros."""
+    slot of length 0 reads nothing and returns zeros.
+
+    With ``newest`` and ``visible`` (``[B]`` int) the slabs are rings:
+    slot b attends the ``visible[b]`` slots that end, wrapping, at ring
+    slot ``newest[b]``; ``lengths[b]`` is then how many ring slots have
+    been written at all (what is fetched; 0 = a free slot), and the
+    kernel is named ``window_attend``."""
+    ring = newest is not None
+    assert ring == (visible is not None)
     B, H, D = q.shape
     _, Hk, _, T = k_slab.shape
     G = H // Hk
@@ -213,7 +247,7 @@ def decode_attend(q, k_slab, v_slab, lengths, *, block: int | None = None,
     lengths = lengths.astype(jnp.int32)
     src, lo, hi = _fetch_plan(lengths, tk)
 
-    def fetched(b, j, n, src, lo, hi):
+    def fetched(b, j, n, src, lo, hi, *_):
         return src[b], jnp.clip(j, lo[b], hi[b])
 
     def k_index(*step):
@@ -225,10 +259,13 @@ def decode_attend(q, k_slab, v_slab, lengths, *, block: int | None = None,
         return slot, 0, t, 0
 
     q_spec = pl.BlockSpec((None, Hk, Gp, D), lambda b, *_: (b, 0, 0, 0))
+    extra = ((newest.astype(jnp.int32), visible.astype(jnp.int32))
+             if ring else ())
     out = pl.pallas_call(
-        functools.partial(_attend_kernel, tk=tk, scale=D ** -0.5),
+        functools.partial(_attend_kernel, tk=tk, scale=D ** -0.5,
+                          ring=T if ring else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(B, T // tk),
+            num_scalar_prefetch=4 + len(extra), grid=(B, T // tk),
             in_specs=[q_spec,
                       pl.BlockSpec((None, Hk, D, tk), k_index),
                       pl.BlockSpec((None, Hk, tk, D), v_index)],
@@ -240,6 +277,7 @@ def decode_attend(q, k_slab, v_slab, lengths, *, block: int | None = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(interpret), name="decode_attend",
-    )(lengths, src, lo, hi, qg, k_slab, v_slab)
+        interpret=_interpret(interpret),
+        name="window_attend" if ring else "decode_attend",
+    )(lengths, src, lo, hi, *extra, qg, k_slab, v_slab)
     return out[:, :, :G].reshape(B, H, D)

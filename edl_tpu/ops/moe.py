@@ -45,11 +45,29 @@ The auxiliary load-balance loss is the Switch-Transformer form
 router probability of e); minimised at uniform routing.  Both paths
 return it (the dropless one only outside ``decode``).
 
+The dropless path also runs the sigmoid-routed family (``router=
+"sigmoid"``): float32 sigmoid scores, the ``top_k`` chosen by score
+plus a learned per-expert ``gate_bias`` (``select_bias``: the bias
+chooses, the score weighs), gates normalised over the chosen and
+multiplied by ``routed_scale``; a **shared expert** of width
+``shared_dim`` that every token passes through beside the routed ones
+(scope ``moe/shared``); and **held experts** (``held`` > 0), one
+device's share of expert parallelism without its exchange: the router
+is ``num_experts`` wide and every gate is normalised over all the
+chosen, but the device holds the matrices of experts ``0 .. held-1``
+and computes the (token, expert) pairs that land on them; the others
+belong to devices that are not here, and the layer's output is this
+device's partial sum.  ``moe_stats`` then carries a fourth entry, the
+pairs ROUTED, beside the pairs computed.
+
 What the paths sow into the ``intermediates`` collection (a no-op
 unless the caller asks for it): ``moe_drops`` (capacity path, int32)
 and ``moe_stats`` (dropless path, float32 ``[assignments, experts
 touched, max load over mean load]`` of this layer's call over real
-tokens) - the serving engine sums both into ``stats()``.
+tokens, with ``held`` also ``pairs routed``: ``assignments`` are then
+the pairs that landed on held experts, and max load over mean load is
+over the held experts) - the serving engine sums both into
+``stats()``.
 """
 
 from __future__ import annotations
@@ -114,6 +132,19 @@ def top_k_gates(probs, top_k: int, norm_topk: bool):
     return gates, idx
 
 
+def sigmoid_gates(scores, bias, top_k: int, norm_topk: bool,
+                  scale: float = 1.0):
+    """``(gates, idx)`` [B, S, K] of a sigmoid router: the ``top_k``
+    experts by ``scores + bias`` (``bias`` [E] or None), weighed by
+    their ``scores`` alone, normalised over the chosen when
+    ``norm_topk``, times ``scale``."""
+    _, idx = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-20)
+    return gates * scale, idx
+
+
 def switch_aux_loss(probs, idx, valid=None):
     """Switch aux loss from top-1 assignments (over real tokens only)."""
     E = probs.shape[-1]
@@ -135,13 +166,17 @@ def _activate(h, gate=None):
     return nn.silu(h) if gate is None else nn.silu(gate) * h
 
 
-def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None):
+def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
+                     held_only: bool = False):
     """The dropless routed expert FFN over tokens ``x [T, M]`` with
     gates and expert indices ``[T, K]``: sort the ``T*K`` assignments
     by expert, one grouped matmul per projection over the sorted rows,
     unsort, weight and sum per token.  ``w_gate`` None = ungated.
     ``valid [T]`` bool marks real tokens; the others are sorted past
     every group (sentinel expert ``E``), so no expert computes them.
+    So is a pair whose expert index is ``E`` or more: an expert this
+    device does not hold (``MoEMLP.held``); its gate weighs nothing
+    here.
 
     Returns ``(y [T, M] in x's dtype, sizes [E] int32)`` - ``sizes``
     the real assignments each expert received."""
@@ -172,6 +207,8 @@ def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None):
         w = gates.astype(jnp.float32)
         if valid is not None:
             w = w * valid[:, None]
+        if held_only:
+            w = w * (idx < E)
         y = (out * w[..., None]).sum(axis=1)
     return y.astype(x.dtype), sizes
 
@@ -209,6 +246,13 @@ class MoEMLP(nn.Module):
     decode: bool = False
     gated: bool = False
     norm_topk: bool = True
+    # the sigmoid-routed family, a shared expert and held experts
+    # (module docstring): dropless path only
+    router: str = "softmax"
+    select_bias: bool = False
+    routed_scale: float = 1.0
+    shared_dim: int = 0
+    held: int = 0
 
     @nn.compact
     def __call__(self, x, token_mask=None):
@@ -216,34 +260,74 @@ class MoEMLP(nn.Module):
         padded prefill - see :func:`compute_routing`."""
         B, S, M = x.shape
         E = self.num_experts
+        plain = (self.router == "softmax" and not self.select_bias
+                 and self.routed_scale == 1.0 and not self.shared_dim
+                 and not self.held)
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.select_bias and self.router != "sigmoid":
+            raise ValueError("a selection bias belongs to the sigmoid router")
+        if not plain and self.capacity_factor > 0:
+            raise ValueError(
+                "a sigmoid router, a selection bias, a gate scale, a "
+                "shared expert and held experts run on the dropless path "
+                "only (capacity_factor <= 0): the capacity path's "
+                "dispatch tensors know none of them")
+        Eh = self.held or E       # experts whose matrices live here
         gate_w = self.param("gate", nn.initializers.lecun_normal(),
                             (M, E), jnp.float32)
         w_in = self.param("w_in", nn.initializers.lecun_normal(),
-                          (E, M, self.mlp_dim), jnp.float32)
+                          (Eh, M, self.mlp_dim), jnp.float32)
         w_out = self.param("w_out", nn.initializers.lecun_normal(),
-                           (E, self.mlp_dim, M), jnp.float32)
+                           (Eh, self.mlp_dim, M), jnp.float32)
         w_gate = (self.param("w_gate", nn.initializers.lecun_normal(),
-                             (E, M, self.mlp_dim), jnp.float32)
+                             (Eh, M, self.mlp_dim), jnp.float32)
                   if self.gated else None)
+        bias = (self.param("gate_bias", nn.initializers.zeros, (E,),
+                           jnp.float32) if self.select_bias else None)
 
         # router in f32 (tiny matmul, routing decisions precision-critical)
-        probs = jax.nn.softmax(x.astype(jnp.float32) @ gate_w, axis=-1)
+        scores = x.astype(jnp.float32) @ gate_w
+        probs = (jax.nn.softmax(scores, axis=-1) if self.router == "softmax"
+                 else jax.nn.sigmoid(scores))
         dtype = self.dtype
 
         if self.capacity_factor <= 0:
-            gates, idx = top_k_gates(probs, self.top_k, self.norm_topk)
+            if self.router == "sigmoid":
+                gates, idx = sigmoid_gates(probs, bias, self.top_k,
+                                           self.norm_topk, self.routed_scale)
+            else:
+                gates, idx = top_k_gates(probs, self.top_k, self.norm_topk)
+                if self.routed_scale != 1.0:
+                    gates = gates * self.routed_scale
             valid = None if token_mask is None else token_mask.reshape(B * S)
+            xt = x.reshape(B * S, M).astype(dtype)
             y, sizes = dropless_experts(
-                x.reshape(B * S, M).astype(dtype),
-                gates.reshape(B * S, -1), idx.reshape(B * S, -1),
+                xt, gates.reshape(B * S, -1), idx.reshape(B * S, -1),
                 None if w_gate is None else w_gate.astype(dtype),
-                w_in.astype(dtype), w_out.astype(dtype), valid)
+                w_in.astype(dtype), w_out.astype(dtype), valid,
+                held_only=bool(self.held))
             total = sizes.sum().astype(jnp.float32)
-            self.sow("intermediates", "moe_stats", jnp.stack([
-                total, (sizes > 0).sum().astype(jnp.float32),
-                sizes.max() * E / jnp.maximum(total, 1.0)]),
-                init_fn=lambda: jnp.zeros((3,), jnp.float32),
-                reduce_fn=lambda a, b: a + b)
+            stats = [total, (sizes > 0).sum().astype(jnp.float32),
+                     sizes.max() * Eh / jnp.maximum(total, 1.0)]
+            if self.held:
+                real = (jnp.asarray(B * S, jnp.float32) if valid is None
+                        else valid.sum().astype(jnp.float32))
+                stats.append(real * self.top_k)
+            self.sow("intermediates", "moe_stats", jnp.stack(stats),
+                     init_fn=lambda: jnp.zeros((len(stats),), jnp.float32),
+                     reduce_fn=lambda a, b: a + b)
+            if self.shared_dim:
+                with jax.named_scope("moe/shared"):
+                    dense = dict(use_bias=False, dtype=dtype,
+                                 param_dtype=jnp.float32)
+                    h = nn.silu(nn.Dense(self.shared_dim, name="shared_gate",
+                                         **dense)(xt)) * nn.Dense(
+                        self.shared_dim, name="shared_in", **dense)(xt)
+                    shared = nn.Dense(M, name="shared_out", **dense)(h)
+                    if valid is not None:
+                        shared = jnp.where(valid[:, None], shared, 0)
+                    y = y + shared.astype(y.dtype)
             aux = (jnp.zeros((), jnp.float32) if self.decode
                    else switch_aux_loss(probs, idx, token_mask))
             return y.reshape(B, S, M), aux

@@ -143,7 +143,7 @@ class _Slot:
 
 class _Request:
     __slots__ = ("ids", "max_new", "future", "session", "ctx", "skipped",
-                 "t_submit", "t_admit", "t_first", "t_done")
+                 "snap", "t_submit", "t_admit", "t_first", "t_done")
 
     def __init__(self, ids: np.ndarray, max_new: int,
                  session: str | None = None):
@@ -155,6 +155,9 @@ class _Request:
         # the engine thread has none of its own
         self.ctx = obs_context.current()
         self.skipped = 0          # prompt tokens a prefix hit spared
+        # window layers: the snapshot (id, end) taken when the prompt's
+        # prefill ended, until the commit finds its node
+        self.snap = (0, 0)
         # stages, on one monotonic clock: submit <= admit <= first <= done
         self.t_submit = time.monotonic()
         self.t_admit = self.t_first = self.t_done = None
@@ -232,19 +235,49 @@ class ContinuousBatcher:
                  draft_params=None):
         cache_len = max_len or cfg.max_len
         self.cfg = cfg
+        self._T = max(1, steps_per_sync)
+        # -- window layers (transformer.Block._ring_attention): a slot
+        # holds a ring of the window and what a snapshot for the pool
+        # reads back after the last token: up to one KV block back to
+        # the block edge it ends at, and the token steps the program
+        # ran on after the request was done (steps_per_sync - 1).  A
+        # window that tiles by lanes keeps a ring that does (the
+        # one-token kernels take it, ops/decode_attention.applies).
+        self._ring_layers = frozenset(
+            f"layer_{i}" for i in range(cfg.num_layers)
+            if cfg.attn_kind(i) == "window")
+        ring = 0
+        if self._ring_layers:
+            k = constants.SPEC_K if spec_k is None else int(spec_k)
+            if k > 0:
+                raise ValueError(
+                    "speculative decoding (spec_k > 0) does not serve a "
+                    "window configuration: a rejected draft rewinds the "
+                    "cache index, and a window layer's ring has already "
+                    "overwritten the positions the rewound window needs")
+            if mesh is not None:
+                raise ValueError(
+                    "a mesh engine does not serve a window configuration: "
+                    "the window layers' snapshot pool has no sharded "
+                    "gather yet (serving/kv_cache.py)")
+            W = cfg.attn_window
+            lanes = 128 if W % 128 == 0 else 1
+            ring = -(-(W + max(kv_block, 1) + self._T - 1)
+                     // lanes) * lanes
         # attention_impl "dense" never reads the mesh; the decode step
         # does (ops/decode_attention.applies): a mesh engine's sharded
         # slabs stay on the einsum path
         self._dcfg = dataclasses.replace(
             cfg, decode=True, attention_impl="dense", mesh=mesh,
-            max_len=cache_len)
+            max_len=cache_len, window_ring=ring)
         self._model = TransformerLM(self._dcfg)
         # dropless expert path (ops/moe.py): its programs carry the
         # layers' moe_stats vector where the others carry a drop count
+        # (one entry longer when the layers hold a share of the experts)
         self._moe_dropless = bool(cfg.moe_experts and cfg.moe_capacity <= 0)
         self._moe_acc_shape = (
-            jax.ShapeDtypeStruct((5,), jnp.float32) if self._moe_dropless
-            else jax.ShapeDtypeStruct((), jnp.int32))
+            jax.ShapeDtypeStruct((6 if cfg.moe_held else 5,), jnp.float32)
+            if self._moe_dropless else jax.ShapeDtypeStruct((), jnp.int32))
         self._pending: "deque[_Request]" = deque()
         self._mesh = mesh
         if mesh is not None:
@@ -279,7 +312,6 @@ class ContinuousBatcher:
         self._top_k = top_k
         self._top_p = top_p
         self._eos = eos_id
-        self._T = max(1, steps_per_sync)
         self._rng = jax.random.key(rng_seed)
         blocks_per_slot = max(1, cache_len // kv_block) if kv_block > 0 else 0
         pool_blocks = (kv_pool_blocks or (2 * slots * blocks_per_slot + 1)
@@ -291,7 +323,30 @@ class ContinuousBatcher:
         # (_cache_shapes) and every compiled program keyed by its shapes
         self._shape_memo: dict[tuple[str, int], object] = {}
         self._prefill_cache: dict[tuple, object] = {}
-        self._require_fit(slots, kv_block, pool_blocks)
+        sessions = (constants.KV_SESSIONS if kv_max_sessions is None
+                    else kv_max_sessions)
+        # window snapshots (kv_cache.py): a pinned tail a session, one
+        # a finished request until it ages out, and the scratch entry
+        n_snaps = (sessions + 2 * slots + 1
+                   if self._ring_layers and kv_block > 0 else 0)
+        self._require_fit(slots, kv_block, pool_blocks, n_snaps)
+        one_lane = self._cache_shapes(1)
+        self._slot_bytes = {
+            cls: sum(leaf.size * leaf.dtype.itemsize
+                     for name, node in one_lane.items()
+                     if (name in self._ring_layers) == (cls == "window")
+                     for leaf in jax.tree.leaves(node) if leaf.ndim > 1)
+            for cls in ("window", "global")}
+        # what one window layer's decode read fetches of a slot that
+        # holds n ring positions: whole attend blocks on the kernels'
+        # path, the ring on the einsum path
+        if self._ring_layers:
+            from edl_tpu.ops import decode_attention
+            R = self._dcfg.ring_len
+            tk = (decode_attention.attend_block(
+                      cfg.kv_heads, cfg.head_dim, R, cfg.dtype)
+                  if decode_attention.applies(1, mesh, R) else R)
+            self._ring_fetch = lambda n: -(-min(n, R) // tk) * tk
         self._cache = self._fresh_cache(slots)
         self._toks = np.zeros((slots,), np.int32)   # last token per slot
         # -- paged KV block pool + prefix-reuse index (kv_cache.py) --
@@ -308,17 +363,21 @@ class ContinuousBatcher:
         if kv_block > 0:
             from edl_tpu.serving.kv_cache import PagedKVCache
             self._kv = PagedKVCache(
-                self._cache_shapes(1), kv_block, pool_blocks,
-                constants.KV_SESSIONS if kv_max_sessions is None
-                else kv_max_sessions, mesh=mesh)
+                one_lane, kv_block, pool_blocks, sessions, mesh=mesh,
+                ring_layers=self._ring_layers, window=cfg.attn_window,
+                n_snaps=n_snaps)
         slab0 = max(jax.tree.leaves(self._cache), key=lambda x: x.ndim)
         pool0 = (jax.tree.leaves(self._kv.pool)[0]
                  if self._kv is not None else None)
         logger.info(
             "kv cache: %d slots x %d tokens (%s, slab sharding %s); pool "
-            "%d blocks of %d (sharding %s)", slots, cache_len,
+            "%d blocks of %d (sharding %s); a slot holds %d bytes in %d "
+            "window layers (ring %d) and %d in global layers; %d window "
+            "snapshots", slots, cache_len,
             slab0.dtype.name, mesh and slab0.sharding.spec, pool_blocks,
-            kv_block, mesh and pool0 is not None and pool0.sharding.spec)
+            kv_block, mesh and pool0 is not None and pool0.sharding.spec,
+            self._slot_bytes["window"], len(self._ring_layers), ring,
+            self._slot_bytes["global"], n_snaps)
         self._kv_hits = 0
         self._kv_misses = 0
         self._prefill_tokens = 0
@@ -345,6 +404,7 @@ class ContinuousBatcher:
         # the dropless expert path's counters (ops/moe.py moe_stats),
         # cumulative; they reach the host with the tick's own sync
         self._moe_assignments = 0
+        self._moe_assignments_routed = 0
         self._moe_tokens = 0      # the host's own count of what was routed
         self._moe_decode_layer_steps = 0
         self._moe_decode_experts_touched = 0
@@ -357,6 +417,10 @@ class ContinuousBatcher:
         # to their length) and the positions its slabs hold, per token step
         self._kv_tokens_live = 0
         self._kv_tokens_slab = 0
+        # per token step and live slot, of ONE window layer: positions
+        # its read fetched, and the positions its window holds
+        self._kv_window_read = 0
+        self._kv_window_need = 0
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
         # the tick ledger: engine thread only; closed (and read by
         # stats()) under _stats_lock.  No switch.
@@ -539,7 +603,7 @@ class ContinuousBatcher:
                 "drain() first)")
         out = []
         for session in self._kv.sessions():
-            chain = self._kv.chain_of(session)
+            chain = self._kv.reusable(self._kv.chain_of(session))
             if not chain:
                 continue
             meta, blob = self._kv.export_chain(chain)
@@ -614,6 +678,9 @@ class ContinuousBatcher:
             self._spec_jit.lower(self._cache, self._draft_cache,
                                  jnp.asarray(self._toks), self._params,
                                  self._draft_params).compile()
+        if self._kv is not None and self._ring_layers:
+            # the snapshot an admission takes at its prompt's end
+            self._kv.store_blocks(self._cache, 0, 0, [], warm=True)
         if self._kv is not None and self._reuse:
             # the reuse-prefill family too — the first prefix hit per
             # (suffix bucket, padded chain depth) must not compile on
@@ -639,7 +706,8 @@ class ContinuousBatcher:
                         jnp.zeros((1, Pb), jnp.int32),
                         jnp.zeros((n_pad,), jnp.int32),
                         jnp.asarray(bs, jnp.int32),
-                        jnp.ones((1,), jnp.int32), key)
+                        jnp.ones((1,), jnp.int32), key,
+                        self._kv.snap_arg(0))
                     jax.block_until_ready(toks)
 
     def stats(self) -> dict:
@@ -663,6 +731,19 @@ class ContinuousBatcher:
                 # (slots x max_len: what an unmasked read touches)
                 "decode_kv_tokens_live": self._kv_tokens_live,
                 "decode_kv_tokens_slab": self._kv_tokens_slab,
+                # window layers (0s without one), per token step and
+                # live slot of ONE window layer: ring positions the
+                # decode read fetched (whole attend blocks on the chip,
+                # the ring off it) and positions the window holds,
+                # min(length, window): read / need is 1 when a step
+                # reads the window and no more, max_len / window when
+                # it reads a slab.  And the bytes of one slot's state in
+                # the window layers' rings (independent of max_len) and
+                # in the global layers' slabs (linear in it)
+                "decode_kv_tokens_window_read": self._kv_window_read,
+                "decode_kv_tokens_window_need": self._kv_window_need,
+                "kv_slot_bytes_window": self._slot_bytes["window"],
+                "kv_slot_bytes_global": self._slot_bytes["global"],
                 # MoE prefill capacity overflow (always 0 for dense
                 # configs; nonzero = raise capacity_factor)
                 "moe_prefill_drops": self._moe_drops,
@@ -679,6 +760,12 @@ class ContinuousBatcher:
                 # prefill programs, the experts they touched and their
                 # max-over-mean expert load summed (ratio: the imbalance)
                 "moe_assignments": self._moe_assignments,
+                # with a share of the experts held (moe_held): the
+                # pairs the routers ROUTED, top_k x sparse layers x
+                # moe_tokens; moe_assignments are then the pairs that
+                # landed on held experts, the ones computed here.
+                # Without a share both count the same pairs
+                "moe_assignments_routed": self._moe_assignments_routed,
                 "moe_decode_layer_steps": self._moe_decode_layer_steps,
                 "moe_decode_experts_touched":
                     self._moe_decode_experts_touched,
@@ -759,6 +846,8 @@ class ContinuousBatcher:
             "kv_evictions": self._kv.evictions,
             "kv_commit_skips": self._kv.commit_skips,
             "kv_sessions": self._kv.session_count(),
+            "kv_window_snapshots": self._kv.snaps_used(),
+            "kv_window_snapshot_skips": self._kv.snap_skips,
         }
 
     def drain(self, timeout: float | None = None) -> bool:
@@ -819,7 +908,7 @@ class ContinuousBatcher:
 
     # -- device state construction -------------------------------------------
     def _require_fit(self, slots: int, kv_block: int,
-                     pool_blocks: int) -> None:
+                     pool_blocks: int, n_snaps: int = 0) -> None:
         """Refuse at construction, with the sizes, an engine whose slot
         slabs + block pool + largest prefill dispatch cannot fit what
         the device has left — instead of an XLA allocation error on
@@ -840,29 +929,49 @@ class ContinuousBatcher:
                    // (tp if s.ndim >= 2 and s.shape[1] % tp == 0 else 1)
                    for s in jax.tree.leaves(one_lane))
         cache_len = self._dcfg.max_len
-        pool = (pool_device_bytes(one_lane, kv_block, pool_blocks, tp)
+        pool = (pool_device_bytes(one_lane, kv_block, pool_blocks, tp,
+                                  self._ring_layers, self.cfg.attn_window,
+                                  n_snaps)
                 if kv_block > 0 else 0)
-        # the widest admission: PREFILL_KS[0] fresh lanes plus their
-        # [K, P, vocab] f32 logits, P the largest monolithic bucket
-        # (prompts past the chunk size prefill one lane, one chunk)
-        k_max = self.PREFILL_KS[0]
+        # the widest admission: K fresh lanes, their [K, P, vocab] f32
+        # logits and one layer's [K, heads, P, cache_len] f32 scores (a
+        # multi-token call attends the whole slab under its mask), P the
+        # largest monolithic bucket (prompts past the chunk size prefill
+        # one lane, one chunk).  The sub-batch ladder is cut to the
+        # widest K that fits: 8 lanes of 64 heads against a 16k slab
+        # are 8.6 GB of scores, one lane is 1.1
         p_max = self._bucket(min(self._chunk_tokens or cache_len,
                                  cache_len - 1))
-        prefill = k_max * lane + 4 * k_max * p_max * self.cfg.vocab_size
+        heads = self.cfg.num_heads // (tp if self.cfg.num_heads % tp == 0
+                                       else 1)
         in_use = stats.get("bytes_in_use", 0)
-        need = in_use + slots * lane + pool + prefill
-        if need > limit:
-            gb = 1 / (1 << 30)
-            raise ValueError(
-                f"engine does not fit {dev.device_kind}: "
-                f"{in_use * gb:.2f} GiB already resident + "
-                f"{slots * lane * gb:.2f} GiB slot slabs ({slots} slots x "
-                f"{cache_len} tokens) + {pool * gb:.2f} GiB block pool "
-                f"({pool_blocks} blocks of {kv_block}) + "
-                f"{prefill * gb:.2f} GiB widest prefill ({k_max} lanes x "
-                f"{p_max} tokens) = {need * gb:.2f} GiB > "
-                f"{limit * gb:.2f} GiB limit; lower --slots/--max_len or "
-                f"set --kv_pool_blocks")
+        for i, k_max in enumerate(self.PREFILL_KS):
+            prefill = k_max * (lane + 4 * p_max * (self.cfg.vocab_size
+                                                   + heads * cache_len))
+            need = in_use + slots * lane + pool + prefill
+            if need <= limit:
+                if i:
+                    logger.info(
+                        "prefill sub-batches cut from %d to %d lanes: %d "
+                        "lanes x %d tokens against a %d-token slab would "
+                        "not fit beside the weights", self.PREFILL_KS[0],
+                        k_max, self.PREFILL_KS[0], p_max, cache_len)
+                    self.PREFILL_KS = self.PREFILL_KS[i:]
+                return
+        gb = 1 / (1 << 30)
+        raise ValueError(
+            f"engine does not fit {dev.device_kind}: "
+            f"{in_use * gb:.2f} GiB already resident + "
+            f"{slots * lane * gb:.2f} GiB slot slabs ({slots} slots x "
+            f"{cache_len} tokens; {len(self._ring_layers)} window "
+            f"layers hold a ring of {self._dcfg.ring_len}) + "
+            f"{pool * gb:.2f} GiB block pool "
+            f"({pool_blocks} blocks of {kv_block}, {n_snaps} window "
+            f"snapshots) + "
+            f"{prefill * gb:.2f} GiB widest prefill ({k_max} lanes x "
+            f"{p_max} tokens) = {need * gb:.2f} GiB > "
+            f"{limit * gb:.2f} GiB limit; lower --slots/--max_len or "
+            f"set --kv_pool_blocks")
 
     def _cache_shapes(self, B: int):
         """Shape tree of a ``B``-lane decode cache.  A pure function of
@@ -1632,7 +1741,7 @@ class ContinuousBatcher:
             prefix = len(chain) * self._kv.block
             if prefix + self._bucket(len(req0.ids) - prefix) <= cache_len:
                 break
-            chain.pop()
+            chain = self._kv.shorter(chain)
         if not chain:
             return None
         req = self._pending.popleft()
@@ -1675,7 +1784,8 @@ class ContinuousBatcher:
                 self._params, self._kv.pool, jnp.asarray(ids),
                 jnp.asarray(block_ids),
                 jnp.asarray(prefix_len, jnp.int32),
-                jnp.asarray([len(suffix)], jnp.int32), key)
+                jnp.asarray([len(suffix)], jnp.int32), key,
+                self._kv.snap_arg(chain[-1].snap))
             # insert true_lens = the FULL prompt length: the slab's
             # cache_index already sits at prefix+suffix and the pool
             # lane must agree.  The draft has no pool: its slab is
@@ -1704,10 +1814,10 @@ class ContinuousBatcher:
         kv = self._kv
 
         def prefill(params, pool, ids, block_ids, prefix_len, true_lens,
-                    key):
+                    key, snap_id):
             cache = _zeros_of(self._cache_shapes(1))
             cache = kv.load_prefix_into(cache, pool, block_ids, n_pad,
-                                        prefix_len)
+                                        prefix_len, snap_id)
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, ids,
                 positions=prefix_len
@@ -1736,6 +1846,7 @@ class ContinuousBatcher:
             self._first_tokens += len(reqs)
             self._ttft_s += sum(ttfts)
         for slot, req, tok in zip(slots, reqs, toks.tolist()):
+            self._snap_prompt(slot, req)
             s = self._slots[slot]
             s.request = req
             s.emitted = [int(tok)]
@@ -1743,6 +1854,24 @@ class ContinuousBatcher:
             self._toks[slot] = int(tok)
             if s.remaining == 0 or int(tok) == self._eos:
                 self._finish(slot)
+
+    def _snap_prompt(self, slot: int, req: "_Request") -> None:
+        """The window layers' state at the end of the PROMPT, while the
+        slot's rings still hold it (decode starts with the next tick):
+        the last window before the deepest block edge the same prompt
+        can match again, ``(len - 1) // block`` blocks down.  Without
+        it only a continuation of prompt + answer could start from the
+        pool; with it a prompt that comes again does.  One small
+        dispatch an admission; the commit gives the snapshot its node."""
+        if self._kv is None or not self._ring_layers:
+            return
+        end = (len(req.ids) - 1) // self._kv.block * self._kv.block
+        if end <= req.skipped:     # nothing new: the hit's own snapshot
+            return
+        sid = self._kv.snap_alloc()
+        if sid:
+            self._kv.store_blocks(self._cache, slot, 0, [], (sid, end))
+            req.snap = (sid, end)
 
     def _live_mask(self, active: list[int]):
         """The decode step's ``live`` argument: [slots] bool."""
@@ -1760,9 +1889,11 @@ class ContinuousBatcher:
             if moe.ndim == 0:
                 self._moe_drops += int(moe)
                 return
-            drops, assigned, touched, load, calls = moe.tolist()
+            drops, assigned, touched, load, *routed, calls = moe.tolist()
             self._moe_drops += int(drops)
             self._moe_assignments += int(assigned)
+            self._moe_assignments_routed += int(
+                routed[0] if routed else assigned)
             if decode:
                 self._moe_decode_layer_steps += int(calls)
                 self._moe_decode_experts_touched += int(touched)
@@ -1778,13 +1909,18 @@ class ContinuousBatcher:
         T, cap = self._T, self._dcfg.max_len
         # step t of the program read a live slot up to the token it
         # appended: prompt + emitted so far + t positions
-        kv_live = sum(min(len(s.request.ids) + len(s.emitted) + t, cap)
-                      for s in self._slots if not s.free for t in range(T))
+        held = [min(len(s.request.ids) + len(s.emitted) + t, cap)
+                for s in self._slots if not s.free for t in range(T)]
+        kv_live = sum(held)
         with self._stats_lock:
             self._lane_steps += len(self._slots) * T
             self._active_lane_steps += n_active * T
             self._kv_tokens_live += kv_live
             self._kv_tokens_slab += len(self._slots) * cap * T
+            if self._ring_layers:
+                W = self._dcfg.attn_window
+                self._kv_window_read += sum(map(self._ring_fetch, held))
+                self._kv_window_need += sum(min(n, W) for n in held)
         for i, s in enumerate(self._slots):
             if s.free:      # occupied slots always have remaining >= 1
                 continue
@@ -1866,6 +2002,20 @@ class ContinuousBatcher:
         seq = np.concatenate([req.ids,
                               np.asarray(emitted[:-1], np.int32)])
         start_block, new_ids, tail = self._kv.commit(seq)
-        self._kv.store_blocks(self._cache, slot, start_block, new_ids)
+        sid, at = req.snap
+        chain, depth = self._kv.committed, at // self._kv.block
+        self._kv.snap_attach(
+            chain[depth - 1] if 0 < depth <= len(chain) else None, sid)
+        snap = (0, 0)
+        if self._ring_layers and tail is not None:
+            # the window layers' last window before the tail's end, as
+            # the slot's rings still hold it: the program may have run
+            # steps_per_sync - 1 token steps past the request's end,
+            # each overwriting the oldest ring position
+            end = (start_block + len(new_ids)) * self._kv.block
+            oldest = len(seq) + self._T - 1 - self._dcfg.ring_len
+            if max(0, end - self._dcfg.attn_window) >= oldest:
+                snap = (self._kv.snap_for(tail), end)
+        self._kv.store_blocks(self._cache, slot, start_block, new_ids, snap)
         if req.session is not None and tail is not None:
             self._kv.pin_session(req.session, tail)

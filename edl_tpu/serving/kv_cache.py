@@ -54,6 +54,23 @@ Design (PagedAttention re-shaped for the engine's attention layout):
   block move is shard-local by construction — no collective can
   appear in the pool path (doc/serving.md "Mesh-sharded paged KV").
 
+- **two allocation classes** — a stack may mix global layers (whole
+  context) with sliding-window layers, whose slot state is a ring of a
+  window and a little more (``transformer.Block._ring_attention``).
+  Global layers page by blocks as above.  A window layer keeps no
+  blocks: a block deep in a chain would need keys the ring dropped
+  long before the commit.  What a prefix hit needs of it is the LAST
+  WINDOW of the prefix and nothing else, so window layers page by
+  **snapshots**: ``window`` positions ending at a chain node's end,
+  taken from the slot's ring when a commit ends there and when a
+  prompt's prefill ends (at the deepest node the SAME prompt can match
+  again), owned by that node, in a pool of their own (``n_snaps``
+  entries, LRU among unpinned holders; entry 0 is scratch).  A chain is reusable as deep
+  as its deepest snapshot-bearing node (``match`` truncates to it); the
+  gather puts the snapshot back at ring slots ``position % ring`` and
+  the global blocks at the front of the slabs, in the same fused jit.
+  One trie, one LRU clock, one ``store_blocks`` dispatch for both.
+
 Thread model: single-writer — every mutating call runs on the engine
 thread (admission, finish-commit, import-task); ``export_chain`` runs
 only after the engine thread has stopped.  Counters are plain ints read
@@ -79,21 +96,26 @@ def _pool_shapes(n_blocks: int, hk: int, d: int, block: int) -> dict:
 
 
 def pool_device_bytes(cache_shapes, block: int, n_blocks: int,
-                      tp: int = 1) -> int:
+                      tp: int = 1, ring_layers=(), window: int = 0,
+                      n_snaps: int = 0) -> int:
     """Per-device HBM bytes of the pool :class:`PagedKVCache` would
     allocate for this cache skeleton (KV heads split over ``tp`` where
-    they divide, as the constructor shards them).  Plain element
-    counts: libtpu lays the 16-token minor dim of a K block out
+    they divide, as the constructor shards them): ``n_blocks`` blocks
+    of ``block`` tokens for every global layer, ``n_snaps`` snapshots
+    of ``window`` tokens for every layer in ``ring_layers``.  Plain
+    element counts: libtpu lays the 16-token minor dim of a K block out
     major-most rather than padding it to 128 lanes (measured on v5e:
     device bytes / nominal = 1.00 for both buffers, f32 and bf16)."""
     total = 0
-    for node in cache_shapes.values():
+    for name, node in cache_shapes.items():
         _, hk, d, _ = node["cached_key"].shape
         hk = hk // tp if tp > 1 and hk % tp == 0 else hk
         item = np.dtype(node["cached_key"].dtype).itemsize
+        shapes = (_pool_shapes(n_snaps, hk, d, window)
+                  if name in ring_layers
+                  else _pool_shapes(n_blocks, hk, d, block))
         total += sum(int(np.prod(shape, dtype=np.int64)) * item
-                     for shape in _pool_shapes(n_blocks, hk, d,
-                                               block).values())
+                     for shape in shapes.values())
     return total
 
 
@@ -101,7 +123,7 @@ class _Node:
     """One committed block in the prefix trie."""
 
     __slots__ = ("chunk", "block_id", "parent", "children", "pins",
-                 "last_use")
+                 "last_use", "snap")
 
     def __init__(self, chunk: tuple, block_id: int, parent: "_Node | None"):
         self.chunk = chunk
@@ -110,6 +132,7 @@ class _Node:
         self.children: dict[tuple, _Node] = {}
         self.pins = 0
         self.last_use = 0
+        self.snap = 0       # window snapshot ending at this node (0: none)
 
 
 class PagedKVCache:
@@ -128,7 +151,8 @@ class PagedKVCache:
     """
 
     def __init__(self, cache_shapes, block: int, n_blocks: int,
-                 max_sessions: int, mesh=None):
+                 max_sessions: int, mesh=None, ring_layers=(),
+                 window: int = 0, n_snaps: int = 0):
         import jax
         import jax.numpy as jnp
 
@@ -139,6 +163,19 @@ class PagedKVCache:
         self.block = int(block)
         self.n_blocks = int(n_blocks)
         self._layers: list[str] = sorted(cache_shapes)
+        # the window class (module docstring): layers whose slot state
+        # is a ring, the window a snapshot holds, and how many there are
+        self._ring = frozenset(ring_layers)
+        self.window = int(window) if self._ring else 0
+        self.n_snaps = int(n_snaps) if self._ring else 0
+        if self._ring and (self.window < 1 or self.n_snaps < 2):
+            raise ValueError(
+                f"window layers {sorted(self._ring)} need a window and at "
+                f"least 2 snapshots, got {window} and {n_snaps}")
+        if self._ring and mesh is not None:
+            raise ValueError(
+                "a paged KV cache with window layers is not sharded over "
+                "a mesh: the snapshot pool has no sharded gather yet")
         self._layout: dict[str, tuple] = {}
         for name in self._layers:
             node = cache_shapes[name]
@@ -149,12 +186,16 @@ class PagedKVCache:
                     f"{name} carries {sorted(node)} (MoE/custom decode "
                     f"caches are served unpaged)")
             k = node["cached_key"]          # [slots, Hk, D, max_len]
-            _, hk, d, max_len = k.shape
-            if block > max_len:
+            _, hk, d, length = k.shape
+            if name in self._ring:
+                if length < self.window:
+                    raise ValueError(
+                        f"layer {name}'s ring holds {length} positions, "
+                        f"fewer than the window {self.window}")
+            elif block > length:
                 raise ValueError(
-                    f"kv block {block} exceeds cache length {max_len}")
+                    f"kv block {block} exceeds cache length {length}")
             self._layout[name] = (hk, d, k.dtype)
-        self.max_len = max_len
         self._mesh = mesh
         self._tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
         # per-layer: shard the pool over ``tp`` on the KV-head axis
@@ -168,8 +209,10 @@ class PagedKVCache:
         # block 0 is a reserved scratch block (never allocated) so a
         # zero-filled block-id vector can never alias live state
         self.pool = {
-            name: {ax: jnp.zeros(shape, dtype) for ax, shape
-                   in _pool_shapes(n_blocks, hk, d, block).items()}
+            name: {ax: jnp.zeros(shape, dtype) for ax, shape in (
+                _pool_shapes(self.n_snaps, hk, d, self.window)
+                if name in self._ring
+                else _pool_shapes(n_blocks, hk, d, block)).items()}
             for name, (hk, d, dtype) in self._layout.items()
         }
         if mesh is not None:
@@ -180,6 +223,11 @@ class PagedKVCache:
                        for ax, spec in node.items()}
                 for name, node in self._pool_specs().items()})
         self._free: list[int] = list(range(n_blocks - 1, 0, -1))
+        # snapshot 0 is scratch, like block 0; holders in LRU order
+        self._snap_free: list[int] = list(range(self.n_snaps - 1, 0, -1))
+        self._snap_lru: "OrderedDict[_Node, None]" = OrderedDict()
+        self.snap_skips = 0
+        self.committed: list[_Node] = []    # the last commit's chain
         self._root = _Node((), 0, None)
         self._nodes: set[_Node] = set()         # every live non-root node
         # lazy min-heap of eviction candidates (last_use, seq, node):
@@ -192,6 +240,7 @@ class PagedKVCache:
         self._max_sessions = max(1, int(max_sessions))
         self._clock = 0
         self._jit_cache: dict[tuple, object] = {}
+        self._zero = jnp.zeros((), jnp.int32)
         self._jax = jax
         self._jnp = jnp
         # -- counters (engine stats mirror these) --
@@ -221,10 +270,72 @@ class PagedKVCache:
                 break
             chain.append(child)
             node = child
+        chain = self.reusable(chain)
         self._clock += 1
         for nd in chain:
             nd.last_use = self._clock
+        if chain and self._ring:
+            self._snap_lru.move_to_end(chain[-1])
         return chain
+
+    def reusable(self, chain: list[_Node]) -> list[_Node]:
+        """``chain`` as deep as a prefix hit, here or on the replica a
+        session migrates to, can start from: with window layers, down
+        to its deepest node that owns a snapshot (the window layers'
+        last window ends there)."""
+        if self._ring:
+            while chain and not chain[-1].snap:
+                chain = chain[:-1]
+        return chain
+
+    def shorter(self, chain: list[_Node]) -> list[_Node]:
+        """The next shorter reusable chain (the engine shortens a hit
+        until prefix + suffix bucket fits the cache)."""
+        return self.reusable(chain[:-1])
+
+    # -- window snapshots ----------------------------------------------------
+    def snap_alloc(self) -> int:
+        """A free snapshot entry for the caller to write
+        (``store_blocks``) and then ``snap_attach`` or ``snap_release``:
+        0 when there is no window class or every entry belongs to a
+        pinned chain (counted)."""
+        if not self._ring:
+            return 0
+        if self._snap_free:
+            return self._snap_free.pop()
+        victim = next((nd for nd in self._snap_lru if not nd.pins), None)
+        if victim is None:
+            self.snap_skips += 1
+            return 0
+        return self._drop_snap(victim)
+
+    def snap_attach(self, node: "_Node | None", sid: int) -> None:
+        """``node`` owns snapshot ``sid`` from now on; an entry that
+        finds no node, or a node that has one, goes back."""
+        if not sid:
+            return
+        if node is None or node is self._root or node.snap:
+            self._snap_free.append(sid)
+            return
+        node.snap = sid
+        self._snap_lru[node] = None
+
+    def snap_for(self, node: "_Node | None") -> int:
+        """``snap_alloc`` + ``snap_attach`` for a node that has no
+        snapshot yet; 0 when it has one."""
+        if node is None or node.snap:
+            return 0
+        sid = self.snap_alloc()
+        self.snap_attach(node, sid)
+        return sid
+
+    def _drop_snap(self, nd: _Node) -> int:
+        sid, nd.snap = nd.snap, 0
+        self._snap_lru.pop(nd, None)
+        return sid
+
+    def snaps_used(self) -> int:
+        return len(self._snap_lru)
 
     def commit(self, tokens) -> tuple[int, list[int], "_Node | None"]:
         """Extend the trie with every full block of ``tokens`` that is
@@ -235,12 +346,17 @@ class PagedKVCache:
         n_full = len(tokens) // self.block
         node = self._root
         chunks = list(self._chunks(tokens, n_full))
+        # the nodes of this commit's chain, root-first: ``committed[d -
+        # 1]`` ends d blocks down (the engine hangs a prompt's window
+        # snapshot on one of them)
+        self.committed = []
         i = 0
         while i < n_full:
             child = node.children.get(chunks[i])
             if child is None:
                 break
             node = child
+            self.committed.append(node)
             i += 1
         start = i
         new_ids: list[int] = []
@@ -249,6 +365,7 @@ class PagedKVCache:
             if child is None:
                 break
             node = child
+            self.committed.append(node)
             new_ids.append(child.block_id)
         tail = node if node is not self._root else None
         return start, new_ids, tail
@@ -307,6 +424,8 @@ class PagedKVCache:
                 continue
             del parent.children[nd.chunk]
             self._nodes.discard(nd)
+            if nd.snap:
+                self._snap_free.append(self._drop_snap(nd))
             if parent is not self._root and not parent.children:
                 self._heap_push(parent)       # newly a leaf
             self.evictions += 1
@@ -404,19 +523,44 @@ class PagedKVCache:
         return self._jax.jit(wrapped, donate_argnums=donate)
 
     # -- jitted device ops ---------------------------------------------------
-    def load_prefix_into(self, cache, pool, block_ids, n: int, prefix_len):
+    def _ring_slots(self, end, ring: int):
+        """Ring slots of the ``window`` positions that end at ``end``
+        (traced): position p lives at slot ``p % ring``.  Positions
+        before 0 fall on slots the ring's own position mask never
+        reads."""
+        return (end - self.window + self._jnp.arange(self.window)) % ring
+
+    def load_prefix_into(self, cache, pool, block_ids, n: int, prefix_len,
+                         snap_id=0):
         """Pure helper traced INSIDE the engine's reuse-prefill jit
         (``pool`` is the traced argument — never read device state off
         ``self`` under a trace): gather ``n`` (padded) blocks into the
         front of a fresh one-lane cache and set its index to the traced
         ``prefix_len`` (<= ``n * block``; the scratch-padded tail lands
         beyond it and is overwritten or masked before any query can
-        attend it)."""
+        attend it).  Window layers take snapshot ``snap_id`` (the
+        chain tail's) into the ring slots of the ``window`` positions
+        before ``prefix_len``: exactly the last window of the prefix."""
         jnp = self._jnp
         bs = self.block
         out = {}
         for name in self._layers:
             node = cache[name]
+            if name in self._ring:
+                ring = node["cached_key"].shape[-1]
+                slots = self._ring_slots(prefix_len, ring)
+                k = pool[name]["k"][snap_id]          # [Hk, D, window]
+                v = pool[name]["v"][snap_id]          # [Hk, window, D]
+                out[name] = {
+                    "cached_key": node["cached_key"][0].at[:, :, slots].set(
+                        k.astype(node["cached_key"].dtype))[None],
+                    "cached_value": node["cached_value"][0].at[
+                        :, slots, :].set(
+                        v.astype(node["cached_value"].dtype))[None],
+                    "cache_index": jnp.full_like(node["cache_index"],
+                                                 prefix_len),
+                }
+                continue
             k = pool[name]["k"][block_ids]            # [n, Hk, D, bs]
             k = jnp.moveaxis(k, 0, 2).reshape(
                 k.shape[1], k.shape[2], n * bs)
@@ -446,9 +590,28 @@ class PagedKVCache:
         bs = self.block
         layers = self._layers
 
-        def scatter(pool, cache, slot, start, block_ids):
+        ring_layers = self._ring
+
+        def scatter(pool, cache, slot, start, block_ids, snap_id, snap_end):
             out = {}
             for name in layers:
+                if name in ring_layers:
+                    # the window that ends at snap_end, out of the
+                    # slot's ring, into snapshot snap_id (0: scratch)
+                    k_lane = jnp.take(cache[name]["cached_key"], slot, axis=0)
+                    v_lane = jnp.take(cache[name]["cached_value"], slot,
+                                      axis=0)
+                    slots = self._ring_slots(snap_end, k_lane.shape[-1])
+                    out[name] = {
+                        "k": pool[name]["k"].at[snap_id].set(
+                            k_lane[:, :, slots]),
+                        "v": pool[name]["v"].at[snap_id].set(
+                            v_lane[:, slots, :]),
+                    }
+                    continue
+                if not n:
+                    out[name] = pool[name]
+                    continue
                 # head/feature extents come from the OPERANDS, not the
                 # global layout: under shard_map this body sees the
                 # per-shard slice (hk/tp heads), and the slab/pool pair
@@ -472,21 +635,35 @@ class PagedKVCache:
 
         fn = self._pool_jit(
             scatter, (self._pool_specs(), self._cache_specs(),
-                      P(), P(), P()), donate=(0,))
+                      P(), P(), P(), P(), P()), donate=(0,))
         self._jit_cache[key] = fn
         return fn
 
     def store_blocks(self, cache, slot: int, start_block: int,
-                     block_ids: list[int]) -> None:
+                     block_ids: list[int], snap: tuple[int, int] = (0, 0),
+                     warm: bool = False) -> None:
         """Write blocks ``[start_block, start_block + len(ids))`` of the
-        slot's slab into the pool (one dispatch)."""
-        if not block_ids:
+        slot's slab into the pool and, for window layers, snapshot
+        ``snap = (id, end position)`` out of the slot's rings (one
+        dispatch for both; id 0 writes the scratch entry).  ``warm``
+        runs the program though there is nothing to write (into the
+        scratch entries: ``ContinuousBatcher.warm``)."""
+        if not block_ids and not snap[0] and not warm:
             return
         jnp = self._jnp
         self.pool = self._scatter_fn(len(block_ids))(
             self.pool, cache, jnp.asarray(slot, jnp.int32),
             jnp.asarray(start_block * self.block, jnp.int32),
-            jnp.asarray(block_ids, jnp.int32))
+            jnp.asarray(block_ids, jnp.int32),
+            self.snap_arg(snap[0]), self.snap_arg(snap[1]))
+
+    def snap_arg(self, value: int):
+        """A snapshot id or end position as the pool programs take it:
+        a device scalar, made once for the 0 that every call of a stack
+        without window layers passes (no transfer a commit there)."""
+        if not value:
+            return self._zero
+        return self._jnp.asarray(value, self._jnp.int32)
 
     def _gather_fn(self, n: int):
         key = ("gather", n)
@@ -495,26 +672,34 @@ class PagedKVCache:
             return fn
         layers = self._layers
 
-        def gather(pool, block_ids):
-            return {name: {"k": pool[name]["k"][block_ids],
-                           "v": pool[name]["v"][block_ids]}
-                    for name in layers}
+        ring_layers = self._ring
+
+        def gather(pool, block_ids, snap_ids):
+            return {name: {ax: pool[name][ax][
+                snap_ids if name in ring_layers else block_ids]
+                for ax in ("k", "v")} for name in layers}
 
         from jax.sharding import PartitionSpec as P
 
-        fn = self._pool_jit(gather, (self._pool_specs(), P()))
+        fn = self._pool_jit(gather, (self._pool_specs(), P(), P()))
         self._jit_cache[key] = fn
         return fn
 
     # -- migration wire format ----------------------------------------------
     def export_chain(self, chain: list[_Node]) -> tuple[dict, bytes]:
         """(meta, blob) for one chain: per layer (sorted), the k blocks
-        then the v blocks, raw ``tobytes()`` concatenated.  ``meta``
-        carries what the importer must agree on; tokens travel beside it
-        (the chain IS the token sequence)."""
-        ids = self._jnp.asarray([nd.block_id for nd in chain],
-                                self._jnp.int32)
-        got = self._gather_fn(len(chain))(self.pool, ids)
+        then the v blocks, raw ``tobytes()`` concatenated; for a window
+        layer the tail's one snapshot in their place.  ``meta`` carries
+        what the importer must agree on; tokens travel beside it (the
+        chain IS the token sequence).  With window layers the chain
+        must end at a snapshot-bearing node (``reusable``)."""
+        jnp = self._jnp
+        if self._ring and not (chain and chain[-1].snap):
+            raise ValueError("chain tail owns no window snapshot")
+        ids = jnp.asarray([nd.block_id for nd in chain], jnp.int32)
+        snaps = jnp.asarray([chain[-1].snap if self._ring else 0],
+                            jnp.int32)
+        got = self._gather_fn(len(chain))(self.pool, ids, snaps)
         parts: list[bytes] = []
         for name in self._layers:
             parts.append(np.asarray(got[name]["k"]).tobytes())
@@ -522,6 +707,7 @@ class PagedKVCache:
         blob = b"".join(parts)
         meta = {"block": self.block, "n": len(chain),
                 "layers": list(self._layers),
+                "window": self.window, "ring_layers": sorted(self._ring),
                 "layout": {name: [hk, d, str(np.dtype(dtype))]
                            for name, (hk, d, dtype) in self._layout.items()}}
         return meta, blob
@@ -542,6 +728,9 @@ class PagedKVCache:
                 f"{self.block}")
         if list(meta["layers"]) != self._layers:
             raise ValueError("kv import layer set mismatch")
+        if (int(meta.get("window", 0)) != self.window
+                or list(meta.get("ring_layers", [])) != sorted(self._ring)):
+            raise ValueError("kv import window layers mismatch")
         for name, (hk, d, dtype) in self._layout.items():
             if list(meta["layout"][name]) != [hk, d,
                                               str(np.dtype(dtype))]:
@@ -556,13 +745,16 @@ class PagedKVCache:
         for name in self._layers:
             hk, d, dtype = self._layout[name]
             item = np.dtype(dtype).itemsize
-            k_bytes = n * hk * d * self.block * item
+            # a window layer carries one snapshot, a global one n blocks
+            m, t = ((1, self.window) if name in self._ring
+                    else (n, self.block))
+            k_bytes = m * hk * d * t * item
             arrays[name] = {
-                "k": np.frombuffer(blob, dtype, count=n * hk * d * self.block,
-                                   offset=off).reshape(n, hk, d, self.block),
-                "v": np.frombuffer(blob, dtype, count=n * hk * self.block * d,
+                "k": np.frombuffer(blob, dtype, count=m * hk * d * t,
+                                   offset=off).reshape(m, hk, d, t),
+                "v": np.frombuffer(blob, dtype, count=m * hk * t * d,
                                    offset=off + k_bytes
-                                   ).reshape(n, hk, self.block, d),
+                                   ).reshape(m, hk, t, d),
             }
             off += 2 * k_bytes
         if off != len(blob):
@@ -570,6 +762,7 @@ class PagedKVCache:
                 f"kv import blob is {len(blob)} bytes, layout needs {off}")
         node = self._root
         fresh: list[tuple[int, int]] = []      # (chain idx, block id)
+        depth = 0
         for i, chunk in enumerate(self._chunks(tokens, n)):
             child = node.children.get(chunk)
             if child is None:
@@ -581,20 +774,28 @@ class PagedKVCache:
                 self._clock += 1
                 child.last_use = self._clock
             node = child
-        if fresh:
+            depth += 1
+        # the snapshot belongs to the exported tail: a walk the pool cut
+        # short ends elsewhere and resumes without it (a cold start)
+        snap = self.snap_for(node) if depth == n else 0
+        if fresh or snap:
             idx = [i for i, _ in fresh]
             ids = jnp.asarray([b for _, b in fresh], jnp.int32)
+            snaps = jnp.asarray([snap], jnp.int32)
             upload = {
-                name: {"k": jnp.asarray(arrays[name]["k"][idx]),
-                       "v": jnp.asarray(arrays[name]["v"][idx])}
+                name: ({"k": jnp.asarray(arrays[name]["k"]),
+                        "v": jnp.asarray(arrays[name]["v"])}
+                       if name in self._ring else
+                       {"k": jnp.asarray(arrays[name]["k"][idx]),
+                        "v": jnp.asarray(arrays[name]["v"][idx])})
                 for name in self._layers}
+            ring_layers = self._ring
 
-            def put(pool, ids, upload):
-                return {name: {"k": pool[name]["k"].at[ids].set(
-                                   upload[name]["k"]),
-                               "v": pool[name]["v"].at[ids].set(
-                                   upload[name]["v"])}
-                        for name in self._layers}
+            def put(pool, ids, snaps, upload):
+                return {name: {ax: pool[name][ax].at[
+                    snaps if name in ring_layers else ids].set(
+                        upload[name][ax]) for ax in ("k", "v")}
+                    for name in self._layers}
 
             key = ("import", len(fresh))
             fn = self._jit_cache.get(key)
@@ -606,10 +807,10 @@ class PagedKVCache:
                 # ITS heads of every fresh block — shape-aligned with
                 # its pool slice by construction
                 fn = self._pool_jit(
-                    put, (self._pool_specs(), P(), self._pool_specs()),
+                    put, (self._pool_specs(), P(), P(), self._pool_specs()),
                     donate=(0,))
                 self._jit_cache[key] = fn
-            self.pool = fn(self.pool, ids, upload)
+            self.pool = fn(self.pool, ids, snaps, upload)
         if node is self._root:
             # a pool too full for even the FIRST block adopted nothing:
             # raising lets the exporter try the next candidate instead

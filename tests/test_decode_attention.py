@@ -208,6 +208,135 @@ def test_toy_engine_gives_the_einsum_paths_tokens_through_a_readmission(
     assert stats["decode_kv_tokens_slab"] % (2 * 128 * 4) == 0
 
 
+# -- a window layer's ring (PR 30) ------------------------------------------
+# the same two kernels over a ring of 256 positions and a window of 128:
+# slot -> (cache_index before the step, live).  Position p lives at ring
+# slot p % 256; the step appends at index % 256 and attends the last
+# min(index + 1, 128) positions, wrapping
+RING, WINDOW = 256, 128
+RING_SLOTS = {
+    "first_token": (0, True),
+    "inside_the_first_window": (5, True),
+    "window_just_full": (WINDOW - 1, True),
+    "window_slides": (WINDOW, True),
+    "free_before_the_wrap": (200, False),
+    "last_slot_of_the_ring": (RING - 1, True),
+    "append_wraps_to_slot_0": (RING, True),
+    "window_spans_the_seam": (RING + 44, True),
+    "free_after_the_wrap": (RING + 77, False),
+    "second_lap_ends": (2 * RING - 1, True),
+    "many_laps": (5 * RING + 131, True),
+}
+RING_NAMES = list(RING_SLOTS)
+RING_INDEX = np.array([RING_SLOTS[n][0] for n in RING_NAMES], np.int32)
+RING_LIVE = np.array([RING_SLOTS[n][1] for n in RING_NAMES])
+
+
+@functools.lru_cache(maxsize=None)
+def _one_ring_step(G: int, dtype_name: str):
+    """One decode token step of a one-layer WINDOW model over a ring
+    full of stale data, on the einsum path and on the kernel path (two
+    attend blocks of 128, so a window may lie in one block only)."""
+    dtype = jnp.dtype(dtype_name)
+    Hk, D, B = 2, 16, len(RING_NAMES)
+    cfg = TransformerConfig(vocab_size=61, num_layers=1, embed_dim=Hk * G * D,
+                            num_heads=Hk * G, num_kv_heads=Hk, mlp_dim=32,
+                            max_len=8 * RING, remat=False, dtype=dtype,
+                            decode=True, attention_impl="dense",
+                            attn_window=WINDOW, window_ring=RING)
+    model = TransformerLM(cfg)
+    ids = jnp.asarray(np.arange(B)[:, None] % 61, jnp.int32)
+    init = model.init(jax.random.key(0), ids,
+                      positions=jnp.zeros((B, 1), jnp.int32))
+    layer = init["cache"]["layer_0"]
+    assert layer["cached_key"].shape == (B, Hk, D, RING)
+    kk, kv = jax.random.split(jax.random.key(1))
+    cache = {"layer_0": {
+        "cached_key": jax.random.normal(kk, layer["cached_key"].shape,
+                                        dtype),
+        "cached_value": jax.random.normal(kv, layer["cached_value"].shape,
+                                          dtype),
+        "cache_index": jnp.asarray(RING_INDEX)}}
+
+    def step():
+        return model.apply(
+            {"params": init["params"], "cache": cache}, ids,
+            positions=jnp.asarray(RING_INDEX)[:, None],
+            token_mask=jnp.asarray(RING_LIVE)[:, None], mutable=["cache"])
+
+    want, want_mut = step()
+    with pytest.MonkeyPatch.context() as mp:
+        _force_kernels(mp, BLOCK)
+        got, got_mut = step()
+    to_np = functools.partial(jax.tree.map, lambda a: np.asarray(
+        a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a))
+    return (to_np(cache["layer_0"]), np.asarray(want),
+            to_np(want_mut["cache"]["layer_0"]), np.asarray(got),
+            to_np(got_mut["cache"]["layer_0"]))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("G", [4, 1], ids=["gqa4", "mha"])
+@pytest.mark.parametrize("slot", RING_NAMES)
+def test_ring_kernel_step_matches_the_einsum_step(slot, G, dtype):
+    before, want, want_cache, got, got_cache = _one_ring_step(G, dtype)
+    b = RING_NAMES.index(slot)
+    at, live = RING_SLOTS[slot]
+    assert got_cache["cache_index"][b] == at + 1 == (
+        want_cache["cache_index"][b])
+    k, v = got_cache["cached_key"][b], got_cache["cached_value"][b]
+    # the einsum path's ring exactly: a live slot's new column at
+    # index % ring and nothing else, a free slot's ring untouched
+    np.testing.assert_array_equal(k, want_cache["cached_key"][b])
+    np.testing.assert_array_equal(v, want_cache["cached_value"][b])
+    rest = np.arange(RING) != at % RING if live else np.full(RING, True)
+    np.testing.assert_array_equal(k[:, :, rest],
+                                  before["cached_key"][b][:, :, rest])
+    np.testing.assert_array_equal(v[:, rest],
+                                  before["cached_value"][b][:, rest])
+    if live:
+        assert (k[:, :, at % RING]
+                != before["cached_key"][b][:, :, at % RING]).any()
+        tol = 1e-5 if dtype == "float32" else 3e-2
+        np.testing.assert_allclose(got[b], want[b], atol=tol, rtol=tol)
+    assert np.isfinite(got[b]).all()
+
+
+def test_ring_attend_sees_the_window_and_nothing_older():
+    """``decode_attend`` in ring mode against plain softmax attention
+    over the ``visible`` most recent ring slots, for a window that lies
+    in one block, spans both, and wraps; moving a key OUTSIDE the
+    window changes nothing, moving one inside it does."""
+    B, H, Hk, D = 4, 4, 2, 16
+    ks = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(ks[0], (B, H, D))
+    k = jax.random.normal(ks[1], (B, Hk, D, RING))
+    v = jax.random.normal(ks[2], (B, Hk, RING, D))
+    newest = jnp.asarray([40, 150, 20, RING - 1], jnp.int32)
+    visible = jnp.asarray([41, 128, 128, 128], jnp.int32)
+    held = jnp.asarray([41, 151, RING, RING], jnp.int32)
+
+    def attend(k, v):
+        return np.asarray(decode_attention.decode_attend(
+            q, k, v, held, newest=newest, visible=visible, block=BLOCK,
+            interpret=True))
+
+    got = attend(k, v)
+    for b in range(B):
+        slots = (int(newest[b]) - np.arange(int(visible[b]))) % RING
+        kb, vb = np.asarray(k[b])[:, :, slots], np.asarray(v[b])[:, slots]
+        for h in range(H):
+            s = np.asarray(q[b, h]) @ kb[h // 2] * D ** -0.5
+            p = np.exp(s - s.max())
+            np.testing.assert_allclose(got[b, h], (p / p.sum()) @ vb[h // 2],
+                                       atol=2e-5, rtol=2e-5)
+    # slot 0's window is ring slots 0..40; slot 2's wraps: 149..255, 0..20
+    outside = attend(k.at[:, :, :, 41].set(9.0).at[2, :, :, 100].set(9.0), v)
+    np.testing.assert_array_equal(outside[[0, 2]], got[[0, 2]])
+    inside = attend(k.at[2, :, :, 200].set(9.0), v)
+    assert np.abs(inside[2] - got[2]).max() > 1e-3
+
+
 # -- compiled for the chip, without the chip ---------------------------------
 
 @contextlib.contextmanager

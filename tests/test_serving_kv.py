@@ -327,3 +327,131 @@ def test_engine_that_cannot_fit_is_refused_at_construction(small,
     monkeypatch.setattr(jax, "devices", lambda: [_Chip(1 << 40)])
     eng = ContinuousBatcher(cfg, params, slots=2, kv_block=8)
     eng.stop()
+
+
+# -- window layers: the second allocation class (PR 30) ----------------------
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """Two window layers (window 8) around two global ones."""
+    cfg = TransformerConfig(vocab_size=97, num_layers=4, embed_dim=32,
+                            num_heads=4, mlp_dim=64, max_len=96,
+                            remat=False, dtype=jnp.float32, attn_window=8,
+                            layer_attn=("window", "global", "global",
+                                        "window"))
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return cfg, params
+
+
+def test_two_allocation_classes_in_one_pool(hybrid):
+    """Global layers page by blocks, window layers by snapshots of one
+    window; a slot's window state is a ring whatever ``max_len`` is."""
+    cfg, params = hybrid
+    eng = _engine(cfg, params, kv_max_sessions=2)
+    try:
+        ring = 8 + 4 + 4 - 1            # window + kv_block + steps - 1
+        pool = {n: tuple(b["k"].shape) for n, b in eng._kv.pool.items()}
+        assert pool == {"layer_0": (2 + 2 * 3 + 1, 4, 8, 8),
+                        "layer_1": (64, 4, 8, 4), "layer_2": (64, 4, 8, 4),
+                        "layer_3": (2 + 2 * 3 + 1, 4, 8, 8)}
+        slabs = {n: c["cached_key"].shape[-1] for n, c in eng._cache.items()}
+        assert slabs == {"layer_0": ring, "layer_1": 96, "layer_2": 96,
+                         "layer_3": ring}
+        stats = eng.stats()
+        assert stats["kv_slot_bytes_window"] == 2 * 2 * 4 * 8 * ring * 4
+        assert stats["kv_slot_bytes_global"] == 2 * 2 * 4 * 8 * 96 * 4
+    finally:
+        eng.stop()
+
+
+def test_hybrid_second_turn_resumes_from_the_last_window(hybrid):
+    """A prefix hit leaves the window layers holding exactly the last
+    window of the prefix: the second turn's tokens are those of an
+    engine that never had a pool, after the rings wrapped twice."""
+    cfg, params = hybrid
+    rng = np.random.default_rng(3)
+    p1 = rng.integers(1, 97, (21,)).astype(np.int32)
+    eng, cold = _engine(cfg, params), _engine(cfg, params, kv_block=0)
+    try:
+        out1 = eng.submit(p1, 14, session="s").result(120)
+        np.testing.assert_array_equal(out1, cold.generate(p1, 14, 120))
+        p2 = np.concatenate([p1, out1, np.asarray([4, 1, 9], np.int32)])
+        out2 = eng.submit(p2, 9, session="s").result(120)
+        np.testing.assert_array_equal(out2, cold.generate(p2, 9, 120))
+        stats = eng.stats()
+    finally:
+        eng.stop()
+        cold.stop()
+    assert stats["kv_prefix_hits"] == 1, stats
+    assert stats["kv_prefill_tokens_skipped"] == (21 + 13) // 4 * 4
+    assert stats["kv_window_snapshots"] >= 2
+
+
+def test_export_import_of_a_session_whose_window_layers_wrapped(hybrid):
+    """The migration primitive with both classes: the chain's blocks
+    for the global layers and the tail's one snapshot for the window
+    layers travel in one blob; the adoptive engine's next turn skips
+    the prefix and answers as an engine without a pool does."""
+    cfg, params = hybrid
+    rng = np.random.default_rng(5)
+    p1 = rng.integers(1, 97, (23,)).astype(np.int32)
+    eng_a = _engine(cfg, params)
+    try:
+        out1 = eng_a.submit(p1, 18, session="s").result(120)
+        conv = np.concatenate([p1, out1])
+        assert eng_a.drain(timeout=30)
+        ((name, tokens, meta, blob),) = eng_a.export_sessions()
+    finally:
+        eng_a.stop()
+    assert name == "s" and tokens == list(map(int, conv[:len(tokens)]))
+    assert len(tokens) == (23 + 17) // 4 * 4       # 40: the rings wrapped
+    assert meta["window"] == 8
+    assert meta["ring_layers"] == ["layer_0", "layer_3"]
+    per_token = 4 * 8 * 4 * 2                      # heads x dim x f32, K + V
+    assert len(blob) == per_token * (2 * len(tokens) + 2 * 8)
+
+    eng_b, cold = _engine(cfg, params), _engine(cfg, params, kv_block=0)
+    try:
+        assert eng_b.import_session("s", tokens, meta, blob) == 10
+        p2 = np.concatenate([conv, np.asarray([4, 1], np.int32)])
+        out2 = eng_b.generate(p2, 6, timeout=120)
+        np.testing.assert_array_equal(out2, cold.generate(p2, 6, 120))
+        stats = eng_b.stats()
+        assert stats["kv_prefix_hits"] == 1, stats
+        assert stats["kv_prefill_tokens_skipped"] == len(tokens), stats
+        # a dense engine's chain carries no window: refused, not adopted
+        with pytest.raises(ValueError, match="window layers mismatch"):
+            eng_b.import_session("t", tokens, dict(meta, window=0,
+                                                   ring_layers=[]), blob)
+    finally:
+        eng_b.stop()
+        cold.stop()
+
+
+def test_snapshots_are_an_lru_of_their_own(hybrid):
+    """More finished requests than snapshot entries: the oldest unpinned
+    holders give theirs up, a pinned session's tail keeps its own, and
+    every answer is still the cold engine's."""
+    cfg, params = hybrid
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 97, (n,)).astype(np.int32)
+               for n in (13, 17, 11, 19, 15, 12)]
+    eng = _engine(cfg, params, slots=1, kv_max_sessions=1)
+    cold = _engine(cfg, params, kv_block=0)
+    try:
+        first = eng.submit(prompts[0], 6, session="keep").result(120)
+        for p in prompts[1:]:
+            np.testing.assert_array_equal(eng.generate(p, 6, 120),
+                                          cold.generate(p, 6, 120))
+        kv = eng._kv
+        assert kv.n_snaps == 1 + 2 * 1 + 1 and kv.snaps_used() <= 3
+        assert kv.chain_of("keep")[-1].snap            # pinned: kept
+        p2 = np.concatenate([prompts[0], first, [5]]).astype(np.int32)
+        np.testing.assert_array_equal(
+            eng.submit(p2, 5, session="keep").result(120),
+            cold.generate(p2, 5, 120))
+        assert eng.stats()["kv_prefix_hits"] == 1
+    finally:
+        eng.stop()
+        cold.stop()
