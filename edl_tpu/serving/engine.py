@@ -11,13 +11,19 @@ pool of ``slots`` decode lanes over ONE persistent KV cache:
   the cache length, so any prompt that leaves room for one generated
   token is accepted);
 - every decode dispatch advances ALL slots ``steps_per_sync`` tokens
-  under one jitted ``lax.scan`` (host↔device sync once per chunk, not
-  per token — decode is host-driven, so the sync cadence sets the
-  floor);
+  under one jitted ``lax.scan``, and the host reads its results ONE
+  TICK LATE: tick n+1's programs are enqueued before tick n is read (a
+  one-tick lookahead, ``_tick``), so the device runs program after
+  program while the host reads, books and admits.  The step's last
+  tokens stay on the device as the next step's input, and the host
+  schedules from budgets (``max_new`` and the count of programs
+  enqueued), so the sync cadence no longer sets a floor under the
+  tick: ``steps_per_sync`` is how often the host looks (finish and
+  admission granularity), not what the device waits for;
 - prefill work is **bounded and overlapped**: each engine tick
-  dispatches at most ONE prefill group (so a burst of arrivals can
-  never starve running lanes), then the decode chunk, then the insert
-  — and syncs the host ONCE for all of it.  Active lanes advance
+  dispatches at most ONE prefill group (so a burst of arrivals can never
+  starve running lanes), then the decode chunk, then the insert — and
+  syncs the host ONCE for all of it, a tick later.  Active lanes advance
   ``steps_per_sync`` tokens every tick no matter how fast requests
   arrive; ``stats()['prefill_stall_s']`` bounds the decode wall-time
   cost of prefill dispatches: it is the host time of ``engine/admit``
@@ -35,9 +41,9 @@ pool of ``slots`` decode lanes over ONE persistent KV cache:
   pass, and greedy acceptance (token == the target's argmax) keeps the
   emitted stream bit-identical to plain decode while consuming up to
   k+1 tokens per target dispatch;
-- a finished slot (token budget or ``eos_id``) frees immediately and
-  the next queued request takes it — no convoy behind the longest
-  generation in a batch.
+- a finished slot (token budget or ``eos_id``) frees when the host
+  reads its last token and the next queued request takes it in the
+  next tick — no convoy behind the longest generation in a batch.
 
 Per-slot independence rests on the transformer's per-example
 ``cache_index`` contract (transformer.Block._decode_attention): each
@@ -56,9 +62,15 @@ live slot and no chunk in flight: waiting for requests; time BETWEEN
 ticks), then inside a tick ``tasks`` (closures from other threads),
 ``admit`` (queue drain, prefix matching, prefill/chunk dispatches),
 ``dispatch`` (the decode step or speculative round and the inserts),
-``sync`` (the ``np.asarray`` reads: blocked on the device), ``finish``
-(tokens to slots and futures) and, nested in it and deducted from it,
-``kv_commit``.  Each is exclusive host seconds in ``stats()``
+``sync`` (the ``np.asarray`` reads of the PREVIOUS tick's programs:
+blocked on the device, which meanwhile holds this tick's), ``finish``
+(that tick's tokens to slots and futures) and, nested in it and
+deducted from it, ``kv_commit``.  ``lookahead_ticks`` counts the ticks
+that enqueued a step or an insert behind an unread tick (a tick that
+only advanced a chunked admission has nothing to read and is not one),
+``lookahead_discarded_token_steps`` the token steps run for a slot
+after an EOS the host had not read.
+Each phase is exclusive host seconds in ``stats()``
 (``tick_<phase>_s``, ``idle_wait_s``, ``ticks``, ``tick_s``,
 ``tick_coverage``) and in ``edl_engine_tick_phase_seconds{phase}``, and
 an ``engine/<phase>`` span in any profiler capture.  Per request the
@@ -134,7 +146,10 @@ def _zeros_of(shapes):
 class _Slot:
     request: "_Request | None" = None
     emitted: list[int] = dataclasses.field(default_factory=list)
-    remaining: int = 0
+    remaining: int = 0    # tokens the host has yet to READ
+    # decode tokens no dispatched program covers yet: what the host
+    # schedules from.  A slot is live in the next step iff owed > 0
+    owed: int = 0
 
     @property
     def free(self) -> bool:
@@ -176,6 +191,21 @@ class _ChunkState:
     drops: object         # device MoE-drop accumulator (traced through)
 
 
+@dataclass
+class _Tick:
+    """What one tick enqueued and the host has not read: the decode
+    step's results with the (slot, request) pairs that were live in it,
+    and the admissions inserted behind it (``_dispatch_prefill``'s
+    tuples).  The requests are named because a slot can change hands
+    before the read: an EOS frees it one read late."""
+
+    live: list
+    dec: object = None
+    moe: object = None
+    counts: object = None
+    pres: list = dataclasses.field(default_factory=list)
+
+
 class _Task:
     """A closure the ENGINE THREAD runs between ticks (single-writer
     device mutations from other threads — e.g. a migrated-session KV
@@ -196,9 +226,13 @@ class ContinuousBatcher:
     (training config + trained params — layer stacking is split here).
     ``max_len`` bounds prompt+generation per slot (defaults to
     ``cfg.max_len``); the KV cache is [slots, ...] at that length.
-    ``steps_per_sync`` trades scheduling latency for dispatch
-    amortisation: a finished slot wastes at most ``steps_per_sync - 1``
-    lane-steps before the host notices.
+    ``steps_per_sync`` is a latency dial: token steps a program, so
+    how often the host reads (a request finishes, and a waiting one is
+    admitted, at a program's end) against dispatches per token.  The
+    device does not wait for those reads (``_tick``: the next program
+    is enqueued before this one is read).  A slot whose budget ends
+    inside a program wastes at most ``steps_per_sync - 1`` lane-steps;
+    with ``eos_id`` set, an EOS costs up to one more program of them.
 
     ``mesh`` (optional) lifts the engine onto a device mesh: params are
     tp-sharded by their logical axes (models/generate.shard_split_params)
@@ -236,11 +270,15 @@ class ContinuousBatcher:
         cache_len = max_len or cfg.max_len
         self.cfg = cfg
         self._T = max(1, steps_per_sync)
+        # token steps a slot can run on as live after its request's
+        # last token: the rest of the program its budget ends in and,
+        # where an EOS can end it early, the one program the lookahead
+        # had already enqueued when the host read the EOS (_tick)
+        self._overrun = self._T - 1 + (self._T if eos_id is not None else 0)
         # -- window layers (transformer.Block._ring_attention): a slot
         # holds a ring of the window and what a snapshot for the pool
         # reads back after the last token: up to one KV block back to
-        # the block edge it ends at, and the token steps the program
-        # ran on after the request was done (steps_per_sync - 1).  A
+        # the block edge it ends at, and the overrun.  A
         # window that tiles by lanes keeps a ring that does (the
         # one-token kernels take it, ops/decode_attention.applies).
         self._ring_layers = frozenset(
@@ -262,7 +300,7 @@ class ContinuousBatcher:
                     "gather yet (serving/kv_cache.py)")
             W = cfg.attn_window
             lanes = 128 if W % 128 == 0 else 1
-            ring = -(-(W + max(kv_block, 1) + self._T - 1)
+            ring = -(-(W + max(kv_block, 1) + self._overrun)
                      // lanes) * lanes
         # attention_impl "dense" never reads the mesh; the decode step
         # does (ops/decode_attention.applies): a mesh engine's sharded
@@ -348,7 +386,14 @@ class ContinuousBatcher:
                   if decode_attention.applies(1, mesh, R) else R)
             self._ring_fetch = lambda n: -(-min(n, R) // tk) * tk
         self._cache = self._fresh_cache(slots)
-        self._toks = np.zeros((slots,), np.int32)   # last token per slot
+        # last token per slot, ON THE DEVICE: each step returns it, each
+        # insert places an admission's first token in it, the next step
+        # takes it.  The host never reads it (_tick)
+        self._toks = jnp.zeros((slots,), jnp.int32)
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._toks = jax.device_put(
+                self._toks, NamedSharding(mesh, PartitionSpec()))
         # -- paged KV block pool + prefix-reuse index (kv_cache.py) --
         # kv_block=0 keeps the engine EXACTLY on the pre-paged path (no
         # pool, no index, no extra dispatches); with a block size, every
@@ -422,6 +467,12 @@ class ContinuousBatcher:
         self._kv_window_read = 0
         self._kv_window_need = 0
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
+        # the tick enqueued and not read (_tick); ticks that enqueued
+        # theirs while it was unread; token steps a program ran for a
+        # slot as live after the slot's EOS (read one tick late)
+        self._inflight: "_Tick | None" = None
+        self._lookahead_ticks = 0
+        self._lookahead_discarded = 0
         # the tick ledger: engine thread only; closed (and read by
         # stats()) under _stats_lock.  No switch.
         self._ledger = StepPhaseLedger(
@@ -445,9 +496,10 @@ class ContinuousBatcher:
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(mesh, PartitionSpec())
             self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,),
-                                     out_shardings=(sh, rep))
+                                     out_shardings=(sh, rep, rep))
             self._insert_jit = jax.jit(self._insert_impl,
-                                       donate_argnums=(0,), out_shardings=sh)
+                                       donate_argnums=(0,),
+                                       out_shardings=(sh, rep))
         else:
             self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,))
             self._insert_jit = jax.jit(self._insert_impl, donate_argnums=(0,))
@@ -505,14 +557,13 @@ class ContinuousBatcher:
                 sh = self._cache_shardings(slots)
                 self._spec_jit = jax.jit(
                     self._spec_impl, donate_argnums=(0, 1),
-                    out_shardings=(sh, dsh, rep, rep))
+                    out_shardings=(sh, dsh, rep, rep, rep))
                 self._draft_insert_jit = jax.jit(
-                    self._insert_impl, donate_argnums=(0,),
-                    out_shardings=dsh)
+                    self._place, donate_argnums=(0,), out_shardings=dsh)
             else:
                 self._spec_jit = jax.jit(self._spec_impl,
                                          donate_argnums=(0, 1))
-                self._draft_insert_jit = jax.jit(self._insert_impl,
+                self._draft_insert_jit = jax.jit(self._place,
                                                  donate_argnums=(0,))
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="continuous-batcher")
@@ -639,11 +690,11 @@ class ContinuousBatcher:
             slab, toks, _ = self._prefill_fn(P, K)(self._params, ids,
                                                    lens, key)
             # lower+compile only: executing would donate the live cache
-            self._insert_jit.lower(self._cache, slab,
+            self._insert_jit.lower(self._cache, self._toks, slab,
                                    jnp.zeros((K,), jnp.int32),
-                                   lens).compile()
+                                   lens, toks).compile()
             jax.block_until_ready(toks)
-        self._step_jit.lower(self._cache, jnp.asarray(self._toks), key,
+        self._step_jit.lower(self._cache, self._toks, key,
                              self._params, self._live_mask([])).compile()
         if self._chunk_tokens:
             # the program every chunked admission starts with, whatever
@@ -676,7 +727,7 @@ class ContinuousBatcher:
                 jax.block_until_ready(jax.tree.leaves(dslab)[0])
             # lower+compile only: executing would donate the live caches
             self._spec_jit.lower(self._cache, self._draft_cache,
-                                 jnp.asarray(self._toks), self._params,
+                                 self._toks, self._params,
                                  self._draft_params).compile()
         if self._kv is not None and self._ring_layers:
             # the snapshot an admission takes at its prompt's end
@@ -808,6 +859,8 @@ class ContinuousBatcher:
                if p != "idle_wait"},
             "idle_wait_s": ph["idle_wait"],
             "tick_coverage": tot["coverage"] or 0.0,
+            "lookahead_ticks": self._lookahead_ticks,
+            "lookahead_discarded_token_steps": self._lookahead_discarded,
             "admitted": self._admitted,
             "queue_wait_s_sum": self._queue_wait_s,
             "first_tokens": self._first_tokens,
@@ -1071,7 +1124,7 @@ class ContinuousBatcher:
         return fn
 
     @staticmethod
-    def _insert_impl(cache, slab, slots, true_lens):
+    def _place(cache, slab, slots, true_lens):
         """Scatter a K-lane prefill cache into slots ``slots`` of the
         pool cache and reset those slots' indices to ``true_lens``."""
         def put(big, small):
@@ -1080,6 +1133,15 @@ class ContinuousBatcher:
             # kv buffers: [K, ...] lanes -> the pool's [n_slots, ...]
             return big.at[slots].set(small)
         return jax.tree.map(put, cache, slab)
+
+    @staticmethod
+    def _insert_impl(cache, toks, slab, slots, true_lens, first):
+        """:meth:`_place` the admission's slab, and its ``first``
+        sampled tokens ([K]) into the slots' entries of ``toks``, the
+        vector the next step feeds: the tokens never visit the host on
+        their way there."""
+        return (ContinuousBatcher._place(cache, slab, slots, true_lens),
+                toks.at[slots].set(first))
 
     def _step_impl(self, cache, toks, key, params, live):
         """Advance every slot ``self._T`` tokens (one dispatch).
@@ -1090,8 +1152,10 @@ class ContinuousBatcher:
         slab (transformer.Block._decode_attention), and the dropless
         expert path routes free slots' ballast tokens nowhere.  With
         that path the layers' ``moe_stats`` ride back beside the
-        tokens, ``(cache, (tokens, stats))``; otherwise ``(cache,
-        tokens)``.
+        tokens, ``(cache, last, (tokens, stats))``; otherwise ``(cache,
+        last, tokens)``.  ``last`` ([slots]) is what the final token
+        step sampled: the next call's ``toks``, handed over on the
+        device.
 
         ``params`` is an ARGUMENT, not a closure capture: a captured
         param tree would be baked into the jaxpr as constants — 124M
@@ -1115,9 +1179,10 @@ class ContinuousBatcher:
 
         keys = jax.random.split(key, self._T)
         acc0 = [_zeros_of(self._moe_acc_shape)] if moe else []
-        (cache, _, *acc), out = jax.lax.scan(one, (cache, toks, *acc0), keys)
+        (cache, last, *acc), out = jax.lax.scan(
+            one, (cache, toks, *acc0), keys)
         # [slots, T]
-        return cache, ((out.T, acc[0]) if moe else out.T)
+        return cache, last, ((out.T, acc[0]) if moe else out.T)
 
     @staticmethod
     def _positions(cache):
@@ -1200,9 +1265,10 @@ class ContinuousBatcher:
         are DROPPED (decode_scatter), and the host consumes at most
         ``remaining`` tokens, so overhang is dead weight, not state.
 
-        Returns ``(cache, draft_cache, out [R, slots, k+1],
-        counts [R, slots])`` — per round, ``counts`` tokens of ``out``
-        are consumable per slot."""
+        Returns ``(cache, draft_cache, last [slots], out [R, slots,
+        k+1], counts [R, slots])`` — per round, ``counts`` tokens of
+        ``out`` are consumable per slot; ``last`` is the final round's
+        bonus token, the next call's ``toks``."""
         k = self._spec_k
         B = len(self._slots)
         draft, vmodel = self._draft_model, self._vmodel
@@ -1255,26 +1321,26 @@ class ContinuousBatcher:
                     set_index(dcache, new_idx),
                     bonus[:, 0]), (out, n_acc + 1)
 
-        (cache, draft_cache, _), (outs, counts) = jax.lax.scan(
+        (cache, draft_cache, last), (outs, counts) = jax.lax.scan(
             one_round, (cache, draft_cache, toks), None,
             length=self._spec_rounds)
-        return cache, draft_cache, outs, counts
+        return cache, draft_cache, last, outs, counts
 
     def _finish_spec(self, toks: np.ndarray, counts: np.ndarray,
-                     n_active: int) -> None:
+                     live: list) -> None:
         """Consume one speculative chunk: ``toks [R, slots, k+1]`` with
         ``counts[r, i]`` consumable tokens per round.  Same contract as
-        :meth:`_finish_decode` (runs before this tick's prefill
-        finishes), just ragged per round."""
+        :meth:`_finish_decode`, just ragged per round; and here alone
+        the budgets follow the READ (``owed`` is what is left to read):
+        how far a round got is the device's answer."""
         R = toks.shape[0]
         lane_tokens = R * (self._spec_k + 1)
         with self._stats_lock:
             self._lane_steps += len(self._slots) * lane_tokens
-            self._active_lane_steps += n_active * lane_tokens
+            self._active_lane_steps += len(live) * lane_tokens
             self._spec_rounds_run += R
-        for i, s in enumerate(self._slots):
-            if s.free:
-                continue
+        for i, _ in live:
+            s = self._slots[i]
             with self._stats_lock:
                 # device-side acceptance for the rate gauge: counts - 1
                 # accepted drafts out of k proposed, per round
@@ -1295,18 +1361,19 @@ class ContinuousBatcher:
                         break
                 if done:
                     break
-            else:
-                self._toks[i] = int(
-                    toks[R - 1, i, int(counts[R - 1, i]) - 1])
+            if not done:
+                s.owed = s.remaining
 
     # -- the loop ------------------------------------------------------------
     def _loop(self) -> None:
         led = self._ledger
         t0 = time.perf_counter()
         while True:
-            # a mid-chunk admission is live work even with no active
-            # slots and an empty queue — never block on the queue then
-            block = not self._any_active() and self._chunking is None
+            # a tick in flight or a mid-chunk admission is live work
+            # even with no active slots and an empty queue — never
+            # block on the queue then: idle_wait begins flushed
+            block = (self._inflight is None and not self._any_active()
+                     and self._chunking is None)
             waiting = block and not self._pending and not self._tasks
             with led.phase("idle_wait" if waiting else "admit"):
                 self._drain(block=block)
@@ -1315,7 +1382,11 @@ class ContinuousBatcher:
             try:
                 self._tick()
             except Exception as e:  # noqa: BLE001 — never die silently
+                # a device error surfaces at a read, one tick after its
+                # program was enqueued: both ticks in flight are lost,
+                # and every request of either holds a slot (_admit)
                 logger.exception("engine tick failed")
+                self._inflight = None
                 self._fail_all(e)
             # close the tick against the loop's own wall time (the
             # ledger takes idle_wait out of it): the phases must tile it
@@ -1347,12 +1418,36 @@ class ContinuousBatcher:
         """One engine tick: admit every consecutive prefix-reuse hit at
         the queue front plus at most ONE cold prefill group, then the
         decode chunk for the lanes that were already live, then the
-        cache inserts — and sync the host once for all of it.  Admission
-        work per tick stays bounded by the free-slot count, so a burst
-        of arrivals can never starve running lanes: they advance
-        ``steps_per_sync`` tokens every tick regardless of the queue."""
+        cache inserts — and only THEN read the tick before this one.
+        The device always holds its next programs while the host reads,
+        books and admits (a one-tick lookahead), so a tick lasts what
+        its device programs last, not that plus the host's turn.
+        Admission work per tick stays bounded by the free-slot count,
+        so a burst of arrivals can never starve running lanes: they
+        advance ``steps_per_sync`` tokens every tick regardless of the
+        queue.
+
+        What lets the host enqueue blind: the step's input tokens never
+        leave the device (``self._toks``), and which slots are live
+        follows from budgets (``_Slot.owed``): a slot whose budget ends
+        inside the unread tick is not in this tick's mask, a slot
+        admitted there is.  Only ``eos_id`` is data: an EOS is read one
+        tick late, its slot has by then run one more program as live,
+        and those ``steps_per_sync`` token steps are discarded
+        (``lookahead_discarded_token_steps``); the emitted stream is the
+        same.  A slot read as finished is free from the next ``_admit``
+        on, so after a budget finish one tick later than a serial tick
+        would free it: its commit needs the tokens this read delivers
+        and has to be enqueued before an insert overwrites the slot.
+
+        A speculative engine cannot know the next budgets (how far a
+        round advances a slot is the device's answer), so it reads its
+        own tick before the next: the same loop, the read not deferred.
+        ``tasks`` see flushed state: the tick in flight is read first."""
         led = self._ledger
         if self._tasks:
+            tick, self._inflight = self._inflight, None
+            self._read(tick)
             with led.phase("tasks"):
                 while self._tasks:
                     task = self._tasks.popleft()
@@ -1361,75 +1456,103 @@ class ContinuousBatcher:
                     except BaseException as e:  # noqa: BLE001 — must resolve
                         task.future.set_exception(e)
         with led.phase("admit"):
-            active = [i for i, s in enumerate(self._slots) if not s.free]
-            pres = self._admit(active)
-        # everything from here to the sync can raise with the prefill
-        # group already popped from _pending but not yet in slots —
-        # _fail_all (our caller's handler) only covers slot-resident
-        # requests, so fail the admitted futures before re-raising
-        try:
-            dec = None
-            counts = None
-            moe = None
-            with led.phase("dispatch"):
-                if active:
-                    if self._spec_k:
-                        (self._cache, self._draft_cache, dec,
-                         counts) = self._spec_jit(
-                            self._cache, self._draft_cache,
-                            jnp.asarray(self._toks), self._params,
-                            self._draft_params)
-                    else:
-                        self._rng, key = jax.random.split(self._rng)
-                        self._cache, dec = self._step_jit(
-                            self._cache, jnp.asarray(self._toks), key,
-                            self._params, self._live_mask(active))
-                        if self._moe_dropless:
-                            dec, moe = dec
-                for slab, _, _, slots, _, lens, dslab in pres:
-                    self._cache = self._insert_jit(
-                        self._cache, slab, jnp.asarray(slots, jnp.int32),
-                        jnp.asarray(lens, jnp.int32))
-                    if dslab is not None:
-                        self._draft_cache = self._draft_insert_jit(
-                            self._draft_cache, dslab,
-                            jnp.asarray(slots, jnp.int32),
-                            jnp.asarray(lens, jnp.int32))
-            # single sync point for decode + every admission
-            with led.phase("sync"):
-                dec_np = np.asarray(dec) if dec is not None else None
-                moe_np = np.asarray(moe) if moe is not None else None
-                counts_np = (np.asarray(counts) if counts is not None
-                             else None)
-                fins = [(p[3], p[4], np.asarray(p[1]),
-                         np.asarray(p[2])) for p in pres]
-        except Exception as e:  # noqa: BLE001
-            for p in pres:
-                for req in p[4]:
-                    req.future.set_exception(e)
-            with self._stats_lock:
-                self._failed_requests += sum(len(p[4]) for p in pres)
-            raise
-        with led.phase("finish"):
-            if dec_np is not None:
-                if counts_np is not None:
-                    self._finish_spec(dec_np, counts_np, len(active))
-                else:
-                    self._finish_decode(dec_np, len(active))
-                if moe_np is not None:
-                    self._count_moe(moe_np, len(active) * self._T,
-                                    decode=True)
-            for slots, reqs, ptoks_np, drops in fins:
-                self._finish_prefill(slots, reqs, ptoks_np, drops)
+            live = [(i, s.request) for i, s in enumerate(self._slots)
+                    if s.owed > 0]
+            pres = self._admit(bool(live))
+        with led.phase("dispatch"):
+            tick = self._dispatch(live, pres)
+        if not self._spec_k:
+            tick, self._inflight = self._inflight, tick
+        self._read(tick)
 
-    def _admit(self, active: list[int]) -> list[tuple]:
+    def _dispatch(self, live: list, pres: list[tuple]) -> "_Tick | None":
+        """Enqueue the decode step for ``live`` and the inserts of
+        ``pres`` behind it; nothing here waits for the device.  None
+        where the tick has nothing to read later (idle, or only a mid
+        chunk of a long admission, which ``_admit`` enqueued)."""
+        if not live and not pres:
+            return None
+        tick = _Tick(live, pres=pres)
+        if self._inflight is not None:    # never, on a speculative engine
+            with self._stats_lock:
+                self._lookahead_ticks += 1
+        if live:
+            if self._spec_k:
+                (self._cache, self._draft_cache, self._toks, tick.dec,
+                 tick.counts) = self._spec_jit(
+                    self._cache, self._draft_cache, self._toks,
+                    self._params, self._draft_params)
+            else:
+                self._rng, key = jax.random.split(self._rng)
+                self._cache, self._toks, tick.dec = self._step_jit(
+                    self._cache, self._toks, key, self._params,
+                    self._live_mask([i for i, _ in live]))
+                if self._moe_dropless:
+                    tick.dec, tick.moe = tick.dec
+                for i, _ in live:
+                    s = self._slots[i]
+                    s.owed = max(0, s.owed - self._T)
+        for slab, toks, _, slots, reqs, lens, dslab in pres:
+            at = jnp.asarray(slots, jnp.int32)
+            n = jnp.asarray(lens, jnp.int32)
+            self._cache, self._toks = self._insert_jit(
+                self._cache, self._toks, slab, at, n, toks)
+            if dslab is not None:
+                self._draft_cache = self._draft_insert_jit(
+                    self._draft_cache, dslab, at, n)
+            # before the next step advances the slot's rings
+            for slot, req in zip(slots, reqs):
+                self._snap_prompt(slot, req)
+        return tick
+
+    def _read(self, tick: "_Tick | None") -> None:
+        """Block on one tick's results and book them: tokens to slots
+        and futures, finished slots' commits."""
+        if tick is None:
+            return
+        led = self._ledger
+        # single sync point for decode + every admission
+        with led.phase("sync"):
+            dec = np.asarray(tick.dec) if tick.dec is not None else None
+            moe = np.asarray(tick.moe) if tick.moe is not None else None
+            counts = (np.asarray(tick.counts) if tick.counts is not None
+                      else None)
+            fins = [(p[3], p[4], np.asarray(p[1]), np.asarray(p[2]))
+                    for p in tick.pres]
+        with led.phase("finish"):
+            if dec is not None:
+                if counts is not None:
+                    self._finish_spec(dec, counts, tick.live)
+                else:
+                    self._finish_decode(dec, tick.live)
+                if moe is not None:
+                    self._count_moe(moe, len(tick.live) * self._T,
+                                    decode=True)
+            for slots, reqs, ptoks, drops in fins:
+                self._finish_prefill(slots, reqs, ptoks, drops)
+
+    def _admit(self, lanes_live: bool) -> list[tuple]:
         """This tick's admissions, dispatched and not synced: every
         consecutive prefix hit at the queue front, then one chunk of
         the chunked admission in flight or else one cold group.
-        Returns the in-flight tuples the tick inserts and finishes."""
+        Returns the in-flight tuples the tick inserts and, a tick
+        later, finishes; their requests hold their slots from here on
+        (``_fail_all`` and ``stop()`` find them there), with the budget
+        the next ticks schedule from."""
         pres: list[tuple] = []
+
+        def take(pre):
+            pres.append(pre)
+            for slot, req in zip(pre[3], pre[4]):
+                s = self._slots[slot]
+                s.request, s.emitted = req, []
+                # the prefill samples the first token; steps owe the rest
+                s.remaining, s.owed = req.max_new, req.max_new - 1
+
         t0 = time.monotonic()
-        taken: set[int] = set()       # slots claimed by THIS tick's admissions
+        # the slot a chunked admission holds while its request is not
+        # in it yet
+        taken: set[int] = set()
         if self._chunking is not None:
             taken.add(self._chunking.slot)
         while True:
@@ -1442,8 +1565,7 @@ class ContinuousBatcher:
                 break
             pre = self._dispatch_reuse(*reuse)
             if pre is not None:
-                taken.add(reuse[0])
-                pres.append(pre)
+                take(pre)
         # long-prompt path: at most one chunked admission in flight; it
         # advances ONE chunk per tick (the final chunk lands in pres and
         # rides the shared insert/finish path), displacing this tick's
@@ -1453,14 +1575,14 @@ class ContinuousBatcher:
         if self._chunking is not None:
             pre = self._advance_chunk()
             if pre is not None:
-                pres.append(pre)
+                take(pre)
         else:
             group = self._next_group(taken)
             if group is not None:
                 pre = self._dispatch_prefill(*group)
                 if pre is not None:
-                    pres.append(pre)
-        if pres and active:
+                    take(pre)
+        if pres and lanes_live:
             with self._stats_lock:
                 self._prefill_stall_s += time.monotonic() - t0
         return pres
@@ -1470,7 +1592,7 @@ class ContinuousBatcher:
         for s in self._slots:
             if s.request is not None:
                 s.request.future.set_exception(e)
-                s.request = None
+                s.request, s.owed = None, 0
                 n += 1
         if self._chunking is not None:
             self._chunking.req.future.set_exception(e)
@@ -1492,9 +1614,9 @@ class ContinuousBatcher:
                     ) -> tuple[int, list[int], list[_Request]] | None:
         """Take the next same-bucket run of pending requests (FIFO from
         the front) as one prefill group, capped by free slots (minus
-        ``taken``, slots this tick's reuse admissions already claimed)
-        and the largest PREFILL_KS sub-batch size (compile count stays
-        bounded at buckets × |PREFILL_KS|)."""
+        ``taken``, the slot a chunked admission holds) and the largest
+        PREFILL_KS sub-batch size (compile count stays bounded at
+        buckets × |PREFILL_KS|)."""
         if self._stopping or not self._pending:
             return None
         free = [i for i, s in enumerate(self._slots)
@@ -1719,7 +1841,7 @@ class ContinuousBatcher:
         """If the FRONT pending request extends a committed chain, take
         it as a one-lane reuse admission (FIFO preserved: a miss at the
         front falls through to the group path unchanged).  ``taken``
-        excludes slots already claimed by this tick's admissions."""
+        excludes the slot a chunked admission holds."""
         if self._kv is None or not self._reuse:
             return None
         if self._stopping or not self._pending:
@@ -1845,24 +1967,23 @@ class ContinuousBatcher:
         with self._stats_lock:
             self._first_tokens += len(reqs)
             self._ttft_s += sum(ttfts)
-        for slot, req, tok in zip(slots, reqs, toks.tolist()):
-            self._snap_prompt(slot, req)
-            s = self._slots[slot]
-            s.request = req
-            s.emitted = [int(tok)]
-            s.remaining = req.max_new - 1
-            self._toks[slot] = int(tok)
-            if s.remaining == 0 or int(tok) == self._eos:
+        for slot, tok in zip(slots, toks.tolist()):
+            s = self._slots[slot]         # the request's since _admit
+            s.emitted = [tok]
+            s.remaining -= 1
+            if s.remaining == 0 or tok == self._eos:
                 self._finish(slot)
 
     def _snap_prompt(self, slot: int, req: "_Request") -> None:
         """The window layers' state at the end of the PROMPT, while the
-        slot's rings still hold it (decode starts with the next tick):
-        the last window before the deepest block edge the same prompt
-        can match again, ``(len - 1) // block`` blocks down.  Without
-        it only a continuation of prompt + answer could start from the
-        pool; with it a prompt that comes again does.  One small
-        dispatch an admission; the commit gives the snapshot its node."""
+        slot's rings still hold it: enqueued right behind the insert,
+        before the next tick's step advances them.  It needs nothing the
+        device returns.  The last window before the deepest block edge
+        the same prompt can match again, ``(len - 1) // block`` blocks
+        down.  Without it only a continuation of prompt + answer could
+        start from the pool; with it a prompt that comes again does.
+        One small dispatch an admission; the commit gives the snapshot
+        its node."""
         if self._kv is None or not self._ring_layers:
             return
         end = (len(req.ids) - 1) // self._kv.block * self._kv.block
@@ -1902,39 +2023,37 @@ class ContinuousBatcher:
                 self._moe_prefill_experts_touched += int(touched)
                 self._moe_prefill_max_load_sum += load
 
-    def _finish_decode(self, toks: np.ndarray, n_active: int) -> None:
-        """Consume one decode chunk [slots, T].  Runs BEFORE this tick's
-        _finish_prefill, so lanes filled this tick are still free here
-        and never consume a chunk that predates their insert."""
+    def _finish_decode(self, toks: np.ndarray, live: list) -> None:
+        """Consume one decode chunk [slots, T] for the (slot, request)
+        pairs that were ``live`` in it.  A slot that no longer holds
+        its request ended at an EOS while this program was already
+        enqueued: its token steps are discarded."""
         T, cap = self._T, self._dcfg.max_len
+        mine = [(i, self._slots[i]) for i, req in live
+                if self._slots[i].request is req]
         # step t of the program read a live slot up to the token it
         # appended: prompt + emitted so far + t positions
         held = [min(len(s.request.ids) + len(s.emitted) + t, cap)
-                for s in self._slots if not s.free for t in range(T)]
+                for _, s in mine for t in range(T)]
         kv_live = sum(held)
         with self._stats_lock:
             self._lane_steps += len(self._slots) * T
-            self._active_lane_steps += n_active * T
+            self._active_lane_steps += len(live) * T
+            self._lookahead_discarded += (len(live) - len(mine)) * T
             self._kv_tokens_live += kv_live
             self._kv_tokens_slab += len(self._slots) * cap * T
             if self._ring_layers:
                 W = self._dcfg.attn_window
                 self._kv_window_read += sum(map(self._ring_fetch, held))
                 self._kv_window_need += sum(min(n, W) for n in held)
-        for i, s in enumerate(self._slots):
-            if s.free:      # occupied slots always have remaining >= 1
-                continue
-            for t in range(self._T):
-                if s.remaining <= 0:
-                    break
+        for i, s in mine:         # live, so it had tokens left to read
+            for t in range(T):
                 tok = int(toks[i, t])
                 s.emitted.append(tok)
                 s.remaining -= 1
-                if tok == self._eos or s.remaining == 0:
+                if tok == self._eos or s.remaining <= 0:
                     self._finish(i)
                     break
-            else:
-                self._toks[i] = int(toks[i, self._T - 1])
 
     def _finish(self, slot: int) -> None:
         s = self._slots[slot]
@@ -1961,7 +2080,7 @@ class ContinuousBatcher:
             self._emitted_tokens += n_out
             self._decode_tokens += n_out - 1
             self._decode_s += decode_s
-        s.request = None
+        s.request, s.owed = None, 0
         s.emitted = []
         if obs_trace.active():
             self._emit_request(req, n_out)
@@ -2009,11 +2128,11 @@ class ContinuousBatcher:
         snap = (0, 0)
         if self._ring_layers and tail is not None:
             # the window layers' last window before the tail's end, as
-            # the slot's rings still hold it: the program may have run
-            # steps_per_sync - 1 token steps past the request's end,
-            # each overwriting the oldest ring position
+            # the slot's rings still hold it: the slot may have run
+            # self._overrun token steps past the request's end, each
+            # overwriting the oldest ring position
             end = (start_block + len(new_ids)) * self._kv.block
-            oldest = len(seq) + self._T - 1 - self._dcfg.ring_len
+            oldest = len(seq) + self._overrun - self._dcfg.ring_len
             if max(0, end - self._dcfg.attn_window) >= oldest:
                 snap = (self._kv.snap_for(tail), end)
         self._kv.store_blocks(self._cache, slot, start_block, new_ids, snap)

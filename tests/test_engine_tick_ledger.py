@@ -56,12 +56,21 @@ def _mark(eng):
     return t, eng.stats()
 
 
-def test_phases_tile_the_engine_thread(small):
+def test_phases_tile_the_engine_thread():
     """Idle stretches, bursts, a chunked admission and a prefix hit:
     tick_s + idle_wait_s is the engine thread's wall time, and the
     phases account for the ticks."""
-    cfg, params = small
-    eng = _engine(cfg, params)
+    # a toy whose step program outlasts the host's turn, as on the chip:
+    # with the reads a tick late (ISSUE 31) the host no longer waits out
+    # the device in every tick, and the two-layer toy's 0.5 ms ticks
+    # would measure the ledger's own 20-70 us a tick between phases (the
+    # same before and after) and not the tiling
+    cfg = TransformerConfig(vocab_size=97, num_layers=4, embed_dim=32,
+                            num_heads=4, mlp_dim=64, max_len=64,
+                            remat=False, dtype=jnp.float32)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = _engine(cfg, params, steps_per_sync=8)
     try:
         # compiles out of the way: the marks must fall on an idle engine
         for p in _prompts(0, (5, 12, 20)):
@@ -87,6 +96,10 @@ def test_phases_tile_the_engine_thread(small):
     d = {k: s_b[k] - s_a[k] for k in s_a
          if isinstance(s_a[k], (int, float)) and not isinstance(s_a[k], bool)}
     assert d["chunked_admissions"] >= 1 and d["kv_prefix_hits"] >= 1, d
+    # the reads trail the dispatches by a tick (ISSUE 31) and the
+    # phases still tile: sync and finish belong to the PREVIOUS tick's
+    # programs, inside this tick's wall time
+    assert 0 < d["lookahead_ticks"] < d["ticks"], d
     assert d["ticks"] > 10 and d["idle_wait_s"] >= 0.4, d
     wall = t_b - t_a
     assert d["tick_s"] + d["idle_wait_s"] == pytest.approx(wall, rel=0.05)
@@ -241,3 +254,126 @@ def test_engine_phases_are_profiler_annotations(small, monkeypatch):
     finally:
         eng.stop()
     assert names == {f"engine/{p}" for p in engine_mod.TICK_PHASES}
+
+
+# -- the one-tick lookahead's counters (ISSUE 31) ----------------------------
+
+def test_lookahead_counters_are_cumulative_and_bounded(small):
+    """``lookahead_ticks`` counts ticks that enqueued programs behind an
+    unread tick: every tick of a busy period but its first (nothing to
+    look past) and its last (nothing left to enqueue)."""
+    cfg, params = small
+    eng = _engine(cfg, params, slots=1, kv_block=0, prefill_chunk=0)
+    try:
+        eng.submit(_prompts(9, (5,))[0], 2).result(timeout=120)  # compiles
+        _, s0 = _mark(eng)
+        # 1 token from the prefill + 12 from 6 step programs of 2: ticks
+        # 1 (prefill), 2-7 (a step each, 6 behind an unread tick), 8
+        # (the last read alone)
+        eng.submit(_prompts(10, (6,))[0], 13).result(timeout=120)
+        _, s1 = _mark(eng)
+    finally:
+        eng.stop()
+    assert s1["lookahead_ticks"] - s0["lookahead_ticks"] == 6
+    assert s1["lookahead_discarded_token_steps"] == 0
+    # s1 also holds the tick of the first mark's closure
+    assert s1["ticks"] - s0["ticks"] == 8 + 1
+
+
+def test_a_chunk_only_tick_is_no_lookahead_tick(small):
+    """No slot live, a long prompt prefilling one chunk a tick: those
+    ticks enqueue a program and leave nothing to read, so neither they
+    nor the tick after them count (a serial engine did not wait on
+    them either: the counter is the mechanism's, not the queue's)."""
+    cfg, params = small
+    eng = _engine(cfg, params, slots=1, kv_block=0)       # chunks of 8
+    try:
+        eng.submit(_prompts(12, (30,))[0], 3).result(timeout=120)
+        _, s0 = _mark(eng)
+        # ticks: mid, mid, mid (8 + 8 + 8), final chunk (6) + insert,
+        # one step of 2 (behind the unread insert), the last read
+        eng.submit(_prompts(13, (30,))[0], 3).result(timeout=120)
+        _, s1 = _mark(eng)
+    finally:
+        eng.stop()
+    assert s1["prefill_chunks"] - s0["prefill_chunks"] == 4
+    assert s1["ticks"] - s0["ticks"] == 6 + 1     # + the mark's own
+    assert s1["lookahead_ticks"] - s0["lookahead_ticks"] == 1
+
+
+def test_speculative_engine_reads_before_it_dispatches(small):
+    """How far a round advances a slot is the device's answer, so the
+    host cannot know the next tick's budgets: same loop, the read not
+    deferred, ``lookahead_ticks`` stays 0."""
+    from edl_tpu.models.generate import generate
+
+    cfg, params = small
+    eng = _engine(cfg, params, kv_block=0, prefill_chunk=0, spec_k=2,
+                  steps_per_sync=6, draft_cfg=cfg, draft_params=params)
+    reads = []
+    real = eng._read
+
+    def read(tick):
+        reads.append(eng._inflight)
+        real(tick)
+
+    eng._read = read
+    try:
+        prompts = _prompts(11, (4, 9, 6, 3))
+        futs = [eng.submit(p, 9) for p in prompts]
+        outs = [f.result(timeout=120) for f in futs]
+        st = eng.stats()
+    finally:
+        eng.stop()
+    for p, out in zip(prompts, outs):
+        want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 9,
+                                   temperature=0.0))[0]
+        np.testing.assert_array_equal(out, want)
+    assert st["lookahead_ticks"] == 0 and st["ticks"] > 3
+    assert st["lookahead_discarded_token_steps"] == 0
+    assert reads and all(r is None for r in reads)    # nothing deferred
+
+
+def _lookahead_share():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "layer_metrics",
+        "engine_lookahead_share.py")
+    spec = importlib.util.spec_from_file_location("_lookahead_share", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({"ticks": 200, "lookahead_ticks": 190}, 95.0),
+    ({"ticks": 200, "lookahead_ticks": 0}, 0.0),
+    ({"ticks": 200}, None),          # the parent: no such counter
+    ({"ticks": 0, "lookahead_ticks": 0}, None),
+])
+def test_engine_lookahead_share_reader(counters, want):
+    got = _lookahead_share()({"counters": counters})
+    assert got == want if want is None else got == pytest.approx(want)
+
+
+def test_engine_lookahead_share_is_declared_for_the_serve_cells():
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    entry = next(m for m in spec["per_layer"]
+                 if m["name"] == "engine_lookahead_share")
+    # a later cell may be appended to its list: the four are held, the
+    # list's end is not
+    cells = entry.pop("workloads")
+    assert entry == {
+        "name": "engine_lookahead_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "engine tick",
+        "moves": "serve_tokens_per_s"}
+    assert cells[:4] == ["serve-chat-open", "serve-doc-sessions",
+                         "serve-moe-decode-open", "serve-hybrid-mixed-open"]
+    layers = {m["layer"] for m in spec["per_layer"]
+              if m["name"] != "engine_lookahead_share"}
+    assert entry["layer"] in layers       # a layer the file already names

@@ -56,7 +56,11 @@ def test_greedy_parity_vs_generate(small):
 
 
 def test_queue_deeper_than_slots(small):
-    # more requests than slots: every future resolves, slots recycle
+    # more requests than slots: every future resolves, slots recycle.
+    # Under the one-tick lookahead a slot recycles one tick after the
+    # read that freed it (test_slot_freed_at_tick_n_is_admitted_at_
+    # n_plus_1 pins the tick): with 9 requests over 2 slots that is 4
+    # refills, each behind a tick that was enqueued unread
     cfg, params = small
     rng = np.random.default_rng(1)
     eng = _engine(cfg, params, slots=2)
@@ -70,6 +74,7 @@ def test_queue_deeper_than_slots(small):
     assert all(len(o) == 5 for o in outs)
     assert stats["requests_done"] == 9
     assert stats["queue_depth"] == 0
+    assert stats["lookahead_ticks"] >= 5, stats
     assert 0.0 < stats["slot_utilization"] <= 1.0
     assert stats["moe_prefill_drops"] == 0     # dense config never drops
 
@@ -402,3 +407,385 @@ def test_drain_timeout_falls_back_to_hard_stop(small):
     for f in futs:
         assert f.done()
     assert sum(1 for f in futs if f.exception() is not None) >= 1
+
+
+# -- the one-tick lookahead (ISSUE 31) ---------------------------------------
+# The engine enqueues tick n+1 before it reads tick n.  Everything above
+# already runs through it; these cases pin what it rests on: budgets the
+# host can count, an EOS read one tick late, a flush wherever host and
+# device have to agree.
+
+def _want(cfg, params, p, n):
+    return np.asarray(generate(cfg, params, jnp.asarray(p[None]), n,
+                               temperature=0.0))[0]
+
+
+def _first_time(ref, k0):
+    """The first index >= k0 whose token did not occur before it in
+    ``ref``: an ``eos_id`` that ends the stream exactly there."""
+    return next(k for k in range(k0, len(ref))
+                if int(ref[k]) not in set(map(int, ref[:k])))
+
+
+class _Hold:
+    """Parks the engine thread at its next read that has a newer tick
+    enqueued behind it: two ticks in flight, for as long as the test
+    wants.  Steps through further such reads on ``release()``."""
+
+    def __init__(self, eng):
+        import threading
+        self.held, self._go = threading.Event(), threading.Event()
+        self._armed, real = True, eng._read
+
+        def read(tick):
+            if (self._armed and tick is not None
+                    and eng._inflight is not None):
+                self._armed = False
+                self.held.set()
+                assert self._go.wait(120)
+            real(tick)
+
+        eng._read = read
+
+    def release(self):
+        self._go.set()
+
+
+def _count_steps(eng):
+    """Calls of the decode step program from here on."""
+    calls, real = [], eng._step_jit
+
+    def step(*args):
+        calls.append(1)
+        return real(*args)
+
+    eng._step_jit = step
+    return calls
+
+
+_LOOKAHEAD_CFGS = {
+    "dense": {},
+    "gqa": {"num_kv_heads": 2},
+    "moe-dropless": {"moe_experts": 4, "moe_top_k": 2, "moe_capacity": 0.0,
+                     "moe_gated": True},
+    "window": {"num_layers": 4, "max_len": 96, "attn_window": 8,
+               "layer_attn": ("window", "global", "global", "window")},
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_LOOKAHEAD_CFGS))
+def test_lookahead_greedy_parity(small, kind):
+    """Slots that churn while every tick is enqueued behind an unread
+    one: tokens equal ``generate()``'s, the lookahead engaged, nothing
+    was discarded (no ``eos_id``: the budgets are exact)."""
+    import dataclasses
+
+    cfg = dataclasses.replace(small[0], **_LOOKAHEAD_CFGS[kind])
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(1, 97, (n,)).astype(np.int32)
+               for n in (3, 7, 12, 5, 9, 16, 2)]
+    news = [6, 4, 9, 13, 1, 5, 8]
+    eng = _engine(cfg, params, slots=2, kv_block=4, kv_pool_blocks=64)
+    try:
+        futs = [eng.submit(p, n) for p, n in zip(prompts, news)]
+        got = [f.result(timeout=120) for f in futs]
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    for p, n, out in zip(prompts, news, got):
+        np.testing.assert_array_equal(out, _want(cfg, params, p, n))
+    assert stats["lookahead_ticks"] > 0.5 * stats["ticks"], stats
+    assert stats["lookahead_discarded_token_steps"] == 0
+    if kind == "moe-dropless":      # the recount the benchmark makes
+        assert stats["moe_assignments"] == (
+            cfg.num_layers * cfg.moe_top_k * stats["moe_tokens"])
+
+
+@pytest.mark.parametrize("max_new", [1, 2, 4, 5, 8, 9])
+def test_budget_ends_without_an_overrun_program(small, max_new):
+    """The host counts programs, not tokens it has read: a budget that
+    ends inside tick n takes its slot out of tick n+1's mask, so a
+    request costs ceil((max_new - 1) / T) step programs and not one
+    more (``max_new`` 1: the prefill's token alone, no step at all)."""
+    cfg, params = small
+    p = np.asarray([5, 9, 2, 7], np.int32)
+    eng = _engine(cfg, params, slots=1, steps_per_sync=4)
+    try:
+        calls = _count_steps(eng)
+        out = eng.generate(p, max_new, timeout=120)
+        eng.run_on_engine(lambda: None)       # whatever was enqueued, read
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    np.testing.assert_array_equal(out, _want(cfg, params, p, max_new))
+    assert len(calls) == -(-(max_new - 1) // 4)
+    assert stats["lookahead_discarded_token_steps"] == 0
+
+
+@pytest.mark.parametrize("kind", ["dense", "window"])
+def test_eos_in_the_middle_of_a_program(small, kind):
+    """An EOS is data: the host reads it one tick late, the slot has by
+    then run one more program as live, and exactly those token steps
+    are discarded.  The stream, the slot's commit to the pool and the
+    next request admitted into the slot are those of an engine that
+    never looked ahead (``generate()`` cut at the EOS; a cold engine).
+    The window rings keep ``steps_per_sync`` more positions for it."""
+    import dataclasses
+
+    cfg = dataclasses.replace(small[0], **_LOOKAHEAD_CFGS[kind])
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    T = 4
+    p = np.random.default_rng(5).integers(1, 97, (13,)).astype(np.int32)
+    ref = _want(cfg, params, p, 16)
+    # ref[0] is the prefill's; program 1 emits ref[1:5], program 2
+    # ref[5:9]: an EOS at index 5-7 is in the middle of program 2
+    k = _first_time(ref, 5)
+    assert k <= 7, (k, ref)
+    eos = int(ref[k])
+    kw = dict(slots=1, steps_per_sync=T, eos_id=eos, kv_block=4,
+              kv_pool_blocks=64)
+    eng = _engine(cfg, params, **kw)
+    cold = _engine(cfg, params, **dict(kw, kv_block=0))
+    try:
+        if kind == "window":
+            ring = eng._cache["layer_0"]["cached_key"].shape[-1]
+            assert ring == 8 + 4 + (T - 1) + T
+        calls = _count_steps(eng)
+        out = eng.generate(p, 16, timeout=120)
+        eng.run_on_engine(lambda: None)
+        assert list(out) == list(ref[:k + 1])
+        # program 3 was enqueued when program 2 was read: discarded
+        assert len(calls) == 3
+        st = eng.stats()
+        assert st["lookahead_discarded_token_steps"] == T
+        # the slot again, by a stranger: a fresh engine's tokens
+        q = np.asarray([3, 1, 4, 1, 5, 9], np.int32)
+        np.testing.assert_array_equal(eng.generate(q, 7, timeout=120),
+                                      cold.generate(q, 7, timeout=120))
+        # and what the slot committed: prompt + emitted[:-1], whole
+        # blocks, resumed by the conversation's next turn
+        p2 = np.concatenate([p, out, np.asarray([8, 6], np.int32)])
+        np.testing.assert_array_equal(eng.generate(p2, 6, timeout=120),
+                                      cold.generate(p2, 6, timeout=120))
+        st = eng.stats()
+        assert st["kv_prefix_hits"] == 1, st
+        assert st["kv_prefill_tokens_skipped"] == (13 + k) // 4 * 4
+    finally:
+        eng.stop()
+        cold.stop()
+
+
+def test_first_token_eos_is_read_one_tick_late(small):
+    """The prefill's own token is the EOS: the slot was live in the one
+    step enqueued before that was read."""
+    cfg, params = small
+    p = np.asarray([5, 9, 2], np.int32)
+    ref = _want(cfg, params, p, 4)
+    eng = _engine(cfg, params, slots=1, eos_id=int(ref[0]))
+    try:
+        out = eng.generate(p, 9, timeout=120)
+        eng.run_on_engine(lambda: None)
+        st = eng.stats()
+    finally:
+        eng.stop()
+    assert list(out) == [int(ref[0])]
+    assert st["lookahead_discarded_token_steps"] == 4
+
+
+def test_slot_freed_at_tick_n_is_admitted_at_n_plus_1(small):
+    """A queue deeper than the slots: a slot READ as finished in tick n
+    (after tick n+1's programs were enqueued) takes its next request in
+    tick n+1's ``_admit``, never in the tick that freed it and never
+    later while requests wait."""
+    cfg, params = small
+    rng = np.random.default_rng(2)
+    eng = _engine(cfg, params, slots=2)
+    events = []
+    tick_no = lambda: eng._ledger.totals()["steps"]  # noqa: E731
+    finish, stamp = eng._finish, eng._stamp_admit
+
+    def spy_finish(slot):
+        events.append(("finish", tick_no(), len(eng._pending)))
+        finish(slot)
+
+    def spy_stamp(reqs):
+        events.append(("admit", tick_no(), len(reqs)))
+        stamp(reqs)
+
+    eng._finish, eng._stamp_admit = spy_finish, spy_stamp
+    try:
+        hold = _Hold(eng)
+        futs = [eng.submit(rng.integers(1, 97, (4,)).astype(np.int32), 6)
+                for _ in range(7)]
+        assert hold.held.wait(120)
+        hold.release()
+        outs = [f.result(timeout=120) for f in futs]
+    finally:
+        eng.stop()
+    assert all(len(o) == 6 for o in outs)
+    admits = [t for kind, t, _ in events if kind == "admit"]
+    frees = {t for kind, t, waiting in events
+             if kind == "finish" and waiting}
+    assert len(admits) >= 4
+    # every admission after the first fill follows a read that freed a
+    # slot, by exactly one tick; and every such read is followed
+    assert {t - 1 for t in admits[1:]} == frees, events
+
+
+def test_tasks_see_flushed_state_with_two_ticks_in_flight(small):
+    """``run_on_engine`` while one tick is unread and the next is
+    enqueued: the closure runs after both were read and booked (what
+    the host has not read equals what it has not enqueued)."""
+    cfg, params = small
+    eng = _engine(cfg, params, slots=2)
+    try:
+        hold = _Hold(eng)
+        futs = [eng.submit(np.asarray([3, 1, 4], np.int32), 30),
+                eng.submit(np.asarray([2, 7], np.int32), 30)]
+        assert hold.held.wait(120)
+        assert eng._inflight is not None          # and one being read
+
+        def look():
+            busy = [s for s in eng._slots if not s.free]
+            return (eng._inflight, len(busy),
+                    [(s.remaining, s.owed, len(s.emitted)) for s in busy])
+
+        import threading
+        seen = []
+        th = threading.Thread(
+            target=lambda: seen.append(eng.run_on_engine(look)))
+        th.start()
+        time.sleep(0.05)
+        assert not seen                           # parked behind the read
+        hold.release()
+        th.join(120)
+        inflight, n_busy, slots = seen[0]
+        assert inflight is None and n_busy == 2
+        for remaining, owed, n_emitted in slots:
+            assert remaining == owed and n_emitted == 30 - remaining
+        for f in futs:
+            assert len(f.result(timeout=120)) == 30
+    finally:
+        eng.stop()
+
+
+def test_session_migration_with_two_ticks_in_flight(small):
+    """``drain()`` + ``export_sessions()`` on an engine caught with two
+    ticks in flight, ``import_session()`` into another one caught the
+    same way: the chain is whole, and the session's next turn there
+    resumes from it with a cold engine's tokens."""
+    import threading
+
+    cfg, params = small
+    kw = dict(slots=2, kv_block=4, kv_pool_blocks=64)
+    p1 = np.asarray([7, 11, 13, 5, 9, 2, 8], np.int32)
+    eng_a = _engine(cfg, params, **kw)
+    try:
+        hold = _Hold(eng_a)
+        fut = eng_a.submit(p1, 10, session="s")
+        assert hold.held.wait(120)
+        drained = []
+        th = threading.Thread(target=lambda: drained.append(
+            eng_a.drain(timeout=60)))
+        th.start()
+        hold.release()
+        th.join(120)
+        assert drained == [True]
+        out1 = fut.result(timeout=1)
+        np.testing.assert_array_equal(out1, _want(cfg, params, p1, 10))
+        (name, tokens, meta, blob), = eng_a.export_sessions()
+    finally:
+        eng_a.stop()
+    conv = np.concatenate([p1, out1])
+    assert name == "s" and tokens == list(map(int, conv[:16]))
+
+    eng_b = _engine(cfg, params, **kw)
+    try:
+        hold = _Hold(eng_b)
+        other = eng_b.submit(np.asarray([3, 1, 4], np.int32), 24)
+        assert hold.held.wait(120)
+        got = []
+        th = threading.Thread(target=lambda: got.append(
+            eng_b.import_session("s", tokens, meta, blob)))
+        th.start()
+        hold.release()
+        th.join(120)
+        assert got and got[0] > 0
+        p2 = np.concatenate([conv, np.asarray([4, 1], np.int32)])
+        out2 = eng_b.submit(p2, 6, session="s").result(timeout=120)
+        np.testing.assert_array_equal(out2, _want(cfg, params, p2, 6))
+        assert len(other.result(timeout=120)) == 24
+        stats = eng_b.stats()
+    finally:
+        eng_b.stop()
+    assert stats["kv_prefix_hits"] == 1, stats
+    assert stats["kv_prefill_tokens_skipped"] == 16
+
+
+@pytest.mark.parametrize("how", ["drain", "stop"])
+def test_shutdown_with_two_ticks_in_flight_resolves_every_future(small, how):
+    import threading
+
+    cfg, params = small
+    eng = _engine(cfg, params, slots=2)
+    hold = _Hold(eng)
+    futs = [eng.submit(np.asarray([3, 4, n], np.int32), 12)
+            for n in range(1, 6)]
+    assert hold.held.wait(120)
+    th = threading.Thread(target=eng.drain if how == "drain" else eng.stop)
+    th.start()
+    time.sleep(0.05)
+    hold.release()
+    th.join(120)
+    assert not th.is_alive()
+    assert all(f.done() for f in futs)
+    if how == "drain":                     # graceful: whole answers
+        assert all(len(f.result()) == 12 for f in futs)
+    eng.stop()
+
+
+def test_failing_program_fails_both_ticks_and_the_engine_serves_on(small):
+    """A device error surfaces when its program's results are READ, a
+    tick after it was enqueued: by then the next tick's programs and
+    admissions are enqueued too.  Every request of either tick fails
+    (none hangs), and the engine serves the next one."""
+    cfg, params = small
+    eng = _engine(cfg, params, slots=3)
+
+    class Poisoned:
+        def __array__(self, *a, **k):
+            raise RuntimeError("injected device error")
+
+    armed, real = [], eng._step_jit
+
+    def step(*args):
+        cache, toks, dec = real(*args)
+        if armed:
+            armed.clear()
+            return cache, toks, Poisoned()
+        return cache, toks, dec
+
+    eng._step_jit = step
+    try:
+        hold = _Hold(eng)
+        a = eng.submit(np.asarray([3, 1, 4], np.int32), 40)
+        assert hold.held.wait(120)
+        # B is admitted in the tick whose step is poisoned, C (another
+        # bucket: the next cold group) in the tick after it, which is
+        # enqueued before the poisoned one is read
+        b = eng.submit(np.asarray([2, 7, 1], np.int32), 40)
+        c = eng.submit(np.arange(1, 13, dtype=np.int32), 40)
+        armed.append(1)
+        hold.release()
+        for f in (a, b, c):
+            with pytest.raises(RuntimeError, match="injected"):
+                f.result(timeout=120)
+        p = np.asarray([5, 9, 2, 6], np.int32)
+        out = eng.generate(p, 7, timeout=120)
+        assert eng.drain(timeout=60)
+    finally:
+        eng.stop()
+    np.testing.assert_array_equal(out, _want(cfg, params, p, 7))
