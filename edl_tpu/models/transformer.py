@@ -28,7 +28,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from edl_tpu.ops import decode_attention
+from edl_tpu.ops import decode_attention, ssm
 from edl_tpu.ops.attention import dot_product_attention
 from edl_tpu.parallel.sharding import logical_constraint
 
@@ -158,6 +158,30 @@ class TransformerConfig:
     # The serving engine sets it: a slot's ring outlives the window by
     # what a pool commit reads back (serving/engine.py)
     window_ring: int = 0
+    # -- a token mixer that is not attention: layer_attn[i] == "ssm" is a
+    # Mamba-2 layer (``Mamba2Mixer``; a published ``layer_types`` entry
+    # "mamba"): ssm_heads heads of ssm_head_dim with a state of
+    # ssm_state a head element, B and C shared by the heads of each of
+    # ssm_groups groups, a causal depthwise convolution over ssm_conv
+    # positions, the scan in chunks of ssm_chunk
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    ssm_conv_bias: bool = True
+    ssm_proj_bias: bool = False
+    # what a decode model carries the recurrent state in between calls
+    # (the arithmetic on it is float32 either way)
+    ssm_state_dtype: Any = jnp.float32
+    # -- four scalars (Granite's): on the embedding, on both residual
+    # branches, the attention scale in place of 1/sqrt(head_dim) (0 =
+    # that default), and a divisor of the logits
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attn_scale: float = 0.0
+    logits_scaling: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -193,9 +217,22 @@ class TransformerConfig:
     def expert_dim(self) -> int:
         return self.moe_mlp_dim or self.mlp_dim
 
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """What the convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.attn_scale or self.head_dim ** -0.5
+
     def __post_init__(self):
         for name, plan, kinds in (
-                ("layer_attn", self.layer_attn, ("window", "global")),
+                ("layer_attn", self.layer_attn, ("window", "global", "ssm")),
                 ("layer_mlp", self.layer_mlp, ("dense", "sparse"))):
             if plan and (len(plan) != self.num_layers
                          or set(plan) - set(kinds)):
@@ -204,6 +241,11 @@ class TransformerConfig:
                     f"{self.num_layers} layers, got {plan!r}")
         if "window" in self.layer_attn and not self.attn_window:
             raise ValueError("a window layer needs attn_window")
+        if "ssm" in self.layer_attn and (
+                self.ssm_heads < 1 or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                f"a state-space layer needs ssm_heads ({self.ssm_heads}) in "
+                f"whole groups ({self.ssm_groups})")
         if "sparse" in self.layer_mlp and not self.moe_experts:
             raise ValueError("a sparse layer needs moe_experts")
         if self.moe_held and not 0 < self.moe_held <= self.moe_experts:
@@ -218,7 +260,11 @@ def _layer_matmul_params(cfg: TransformerConfig, experts: int,
     to); a shared expert counts whole either way."""
     D = cfg.embed_dim
     H, Hk, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    attn = D * (H + 2 * Hk) * Dh + H * Dh * D
+    if cfg.attn_kind(layer) == "ssm":
+        attn = (D * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
+                + cfg.ssm_inner * D)
+    else:
+        attn = D * (H + 2 * Hk) * Dh + H * Dh * D
     if cfg.mlp_kind(layer) == "dense":
         return attn + 3 * D * cfg.mlp_dim
     return (attn + D * cfg.moe_experts + 3 * D * cfg.moe_shared_dim
@@ -236,8 +282,15 @@ def param_count(cfg: TransformerConfig) -> int:
     head = 0 if cfg.tie_embeddings else D * V
     held = cfg.moe_held or cfg.moe_experts
     bias = cfg.moe_experts if cfg.moe_select_bias else 0
+    # a state-space layer: the convolution, dt_bias, A_log and D, the
+    # gated norm's scale in place of any q / k norm
+    ssm_own = (cfg.ssm_conv_dim * (cfg.ssm_conv + cfg.ssm_conv_bias)
+               + 3 * cfg.ssm_heads + cfg.ssm_inner
+               + (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads
+                  + D if cfg.ssm_proj_bias else 0))
     return V * D + head + D + sum(
-        _layer_matmul_params(cfg, held, i) + norms
+        _layer_matmul_params(cfg, held, i)
+        + (2 * D + ssm_own if cfg.attn_kind(i) == "ssm" else norms)
         + (bias if cfg.mlp_kind(i) == "sparse" else 0)
         for i in range(cfg.num_layers))
 
@@ -343,6 +396,157 @@ class RMSNorm(nn.Module):
         return (x * jax.lax.rsqrt(var + self.eps)).astype(self.dtype) * scale
 
 
+def _residual(cfg: "TransformerConfig", x, branch):
+    """``x + residual_multiplier * branch``; the product and the sum in
+    float32 where the multiplier is not 1 (0.22 is not a bfloat16)."""
+    m = cfg.residual_multiplier
+    if m == 1.0:
+        return x + branch
+    return (x.astype(jnp.float32) + m * branch.astype(jnp.float32)).astype(
+        x.dtype)
+
+
+def _gate_norm(o, z, norm):
+    """Gate first, then norm (one group over the whole inner width)."""
+    return norm(o * nn.silu(z))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a log-uniform step in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 token mixer (``ops/ssm.py`` has the recurrence): with
+    ``y`` the layer's normed input,
+
+    ``[z | xBC | dt] = y W_in``; ``xBC`` through a causal depthwise
+    convolution over ``ssm_conv`` positions and a SiLU; ``[x | B | C] =
+    xBC``; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the
+    recurrence; ``o = y_ssm + D * x``; ``RMSNorm(o * silu(z))`` over the
+    whole inner width (gate first, then norm, one group); ``W_out``.
+
+    In a decode model its ``cache`` is not keys and values but a
+    recurrence: ``conv_state [B, ssm_conv - 1, conv_dim]`` (the inputs
+    the next position's convolution looks back on, in the compute
+    dtype; time-major, so that the jit boundary's layout does not pad
+    three positions to a lane tile), ``ssm_state [B, H, P, N]`` float32,
+    and ``cache_index`` as every layer keeps it.  A one-token call
+    updates them in place (``ops/ssm.ssm_step`` on the chip; slots that
+    ``token_mask`` marks free keep theirs).  A multi-token call (prefill,
+    a chunk, a reuse suffix) runs the chunked scan FROM the cached
+    state and leaves the state after each lane's last REAL token
+    (``token_mask`` [B, L], real tokens leading): a padded position must
+    not move a recurrence.  ``snap_at`` [B] asks such a call for the
+    state after ``snap_at`` of its tokens too, sown into the ``snap``
+    collection under the cache's names: where the serving engine snapshots
+    a prompt for its prefix pool."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, y, token_mask=None, snap_at=None):
+        cfg = self.cfg
+        f32 = jnp.float32
+        H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                      cfg.ssm_groups)
+        K, Di, Cd = cfg.ssm_conv, cfg.ssm_inner, cfg.ssm_conv_dim
+        B, L = y.shape[:2]
+        with jax.named_scope("ssm/proj_in"):
+            zxbcdt = nn.Dense(Di + Cd + H, use_bias=cfg.ssm_proj_bias,
+                              dtype=cfg.dtype, param_dtype=f32,
+                              name="in_proj")(y)
+        z, xBC, dt = jnp.split(zxbcdt, [Di, Di + Cd], axis=-1)
+        conv_w = self.param("conv_w", nn.initializers.normal(K ** -0.5),
+                            (K, Cd), f32)
+        conv_b = (self.param("conv_b", nn.initializers.zeros, (Cd,), f32)
+                  if cfg.ssm_conv_bias else None)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,), f32)
+        A = -jnp.exp(self.param("A_log", _a_log_init, (H,), f32))
+        D_skip = self.param("D", nn.initializers.ones, (H,), f32)
+
+        cached = cfg.decode and self.has_variable("cache", "ssm_state")
+        if cfg.decode:
+            conv_v = self.variable("cache", "conv_state", jnp.zeros,
+                                   (B, K - 1, Cd), cfg.dtype)
+            ssm_v = self.variable("cache", "ssm_state", jnp.zeros,
+                                  (B, H, P, N), cfg.ssm_state_dtype)
+            ci = self.variable("cache", "cache_index",
+                               lambda: jnp.zeros((B,), jnp.int32))
+        conv0 = (conv_v.value if cached
+                 else jnp.zeros((B, K - 1, Cd), cfg.dtype))
+        state0 = (ssm_v.value.astype(f32) if cached
+                  else jnp.zeros((B, H, P, N), f32))
+        kept = cfg.ssm_state_dtype
+        n_real = (token_mask.sum(-1).astype(jnp.int32)
+                  if cached and token_mask is not None else None)
+
+        with jax.named_scope("ssm/conv"):
+            xin = jnp.concatenate([conv0, xBC.astype(cfg.dtype)], axis=1)
+            acc = sum(xin[:, i:i + L].astype(f32) * conv_w[i]
+                      for i in range(K))
+            if conv_b is not None:
+                acc = acc + conv_b
+            xBC = nn.silu(acc)
+
+            def window(at):     # the K - 1 inputs before position ``at``
+                idx = at[:, None] + jnp.arange(K - 1)[None, :]
+                return jnp.take_along_axis(xin, idx[:, :, None], axis=1)
+        x, Bm, Cm = jnp.split(xBC, [Di, Di + G * N], axis=-1)
+        x = x.reshape(B, L, H, P)
+        Bm, Cm = Bm.reshape(B, L, G, N), Cm.reshape(B, L, G, N)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+
+        if cached and L == 1:
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            kernel = kept == f32 and ssm.applies(L, cfg.mesh)
+            step = ssm.ssm_step if kernel else ssm.ssm_step_reference
+            with jax.named_scope("ssm/step"):
+                ys, new = step(state0, x[:, 0], dt[:, 0], A, Bm[:, 0],
+                               Cm[:, 0], live)
+            # slot states this update read and wrote, for the engine's
+            # ``ssm_state_steps_run``: the kernel's own fetch plan
+            # counted; the einsum path touches every slot
+            self.sow("intermediates", "ssm_slots_run",
+                     ssm.slots_fetched(live, H, P, N, G) if kernel
+                     else jnp.asarray(B, f32))
+            ys, ssm_v.value = ys[:, None], new.astype(kept)
+            conv_v.value = jnp.where(live[:, None, None], xin[:, 1:], conv0)
+        else:
+            ys, final, snap = ssm.ssd_scan(
+                x, dt, A, Bm, Cm, state0, chunk=cfg.ssm_chunk,
+                lengths=n_real, snap_at=snap_at if cached else None)
+            if cached:
+                ssm_v.value = final.astype(kept)
+                conv_v.value = window(
+                    jnp.full((B,), L, jnp.int32) if n_real is None
+                    else n_real)
+                if snap is not None:
+                    at = jnp.clip(snap_at.astype(jnp.int32), 0,
+                                  L if n_real is None else n_real)
+                    # named as the cache names them
+                    self.sow("snap", "ssm_state", snap.astype(kept))
+                    self.sow("snap", "conv_state", window(at))
+        if cached:
+            ci.value = ci.value + L
+        with jax.named_scope("ssm/gate_norm"):
+            o = ys + D_skip[:, None] * x.astype(f32)
+            u = _gate_norm(o.reshape(B, L, Di), z.astype(f32),
+                           RMSNorm(cfg.dtype, cfg.norm_eps, name="norm")
+                           ).astype(cfg.dtype)
+        with jax.named_scope("ssm/proj_out"):
+            return nn.Dense(cfg.embed_dim, use_bias=cfg.ssm_proj_bias,
+                            dtype=cfg.dtype, param_dtype=f32,
+                            name="out_proj")(u)
+
+
 class Block(nn.Module):
     """One decoder layer; instances are stacked by ``nn.scan`` where
     every layer is alike.  ``layer`` is the layer's place in the
@@ -390,7 +594,8 @@ class Block(nn.Module):
                            lambda: jnp.zeros((B,), jnp.int32))
         if not is_initialized:      # init trace: shapes only
             return dot_product_attention(q, k, v, causal=True, impl="dense",
-                                         window=W)
+                                         window=W,
+                                         sm_scale=cfg.attn_scale or None)
         idx = ci.value                                    # [B]
         if decode_attention.applies(L, cfg.mesh, R):
             live = (jnp.ones((B,), bool) if token_mask is None
@@ -403,8 +608,8 @@ class Block(nn.Module):
                 q[:, 0], ck.value, cv.value,
                 jnp.where(live, jnp.minimum(idx + 1, R), 0),
                 newest=idx % R,
-                visible=jnp.where(live, jnp.minimum(idx + 1, W), 0)
-            )[:, None]
+                visible=jnp.where(live, jnp.minimum(idx + 1, W), 0),
+                scale=cfg.softmax_scale)[:, None]
         slot = jnp.arange(R)[None, :]                     # [1, R]
         # the position ring slot r holds: the latest p < idx, p % R == r
         held = (idx[:, None] - 1) - (idx[:, None] - 1 - slot) % R
@@ -423,7 +628,7 @@ class Block(nn.Module):
             [cv.value, v.transpose(0, 2, 1, 3).astype(cfg.dtype)], axis=2)
         qg = q.reshape(B, L, Hk, G, Dh)
         logits = jnp.einsum("blhgd,bhdk->bhglk", qg, k_all
-                            ).astype(jnp.float32) * Dh ** -0.5
+                            ).astype(jnp.float32) * cfg.softmax_scale
         logits = jnp.where(seen[:, None, None], logits, -jnp.inf)
         weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         out = jnp.einsum("bhglk,bhkd->blhgd", weights, v_all)
@@ -503,7 +708,8 @@ class Block(nn.Module):
         ci = self.variable("cache", "cache_index",
                            lambda: jnp.zeros((B,), jnp.int32))
         if not is_initialized:      # init trace: shapes only
-            return dot_product_attention(q, k, v, causal=True, impl="dense")
+            return dot_product_attention(q, k, v, causal=True, impl="dense",
+                                         sm_scale=cfg.attn_scale or None)
         idx = ci.value                                    # [B]
         if decode_attention.applies(L, cfg.mesh, cfg.max_len):
             live = (jnp.ones((B,), bool) if token_mask is None
@@ -513,7 +719,8 @@ class Block(nn.Module):
             ci.value = idx + 1
             lengths = jnp.where(live, jnp.minimum(idx + 1, cfg.max_len), 0)
             return decode_attention.decode_attend(
-                q[:, 0], ck.value, cv.value, lengths)[:, None]
+                q[:, 0], ck.value, cv.value, lengths,
+                scale=cfg.softmax_scale)[:, None]
         if L == 1:
             # per-example scatter (tiny update: B×Hk×D elements)
             ck.value = ck.value.at[jnp.arange(B), :, :, idx].set(
@@ -547,7 +754,7 @@ class Block(nn.Module):
         q_pos = idx[:, None] + jnp.arange(L)              # [B, L]
         mask = (jnp.arange(cfg.max_len)[None, None, :]
                 <= q_pos[:, :, None])                     # [B, L, max]
-        scale = Dh ** -0.5
+        scale = cfg.softmax_scale
         # precision recipe matches dense_attention exactly (input-dtype
         # matmuls, f32 softmax) so cached decode stays bit-identical to
         # the full-prefix forward in bf16 too
@@ -559,22 +766,20 @@ class Block(nn.Module):
         out = jnp.einsum("bhglk,bhkd->blhgd", weights, cv.value)
         return out.reshape(B, L, H, Dh)
 
-    @nn.compact
-    def __call__(self, x, positions, token_mask=None):
+    def _attention(self, y, positions, token_mask, kind):
+        """The attention mixer on the normed input: projections, norms,
+        rotation, the layer's kind of attention, the output matrix."""
         cfg = self.cfg
         H, Dh = cfg.num_heads, cfg.head_dim
         Hk = cfg.kv_heads
         assert H % Hk == 0, f"num_heads {H} not divisible by kv heads {Hk}"
-        kind = cfg.attn_kind(self.layer)
         window = cfg.attn_window if kind == "window" else 0
-        x = _pin(cfg, x, "batch", "seq", None)
-        y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
         qkv = nn.DenseGeneral(((H + 2 * Hk) * Dh,), use_bias=False,
                               dtype=cfg.dtype, param_dtype=jnp.float32,
                               name="attn_qkv")(y)
         qkv = _pin(cfg, qkv, "batch", "seq", "heads")
         q, k, v = jnp.split(qkv, [H * Dh, (H + Hk) * Dh], axis=-1)
-        B, L = x.shape[:2]
+        B, L = y.shape[:2]
         if cfg.qk_norm and cfg.qk_norm_per_head:
             # each head over its own head_dim, one scale for all heads
             q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(
@@ -595,7 +800,8 @@ class Block(nn.Module):
         v = v.reshape(B, L, Hk, Dh)
         # the scopes name a mixed stack's two kinds in a trace; a stack
         # without a window keeps the op names it always had
-        with (jax.named_scope(f"attn/{kind}") if cfg.attn_window
+        with (jax.named_scope(f"attn/{kind}")
+              if cfg.attn_window or "ssm" in cfg.layer_attn
               else contextlib.nullcontext()):
             if cfg.decode and window:
                 attn = self._ring_attention(q, k, v, token_mask)
@@ -606,11 +812,23 @@ class Block(nn.Module):
                 # K/V without materialising repeats; kernels expand inside
                 attn = dot_product_attention(q, k, v, causal=True,
                                              impl=cfg.attention_impl,
-                                             mesh=cfg.mesh, window=window)
+                                             mesh=cfg.mesh, window=window,
+                                             sm_scale=cfg.attn_scale or None)
         attn = _pin(cfg, attn.reshape(B, L, H * Dh), "batch", "seq", "heads")
-        x = x + nn.DenseGeneral(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                                param_dtype=jnp.float32, name="attn_out")(attn)
+        return nn.DenseGeneral(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                               param_dtype=jnp.float32, name="attn_out")(attn)
+
+    @nn.compact
+    def __call__(self, x, positions, token_mask=None, snap_at=None):
+        cfg = self.cfg
+        kind = cfg.attn_kind(self.layer)
         x = _pin(cfg, x, "batch", "seq", None)
+        y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
+        if kind == "ssm":
+            mixed = Mamba2Mixer(cfg, name="ssm")(y, token_mask, snap_at)
+        else:
+            mixed = self._attention(y, positions, token_mask, kind)
+        x = _pin(cfg, _residual(cfg, x, mixed), "batch", "seq", None)
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="mlp_norm")(x)
         if cfg.mlp_kind(self.layer) == "sparse":
             from edl_tpu.ops.moe import MoEMLP
@@ -626,15 +844,16 @@ class Block(nn.Module):
                             shared_dim=cfg.moe_shared_dim,
                             held=cfg.moe_held,
                             name="moe")(y, token_mask)
-            return _pin(cfg, x + y, "batch", "seq", None), aux
+            return _pin(cfg, _residual(cfg, x, y), "batch", "seq", None), aux
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                         param_dtype=jnp.float32, name="mlp_gate")(y)
         up = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                       param_dtype=jnp.float32, name="mlp_in")(y)
         y = nn.silu(_pin(cfg, gate, "batch", "seq", "mlp")) * _pin(
             cfg, up, "batch", "seq", "mlp")
-        x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, name="mlp_out")(y)
+        x = _residual(cfg, x, nn.Dense(
+            cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+            param_dtype=jnp.float32, name="mlp_out")(y))
         return _pin(cfg, x, "batch", "seq", None), None
 
 
@@ -650,7 +869,7 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, ids, positions=None, train: bool = True,
                  return_hidden: bool = False, with_aux: bool = False,
-                 token_mask=None):
+                 token_mask=None, snap_at=None):
         """Logits [B, L, V] f32 — or, with ``return_hidden``, the
         final-norm hidden states [B, L, D] for the fused-CE loss path
         (:func:`lm_loss_fused`), which never materialises the logits.
@@ -658,13 +877,18 @@ class TransformerLM(nn.Module):
         loss (the MoE load-balance term; 0 for dense MLP configs).
         ``token_mask`` ([B, L] bool) marks real tokens in a padded
         batch — pad positions are excluded from MoE routing (they must
-        not consume expert capacity; ops/moe.py compute_routing)."""
+        not consume expert capacity; ops/moe.py compute_routing); in a
+        decode model real tokens lead, and a state-space layer's state
+        stops at the last of them.  ``snap_at`` ([B] int, decode models)
+        is handed to the state-space layers (``Mamba2Mixer``)."""
         cfg = self.cfg
         del train
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
         x = nn.Embed(cfg.vocab_size, cfg.embed_dim, param_dtype=jnp.float32,
                      dtype=cfg.dtype, name="tok_embed")(ids)
+        if cfg.embed_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embed_multiplier, x.dtype)
         x = _pin(cfg, x, "batch", "seq", None)
 
         if cfg.decode:
@@ -678,7 +902,7 @@ class TransformerLM(nn.Module):
             aux = None
             for i in range(cfg.num_layers):
                 x, _ = Block(cfg, i, name=f"layer_{i}")(x, positions,
-                                                        token_mask)
+                                                        token_mask, snap_at)
         elif not cfg.uniform:
             # layers that differ cannot be stacked (a dense layer has
             # no expert matrices): unrolled, ``layer_<i>`` parameters,
@@ -715,6 +939,8 @@ class TransformerLM(nn.Module):
             logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                               param_dtype=jnp.float32, name="lm_head")(x)
         logits = logits.astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         return (logits, aux_total) if with_aux else logits
 
 
@@ -744,6 +970,11 @@ def lm_loss_fused(params, hidden, targets, cfg: TransformerConfig,
         w = params["tok_embed"]["embedding"].T
     else:
         w = params["lm_head"]["kernel"]
+    if cfg.logits_scaling != 1.0:
+        # on the small operand, in float32: the logits are the dense
+        # head's divided by logits_scaling
+        hidden = (hidden.astype(jnp.float32) / cfg.logits_scaling).astype(
+            hidden.dtype)
     nll = blockwise_cross_entropy(hidden, w.astype(hidden.dtype), targets,
                                   block_size=block_size)
     return _masked_mean(nll, mask)

@@ -222,11 +222,13 @@ def attend_block(Hk: int, D: int, max_len: int, dtype) -> int:
 
 
 def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
-                  block: int | None = None, interpret=None):
+                  block: int | None = None, interpret=None,
+                  scale: float | None = None):
     """Attention of one query per head, ``q [B, H, D]``, against the
     first ``lengths[b]`` positions of slot b's slabs; ``[B, H, D]`` in
     ``q``'s dtype.  Query head h reads KV head ``h // (H // Hk)``.  A
-    slot of length 0 reads nothing and returns zeros.
+    slot of length 0 reads nothing and returns zeros.  ``scale``
+    multiplies the scores (None = ``D ** -0.5``).
 
     With ``newest`` and ``visible`` (``[B]`` int) the slabs are rings:
     slot b attends the ``visible[b]`` slots that end, wrapping, at ring
@@ -262,7 +264,8 @@ def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
     extra = ((newest.astype(jnp.int32), visible.astype(jnp.int32))
              if ring else ())
     out = pl.pallas_call(
-        functools.partial(_attend_kernel, tk=tk, scale=D ** -0.5,
+        functools.partial(_attend_kernel, tk=tk,
+                          scale=D ** -0.5 if scale is None else scale,
                           ring=T if ring else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4 + len(extra), grid=(B, T // tk),

@@ -137,6 +137,17 @@ _INTERTOKEN_SECONDS = obs_metrics.histogram(
     "(done - first token) / (tokens - 1)", buckets=_FINE_BUCKETS)
 
 
+def _ssm_slots_run(intermediates) -> "jax.Array":
+    """Slot states the one-token updates of a program's state-space
+    layers read and wrote (``Mamba2Mixer`` sows ``ssm_slots_run``: on
+    the chip counted from the kernel's fetch plan), summed over the
+    layers: a float32 scalar."""
+    return sum((jnp.asarray(leaf, jnp.float32).sum() for path, leaf in
+                jax.tree_util.tree_leaves_with_path(intermediates or {})
+                if any(getattr(k, "key", None) == "ssm_slots_run"
+                       for k in path)), jnp.zeros((), jnp.float32))
+
+
 def _zeros_of(shapes):
     """Zeros for a tree of ``ShapeDtypeStruct``s, traced or eager."""
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
@@ -158,7 +169,7 @@ class _Slot:
 
 class _Request:
     __slots__ = ("ids", "max_new", "future", "session", "ctx", "skipped",
-                 "snap", "t_submit", "t_admit", "t_first", "t_done")
+                 "snap", "cut", "t_submit", "t_admit", "t_first", "t_done")
 
     def __init__(self, ids: np.ndarray, max_new: int,
                  session: str | None = None):
@@ -173,6 +184,9 @@ class _Request:
         # window layers: the snapshot (id, end) taken when the prompt's
         # prefill ended, until the commit finds its node
         self.snap = (0, 0)
+        # pooled tokens a hit had to give up and prefill again: no
+        # layer-state snapshot that deep (kv_cache.match's last_cut)
+        self.cut = 0
         # stages, on one monotonic clock: submit <= admit <= first <= done
         self.t_submit = time.monotonic()
         self.t_admit = self.t_first = self.t_done = None
@@ -202,6 +216,7 @@ class _Tick:
     live: list
     dec: object = None
     moe: object = None
+    ssm: object = None
     counts: object = None
     pres: list = dataclasses.field(default_factory=list)
 
@@ -284,9 +299,25 @@ class ContinuousBatcher:
         self._ring_layers = frozenset(
             f"layer_{i}" for i in range(cfg.num_layers)
             if cfg.attn_kind(i) == "window")
+        # -- state-space layers (transformer.Mamba2Mixer): a slot holds
+        # a fixed-size recurrence, whatever max_len is
+        self._state_layers = frozenset(
+            f"layer_{i}" for i in range(cfg.num_layers)
+            if cfg.attn_kind(i) == "ssm")
+        k = constants.SPEC_K if spec_k is None else int(spec_k)
+        if self._state_layers and k > 0:
+            raise ValueError(
+                "speculative decoding (spec_k > 0) does not serve a "
+                "state-space configuration: a rejected draft rewinds the "
+                "cache index, and a recurrent state that has taken the "
+                "rejected tokens in cannot be rewound")
+        if self._state_layers and mesh is not None:
+            raise ValueError(
+                "a mesh engine does not serve a state-space configuration: "
+                "the state layers' slot state and snapshot pool have no "
+                "sharding yet (serving/kv_cache.py)")
         ring = 0
         if self._ring_layers:
-            k = constants.SPEC_K if spec_k is None else int(spec_k)
             if k > 0:
                 raise ValueError(
                     "speculative decoding (spec_k > 0) does not serve a "
@@ -365,16 +396,24 @@ class ContinuousBatcher:
                     else kv_max_sessions)
         # window snapshots (kv_cache.py): a pinned tail a session, one
         # a finished request until it ages out, and the scratch entry
+        # a state layer's snapshot is its whole slot state (38 MB at
+        # granite-4.0-h-small's widths where a window's is 3): as many
+        # of them as _require_fit finds room for, slots / 2 at least
         n_snaps = (sessions + 2 * slots + 1
-                   if self._ring_layers and kv_block > 0 else 0)
-        self._require_fit(slots, kv_block, pool_blocks, n_snaps)
+                   if (self._ring_layers or self._state_layers)
+                   and kv_block > 0 else 0)
+        n_snaps = self._require_fit(slots, kv_block, pool_blocks, n_snaps)
         one_lane = self._cache_shapes(1)
+
+        def cls_of(name):
+            return ("window" if name in self._ring_layers else
+                    "state" if name in self._state_layers else "global")
+
         self._slot_bytes = {
             cls: sum(leaf.size * leaf.dtype.itemsize
-                     for name, node in one_lane.items()
-                     if (name in self._ring_layers) == (cls == "window")
+                     for name, node in one_lane.items() if cls_of(name) == cls
                      for leaf in jax.tree.leaves(node) if leaf.ndim > 1)
-            for cls in ("window", "global")}
+            for cls in ("window", "global", "state")}
         # what one window layer's decode read fetches of a slot that
         # holds n ring positions: whole attend blocks on the kernels'
         # path, the ring on the einsum path
@@ -410,19 +449,20 @@ class ContinuousBatcher:
             self._kv = PagedKVCache(
                 one_lane, kv_block, pool_blocks, sessions, mesh=mesh,
                 ring_layers=self._ring_layers, window=cfg.attn_window,
-                n_snaps=n_snaps)
+                n_snaps=n_snaps, state_layers=self._state_layers)
         slab0 = max(jax.tree.leaves(self._cache), key=lambda x: x.ndim)
         pool0 = (jax.tree.leaves(self._kv.pool)[0]
                  if self._kv is not None else None)
         logger.info(
             "kv cache: %d slots x %d tokens (%s, slab sharding %s); pool "
             "%d blocks of %d (sharding %s); a slot holds %d bytes in %d "
-            "window layers (ring %d) and %d in global layers; %d window "
-            "snapshots", slots, cache_len,
+            "window layers (ring %d), %d in global layers and %d in %d "
+            "state-space layers; %d layer-state snapshots", slots, cache_len,
             slab0.dtype.name, mesh and slab0.sharding.spec, pool_blocks,
             kv_block, mesh and pool0 is not None and pool0.sharding.spec,
             self._slot_bytes["window"], len(self._ring_layers), ring,
-            self._slot_bytes["global"], n_snaps)
+            self._slot_bytes["global"], self._slot_bytes["state"],
+            len(self._state_layers), n_snaps)
         self._kv_hits = 0
         self._kv_misses = 0
         self._prefill_tokens = 0
@@ -466,6 +506,17 @@ class ContinuousBatcher:
         # its read fetched, and the positions its window holds
         self._kv_window_read = 0
         self._kv_window_need = 0
+        # state-space layers: (slot, token step, layer) states the step
+        # programs updated for live slots, and all they read and wrote;
+        # positions the prefill and chunk programs ran through the scan,
+        # and those of them that were padding; snapshots not taken; and
+        # pooled tokens prefilled again for want of a snapshot
+        self._ssm_steps = 0
+        self._ssm_steps_run = 0
+        self._ssm_prefill_pos = 0
+        self._ssm_prefill_pad = 0
+        self._state_snap_skips = 0
+        self._state_reprefill = 0
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
         # the tick enqueued and not read (_tick); ticks that enqueued
         # theirs while it was unread; token steps a program ran for a
@@ -687,8 +738,13 @@ class ContinuousBatcher:
         for K in self.PREFILL_KS:   # __init__ already filtered by slots
             ids = jnp.zeros((K, P), jnp.int32)
             lens = jnp.ones((K,), jnp.int32)
-            slab, toks, _ = self._prefill_fn(P, K)(self._params, ids,
-                                                   lens, key)
+            at = (jnp.zeros((K,), jnp.int32) if self._state_layers
+                  else None)
+            slab, toks, _, snap = self._prefill_fn(P, K)(
+                self._params, ids, lens, key, at)
+            if snap is not None and self._kv is not None:
+                # the snapshot an admission takes of its state layers
+                self._kv.store_state(snap, 0, 0)
             # lower+compile only: executing would donate the live cache
             self._insert_jit.lower(self._cache, self._toks, slab,
                                    jnp.zeros((K,), jnp.int32),
@@ -711,9 +767,11 @@ class ContinuousBatcher:
                 slab, drops = self._chunk_mid_fn(C)(
                     self._params, slab, jnp.zeros((1, C), jnp.int32), drops)
                 Pf = self._bucket(prompt_len - off)
-                slab, toks, _ = self._chunk_final_fn(Pf)(
+                slab, toks, *_ = self._chunk_final_fn(Pf)(
                     self._params, slab, jnp.zeros((1, Pf), jnp.int32),
-                    jnp.ones((1,), jnp.int32), drops, key)
+                    jnp.ones((1,), jnp.int32), drops, key,
+                    jnp.zeros((1,), jnp.int32) if self._state_layers
+                    else None)
                 jax.block_until_ready(toks)
         if self._spec_k:
             for K in self.PREFILL_KS:
@@ -749,16 +807,23 @@ class ContinuousBatcher:
                 # shallowest real depth that pads to n_pad — combos no
                 # admissible chain can produce must not be compiled
                 n_min = n_pad // 2 + 1 if n_pad > 1 else 1
+                hit = (self._kv.pool, jnp.zeros((n_pad,), jnp.int32),
+                       jnp.asarray(bs, jnp.int32))
                 for Pb in (b for b in self._buckets if b <= P):
                     if n_min * bs + Pb > cache_len:
                         continue
-                    _, toks, _ = self._reuse_prefill_fn(Pb, n_pad)(
-                        self._params, self._kv.pool,
-                        jnp.zeros((1, Pb), jnp.int32),
-                        jnp.zeros((n_pad,), jnp.int32),
-                        jnp.asarray(bs, jnp.int32),
-                        jnp.ones((1,), jnp.int32), key,
-                        self._kv.snap_arg(0))
+                    ids = jnp.zeros((1, Pb), jnp.int32)
+                    one = jnp.ones((1,), jnp.int32)
+                    if self._state_layers:      # _dispatch_reuse's pair
+                        _, toks, *_ = self._chunk_final_fn(Pb)(
+                            self._params, self._load_prefix_fn(n_pad)(
+                                *hit, self._kv.snap_arg(0)), ids, one,
+                            self._zeros(("acc",), self._moe_acc_shape, None),
+                            key, jnp.zeros((1,), jnp.int32))
+                    else:
+                        _, toks, *_ = self._reuse_prefill_fn(Pb, n_pad)(
+                            self._params, *hit, ids, one, key,
+                            self._kv.snap_arg(0))
                     jax.block_until_ready(toks)
 
     def stats(self) -> dict:
@@ -795,6 +860,21 @@ class ContinuousBatcher:
                 "decode_kv_tokens_window_need": self._kv_window_need,
                 "kv_slot_bytes_window": self._slot_bytes["window"],
                 "kv_slot_bytes_global": self._slot_bytes["global"],
+                # state-space layers (0s without one): the bytes of one
+                # slot's recurrent state (independent of max_len too);
+                # (slot, token step, layer) states the step programs
+                # updated for LIVE slots, and all they read and wrote
+                # (counted by the step program from the kernel's own
+                # fetch plan: equal when free slots cost nothing; slots
+                # x steps x layers on the einsum path);
+                # positions the prefill, chunk and reuse programs ran
+                # through the scan, and those that were padding (bucket
+                # padding, masked: a scan cannot skip them for free)
+                "kv_slot_bytes_state": self._slot_bytes["state"],
+                "ssm_state_steps": self._ssm_steps,
+                "ssm_state_steps_run": self._ssm_steps_run,
+                "ssm_prefill_positions": self._ssm_prefill_pos,
+                "ssm_prefill_positions_pad": self._ssm_prefill_pad,
                 # MoE prefill capacity overflow (always 0 for dense
                 # configs; nonzero = raise capacity_factor)
                 "moe_prefill_drops": self._moe_drops,
@@ -901,6 +981,17 @@ class ContinuousBatcher:
             "kv_sessions": self._kv.session_count(),
             "kv_window_snapshots": self._kv.snaps_used(),
             "kv_window_snapshot_skips": self._kv.snap_skips,
+            # state-space layers (0s without one): snapshots held (one
+            # entry serves a node's window and state layers alike),
+            # prompt snapshots not taken (no free entry, or the block
+            # edge lay before the prefill's last program), and pooled
+            # tokens a hit prefilled again because no snapshot lay as
+            # deep as the blocks (an answer's end is never snapshotted:
+            # a session's next turn starts from its last PROMPT's edge)
+            "kv_state_snapshots": (self._kv.snaps_used()
+                                   if self._state_layers else 0),
+            "kv_state_snapshot_skips": self._state_snap_skips,
+            "kv_state_reprefill_tokens": self._state_reprefill,
         }
 
     def drain(self, timeout: float | None = None) -> bool:
@@ -961,31 +1052,45 @@ class ContinuousBatcher:
 
     # -- device state construction -------------------------------------------
     def _require_fit(self, slots: int, kv_block: int,
-                     pool_blocks: int, n_snaps: int = 0) -> None:
+                     pool_blocks: int, n_snaps: int = 0) -> int:
         """Refuse at construction, with the sizes, an engine whose slot
         slabs + block pool + largest prefill dispatch cannot fit what
         the device has left — instead of an XLA allocation error on
         whichever request first needs the memory.  Backends that report
-        no limit (CPU) are not checked."""
+        no limit (CPU) are not checked.
+
+        Returns the number of layer-state snapshots the pool gets:
+        ``n_snaps`` as asked, but for a state-space configuration, whose
+        snapshot is a whole slot state: there the count starts at
+        ``slots // 2`` (+ the scratch entry), the prefill ladder is
+        fitted beside that, and what is left over goes to snapshots, up
+        to one a slot."""
         from edl_tpu.serving.kv_cache import pool_device_bytes
         dev = (self._mesh.devices.flat[0] if self._mesh is not None
                else jax.devices()[0])
         stats = dev.memory_stats() or {}
         limit = stats.get("bytes_limit")
         if not limit:
-            return
+            return n_snaps
         tp = dict(self._mesh.shape).get("tp", 1) if self._mesh else 1
         one_lane = self._cache_shapes(1)
+        wanted = n_snaps
+        if self._state_layers and n_snaps:
+            wanted = min(wanted, slots + 1)
+            n_snaps = min(n_snaps, slots // 2 + 1)
         # every cache leaf is [lanes, ...]: one lane's bytes, with
         # _leaf_sharding's rule (KV heads over tp where they divide)
         lane = sum(s.size * s.dtype.itemsize
                    // (tp if s.ndim >= 2 and s.shape[1] % tp == 0 else 1)
                    for s in jax.tree.leaves(one_lane))
         cache_len = self._dcfg.max_len
-        pool = (pool_device_bytes(one_lane, kv_block, pool_blocks, tp,
-                                  self._ring_layers, self.cfg.attn_window,
-                                  n_snaps)
-                if kv_block > 0 else 0)
+        def pool_bytes(n):
+            return (pool_device_bytes(one_lane, kv_block, pool_blocks, tp,
+                                      self._ring_layers, self.cfg.attn_window,
+                                      n, self._state_layers)
+                    if kv_block > 0 else 0)
+
+        pool = pool_bytes(n_snaps)
         # the widest admission: K fresh lanes, their [K, P, vocab] f32
         # logits and one layer's [K, heads, P, cache_len] f32 scores (a
         # multi-token call attends the whole slab under its mask), P the
@@ -998,9 +1103,18 @@ class ContinuousBatcher:
         heads = self.cfg.num_heads // (tp if self.cfg.num_heads % tp == 0
                                        else 1)
         in_use = stats.get("bytes_in_use", 0)
+        # a state-space layer's prefill: the projections' float32 copies
+        # of a lane's tokens and the scan's [heads, chunk, chunk] blocks
+        cfg = self.cfg
+        scan = 0
+        if self._state_layers:
+            q = min(cfg.ssm_chunk, p_max)
+            scan = 4 * (4 * p_max * (cfg.ssm_inner + cfg.ssm_conv_dim)
+                        + 3 * cfg.ssm_heads * q * q)
         for i, k_max in enumerate(self.PREFILL_KS):
-            prefill = k_max * (lane + 4 * p_max * (self.cfg.vocab_size
-                                                   + heads * cache_len))
+            prefill = k_max * (lane + scan
+                               + 4 * p_max * (self.cfg.vocab_size
+                                              + heads * cache_len))
             need = in_use + slots * lane + pool + prefill
             if need <= limit:
                 if i:
@@ -1010,7 +1124,11 @@ class ContinuousBatcher:
                         "not fit beside the weights", self.PREFILL_KS[0],
                         k_max, self.PREFILL_KS[0], p_max, cache_len)
                     self.PREFILL_KS = self.PREFILL_KS[i:]
-                return
+                if n_snaps < wanted:
+                    each = pool_bytes(n_snaps + 1) - pool
+                    n_snaps = min(wanted,
+                                  n_snaps + int((limit - need) // each))
+                return n_snaps
         gb = 1 / (1 << 30)
         raise ValueError(
             f"engine does not fit {dev.device_kind}: "
@@ -1019,7 +1137,8 @@ class ContinuousBatcher:
             f"{cache_len} tokens; {len(self._ring_layers)} window "
             f"layers hold a ring of {self._dcfg.ring_len}) + "
             f"{pool * gb:.2f} GiB block pool "
-            f"({pool_blocks} blocks of {kv_block}, {n_snaps} window "
+            f"({pool_blocks} blocks of {kv_block}, {n_snaps} "
+            f"{'layer-state' if self._state_layers else 'window'} "
             f"snapshots) + "
             f"{prefill * gb:.2f} GiB widest prefill ({k_max} lanes x "
             f"{p_max} tokens) = {need * gb:.2f} GiB > "
@@ -1094,7 +1213,7 @@ class ContinuousBatcher:
             return cached
         model = self._model
 
-        def prefill(params, ids, true_lens, key):
+        def prefill(params, ids, true_lens, key, snap_at=None):
             cache = _zeros_of(self._cache_shapes(K))
             # pad positions are masked out of MoE routing (they must
             # not claim expert capacity ahead of real tokens' choices;
@@ -1108,7 +1227,7 @@ class ContinuousBatcher:
                                            ids.shape),
                 token_mask=jnp.arange(ids.shape[1])[None, :]
                 < true_lens[:, None],
-                mutable=["cache", "intermediates"])
+                **self._snap_kw(snap_at))
             # padded prompts: sample each lane at ITS last real
             # position; the pad queries wrote kv past true_len, which
             # insertion resets (cache_index := true_len) and masks
@@ -1117,11 +1236,49 @@ class ContinuousBatcher:
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
             # MoE capacity overflow at prefill (0 for dense configs)
-            return mut["cache"], toks, _moe_stats(mut.get("intermediates"))
+            return (mut["cache"], toks, _moe_stats(mut.get("intermediates")),
+                    self._snap_of(mut))
 
         fn = jax.jit(prefill)
         self._prefill_cache[(P, K)] = fn
         return fn
+
+    def _snap_kw(self, snap_at) -> dict:
+        """A prefill program's ``mutable`` and, with state-space layers,
+        where each lane's snapshot is wanted and the ``snap`` collection
+        they sow it into.  Without them the programs are what they
+        were."""
+        if not self._state_layers:
+            return {"mutable": ["cache", "intermediates"]}
+        return {"snap_at": snap_at,
+                "mutable": ["cache", "intermediates", "snap"]}
+
+    def _snap_of(self, mut):
+        """``{layer: {leaf: [lanes, ...]}}`` out of a prefill's ``snap``
+        collection, the leaves named as the pool names them
+        (``kv_cache.state_leaves``); None without state-space layers."""
+        if not self._state_layers:
+            return None
+        from edl_tpu.serving.kv_cache import state_leaves
+        return {name: state_leaves(node)
+                for name, node in mut["snap"].items()}
+
+    def _snap_end(self, req: "_Request") -> int:
+        """Where a prompt is snapshotted for the pool: the deepest block
+        edge the SAME prompt can match again (a hit leaves at least one
+        token to prefill); 0 without layer-state snapshots."""
+        if self._kv is None or not (self._ring_layers or self._state_layers):
+            return 0
+        return (len(req.ids) - 1) // self._kv.block * self._kv.block
+
+    def _count_scan(self, lanes: int, width: int, real: int) -> None:
+        """One prefill, chunk or reuse program ran ``lanes x width``
+        positions through every state-space layer's scan, ``real`` of
+        them tokens."""
+        if self._state_layers:
+            with self._stats_lock:
+                self._ssm_prefill_pos += lanes * width
+                self._ssm_prefill_pad += lanes * width - real
 
     @staticmethod
     def _place(cache, slab, slots, true_lens):
@@ -1153,9 +1310,11 @@ class ContinuousBatcher:
         expert path routes free slots' ballast tokens nowhere.  With
         that path the layers' ``moe_stats`` ride back beside the
         tokens, ``(cache, last, (tokens, stats))``; otherwise ``(cache,
-        last, tokens)``.  ``last`` ([slots]) is what the final token
-        step sampled: the next call's ``toks``, handed over on the
-        device.
+        last, tokens)``.  State-space layers add the slot states their
+        one-token updates read and wrote (``_ssm_slots_run``: counted
+        on the device from the kernel's own plan), last in that tuple.
+        ``last`` ([slots]) is what the final token step sampled: the
+        next call's ``toks``, handed over on the device.
 
         ``params`` is an ARGUMENT, not a closure capture: a captured
         param tree would be baked into the jaxpr as constants — 124M
@@ -1163,7 +1322,9 @@ class ContinuousBatcher:
         every compile-cache key would then have to carry."""
         model = self._model
 
-        moe = self._moe_dropless
+        # what the layers sow for the host, each read by its own reader
+        readers = ([_moe_stats] if self._moe_dropless else []) + (
+            [_ssm_slots_run] if self._state_layers else [])
 
         def one(carry, k):
             cache, tok, *acc = carry
@@ -1172,17 +1333,20 @@ class ContinuousBatcher:
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
                 positions=pos[:, None], token_mask=live[:, None],
-                mutable=["cache", "intermediates"] if moe else ["cache"])
+                mutable=["cache", "intermediates"] if readers else ["cache"])
             nxt = self._sample(logits[:, -1], k)
-            acc = [a + _moe_stats(mut.get("intermediates")) for a in acc]
+            acc = [a + read(mut.get("intermediates"))
+                   for a, read in zip(acc, readers)]
             return (mut["cache"], nxt, *acc), nxt
 
         keys = jax.random.split(key, self._T)
-        acc0 = [_zeros_of(self._moe_acc_shape)] if moe else []
+        acc0 = ([_zeros_of(self._moe_acc_shape)] if self._moe_dropless
+                else []) + ([jnp.zeros((), jnp.float32)]
+                            if self._state_layers else [])
         (cache, last, *acc), out = jax.lax.scan(
             one, (cache, toks, *acc0), keys)
         # [slots, T]
-        return cache, last, ((out.T, acc[0]) if moe else out.T)
+        return cache, last, ((out.T, *acc) if acc else out.T)
 
     @staticmethod
     def _positions(cache):
@@ -1487,12 +1651,16 @@ class ContinuousBatcher:
                 self._cache, self._toks, tick.dec = self._step_jit(
                     self._cache, self._toks, key, self._params,
                     self._live_mask([i for i, _ in live]))
-                if self._moe_dropless:
-                    tick.dec, tick.moe = tick.dec
+                if isinstance(tick.dec, tuple):     # tokens, then counts
+                    tick.dec, *counts = tick.dec
+                    if self._moe_dropless:
+                        tick.moe = counts.pop(0)
+                    if self._state_layers:
+                        tick.ssm = counts.pop(0)
                 for i, _ in live:
                     s = self._slots[i]
                     s.owed = max(0, s.owed - self._T)
-        for slab, toks, _, slots, reqs, lens, dslab in pres:
+        for slab, toks, _, slots, reqs, lens, dslab, snap in pres:
             at = jnp.asarray(slots, jnp.int32)
             n = jnp.asarray(lens, jnp.int32)
             self._cache, self._toks = self._insert_jit(
@@ -1501,8 +1669,8 @@ class ContinuousBatcher:
                 self._draft_cache = self._draft_insert_jit(
                     self._draft_cache, dslab, at, n)
             # before the next step advances the slot's rings
-            for slot, req in zip(slots, reqs):
-                self._snap_prompt(slot, req)
+            for lane, (slot, req) in enumerate(zip(slots, reqs)):
+                self._snap_prompt(slot, req, lane, *snap)
         return tick
 
     def _read(self, tick: "_Tick | None") -> None:
@@ -1515,6 +1683,7 @@ class ContinuousBatcher:
         with led.phase("sync"):
             dec = np.asarray(tick.dec) if tick.dec is not None else None
             moe = np.asarray(tick.moe) if tick.moe is not None else None
+            ssm = float(tick.ssm) if tick.ssm is not None else 0.0
             counts = (np.asarray(tick.counts) if tick.counts is not None
                       else None)
             fins = [(p[3], p[4], np.asarray(p[1]), np.asarray(p[2]))
@@ -1524,7 +1693,7 @@ class ContinuousBatcher:
                 if counts is not None:
                     self._finish_spec(dec, counts, tick.live)
                 else:
-                    self._finish_decode(dec, tick.live)
+                    self._finish_decode(dec, tick.live, ssm)
                 if moe is not None:
                     self._count_moe(moe, len(tick.live) * self._T,
                                     decode=True)
@@ -1665,12 +1834,15 @@ class ContinuousBatcher:
                 ids[i, :len(req.ids)] = req.ids
                 lens[i] = len(req.ids)
             self._rng, key = jax.random.split(self._rng)
-            slab, toks, drops = self._prefill_fn(P, K)(
-                self._params, jnp.asarray(ids), jnp.asarray(lens), key)
+            ends = (jnp.asarray([self._snap_end(r) for r in reqs], jnp.int32)
+                    if self._state_layers else None)
+            slab, toks, drops, snap = self._prefill_fn(P, K)(
+                self._params, jnp.asarray(ids), jnp.asarray(lens), key, ends)
+            self._count_scan(K, P, int(lens.sum()))
             dslab = (self._draft_prefill_fn(P, K)(
                 self._draft_params, jnp.asarray(ids), jnp.asarray(lens))
                 if self._spec_k else None)
-            return slab, toks, drops, slots, reqs, lens, dslab
+            return slab, toks, drops, slots, reqs, lens, dslab, (snap, 0)
         except Exception as e:  # noqa: BLE001 — fail THIS group only
             logger.exception("prefill failed (bucket %d, %d reqs)", P, K)
             for req in reqs:
@@ -1751,6 +1923,7 @@ class ContinuousBatcher:
                 st.slab, st.drops = self._chunk_mid_fn(C)(
                     self._params, st.slab, jnp.asarray(chunk), st.drops)
                 st.offset += C
+                self._count_scan(1, C, C)
                 with self._stats_lock:
                     self._prefill_chunks += 1
                 return None
@@ -1758,14 +1931,18 @@ class ContinuousBatcher:
             tail = np.zeros((1, P), np.int32)
             tail[0, :rest] = ids[st.offset:]
             self._rng, key = jax.random.split(self._rng)
-            slab, toks, drops = self._chunk_final_fn(P)(
+            at = (jnp.asarray([max(self._snap_end(st.req) - st.offset, 0)],
+                              jnp.int32) if self._state_layers else None)
+            slab, toks, drops, snap = self._chunk_final_fn(P)(
                 self._params, st.slab, jnp.asarray(tail),
-                jnp.asarray([rest], jnp.int32), st.drops, key)
+                jnp.asarray([rest], jnp.int32), st.drops, key, at)
             self._chunking = None
+            self._count_scan(1, P, rest)
             with self._stats_lock:
                 self._prefill_chunks += 1
             dslab = self._draft_slab_for(st.req) if self._spec_k else None
-            return slab, toks, drops, [st.slot], [st.req], [len(ids)], dslab
+            return (slab, toks, drops, [st.slot], [st.req], [len(ids)],
+                    dslab, (snap, st.offset))
         except Exception as e:  # noqa: BLE001 — fail THIS request only
             logger.exception("chunked prefill failed (offset %d of %d)",
                              st.offset, len(ids))
@@ -1811,25 +1988,26 @@ class ContinuousBatcher:
             return cached
         model = self._model
 
-        def fin(params, slab, ids, rel_lens, drops_in, key):
+        def fin(params, slab, ids, rel_lens, drops_in, key, snap_at=None):
             idx = self._positions(slab)
             logits, mut = model.apply(
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(P)[None, :],
                 token_mask=jnp.arange(P)[None, :] < rel_lens[:, None],
-                mutable=["cache", "intermediates"])
+                **self._snap_kw(snap_at))
             last = jnp.take_along_axis(
                 logits, (rel_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
             return (mut["cache"], toks,
-                    drops_in + _moe_stats(mut.get("intermediates")))
+                    drops_in + _moe_stats(mut.get("intermediates")),
+                    self._snap_of(mut))
 
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             sh = self._cache_shardings(1)
             rep = NamedSharding(self._mesh, PartitionSpec())
             fn = jax.jit(fin, donate_argnums=(1,),
-                         out_shardings=(sh, rep, rep))
+                         out_shardings=(sh, rep, rep, None))
         else:
             fn = jax.jit(fin, donate_argnums=(1,))
         self._prefill_cache[("chunkfin", P)] = fn
@@ -1852,6 +2030,8 @@ class ContinuousBatcher:
             return None
         req0 = self._pending[0]
         chain = self._kv.match(req0.ids)
+        if self._state_layers:
+            req0.cut = self._kv.last_cut
         cache_len = self._dcfg.max_len
         while chain:
             # the suffix pads to its bucket, and the cache write is a
@@ -1902,19 +2082,40 @@ class ContinuousBatcher:
             block_ids = np.zeros((n_pad,), np.int32)
             block_ids[:n] = [nd.block_id for nd in chain]
             self._rng, key = jax.random.split(self._rng)
-            slab, toks, drops = self._reuse_prefill_fn(P, n_pad)(
-                self._params, self._kv.pool, jnp.asarray(ids),
-                jnp.asarray(block_ids),
-                jnp.asarray(prefix_len, jnp.int32),
-                jnp.asarray([len(suffix)], jnp.int32), key,
-                self._kv.snap_arg(chain[-1].snap))
+            hit = (self._kv.pool, jnp.asarray(block_ids),
+                   jnp.asarray(prefix_len, jnp.int32))
+            n_real = jnp.asarray([len(suffix)], jnp.int32)
+            snap_id = self._kv.snap_arg(chain[-1].snap)
+            if self._state_layers:
+                # two programs where the others fuse them: the gather
+                # alone (small: one attention layer's blocks and the
+                # snapshot), then the chunk lane's last-chunk program,
+                # which IS a suffix prefill from a slab's state.  Fused,
+                # every (suffix bucket, chain depth) pair would be one
+                # more compile of the whole stack (36 of them at 4096
+                # tokens: nine minutes of set-up on the chip, PR 32).
+                # The others keep the fused program: as a pair, their
+                # sessions cell read 1.4% fewer tokens a second, lower
+                # in four pairs of four (PERF.md section 6, PR 32)
+                at = jnp.asarray([max(self._snap_end(req) - prefix_len, 0)],
+                                 jnp.int32)
+                slab, toks, drops, snap = self._chunk_final_fn(P)(
+                    self._params, self._load_prefix_fn(n_pad)(*hit, snap_id),
+                    jnp.asarray(ids), n_real,
+                    self._zeros(("acc",), self._moe_acc_shape, None), key, at)
+            else:
+                slab, toks, drops, snap = self._reuse_prefill_fn(P, n_pad)(
+                    self._params, *hit, jnp.asarray(ids), n_real, key,
+                    snap_id)
+            self._count_scan(1, P, len(suffix))
             # insert true_lens = the FULL prompt length: the slab's
             # cache_index already sits at prefix+suffix and the pool
             # lane must agree.  The draft has no pool: its slab is
             # rebuilt from the FULL prompt in one small-model pass
             # (draft state moves the accept rate, never correctness).
             dslab = self._draft_slab_for(req) if self._spec_k else None
-            return slab, toks, drops, [slot], [req], [len(req.ids)], dslab
+            return (slab, toks, drops, [slot], [req], [len(req.ids)], dslab,
+                    (snap, prefix_len))
         except Exception as e:  # noqa: BLE001 — fail THIS request only
             logger.exception("reuse prefill failed (suffix bucket %d, "
                              "%d blocks)", P, n)
@@ -1935,7 +2136,7 @@ class ContinuousBatcher:
         model = self._model
         kv = self._kv
 
-        def prefill(params, pool, ids, block_ids, prefix_len, true_lens,
+        def prefill(params, pool, block_ids, prefix_len, ids, true_lens,
                     key, snap_id):
             cache = _zeros_of(self._cache_shapes(1))
             cache = kv.load_prefix_into(cache, pool, block_ids, n_pad,
@@ -1949,10 +2150,29 @@ class ContinuousBatcher:
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
-            return mut["cache"], toks, _moe_stats(mut.get("intermediates"))
+            return (mut["cache"], toks, _moe_stats(mut.get("intermediates")),
+                    None)
 
         fn = jax.jit(prefill)
         self._prefill_cache[("reuse", P, n_pad)] = fn
+        return fn
+
+    def _load_prefix_fn(self, n_pad: int):
+        """Compiled per PADDED chain length: a fresh one-lane slab with
+        a hit's blocks and layer-state snapshot in it, its index at the
+        prefix's end (``PagedKVCache.load_prefix_into`` alone): what a
+        state-space configuration's reuse admission runs before the
+        last-chunk program (``_dispatch_reuse``)."""
+        cached = self._prefill_cache.get(("load", n_pad))
+        if cached is not None:
+            return cached
+        kv = self._kv
+
+        def load(pool, block_ids, prefix_len, snap_id):
+            return kv.load_prefix_into(_zeros_of(self._cache_shapes(1)), pool,
+                                       block_ids, n_pad, prefix_len, snap_id)
+
+        fn = self._prefill_cache[("load", n_pad)] = jax.jit(load)
         return fn
 
     def _finish_prefill(self, slots: list[int], reqs: list[_Request],
@@ -1974,7 +2194,8 @@ class ContinuousBatcher:
             if s.remaining == 0 or tok == self._eos:
                 self._finish(slot)
 
-    def _snap_prompt(self, slot: int, req: "_Request") -> None:
+    def _snap_prompt(self, slot: int, req: "_Request", lane: int = 0,
+                     snap=None, base: int = 0) -> None:
         """The window layers' state at the end of the PROMPT, while the
         slot's rings still hold it: enqueued right behind the insert,
         before the next tick's step advances them.  It needs nothing the
@@ -1983,16 +2204,27 @@ class ContinuousBatcher:
         down.  Without it only a continuation of prompt + answer could
         start from the pool; with it a prompt that comes again does.
         One small dispatch an admission; the commit gives the snapshot
-        its node."""
-        if self._kv is None or not self._ring_layers:
+        its node.
+
+        A state-space layer's state at that edge cannot come out of the
+        slot, which is at the prompt's END: the prefill's last program
+        computed it on its way (``snap``, lane ``lane``; the program
+        began at position ``base``).  An edge before ``base`` was
+        passed a program ago and is not snapshotted (counted)."""
+        if self._kv is None or not (self._ring_layers or self._state_layers):
             return
-        end = (len(req.ids) - 1) // self._kv.block * self._kv.block
+        end = self._snap_end(req)
         if end <= req.skipped:     # nothing new: the hit's own snapshot
             return
-        sid = self._kv.snap_alloc()
-        if sid:
+        sid = 0 if end < base else self._kv.snap_alloc()
+        if not sid:
+            self._state_snap_skips += bool(self._state_layers)
+            return
+        if self._ring_layers:
             self._kv.store_blocks(self._cache, slot, 0, [], (sid, end))
-            req.snap = (sid, end)
+        if self._state_layers:
+            self._kv.store_state(snap, lane, sid)
+        req.snap = (sid, end)
 
     def _live_mask(self, active: list[int]):
         """The decode step's ``live`` argument: [slots] bool."""
@@ -2023,11 +2255,14 @@ class ContinuousBatcher:
                 self._moe_prefill_experts_touched += int(touched)
                 self._moe_prefill_max_load_sum += load
 
-    def _finish_decode(self, toks: np.ndarray, live: list) -> None:
+    def _finish_decode(self, toks: np.ndarray, live: list,
+                       ssm_run: float = 0.0) -> None:
         """Consume one decode chunk [slots, T] for the (slot, request)
         pairs that were ``live`` in it.  A slot that no longer holds
         its request ended at an EOS while this program was already
-        enqueued: its token steps are discarded."""
+        enqueued: its token steps are discarded.  ``ssm_run``: the slot
+        states the program's state-space layers read and wrote, as the
+        program counted them."""
         T, cap = self._T, self._dcfg.max_len
         mine = [(i, self._slots[i]) for i, req in live
                 if self._slots[i].request is req]
@@ -2046,6 +2281,9 @@ class ContinuousBatcher:
                 W = self._dcfg.attn_window
                 self._kv_window_read += sum(map(self._ring_fetch, held))
                 self._kv_window_need += sum(min(n, W) for n in held)
+            if self._state_layers:
+                self._ssm_steps += len(live) * T * len(self._state_layers)
+                self._ssm_steps_run += ssm_run
         for i, s in mine:         # live, so it had tokens left to read
             for t in range(T):
                 tok = int(toks[i, t])
@@ -2126,7 +2364,12 @@ class ContinuousBatcher:
         self._kv.snap_attach(
             chain[depth - 1] if 0 < depth <= len(chain) else None, sid)
         snap = (0, 0)
-        if self._ring_layers and tail is not None:
+        self._state_reprefill += req.cut
+        # a recurrence has moved past the tail's end and kept nothing
+        # of it: with a state-space layer an answer's end is never
+        # snapshotted, and a session's next turn starts from the edge
+        # its last prompt left (kv_state_reprefill_tokens)
+        if self._ring_layers and not self._state_layers and tail is not None:
             # the window layers' last window before the tail's end, as
             # the slot's rings still hold it: the slot may have run
             # self._overrun token steps past the request's end, each

@@ -70,6 +70,18 @@ Design (PagedAttention re-shaped for the engine's attention layout):
   gather puts the snapshot back at ring slots ``position % ring`` and
   the global blocks at the front of the slabs, in the same fused jit.
   One trie, one LRU clock, one ``store_blocks`` dispatch for both.
+- **layer state** - the snapshot class is not the window layers' alone.
+  A state-space layer (``transformer.Mamba2Mixer``) keeps neither
+  blocks nor a ring: a slot's state there is a fixed-size recurrence
+  (``conv_state``, ``ssm_state``), and what a prefix hit needs of it is
+  THE state after the prefix's last token.  A snapshot entry holds that
+  for every state layer (and the last window for every window layer of
+  the same stack), owned by the chain node it ends at, out of the same
+  ``n_snaps`` entries under the same LRU.  A ring still holds a little
+  history and can be snapshotted when a commit ends; a recurrence can
+  be saved only at a position the program is AT, so a state layer's
+  snapshot comes out of the prefill itself (``store_state``: the state
+  the scan computed at the block edge), never out of a slot.
 
 Thread model: single-writer — every mutating call runs on the engine
 thread (admission, finish-commit, import-task); ``export_chain`` runs
@@ -95,19 +107,41 @@ def _pool_shapes(n_blocks: int, hk: int, d: int, block: int) -> dict:
     return {"k": (n_blocks, hk, d, block), "v": (n_blocks, hk, block, d)}
 
 
+def _leaf_key(path) -> str:
+    """A cache leaf's place under its layer, as the pool names it
+    (``ssm/ssm_state``): the dictionary keys of its path."""
+    return "/".join(k.key for k in path if hasattr(k, "key"))
+
+
+def state_leaves(node) -> dict:
+    """A state layer's cache leaves a snapshot holds (all but the
+    index), by their place under the layer (``_leaf_key``)."""
+    import jax
+    return {_leaf_key(path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(node)[0]
+            if not _leaf_key(path).endswith("cache_index")}
+
+
 def pool_device_bytes(cache_shapes, block: int, n_blocks: int,
                       tp: int = 1, ring_layers=(), window: int = 0,
-                      n_snaps: int = 0) -> int:
+                      n_snaps: int = 0, state_layers=()) -> int:
     """Per-device HBM bytes of the pool :class:`PagedKVCache` would
     allocate for this cache skeleton (KV heads split over ``tp`` where
     they divide, as the constructor shards them): ``n_blocks`` blocks
     of ``block`` tokens for every global layer, ``n_snaps`` snapshots
-    of ``window`` tokens for every layer in ``ring_layers``.  Plain
+    of ``window`` tokens for every layer in ``ring_layers`` and of one
+    lane's state for every layer in ``state_layers``.  Plain
     element counts: libtpu lays the 16-token minor dim of a K block out
     major-most rather than padding it to 128 lanes (measured on v5e:
     device bytes / nominal = 1.00 for both buffers, f32 and bf16)."""
     total = 0
     for name, node in cache_shapes.items():
+        if name in state_layers:
+            total += n_snaps * sum(
+                int(np.prod(leaf.shape[1:], dtype=np.int64))
+                * np.dtype(leaf.dtype).itemsize
+                for leaf in state_leaves(node).values())
+            continue
         _, hk, d, _ = node["cached_key"].shape
         hk = hk // tp if tp > 1 and hk % tp == 0 else hk
         item = np.dtype(node["cached_key"].dtype).itemsize
@@ -132,7 +166,7 @@ class _Node:
         self.children: dict[tuple, _Node] = {}
         self.pins = 0
         self.last_use = 0
-        self.snap = 0       # window snapshot ending at this node (0: none)
+        self.snap = 0       # layer-state snapshot ending here (0: none)
 
 
 class PagedKVCache:
@@ -152,7 +186,7 @@ class PagedKVCache:
 
     def __init__(self, cache_shapes, block: int, n_blocks: int,
                  max_sessions: int, mesh=None, ring_layers=(),
-                 window: int = 0, n_snaps: int = 0):
+                 window: int = 0, n_snaps: int = 0, state_layers=()):
         import jax
         import jax.numpy as jnp
 
@@ -166,19 +200,32 @@ class PagedKVCache:
         # the window class (module docstring): layers whose slot state
         # is a ring, the window a snapshot holds, and how many there are
         self._ring = frozenset(ring_layers)
+        # the state class: layers whose slot state is a recurrence
+        self._state = frozenset(state_layers)
+        self._snapped = bool(self._ring or self._state)
         self.window = int(window) if self._ring else 0
-        self.n_snaps = int(n_snaps) if self._ring else 0
-        if self._ring and (self.window < 1 or self.n_snaps < 2):
+        self.n_snaps = int(n_snaps) if self._snapped else 0
+        if self._snapped and ((self._ring and self.window < 1)
+                              or self.n_snaps < 2):
             raise ValueError(
-                f"window layers {sorted(self._ring)} need a window and at "
-                f"least 2 snapshots, got {window} and {n_snaps}")
-        if self._ring and mesh is not None:
+                f"window layers {sorted(self._ring)} and state layers "
+                f"{sorted(self._state)} need a window and at least 2 "
+                f"snapshots, got {window} and {n_snaps}")
+        if self._snapped and mesh is not None:
             raise ValueError(
-                "a paged KV cache with window layers is not sharded over "
-                "a mesh: the snapshot pool has no sharded gather yet")
+                "a paged KV cache with window or state layers is not "
+                "sharded over a mesh: the snapshot pool has no sharded "
+                "gather yet")
         self._layout: dict[str, tuple] = {}
+        # a state layer's snapshot leaves: {leaf: (shape of a lane, dtype)}
+        self._state_layout: dict[str, dict] = {}
         for name in self._layers:
             node = cache_shapes[name]
+            if name in self._state:
+                self._state_layout[name] = {
+                    k: (tuple(v.shape[1:]), v.dtype)
+                    for k, v in sorted(state_leaves(node).items())}
+                continue
             if set(node) != {"cached_key", "cached_value", "cache_index"}:
                 raise ValueError(
                     f"paged KV cache requires plain per-layer "
@@ -206,6 +253,7 @@ class PagedKVCache:
         self._layer_sharded = {
             name: self._tp > 1 and hk % self._tp == 0
             for name, (hk, d, _) in self._layout.items()}
+        self._layer_sharded.update(dict.fromkeys(self._state, False))
         # block 0 is a reserved scratch block (never allocated) so a
         # zero-filled block-id vector can never alias live state
         self.pool = {
@@ -215,6 +263,10 @@ class PagedKVCache:
                 else _pool_shapes(n_blocks, hk, d, block)).items()}
             for name, (hk, d, dtype) in self._layout.items()
         }
+        for name, leaves in self._state_layout.items():
+            self.pool[name] = {
+                k: jnp.zeros((self.n_snaps,) + shape, dtype)
+                for k, (shape, dtype) in leaves.items()}
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -234,9 +286,11 @@ class PagedKVCache:
         # pushed on every candidate transition (created childless,
         # unpinned, child evicted), validated on pop — a full pool's
         # steady-state commit must not rescan every node per block
+        self.last_cut = 0       # tokens the last match() gave up (above)
         self._evict_heap: list[tuple[int, int, _Node]] = []
         self._heap_seq = 0
         self._sessions: "OrderedDict[str, _Node]" = OrderedDict()
+        self._session_snaps: dict[str, _Node] = {}
         self._max_sessions = max(1, int(max_sessions))
         self._clock = 0
         self._jit_cache: dict[tuple, object] = {}
@@ -270,20 +324,24 @@ class PagedKVCache:
                 break
             chain.append(child)
             node = child
+        # what the layer state cut off a hit the blocks alone would give
+        self.last_cut = len(chain) * self.block
         chain = self.reusable(chain)
+        self.last_cut -= len(chain) * self.block
         self._clock += 1
         for nd in chain:
             nd.last_use = self._clock
-        if chain and self._ring:
+        if chain and self._snapped:
             self._snap_lru.move_to_end(chain[-1])
         return chain
 
     def reusable(self, chain: list[_Node]) -> list[_Node]:
         """``chain`` as deep as a prefix hit, here or on the replica a
-        session migrates to, can start from: with window layers, down
-        to its deepest node that owns a snapshot (the window layers'
-        last window ends there)."""
-        if self._ring:
+        session migrates to, can start from: with window or state
+        layers, down to its deepest node that owns a snapshot (the
+        window layers' last window ends there, and the state layers'
+        state is the one after its last token)."""
+        if self._snapped:
             while chain and not chain[-1].snap:
                 chain = chain[:-1]
         return chain
@@ -297,9 +355,9 @@ class PagedKVCache:
     def snap_alloc(self) -> int:
         """A free snapshot entry for the caller to write
         (``store_blocks``) and then ``snap_attach`` or ``snap_release``:
-        0 when there is no window class or every entry belongs to a
+        0 when no layer keeps snapshots or every entry belongs to a
         pinned chain (counted)."""
-        if not self._ring:
+        if not self._snapped:
             return 0
         if self._snap_free:
             return self._snap_free.pop()
@@ -434,19 +492,25 @@ class PagedKVCache:
 
     # -- session pins --------------------------------------------------------
     def pin_session(self, session: str, node: _Node) -> None:
-        old = self._sessions.pop(session, None)
-        if old is not None:
-            self._unpin(old)
+        self.unpin_session(session)
         node.pins += 1
         self._sessions[session] = node
+        # with state layers the chain's snapshot sits where its last
+        # PROMPT ended, above the tail: the session holds that too
+        holder = node
+        while self._state and holder is not None and not holder.snap:
+            holder = holder.parent
+        if holder is not None and holder is not node and holder.snap:
+            holder.pins += 1
+            self._session_snaps[session] = holder
         while len(self._sessions) > self._max_sessions:
-            _, stale = self._sessions.popitem(last=False)
-            self._unpin(stale)
+            self.unpin_session(next(iter(self._sessions)))
 
     def unpin_session(self, session: str) -> None:
-        node = self._sessions.pop(session, None)
-        if node is not None:
-            self._unpin(node)
+        for held in (self._sessions, self._session_snaps):
+            node = held.pop(session, None)
+            if node is not None:
+                self._unpin(node)
 
     def sessions(self) -> list[str]:
         """Pinned session ids — engine-thread / post-stop callers only
@@ -540,12 +604,22 @@ class PagedKVCache:
         beyond it and is overwritten or masked before any query can
         attend it).  Window layers take snapshot ``snap_id`` (the
         chain tail's) into the ring slots of the ``window`` positions
-        before ``prefix_len``: exactly the last window of the prefix."""
+        before ``prefix_len``: exactly the last window of the prefix.
+        State layers take the same entry whole: the recurrence as it
+        stood after token ``prefix_len - 1``."""
         jnp = self._jnp
         bs = self.block
         out = {}
         for name in self._layers:
             node = cache[name]
+            if name in self._state:
+                out[name] = self._jax.tree_util.tree_map_with_path(
+                    lambda path, v, name=name: (
+                        jnp.full_like(v, prefix_len)
+                        if path[-1].key == "cache_index" else
+                        pool[name][_leaf_key(path)][snap_id][None].astype(
+                            v.dtype)), node)
+                continue
             if name in self._ring:
                 ring = node["cached_key"].shape[-1]
                 slots = self._ring_slots(prefix_len, ring)
@@ -595,6 +669,11 @@ class PagedKVCache:
         def scatter(pool, cache, slot, start, block_ids, snap_id, snap_end):
             out = {}
             for name in layers:
+                if name in self._state:
+                    # a recurrence is saved where the prefill computed
+                    # it (store_state), never out of a slot
+                    out[name] = pool[name]
+                    continue
                 if name in ring_layers:
                     # the window that ends at snap_end, out of the
                     # slot's ring, into snapshot snap_id (0: scratch)
@@ -657,6 +736,30 @@ class PagedKVCache:
             jnp.asarray(block_ids, jnp.int32),
             self.snap_arg(snap[0]), self.snap_arg(snap[1]))
 
+    def store_state(self, snap, lane: int, sid: int) -> None:
+        """The state layers' part of snapshot ``sid``: lane ``lane`` of
+        ``snap`` ``{layer: {leaf: [K, ...]}}`` (leaves named as the pool
+        names them, ``state_leaves``), the states a prefill
+        program computed at its lanes' block edges
+        (``transformer.Mamba2Mixer``'s ``snap`` collection).  One
+        dispatch; the pool is donated."""
+        fn = self._jit_cache.get("store_state")
+        if fn is None:
+            def put(pool, snap, lane, sid):
+                out = dict(pool)
+                for name in self._state:
+                    out[name] = {
+                        k: pool[name][k].at[sid].set(
+                            self._jnp.take(snap[name][k], lane, axis=0
+                                           ).astype(pool[name][k].dtype))
+                        for k in pool[name]}
+                return out
+
+            fn = self._jit_cache["store_state"] = self._jax.jit(
+                put, donate_argnums=(0,))
+        self.pool = fn(self.pool, snap, self._jnp.asarray(lane, self._jnp.int32),
+                       self.snap_arg(sid))
+
     def snap_arg(self, value: int):
         """A snapshot id or end position as the pool programs take it:
         a device scalar, made once for the 0 that every call of a stack
@@ -676,8 +779,9 @@ class PagedKVCache:
 
         def gather(pool, block_ids, snap_ids):
             return {name: {ax: pool[name][ax][
-                snap_ids if name in ring_layers else block_ids]
-                for ax in ("k", "v")} for name in layers}
+                snap_ids if name in ring_layers or name in self._state
+                else block_ids]
+                for ax in pool[name]} for name in layers}
 
         from jax.sharding import PartitionSpec as P
 
@@ -694,23 +798,45 @@ class PagedKVCache:
         chain IS the token sequence).  With window layers the chain
         must end at a snapshot-bearing node (``reusable``)."""
         jnp = self._jnp
-        if self._ring and not (chain and chain[-1].snap):
-            raise ValueError("chain tail owns no window snapshot")
+        if self._snapped and not (chain and chain[-1].snap):
+            raise ValueError("chain tail owns no layer-state snapshot")
         ids = jnp.asarray([nd.block_id for nd in chain], jnp.int32)
-        snaps = jnp.asarray([chain[-1].snap if self._ring else 0],
+        snaps = jnp.asarray([chain[-1].snap if self._snapped else 0],
                             jnp.int32)
         got = self._gather_fn(len(chain))(self.pool, ids, snaps)
         parts: list[bytes] = []
         for name in self._layers:
-            parts.append(np.asarray(got[name]["k"]).tobytes())
-            parts.append(np.asarray(got[name]["v"]).tobytes())
+            for ax in self._axes(name):
+                parts.append(np.asarray(got[name][ax]).tobytes())
         blob = b"".join(parts)
         meta = {"block": self.block, "n": len(chain),
                 "layers": list(self._layers),
                 "window": self.window, "ring_layers": sorted(self._ring),
                 "layout": {name: [hk, d, str(np.dtype(dtype))]
                            for name, (hk, d, dtype) in self._layout.items()}}
+        if self._state:
+            meta["state_layers"] = sorted(self._state)
+            meta["layout"].update(
+                {name: {k: [list(shape), str(np.dtype(dtype))]
+                        for k, (shape, dtype) in leaves.items()}
+                 for name, leaves in self._state_layout.items()})
         return meta, blob
+
+    def _axes(self, name: str) -> tuple:
+        """A layer's pool buffers in the blob's order."""
+        return (tuple(self._state_layout[name]) if name in self._state
+                else ("k", "v"))
+
+    def _wire_shapes(self, name: str, n: int) -> dict:
+        """``{buffer: (shape, dtype)}`` of what a chain of ``n`` blocks
+        carries for layer ``name``: n blocks of a global layer, one
+        snapshot of a window or a state layer."""
+        if name in self._state:
+            return {k: ((1,) + shape, dtype)
+                    for k, (shape, dtype) in self._state_layout[name].items()}
+        hk, d, dtype = self._layout[name]
+        m, t = (1, self.window) if name in self._ring else (n, self.block)
+        return {"k": ((m, hk, d, t), dtype), "v": ((m, hk, t, d), dtype)}
 
     def import_chain(self, session: str, tokens: list[int], meta: dict,
                      blob: bytes) -> int:
@@ -731,9 +857,16 @@ class PagedKVCache:
         if (int(meta.get("window", 0)) != self.window
                 or list(meta.get("ring_layers", [])) != sorted(self._ring)):
             raise ValueError("kv import window layers mismatch")
+        if list(meta.get("state_layers", [])) != sorted(self._state):
+            raise ValueError("kv import state layers mismatch")
         for name, (hk, d, dtype) in self._layout.items():
             if list(meta["layout"][name]) != [hk, d,
                                               str(np.dtype(dtype))]:
+                raise ValueError(f"kv import layout mismatch at {name}")
+        for name, leaves in self._state_layout.items():
+            if meta["layout"][name] != {
+                    k: [list(shape), str(np.dtype(dtype))]
+                    for k, (shape, dtype) in leaves.items()}:
                 raise ValueError(f"kv import layout mismatch at {name}")
         if len(tokens) < n * self.block:
             raise ValueError(
@@ -743,20 +876,16 @@ class PagedKVCache:
         arrays: dict[str, dict[str, np.ndarray]] = {}
         off = 0
         for name in self._layers:
-            hk, d, dtype = self._layout[name]
-            item = np.dtype(dtype).itemsize
-            # a window layer carries one snapshot, a global one n blocks
-            m, t = ((1, self.window) if name in self._ring
-                    else (n, self.block))
-            k_bytes = m * hk * d * t * item
-            arrays[name] = {
-                "k": np.frombuffer(blob, dtype, count=m * hk * d * t,
-                                   offset=off).reshape(m, hk, d, t),
-                "v": np.frombuffer(blob, dtype, count=m * hk * t * d,
-                                   offset=off + k_bytes
-                                   ).reshape(m, hk, t, d),
-            }
-            off += 2 * k_bytes
+            arrays[name] = {}
+            for ax, (shape, dtype) in self._wire_shapes(name, n).items():
+                count = int(np.prod(shape, dtype=np.int64))
+                if off + count * np.dtype(dtype).itemsize > len(blob):
+                    raise ValueError(
+                        f"kv import blob is {len(blob)} bytes, too short "
+                        f"for the layout")
+                arrays[name][ax] = np.frombuffer(
+                    blob, dtype, count=count, offset=off).reshape(shape)
+                off += count * np.dtype(dtype).itemsize
         if off != len(blob):
             raise ValueError(
                 f"kv import blob is {len(blob)} bytes, layout needs {off}")
@@ -782,19 +911,16 @@ class PagedKVCache:
             idx = [i for i, _ in fresh]
             ids = jnp.asarray([b for _, b in fresh], jnp.int32)
             snaps = jnp.asarray([snap], jnp.int32)
+            snapped = self._ring | self._state
             upload = {
-                name: ({"k": jnp.asarray(arrays[name]["k"]),
-                        "v": jnp.asarray(arrays[name]["v"])}
-                       if name in self._ring else
-                       {"k": jnp.asarray(arrays[name]["k"][idx]),
-                        "v": jnp.asarray(arrays[name]["v"][idx])})
+                name: {ax: jnp.asarray(a if name in snapped else a[idx])
+                       for ax, a in arrays[name].items()}
                 for name in self._layers}
-            ring_layers = self._ring
 
             def put(pool, ids, snaps, upload):
                 return {name: {ax: pool[name][ax].at[
-                    snaps if name in ring_layers else ids].set(
-                        upload[name][ax]) for ax in ("k", "v")}
+                    snaps if name in snapped else ids].set(
+                        upload[name][ax]) for ax in pool[name]}
                     for name in self._layers}
 
             key = ("import", len(fresh))

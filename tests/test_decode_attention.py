@@ -395,6 +395,7 @@ def test_step_program_for_v5e_holds_no_whole_slab_temporary(
     # the part of an engine the step reads, and nothing it would allocate
     engine = types.SimpleNamespace(
         _model=model, _T=T, _moe_dropless=False, _moe_acc_shape=None,
+        _state_layers=(),
         _positions=ContinuousBatcher._positions,
         _sample=lambda logits, key: sample_logits(logits, key,
                                                   temperature=0.0))
@@ -429,6 +430,35 @@ def test_step_program_for_v5e_holds_no_whole_slab_temporary(
     assert not moved, moved
     text = compiled.as_text()
     assert "decode_append" in text and "decode_attend" in text
+
+
+
+def test_ssm_step_kernel_compiles_for_v5e_at_published_widths(one_chip):
+    """``ops/ssm.ssm_step`` at granite-4.0-h-small's widths (32 slots,
+    128 heads of 64, state 128), compiled ahead of time for one v5e
+    chip from abstract shapes: the Mosaic compiler takes the kernel,
+    the states are aliased in and out (no second copy: 134 MB each),
+    and the step's own rows go in head-minor, not padded to a lane
+    tile a head (a [B, H, P, 1] operand alone is 134 MB of padding)."""
+    from edl_tpu.ops import ssm
+
+    B, H, P, N = 32, 128, 64, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _no_compile_cache():
+        compiled = jax.jit(
+            lambda s, x, dt, A, b, c, live: ssm.ssm_step(
+                s, x, dt, A, b, c, live, interpret=False),
+            donate_argnums=(0,)).lower(
+                sds((B, H, P, N)), sds((B, H, P)), sds((B, H)), sds((H,)),
+                sds((B, 1, N)), sds((B, 1, N)), sds((B,), jnp.bool_)
+        ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == B * H * P * N * 4
+    assert mem.temp_size_in_bytes < 8e6
+    assert "ssm_step" in compiled.as_text()
 
 
 @pytest.fixture(scope="module")

@@ -626,13 +626,17 @@ def test_slot_freed_at_tick_n_is_admitted_at_n_plus_1(small):
     finally:
         eng.stop()
     assert all(len(o) == 6 for o in outs)
-    admits = [t for kind, t, _ in events if kind == "admit"]
+    admits = [(t, n) for kind, t, n in events if kind == "admit"]
     frees = {t for kind, t, waiting in events
              if kind == "finish" and waiting}
     assert len(admits) >= 4
+    # the first fill is one admission of two requests, or two of one
+    # where the engine's first tick beat the second submit() (a loaded
+    # host: seen once in 970 tests on 6 workers, PR 32)
+    filled = 1 if admits[0][1] == 2 else 2
     # every admission after the first fill follows a read that freed a
     # slot, by exactly one tick; and every such read is followed
-    assert {t - 1 for t in admits[1:]} == frees, events
+    assert {t - 1 for t, _ in admits[filled:]} == frees, events
 
 
 def test_tasks_see_flushed_state_with_two_ticks_in_flight(small):

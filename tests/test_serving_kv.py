@@ -455,3 +455,168 @@ def test_snapshots_are_an_lru_of_their_own(hybrid):
     finally:
         eng.stop()
         cold.stop()
+
+
+# -- state-space layers: the snapshot class as a class of LAYER STATE (PR 32) --
+
+@pytest.fixture(scope="module")
+def recurrent():
+    """Two state-space layers around one attention layer."""
+    cfg = TransformerConfig(vocab_size=97, num_layers=3, embed_dim=32,
+                            num_heads=4, mlp_dim=64, max_len=96,
+                            remat=False, dtype=jnp.float32,
+                            layer_attn=("ssm", "global", "ssm"),
+                            ssm_heads=4, ssm_head_dim=16, ssm_state=8,
+                            ssm_chunk=8)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return cfg, params
+
+
+_STATE_BYTES = 4 * 16 * 8 * 4 + 3 * (64 + 16) * 4     # S and the conv, f32
+
+
+def test_the_state_class_is_allocated_by_its_bytes(recurrent, monkeypatch):
+    """The attention layer pages by blocks; a state-space layer's pool
+    holds whole slot states, ``n_snaps`` of them.  Without a memory
+    limit one a slot (+ scratch); with one, ``slots // 2`` at least,
+    and what the prefill ladder leaves, never more than one a slot."""
+    from edl_tpu.serving.kv_cache import pool_device_bytes
+    cfg, params = recurrent
+    eng = _engine(cfg, params, slots=4, kv_max_sessions=2)
+    try:
+        pool = {n: {k: tuple(v.shape) for k, v in b.items()}
+                for n, b in eng._kv.pool.items()}
+        n = 2 + 2 * 4 + 1
+        assert pool == {
+            "layer_0": {"ssm/conv_state": (n, 3, 80),
+                        "ssm/ssm_state": (n, 4, 16, 8)},
+            "layer_1": {"k": (64, 4, 8, 4), "v": (64, 4, 4, 8)},
+            "layer_2": {"ssm/conv_state": (n, 3, 80),
+                        "ssm/ssm_state": (n, 4, 16, 8)}}
+        stats = eng.stats()
+        assert stats["kv_slot_bytes_state"] == 2 * _STATE_BYTES
+        assert stats["kv_slot_bytes_window"] == 0
+        one_lane = eng._cache_shapes(1)
+        blocks = 2 * 64 * 4 * 8 * 4 * 4
+        assert pool_device_bytes(
+            one_lane, 4, 64, n_snaps=n,
+            state_layers=eng._state_layers) == blocks + n * 2 * _STATE_BYTES
+
+        class _Chip:
+            device_kind = "toy chip"
+
+            def __init__(self, limit):
+                self.limit = limit
+
+            def memory_stats(self):
+                return {"bytes_limit": self.limit, "bytes_in_use": 0}
+
+        monkeypatch.setattr(jax, "devices", lambda: [_Chip(1 << 40)])
+        assert eng._require_fit(4, 4, 64, n) == 4 + 1     # one a slot
+        floor = 4 // 2 + 1
+        need = (4 * sum(s.size * s.dtype.itemsize
+                        for s in jax.tree.leaves(one_lane))
+                + blocks + floor * 2 * _STATE_BYTES)
+        prefill = 1 << 30       # more than any toy prefill needs
+        monkeypatch.setattr(jax, "devices", lambda: [_Chip(
+            need + prefill + 2 * _STATE_BYTES + 5)])
+        eng.PREFILL_KS = (1,)
+        got = eng._require_fit(4, 4, 64, n)
+        assert floor <= got <= 4 + 1
+    finally:
+        eng.stop()
+
+
+def test_a_recurrent_second_turn_resumes_from_its_prompts_snapshot(recurrent):
+    """A recurrence is snapshotted where the prefill was AT: the first
+    prompt's last block edge.  The next turn (prompt + answer + more)
+    matches blocks deeper than that, is cut back to the snapshot
+    (``reusable``), prefills the rest again, and answers as an engine
+    that never had a pool."""
+    cfg, params = recurrent
+    rng = np.random.default_rng(3)
+    p1 = rng.integers(1, 97, (21,)).astype(np.int32)
+    eng, cold = _engine(cfg, params), _engine(cfg, params, kv_block=0)
+    try:
+        out1 = eng.submit(p1, 14, session="s").result(120)
+        np.testing.assert_array_equal(out1, cold.generate(p1, 14, 120))
+        kv = eng._kv
+        chain = kv.chain_of("s")
+        assert len(chain) == (21 + 13) // 4 and [bool(nd.snap) for nd in
+                                                 chain].index(True) == 4
+        assert len(kv.reusable(chain)) == 5             # 20 tokens
+        p2 = np.concatenate([p1, out1, np.asarray([4, 1, 9], np.int32)])
+        out2 = eng.submit(p2, 9, session="s").result(120)
+        np.testing.assert_array_equal(out2, cold.generate(p2, 9, 120))
+        stats = eng.stats()
+    finally:
+        eng.stop()
+        cold.stop()
+    assert stats["kv_prefix_hits"] == 1, stats
+    assert stats["kv_prefill_tokens_skipped"] == 20
+    assert stats["kv_state_reprefill_tokens"] == 32 - 20
+    assert stats["kv_state_snapshots"] == 2
+
+
+def test_export_import_of_a_recurrent_session(recurrent):
+    """One blob: the chain's blocks for the attention layer and the
+    tail's one state snapshot for the state-space layers, down to the
+    deepest node that owns one."""
+    cfg, params = recurrent
+    rng = np.random.default_rng(5)
+    p1 = rng.integers(1, 97, (23,)).astype(np.int32)
+    eng_a = _engine(cfg, params)
+    try:
+        out1 = eng_a.submit(p1, 10, session="s").result(120)
+        conv = np.concatenate([p1, out1])
+        assert eng_a.drain(timeout=30)
+        ((name, tokens, meta, blob),) = eng_a.export_sessions()
+    finally:
+        eng_a.stop()
+    assert name == "s" and tokens == list(map(int, p1[:20]))
+    assert meta["state_layers"] == ["layer_0", "layer_2"]
+    per_token = 4 * 8 * 4 * 2                      # heads x dim x f32, K + V
+    assert len(blob) == per_token * 20 + 2 * _STATE_BYTES
+    eng_b, cold = _engine(cfg, params), _engine(cfg, params, kv_block=0)
+    try:
+        assert eng_b.import_session("s", tokens, meta, blob) == 5
+        p2 = np.concatenate([conv, np.asarray([4, 1], np.int32)])
+        out2 = eng_b.generate(p2, 6, timeout=120)
+        np.testing.assert_array_equal(out2, cold.generate(p2, 6, 120))
+        stats = eng_b.stats()
+        assert stats["kv_prefix_hits"] == 1, stats
+        assert stats["kv_prefill_tokens_skipped"] == 20, stats
+        with pytest.raises(ValueError, match="state layers mismatch"):
+            eng_b.import_session("t", tokens, dict(meta, state_layers=[]),
+                                 blob)
+    finally:
+        eng_b.stop()
+        cold.stop()
+
+
+def test_state_snapshots_share_the_snapshot_lru(recurrent):
+    """More prompts than entries: the oldest unpinned holders give
+    theirs up, a pinned session keeps its own, answers stay right."""
+    cfg, params = recurrent
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 97, (n,)).astype(np.int32)
+               for n in (13, 17, 11, 19, 15, 12)]
+    eng = _engine(cfg, params, slots=1, kv_max_sessions=1)
+    cold = _engine(cfg, params, kv_block=0)
+    try:
+        eng.submit(prompts[0], 6, session="keep").result(120)
+        for p in prompts[1:]:
+            np.testing.assert_array_equal(eng.generate(p, 6, 120),
+                                          cold.generate(p, 6, 120))
+        kv = eng._kv
+        assert kv.n_snaps == 1 + 2 * 1 + 1 and kv.snaps_used() <= 3
+        # the pinned session's chain still has its prompt's snapshot
+        assert any(nd.snap for nd in kv.chain_of("keep"))
+        p2 = np.concatenate([prompts[0], [5, 6]]).astype(np.int32)
+        np.testing.assert_array_equal(eng.generate(p2, 5, 120),
+                                      cold.generate(p2, 5, 120))
+        assert eng.stats()["kv_prefix_hits"] == 1
+    finally:
+        eng.stop()
+        cold.stop()
