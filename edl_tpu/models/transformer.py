@@ -842,7 +842,7 @@ class Block(nn.Module):
                             select_bias=cfg.moe_select_bias,
                             routed_scale=cfg.moe_routed_scale,
                             shared_dim=cfg.moe_shared_dim,
-                            held=cfg.moe_held,
+                            held=cfg.moe_held, mesh=cfg.mesh,
                             name="moe")(y, token_mask)
             return _pin(cfg, _residual(cfg, x, y), "batch", "seq", None), aux
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,

@@ -13,17 +13,35 @@ Which path runs when:
 - **Dropless** (``capacity_factor <= 0``): one path for training,
   prefill, chunked prefill and decode.  The ``B*S*K`` (token, expert)
   assignments are sorted by expert (stable), the token rows gathered in
-  that order, and each projection is ONE grouped matmul over the sorted
-  rows (``jax.lax.ragged_dot``, group sizes from a per-expert count:
-  on TPU a Mosaic grouped-matmul kernel that visits only the tiles of
-  experts that received rows, so a decode step reads the experts its
-  batch touched and no others).  The rows are then unsorted, weighted
-  by their gates and summed per token.  Nothing is ever dropped;
-  ``token_mask``-ed (pad) positions route nowhere: zero weight, in no
-  group, in no count.  No ``[B, S, E, C]`` tensor and no per-token
-  weight gather exists on this path.  Serving an expert model through
-  it (``serving/engine.py``) no longer drops: ``moe_prefill_drops``
-  reads 0.
+  that order, the experts run over the sorted rows, and the rows are
+  then unsorted, weighted by their gates and summed per token.  Nothing
+  is ever dropped; ``token_mask``-ed (pad) positions route nowhere:
+  zero weight, in no group, in no count.  No ``[B, S, E, C]`` tensor
+  and no per-token weight gather exists on this path.  Serving an
+  expert model through it (``serving/engine.py``) no longer drops:
+  ``moe_prefill_drops`` reads 0.  What runs the experts over the sorted
+  rows:
+
+  - :func:`ragged_experts`, everywhere but the case below: each
+    projection is ONE grouped matmul (``jax.lax.ragged_dot``, group
+    sizes from a per-expert count: on TPU a Mosaic grouped-matmul
+    kernel XLA tiles for us, work listed per (expert, row tile)).  It
+    is also the kernel's parity reference.
+  - :func:`decode_gmm`, a **decode-shaped call**: one token a slot
+    (``S == 1`` with ``decode``), on a TPU, with no mesh, the sorted
+    rows small enough for VMEM: what :func:`applies` tests, from what
+    the call can observe and nothing else.  The three projections are
+    ONE Pallas kernel (``moe_decode_gmm``): a loop walks the touched
+    experts alone, each one's matrices stream from HBM once, in
+    contiguous chunks of a few MiB (:func:`gmm_chunks`: from the bytes,
+    whatever the widths), the next chunk arriving while this one is
+    multiplied, an untouched expert is never named, and the hidden rows
+    never leave VMEM.  Same mathematics and precision: operands in the
+    layer's dtype, float32 accumulation, the hidden rows rounded once.
+    The layer sows ``moe_fetched``, the expert weight sets the kernel
+    counted itself fetching, beside ``moe_stats``.  Off a TPU the kernel
+    runs in Pallas interpret mode (the tier-1 parity tests); nothing
+    selects it there.
 - **Capacity** (``capacity_factor > 0``, the default 1.25): the
   GShard-style path designed for the compiler rather than
   hand-scheduled all-to-alls - routing is expressed as dense
@@ -66,18 +84,42 @@ and ``moe_stats`` (dropless path, float32 ``[assignments, experts
 touched, max load over mean load]`` of this layer's call over real
 tokens, with ``held`` also ``pairs routed``: ``assignments`` are then
 the pairs that landed on held experts, and max load over mean load is
-over the held experts) - the serving engine sums both into
-``stats()``.
+over the held experts), and where the decode kernel ran
+``moe_fetched`` (float32 scalar: the expert weight sets it counted
+itself fetching) - the serving engine sums all three into ``stats()``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from edl_tpu.ops.attention import _on_tpu
+from edl_tpu.ops.decode_attention import _interpret, _sublanes
+
+# rows one pass of the decode kernel's matmuls may take: a group runs
+# in the smallest window that holds it (a matmul of few rows costs the
+# MXU a weight tile's load plus the rows), the last one looped for a
+# group that outgrows it; multiples of every dtype's sublane tile
+_WINDOWS = (16, 32, 128)
+# bytes of one weight chunk the kernel has in flight (two are in VMEM,
+# one computed on, one arriving): small enough that the first chunk's
+# fetch and the last one's matmuls, which nothing hides, cost little
+_CHUNK_BYTES = 4 << 20
+# bytes the sorted rows may take in VMEM (rows in, rows out, both double
+# buffered, and the float32 accumulator): a call with more rows than
+# that stays on ragged_dot
+_ROW_BYTES = 24 << 20
+# what the kernel asks of VMEM: those rows, two chunks a projection and
+# the float32 hidden rows
+_VMEM_LIMIT = 64 << 20
 
 
 def compute_routing(probs, top_k: int, capacity: int, valid=None,
@@ -166,20 +208,271 @@ def _activate(h, gate=None):
     return nn.silu(h) if gate is None else nn.silu(gate) * h
 
 
+def ragged_experts(rows, sizes, w_gate, w_in, w_out):
+    """The routed experts over rows sorted by expert, ``rows [R, M]``
+    with ``sizes [E]`` rows a group: one ``jax.lax.ragged_dot`` a
+    projection.  Every path's expert FFN but the decode step's on a
+    TPU, and :func:`decode_gmm`'s parity reference.  What it leaves in
+    rows past the last group is unspecified."""
+    h = _activate(
+        jax.lax.ragged_dot(rows, w_in, sizes),
+        None if w_gate is None else jax.lax.ragged_dot(rows, w_gate, sizes))
+    return jax.lax.ragged_dot(h, w_out, sizes)
+
+
+def applies(S: int, mesh, rows: int, M: int, dtype) -> bool:
+    """Whether a dropless call takes the decode kernel: one token a
+    slot on a TPU with no mesh, the sorted rows small enough to stay in
+    VMEM.  Training, prefill, the chunk lane, the speculative verify, a
+    mesh engine and every other backend keep ``ragged_dot``."""
+    return (S == 1 and mesh is None and _gmm_row_bytes(rows, M, dtype)
+            <= _ROW_BYTES and _on_tpu())
+
+
+def _gmm_rows(rows: int, dtype) -> int:
+    """Rows the kernel's row blocks hold: windows start at sublane
+    tiles, so the widest may reach its width past the tile the last row
+    lies in."""
+    tile = _sublanes(dtype)
+    return (rows - 1) // tile * tile + _WINDOWS[-1]
+
+
+def _gmm_row_bytes(rows: int, M: int, dtype) -> int:
+    return _gmm_rows(rows, dtype) * M * (4 * jnp.dtype(dtype).itemsize + 4)
+
+
+def _chunk_rows(n: int, row_bytes: int) -> int:
+    """Rows of a weight matrix one chunk holds: the largest lane-tile
+    multiple that divides ``n`` and fits ``_CHUNK_BYTES``, the smallest
+    where none fits, all ``n`` where no lane tile divides it."""
+    tiles = [t for t in range(n, 0, -128) if n % t == 0] if n % 128 == 0 \
+        else [n]
+    return next((t for t in tiles if t * row_bytes <= _CHUNK_BYTES),
+                tiles[-1])
+
+
+def gmm_chunks(M: int, H: int, gated: bool, dtype) -> tuple[int, int]:
+    """``(tm, th)``: the kernel streams an expert as ``M // tm`` chunks
+    of its input projections (rows ``[tm, H]`` of ``w_in`` and of
+    ``w_gate``, contiguous) and then ``H // th`` chunks of ``w_out``
+    (rows ``[th, M]``): whatever the widths, from the bytes alone."""
+    item = jnp.dtype(dtype).itemsize
+    return (_chunk_rows(M, (2 if gated else 1) * H * item),
+            _chunk_rows(H, M * item))
+
+
+def _gmm_plan(sizes):
+    """What the kernel walks: the touched experts in order (then the
+    others, never read), each expert's rows and their offset among the
+    sorted rows, and how many are touched."""
+    sizes = sizes.astype(jnp.int32)
+    ids = jnp.argsort(sizes == 0, stable=True).astype(jnp.int32)
+    return (ids, sizes, jnp.cumsum(sizes) - sizes,
+            jnp.sum(sizes > 0, dtype=jnp.int32).reshape(1))
+
+
+def _gmm_kernel(ids_ref, sizes_ref, offs_ref, nt_ref, x_ref, *refs,
+                gated: bool):
+    """The whole call: the sorted rows in VMEM (``x_ref [na, Rp, tm]``,
+    a chunk's columns at a time), the stacked weights in HBM.  A loop
+    over the touched experts streams each one's chunks
+    (:func:`gmm_chunks`) through two buffers a projection, the next
+    chunk arriving while this one is multiplied: the ``na`` input chunks
+    accumulate the float32 hidden rows ``h_ref [G, nb, Rp, th]`` (gate
+    and up), the ``nb`` output chunks the float32 result ``acc_ref [Rp,
+    M]``.  ``cnt_ref`` counts the chunk fetches started."""
+    G = 2 if gated else 1
+    ins, (wo_hbm, o_ref, cnt_ref, a_buf, b_buf, a_sem, b_sem, h_ref,
+          acc_ref) = refs[:G], refs[G:]
+    na, _, tm = x_ref.shape
+    nb, _, th = h_ref.shape[1:]
+    tile = _sublanes(x_ref.dtype)
+    f32 = jnp.float32
+    nt = nt_ref[0]
+
+    def a_copies(e, k):
+        rows = pl.ds(pl.multiple_of(k * tm, tm), tm)
+        return [pltpu.make_async_copy(w.at[e, rows, :], a_buf.at[k % 2, g],
+                                      a_sem.at[k % 2, g])
+                for g, w in enumerate(ins)]
+
+    def b_copies(e, j):
+        rows = pl.ds(pl.multiple_of(j * th, th), th)
+        return [pltpu.make_async_copy(wo_hbm.at[e, rows, :], b_buf.at[j % 2],
+                                      b_sem.at[j % 2])]
+
+    def start(copies):
+        for c in copies:
+            c.start()
+        cnt_ref[0] += 1
+
+    cnt_ref[0] = 0
+    h_ref[...] = jnp.zeros(h_ref.shape, f32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    @pl.when(nt > 0)
+    def _():
+        start(a_copies(ids_ref[0], 0))
+
+    def expert(t, carry):
+        e = ids_ref[t]
+        n, off = sizes_ref[e], offs_ref[e]
+        first = off // tile * tile
+        span = off - first + n
+
+        def windows(multiply):
+            """``multiply(here, keep)`` over the group's rows: one pass
+            in the smallest window that holds them, the widest looped
+            where none does.  A window is sublane aligned, the group is
+            not: rows of its neighbours are computed with it and not
+            kept."""
+            def one(W, w):
+                at = pl.multiple_of(first + w * W, tile)
+                r = at + jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+                multiply(pl.ds(at, W), (r >= off) & (r < off + n))
+
+            for fits, W in zip((0,) + _WINDOWS, _WINDOWS[:-1]):
+                pl.when((span > fits) & (span <= W))(
+                    functools.partial(one, W, 0))
+            W = _WINDOWS[-1]
+
+            @pl.when(span > _WINDOWS[-2])
+            def _():
+                jax.lax.fori_loop(0, pl.cdiv(span, W),
+                                  lambda w, c: one(W, w) or c, 0)
+
+        def a_chunk(k, carry):
+            @pl.when(k + 1 < na)
+            def _():
+                start(a_copies(e, k + 1))
+
+            @pl.when(k + 1 == na)
+            def _():
+                start(b_copies(e, 0))
+
+            for c in a_copies(e, k):
+                c.wait()
+
+            def multiply(here, keep):
+                rows = x_ref[k, here, :]
+                for g in range(G):
+                    h = jnp.where(keep, jnp.dot(
+                        rows, a_buf[k % 2, g], preferred_element_type=f32),
+                        0.0)
+                    for j in range(nb):
+                        h_ref[g, j, here, :] += h[:, j * th:(j + 1) * th]
+
+            windows(multiply)
+            return carry
+
+        def b_chunk(j, carry):
+            @pl.when(j + 1 < nb)
+            def _():
+                start(b_copies(e, j + 1))
+
+            @pl.when((j + 1 == nb) & (t + 1 < nt))
+            def _():
+                start(a_copies(ids_ref[t + 1], 0))
+
+            for c in b_copies(e, j):
+                c.wait()
+
+            def multiply(here, keep):
+                h = _activate(h_ref[G - 1, j, here, :],
+                              h_ref[0, j, here, :] if gated else None)
+                acc_ref[here, :] += jnp.where(keep, jnp.dot(
+                    h.astype(x_ref.dtype), b_buf[j % 2],
+                    preferred_element_type=f32), 0.0)
+
+            windows(multiply)
+            return carry
+
+        jax.lax.fori_loop(0, na, a_chunk, 0)
+        jax.lax.fori_loop(0, nb, b_chunk, 0)
+        return carry
+
+    jax.lax.fori_loop(0, nt, expert, 0)
+    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def decode_gmm(rows, sizes, w_gate, w_in, w_out, *, interpret=None):
+    """:func:`ragged_experts` as ONE Pallas call (``moe_decode_gmm``),
+    for the few rows an expert a decode step has, and beside its output
+    the expert weight sets it fetched (float32: the chunk fetches the
+    kernel counted as it started them, over the chunks an expert has).
+    The kernel walks the touched experts alone; each one's matrices
+    stream from HBM once, in contiguous chunks of :func:`gmm_chunks`,
+    the next chunk (the next expert's first after this one's last)
+    arriving while this one is multiplied; the hidden rows stay in VMEM,
+    float32 until their one rounding to the operands' dtype before the
+    down projection.  Rows past the last group come back zero."""
+    M, H = w_in.shape[1:]
+    return _decode_gmm(
+        rows, sizes, w_gate, w_in, w_out, _interpret(interpret),
+        *gmm_chunks(M, H, w_gate is not None, rows.dtype))
+
+
+# a program's layers are alike: jitted, the kernel is traced and lowered
+# once a program, not once a layer
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _decode_gmm(rows, sizes, w_gate, w_in, w_out, interpret: bool, tm: int,
+                th: int):
+    R, M = rows.shape
+    H = w_in.shape[2]
+    G = 1 if w_gate is None else 2
+    na, nb = M // tm, H // th
+    Rp = _gmm_rows(R, rows.dtype)
+    # a chunk's columns of every row together: [na, Rp, tm]
+    x = jnp.pad(rows, ((0, Rp - R), (0, 0))).reshape(Rp, na, tm).swapaxes(
+        0, 1)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out, count = pl.pallas_call(
+        functools.partial(_gmm_kernel, gated=G == 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(1,),
+            in_specs=[pl.BlockSpec((na, Rp, tm), lambda *_: (0, 0, 0))]
+            + [hbm] * (G + 1),
+            out_specs=[pl.BlockSpec((Rp, M), lambda *_: (0, 0)),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[
+                pltpu.VMEM((2, G, tm, H), rows.dtype),
+                pltpu.VMEM((2, th, M), rows.dtype),
+                pltpu.SemaphoreType.DMA((2, G)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((G, nb, Rp, th), jnp.float32),
+                pltpu.VMEM((Rp, M), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((Rp, M), rows.dtype),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_decode_gmm",
+    )(*_gmm_plan(sizes), x, *([] if w_gate is None else [w_gate]), w_in,
+      w_out)
+    return out[:R], count[0].astype(jnp.float32) / (na + nb)
+
+
 def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
-                     held_only: bool = False):
+                     held_only: bool = False, kernel: bool = False):
     """The dropless routed expert FFN over tokens ``x [T, M]`` with
     gates and expert indices ``[T, K]``: sort the ``T*K`` assignments
-    by expert, one grouped matmul per projection over the sorted rows,
-    unsort, weight and sum per token.  ``w_gate`` None = ungated.
+    by expert, the experts over the sorted rows, unsort, weight and sum
+    per token.  The experts are one grouped matmul per projection
+    (:func:`ragged_experts`) or, with ``kernel`` (a decode-shaped call
+    where :func:`applies` says so), the three projections as one Pallas
+    call (:func:`decode_gmm`); sort, unsort and weighting are the same
+    XLA ops around either.  ``w_gate`` None = ungated.
     ``valid [T]`` bool marks real tokens; the others are sorted past
     every group (sentinel expert ``E``), so no expert computes them.
     So is a pair whose expert index is ``E`` or more: an expert this
     device does not hold (``MoEMLP.held``); its gate weighs nothing
     here.
 
-    Returns ``(y [T, M] in x's dtype, sizes [E] int32)`` - ``sizes``
-    the real assignments each expert received."""
+    Returns ``(y [T, M] in x's dtype, sizes [E] int32, fetched)`` -
+    ``sizes`` the real assignments each expert received, ``fetched`` the
+    expert weight sets the kernel counted itself fetching (None on the
+    ``ragged_dot`` path, whose reads are not the program's to count)."""
     T, K = idx.shape
     E = w_in.shape[0]
     with jax.named_scope("moe/route"):
@@ -193,11 +486,11 @@ def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
         in_group = jnp.arange(T * K) < sizes.sum()
     with jax.named_scope("moe/experts"):
         rows = x[order // K]                              # [T*K, M]
-        h = _activate(
-            jax.lax.ragged_dot(rows, w_in, sizes),
-            None if w_gate is None
-            else jax.lax.ragged_dot(rows, w_gate, sizes))
-        out = jax.lax.ragged_dot(h, w_out, sizes)         # [T*K, M]
+        if kernel:
+            out, fetched = decode_gmm(rows, sizes, w_gate, w_in, w_out)
+        else:                                             # [T*K, M]
+            out, fetched = ragged_experts(rows, sizes, w_gate, w_in,
+                                          w_out), None
     with jax.named_scope("moe/combine"):
         out = jnp.where(in_group[:, None], out, 0)
         # unsort with the inverse permutation (a gather, not a scatter-
@@ -210,7 +503,7 @@ def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
         if held_only:
             w = w * (idx < E)
         y = (out * w[..., None]).sum(axis=1)
-    return y.astype(x.dtype), sizes
+    return y.astype(x.dtype), sizes, fetched
 
 
 class MoEMLP(nn.Module):
@@ -223,8 +516,10 @@ class MoEMLP(nn.Module):
 
     ``capacity_factor <= 0`` selects the dropless path (module
     docstring) for every call: training, prefill, chunked prefill and
-    decode run the same sort + grouped matmuls, nothing is dropped, and
-    the layer's ``moe_stats`` are sown into ``intermediates``.
+    decode run the same sort + grouped matmuls (a ``decode`` call of
+    one token a slot runs them as one kernel where :func:`applies`
+    says so), nothing is dropped, and the layer's ``moe_stats`` are
+    sown into ``intermediates``.
 
     ``capacity_factor > 0`` keeps the capacity path.  There
     ``decode=True`` (incremental generation) sends the single-token
@@ -253,6 +548,9 @@ class MoEMLP(nn.Module):
     routed_scale: float = 1.0
     shared_dim: int = 0
     held: int = 0
+    # the mesh the caller's arrays are sharded over, if any: what
+    # :func:`applies` reads beside the call's shape
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x, token_mask=None):
@@ -302,11 +600,17 @@ class MoEMLP(nn.Module):
                     gates = gates * self.routed_scale
             valid = None if token_mask is None else token_mask.reshape(B * S)
             xt = x.reshape(B * S, M).astype(dtype)
-            y, sizes = dropless_experts(
+            kernel = self.decode and applies(S, self.mesh, B * S * self.top_k,
+                                             M, dtype)
+            y, sizes, fetched = dropless_experts(
                 xt, gates.reshape(B * S, -1), idx.reshape(B * S, -1),
                 None if w_gate is None else w_gate.astype(dtype),
                 w_in.astype(dtype), w_out.astype(dtype), valid,
-                held_only=bool(self.held))
+                held_only=bool(self.held), kernel=kernel)
+            if kernel:
+                self.sow("intermediates", "moe_fetched", fetched,
+                         init_fn=lambda: jnp.zeros((), jnp.float32),
+                         reduce_fn=lambda a, b: a + b)
             total = sizes.sum().astype(jnp.float32)
             stats = [total, (sizes > 0).sum().astype(jnp.float32),
                      sizes.max() * Eh / jnp.maximum(total, 1.0)]

@@ -137,15 +137,30 @@ _INTERTOKEN_SECONDS = obs_metrics.histogram(
     "(done - first token) / (tokens - 1)", buckets=_FINE_BUCKETS)
 
 
+def _sown_sum(intermediates, name: str) -> "jax.Array":
+    """Every leaf a program's layers sowed under ``name``, summed: a
+    float32 scalar (zero where no layer sowed it)."""
+    return sum((jnp.asarray(leaf, jnp.float32).sum() for path, leaf in
+                jax.tree_util.tree_leaves_with_path(intermediates or {})
+                if any(getattr(k, "key", None) == name for k in path)),
+               jnp.zeros((), jnp.float32))
+
+
 def _ssm_slots_run(intermediates) -> "jax.Array":
     """Slot states the one-token updates of a program's state-space
     layers read and wrote (``Mamba2Mixer`` sows ``ssm_slots_run``: on
     the chip counted from the kernel's fetch plan), summed over the
-    layers: a float32 scalar."""
-    return sum((jnp.asarray(leaf, jnp.float32).sum() for path, leaf in
-                jax.tree_util.tree_leaves_with_path(intermediates or {})
-                if any(getattr(k, "key", None) == "ssm_slots_run"
-                       for k in path)), jnp.zeros((), jnp.float32))
+    layers."""
+    return _sown_sum(intermediates, "ssm_slots_run")
+
+
+def _moe_fetched(intermediates) -> "jax.Array":
+    """Expert weight sets the decode kernel of a program's expert
+    layers fetched (``MoEMLP`` sows ``moe_fetched`` where ``ops/moe.
+    decode_gmm`` runs: the kernel's own count of the chunk fetches it
+    started), summed over the layers; zero on the ``ragged_dot`` path,
+    whose reads are not the program's to count."""
+    return _sown_sum(intermediates, "moe_fetched")
 
 
 def _zeros_of(shapes):
@@ -216,6 +231,7 @@ class _Tick:
     live: list
     dec: object = None
     moe: object = None
+    fetched: object = None
     ssm: object = None
     counts: object = None
     pres: list = dataclasses.field(default_factory=list)
@@ -493,6 +509,7 @@ class ContinuousBatcher:
         self._moe_tokens = 0      # the host's own count of what was routed
         self._moe_decode_layer_steps = 0
         self._moe_decode_experts_touched = 0
+        self._moe_decode_experts_fetched = 0.0
         self._moe_prefill_groups = 0
         self._moe_prefill_experts_touched = 0
         self._moe_prefill_max_load_sum = 0.0
@@ -900,6 +917,12 @@ class ContinuousBatcher:
                 "moe_decode_layer_steps": self._moe_decode_layer_steps,
                 "moe_decode_experts_touched":
                     self._moe_decode_experts_touched,
+                # expert weight sets the decode kernel fetched, as the
+                # kernel counted the fetches it started (ops/moe.
+                # decode_gmm): equal to the touched when an untouched
+                # expert costs nothing; 0 where ragged_dot runs
+                "moe_decode_experts_fetched":
+                    round(self._moe_decode_experts_fetched, 3),
                 "moe_prefill_groups": self._moe_prefill_groups,
                 "moe_prefill_experts_touched":
                     self._moe_prefill_experts_touched,
@@ -1309,8 +1332,10 @@ class ContinuousBatcher:
         slab (transformer.Block._decode_attention), and the dropless
         expert path routes free slots' ballast tokens nowhere.  With
         that path the layers' ``moe_stats`` ride back beside the
-        tokens, ``(cache, last, (tokens, stats))``; otherwise ``(cache,
-        last, tokens)``.  State-space layers add the slot states their
+        tokens, and after them the expert weight sets the decode kernel
+        fetched (``_moe_fetched``), ``(cache, last, (tokens, stats,
+        fetched))``; otherwise ``(cache, last, tokens)``.  State-space
+        layers add the slot states their
         one-token updates read and wrote (``_ssm_slots_run``: counted
         on the device from the kernel's own plan), last in that tuple.
         ``last`` ([slots]) is what the final token step sampled: the
@@ -1323,8 +1348,8 @@ class ContinuousBatcher:
         model = self._model
 
         # what the layers sow for the host, each read by its own reader
-        readers = ([_moe_stats] if self._moe_dropless else []) + (
-            [_ssm_slots_run] if self._state_layers else [])
+        readers = ([_moe_stats, _moe_fetched] if self._moe_dropless
+                   else []) + ([_ssm_slots_run] if self._state_layers else [])
 
         def one(carry, k):
             cache, tok, *acc = carry
@@ -1340,9 +1365,9 @@ class ContinuousBatcher:
             return (mut["cache"], nxt, *acc), nxt
 
         keys = jax.random.split(key, self._T)
-        acc0 = ([_zeros_of(self._moe_acc_shape)] if self._moe_dropless
-                else []) + ([jnp.zeros((), jnp.float32)]
-                            if self._state_layers else [])
+        zero = jnp.zeros((), jnp.float32)
+        acc0 = ([_zeros_of(self._moe_acc_shape), zero] if self._moe_dropless
+                else []) + ([zero] if self._state_layers else [])
         (cache, last, *acc), out = jax.lax.scan(
             one, (cache, toks, *acc0), keys)
         # [slots, T]
@@ -1654,7 +1679,7 @@ class ContinuousBatcher:
                 if isinstance(tick.dec, tuple):     # tokens, then counts
                     tick.dec, *counts = tick.dec
                     if self._moe_dropless:
-                        tick.moe = counts.pop(0)
+                        tick.moe, tick.fetched = counts.pop(0), counts.pop(0)
                     if self._state_layers:
                         tick.ssm = counts.pop(0)
                 for i, _ in live:
@@ -1683,6 +1708,7 @@ class ContinuousBatcher:
         with led.phase("sync"):
             dec = np.asarray(tick.dec) if tick.dec is not None else None
             moe = np.asarray(tick.moe) if tick.moe is not None else None
+            fetched = float(tick.fetched) if tick.moe is not None else 0.0
             ssm = float(tick.ssm) if tick.ssm is not None else 0.0
             counts = (np.asarray(tick.counts) if tick.counts is not None
                       else None)
@@ -1696,7 +1722,7 @@ class ContinuousBatcher:
                     self._finish_decode(dec, tick.live, ssm)
                 if moe is not None:
                     self._count_moe(moe, len(tick.live) * self._T,
-                                    decode=True)
+                                    decode=True, fetched=fetched)
             for slots, reqs, ptoks, drops in fins:
                 self._finish_prefill(slots, reqs, ptoks, drops)
 
@@ -2232,10 +2258,12 @@ class ContinuousBatcher:
         live[active] = True
         return jnp.asarray(live)
 
-    def _count_moe(self, moe: np.ndarray, tokens: int, decode: bool) -> None:
+    def _count_moe(self, moe: np.ndarray, tokens: int, decode: bool,
+                   fetched: float = 0.0) -> None:
         """Add one program's expert counters (generate._moe_stats: a
         drop count alone, or the dropless path's vector) to stats(),
-        and beside them the ``tokens`` the host knows it routed."""
+        and beside them the ``tokens`` the host knows it routed;
+        ``fetched``: a step program's ``_moe_fetched``."""
         with self._stats_lock:
             if self._dcfg.moe_experts:
                 self._moe_tokens += tokens
@@ -2250,6 +2278,7 @@ class ContinuousBatcher:
             if decode:
                 self._moe_decode_layer_steps += int(calls)
                 self._moe_decode_experts_touched += int(touched)
+                self._moe_decode_experts_fetched += fetched
             else:
                 self._moe_prefill_groups += int(calls)
                 self._moe_prefill_experts_touched += int(touched)

@@ -461,6 +461,34 @@ def test_ssm_step_kernel_compiles_for_v5e_at_published_widths(one_chip):
     assert "ssm_step" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,M,H,E,chunks", [
+    (320, 4096, 768, 36, (1024, 384)),    # granite-4.0-h-small, 32 x top-10
+    (96, 2048, 1024, 64, (1024, 1024)),   # OLMoE, 12 slots x top-8
+    (96, 6144, 2048, 16, (512, 256)),     # K-EXAONE, 12 x top-8, 16 held
+])
+def test_expert_decode_kernel_compiles_for_v5e_at_published_widths(
+        one_chip, rows, M, H, E, chunks):
+    """``ops/moe.decode_gmm`` at the three served expert configurations'
+    decode shapes, compiled ahead of time for one v5e chip from abstract
+    shapes: the Mosaic compiler takes the kernel (the dynamic row
+    windows, the loops over touched experts and over their chunks, the
+    chunk copies from HBM into two buffers a projection), and the
+    program keeps nothing but the padded rows beside it."""
+    from edl_tpu.ops import moe
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert moe.gmm_chunks(M, H, True, jnp.bfloat16) == chunks
+    with _no_compile_cache():
+        compiled = jax.jit(lambda *a: moe.decode_gmm(
+            *a, interpret=False)).lower(
+                sds((rows, M)), sds((E,), jnp.int32), sds((E, M, H)),
+                sds((E, M, H)), sds((E, H, M))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
+    assert "moe_decode_gmm" in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def four_chips():
     from jax.experimental import topologies

@@ -208,3 +208,154 @@ def test_decode_gather_any_top_k():
     y_cap, _ = m2.apply(params, x)
     np.testing.assert_allclose(np.asarray(y_gather), np.asarray(y_cap),
                                atol=1e-5)
+
+
+# -- the decode step's expert kernel (ops/moe.decode_gmm) -----------------
+
+def _held(idx, E):
+    """Expert indices of a router wider than the ``E`` experts held: the
+    others become the sentinel ``E``, as ``MoEMLP.held`` routes them."""
+    return np.minimum(idx, E)
+
+
+def _spread(T, K, router, seed):
+    rng = np.random.default_rng(seed)
+    return np.stack([rng.permutation(router)[:K] for _ in range(T)])
+
+
+# name: (M, H, E, dtype, gated, idx [T, K], valid [T] or None, bytes a
+# weight chunk may take or None).  Widths are the three served
+# configurations' cut to test size: granite-4.0-h-small 4096 x 768 with
+# 36 of 72 held and top-10, OLMoE 2048 x 1024 with 64 and top-8,
+# K-EXAONE 6144 x 2048 with 16 of 128 held, an expert streamed in
+# several chunks of each projection.
+GMM_CASES = {
+    "granite_held_sentinel": (
+        128, 24, 6, "bfloat16", True, _held(_spread(4, 10, 12, 0), 6), None,
+        None),
+    "olmoe_empty_experts": (
+        64, 32, 8, "bfloat16", True, _spread(3, 2, 8, 1), None, None),
+    "exaone_streamed_in_chunks": (
+        384, 256, 4, "bfloat16", True, _held(_spread(12, 8, 32, 2), 4), None,
+        128 * 384 * 2),
+    "ungated": (
+        64, 32, 8, "bfloat16", False, _spread(6, 2, 8, 3), None, None),
+    "ungated_in_chunks": (
+        256, 256, 4, "bfloat16", False, _spread(6, 2, 4, 4), None,
+        128 * 256 * 2),
+    "every_row_on_one_expert": (
+        64, 32, 8, "bfloat16", True, np.full((5, 1), 3), None, None),
+    "group_larger_than_the_widest_window": (
+        64, 32, 4, "bfloat16", True,
+        np.concatenate([np.full((150, 1), 2), _spread(9, 1, 4, 5)]), None,
+        None),
+    "groups_in_every_window_in_chunks": (
+        256, 256, 4, "bfloat16", True,
+        np.concatenate([np.full((7, 1), 0), np.full((25, 1), 1),
+                        np.full((3, 1), 2), np.full((40, 1), 3)]), None,
+        128 * 256 * 2),
+    "valid_masks_pad_rows": (
+        64, 32, 8, "bfloat16", True, _spread(12, 2, 8, 6),
+        np.arange(12) % 3 != 1, None),
+    "no_row_valid": (
+        64, 32, 8, "bfloat16", True, _spread(4, 2, 8, 7), np.zeros(4, bool),
+        None),
+    "held_and_valid_float32": (
+        64, 32, 5, "float32", True, _held(_spread(9, 4, 10, 8), 5),
+        np.arange(9) % 4 != 0, None),
+}
+
+
+@pytest.mark.parametrize("case", list(GMM_CASES))
+def test_decode_kernel_equals_the_ragged_dot_path(case, monkeypatch):
+    """``dropless_experts`` with the decode kernel (interpret mode here)
+    against the same call on ``ragged_dot``, its reference: the output
+    within the operands' rounding (the kernel rounds the hidden rows
+    once, the reference twice), the group sizes equal, and the expert
+    weight sets the kernel counted itself fetching equal to the experts
+    touched (none where nothing is touched)."""
+    from edl_tpu.ops import moe
+
+    M, H, E, dtype, gated, idx, valid, budget = GMM_CASES[case]
+    if budget:
+        monkeypatch.setattr(moe, "_CHUNK_BYTES", budget)
+        tm, th = moe.gmm_chunks(M, H, gated, dtype)
+        assert M // tm > 1 and H // th > 1
+    else:
+        assert moe.gmm_chunks(M, H, gated, dtype) == (M, H)
+    T, K = idx.shape
+    k = jax.random.split(jax.random.key(11), 5)
+    x = jax.random.normal(k[0], (T, M), dtype)
+    gates = jax.nn.softmax(jax.random.normal(k[1], (T, K)), axis=-1)
+    w = [(jax.random.normal(kk, shape) * shape[1] ** -0.5).astype(dtype)
+         for kk, shape in zip(k[2:], [(E, M, H), (E, M, H), (E, H, M)])]
+    args = (x, gates, jnp.asarray(idx, jnp.int32), w[0] if gated else None,
+            w[1], w[2], None if valid is None else jnp.asarray(valid))
+    kw = dict(held_only=bool((idx >= E).any()))
+    want, sizes, unread = moe.dropless_experts(*args, **kw)
+    got, sizes_k, fetched = moe.dropless_experts(*args, kernel=True, **kw)
+    assert unread is None
+    assert got.dtype == want.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(sizes_k), np.asarray(sizes))
+    want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
+    eps = float(jnp.finfo(dtype).eps)
+    assert np.abs(got - want).max() <= 4 * eps * max(np.abs(want).max(), 1.0)
+    if valid is not None:
+        assert not got[~np.asarray(valid)].any()
+    assert float(fetched) == int((np.asarray(sizes) > 0).sum())
+
+
+def test_decode_kernel_dispatch_rule_is_shape_mesh_and_backend_only(
+        monkeypatch):
+    from edl_tpu.ops import moe
+
+    bf16 = jnp.bfloat16
+    assert not moe.applies(1, None, 320, 4096, bf16)    # this backend: no TPU
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    assert moe.applies(1, None, 320, 4096, bf16)
+    assert not moe.applies(2, None, 320, 4096, bf16)    # verify pass, prefill
+    assert not moe.applies(1, object(), 320, 4096, bf16)    # a mesh engine
+    # the sorted rows live in VMEM: 2048 rows of 4096 do not fit there
+    assert not moe.applies(1, None, 2048, 4096, bf16)
+    # an expert streams in contiguous chunks of at most 4 MiB, whatever
+    # the widths: rows of w_in and w_gate together, then rows of w_out
+    assert moe.gmm_chunks(4096, 768, True, bf16) == (1024, 384)
+    assert moe.gmm_chunks(2048, 1024, True, bf16) == (1024, 1024)
+    assert moe.gmm_chunks(6144, 2048, True, bf16) == (512, 256)
+    assert moe.gmm_chunks(6144, 2048, False, bf16) == (1024, 256)
+    assert moe.gmm_chunks(6144, 2048, False, jnp.float32) == (512, 128)
+
+
+@pytest.mark.parametrize("held", [0, 4])
+def test_moe_layer_takes_the_kernel_on_a_decode_shaped_call_only(
+        held, monkeypatch):
+    """``MoEMLP`` with ``decode`` and one token a slot runs the kernel
+    where ``applies`` says so and sows what it fetched; a prefill-shaped
+    call of the same layer, and the same call without ``decode``, stay
+    on ``ragged_dot`` and sow nothing."""
+    from edl_tpu.ops import moe
+
+    monkeypatch.setattr(
+        moe, "applies",
+        lambda S, mesh, rows, M, dtype: S == 1 and mesh is None)
+    kw = dict(num_experts=8, mlp_dim=32, top_k=2, capacity_factor=0.0,
+              dtype=jnp.float32, gated=True, held=held)
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(5, 1, 16)),
+                    jnp.float32)
+    mask = jnp.asarray([[True], [False], [True], [True], [False]])
+    layer = MoEMLP(decode=True, **kw)
+    params = layer.init(jax.random.key(0), x)["params"]
+    (y, _), mut = layer.apply({"params": params}, x, mask,
+                              mutable=["intermediates"])
+    sown = mut["intermediates"]
+    assert float(sown["moe_fetched"]) == float(sown["moe_stats"][1])
+    calls = []
+    monkeypatch.setattr(moe, "decode_gmm", lambda *a, **k: calls.append(a))
+    (want, _), mut = MoEMLP(decode=False, **kw).apply(
+        {"params": params}, x, mask, mutable=["intermediates"])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+    _, wide = layer.apply({"params": params}, x.reshape(1, 5, 16),
+                          mask.reshape(1, 5), mutable=["intermediates"])
+    assert not calls
+    assert "moe_fetched" not in mut["intermediates"]
+    assert "moe_fetched" not in wide["intermediates"]

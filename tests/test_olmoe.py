@@ -187,6 +187,44 @@ def test_engine_counts_what_the_expert_layers_did(params):
     assert stats["moe_prefill_drops"] == 0
 
 
+def test_engine_serves_the_same_tokens_through_the_decode_kernel(
+        params, monkeypatch):
+    """The decode step's expert FFN as ``ops/moe.decode_gmm`` (interpret
+    mode here; ``applies`` answered as a TPU without a mesh would)
+    against the same engine on ``ragged_dot``: the same greedy tokens,
+    and the step programs' own count of the expert weight sets the
+    kernel fetched equals the experts the batches touched.  Off the
+    kernel the counter stays 0: ``ragged_dot``'s reads are not the
+    program's to count."""
+    from edl_tpu.ops import moe
+
+    prompts = [np.asarray(ids_of(n, seed=n))[0] for n in (9, 5, 12)]
+
+    def served():
+        eng = ContinuousBatcher(CFG, params, slots=4, temperature=0.0,
+                                top_k=0, steps_per_sync=2, kv_block=0,
+                                prefill_chunk=0)
+        try:
+            futs = [eng.submit(p, 7) for p in prompts]
+            return [f.result(timeout=300) for f in futs], eng.stats()
+        finally:
+            eng.stop()
+
+    want, plain = served()
+    monkeypatch.setattr(
+        moe, "applies",
+        lambda S, mesh, rows, M, dtype: S == 1 and mesh is None)
+    got, stats = served()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert plain["moe_decode_experts_fetched"] == 0
+    assert stats["moe_decode_experts_touched"] > 0
+    assert (stats["moe_decode_experts_fetched"]
+            == stats["moe_decode_experts_touched"])
+    assert (stats["moe_decode_layer_steps"]
+            == plain["moe_decode_layer_steps"])
+
+
 def test_a_capacity_path_engine_breaks_the_routing_identity(params):
     """The same model through the capacity path (what a dropped token
     looks like to the counters): the host still counts the tokens it
