@@ -49,12 +49,29 @@ sits in a profiler capture on the device's clock (``train/compute``,
 :func:`edl_tpu.obs.trace.annotation`, a no-op without JAX), and the
 coverage self-check.  There is no switch in the environment: the
 benchmark reads the ledger, and ``enabled=False`` is for tests.
+
+**The request-scoped sibling** (:class:`RequestStageLedger`).  A tick
+has phases; a request has STAGES (``queue_wait``, ``prefill``,
+``decode``, ...) that overlap other requests' and outlive any tick, so
+they are not spans of a loop but durations between stamps.  One
+``observe(stage, seconds, lane=...)`` feeds a cumulative sum and count
+for means, the same by ``lane`` where the stage has lanes, and, for a
+stage whose TAILS are read, its registry histogram (the operator's
+view) and cumulative bucket counts on ONE geometric ladder
+(:data:`STAGE_EDGES`); :meth:`RequestStageLedger.totals` returns them
+as flat numeric keys, so whoever differences ``stats()`` at two
+instants gets the window's percentiles with no span handed over
+(``benchmarks/layer_metrics/engine_queue_wait_p90_s.py`` is such a
+reader).
 """
 
 from __future__ import annotations
 
+import math
 import time
+from bisect import bisect_left
 from contextlib import contextmanager
+from itertools import accumulate
 
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.obs import trace as obs_trace
@@ -121,11 +138,12 @@ class StepPhaseLedger:
 
     # -- recording -----------------------------------------------------------
     @contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, **args):
         """Time the block into ``name``.  Credits recorded inside the
         block (a nested phase, an external :meth:`add`) are deducted,
         so enclosing phases report only their own exclusive time.  The
-        block is also a profiler annotation ``<component>/<name>``."""
+        block is also a profiler annotation ``<component>/<name>``,
+        with ``args`` as the span's arguments in a capture."""
         if not self.enabled:
             yield
             return
@@ -133,7 +151,7 @@ class StepPhaseLedger:
         self._open.append(frame)
         t0 = time.perf_counter()
         try:
-            with obs_trace.annotation(f"{self.component}/{name}"):
+            with obs_trace.annotation(f"{self.component}/{name}", **args):
                 yield
         finally:
             dt = time.perf_counter() - t0
@@ -260,3 +278,71 @@ class StepPhaseLedger:
     def coverage(self) -> float | None:
         """The coverage EMA (None before the first completed step)."""
         return self._cover_ema
+
+
+# -- request stages ----------------------------------------------------------
+
+# one ladder for every stage with tails: ratio 1.5 from 2 ms to 50 s (a
+# tick is 9-50 ms, a long prompt's lane seconds, a queue at the knee
+# tens of seconds), +Inf last.  Rounded to three figures: the edge is part of a
+# key's NAME (``stage_<stage>_le_<edge>``), which a reader parses back
+STAGE_EDGES = tuple(float(f"{0.002 * 1.5 ** i:.3g}") for i in range(26)) \
+    + (math.inf,)
+
+
+def _edge_name(edge: float) -> str:
+    return "inf" if edge == math.inf else f"{edge:g}"
+
+
+class RequestStageLedger:
+    """Durations of the stages of a request's life, by stage and lane.
+
+    ``stages`` maps each stage to the lanes its ``lane=`` may take (a
+    stage ended where the lane is not known, or whose split nobody
+    reads, has none).  ``tails`` maps the stages whose percentiles are
+    read to their registry histogram: those also get the ladder's
+    bucket counts in :meth:`totals`, the others a sum and a count.
+    NOT thread-safe: the owner calls :meth:`observe` and :meth:`totals`
+    under one lock (the histograms have their own)."""
+
+    def __init__(self, stages: dict[str, tuple[str, ...]],
+                 tails: dict | None = None):
+        self._tails = dict(tails or {})
+        # every key exists from construction: a reader that differences
+        # two totals() only sees the keys of the first
+        self._sums: dict[str, float] = {}
+        for st, lanes in stages.items():
+            for who in (st, *(f"{st}_{ln}" for ln in lanes)):
+                self._sums[f"stage_{who}_sum_s"] = 0.0
+                self._sums[f"stage_{who}_n"] = 0
+        self._counts = {st: [0] * len(STAGE_EDGES) for st in self._tails}
+        self._le = {st: [f"stage_{st}_le_{_edge_name(e)}"
+                         for e in STAGE_EDGES] for st in self._tails}
+
+    def observe(self, stage: str, seconds: float,
+                lane: str | None = None) -> None:
+        """One request spent ``seconds`` in ``stage`` (having taken
+        ``lane``): sum and count, the lane's sum and count, and where
+        the stage's tails are read its histogram and the ladder.  A
+        stage or a lane it was not built with is a ``KeyError``."""
+        seconds = max(0.0, float(seconds))
+        # the lane first: an unknown one raises before anything moved
+        for who in (stage,) if lane is None else (f"{stage}_{lane}", stage):
+            self._sums[f"stage_{who}_sum_s"] += seconds
+            self._sums[f"stage_{who}_n"] += 1
+        hist = self._tails.get(stage)
+        if hist is not None:
+            hist.observe(seconds)
+            # first edge >= seconds, as a Prometheus ``le`` bucket
+            self._counts[stage][bisect_left(STAGE_EDGES, seconds)] += 1
+
+    def totals(self) -> dict:
+        """Since construction, flat and numeric: ``stage_<stage>_sum_s``
+        / ``_n``, ``stage_<stage>_<lane>_sum_s`` / ``_n`` and, for a
+        stage with tails, the CUMULATIVE bucket counts
+        ``stage_<stage>_le_<edge>`` (the last, ``le_inf``, equals
+        ``_n``).  A reader differences two calls key by key."""
+        out = dict(self._sums)
+        for stage, counts in self._counts.items():
+            out.update(zip(self._le[stage], accumulate(counts)))
+        return out

@@ -73,13 +73,43 @@ after an EOS the host had not read.
 Each phase is exclusive host seconds in ``stats()``
 (``tick_<phase>_s``, ``idle_wait_s``, ``ticks``, ``tick_s``,
 ``tick_coverage``) and in ``edl_engine_tick_phase_seconds{phase}``, and
-an ``engine/<phase>`` span in any profiler capture.  Per request the
-engine stamps submit, admission, first token and completion:
-``queue_wait_s_sum``/``admitted``, ``ttft_s_sum``/``first_tokens``,
-``decode_s_sum``/``decode_tokens`` in ``stats()``, the
-``edl_engine_queue_wait_seconds`` / ``edl_engine_ttft_seconds`` /
-``edl_engine_intertoken_seconds`` histograms, and one ``engine/request``
-trace event under the submitter's trace.  There is no switch.
+an ``engine/<phase>`` span in any profiler capture (``engine/admit``
+and ``engine/dispatch`` carry ``pending``, ``cause``, ``lane_offset``
+and ``ahead`` as the span's arguments).
+
+**Why a request waited** (the request-stage ledger, an
+:class:`edl_tpu.obs.ledger.RequestStageLedger`).  A request's life is
+three stages end to end, each closed where the engine thread makes the
+transition: ``queue_wait`` (``submit()`` until it leaves ``_pending``
+for an admission), ``prefill`` (until its first token is read on the
+host; for a long prompt the whole time it held the chunk lane) and
+``decode`` (until its future resolves); ``ttft`` is the first two
+together and ``deliver`` what the replica adds after that (the answer's
+way out, ``ReplicaServer.serve_release`` through :meth:`ContinuousBatcher.
+observe_stage`).  Every request is admitted down one of three LANES:
+``cold`` (a same-bucket group prefilled in one program), ``chunk`` (a
+prompt over ``prefill_chunk`` tokens, one chunk a tick, one prompt at a
+time) or ``reuse`` (a prefix hit: the suffix alone).  While it waits it
+is charged, tick by tick, to the CAUSE that kept the queue's head
+where it was: ``slots`` (no free slot), ``lane`` (a slot was free but
+the chunk lane was held, which takes the tick's one cold group and bars
+the next long prompt), ``group`` (the tick's one cold group took another
+bucket or its lane cap) or ``tick`` (it arrived after the tick's
+admission ran: the granularity of the loop itself).  The four sum to
+the queue wait, per request and in ``stats()``.  All of it is in
+``stats()`` as flat cumulative keys (``stage_<stage>_sum_s`` / ``_n``,
+the same by lane for ``queue_wait`` and ``prefill``, cumulative bucket
+counts ``stage_<stage>_le_<edge>`` on one geometric ladder for
+``queue_wait`` and ``ttft``, ``queue_wait_cause_<cause>_s``) beside the
+pairs that were there (``queue_wait_s_sum``/``admitted``,
+``ttft_s_sum``/``first_tokens``, ``decode_s_sum``/``decode_tokens``), in
+the ``edl_engine_queue_wait_seconds`` / ``edl_engine_ttft_seconds`` /
+``edl_engine_intertoken_seconds`` histograms, and on one
+``engine/request`` trace event under the submitter's trace.  Beside
+them the device's queue as the host knows it (``device_enqueues``,
+``device_queue_programs_sum``: programs enqueued and not yet proven
+run by a read, summed at every enqueue) and the chunk lane's
+occupancy (``chunk_lane_busy_s``).  There is no switch.
 """
 
 from __future__ import annotations
@@ -102,7 +132,7 @@ from edl_tpu.models.transformer import TransformerConfig, TransformerLM
 from edl_tpu.obs import context as obs_context
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.obs import trace as obs_trace
-from edl_tpu.obs.ledger import StepPhaseLedger
+from edl_tpu.obs.ledger import RequestStageLedger, StepPhaseLedger
 from edl_tpu.utils import constants
 from edl_tpu.utils.logger import get_logger
 
@@ -122,6 +152,21 @@ _TICK_PHASE_SECONDS = obs_metrics.histogram(
     "Per-tick exclusive host time of the engine thread by phase: "
     "idle_wait / tasks / admit / dispatch / sync / finish / kv_commit "
     "(serving/engine.py tick ledger)", ("phase",), buckets=_FINE_BUCKETS)
+# a request's life (module docstring): the lane it was admitted down,
+# the stages that tile it, each between two of its stamps, and with
+# them ttft (the first two together) and deliver (the replica's); what
+# kept it waiting.  The lane splits the stages where it decides the
+# service time (a short prompt's wait against a long one's, one program
+# against a lane held for seconds)
+LANES = ("cold", "chunk", "reuse")
+TILING_STAGES = ("queue_wait", "prefill", "decode")
+_STAGE_STAMPS = {"queue_wait": ("t_submit", "t_admit"),
+                 "prefill": ("t_admit", "t_first"),
+                 "decode": ("t_first", "t_done"),
+                 "ttft": ("t_submit", "t_first")}
+_STAGE_LANES = {"queue_wait": LANES, "prefill": LANES, "decode": (),
+                "ttft": (), "deliver": ()}
+WAIT_CAUSES = ("slots", "lane", "group", "tick")
 _QUEUE_WAIT_SECONDS = obs_metrics.histogram(
     "edl_engine_queue_wait_seconds",
     "submit() to admission (popped into a prefill, reuse or chunked "
@@ -184,7 +229,8 @@ class _Slot:
 
 class _Request:
     __slots__ = ("ids", "max_new", "future", "session", "ctx", "skipped",
-                 "snap", "cut", "t_submit", "t_admit", "t_first", "t_done")
+                 "snap", "cut", "t_submit", "t_admit", "t_first", "t_done",
+                 "lane", "chunks", "cause", "t_mark", "waits")
 
     def __init__(self, ids: np.ndarray, max_new: int,
                  session: str | None = None):
@@ -205,6 +251,21 @@ class _Request:
         # stages, on one monotonic clock: submit <= admit <= first <= done
         self.t_submit = time.monotonic()
         self.t_admit = self.t_first = self.t_done = None
+        # the stage ledger's record of this request: the lane it was
+        # admitted down and the chunks it took there, and its wait by
+        # cause: charged to ``cause`` since ``t_mark`` (born waiting for
+        # the tick's admission)
+        self.lane = None
+        self.chunks = 0
+        self.cause, self.t_mark = "tick", self.t_submit
+        self.waits: dict[str, float] = {}
+
+    def stage_s(self, stage: str) -> float:
+        """Seconds of a closed stage: the one place the stamps are
+        subtracted (the ledger and the ``engine/request`` event both
+        read this)."""
+        begin, end = _STAGE_STAMPS[stage]
+        return getattr(self, end) - getattr(self, begin)
 
 
 @dataclass
@@ -218,6 +279,7 @@ class _ChunkState:
     offset: int           # prompt tokens already prefilled
     slab: object          # one-lane decode cache, index == offset
     drops: object         # device MoE-drop accumulator (traced through)
+    t_start: float = 0.0  # the lane is held from here (monotonic)
 
 
 @dataclass
@@ -235,6 +297,9 @@ class _Tick:
     ssm: object = None
     counts: object = None
     pres: list = dataclasses.field(default_factory=list)
+    # programs enqueued up to the one this tick's read waits for: read,
+    # they have all run (the device runs them in order)
+    mark: int = 0
 
 
 class _Task:
@@ -547,13 +612,22 @@ class ContinuousBatcher:
             component="engine", phases=TICK_PHASES,
             histogram=_TICK_PHASE_SECONDS, coverage_gauge=None,
             idle_phase="idle_wait", overhead_phase="finish")
-        # per-request stages (sum, count): admission, first token, decode
-        self._admitted = 0
-        self._queue_wait_s = 0.0
-        self._first_tokens = 0
-        self._ttft_s = 0.0
+        # the request-stage ledger (module docstring): written and read
+        # under _stats_lock
+        self._stages = RequestStageLedger(
+            _STAGE_LANES, tails={"queue_wait": _QUEUE_WAIT_SECONDS,
+                                 "ttft": _TTFT_SECONDS})
+        self._wait_cause_s = dict.fromkeys(WAIT_CAUSES, 0.0)
         self._decode_tokens = 0
         self._decode_s = 0.0
+        # the device's queue as the host knows it: programs enqueued,
+        # and of them those a read has proven run (engine thread only);
+        # summed at every enqueue: how many were ahead of the new one
+        self._enqueued = 0
+        self._ran = 0
+        self._device_queue_sum = 0
+        self._device_enqueues = 0
+        self._chunk_lane_busy_s = 0.0
         self._t0 = time.monotonic()
         if mesh is not None:
             # pin the pool cache's sharding on every step/insert output
@@ -575,7 +649,6 @@ class ContinuousBatcher:
         self._spec_k = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
-        self._spec_rounds_run = 0
         self._draft_cache = None
         k = constants.SPEC_K if spec_k is None else int(spec_k)
         if k > 0:
@@ -947,14 +1020,18 @@ class ContinuousBatcher:
             }
 
     def _tick_stats(self) -> dict:
-        """The tick ledger and the per-request stages, cumulative (a
-        reader differences two calls; ``tick_coverage`` alone is a
-        level, the ledger's EMA).  ``tick_s`` is the wall time of the
-        ticks, ``idle_wait_s`` the time between them: together the
-        engine thread's life.  The ``_sum``/count pairs give means;
-        the histograms carry the tails."""
+        """The tick ledger and the request-stage ledger, cumulative (a
+        reader differences two calls).  ``tick_coverage`` alone is a
+        LEVEL, the tick ledger's EMA: whoever differences every numeric
+        key (``benchmarks/runners/serve.py``) gets a meaningless number
+        under that name, and no reader takes it from there.  ``tick_s``
+        is the wall time of the ticks, ``idle_wait_s`` the time between
+        them: together the engine thread's life.  The ``_sum``/count
+        pairs give means, the ``stage_<stage>_le_<edge>`` bucket counts
+        the tails (module docstring)."""
         tot = self._ledger.totals()
         ph = tot["phases"]
+        stages = self._stages.totals()
         return {
             "ticks": tot["steps"],
             "tick_s": tot["wall_s"],
@@ -964,13 +1041,43 @@ class ContinuousBatcher:
             "tick_coverage": tot["coverage"] or 0.0,
             "lookahead_ticks": self._lookahead_ticks,
             "lookahead_discarded_token_steps": self._lookahead_discarded,
-            "admitted": self._admitted,
-            "queue_wait_s_sum": self._queue_wait_s,
-            "first_tokens": self._first_tokens,
-            "ttft_s_sum": self._ttft_s,
+            # the names three accepted readers know
+            "admitted": stages["stage_queue_wait_n"],
+            "queue_wait_s_sum": stages["stage_queue_wait_sum_s"],
+            "first_tokens": stages["stage_ttft_n"],
+            "ttft_s_sum": stages["stage_ttft_sum_s"],
             "decode_tokens": self._decode_tokens,
             "decode_s_sum": self._decode_s,
+            **{f"queue_wait_cause_{c}_s": v
+               for c, v in self._wait_cause_s.items()},
+            "device_enqueues": self._device_enqueues,
+            "device_queue_programs_sum": self._device_queue_sum,
+            "chunk_lane_busy_s": self._chunk_lane_busy_s,
+            **stages,
         }
+
+    def observe_stage(self, stage: str, seconds: float) -> None:
+        """A stage of a request's life that ends OUTSIDE the engine
+        (``deliver``: the replica stamps the answer's way out), into
+        the same ledger.  Any thread."""
+        with self._stats_lock:
+            self._stages.observe(stage, seconds)
+
+    def _stage(self, req: "_Request", stage: str) -> None:
+        """``stage`` of ``req`` is closed (both its stamps are set):
+        into the ledger, under its lane where the stage has lanes.
+        Under ``_stats_lock``."""
+        self._stages.observe(stage, req.stage_s(stage),
+                             req.lane if _STAGE_LANES[stage] else None)
+
+    def _count_enqueue(self) -> None:
+        """One more program in the device's queue, behind ``ahead``
+        others the host has not yet seen run."""
+        ahead = self._enqueued - self._ran
+        self._enqueued += 1
+        with self._stats_lock:
+            self._device_enqueues += 1
+            self._device_queue_sum += ahead
 
     def _spec_stats(self) -> dict:
         """Speculative-decode counters (empty when spec is off, so
@@ -983,7 +1090,6 @@ class ContinuousBatcher:
             "spec_proposed": self._spec_proposed,
             "spec_accepted": self._spec_accepted,
             "spec_accept_rate": round(self._spec_accepted / prop, 3),
-            "spec_rounds": self._spec_rounds_run,
         }
 
     def _kv_stats(self) -> dict:
@@ -1527,7 +1633,6 @@ class ContinuousBatcher:
         with self._stats_lock:
             self._lane_steps += len(self._slots) * lane_tokens
             self._active_lane_steps += len(live) * lane_tokens
-            self._spec_rounds_run += R
         for i, _ in live:
             s = self._slots[i]
             with self._stats_lock:
@@ -1644,11 +1749,17 @@ class ContinuousBatcher:
                         task.future.set_result(task.fn())
                     except BaseException as e:  # noqa: BLE001 — must resolve
                         task.future.set_exception(e)
-        with led.phase("admit"):
+        st = self._chunking
+        # the head's cause as this tick's admission begins: what the
+        # tick before left it waiting for ("tick" for a new arrival)
+        with led.phase("admit", pending=len(self._pending),
+                       cause=(self._pending[0].cause if self._pending
+                              else "none"),
+                       lane_offset=-1 if st is None else st.offset):
             live = [(i, s.request) for i, s in enumerate(self._slots)
                     if s.owed > 0]
             pres = self._admit(bool(live))
-        with led.phase("dispatch"):
+        with led.phase("dispatch", ahead=self._enqueued - self._ran):
             tick = self._dispatch(live, pres)
         if not self._spec_k:
             tick, self._inflight = self._inflight, tick
@@ -1685,11 +1796,16 @@ class ContinuousBatcher:
                 for i, _ in live:
                     s = self._slots[i]
                     s.owed = max(0, s.owed - self._T)
+            self._count_enqueue()
+        # the read waits for the step and the admissions' prefills, not
+        # for the inserts behind them
+        tick.mark = self._enqueued
         for slab, toks, _, slots, reqs, lens, dslab, snap in pres:
             at = jnp.asarray(slots, jnp.int32)
             n = jnp.asarray(lens, jnp.int32)
             self._cache, self._toks = self._insert_jit(
                 self._cache, self._toks, slab, at, n, toks)
+            self._count_enqueue()
             if dslab is not None:
                 self._draft_cache = self._draft_insert_jit(
                     self._draft_cache, dslab, at, n)
@@ -1714,6 +1830,7 @@ class ContinuousBatcher:
                       else None)
             fins = [(p[3], p[4], np.asarray(p[1]), np.asarray(p[2]))
                     for p in tick.pres]
+        self._ran = max(self._ran, tick.mark)
         with led.phase("finish"):
             if dec is not None:
                 if counts is not None:
@@ -1767,7 +1884,8 @@ class ContinuousBatcher:
         # cold-group slot in the dispatch budget
         if self._chunking is None:
             self._maybe_start_chunk(taken)
-        if self._chunking is not None:
+        lane_held, group = self._chunking is not None, None
+        if lane_held:
             pre = self._advance_chunk()
             if pre is not None:
                 take(pre)
@@ -1777,10 +1895,37 @@ class ContinuousBatcher:
                 pre = self._dispatch_prefill(*group)
                 if pre is not None:
                     take(pre)
+        if self._pending:
+            self._passed_over(lane_held, group is not None, taken)
         if pres and lanes_live:
             with self._stats_lock:
                 self._prefill_stall_s += time.monotonic() - t0
         return pres
+
+    def _passed_over(self, lane_held: bool, grouped: bool,
+                     taken: set[int]) -> None:
+        """This tick's admission is over and requests are still
+        pending: WHY, judged once for the queue's head and inherited by
+        those behind it (the queue is FIFO).  ``slots``: no slot was
+        free, so no policy of lanes would have admitted it; else
+        ``lane``: the chunk lane was held this tick, which displaced
+        the cold group and bars the next long prompt; else ``group``:
+        the tick's one cold group took another bucket or its cap; else
+        ``tick``.  Each pending request is charged to its cause from
+        its mark on; only a change of cause moves the mark."""
+        if not any(s.free and i not in taken
+                   for i, s in enumerate(self._slots)):
+            cause = "slots"
+        elif lane_held:
+            cause = "lane"
+        else:
+            cause = "group" if grouped else "tick"
+        now = time.monotonic()
+        for req in self._pending:
+            if req.cause != cause:
+                req.waits[req.cause] = (req.waits.get(req.cause, 0.0)
+                                        + now - req.t_mark)
+                req.cause, req.t_mark = cause, now
 
     def _fail_all(self, e: Exception) -> None:
         n = 0
@@ -1828,20 +1973,22 @@ class ContinuousBatcher:
         for req in reversed(reqs[K:]):                 # overflow back, FIFO
             self._pending.appendleft(req)
         reqs = reqs[:K]
-        self._stamp_admit(reqs)
+        self._stamp_admit(reqs, "cold")
         return P, free[:K], reqs
 
-    def _stamp_admit(self, reqs: list[_Request]) -> None:
-        """The requests left ``_pending`` for an admission (cold group,
-        prefix reuse or chunked): their queue wait ends here."""
+    def _stamp_admit(self, reqs: list[_Request], lane: str) -> None:
+        """The requests left ``_pending`` for an admission down ``lane``
+        (cold group, prefix reuse or chunked): their queue wait ends
+        here, its last stretch charged to the cause they carried."""
         now = time.monotonic()
-        waits = [now - r.t_submit for r in reqs]
-        for req, wait in zip(reqs, waits):
-            req.t_admit = now
-            _QUEUE_WAIT_SECONDS.observe(wait)
         with self._stats_lock:
-            self._admitted += len(reqs)
-            self._queue_wait_s += sum(waits)
+            for req in reqs:
+                req.lane, req.t_admit = lane, now
+                req.waits[req.cause] = (req.waits.get(req.cause, 0.0)
+                                        + now - req.t_mark)
+                self._stage(req, "queue_wait")
+                for cause, s in req.waits.items():
+                    self._wait_cause_s[cause] += s
 
     def _dispatch_prefill(self, P: int, slots: list[int],
                           reqs: list[_Request]):
@@ -1864,6 +2011,7 @@ class ContinuousBatcher:
                     if self._state_layers else None)
             slab, toks, drops, snap = self._prefill_fn(P, K)(
                 self._params, jnp.asarray(ids), jnp.asarray(lens), key, ends)
+            self._count_enqueue()
             self._count_scan(K, P, int(lens.sum()))
             dslab = (self._draft_prefill_fn(P, K)(
                 self._draft_params, jnp.asarray(ids), jnp.asarray(lens))
@@ -1905,13 +2053,14 @@ class ContinuousBatcher:
         if slot is None:
             return
         req = self._pending.popleft()
-        self._stamp_admit([req])
+        self._stamp_admit([req], "chunk")
         if self._kv is not None:
             # one admission, counted once at start (the reuse matcher
             # already passed on it — this is the cold long-prompt path)
             self._kv_misses += 1
             self._prefill_tokens += len(req.ids)
-        self._chunking = _ChunkState(req, slot, 0, *self._chunk_start())
+        self._chunking = _ChunkState(req, slot, 0, *self._chunk_start(),
+                                     t_start=req.t_admit)
         with self._stats_lock:
             self._chunked_admissions += 1
 
@@ -1943,11 +2092,13 @@ class ContinuousBatcher:
         assert st is not None
         ids, C = st.req.ids, self._chunk_tokens
         rest = len(ids) - st.offset
+        st.req.chunks += 1
         try:
             if rest > C:
                 chunk = np.asarray(ids[st.offset:st.offset + C])[None, :]
                 st.slab, st.drops = self._chunk_mid_fn(C)(
                     self._params, st.slab, jnp.asarray(chunk), st.drops)
+                self._count_enqueue()
                 st.offset += C
                 self._count_scan(1, C, C)
                 with self._stats_lock:
@@ -1962,10 +2113,13 @@ class ContinuousBatcher:
             slab, toks, drops, snap = self._chunk_final_fn(P)(
                 self._params, st.slab, jnp.asarray(tail),
                 jnp.asarray([rest], jnp.int32), st.drops, key, at)
+            self._count_enqueue()
             self._chunking = None
             self._count_scan(1, P, rest)
             with self._stats_lock:
                 self._prefill_chunks += 1
+                # the lane is free from its last chunk's dispatch on
+                self._chunk_lane_busy_s += time.monotonic() - st.t_start
             dslab = self._draft_slab_for(st.req) if self._spec_k else None
             return (slab, toks, drops, [st.slot], [st.req], [len(ids)],
                     dslab, (snap, st.offset))
@@ -1976,6 +2130,7 @@ class ContinuousBatcher:
             self._chunking = None
             with self._stats_lock:
                 self._failed_requests += 1
+                self._chunk_lane_busy_s += time.monotonic() - st.t_start
             return None
 
     def _chunk_mid_fn(self, C: int):
@@ -2073,7 +2228,7 @@ class ContinuousBatcher:
         if not chain:
             return None
         req = self._pending.popleft()
-        self._stamp_admit([req])
+        self._stamp_admit([req], "reuse")
         return free, req, chain
 
     def _dispatch_reuse(self, slot: int, req: "_Request", chain: list):
@@ -2133,6 +2288,7 @@ class ContinuousBatcher:
                 slab, toks, drops, snap = self._reuse_prefill_fn(P, n_pad)(
                     self._params, *hit, jnp.asarray(ids), n_real, key,
                     snap_id)
+            self._count_enqueue()
             self._count_scan(1, P, len(suffix))
             # insert true_lens = the FULL prompt length: the slab's
             # cache_index already sits at prefix+suffix and the pool
@@ -2204,15 +2360,13 @@ class ContinuousBatcher:
     def _finish_prefill(self, slots: list[int], reqs: list[_Request],
                         toks: np.ndarray, moe: np.ndarray) -> None:
         now = time.monotonic()
-        ttfts = [now - r.t_submit for r in reqs]
-        for req, ttft in zip(reqs, ttfts):
-            req.t_first = now         # its first token is on the host
-            _TTFT_SECONDS.observe(ttft)
+        with self._stats_lock:
+            for req in reqs:
+                req.t_first = now     # its first token is on the host
+                self._stage(req, "prefill")
+                self._stage(req, "ttft")
         self._count_moe(moe, sum(len(r.ids) - r.skipped for r in reqs),
                         decode=False)
-        with self._stats_lock:
-            self._first_tokens += len(reqs)
-            self._ttft_s += sum(ttfts)
         for slot, tok in zip(slots, toks.tolist()):
             s = self._slots[slot]         # the request's since _admit
             s.emitted = [tok]
@@ -2338,15 +2492,17 @@ class ContinuousBatcher:
                                  "unaffected)", slot)
         req.t_done = time.monotonic()
         n_out = len(out)
+        decode_s = req.stage_s("decode")
         # a one-token answer has no gap between tokens
-        decode_s = req.t_done - req.t_first if n_out > 1 else 0.0
         if n_out > 1:
             _INTERTOKEN_SECONDS.observe(decode_s / (n_out - 1))
         with self._stats_lock:
             self._done_requests += 1
             self._emitted_tokens += n_out
-            self._decode_tokens += n_out - 1
-            self._decode_s += decode_s
+            self._stage(req, "decode")
+            if n_out > 1:
+                self._decode_tokens += n_out - 1
+                self._decode_s += decode_s
         s.request, s.owed = None, 0
         s.emitted = []
         if obs_trace.active():
@@ -2359,21 +2515,26 @@ class ContinuousBatcher:
         under the submitter's span (``ReplicaServer.serve_submit`` runs
         in the gateway's trace), so a merged timeline reads
         gateway/request > gateway/route > serving/submit >
-        engine/request > serving/complete."""
+        engine/request > serving/complete.  It says what THIS request
+        waited for: its stages, its lane and chunks, and its queue wait
+        by cause (``wait_<cause>``, those it was charged to)."""
         ids = {}
         if req.ctx is not None:
             child = req.ctx.child()
             ids = {"trace_id": child.trace_id, "span_id": child.span_id,
                    "parent_id": child.parent_id}
-        dur = req.t_done - req.t_submit
+        # what the ledger was given (``_Request.stage_s``): the three
+        # stages tile the request's life
+        tiled = {k: req.stage_s(k) for k in TILING_STAGES}
+        dur = sum(tiled.values())
         obs_trace.emit(
             "engine/request", dur=dur,
             # edl-lint: disable=clock — back-dating a TRACE ts to the
             # span begin (merge convention: ts is begin)
             at=time.time() - dur,
-            queue_wait=round(req.t_admit - req.t_submit, 6),
-            prefill=round(req.t_first - req.t_admit, 6),
-            decode=round(req.t_done - req.t_first, 6),
+            **{k: round(v, 6) for k, v in tiled.items()},
+            lane=req.lane, chunks=req.chunks,
+            **{f"wait_{c}": round(s, 6) for c, s in req.waits.items()},
             n_prompt=len(req.ids), n_out=n_out,
             prefix_tokens_skipped=req.skipped, **ids)
 

@@ -113,6 +113,10 @@ _SPEC_ACCEPT_RATE = obs_metrics.gauge(
     "Lifetime fraction of proposed draft tokens accepted")
 
 
+def _stamp_resolved(fut: Future) -> None:
+    fut.t_resolved = time.monotonic()
+
+
 def publish_engine_stats(stats: dict, totals: dict | None = None) -> None:
     """Mirror :meth:`ContinuousBatcher.stats` into the metrics registry
     (the replica's /metrics page must cover the engine itself).
@@ -167,7 +171,8 @@ class ReplicaServer:
             f"{local_ip()}-{os.getpid()}-{uuid.uuid4().hex[:6]}")
         self._lock = threading.Lock()
         self._futures: dict[str, Future] = {}
-        self._results: dict[str, tuple[bytes, float]] = {}  # rid -> (buf, t)
+        # rid -> (buf, t fetched-from, t the future resolved)
+        self._results: dict[str, tuple[bytes, float, float]] = {}
         self._result_ttl = result_ttl
         self._draining = False
         self._drained = threading.Event()
@@ -218,6 +223,9 @@ class ReplicaServer:
             raise EdlUnavailableError(str(e)) from e
         with self._lock:
             self._futures[request_id] = fut
+        # the instant the answer exists: where its way out begins
+        # (serve_release observes the stage ``deliver`` from here)
+        fut.add_done_callback(_stamp_resolved)
         _REPLICA_REQS.inc()
         # runs under the RPC wire's re-established context, so this
         # span carries the GATEWAY's trace_id — the cross-process link
@@ -250,9 +258,12 @@ class ReplicaServer:
             raise EdlInternalError(
                 f"generation failed: {type(e).__name__}: {e}") from e
         data = np.asarray(toks, np.int32).tobytes()
+        now = time.monotonic()
         with self._lock:
             self._futures.pop(request_id, None)
-            self._results[request_id] = (data, time.monotonic())
+            # a waiter can wake before the future's callbacks have run
+            self._results[request_id] = (
+                data, now, getattr(fut, "t_resolved", now))
         obs_trace.emit("serving/complete", request=request_id,
                        replica=self.replica_id, nbytes=len(data))
         return {"done": True, "nbytes": len(data)}
@@ -266,15 +277,22 @@ class ReplicaServer:
 
     def serve_release(self, request_id: str) -> dict:
         with self._lock:
-            had_result = self._results.pop(request_id, None) is not None
+            result = self._results.pop(request_id, None)
             fut = self._futures.pop(request_id, None)
         if fut is not None and not fut.done():
             # hedge loser cancelled mid-generation: the engine lane
             # still finishes; discard its output on arrival
             fut.add_done_callback(lambda _f: _RELEASED.labels(
                 cause="cancelled").inc())
-        elif had_result:
+        elif result is not None:
             _RELEASED.labels(cause="acked").inc()
+            # the answer's way out, seen from inside: the serve_wait
+            # wake-up and the serve_fetch / serve_release round trips,
+            # into the engine's request-stage ledger (a fake engine has
+            # none)
+            observe = getattr(self._engine, "observe_stage", None)
+            if observe is not None:
+                observe("deliver", time.monotonic() - result[2])
         return {"ok": True}
 
     def serve_stats(self) -> dict:
@@ -599,8 +617,8 @@ class ReplicaServer:
             return
         cutoff = time.monotonic() - self._result_ttl
         with self._lock:
-            stale = [rid for rid, (_, t) in self._results.items()
-                     if t < cutoff]
+            stale = [rid for rid, res in self._results.items()
+                     if res[1] < cutoff]
             for rid in stale:
                 del self._results[rid]
         for _ in stale:

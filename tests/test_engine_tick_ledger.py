@@ -140,7 +140,18 @@ def test_request_stages_ordered_and_counted(small, monkeypatch):
     for req in seen:
         assert (t0 <= req.t_submit <= req.t_admit <= req.t_first
                 <= req.t_done <= t1)
+        # the ledger's record of the request tiles its life, and its
+        # wait by cause tiles its queue wait (ISSUE 34)
+        rec = {k: req.stage_s(k) for k in engine_mod._STAGE_STAMPS}
+        assert rec["queue_wait"] + rec["prefill"] + rec["decode"] == \
+            pytest.approx(req.t_done - req.t_submit, abs=1e-9)
+        assert rec["ttft"] == pytest.approx(req.t_first - req.t_submit)
+        assert sum(req.waits.values()) == pytest.approx(rec["queue_wait"])
+        assert req.lane in engine_mod.LANES
     assert st["admitted"] == st["first_tokens"] == st["requests_done"] == 7
+    assert sum(st[f"queue_wait_cause_{c}_s"]
+               for c in engine_mod.WAIT_CAUSES) == pytest.approx(
+        st["queue_wait_s_sum"], rel=1e-9)
     assert st["tokens_emitted"] == sum(len(o) for o in outs)
     assert st["decode_tokens"] == st["tokens_emitted"] - st["requests_done"]
     # the sums are the stamps' own differences
@@ -242,9 +253,9 @@ def test_engine_phases_are_profiler_annotations(small, monkeypatch):
     names = set()
     real = obs_trace.annotation
 
-    def spy(name):
+    def spy(name, **args):
         names.add(name)
-        return real(name)
+        return real(name, **args)
 
     monkeypatch.setattr(obs_trace, "annotation", spy)
     eng = _engine(cfg, params)

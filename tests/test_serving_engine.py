@@ -611,9 +611,9 @@ def test_slot_freed_at_tick_n_is_admitted_at_n_plus_1(small):
         events.append(("finish", tick_no(), len(eng._pending)))
         finish(slot)
 
-    def spy_stamp(reqs):
+    def spy_stamp(reqs, lane):
         events.append(("admit", tick_no(), len(reqs)))
-        stamp(reqs)
+        stamp(reqs, lane)
 
     eng._finish, eng._stamp_admit = spy_finish, spy_stamp
     try:
