@@ -29,7 +29,9 @@ import jax
 import jax.numpy as jnp
 
 from edl_tpu.ops import decode_attention, ssm
-from edl_tpu.ops.attention import dot_product_attention
+from edl_tpu.ops.attention import (
+    SPLASH_RESIDUALS, dot_product_attention, splash_partials_bytes,
+)
 from edl_tpu.parallel.sharding import logical_constraint
 
 # param-path regex → logical axes (ElasticTrainer.create_state consumes)
@@ -342,7 +344,44 @@ def auto_layout(cfg: TransformerConfig, per_device_batch: int,
                 f"(memory_stats()={stats!r}); pass hbm_bytes")
         # CPU/test backends report no limit: size as a 16 GB-class chip
         hbm_bytes = float(stats.get("bytes_limit") or 16e9)
-    seq = seq or cfg.max_len
+    remat = (train_bytes_estimate(cfg, per_device_batch, seq or cfg.max_len)
+             > 0.9 * hbm_bytes)
+    return replace(cfg, remat=remat, scan_layers=cfg.num_layers > 16)
+
+
+def attention_kept_bytes(cfg: TransformerConfig, per_device_batch: int,
+                         seq: int) -> int:
+    """What the attention layers' forward kernels hand their backward,
+    on one device for a whole step: ``out`` in the compute dtype
+    ``[B, S, H, Dh]`` and the logsumexp in f32 ``[B, H, S]`` a layer.
+    ``_remat`` keeps both by name beside the matmuls' outputs, so they
+    are held for every layer at once whether remat is on or off: 136 MB
+    a layer at 4 x 4096 tokens of 32 heads of 128."""
+    layers = sum(cfg.attn_kind(i) != "ssm" for i in range(cfg.num_layers))
+    rows = per_device_batch * seq * cfg.num_heads
+    return layers * rows * (cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
+
+
+def attention_backward_bytes(cfg: TransformerConfig, per_device_batch: int,
+                             seq: int) -> int:
+    """The buffer ONE attention layer's backward kernel writes beside
+    its gradients, the largest over the stack's layers (one layer's
+    backward runs at a time): the fused splash backward's partial dQs,
+    0.54 GB at 4 x 4096 tokens of 32 heads of 128."""
+    windows = {cfg.attn_window if kind == "window" else 0
+               for kind in map(cfg.attn_kind, range(cfg.num_layers))
+               if kind != "ssm"}
+    return max((splash_partials_bytes(
+        per_device_batch, seq, cfg.num_heads, cfg.head_dim, w,
+        jnp.dtype(cfg.dtype).itemsize) for w in windows), default=0)
+
+
+def train_bytes_estimate(cfg: TransformerConfig, per_device_batch: int,
+                         seq: int) -> int:
+    """The train footprint :func:`auto_layout` decides from, without
+    remat: state, activations, the head's logits, what attention keeps
+    for its backward and what that backward writes beside its
+    gradients."""
     state_bytes = 16 * param_count(cfg)     # f32 params + adam m/v + grads
     act_bytes = (2 * per_device_batch * seq * cfg.num_layers * cfg.embed_dim
                  * _ACT_VALS_PER_TOK_LAYER_EMBED)
@@ -352,8 +391,9 @@ def auto_layout(cfg: TransformerConfig, per_device_batch: int,
     # The fused-CE loss path never materialises them, but auto_layout
     # cannot know which loss the caller uses; estimate conservatively.
     logits_bytes = 2 * 4 * per_device_batch * seq * cfg.vocab_size
-    remat = state_bytes + act_bytes + logits_bytes > 0.9 * hbm_bytes
-    return replace(cfg, remat=remat, scan_layers=cfg.num_layers > 16)
+    return (state_bytes + act_bytes + logits_bytes
+            + attention_kept_bytes(cfg, per_device_batch, seq)
+            + attention_backward_bytes(cfg, per_device_batch, seq))
 
 
 def rope(x, positions, theta: float):
@@ -857,10 +897,18 @@ class Block(nn.Module):
         return _pin(cfg, x, "batch", "seq", None), None
 
 
+# What a remat block keeps for its backward pass: the matmuls' outputs,
+# and what the splash forward kernel hands its backward (``out`` and the
+# logsumexp: a Pallas call is no dot, and without its name the backward
+# pass would run that kernel again).  One object: a jaxpr prints its
+# policy, and two traces of one model must read the same.
+_REMAT_KEEPS = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(SPLASH_RESIDUALS))
+
+
 def _remat(block):
-    return nn.remat(
-        block, prevent_cse=False,
-        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    return nn.remat(block, prevent_cse=False, policy=_REMAT_KEEPS)
 
 
 class TransformerLM(nn.Module):

@@ -6,9 +6,11 @@ tensors and dispatches:
 - ``dense``: XLA einsum attention, f32 softmax — always available, the
   CPU-mesh test path;
 - ``splash``: the pallas TPU splash-attention kernel (block-sparse
-  tiled online softmax, causal-only here) — the fastest MXU path;
-  profiled 5× faster fwd+bwd than the legacy flash kernel at the
-  flagship shape ([8, 1024, 6, 128]: 0.77 ms vs 3.9 ms per layer);
+  tiled online softmax, causal-only here) — the fastest MXU path:
+  forward + backward of one 4096-token sequence of 32 heads of 128
+  take 5.0 ms on a v5e chip, the legacy flash kernel 36.6 and dense
+  33.2 (PERF.md section 6, PR 35); its tiles and the arrangement of
+  its backward come from the shape (:func:`splash_block_sizes`);
 - ``flash``: the pallas TPU flash-attention kernel — kept for
   non-causal masks and shapes splash rejects;
 - ``auto``: splash when causal + tileable on TPU, else flash when
@@ -91,7 +93,85 @@ def _flash(q, k, v, causal, sm_scale):
     return out.swapaxes(1, 2)
 
 
-# splash kernels are built per (L, H, block) — construction walks the
+# The name the splash forward gives its two residuals, ``out`` (bf16
+# [H, L, D]) and the logsumexp (f32 [H, L]), under ``jax.checkpoint``.
+# A Pallas call is no dot, so a policy that keeps dots alone runs the
+# forward kernel a second time in the backward pass to get them back;
+# ``models/transformer._remat`` keeps this name beside the dots.
+SPLASH_RESIDUALS = "splash_residuals"
+
+
+# What the sweep on a v5e chip chose (``scripts/chip_splash_sweep.py``;
+# every row, losers too, in PERF.md section 6, PR 35), by sequence
+# length: the tile of the forward and of the backward's queries, then
+# the backward's ``block_kv_dkv`` and ``block_kv_dkv_compute``.  Swept at
+# 4096 with 32 heads (four sequences) and 48 (one), at 1024 with heads
+# of 128 and 64, and at 8192: the same tiles won at both head counts
+# and both head sizes, so the length is the whole key.  A length that
+# was not timed gets none of this.
+_SWEPT_TILES = {1024: (512, 512, 512),     # one 1024-tile: no half to skip
+                4096: (1024, 1024, 1024),
+                8192: (1024, 2048, 512)}   # 2048 in one piece: no VMEM
+
+
+def splash_block_sizes(L: int, window: int = 0):
+    """The splash kernels' tiles for causal self-attention over ``L``
+    positions under a band of ``window`` (0: none).  A pure function of
+    the shape, and nothing else selects an arrangement.
+
+    At the lengths of ``_SWEPT_TILES``:
+
+    - the tiles the sweep chose (a layer call of the forward at
+      4 x 32 x 4096 x 128: 5.13 ms at 1024 against 6.72 at 512), the
+      forward's softmax over 512 keys at a time;
+    - the backward as ONE kernel (``use_fused_bwd_kernel``: dQ, dK and
+      dV from one pass over S and dP): 11.8 ms against 18.7 for ``dkv``
+      + ``dq`` at 512 and 16.0 at their own best tiles.  It writes
+      ``L / block_kv_dkv`` partial dQs in bf16 and XLA sums them, so
+      that block doubles at 8192 (four partials, the time of eight).
+      The partials are a buffer of their own while one layer's backward
+      runs (:func:`splash_partials_bytes`: 0.54 GB at 4 x 4096 x 32 x
+      128).  Nothing here sees the batch or the memory left, and nothing
+      falls back to two kernels.  Compiled for a 16 GB v5e, the two
+      train configurations lost no batch that fitted before (a 2-layer
+      7B-wide step at 5 x 4096: 16.61 GB before, 16.50 with the fused
+      kernel; at 6 x 4096 neither fits): the buffer lives while the
+      MLP's backward temporaries, more than twice its size there, do not.
+      A stack with a narrow MLP has no such room (PERF.md section 7).
+
+    Everywhere else (a window: the band is narrower than a big tile, and
+    the fused kernel visits, and writes a partial dQ for, every tile the
+    band skips; a length nobody timed) every tile is the largest of
+    512 / 256 / 128 that divides ``L`` and the backward is ``dkv`` +
+    ``dq``, as before the sweep."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+    if window or L not in _SWEPT_TILES:
+        blk = next(b for b in (512, 256, 128) if L % b == 0)
+        return sk.BlockSizes(
+            block_q=blk, block_kv=blk, block_kv_compute=blk,
+            block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+            block_q_dq=blk, block_kv_dq=blk)
+    tile, kv, compute = _SWEPT_TILES[L]
+    return sk.BlockSizes(
+        block_q=tile, block_kv=tile, block_kv_compute=512,
+        block_q_dkv=tile, block_kv_dkv=kv, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True)
+
+
+def splash_partials_bytes(B: int, L: int, H: int, D: int, window: int = 0,
+                          itemsize: int = 2) -> int:
+    """What the fused backward writes beside its gradients while ONE
+    layer's backward runs: ``L / block_kv_dkv`` partial dQs of
+    ``[B, H, L, D]`` in the compute dtype (0 where the backward is
+    ``dkv`` + ``dq``)."""
+    if window or L not in _SWEPT_TILES:
+        return 0
+    return B * (L // _SWEPT_TILES[L][1]) * H * L * D * itemsize
+
+
+# splash kernels are built per (L, H, window) — construction walks the
 # mask lazily but still costs Python time, so memoise.  Construction
 # runs under ensure_compile_time_eval: the kernel materialises mask
 # block info as arrays on first build, and if that first build happens
@@ -99,20 +179,18 @@ def _flash(q, k, v, causal, sm_scale):
 # would otherwise hold that trace's tracers and poison every later
 # trace (UnexpectedTracerError).
 @functools.cache
-def _splash_kernel(L: int, H: int, blk: int, window: int = 0):
+def _splash_kernel(L: int, H: int, window: int = 0):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm,
     )
     one = (sm.LocalMask(shape=(L, L), window_size=(window - 1, 0), offset=0)
            if window else sm.CausalMask(shape=(L, L)))
     mask = sm.MultiHeadMask(masks=[one for _ in range(H)])
-    sizes = sk.BlockSizes(
-        block_q=blk, block_kv=blk, block_kv_compute=blk,
-        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
-        block_q_dq=blk, block_kv_dq=blk)
     with jax.ensure_compile_time_eval():
-        return sk.make_splash_mha(mask=mask, head_shards=1, q_seq_shards=1,
-                                  block_sizes=sizes)
+        return sk.make_splash_mha(
+            mask=mask, head_shards=1, q_seq_shards=1,
+            block_sizes=splash_block_sizes(L, window),
+            residual_checkpoint_name=SPLASH_RESIDUALS)
 
 
 def _splash(q, k, v, sm_scale, mesh=None, window: int = 0):
@@ -129,13 +207,12 @@ def _splash(q, k, v, sm_scale, mesh=None, window: int = 0):
         raise ValueError(
             f"impl='splash' needs causal self-attention with L % 128 == 0 "
             f"and head_dim % 64 == 0; got Lq={L}, Lk={k.shape[1]}, D={D}")
-    blk = next(b for b in (512, 256, 128) if L % b == 0)
     scale = sm_scale if sm_scale is not None else D ** -0.5
 
     def local(qt, kt, vt):
         # kernel wants [H, L, D] per example; vmap over batch
         band = (window,) if window else ()     # causal: the kernel's key
-        return jax.vmap(_splash_kernel(L, qt.shape[1], blk, *band))(
+        return jax.vmap(_splash_kernel(L, qt.shape[1], *band))(
             qt, kt, vt)
 
     if mesh is not None and mesh.size > 1:
@@ -156,10 +233,15 @@ def _splash(q, k, v, sm_scale, mesh=None, window: int = 0):
 def _splash_ok(q, k, causal: bool) -> bool:
     # causal self-attention only (the mask is a CausalMask over L×L);
     # the kernel tiles L over 128-multiples and wants lane-aligned
-    # heads.  D % 64 is measured, not assumed: at [8, 1024, H, D]
-    # fwd+bwd, splash beats the alternatives at BOTH lane widths
-    # (D=128: 0.77 ms vs flash 1.06 / dense 1.69; D=64: 1.81 ms vs
-    # flash-256 3.90 / dense 3.94)
+    # heads.  Measured on a v5e chip, forward + backward of ONE
+    # sequence, every device op counted (scripts/chip_splash_sweep.py
+    # --others, PR 35): [4096, 32, 128] splash 5.04 ms, flash 36.6,
+    # dense 33.2; [4096, 48, 128] 7.45 / 54.5 / 50.2; [8192, 32, 128]
+    # 16.4 / 141 / dense does not fit; [1024, 6, 128] 0.084 / 0.36 /
+    # 0.080.  D % 64 is measured too: [1024, 12, 64] 0.170 / 0.64 /
+    # 0.110: ONE short sequence is dense's; eight were splash's
+    # before this sweep (1.81 ms against dense's 3.94), and its
+    # backward is a fifth shorter since
     B, Lq, H, D = q.shape
     return (causal and Lq == k.shape[1] and Lq % 128 == 0 and Lq >= 128
             and D % 64 == 0)

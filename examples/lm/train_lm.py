@@ -112,22 +112,18 @@ class _PipelinedLM:
 
     def __init__(self, cfg, n_microbatches: int):
         import flax.linen as nn
-        import jax
         import jax.numpy as jnp
 
-        from edl_tpu.models.transformer import Block, RMSNorm
+        from edl_tpu.models.transformer import Block, RMSNorm, _remat
 
         self.cfg = cfg
         self.M = n_microbatches
         self.mesh = None  # bound by main() once the trainer exists
         self.embed = nn.Embed(cfg.vocab_size, cfg.embed_dim,
                               param_dtype=jnp.float32, dtype=cfg.dtype)
-        block_cls = Block
-        if cfg.remat:  # same remat policy as TransformerLM's stack
-            block_cls = nn.remat(
-                Block, prevent_cse=False,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        self.block = block_cls(cfg)
+        # TransformerLM's own remat policy (what it keeps for the
+        # backward pass is decided in one place)
+        self.block = (_remat(Block) if cfg.remat else Block)(cfg)
         self.norm = RMSNorm(cfg.dtype)
         self.head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                              param_dtype=jnp.float32)

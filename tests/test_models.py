@@ -178,3 +178,86 @@ def test_auto_layout_decisions():
     deep = auto_layout(TransformerConfig(num_layers=48), 8, 1024,
                        hbm_bytes=16.6e9)
     assert deep.scan_layers is True and deep.remat is True
+
+
+# the widths of the benchmark's two train configurations (benchmarks/
+# configs/): name, layers, embed, heads, kv heads, mlp, rows a chip
+_TRAIN_WIDTHS = {
+    "mistral-7b-d2": (2, 4096, 32, 8, 14336, 4),
+    "codestral-22b-d4": (4, 6144, 48, 8, 16384, 1),
+    "mistral-7b-d32": (32, 4096, 32, 8, 14336, 4),
+}
+
+
+def _train_cfg(name, **kw):
+    from edl_tpu.models.transformer import TransformerConfig
+    layers, embed, heads, kv, mlp, rows = _TRAIN_WIDTHS[name]
+    return TransformerConfig(
+        vocab_size=32768, num_layers=layers, embed_dim=embed,
+        num_heads=heads, num_kv_heads=kv, mlp_dim=mlp, max_len=4096,
+        **kw), rows
+
+
+def test_auto_layout_counts_what_attention_keeps():
+    """``_remat`` keeps the splash forward's ``out`` and logsumexp for
+    the backward pass; the estimate ``auto_layout`` decides from holds
+    them: 136 MB a layer at 4 x 4096 tokens of 32 heads of 128, 4.3 GB
+    at depth 32, on top of what it counted before."""
+    from edl_tpu.models.transformer import (
+        _ACT_VALS_PER_TOK_LAYER_EMBED, attention_backward_bytes,
+        attention_kept_bytes, param_count, train_bytes_estimate)
+    cfg, rows = _train_cfg("mistral-7b-d32")
+    before = (16 * param_count(cfg)
+              + 2 * rows * 4096 * 32 * 4096 * _ACT_VALS_PER_TOK_LAYER_EMBED
+              + 2 * 4 * rows * 4096 * 32768)
+    kept = attention_kept_bytes(cfg, rows, 4096)
+    assert kept == 32 * (4 * 4096 * 32 * 128 * 2 + 4 * 32 * 4096 * 4)
+    assert 4.3e9 < kept < 4.4e9
+    # and, once for the stack, the four partial dQs the fused backward
+    # of one layer writes (537 MB here)
+    partials = attention_backward_bytes(cfg, rows, 4096)
+    assert partials == 4 * (4 * 4096 * 32 * 128 * 2)
+    assert train_bytes_estimate(cfg, rows, 4096) - before == kept + partials
+
+
+def test_auto_layout_counts_attention_layers_only():
+    """A state-space layer runs no attention kernel and keeps nothing
+    of one."""
+    import dataclasses
+
+    from edl_tpu.models.transformer import attention_kept_bytes
+    cfg, rows = _train_cfg("mistral-7b-d2")
+    mixed = dataclasses.replace(cfg, layer_attn=("ssm", "global"),
+                                ssm_heads=64)
+    assert (2 * attention_kept_bytes(mixed, rows, 4096)
+            == attention_kept_bytes(cfg, rows, 4096))
+
+
+@pytest.mark.parametrize("stack,seq,partials", [
+    (dict(), 4096, 4), (dict(), 8192, 4), (dict(), 1024, 2),
+    (dict(), 2048, 0), (dict(attn_window=128), 4096, 0),
+    (dict(attn_window=128, layer_attn=("window", "global")), 4096, 4),
+    (dict(layer_attn=("ssm", "ssm"), ssm_heads=64), 4096, 0)],
+    ids=["4096", "8192", "1024", "unswept", "window", "non-uniform", "ssm"])
+def test_auto_layout_counts_the_fused_backwards_partials(stack, seq,
+                                                         partials):
+    """The buffer is there exactly where ``splash_block_sizes`` takes
+    the fused backward (a swept length, no window), for the one layer
+    whose backward is running."""
+    import dataclasses
+
+    from edl_tpu.models.transformer import attention_backward_bytes
+    cfg, rows = _train_cfg("mistral-7b-d2")
+    cfg = dataclasses.replace(cfg, max_len=seq, **stack)
+    assert (attention_backward_bytes(cfg, rows, seq)
+            == partials * rows * seq * 32 * 128 * 2)
+
+
+@pytest.mark.parametrize("name", ["mistral-7b-d2", "codestral-22b-d4"])
+def test_auto_layout_keeps_remat_on_for_the_train_cells(name):
+    """The two train configurations' shapes decide as they did: remat
+    on (the state alone is most of the chip), layers unrolled."""
+    from edl_tpu.models.transformer import auto_layout
+    cfg, rows = _train_cfg(name)
+    got = auto_layout(cfg, rows, 4096, hbm_bytes=16.9e9)
+    assert got.remat is True and got.scan_layers is False
