@@ -21,6 +21,7 @@ mesh from day one:
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -28,7 +29,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from edl_tpu.ops import decode_attention, ssm
+from edl_tpu.ops import decode_attention, kda, latent_attention, ssm
 from edl_tpu.ops.attention import (
     SPLASH_RESIDUALS, dot_product_attention, splash_partials_bytes,
 )
@@ -177,6 +178,29 @@ class TransformerConfig:
     # what a decode model carries the recurrent state in between calls
     # (the arithmetic on it is float32 either way)
     ssm_state_dtype: Any = jnp.float32
+    # -- layer_attn[i] == "kda" is a delta-rule linear-attention layer
+    # (``KDAMixer``, ``ops/kda.py``; a published ``linear_attn_config``):
+    # kda_heads heads with keys and values of kda_head_dim (also the
+    # width of the decay's and the gate's low-rank path), a causal
+    # depthwise convolution over kda_conv positions on q, k and v, the
+    # chunked form in chunks of kda_chunk, the state a decode model
+    # carries kept in kda_state_dtype
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    kda_state_dtype: Any = jnp.float32
+    # -- layer_attn[i] == "latent" is a multi-head latent attention layer
+    # (``LatentAttention``, ``ops/latent_attention.py``): num_heads
+    # heads, keys and values through one latent of mla_rank
+    # (``kv_lora_rank``) a token, mla_nope_dim key dims a head from it
+    # and mla_rope_dim more that all heads share, values of mla_v_dim;
+    # mla_rope rotates those shared dims (off: ``mla_use_nope``)
+    mla_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    mla_rope: bool = False
     # -- four scalars (Granite's): on the embedding, on both residual
     # branches, the attention scale in place of 1/sqrt(head_dim) (0 =
     # that default), and a divisor of the logits
@@ -232,9 +256,32 @@ class TransformerConfig:
     def softmax_scale(self) -> float:
         return self.attn_scale or self.head_dim ** -0.5
 
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_proj_dim(self) -> int:
+        """What a delta-rule layer's fused input projection gives: q, k
+        and v, the decay's and the gate's low-rank inputs, and beta."""
+        return 3 * self.kda_inner + 2 * self.kda_head_dim + self.kda_heads
+
+    @property
+    def mla_width(self) -> int:
+        """Values a latent layer caches a token: the latent and the
+        shared key dims."""
+        return self.mla_rank + self.mla_rope_dim
+
+    @property
+    def mla_row(self) -> int:
+        """The row a latent layer's decode cache keeps a token:
+        ``mla_width`` in whole lane tiles."""
+        return latent_attention.padded_width(self.mla_width)
+
     def __post_init__(self):
         for name, plan, kinds in (
-                ("layer_attn", self.layer_attn, ("window", "global", "ssm")),
+                ("layer_attn", self.layer_attn,
+                 ("window", "global", "ssm", "kda", "latent")),
                 ("layer_mlp", self.layer_mlp, ("dense", "sparse"))):
             if plan and (len(plan) != self.num_layers
                          or set(plan) - set(kinds)):
@@ -248,6 +295,19 @@ class TransformerConfig:
             raise ValueError(
                 f"a state-space layer needs ssm_heads ({self.ssm_heads}) in "
                 f"whole groups ({self.ssm_groups})")
+        if "kda" in self.layer_attn and (
+                self.kda_heads < 1 or self.kda_head_dim < 1
+                or self.kda_conv < 1 or self.kda_chunk < 1):
+            raise ValueError(
+                f"a delta-rule layer needs kda_heads ({self.kda_heads}), "
+                f"kda_head_dim, kda_conv and kda_chunk")
+        if "latent" in self.layer_attn and (
+                self.mla_rank < 1 or self.mla_nope_dim < 1
+                or self.mla_rope_dim < 1 or self.mla_v_dim < 1
+                or self.mla_rope_dim % 2):
+            raise ValueError(
+                f"a latent attention layer needs mla_rank ({self.mla_rank}), "
+                f"mla_nope_dim, mla_v_dim and an even mla_rope_dim")
         if "sparse" in self.layer_mlp and not self.moe_experts:
             raise ValueError("a sparse layer needs moe_experts")
         if self.moe_held and not 0 < self.moe_held <= self.moe_experts:
@@ -262,9 +322,18 @@ def _layer_matmul_params(cfg: TransformerConfig, experts: int,
     to); a shared expert counts whole either way."""
     D = cfg.embed_dim
     H, Hk, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    if cfg.attn_kind(layer) == "ssm":
+    kind = cfg.attn_kind(layer)
+    if kind == "ssm":
         attn = (D * (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads)
                 + cfg.ssm_inner * D)
+    elif kind == "kda":
+        attn = (D * cfg.kda_proj_dim + 2 * cfg.kda_head_dim * cfg.kda_inner
+                + cfg.kda_inner * D)
+    elif kind == "latent":
+        attn = (D * H * (cfg.mla_nope_dim + cfg.mla_rope_dim)
+                + D * cfg.mla_width
+                + cfg.mla_rank * H * (cfg.mla_nope_dim + cfg.mla_v_dim)
+                + H * cfg.mla_v_dim * D)
     else:
         attn = D * (H + 2 * Hk) * Dh + H * Dh * D
     if cfg.mlp_kind(layer) == "dense":
@@ -290,9 +359,15 @@ def param_count(cfg: TransformerConfig) -> int:
                + 3 * cfg.ssm_heads + cfg.ssm_inner
                + (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads
                   + D if cfg.ssm_proj_bias else 0))
+    # a delta-rule layer: the convolution, dt_bias, A_log and the output
+    # norm's scale; a latent layer: the latent's norm
+    own = {"ssm": 2 * D + ssm_own,
+           "kda": (2 * D + 3 * cfg.kda_inner * cfg.kda_conv + cfg.kda_inner
+                   + cfg.kda_heads + cfg.kda_head_dim),
+           "latent": 2 * D + cfg.mla_rank}
     return V * D + head + D + sum(
         _layer_matmul_params(cfg, held, i)
-        + (2 * D + ssm_own if cfg.attn_kind(i) == "ssm" else norms)
+        + own.get(cfg.attn_kind(i), norms)
         + (bias if cfg.mlp_kind(i) == "sparse" else 0)
         for i in range(cfg.num_layers))
 
@@ -357,7 +432,8 @@ def attention_kept_bytes(cfg: TransformerConfig, per_device_batch: int,
     ``_remat`` keeps both by name beside the matmuls' outputs, so they
     are held for every layer at once whether remat is on or off: 136 MB
     a layer at 4 x 4096 tokens of 32 heads of 128."""
-    layers = sum(cfg.attn_kind(i) != "ssm" for i in range(cfg.num_layers))
+    layers = sum(cfg.attn_kind(i) in ("window", "global")
+                 for i in range(cfg.num_layers))
     rows = per_device_batch * seq * cfg.num_heads
     return layers * rows * (cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
 
@@ -370,7 +446,7 @@ def attention_backward_bytes(cfg: TransformerConfig, per_device_batch: int,
     0.54 GB at 4 x 4096 tokens of 32 heads of 128."""
     windows = {cfg.attn_window if kind == "window" else 0
                for kind in map(cfg.attn_kind, range(cfg.num_layers))
-               if kind != "ssm"}
+               if kind in ("window", "global")}
     return max((splash_partials_bytes(
         per_device_batch, seq, cfg.num_heads, cfg.head_dim, w,
         jnp.dtype(cfg.dtype).itemsize) for w in windows), default=0)
@@ -462,6 +538,26 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
+def _short_conv(conv0, x, conv_w):
+    """A causal depthwise convolution over ``K = conv_w.shape[0]``
+    positions, FROM ``conv0 [B, K - 1, C]`` (the inputs before the
+    call's first position; a decode model's ``conv_state``): ``(the
+    float32 sums [B, L, C], the inputs it ran over [B, K - 1 + L, C],
+    window)`` with ``window(at [B])`` the ``K - 1`` inputs before
+    position ``at`` of the call: what a call that resumes there has to
+    be given as ``conv0``."""
+    K, L = conv_w.shape[0], x.shape[1]
+    xin = jnp.concatenate([conv0, x.astype(conv0.dtype)], axis=1)
+    acc = sum(xin[:, i:i + L].astype(jnp.float32) * conv_w[i]
+              for i in range(K))
+
+    def window(at):
+        idx = at[:, None] + jnp.arange(K - 1)[None, :]
+        return jnp.take_along_axis(xin, idx[:, :, None], axis=1)
+
+    return acc, xin, window
+
+
 class Mamba2Mixer(nn.Module):
     """A Mamba-2 token mixer (``ops/ssm.py`` has the recurrence): with
     ``y`` the layer's normed input,
@@ -528,16 +624,10 @@ class Mamba2Mixer(nn.Module):
                   if cached and token_mask is not None else None)
 
         with jax.named_scope("ssm/conv"):
-            xin = jnp.concatenate([conv0, xBC.astype(cfg.dtype)], axis=1)
-            acc = sum(xin[:, i:i + L].astype(f32) * conv_w[i]
-                      for i in range(K))
+            acc, xin, window = _short_conv(conv0, xBC, conv_w)
             if conv_b is not None:
                 acc = acc + conv_b
             xBC = nn.silu(acc)
-
-            def window(at):     # the K - 1 inputs before position ``at``
-                idx = at[:, None] + jnp.arange(K - 1)[None, :]
-                return jnp.take_along_axis(xin, idx[:, :, None], axis=1)
         x, Bm, Cm = jnp.split(xBC, [Di, Di + G * N], axis=-1)
         x = x.reshape(B, L, H, P)
         Bm, Cm = Bm.reshape(B, L, G, N), Cm.reshape(B, L, G, N)
@@ -585,6 +675,230 @@ class Mamba2Mixer(nn.Module):
             return nn.Dense(cfg.embed_dim, use_bias=cfg.ssm_proj_bias,
                             dtype=cfg.dtype, param_dtype=f32,
                             name="out_proj")(u)
+
+
+class KDAMixer(nn.Module):
+    """A delta-rule linear-attention token mixer (Kimi delta attention;
+    ``ops/kda.py`` has the recurrence): with ``y`` the layer's normed
+    input and ``R = kda_head_dim``,
+
+    ``[q | k | v | f | z | b] = y W_in`` (one fused projection: ``f`` and
+    ``z`` the ``R``-wide inputs of the decay's and the gate's low-rank
+    paths, ``b`` a head's beta logit); ``q | k | v`` through a causal
+    depthwise convolution over ``kda_conv`` positions (no bias) and a
+    SiLU; ``q``, ``k`` L2-normalised a head and ``q`` scaled by
+    ``R ** -0.5``; ``g = -exp(A_log) * softplus(f W_f + dt_bias)`` a key
+    channel, ``beta = sigmoid(b)``; the recurrence;
+    ``RMSNorm_head(o) * sigmoid(z W_g)``; ``W_out``.
+
+    In a decode model its ``cache`` is ``conv_state [B, kda_conv - 1, 3
+    H R]`` (time-major, the compute dtype), ``kda_state [B, H, R, R]``
+    (``kda_state_dtype``) and ``cache_index``: ``Mamba2Mixer``'s
+    contract to the letter (one-token calls update in place,
+    ``ops/kda.kda_step`` on the chip, free slots keep theirs; multi-token
+    calls run the chunked form FROM the cached state to each lane's last
+    REAL token; ``snap_at`` sows the state at one more position into the
+    ``snap`` collection under the cache's names)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, y, token_mask=None, snap_at=None):
+        cfg = self.cfg
+        f32 = jnp.float32
+        H, R, K = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+        Di = cfg.kda_inner
+        B, L = y.shape[:2]
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=f32, name=name)
+
+        with jax.named_scope("kda/proj_in"):
+            qkv, f, z, b = jnp.split(
+                dense(cfg.kda_proj_dim, "in_proj")(y),
+                [3 * Di, 3 * Di + R, 3 * Di + 2 * R], axis=-1)
+            f = dense(Di, "f_proj")(f)
+        conv_w = self.param("conv_w", nn.initializers.normal(K ** -0.5),
+                            (K, 3 * Di), f32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (Di,), f32)
+        A = -jnp.exp(self.param("A_log", _a_log_init, (H,), f32))
+
+        cached = cfg.decode and self.has_variable("cache", "kda_state")
+        if cfg.decode:
+            conv_v = self.variable("cache", "conv_state", jnp.zeros,
+                                   (B, K - 1, 3 * Di), cfg.dtype)
+            state_v = self.variable("cache", "kda_state", jnp.zeros,
+                                    (B, H, R, R), cfg.kda_state_dtype)
+            ci = self.variable("cache", "cache_index",
+                               lambda: jnp.zeros((B,), jnp.int32))
+        conv0 = (conv_v.value if cached
+                 else jnp.zeros((B, K - 1, 3 * Di), cfg.dtype))
+        state0 = (state_v.value.astype(f32) if cached
+                  else jnp.zeros((B, H, R, R), f32))
+        kept = cfg.kda_state_dtype
+        n_real = (token_mask.sum(-1).astype(jnp.int32)
+                  if cached and token_mask is not None else None)
+
+        with jax.named_scope("kda/conv"):
+            acc, xin, window = _short_conv(conv0, qkv, conv_w)
+            qkv = nn.silu(acc)
+            q, k, v = (a.reshape(B, L, H, R)
+                       for a in jnp.split(qkv, 3, axis=-1))
+            q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6
+                                  ) * R ** -0.5
+            k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        g = (jax.nn.softplus(f.astype(f32) + dt_bias).reshape(B, L, H, R)
+             * A[:, None])
+        beta = jax.nn.sigmoid(b.astype(f32))                     # [B, L, H]
+
+        if cached and L == 1:
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            kernel = kept == f32 and kda.applies(L, cfg.mesh)
+            step = kda.kda_step if kernel else kda.kda_step_reference
+            with jax.named_scope("kda/step"):
+                o, new = step(state0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                              beta[:, 0], live)
+            # slot states this update read and wrote: the engine's
+            # ``ssm_state_steps_run`` counts any state layer's
+            self.sow("intermediates", "ssm_slots_run",
+                     kda.slots_fetched(live, H, R, R) if kernel
+                     else jnp.asarray(B, f32))
+            o, state_v.value = o[:, None], new.astype(kept)
+            conv_v.value = jnp.where(live[:, None, None], xin[:, 1:], conv0)
+        else:
+            o, final, snap = kda.kda_chunked(
+                q, k, v, g, beta, state0, chunk=cfg.kda_chunk,
+                lengths=n_real, snap_at=snap_at if cached else None)
+            if cached:
+                state_v.value = final.astype(kept)
+                conv_v.value = window(
+                    jnp.full((B,), L, jnp.int32) if n_real is None
+                    else n_real)
+                if snap is not None:
+                    at = jnp.clip(snap_at.astype(jnp.int32), 0,
+                                  L if n_real is None else n_real)
+                    # named as the cache names them
+                    self.sow("snap", "kda_state", snap.astype(kept))
+                    self.sow("snap", "conv_state", window(at))
+        if cached:
+            ci.value = ci.value + L
+        with jax.named_scope("kda/gate_norm"):
+            gate = jax.nn.sigmoid(dense(Di, "g_proj")(z).astype(f32))
+            u = (RMSNorm(cfg.dtype, cfg.norm_eps, name="o_norm")(o).astype(f32)
+                 .reshape(B, L, Di) * gate).astype(cfg.dtype)
+        with jax.named_scope("kda/proj_out"):
+            return dense(cfg.embed_dim, "o_proj")(u)
+
+
+class LatentAttention(nn.Module):
+    """A multi-head latent attention mixer (``ops/latent_attention.py``
+    has the two paths and the kernels): with ``y`` the normed input,
+
+    ``q = y W_q`` -> ``[H, nope + rope]``; ``[c' | k_pe] = y W_kva``;
+    ``c = RMSNorm(c')``; ``[k_nope | v]_h = W_kvb[:, h]^T c``; scores
+    ``(q_nope . k_nope + q_pe . k_pe) / sqrt(nope + rope)``, causal,
+    float32 softmax; ``W_o``.  ``q_pe`` and ``k_pe`` are rotated only
+    with ``mla_rope``.
+
+    In a decode model the ``cache`` is ``cached_latent [B, max_len,
+    mla_row]``: ONE row ``c | k_pe`` a token (zeros up to whole lane
+    tiles), after the norm, no head axis, keys and values the same
+    bytes; and ``cache_index``.  A one-token call appends a row and
+    attends on the absorbed path (on the chip ``latent_append`` and
+    ``latent_attend``, which read live positions of live slots once;
+    slots that ``token_mask`` marks free are neither written nor read);
+    a multi-token call writes its rows at the (batch-uniform) index and
+    attends the slab on the expanded path under the position mask."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, y, positions, token_mask=None):
+        cfg = self.cfg
+        H, rank = cfg.num_heads, cfg.mla_rank
+        nope, rope_d, vd = cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim
+        B, L = y.shape[:2]
+        scale = cfg.attn_scale or (nope + rope_d) ** -0.5
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, name=name)
+
+        q = dense(H * (nope + rope_d), "q_proj")(y).reshape(
+            B, L, H, nope + rope_d)
+        ckv = dense(cfg.mla_width, "kv_a")(y)
+        c = RMSNorm(cfg.dtype, cfg.norm_eps, name="kv_norm")(
+            ckv[..., :rank]).astype(cfg.dtype)
+        k_pe = ckv[..., rank:]
+        if cfg.mla_rope:
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], positions,
+                                     cfg.rope_theta)], axis=-1)
+            k_pe = rope(k_pe[:, :, None], positions, cfg.rope_theta)[:, :, 0]
+        latent = jnp.concatenate([c, k_pe], axis=-1)             # [B, L, W]
+        w_kvb = self.param("kv_b", nn.initializers.lecun_normal(),
+                           (rank, H * (nope + vd)), jnp.float32
+                           ).reshape(rank, H, nope + vd)
+        attend = functools.partial(latent_attention.expanded_attention,
+                                   rank=rank, nope=nope, scale=scale)
+        i = jnp.arange(L)
+        causal = jnp.broadcast_to(i[None, :] <= i[:, None], (B, L, L))
+        with jax.named_scope("attn/latent"):
+            if not cfg.decode:
+                out = attend(q, latent, w_kvb, causal)
+            else:
+                out = self._cached(q, latent, w_kvb, token_mask, attend,
+                                   causal, scale)
+        return dense(cfg.embed_dim, "o_proj")(out.reshape(B, L, H * vd))
+
+    def _cached(self, q, latent, w_kvb, token_mask, attend, causal, scale):
+        cfg = self.cfg
+        B, L = q.shape[:2]
+        T, row = cfg.max_len, cfg.mla_row
+        is_initialized = self.has_variable("cache", "cached_latent")
+        cl = self.variable("cache", "cached_latent", jnp.zeros,
+                           (B, T, row), cfg.dtype)
+        ci = self.variable("cache", "cache_index",
+                           lambda: jnp.zeros((B,), jnp.int32))
+        if not is_initialized:      # init trace: shapes only
+            return attend(q, latent, w_kvb, causal)
+        idx = ci.value                                           # [B]
+        rows = latent_attention.cache_rows(latent, row, cfg.dtype)
+        ci.value = idx + L
+        if L == 1:
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            kernel = latent_attention.applies(L, cfg.mesh, T)
+            lengths = jnp.where(live, jnp.minimum(idx + 1, T), 0)
+            q_lat = latent_attention.absorb(q[:, 0], w_kvb,
+                                            nope=cfg.mla_nope_dim, width=row)
+            if kernel:
+                cl.value = latent_attention.latent_append(
+                    cl.value, rows[:, 0], idx, live)
+                o_lat = latent_attention.latent_attend(
+                    q_lat, cl.value, lengths, scale=scale)
+            else:
+                cl.value = latent_attention.latent_append_reference(
+                    cl.value, rows[:, 0], idx, live)
+                o_lat = latent_attention.latent_attend_reference(
+                    q_lat, cl.value, lengths, scale=scale)
+            # positions this read fetched of the slots' rows, for the
+            # engine's ``latent_tokens_read``
+            self.sow("intermediates", "latent_tokens_read",
+                     latent_attention.tokens_fetched(
+                         lengths, row, T, cfg.dtype, kernel))
+            return latent_attention.unabsorb(
+                o_lat, w_kvb, rank=cfg.mla_rank, nope=cfg.mla_nope_dim
+            )[:, None].astype(q.dtype)
+        # contiguous rows at a batch-uniform index (a prefill, a chunk,
+        # a reuse suffix: ``Block._decode_attention``'s contract)
+        cl.value = jax.lax.dynamic_update_slice(cl.value, rows,
+                                                (0, idx[0], 0))
+        q_pos = idx[:, None] + jnp.arange(L)                     # [B, L]
+        mask = jnp.arange(T)[None, None, :] <= q_pos[:, :, None]
+        return attend(q, cl.value, w_kvb, mask)
 
 
 class Block(nn.Module):
@@ -866,6 +1180,10 @@ class Block(nn.Module):
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
         if kind == "ssm":
             mixed = Mamba2Mixer(cfg, name="ssm")(y, token_mask, snap_at)
+        elif kind == "kda":
+            mixed = KDAMixer(cfg, name="kda")(y, token_mask, snap_at)
+        elif kind == "latent":
+            mixed = LatentAttention(cfg, name="mla")(y, positions, token_mask)
         else:
             mixed = self._attention(y, positions, token_mask, kind)
         x = _pin(cfg, _residual(cfg, x, mixed), "batch", "seq", None)
@@ -928,7 +1246,8 @@ class TransformerLM(nn.Module):
         not consume expert capacity; ops/moe.py compute_routing); in a
         decode model real tokens lead, and a state-space layer's state
         stops at the last of them.  ``snap_at`` ([B] int, decode models)
-        is handed to the state-space layers (``Mamba2Mixer``)."""
+        is handed to the layers that keep a recurrence (``Mamba2Mixer``,
+        ``KDAMixer``)."""
         cfg = self.cfg
         del train
         if positions is None:

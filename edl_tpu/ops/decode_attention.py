@@ -210,6 +210,18 @@ def _fetch_plan(lengths, tk: int):
     return src, lo.astype(jnp.int32), hi.astype(jnp.int32)
 
 
+def blocks_fetched(src, lo, hi, nb: int):
+    """Blocks a grid over (slots, ``nb`` blocks) fetches under a plan
+    ``(src, lo, hi)`` (:func:`_fetch_plan`'s shape), float32: the
+    pipeline fetches a block when a grid step names another than the
+    step before it, so the count is the changes of block along the
+    grid, its first step included."""
+    j = jnp.arange(nb, dtype=jnp.int32)[None]
+    named = (src[:, None] * nb
+             + jnp.clip(j, lo[:, None], hi[:, None])).reshape(-1)
+    return (1 + jnp.sum(named[1:] != named[:-1])).astype(jnp.float32)
+
+
 def attend_block(Hk: int, D: int, max_len: int, dtype) -> int:
     """Time steps a grid step of the attend kernel reads: the largest
     power-of-two multiple of 128 that divides ``max_len`` and keeps one

@@ -1,0 +1,317 @@
+"""Multi-head latent attention over a head-less cache: the expanded path,
+the absorbed path, and the one-token kernels.
+
+A latent layer caches, a token, ONE row ``(c, k_pe)`` of ``rank + rope``
+values (``c`` the normed low-rank key/value latent, ``k_pe`` the few
+extra key dims every head shares; with no rotation they are just more
+dims): no head axis, keys and values the same bytes.  With ``W_kvb``
+``[rank, H, nope + v]`` = ``W_K | W_V`` the attention of head h is::
+
+    k_nope_h, v_h = W_K[:, h]^T c, W_V[:, h]^T c
+    score_h = (q_nope_h . k_nope_h + q_pe_h . k_pe) * scale
+    o_h     = sum_t softmax(score_h)_t v_h,t
+
+Two ways to compute it, equal in exact arithmetic:
+
+- **expanded** (:func:`expanded_attention`, scope ``attn/latent_expand``)
+  - ``k_nope | v`` are computed from the latent rows and plain attention
+  runs over them: multi-token calls (a full forward, a prefill, a chunk
+  against the slot's rows and its own).  Heads run in groups so that one
+  group's float32 scores stay within ``_SCORE_BYTES`` whatever the
+  slab's length is.
+- **absorbed** (:func:`absorb` / :func:`unabsorb`, scope
+  ``attn/latent_absorb``) - ``W_K`` moves onto the query (``q~_h =
+  W_K[:, h] q_nope_h``, ``rank`` wide) and ``W_V`` onto the output
+  (``o_h = W_V[:, h]^T sum p c``), so the scores and the value sum read
+  the latent rows as they lie, once for all heads: one-token steps.  On
+  the chip two Pallas kernels run it in place: :func:`latent_append`
+  (one row a live slot through one aliased sublane tile) and
+  :func:`latent_attend` (flash-decoding over the rows: the grid over
+  slots and position tiles, a tile fetched ONCE for all heads' scores
+  and the value sum; lengths scalar-prefetched, so a tile past a slot's
+  length and a free slot fetch nothing:
+  ``ops/decode_attention._fetch_plan``).  :func:`latent_attend_reference`
+  is the same as einsums over the whole slab: every other backend's path
+  and the kernels' parity reference.
+
+:func:`applies` is the dispatch rule, from what the caller can observe
+and nothing else.  Precision is the slabs' (``ops/decode_attention``):
+input-dtype matmuls accumulated in float32, scores and softmax
+statistics in float32, probabilities cast to the cache dtype before the
+second matmul.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from edl_tpu.ops import decode_attention
+from edl_tpu.ops.decode_attention import (
+    _NEG, _VMEM_LIMIT, _fetch_plan, _interpret, _sublanes,
+)
+
+_LANES = 128
+# bytes of one tile of latent rows of the attend kernel (double buffered)
+_BLOCK_BYTES = 1 << 20
+# float32 scores one head group of the expanded path may hold
+_SCORE_BYTES = 256 << 20
+
+
+def applies(L: int, mesh, max_len: int) -> bool:
+    """Whether a call takes the kernels (``ops/decode_attention.
+    applies``: a one-token step on a TPU, no mesh, a lane-tiled time
+    axis)."""
+    return decode_attention.applies(L, mesh, max_len)
+
+
+def padded_width(width: int) -> int:
+    """The row a latent cache keeps for ``width`` values: whole lane
+    tiles (576 -> 640).  The device's tiled layout pads the minor
+    dimension to that in any case; kept explicit, the kernels' blocks
+    and matmuls are aligned and the bytes are the same."""
+    return -(-width // _LANES) * _LANES
+
+
+def cache_rows(latent, row: int, dtype):
+    """``latent [..., width]`` as the cache keeps it: the cache's dtype,
+    zeros up to ``row`` values."""
+    pad = [(0, 0)] * (latent.ndim - 1) + [(0, row - latent.shape[-1])]
+    return jnp.pad(latent.astype(dtype), pad)
+
+
+# -- expanded ---------------------------------------------------------------
+
+def _head_groups(H: int, L: int, T: int) -> int:
+    """Groups the expanded path's heads run in: the fewest that keep one
+    group's ``[H / groups, L, T]`` float32 scores (``L`` over all lanes) within
+    ``_SCORE_BYTES``."""
+    for groups in range(1, H + 1):
+        if H % groups == 0 and (H // groups) * L * T * 4 <= _SCORE_BYTES:
+            return groups
+    return H
+
+
+def expanded_attention(q, latent, w_kvb, mask, *, rank: int, nope: int,
+                       scale: float):
+    """``q [B, L, H, nope + rope]``, ``latent [B, T, >= rank + rope]``
+    (``c | k_pe`` leading each row), ``w_kvb [rank, H, nope + v]``,
+    ``mask [B, L, T]`` bool (what each query may see).  Returns ``[B, L,
+    H, v]`` in ``q``'s dtype."""
+    B, L, H, Dq = q.shape
+    T = latent.shape[1]
+    rope = Dq - nope
+    groups = _head_groups(H, B * L, T)
+    hg = H // groups
+    c = latent[..., :rank]
+    k_pe = latent[..., rank:rank + rope]
+
+    def group(args):
+        qg, wg = args                          # [B, L, hg, Dq], [rank, hg, :]
+        with jax.named_scope("attn/latent_expand"):
+            kv = jnp.einsum("btc,chd->bthd", c, wg.astype(c.dtype))
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        s = (jnp.einsum("blhd,bthd->bhlt", qg[..., :nope], k_nope)
+             + jnp.einsum("blhd,btd->bhlt", qg[..., nope:], k_pe)
+             ).astype(jnp.float32) * scale
+        s = jnp.where(mask[:, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhlt,bthd->blhd", p, v)
+
+    if groups == 1:
+        return group((q, w_kvb))
+    qs = jnp.moveaxis(q.reshape(B, L, groups, hg, Dq), 2, 0)
+    ws = jnp.moveaxis(w_kvb.reshape(rank, groups, hg, -1), 1, 0)
+    out = jax.lax.map(group, (qs, ws))         # [groups, B, L, hg, v]
+    return jnp.moveaxis(out, 0, 2).reshape(B, L, H, -1)
+
+
+# -- absorbed ---------------------------------------------------------------
+
+def absorb(q, w_kvb, *, nope: int, width: int):
+    """``q [B, H, nope + rope]`` -> the query against latent rows, ``[B,
+    H, width]``: ``W_K[:, h] q_nope_h | q_pe_h | 0``."""
+    with jax.named_scope("attn/latent_absorb"):
+        q_lat = jnp.einsum("bhd,chd->bhc", q[..., :nope],
+                           w_kvb[..., :nope].astype(q.dtype))
+    full = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+    return jnp.pad(full, ((0, 0), (0, 0), (0, width - full.shape[-1])))
+
+
+def unabsorb(o_lat, w_kvb, *, rank: int, nope: int):
+    """``o_lat [B, H, >= rank]`` (the probabilities' sum of latent rows)
+    -> ``[B, H, v]``: ``W_V[:, h]^T`` of its first ``rank`` values."""
+    with jax.named_scope("attn/latent_absorb"):
+        return jnp.einsum("bhc,chd->bhd", o_lat[..., :rank],
+                          w_kvb[..., nope:].astype(o_lat.dtype))
+
+
+def latent_attend_reference(q_lat, latent, lengths, *, scale: float):
+    """One absorbed query a head, ``q_lat [B, H, W]``, against the first
+    ``lengths[b]`` rows of ``latent [B, T, W]``: ``[B, H, W]`` in
+    ``q_lat``'s dtype, the probabilities' sum of the rows.  A slot of
+    length 0 returns zeros.  Reads every slot's slab whole."""
+    T = latent.shape[1]
+    s = jnp.einsum("bhw,btw->bht", q_lat, latent).astype(jnp.float32) * scale
+    seen = jnp.arange(T)[None, :] < lengths[:, None]
+    s = jnp.where(seen[:, None], s, _NEG)
+    p = jnp.where(seen[:, None], jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    l = p.sum(-1, keepdims=True)
+    acc = jnp.einsum("bht,btw->bhw", p.astype(latent.dtype), latent
+                     ).astype(jnp.float32)
+    return (acc / jnp.where(l > 0, l, 1.0)).astype(q_lat.dtype)
+
+
+def latent_append_reference(latent, row, index, live):
+    """``row [B, W]`` at position ``index[b]`` of slot b's rows; slots
+    that are free, or at or past the slab's end, keep theirs."""
+    B, T, _ = latent.shape
+    on = live & (index < T)
+    at = jnp.clip(index, 0, T - 1)
+    old = latent[jnp.arange(B), at]
+    return latent.at[jnp.arange(B), at].set(
+        jnp.where(on[:, None], row.astype(latent.dtype), old))
+
+
+# -- the kernels ------------------------------------------------------------
+
+def _append_kernel(idx_ref, on_ref, lat_ref, new_ref, out_ref, *, rows: int):
+    b = pl.program_id(0)
+    at, on = idx_ref[b], on_ref[b] != 0
+    row = jax.lax.broadcasted_iota(jnp.int32, lat_ref.shape, 0)
+    out_ref[...] = jnp.where((row == at % rows) & on,
+                             jnp.broadcast_to(new_ref[...], lat_ref.shape),
+                             lat_ref[...])
+
+
+def latent_append(latent, row, index, live, *, interpret=None):
+    """:func:`latent_append_reference` as one Pallas call, in place
+    (donate or carry ``latent``: it is aliased in and out): one sublane
+    tile a slot, chosen by the scalar-prefetched index."""
+    B, T, W = latent.shape
+    rows = _sublanes(latent.dtype)
+    on = (live & (index < T)).astype(jnp.int32)
+    at = jnp.clip(index, 0, T - 1).astype(jnp.int32)
+    spec = pl.BlockSpec((None, rows, W), lambda b, at, on: (b, at[b] // rows,
+                                                            0))
+    return pl.pallas_call(
+        functools.partial(_append_kernel, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[spec, pl.BlockSpec((None, 1, W),
+                                         lambda b, at, on: (b, 0, 0))],
+            out_specs=spec),
+        out_shape=jax.ShapeDtypeStruct(latent.shape, latent.dtype),
+        # operands count the two prefetched scalars
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(interpret),
+        name="latent_append",
+    )(at, on, latent, row.astype(latent.dtype)[:, None, :])
+
+
+def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, q_ref, lat_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, tk: int, scale: float):
+    b, j = pl.program_id(0), pl.program_id(1)
+    n = len_ref[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j * tk < n)
+    def _():
+        q, lat = q_ref[...], lat_ref[...]
+        # [Hp, W] x [tk, W]^T -> [Hp, tk], f32: the tile serves every head
+        s = jax.lax.dot_general(
+            q, lat, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < n, s, _NEG)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        # a tile that runs holds a live position, so m_new is a real
+        # score and the masked tail's exp(_NEG - m_new) is exactly 0
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        # [Hp, tk] x [tk, W] -> [Hp, W]: the same tile as the values
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]      # 0 for a free slot, whose acc is 0 too
+        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def attend_block(W: int, max_len: int, dtype) -> int:
+    """Positions a grid step of the attend kernel reads: the largest
+    power-of-two multiple of 128 that divides ``max_len`` and keeps one
+    tile of rows within ``_BLOCK_BYTES``."""
+    tk = _LANES
+    while (max_len % (2 * tk) == 0
+           and 2 * tk * W * jnp.dtype(dtype).itemsize <= _BLOCK_BYTES):
+        tk *= 2
+    return tk
+
+
+def latent_attend(q_lat, latent, lengths, *, scale: float,
+                  block: int | None = None, interpret=None):
+    """:func:`latent_attend_reference` as one Pallas call: a slot's rows
+    are read up to its length, in tiles, each tile once; a slot of
+    length 0 reads nothing and returns zeros."""
+    B, H, W = q_lat.shape
+    T = latent.shape[1]
+    tk = block or attend_block(W, T, latent.dtype)
+    assert T % tk == 0, (T, tk)
+    # the head axis is the matmuls' row axis: pad it to a sublane tile
+    Hp = -(-H // _sublanes(q_lat.dtype)) * _sublanes(q_lat.dtype)
+    qp = jnp.pad(q_lat, ((0, 0), (0, Hp - H), (0, 0)))
+    lengths = lengths.astype(jnp.int32)
+    src, lo, hi = _fetch_plan(lengths, tk)
+
+    def lat_index(b, j, n, src, lo, hi):
+        return src[b], jnp.clip(j, lo[b], hi[b]), 0
+
+    q_spec = pl.BlockSpec((None, Hp, W), lambda b, *_: (b, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_attend_kernel, tk=tk, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, T // tk),
+            in_specs=[q_spec, pl.BlockSpec((None, tk, W), lat_index)],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((Hp, 1), jnp.float32),
+                            pltpu.VMEM((Hp, 1), jnp.float32),
+                            pltpu.VMEM((Hp, W), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, W), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(interpret),
+        name="latent_attend",
+    )(lengths, src, lo, hi, qp, latent)
+    return out[:, :H]
+
+
+def tokens_fetched(lengths, W: int, max_len: int, dtype, kernel: bool):
+    """Positions one attend call fetches of the slots' rows, float32: on
+    the kernels' path whole tiles up to each live slot's length (COUNTED
+    from the fetch plan: the changes of tile along the grid, as
+    ``ops/ssm.slots_fetched`` counts blocks), on the einsum path every
+    slot's slab."""
+    if not kernel:
+        return jnp.asarray(lengths.shape[0] * max_len, jnp.float32)
+    tk = attend_block(W, max_len, dtype)
+    plan = _fetch_plan(lengths.astype(jnp.int32), tk)
+    return decode_attention.blocks_fetched(*plan, max_len // tk) * tk

@@ -53,7 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from edl_tpu.ops.attention import _on_tpu
-from edl_tpu.ops.decode_attention import _fetch_plan
+from edl_tpu.ops.decode_attention import _fetch_plan, blocks_fetched
 
 # bytes of one state block of the step kernel: in and out, double
 # buffered, is four of these in VMEM
@@ -270,10 +270,7 @@ def slots_fetched(live, H: int, P: int, N: int, G: int = 1):
     live."""
     nb = H // step_block(H, P, N, G)
     _, _, src, lo, hi = _step_plan(live, nb)
-    j = jnp.arange(nb, dtype=jnp.int32)[None]
-    named = (src[:, None] * nb
-             + jnp.clip(j, lo[:, None], hi[:, None])).reshape(-1)
-    return (1 + jnp.sum(named[1:] != named[:-1])).astype(jnp.float32) / nb
+    return blocks_fetched(src, lo, hi, nb) / nb
 
 
 def ssm_step(state, x, dt, A, Bv, Cv, live, *, interpret=None):

@@ -199,6 +199,14 @@ def _ssm_slots_run(intermediates) -> "jax.Array":
     return _sown_sum(intermediates, "ssm_slots_run")
 
 
+def _latent_read(intermediates) -> "jax.Array":
+    """Positions the one-token reads of a program's latent attention
+    layers fetched of the slots' rows (``LatentAttention`` sows
+    ``latent_tokens_read``: on the chip counted from the kernel's fetch
+    plan), summed over the layers."""
+    return _sown_sum(intermediates, "latent_tokens_read")
+
+
 def _moe_fetched(intermediates) -> "jax.Array":
     """Expert weight sets the decode kernel of a program's expert
     layers fetched (``MoEMLP`` sows ``moe_fetched`` where ``ops/moe.
@@ -295,6 +303,7 @@ class _Tick:
     moe: object = None
     fetched: object = None
     ssm: object = None
+    latent: object = None
     counts: object = None
     pres: list = dataclasses.field(default_factory=list)
     # programs enqueued up to the one this tick's read waits for: read,
@@ -380,11 +389,17 @@ class ContinuousBatcher:
         self._ring_layers = frozenset(
             f"layer_{i}" for i in range(cfg.num_layers)
             if cfg.attn_kind(i) == "window")
-        # -- state-space layers (transformer.Mamba2Mixer): a slot holds
-        # a fixed-size recurrence, whatever max_len is
+        # -- state-space and delta-rule layers (transformer.Mamba2Mixer,
+        # KDAMixer): a slot holds a fixed-size recurrence, whatever
+        # max_len is
         self._state_layers = frozenset(
             f"layer_{i}" for i in range(cfg.num_layers)
-            if cfg.attn_kind(i) == "ssm")
+            if cfg.attn_kind(i) in ("ssm", "kda"))
+        # -- latent attention layers (transformer.LatentAttention): a
+        # slot holds one head-less row a token
+        self._latent_layers = frozenset(
+            f"layer_{i}" for i in range(cfg.num_layers)
+            if cfg.attn_kind(i) == "latent")
         k = constants.SPEC_K if spec_k is None else int(spec_k)
         if self._state_layers and k > 0:
             raise ValueError(
@@ -397,6 +412,17 @@ class ContinuousBatcher:
                 "a mesh engine does not serve a state-space configuration: "
                 "the state layers' slot state and snapshot pool have no "
                 "sharding yet (serving/kv_cache.py)")
+        if self._latent_layers and k > 0:
+            raise ValueError(
+                "speculative decoding (spec_k > 0) does not serve a "
+                "latent attention configuration: the verify step writes "
+                "each slot's candidates at its own index, and the latent "
+                "layers' multi-token path writes at one")
+        if self._latent_layers and mesh is not None:
+            raise ValueError(
+                "a mesh engine does not serve a latent attention "
+                "configuration: a latent row has no head axis to shard "
+                "over tp (serving/kv_cache.py)")
         ring = 0
         if self._ring_layers:
             if k > 0:
@@ -488,13 +514,14 @@ class ContinuousBatcher:
 
         def cls_of(name):
             return ("window" if name in self._ring_layers else
-                    "state" if name in self._state_layers else "global")
+                    "state" if name in self._state_layers else
+                    "latent" if name in self._latent_layers else "global")
 
         self._slot_bytes = {
             cls: sum(leaf.size * leaf.dtype.itemsize
                      for name, node in one_lane.items() if cls_of(name) == cls
                      for leaf in jax.tree.leaves(node) if leaf.ndim > 1)
-            for cls in ("window", "global", "state")}
+            for cls in ("window", "global", "state", "latent")}
         # what one window layer's decode read fetches of a slot that
         # holds n ring positions: whole attend blocks on the kernels'
         # path, the ring on the einsum path
@@ -530,19 +557,22 @@ class ContinuousBatcher:
             self._kv = PagedKVCache(
                 one_lane, kv_block, pool_blocks, sessions, mesh=mesh,
                 ring_layers=self._ring_layers, window=cfg.attn_window,
-                n_snaps=n_snaps, state_layers=self._state_layers)
+                n_snaps=n_snaps, state_layers=self._state_layers,
+                latent_layers=self._latent_layers)
         slab0 = max(jax.tree.leaves(self._cache), key=lambda x: x.ndim)
         pool0 = (jax.tree.leaves(self._kv.pool)[0]
                  if self._kv is not None else None)
         logger.info(
             "kv cache: %d slots x %d tokens (%s, slab sharding %s); pool "
             "%d blocks of %d (sharding %s); a slot holds %d bytes in %d "
-            "window layers (ring %d), %d in global layers and %d in %d "
-            "state-space layers; %d layer-state snapshots", slots, cache_len,
+            "window layers (ring %d), %d in global layers, %d in %d latent "
+            "layers and %d in %d state layers; %d layer-state snapshots",
+            slots, cache_len,
             slab0.dtype.name, mesh and slab0.sharding.spec, pool_blocks,
             kv_block, mesh and pool0 is not None and pool0.sharding.spec,
             self._slot_bytes["window"], len(self._ring_layers), ring,
-            self._slot_bytes["global"], self._slot_bytes["state"],
+            self._slot_bytes["global"], self._slot_bytes["latent"],
+            len(self._latent_layers), self._slot_bytes["state"],
             len(self._state_layers), n_snaps)
         self._kv_hits = 0
         self._kv_misses = 0
@@ -588,6 +618,10 @@ class ContinuousBatcher:
         # its read fetched, and the positions its window holds
         self._kv_window_read = 0
         self._kv_window_need = 0
+        # latent layers: positions their decode reads needed (a live
+        # slot's length, a token step and layer) and fetched
+        self._latent_live = 0
+        self._latent_read = 0.0
         # state-space layers: (slot, token step, layer) states the step
         # programs updated for live slots, and all they read and wrote;
         # positions the prefill and chunk programs ran through the scan,
@@ -961,6 +995,16 @@ class ContinuousBatcher:
                 # through the scan, and those that were padding (bucket
                 # padding, masked: a scan cannot skip them for free)
                 "kv_slot_bytes_state": self._slot_bytes["state"],
+                # latent attention layers (0s without one): the bytes of
+                # one slot's rows (one row a token a layer, no head
+                # axis); per token step, live slot and layer, positions
+                # the decode read needed (the slot's length) and
+                # positions it fetched (whole tiles up to the length on
+                # the chip, counted from the kernel's fetch plan; the
+                # slab of every slot on the einsum path)
+                "kv_slot_bytes_latent": self._slot_bytes["latent"],
+                "latent_tokens_live": self._latent_live,
+                "latent_tokens_read": self._latent_read,
                 "ssm_state_steps": self._ssm_steps,
                 "ssm_state_steps_run": self._ssm_steps_run,
                 "ssm_prefill_positions": self._ssm_prefill_pos,
@@ -1216,7 +1260,8 @@ class ContinuousBatcher:
         def pool_bytes(n):
             return (pool_device_bytes(one_lane, kv_block, pool_blocks, tp,
                                       self._ring_layers, self.cfg.attn_window,
-                                      n, self._state_layers)
+                                      n, self._state_layers,
+                                      self._latent_layers)
                     if kv_block > 0 else 0)
 
         pool = pool_bytes(n_snaps)
@@ -1236,14 +1281,26 @@ class ContinuousBatcher:
         # of a lane's tokens and the scan's [heads, chunk, chunk] blocks
         cfg = self.cfg
         scan = 0
-        if self._state_layers:
+        kinds = {cfg.attn_kind(i) for i in range(cfg.num_layers)}
+        if "ssm" in kinds:
             q = min(cfg.ssm_chunk, p_max)
             scan = 4 * (4 * p_max * (cfg.ssm_inner + cfg.ssm_conv_dim)
                         + 3 * cfg.ssm_heads * q * q)
+        if "kda" in kinds:
+            # the projections' float32 copies and the chunk's [heads,
+            # chunk, chunk, key] decay differences
+            q = min(cfg.kda_chunk, p_max)
+            scan = max(scan, 4 * (8 * p_max * 3 * cfg.kda_inner
+                                  + 3 * cfg.kda_inner * q * q))
+        # one layer's float32 scores against the whole slab; a latent
+        # layer's expanded path runs its heads in groups that bound them
+        scores = 4 * p_max * heads * cache_len
+        if kinds <= {"kda", "ssm", "latent"}:
+            from edl_tpu.ops import latent_attention
+            scores = 2 * min(scores, latent_attention._SCORE_BYTES)
         for i, k_max in enumerate(self.PREFILL_KS):
             prefill = k_max * (lane + scan
-                               + 4 * p_max * (self.cfg.vocab_size
-                                              + heads * cache_len))
+                               + 4 * p_max * self.cfg.vocab_size + scores)
             need = in_use + slots * lane + pool + prefill
             if need <= limit:
                 if i:
@@ -1443,7 +1500,9 @@ class ContinuousBatcher:
         fetched))``; otherwise ``(cache, last, tokens)``.  State-space
         layers add the slot states their
         one-token updates read and wrote (``_ssm_slots_run``: counted
-        on the device from the kernel's own plan), last in that tuple.
+        on the device from the kernel's own plan) and latent layers
+        the positions their reads fetched (``_latent_read``), last in
+        that tuple.
         ``last`` ([slots]) is what the final token step sampled: the
         next call's ``toks``, handed over on the device.
 
@@ -1455,7 +1514,9 @@ class ContinuousBatcher:
 
         # what the layers sow for the host, each read by its own reader
         readers = ([_moe_stats, _moe_fetched] if self._moe_dropless
-                   else []) + ([_ssm_slots_run] if self._state_layers else [])
+                   else []) + ([_ssm_slots_run] if self._state_layers else []
+                               ) + ([_latent_read] if self._latent_layers
+                                    else [])
 
         def one(carry, k):
             cache, tok, *acc = carry
@@ -1473,7 +1534,8 @@ class ContinuousBatcher:
         keys = jax.random.split(key, self._T)
         zero = jnp.zeros((), jnp.float32)
         acc0 = ([_zeros_of(self._moe_acc_shape), zero] if self._moe_dropless
-                else []) + ([zero] if self._state_layers else [])
+                else []) + ([zero] if self._state_layers else []
+                            ) + ([zero] if self._latent_layers else [])
         (cache, last, *acc), out = jax.lax.scan(
             one, (cache, toks, *acc0), keys)
         # [slots, T]
@@ -1793,6 +1855,8 @@ class ContinuousBatcher:
                         tick.moe, tick.fetched = counts.pop(0), counts.pop(0)
                     if self._state_layers:
                         tick.ssm = counts.pop(0)
+                    if self._latent_layers:
+                        tick.latent = counts.pop(0)
                 for i, _ in live:
                     s = self._slots[i]
                     s.owed = max(0, s.owed - self._T)
@@ -1826,6 +1890,7 @@ class ContinuousBatcher:
             moe = np.asarray(tick.moe) if tick.moe is not None else None
             fetched = float(tick.fetched) if tick.moe is not None else 0.0
             ssm = float(tick.ssm) if tick.ssm is not None else 0.0
+            latent = float(tick.latent) if tick.latent is not None else 0.0
             counts = (np.asarray(tick.counts) if tick.counts is not None
                       else None)
             fins = [(p[3], p[4], np.asarray(p[1]), np.asarray(p[2]))
@@ -1836,7 +1901,7 @@ class ContinuousBatcher:
                 if counts is not None:
                     self._finish_spec(dec, counts, tick.live)
                 else:
-                    self._finish_decode(dec, tick.live, ssm)
+                    self._finish_decode(dec, tick.live, ssm, latent)
                 if moe is not None:
                     self._count_moe(moe, len(tick.live) * self._T,
                                     decode=True, fetched=fetched)
@@ -2439,13 +2504,15 @@ class ContinuousBatcher:
                 self._moe_prefill_max_load_sum += load
 
     def _finish_decode(self, toks: np.ndarray, live: list,
-                       ssm_run: float = 0.0) -> None:
+                       ssm_run: float = 0.0, latent_read: float = 0.0
+                       ) -> None:
         """Consume one decode chunk [slots, T] for the (slot, request)
         pairs that were ``live`` in it.  A slot that no longer holds
         its request ended at an EOS while this program was already
         enqueued: its token steps are discarded.  ``ssm_run``: the slot
         states the program's state-space layers read and wrote, as the
-        program counted them."""
+        program counted them; ``latent_read``: the positions its latent
+        layers' reads fetched."""
         T, cap = self._T, self._dcfg.max_len
         mine = [(i, self._slots[i]) for i, req in live
                 if self._slots[i].request is req]
@@ -2467,6 +2534,9 @@ class ContinuousBatcher:
             if self._state_layers:
                 self._ssm_steps += len(live) * T * len(self._state_layers)
                 self._ssm_steps_run += ssm_run
+            if self._latent_layers:
+                self._latent_live += kv_live * len(self._latent_layers)
+                self._latent_read += latent_read
         for i, s in mine:         # live, so it had tokens left to read
             for t in range(T):
                 tok = int(toks[i, t])

@@ -81,7 +81,19 @@ Design (PagedAttention re-shaped for the engine's attention layout):
   history and can be snapshotted when a commit ends; a recurrence can
   be saved only at a position the program is AT, so a state layer's
   snapshot comes out of the prefill itself (``store_state``: the state
-  the scan computed at the block edge), never out of a slot.
+  the scan computed at the block edge), never out of a slot.  A
+  delta-rule layer (``transformer.KDAMixer``: ``conv_state``,
+  ``kda_state``) is a state layer like any other.
+- **latent rows** - a latent attention layer
+  (``transformer.LatentAttention``) caches ONE row a token, the normed
+  key/value latent and the key dims all heads share: no head axis, keys
+  and values the same bytes.  Its rows page by BLOCKS exactly as a
+  global layer's keys and values do (same trie, same block ids, same
+  LRU, same gather at a hit and scatter at a commit), but as one buffer
+  a layer, ``[n_blocks, block, row]``: storing them as keys and as
+  values would give back half of what the architecture saves.  A chain
+  exports its latent blocks beside the other layers' (and, in a stack
+  with state layers, the tail's snapshot).
 
 Thread model: single-writer — every mutating call runs on the engine
 thread (admission, finish-commit, import-task); ``export_chain`` runs
@@ -122,15 +134,25 @@ def state_leaves(node) -> dict:
             if not _leaf_key(path).endswith("cache_index")}
 
 
+def latent_leaf(node) -> tuple:
+    """A latent layer's one cache buffer, ``(its place under the layer,
+    the leaf [lanes, max_len, row])``."""
+    (key, leaf), = state_leaves(node).items()
+    return key, leaf
+
+
 def pool_device_bytes(cache_shapes, block: int, n_blocks: int,
                       tp: int = 1, ring_layers=(), window: int = 0,
-                      n_snaps: int = 0, state_layers=()) -> int:
+                      n_snaps: int = 0, state_layers=(),
+                      latent_layers=()) -> int:
     """Per-device HBM bytes of the pool :class:`PagedKVCache` would
     allocate for this cache skeleton (KV heads split over ``tp`` where
     they divide, as the constructor shards them): ``n_blocks`` blocks
     of ``block`` tokens for every global layer, ``n_snaps`` snapshots
     of ``window`` tokens for every layer in ``ring_layers`` and of one
-    lane's state for every layer in ``state_layers``.  Plain
+    lane's state for every layer in ``state_layers``; ``n_blocks``
+    blocks of ``block`` rows, once, for every layer in
+    ``latent_layers``.  Plain
     element counts: libtpu lays the 16-token minor dim of a K block out
     major-most rather than padding it to 128 lanes (measured on v5e:
     device bytes / nominal = 1.00 for both buffers, f32 and bf16)."""
@@ -141,6 +163,11 @@ def pool_device_bytes(cache_shapes, block: int, n_blocks: int,
                 int(np.prod(leaf.shape[1:], dtype=np.int64))
                 * np.dtype(leaf.dtype).itemsize
                 for leaf in state_leaves(node).values())
+            continue
+        if name in latent_layers:
+            _, leaf = latent_leaf(node)
+            total += (n_blocks * block * leaf.shape[-1]
+                      * np.dtype(leaf.dtype).itemsize)
             continue
         _, hk, d, _ = node["cached_key"].shape
         hk = hk // tp if tp > 1 and hk % tp == 0 else hk
@@ -170,13 +197,16 @@ class _Node:
 
 
 class PagedKVCache:
-    """Device block pools (one k + one v buffer per layer) plus the
-    host-side prefix trie, free list, session pins and eviction policy.
+    """Device block pools (one k + one v buffer per global layer, one
+    buffer of rows per latent layer, snapshot entries for window and
+    state layers) plus the host-side prefix trie, free list, session
+    pins and eviction policy.
 
     ``cache_shapes`` is the engine's per-slot cache skeleton
     (``{layer: {cached_key, cached_value, cache_index}}`` eval_shape
-    tree) — pool layouts are derived from it so the gather/scatter jits
-    line up with the slot slabs by construction.
+    tree; a state or latent layer's node is what its mixer keeps) —
+    pool layouts are derived from it so the gather/scatter jits line up
+    with the slot slabs by construction.
 
     ``mesh`` (optional) shards the pool buffers over the mesh's ``tp``
     axis on the KV-head dim, mirroring the engine's slot-slab sharding
@@ -186,7 +216,8 @@ class PagedKVCache:
 
     def __init__(self, cache_shapes, block: int, n_blocks: int,
                  max_sessions: int, mesh=None, ring_layers=(),
-                 window: int = 0, n_snaps: int = 0, state_layers=()):
+                 window: int = 0, n_snaps: int = 0, state_layers=(),
+                 latent_layers=()):
         import jax
         import jax.numpy as jnp
 
@@ -202,6 +233,8 @@ class PagedKVCache:
         self._ring = frozenset(ring_layers)
         # the state class: layers whose slot state is a recurrence
         self._state = frozenset(state_layers)
+        # the latent class: layers that cache one head-less row a token
+        self._latent = frozenset(latent_layers)
         self._snapped = bool(self._ring or self._state)
         self.window = int(window) if self._ring else 0
         self.n_snaps = int(n_snaps) if self._snapped else 0
@@ -216,7 +249,13 @@ class PagedKVCache:
                 "a paged KV cache with window or state layers is not "
                 "sharded over a mesh: the snapshot pool has no sharded "
                 "gather yet")
+        if self._latent and mesh is not None:
+            raise ValueError(
+                "a paged KV cache with latent layers is not sharded over a "
+                "mesh: a latent row has no head axis to shard over tp")
         self._layout: dict[str, tuple] = {}
+        # a latent layer's buffer: {layer: (leaf's place, row, dtype)}
+        self._latent_layout: dict[str, tuple] = {}
         # a state layer's snapshot leaves: {leaf: (shape of a lane, dtype)}
         self._state_layout: dict[str, dict] = {}
         for name in self._layers:
@@ -226,12 +265,20 @@ class PagedKVCache:
                     k: (tuple(v.shape[1:]), v.dtype)
                     for k, v in sorted(state_leaves(node).items())}
                 continue
+            if name in self._latent:
+                key, leaf = latent_leaf(node)   # [slots, max_len, row]
+                if leaf.ndim != 3 or block > leaf.shape[1]:
+                    raise ValueError(
+                        f"latent layer {name} caches {leaf.shape}: not "
+                        f"[slots, max_len >= {block}, row]")
+                self._latent_layout[name] = (key, leaf.shape[-1], leaf.dtype)
+                continue
             if set(node) != {"cached_key", "cached_value", "cache_index"}:
                 raise ValueError(
                     f"paged KV cache requires plain per-layer "
                     f"cached_key/cached_value/cache_index state; layer "
-                    f"{name} carries {sorted(node)} (MoE/custom decode "
-                    f"caches are served unpaged)")
+                    f"{name} carries {sorted(node)} and was named neither "
+                    f"a state layer nor a latent layer")
             k = node["cached_key"]          # [slots, Hk, D, max_len]
             _, hk, d, length = k.shape
             if name in self._ring:
@@ -253,7 +300,8 @@ class PagedKVCache:
         self._layer_sharded = {
             name: self._tp > 1 and hk % self._tp == 0
             for name, (hk, d, _) in self._layout.items()}
-        self._layer_sharded.update(dict.fromkeys(self._state, False))
+        self._layer_sharded.update(
+            dict.fromkeys(self._state | self._latent, False))
         # block 0 is a reserved scratch block (never allocated) so a
         # zero-filled block-id vector can never alias live state
         self.pool = {
@@ -267,6 +315,8 @@ class PagedKVCache:
             self.pool[name] = {
                 k: jnp.zeros((self.n_snaps,) + shape, dtype)
                 for k, (shape, dtype) in leaves.items()}
+        for name, (_, row, dtype) in self._latent_layout.items():
+            self.pool[name] = {"c": jnp.zeros((n_blocks, block, row), dtype)}
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -606,7 +656,8 @@ class PagedKVCache:
         chain tail's) into the ring slots of the ``window`` positions
         before ``prefix_len``: exactly the last window of the prefix.
         State layers take the same entry whole: the recurrence as it
-        stood after token ``prefix_len - 1``."""
+        stood after token ``prefix_len - 1``.  Latent layers gather
+        their blocks of rows as global layers gather keys and values."""
         jnp = self._jnp
         bs = self.block
         out = {}
@@ -619,6 +670,15 @@ class PagedKVCache:
                         if path[-1].key == "cache_index" else
                         pool[name][_leaf_key(path)][snap_id][None].astype(
                             v.dtype)), node)
+                continue
+            if name in self._latent:
+                rows = pool[name]["c"][block_ids]         # [n, bs, row]
+                rows = rows.reshape(n * bs, rows.shape[-1])
+                out[name] = self._jax.tree_util.tree_map_with_path(
+                    lambda path, v, rows=rows: (
+                        jnp.full_like(v, prefix_len)
+                        if path[-1].key == "cache_index" else
+                        v.at[0, :n * bs].set(rows.astype(v.dtype))), node)
                 continue
             if name in self._ring:
                 ring = node["cached_key"].shape[-1]
@@ -690,6 +750,15 @@ class PagedKVCache:
                     continue
                 if not n:
                     out[name] = pool[name]
+                    continue
+                if name in self._latent:
+                    key, row, _ = self._latent_layout[name]
+                    lane = jnp.take(state_leaves(cache[name])[key], slot,
+                                    axis=0)               # [max_len, row]
+                    rows = jax.lax.dynamic_slice(lane, (start, 0),
+                                                 (n * bs, row))
+                    out[name] = {"c": pool[name]["c"].at[block_ids].set(
+                        rows.reshape(n, bs, row))}
                     continue
                 # head/feature extents come from the OPERANDS, not the
                 # global layout: under shard_map this body sees the
@@ -814,6 +883,11 @@ class PagedKVCache:
                 "window": self.window, "ring_layers": sorted(self._ring),
                 "layout": {name: [hk, d, str(np.dtype(dtype))]
                            for name, (hk, d, dtype) in self._layout.items()}}
+        if self._latent:
+            meta["latent_layers"] = sorted(self._latent)
+            meta["layout"].update(
+                {name: [row, str(np.dtype(dtype))]
+                 for name, (_, row, dtype) in self._latent_layout.items()})
         if self._state:
             meta["state_layers"] = sorted(self._state)
             meta["layout"].update(
@@ -825,15 +899,19 @@ class PagedKVCache:
     def _axes(self, name: str) -> tuple:
         """A layer's pool buffers in the blob's order."""
         return (tuple(self._state_layout[name]) if name in self._state
-                else ("k", "v"))
+                else ("c",) if name in self._latent else ("k", "v"))
 
     def _wire_shapes(self, name: str, n: int) -> dict:
         """``{buffer: (shape, dtype)}`` of what a chain of ``n`` blocks
         carries for layer ``name``: n blocks of a global layer, one
-        snapshot of a window or a state layer."""
+        snapshot of a window or a state layer, n blocks of rows of a
+        latent layer."""
         if name in self._state:
             return {k: ((1,) + shape, dtype)
                     for k, (shape, dtype) in self._state_layout[name].items()}
+        if name in self._latent:
+            _, row, dtype = self._latent_layout[name]
+            return {"c": ((n, self.block, row), dtype)}
         hk, d, dtype = self._layout[name]
         m, t = (1, self.window) if name in self._ring else (n, self.block)
         return {"k": ((m, hk, d, t), dtype), "v": ((m, hk, t, d), dtype)}
@@ -859,6 +937,11 @@ class PagedKVCache:
             raise ValueError("kv import window layers mismatch")
         if list(meta.get("state_layers", [])) != sorted(self._state):
             raise ValueError("kv import state layers mismatch")
+        if list(meta.get("latent_layers", [])) != sorted(self._latent):
+            raise ValueError("kv import latent layers mismatch")
+        for name, (_, row, dtype) in self._latent_layout.items():
+            if list(meta["layout"][name]) != [row, str(np.dtype(dtype))]:
+                raise ValueError(f"kv import layout mismatch at {name}")
         for name, (hk, d, dtype) in self._layout.items():
             if list(meta["layout"][name]) != [hk, d,
                                               str(np.dtype(dtype))]:

@@ -395,7 +395,7 @@ def test_step_program_for_v5e_holds_no_whole_slab_temporary(
     # the part of an engine the step reads, and nothing it would allocate
     engine = types.SimpleNamespace(
         _model=model, _T=T, _moe_dropless=False, _moe_acc_shape=None,
-        _state_layers=(),
+        _state_layers=(), _latent_layers=(),
         _positions=ContinuousBatcher._positions,
         _sample=lambda logits, key: sample_logits(logits, key,
                                                   temperature=0.0))
@@ -459,6 +459,57 @@ def test_ssm_step_kernel_compiles_for_v5e_at_published_widths(one_chip):
     assert mem.alias_size_in_bytes == B * H * P * N * 4
     assert mem.temp_size_in_bytes < 8e6
     assert "ssm_step" in compiled.as_text()
+
+
+def test_kda_and_latent_kernels_compile_for_v5e_at_published_widths(
+        one_chip):
+    """``ops/kda.kda_step``, ``ops/latent_attention.latent_append`` and
+    ``latent_attend`` at Kimi-Linear's widths (32 slots of 32768
+    positions, 32 heads of 128 x 128 state, rows of 640 values),
+    compiled ahead of time for one v5e chip: the Mosaic compiler takes
+    the kernels, the states and the rows are aliased in and out, and
+    nothing copies a slab (a row of 576 values, not in whole lane tiles,
+    compiled too, with a 1.3 GB relayout of the rows a call: why the
+    cache keeps 640)."""
+    from edl_tpu.ops import kda
+    from edl_tpu.ops import latent_attention as la
+
+    B, H, R, T, W = 32, 32, 128, 32768, 640
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    with _no_compile_cache():
+        step = jax.jit(
+            lambda s, q, k, v, g, b, live: kda.kda_step(
+                s, q, k, v, g, b, live, interpret=False),
+            donate_argnums=(0,)).lower(
+                sds((B, H, R, R)), sds((B, H, R)), sds((B, H, R)),
+                sds((B, H, R)), sds((B, H, R)), sds((B, H)),
+                sds((B,), jnp.bool_)).compile()
+        append = jax.jit(
+            lambda rows, new, at, live: la.latent_append(
+                rows, new, at, live, interpret=False),
+            donate_argnums=(0,)).lower(
+                sds((B, T, W), bf), sds((B, W), bf), sds((B,), jnp.int32),
+                sds((B,), jnp.bool_)).compile()
+        attend = jax.jit(
+            lambda q, rows, n: la.latent_attend(
+                q, rows, n, scale=192 ** -0.5, interpret=False)).lower(
+                sds((B, H, W), bf), sds((B, T, W), bf),
+                sds((B,), jnp.int32)).compile()
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == B * H * R * R * 4
+    assert mem.temp_size_in_bytes < 8e6 and "kda_step" in step.as_text()
+    mem = append.memory_analysis()
+    assert mem.alias_size_in_bytes == B * T * W * 2
+    assert mem.temp_size_in_bytes < 8e6
+    assert "latent_append" in append.as_text()
+    assert attend.memory_analysis().temp_size_in_bytes < 8e6
+    assert "latent_attend" in attend.as_text()
+    assert la.padded_width(576) == W
+    assert la.attend_block(W, T, bf) == 512
 
 
 @pytest.mark.parametrize("rows,M,H,E,chunks", [

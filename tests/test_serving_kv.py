@@ -620,3 +620,117 @@ def test_state_snapshots_share_the_snapshot_lru(recurrent):
     finally:
         eng.stop()
         cold.stop()
+
+
+# -- latent layers: a fourth class, one head-less buffer a layer (PR 37) -------
+
+@pytest.fixture(scope="module")
+def latent():
+    """A latent attention layer between two delta-rule layers."""
+    cfg = TransformerConfig(vocab_size=97, num_layers=3, embed_dim=32,
+                            num_heads=2, mlp_dim=64, max_len=96,
+                            remat=False, dtype=jnp.float32,
+                            layer_attn=("kda", "latent", "kda"),
+                            kda_heads=2, kda_head_dim=16, kda_chunk=8,
+                            mla_rank=24, mla_nope_dim=16, mla_rope_dim=8,
+                            mla_v_dim=16)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return cfg, params
+
+
+_KDA_BYTES = 2 * 16 * 16 * 4 + 3 * 3 * 32 * 4       # S and the conv, f32
+
+
+def test_the_latent_class_is_one_buffer_a_layer(latent):
+    """Latent rows page by blocks like a global layer's keys and
+    values, but once: ``[n_blocks, block, row]``, no k and v, no head
+    axis; the delta-rule layers ride the state-snapshot class."""
+    from edl_tpu.serving.kv_cache import PagedKVCache, pool_device_bytes
+    cfg, params = latent
+    eng = _engine(cfg, params, slots=4, kv_max_sessions=2)
+    try:
+        pool = {n: {k: tuple(v.shape) for k, v in b.items()}
+                for n, b in eng._kv.pool.items()}
+        n = 2 + 2 * 4 + 1
+        state = {"kda/conv_state": (n, 3, 96), "kda/kda_state": (n, 2, 16, 16)}
+        assert pool == {"layer_0": state, "layer_1": {"c": (64, 4, 128)},
+                        "layer_2": state}
+        stats = eng.stats()
+        assert stats["kv_slot_bytes_latent"] == 96 * 128 * 4
+        assert stats["kv_slot_bytes_state"] == 2 * _KDA_BYTES
+        assert stats["kv_slot_bytes_global"] == 0
+        one_lane = eng._cache_shapes(1)
+        assert pool_device_bytes(
+            one_lane, 4, 64, n_snaps=n, state_layers=eng._state_layers,
+            latent_layers=eng._latent_layers
+        ) == 64 * 4 * 128 * 4 + n * 2 * _KDA_BYTES
+        # a layer that is named to no class is refused, and a mesh is
+        with pytest.raises(ValueError, match="neither a state layer nor"):
+            PagedKVCache(one_lane, 4, 8, 2, n_snaps=3,
+                         state_layers=eng._state_layers)
+        from jax.sharding import Mesh
+        with pytest.raises(ValueError, match="no head axis"):
+            PagedKVCache({"layer_1": one_lane["layer_1"]}, 4, 8, 2,
+                         latent_layers={"layer_1"},
+                         mesh=Mesh(np.asarray(jax.devices()[:2]), ("tp",)))
+    finally:
+        eng.stop()
+
+
+def test_latent_blocks_gather_scatter_and_evict_with_parity(latent):
+    """A prompt that comes again starts from its latent blocks and the
+    state snapshot; a pool too small for all prompts evicts, and every
+    answer equals the unpaged engine's."""
+    cfg, params = latent
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, 97, (n,)).astype(np.int32)
+               for n in (21, 17, 26, 19, 23)]
+    eng = _engine(cfg, params, slots=2, kv_pool_blocks=13)
+    cold = _engine(cfg, params, kv_block=0)
+    try:
+        want = [cold.generate(p, 6, 120) for p in prompts]
+        for p, w in zip(prompts, want):
+            np.testing.assert_array_equal(eng.generate(p, 6, 120), w)
+        s0 = eng.stats()
+        assert s0["kv_evictions"] > 0 and s0["kv_prefix_hits"] == 0
+        np.testing.assert_array_equal(eng.generate(prompts[-1], 6, 120),
+                                      want[-1])
+        s1 = eng.stats()
+        assert s1["kv_prefix_hits"] == 1
+        assert s1["kv_prefill_tokens_skipped"] == 20
+    finally:
+        eng.stop()
+        cold.stop()
+
+
+def test_export_import_of_a_latent_chain_on_a_second_pool(latent):
+    """``export_chain`` carries the latent blocks and the tail's state
+    snapshot; a second pool adopts them, dedups what it holds, and
+    refuses a blob of another layout."""
+    cfg, params = latent
+    rng = np.random.default_rng(12)
+    prompt = rng.integers(1, 97, (22,)).astype(np.int32)
+    a, b = _engine(cfg, params), _engine(cfg, params)
+    cold = _engine(cfg, params, kv_block=0)
+    try:
+        first = a.submit(prompt, 6, session="s").result(120)
+        assert a.drain(60)
+        (session, tokens, meta, blob), = a.export_sessions()
+        assert len(tokens) == 20 and meta["latent_layers"] == ["layer_1"]
+        assert meta["layout"]["layer_1"] == [128, "float32"]
+        assert len(blob) == 5 * 4 * 128 * 4 + 2 * _KDA_BYTES
+        assert b.import_session(session, tokens, meta, blob) == 5
+        assert b.import_session("t", tokens, meta, blob) == 0     # dedup
+        nxt = np.concatenate([prompt, first[:-1], [3, 4]]).astype(np.int32)
+        np.testing.assert_array_equal(b.generate(nxt, 5, 120),
+                                      cold.generate(nxt, 5, 120))
+        assert b.stats()["kv_prefill_tokens_skipped"] == 20
+        bad = dict(meta, layout=dict(meta["layout"], layer_1=[64, "float32"]))
+        with pytest.raises(Exception, match="layout mismatch"):
+            b.import_session("u", tokens, bad, blob)
+        with pytest.raises(Exception, match="latent layers mismatch"):
+            b.import_session("u", tokens, dict(meta, latent_layers=[]), blob)
+    finally:
+        b.stop()
+        cold.stop()
