@@ -810,7 +810,8 @@ class LatentAttention(nn.Module):
     ``latent_attend``, which read live positions of live slots once;
     slots that ``token_mask`` marks free are neither written nor read);
     a multi-token call writes its rows at the (batch-uniform) index and
-    attends the slab on the expanded path under the position mask."""
+    attends the slot's rows up to its own last position on the expanded
+    path, in tiles (the rows past them are not read)."""
 
     cfg: TransformerConfig
 
@@ -843,11 +844,13 @@ class LatentAttention(nn.Module):
                            ).reshape(rank, H, nope + vd)
         attend = functools.partial(latent_attention.expanded_attention,
                                    rank=rank, nope=nope, scale=scale)
-        i = jnp.arange(L)
-        causal = jnp.broadcast_to(i[None, :] <= i[:, None], (B, L, L))
+        # the call's own rows alone, causal: a model that is not decoding
+        causal = functools.partial(
+            attend, q, latent, w_kvb,
+            jnp.broadcast_to(jnp.arange(L), (B, L)), L)
         with jax.named_scope("attn/latent"):
             if not cfg.decode:
-                out = attend(q, latent, w_kvb, causal)
+                out = causal()
             else:
                 out = self._cached(q, latent, w_kvb, token_mask, attend,
                                    causal, scale)
@@ -863,7 +866,7 @@ class LatentAttention(nn.Module):
         ci = self.variable("cache", "cache_index",
                            lambda: jnp.zeros((B,), jnp.int32))
         if not is_initialized:      # init trace: shapes only
-            return attend(q, latent, w_kvb, causal)
+            return causal()
         idx = ci.value                                           # [B]
         rows = latent_attention.cache_rows(latent, row, cfg.dtype)
         ci.value = idx + L
@@ -896,9 +899,9 @@ class LatentAttention(nn.Module):
         # a reuse suffix: ``Block._decode_attention``'s contract)
         cl.value = jax.lax.dynamic_update_slice(cl.value, rows,
                                                 (0, idx[0], 0))
-        q_pos = idx[:, None] + jnp.arange(L)                     # [B, L]
-        mask = jnp.arange(T)[None, None, :] <= q_pos[:, :, None]
-        return attend(q, cl.value, w_kvb, mask)
+        # the slot's rows up to the call's last position, and no further
+        return attend(q, cl.value, w_kvb, idx[:, None] + jnp.arange(L),
+                      idx.max() + L)
 
 
 class Block(nn.Module):

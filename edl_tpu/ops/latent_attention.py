@@ -16,9 +16,13 @@ Two ways to compute it, equal in exact arithmetic:
 - **expanded** (:func:`expanded_attention`, scope ``attn/latent_expand``)
   - ``k_nope | v`` are computed from the latent rows and plain attention
   runs over them: multi-token calls (a full forward, a prefill, a chunk
-  against the slot's rows and its own).  Heads run in groups so that one
-  group's float32 scores stay within ``_SCORE_BYTES`` whatever the
-  slab's length is.
+  against the slot's rows and its own).  It runs flash-style over tiles
+  of rows, one softmax carried across them (running maximum, sum and a
+  float32 accumulator), up to the call's last position and no further:
+  the trip count follows the cache index, so a chunk against a long
+  slab reads the rows it can see; a tile (:func:`expand_block`) keeps
+  its float32 scores and expanded rows within ``_TILE_BYTES`` whatever
+  the slab's length is.
 - **absorbed** (:func:`absorb` / :func:`unabsorb`, scope
   ``attn/latent_absorb``) - ``W_K`` moves onto the query (``q~_h =
   W_K[:, h] q_nope_h``, ``rank`` wide) and ``W_V`` onto the output
@@ -58,8 +62,9 @@ from edl_tpu.ops.decode_attention import (
 _LANES = 128
 # bytes of one tile of latent rows of the attend kernel (double buffered)
 _BLOCK_BYTES = 1 << 20
-# float32 scores one head group of the expanded path may hold
-_SCORE_BYTES = 256 << 20
+# float32 scores and expanded keys and values of one tile of the
+# expanded path
+_TILE_BYTES = 48 << 20
 
 
 def applies(L: int, mesh, max_len: int) -> bool:
@@ -86,48 +91,72 @@ def cache_rows(latent, row: int, dtype):
 
 # -- expanded ---------------------------------------------------------------
 
-def _head_groups(H: int, L: int, T: int) -> int:
-    """Groups the expanded path's heads run in: the fewest that keep one
-    group's ``[H / groups, L, T]`` float32 scores (``L`` over all lanes) within
-    ``_SCORE_BYTES``."""
-    for groups in range(1, H + 1):
-        if H % groups == 0 and (H // groups) * L * T * 4 <= _SCORE_BYTES:
-            return groups
-    return H
+def expand_block(lanes: int, L: int, H: int, kv: int, T: int, dtype) -> int:
+    """Rows one tile of the expanded path reads of a ``T``-row slab for
+    ``lanes`` calls of ``L`` queries and ``H`` heads: the largest
+    power-of-two multiple of 128 that divides ``T`` and keeps what the
+    tile holds - its ``[lanes, H, L, rows]`` float32 scores and the
+    rows' keys and values expanded for every head, ``kv = nope + v`` of
+    ``dtype`` a head - within ``_TILE_BYTES``; a slab that is not whole
+    lane tiles is one tile."""
+    if T % _LANES:
+        return T
+    row = lanes * H * (4 * L + kv * jnp.dtype(dtype).itemsize)
+    tk = _LANES
+    while T % (2 * tk) == 0 and 2 * tk * row <= _TILE_BYTES:
+        tk *= 2
+    return tk
 
 
-def expanded_attention(q, latent, w_kvb, mask, *, rank: int, nope: int,
-                       scale: float):
+def expanded_attention(q, latent, w_kvb, q_pos, limit, *, rank: int,
+                       nope: int, scale: float):
     """``q [B, L, H, nope + rope]``, ``latent [B, T, >= rank + rope]``
     (``c | k_pe`` leading each row), ``w_kvb [rank, H, nope + v]``,
-    ``mask [B, L, T]`` bool (what each query may see).  Returns ``[B, L,
-    H, v]`` in ``q``'s dtype."""
+    ``q_pos [B, L]`` (query l of lane b sees rows ``<= q_pos[b, l]``),
+    ``limit`` the number of leading rows that can matter (``max(q_pos) +
+    1``; a Python int for a static trip count, a traced scalar for a
+    dynamic one: rows past its last tile are not read).  Returns ``[B,
+    L, H, v]`` in ``q``'s dtype."""
     B, L, H, Dq = q.shape
     T = latent.shape[1]
     rope = Dq - nope
-    groups = _head_groups(H, B * L, T)
-    hg = H // groups
-    c = latent[..., :rank]
-    k_pe = latent[..., rank:rank + rope]
+    tk = expand_block(B, L, H, w_kvb.shape[-1], T, latent.dtype)
+    qh = jnp.moveaxis(q, 2, 1)                             # [B, H, L, Dq]
+    q_nope, q_pe = qh[..., :nope], qh[..., nope:]
+    w = w_kvb.astype(latent.dtype)
+    f32 = jnp.float32
 
-    def group(args):
-        qg, wg = args                          # [B, L, hg, Dq], [rank, hg, :]
+    def tile(j, carry):
+        m, l, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(latent, j * tk, tk, axis=1)
         with jax.named_scope("attn/latent_expand"):
-            kv = jnp.einsum("btc,chd->bthd", c, wg.astype(c.dtype))
-        k_nope, v = kv[..., :nope], kv[..., nope:]
-        s = (jnp.einsum("blhd,bthd->bhlt", qg[..., :nope], k_nope)
-             + jnp.einsum("blhd,btd->bhlt", qg[..., nope:], k_pe)
-             ).astype(jnp.float32) * scale
-        s = jnp.where(mask[:, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhlt,bthd->blhd", p, v)
+            kv = jnp.einsum("btc,chd->bhtd", rows[..., :rank], w)
+        s = (jnp.einsum("bhld,bhtd->bhlt", q_nope, kv[..., :nope],
+                        preferred_element_type=f32)
+             + jnp.einsum("bhld,btd->bhlt", q_pe, rows[..., rank:rank + rope],
+                          preferred_element_type=f32)) * scale
+        pos = j * tk + jnp.arange(tk)
+        s = jnp.where(pos <= q_pos[:, None, :, None], s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        # every query sees row 0, so from the first tile on m_new is a
+        # real score and a masked row's exp(_NEG - m_new) is exactly 0
+        p = jnp.exp(s - m_new[..., None])
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bhlt,bhtd->bhld", p.astype(latent.dtype), kv[..., nope:],
+            preferred_element_type=f32)
+        return m_new, alpha * l + p.sum(-1), acc
 
-    if groups == 1:
-        return group((q, w_kvb))
-    qs = jnp.moveaxis(q.reshape(B, L, groups, hg, Dq), 2, 0)
-    ws = jnp.moveaxis(w_kvb.reshape(rank, groups, hg, -1), 1, 0)
-    out = jax.lax.map(group, (qs, ws))         # [groups, B, L, hg, v]
-    return jnp.moveaxis(out, 0, 2).reshape(B, L, H, -1)
+    carry = (jnp.full((B, H, L), _NEG, f32), jnp.zeros((B, H, L), f32),
+             jnp.zeros((B, H, L, w_kvb.shape[-1] - nope), f32))
+    static = isinstance(limit, int)
+    tiles = -(-(min(limit, T) if static else jnp.minimum(limit, T)) // tk)
+    if static and tiles == 1:
+        _, l, acc = tile(0, carry)
+    else:
+        _, l, acc = jax.lax.fori_loop(0, tiles, tile, carry)
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return jnp.moveaxis(out, 1, 2).astype(q.dtype)
 
 
 # -- absorbed ---------------------------------------------------------------

@@ -622,6 +622,10 @@ class ContinuousBatcher:
         # slot's length, a token step and layer) and fetched
         self._latent_live = 0
         self._latent_read = 0.0
+        # and, a multi-token call, lane and layer, the rows up to the
+        # call's end and the whole tiles its expanded path read of them
+        self._latent_prefill_live = 0
+        self._latent_prefill_read = 0
         # state-space layers: (slot, token step, layer) states the step
         # programs updated for live slots, and all they read and wrote;
         # positions the prefill and chunk programs ran through the scan,
@@ -1005,6 +1009,10 @@ class ContinuousBatcher:
                 "kv_slot_bytes_latent": self._slot_bytes["latent"],
                 "latent_tokens_live": self._latent_live,
                 "latent_tokens_read": self._latent_read,
+                # and for the multi-token programs' expanded path: rows
+                # up to the call's end, and the whole tiles read of them
+                "latent_prefill_rows_live": self._latent_prefill_live,
+                "latent_prefill_rows_read": self._latent_prefill_read,
                 "ssm_state_steps": self._ssm_steps,
                 "ssm_state_steps_run": self._ssm_steps_run,
                 "ssm_prefill_positions": self._ssm_prefill_pos,
@@ -1267,7 +1275,8 @@ class ContinuousBatcher:
         pool = pool_bytes(n_snaps)
         # the widest admission: K fresh lanes, their [K, P, vocab] f32
         # logits and one layer's [K, heads, P, cache_len] f32 scores (a
-        # multi-token call attends the whole slab under its mask), P the
+        # multi-token call attends the whole slab under its mask; a
+        # latent layer's a tile of it at a time: ``scores``), P the
         # largest monolithic bucket (prompts past the chunk size prefill
         # one lane, one chunk).  The sub-batch ladder is cut to the
         # widest K that fits: 8 lanes of 64 heads against a 16k slab
@@ -1292,15 +1301,20 @@ class ContinuousBatcher:
             q = min(cfg.kda_chunk, p_max)
             scan = max(scan, 4 * (8 * p_max * 3 * cfg.kda_inner
                                   + 3 * cfg.kda_inner * q * q))
-        # one layer's float32 scores against the whole slab; a latent
-        # layer's expanded path runs its heads in groups that bound them
-        scores = 4 * p_max * heads * cache_len
-        if kinds <= {"kda", "ssm", "latent"}:
-            from edl_tpu.ops import latent_attention
-            scores = 2 * min(scores, latent_attention._SCORE_BYTES)
+        def scores(k):
+            """A lane's widest attention temporaries in a ``k``-lane
+            call: one layer's float32 scores against the whole slab; of
+            a latent layer's expanded path one tile of rows: its float32
+            scores and probabilities and its expanded keys and values."""
+            if not kinds <= {"kda", "ssm", "latent"}:
+                return 4 * p_max * heads * cache_len
+            return self._latent_tile(k, p_max, heads) * heads * (
+                2 * 4 * p_max + (cfg.mla_nope_dim + cfg.mla_v_dim)
+                * jnp.dtype(cfg.dtype).itemsize)
+
         for i, k_max in enumerate(self.PREFILL_KS):
-            prefill = k_max * (lane + scan
-                               + 4 * p_max * self.cfg.vocab_size + scores)
+            prefill = k_max * (lane + scan + 4 * p_max * self.cfg.vocab_size
+                               + scores(k_max))
             need = in_use + slots * lane + pool + prefill
             if need <= limit:
                 if i:
@@ -1457,14 +1471,32 @@ class ContinuousBatcher:
             return 0
         return (len(req.ids) - 1) // self._kv.block * self._kv.block
 
-    def _count_scan(self, lanes: int, width: int, real: int) -> None:
+    def _latent_tile(self, lanes: int, width: int, heads: int) -> int:
+        """Rows a tile of the latent layers' expanded path holds in a
+        ``lanes x width`` call (``ops/latent_attention.expand_block``)."""
+        from edl_tpu.ops import latent_attention
+        cfg = self.cfg
+        return latent_attention.expand_block(
+            lanes, width, heads, cfg.mla_nope_dim + cfg.mla_v_dim,
+            self._dcfg.max_len, cfg.dtype)
+
+    def _count_prefill(self, lanes: int, width: int, real: int,
+                       offset: int = 0) -> None:
         """One prefill, chunk or reuse program ran ``lanes x width``
-        positions through every state-space layer's scan, ``real`` of
-        them tokens."""
-        if self._state_layers:
-            with self._stats_lock:
+        positions from ``offset`` on, ``real`` of them tokens, through
+        every state-space layer's scan and every latent layer's expanded
+        path (rows up to the call's end, read in whole tiles: the plan
+        the loop itself runs under, nothing read back from the device)."""
+        if not (self._state_layers or self._latent_layers):
+            return
+        calls, end = lanes * len(self._latent_layers), offset + width
+        tk = self._latent_tile(lanes, width, self.cfg.num_heads)
+        with self._stats_lock:
+            if self._state_layers:
                 self._ssm_prefill_pos += lanes * width
                 self._ssm_prefill_pad += lanes * width - real
+            self._latent_prefill_live += calls * end
+            self._latent_prefill_read += calls * -(-end // tk) * tk
 
     @staticmethod
     def _place(cache, slab, slots, true_lens):
@@ -2077,7 +2109,7 @@ class ContinuousBatcher:
             slab, toks, drops, snap = self._prefill_fn(P, K)(
                 self._params, jnp.asarray(ids), jnp.asarray(lens), key, ends)
             self._count_enqueue()
-            self._count_scan(K, P, int(lens.sum()))
+            self._count_prefill(K, P, int(lens.sum()))
             dslab = (self._draft_prefill_fn(P, K)(
                 self._draft_params, jnp.asarray(ids), jnp.asarray(lens))
                 if self._spec_k else None)
@@ -2164,8 +2196,8 @@ class ContinuousBatcher:
                 st.slab, st.drops = self._chunk_mid_fn(C)(
                     self._params, st.slab, jnp.asarray(chunk), st.drops)
                 self._count_enqueue()
+                self._count_prefill(1, C, C, st.offset)
                 st.offset += C
-                self._count_scan(1, C, C)
                 with self._stats_lock:
                     self._prefill_chunks += 1
                 return None
@@ -2180,7 +2212,7 @@ class ContinuousBatcher:
                 jnp.asarray([rest], jnp.int32), st.drops, key, at)
             self._count_enqueue()
             self._chunking = None
-            self._count_scan(1, P, rest)
+            self._count_prefill(1, P, rest, st.offset)
             with self._stats_lock:
                 self._prefill_chunks += 1
                 # the lane is free from its last chunk's dispatch on
@@ -2354,7 +2386,7 @@ class ContinuousBatcher:
                     self._params, *hit, jnp.asarray(ids), n_real, key,
                     snap_id)
             self._count_enqueue()
-            self._count_scan(1, P, len(suffix))
+            self._count_prefill(1, P, len(suffix), prefix_len)
             # insert true_lens = the FULL prompt length: the slab's
             # cache_index already sits at prefix+suffix and the pool
             # lane must agree.  The draft has no pool: its slab is

@@ -512,6 +512,42 @@ def test_kda_and_latent_kernels_compile_for_v5e_at_published_widths(
     assert la.attend_block(W, T, bf) == 512
 
 
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_expanded_latent_path_for_v5e_holds_a_tile_of_scores(one_chip, lanes):
+    """``ops/latent_attention.expanded_attention`` at Kimi-Linear's
+    widths and the chunk lane's shape (256 tokens a lane against 32768
+    rows of 640 values, 32 heads), compiled ahead of time for one v5e
+    chip: its temporaries are a tile's (the whole slab's float32 scores
+    are 1 GiB a lane) and its loop over tiles has no constant trip
+    count."""
+    from edl_tpu.ops import latent_attention as la
+
+    L, H, T, W = 256, 32, 32768, 640
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(q, rows, w_kvb, idx):
+        return la.expanded_attention(
+            q, rows, w_kvb, idx[:, None] + jnp.arange(L), idx.max() + L,
+            rank=512, nope=128, scale=192 ** -0.5)
+
+    with _no_compile_cache():
+        compiled = jax.jit(chunk).lower(
+            sds((lanes, L, H, 192)), sds((lanes, T, W)),
+            sds((512, H, 256), jnp.float32),
+            sds((lanes,), jnp.int32)).compile()
+    assert 128 <= la.expand_block(lanes, L, H, 256, T, bf) < T
+    # scores and probabilities, the expanded tile, the carried statistics
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * la._TILE_BYTES + lanes * 2 * L * H * 128 * 4 + (8 << 20))
+    text = compiled.as_text()
+    assert not re.search(rf"f32\[[0-9,]*{L},{T}\]", text)
+    loops = [ln for ln in text.splitlines() if re.search(r"= .* while\(", ln)]
+    assert loops and not any("known_trip_count" in ln for ln in loops)
+
+
 @pytest.mark.parametrize("rows,M,H,E,chunks", [
     (320, 4096, 768, 36, (1024, 384)),    # granite-4.0-h-small, 32 x top-10
     (96, 2048, 1024, 64, (1024, 1024)),   # OLMoE, 12 slots x top-8

@@ -156,24 +156,116 @@ def plain(q, latent, w_kvb, mask, scale):
     return jnp.einsum("bhlt,bthd->blhd", p, kv[..., NOPE:])
 
 
-def test_expanded_equals_absorbed_equals_plain_attention(monkeypatch):
+def test_expanded_equals_absorbed_equals_plain_attention():
     T, scale = 24, (NOPE + ROPE) ** -0.5
     q, latent, w_kvb = latent_inputs(1, T)
     lengths = jnp.asarray([24, 9])
     mask = (jnp.arange(T)[None, None, :] < lengths[:, None, None])
     want = plain(q, latent, w_kvb, mask, scale)
-    close(la.expanded_attention(q, latent, w_kvb, mask, rank=RANK, nope=NOPE,
-                                scale=scale), want)
+    close(la.expanded_attention(q, latent, w_kvb, lengths[:, None] - 1, T,
+                                rank=RANK, nope=NOPE, scale=scale), want)
     row = la.padded_width(W)
     rows = la.cache_rows(latent, row, jnp.float32)
     q_lat = la.absorb(q[:, 0], w_kvb, nope=NOPE, width=row)
     o_lat = la.latent_attend_reference(q_lat, rows, lengths, scale=scale)
     close(la.unabsorb(o_lat, w_kvb, rank=RANK, nope=NOPE), want[:, 0])
-    # heads in groups: the same numbers
-    monkeypatch.setattr(la, "_SCORE_BYTES", 2 * 1 * T * 4)
-    assert la._head_groups(HEADS, 2, T) == HEADS
-    close(la.expanded_attention(q, latent, w_kvb, mask, rank=RANK, nope=NOPE,
-                                scale=scale), want)
+
+
+def test_the_tile_follows_the_shape():
+    """The largest power-of-two multiple of 128 rows that divides the
+    slab and keeps a tile's float32 scores and expanded rows within the
+    budget; a slab that is not whole lane tiles is one tile."""
+    bf, T = jnp.bfloat16, 32768
+    tile = lambda lanes, L, T=T: la.expand_block(lanes, L, 32, 256, T, bf)  # noqa: E731
+    rows = tile(1, 256)                         # the chunk lane's shape
+    assert rows >= 128 and rows & (rows - 1) == 0
+    assert rows * 32 * (4 * 256 + 256 * 2) <= la._TILE_BYTES < (
+        2 * rows * 32 * (4 * 256 + 256 * 2))
+    assert tile(2, 256) == rows // 2 and tile(4, 256) == rows // 4
+    assert tile(1, 256, 3 * rows) == rows                  # divides T
+    assert tile(1 << 20, 256) == 128                       # never under
+    # the expanded rows are a third of that tile's bytes, and count
+    # however short the call is
+    assert tile(1, 1) == tile(1, 64) == 2 * rows
+    assert la.expand_block(2, 1, 4, 16, 96, bf) == 96
+    assert la.expand_block(1, 1, 1, 1, 4096, bf) == 4096   # at most T
+
+
+TILE = 128
+
+
+@pytest.fixture
+def tiles_of_128(monkeypatch):
+    """The budget under one row of the toy shapes' scores: tiles of 128
+    rows, the smallest there is."""
+    monkeypatch.setattr(la, "_TILE_BYTES", 1)
+
+
+# (lanes, call length, offset, real tokens of the call, traced limit)
+@pytest.mark.parametrize("batch,L,offset,real,traced", [
+    (1, 8, 92, 8, True),        # limit 100: inside the first tile
+    (1, 8, 120, 8, True),       # limit 128: at the tile's edge
+    (1, 8, 121, 8, True),       # limit 129: one row past it
+    (1, 8, 292, 8, True),       # limit 300: not a multiple of the tile
+    (1, 16, 120, 16, True),     # a chunk that crosses the edge
+    (2, 8, 200, 8, True),       # two lanes at a uniform index
+    (1, 16, 240, 5, True),      # a padded final chunk, the pads past an edge
+    (1, 8, 504, 8, True),       # the slab's last rows
+    (1, 8, 292, 8, False),      # the same limit, a static trip count
+    (2, 16, 0, 16, True),       # a cold prefill from index 0
+])
+def test_the_tiled_path_reads_the_rows_it_can_see_and_no_others(
+        tiles_of_128, batch, L, offset, real, traced):
+    """The expanded path against a slab of four tiles holds plain
+    attention over the whole slab under the position mask; and the rows
+    it must not read are poisoned: every tile wholly past ``limit`` is
+    NaN, and the stale rows of the last live tile (past the call's own)
+    are large and finite (the mask multiplies them by an exact zero).
+    The output does not move."""
+    T, scale = 4 * TILE, (NOPE + ROPE) ** -0.5
+    q, latent, w_kvb = latent_inputs(L, T, seed=offset + L, batch=batch)
+    limit = offset + L
+    q_pos = offset + jnp.broadcast_to(jnp.arange(L), (batch, L))
+    mask = jnp.arange(T)[None, None, :] <= q_pos[:, :, None]
+    want = plain(q, latent, w_kvb, mask, scale)
+    edge = -(-limit // TILE) * TILE             # whole tiles up to it
+    poisoned = latent.at[:, limit:edge].set(3e4).at[:, edge:].set(jnp.nan)
+    attend = jax.jit(
+        lambda rows, n: la.expanded_attention(
+            q, rows, w_kvb, q_pos, n if traced else limit, rank=RANK,
+            nope=NOPE, scale=scale))
+    for rows in (latent, poisoned):
+        got = attend(rows, jnp.asarray(limit, jnp.int32))
+        assert np.isfinite(np.asarray(got)).all()
+        # pad queries (past ``real``) see their own garbage rows only
+        close(got[:, :real], want[:, :real])
+    hlo = attend.lower(latent, jnp.asarray(limit, jnp.int32)).as_text()
+    assert ("stablehlo.while" in hlo) == (traced or edge > TILE)
+
+
+@pytest.mark.parametrize("L", [24, TILE, 3 * TILE])
+def test_a_forward_that_is_not_decoding_is_the_same_function(tiles_of_128, L):
+    """``T == L``, causal, a static trip count over the call's own rows
+    (one pass where they fit a tile): values and gradients equal plain
+    attention's."""
+    scale = (NOPE + ROPE) ** -0.5
+    q, latent, w_kvb = latent_inputs(L, L, seed=L)
+    q_pos = jnp.broadcast_to(jnp.arange(L), (2, L))
+    mask = jnp.arange(L)[None, None, :] <= q_pos[:, :, None]
+
+    def tiled(q, latent, w_kvb):
+        return la.expanded_attention(q, latent, w_kvb, q_pos, L, rank=RANK,
+                                     nope=NOPE, scale=scale)
+
+    close(tiled(q, latent, w_kvb), plain(q, latent, w_kvb, mask, scale))
+    loss = lambda f: lambda *a: (f(*a) ** 2).sum()           # noqa: E731
+    got = jax.grad(loss(tiled), argnums=(0, 1, 2))(q, latent, w_kvb)
+    want = jax.grad(loss(lambda *a: plain(*a, mask, scale)),
+                    argnums=(0, 1, 2))(q, latent, w_kvb)
+    for g, w in zip(got, want):
+        close(g, w, 1e-4)
+    assert ("while" in jax.jit(tiled).lower(q, latent, w_kvb).as_text()
+            ) == (L > TILE)
 
 
 @pytest.mark.parametrize("lengths", [[256, 100, 0, 129], [0, 0, 0, 0],

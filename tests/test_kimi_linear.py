@@ -164,9 +164,23 @@ def fresh_cache(model, batch):
         )["cache"])
 
 
-@pytest.mark.parametrize("path", ["einsum", "kernel"])
-def test_prefill_in_chunks_then_decode_through_the_cache(params, path,
-                                                         monkeypatch):
+def tiles_of_128(monkeypatch, max_len):
+    """The expanded path's tile at its smallest, 128 rows, so that a toy
+    slab of ``max_len`` is several tiles long."""
+    from edl_tpu.ops import latent_attention
+    monkeypatch.setattr(latent_attention, "_TILE_BYTES", 1)
+    assert 128 < max_len and max_len % 128 == 0
+
+
+@pytest.mark.parametrize("path,max_len,calls", [
+    ("einsum", 128, (16, 13) + (1,) * 8),
+    ("kernel", 128, (16, 13) + (1,) * 8),
+    # a slab of four tiles of 128 rows: a chunk inside the first tile, one
+    # that crosses its edge, one that ends AT the next edge, a remainder
+    ("einsum", 512, (104, 56, 96, 13) + (1,) * 3),
+])
+def test_prefill_in_chunks_then_decode_through_the_cache(
+        params, path, max_len, calls, monkeypatch):
     """A 16-token chunk, a 13-token chunk (a remainder of the delta
     rule's chunk) with state and latent rows carried, then 8 one-token
     steps: every call's logits equal the reference's one full pass.
@@ -178,11 +192,13 @@ def test_prefill_in_chunks_then_decode_through_the_cache(params, path,
                             lambda L, mesh: L == 1 and mesh is None)
         monkeypatch.setattr(decode_attention, "applies",
                             lambda L, mesh, T: L == 1 and mesh is None)
-    model = decode_model(128)
-    ids = ids_of(37)
+    if max_len > 128:
+        tiles_of_128(monkeypatch, max_len)
+    model = decode_model(max_len)
+    ids = ids_of(sum(calls))
     want = ref.logits(CONF, params, ids)
     cache, at = fresh_cache(model, 1), 0
-    for n in (16, 13) + (1,) * 8:
+    for n in calls:
         logits, mut = model.apply(
             {"params": params, "cache": cache}, ids[:, at:at + n],
             positions=at + jnp.arange(n)[None],
@@ -311,9 +327,114 @@ def test_the_counters_are_the_hosts_recount(params, path, monkeypatch):
     assert s["latent_tokens_live"] == MLA * sum(range(11, 19))
     assert s["latent_tokens_read"] == MLA * 8 * (
         3 * 128 if path == "einsum" else 128)
+    # the prefill's expanded path: a 16-token call from row 0, one tile
+    assert s["latent_prefill_rows_live"] == MLA * 16
+    assert s["latent_prefill_rows_read"] == MLA * 128
     assert s["moe_assignments_routed"] == TOP_K * SPARSE * s["moe_tokens"]
     assert s["moe_tokens"] == 10 + 8
     assert s["moe_prefill_drops"] == 0
+
+
+def test_the_prefill_rows_are_the_hosts_recount(params, monkeypatch):
+    """A 300-token prompt through the chunk lane in chunks of 64 against
+    a slab of four tiles of 128 rows, then the same prompt again, which
+    hits the pool up to its snapshot at row 296: a call, lane and latent
+    layer, live = the call's end (offset + length, a last bucket's pads
+    counted), read = whole tiles up to it."""
+    tiles_of_128(monkeypatch, 512)
+    eng = engine(params, max_len=512, prefill_chunk=64, kv_pool_blocks=128,
+                 prefill_buckets=(8, 16, 32, 64))
+    prompt = np.asarray(ids_of(300, seed=15))[0].tolist()
+    try:
+        served(eng, prompt, 2)
+        cold = eng.stats()
+        served(eng, prompt, 2)
+        hit = eng.stats()
+    finally:
+        eng.stop()
+    ends = [64, 128, 192, 256, 256 + 64]
+    assert cold["prefill_chunks"] == len(ends)
+    assert cold["latent_prefill_rows_live"] == MLA * sum(ends)
+    assert cold["latent_prefill_rows_read"] == MLA * (128 + 128 + 256 + 256
+                                                      + 384)
+    # the suffix of 4 tokens in a bucket of 8 from row 296: three tiles
+    assert hit["kv_prefix_hits"] - cold["kv_prefix_hits"] == 1
+    assert hit["kv_prefill_tokens_skipped"] == 296
+    assert (hit["latent_prefill_rows_live"]
+            - cold["latent_prefill_rows_live"]) == MLA * (296 + 8)
+    assert (hit["latent_prefill_rows_read"]
+            - cold["latent_prefill_rows_read"]) == MLA * 384
+
+
+def test_what_the_chunk_program_holds(params, monkeypatch):
+    """The chunk program against a slab of 32 tiles: no float32 array of
+    ``[.., chunk, max_len]`` is left in it (the scores are a tile's), and
+    the latent layers' loops over tiles have no constant trip count (it
+    follows the cache index), where the delta rule's scans over the
+    chunk's blocks have one."""
+    import re
+    T, C = 4096, 16
+    tiles_of_128(monkeypatch, T)
+    eng = engine(params, max_len=T, prefill_chunk=C, kv_pool_blocks=0,
+                 kv_block=0)
+    try:
+        slab, drops = eng._chunk_start()
+        compiled = eng._chunk_mid_fn(C).lower(
+            eng._params, slab, jnp.zeros((1, C), jnp.int32), drops).compile()
+    finally:
+        eng.stop()
+    text = compiled.as_text()
+    assert not re.search(rf"f32\[[0-9,]*\b{C},{T}\]", text)
+    assert re.search(rf"f32\[[0-9,]*\b{C},128\]", text)      # a tile's
+    whiles = [ln for ln in text.splitlines() if re.search(r"= .* while\(", ln)]
+    latent = [ln for ln in whiles if "attn/latent" in ln]
+    assert len(latent) == MLA, whiles
+    assert not any("known_trip_count" in ln for ln in latent)
+    assert any("known_trip_count" in ln for ln in whiles)     # the scans
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * 2 * C * T          # under ONE head's whole-slab scores
+
+
+@pytest.mark.parametrize("stack", ["kda+latent", "ssm+global"])
+def test_the_fit_prices_a_tile_only_where_the_attention_is_latent(
+        params, stack, monkeypatch):
+    """``_require_fit`` (through a device that reports a limit) asks the
+    expanded path for its tile, once a rung of the ladder it tries, for
+    this stack; a stack with a GQA layer among its state-space layers
+    (Granite's kinds) keeps the whole-slab price and never asks."""
+    from edl_tpu.ops import latent_attention
+
+    class _Chip:
+        device_kind = "toy chip"
+
+        def memory_stats(self):
+            return {"bytes_limit": 1 << 40, "bytes_in_use": 0}
+
+    cfg = CFG
+    if stack == "ssm+global":
+        cfg = TransformerConfig(
+            vocab_size=64, num_layers=2, embed_dim=32, num_heads=2,
+            mlp_dim=64, max_len=96, dtype=jnp.float32, remat=False,
+            attention_impl="dense", layer_attn=("ssm", "global"),
+            ssm_heads=2, ssm_head_dim=16, ssm_state=8, ssm_groups=1,
+            ssm_conv=4, ssm_chunk=8)
+        params = TransformerLM(cfg).init(
+            jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    asked = []
+    tile = latent_attention.expand_block
+    monkeypatch.setattr(latent_attention, "expand_block",
+                        lambda *a: asked.append(a) or tile(*a))
+    eng = engine(params, cfg=cfg, max_len=512)
+    try:
+        monkeypatch.setattr(jax, "devices", lambda: [_Chip()])
+        asked.clear()                 # the constructor's shape traces
+        eng._require_fit(3, BLOCK, 48, 4)
+        rungs = eng.PREFILL_KS
+    finally:
+        eng.stop()
+    # the widest rung fits a device this large: one price asked, its own
+    want = [(rungs[0], 16, 2, 32, 512, jnp.float32)]
+    assert asked == (want if stack == "kda+latent" else [])
 
 
 def test_session_export_and_import_carry_latent_blocks_and_the_snapshot(
