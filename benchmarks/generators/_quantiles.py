@@ -28,6 +28,8 @@ def stratified(dist: dict, n: int) -> np.ndarray:
         return np.rint(x).astype(np.int64)
     if kind == "exponential":
         return -np.log1p(-u)          # mean -> 1 as n grows
+    if kind == "mixture":
+        return np.asarray(mixture(dist["parts"], n), np.int64)
     if kind == "listed":
         # any other distribution is a data file: its n quantiles, listed
         x = np.asarray(dist["values"])
@@ -35,6 +37,18 @@ def stratified(dist: dict, n: int) -> np.ndarray:
             raise ValueError(f"{len(x)} listed values for {n} requests")
         return x
     raise ValueError(f"unknown distribution {kind!r}")
+
+
+def mixture(parts: list[dict], n: int) -> list[int]:
+    """The n stratified quantiles of a mixture whose components do not
+    overlap (so each component's own quantiles, in the order given, are
+    the mixture's): round(share x n) of every component but the last,
+    which takes the rest.  ``{"dist": "mixture", "parts": [...]}`` in
+    a traffic file is this list at N = rate x seconds, so a rate sweep
+    changes the rate and nothing else."""
+    counts = [int(round(p["share"] * n)) for p in parts[:-1]]
+    counts.append(n - sum(counts))
+    return [int(v) for p, k in zip(parts, counts) for v in stratified(p, k)]
 
 
 def token_ids(rng: np.random.Generator, n: int, vocab: int) -> list[int]:

@@ -17,11 +17,34 @@ def percentile(values: list[float], p: float) -> float:
 
 
 def window_token_rate(records: list[dict], t0: float, seconds: float) -> float:
-    """Open loop: output tokens of the answers that arrived at the
-    client inside [t0, t0 + seconds), per second of the window."""
-    got = sum(r["n_got"] for r in records
-              if r.get("t_done") is not None and r["ok"]
-              and t0 <= r["t_done"] < t0 + seconds)
+    """Open loop: each answered request is credited its output tokens
+    times the share of its life, send to last byte, that lies inside
+    [t0, t0 + seconds); the sum per second of the window.  A request
+    that failed or never came back is credited nothing.
+
+    While the system keeps up this is the offered rate (what leaves the
+    window by one edge enters by the other: the trace is cyclic and
+    warm traffic precedes it); under a growing backlog every life
+    lengthens, each request's share of the window shrinks and the value
+    falls.  Until PR 40 an answer's tokens were credited whole at the
+    instant of its last byte, so one long answer that ended 0.1 s
+    before or after an edge moved the value by all its tokens (12.8%
+    in one cell); now such a move is worth its tokens times 0.1 s over
+    its life.
+
+    A model, not a count of delivered tokens: an answer's tokens are
+    spread evenly over its life, queue and prefill included.  A stall
+    moves it by the work it pushes across an edge, in either direction
+    (a host that hung 3 s in the warm traffic read 7.5% ABOVE the
+    offered rate: PERF.md section 2)."""
+    t1 = t0 + seconds
+    got = 0.0
+    for r in records:
+        if not r["ok"] or r.get("t_done") is None:
+            continue
+        inside = min(r["t_done"], t1) - max(r["t_send"], t0)
+        if inside > 0:          # and so is its life, which holds it
+            got += r["n_got"] * inside / (r["t_done"] - r["t_send"])
     return got / seconds
 
 

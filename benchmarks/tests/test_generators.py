@@ -121,3 +121,34 @@ def test_a_listed_distribution_is_a_data_file():
     t["gaps"]["values"] = [1.0]
     with pytest.raises(ValueError):
         open_trace.schedule(t, 1, 45.0, 32768)
+
+
+# -- every open-loop cell's cycle can carry its metrics (PR 40)
+
+def _open_cells():
+    root = os.path.dirname(BENCH)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    bound = next(m["bound"] for m in spec["end_to_end"]
+                 if m["name"] == "serve_tokens_per_s")
+    return [(w["name"], w["traffic"], spec["run_seconds"], bound)
+            for w in spec["workloads"]
+            if traffic(w["traffic"]).get("loop") == "open"]
+
+
+OPEN_CELLS = _open_cells()
+
+
+@pytest.mark.parametrize("cell,name,seconds,bound", OPEN_CELLS,
+                         ids=[c[0] for c in OPEN_CELLS])
+def test_an_open_loop_cycle_can_carry_its_metrics(cell, name, seconds, bound):
+    """A median wants some tens of requests, a p90 at least 5 beyond
+    it, and no one answer may be worth more of the cycle's tokens than
+    the bound on the token rate (the hybrid cell's first cut, gone with
+    PR 40, had 31 requests, 3 beyond p90 and one answer of 12.8%)."""
+    prompts, outs, gaps = open_trace.cycle(traffic(name), float(seconds))
+    n = len(prompts)
+    beyond_p90 = n - int(np.ceil(0.9 * n))
+    largest = outs.max() / outs.sum()
+    assert n >= 50 and beyond_p90 >= 5 and largest < bound, (
+        cell, n, beyond_p90, largest)

@@ -17,7 +17,10 @@ from test_run_cpu import DRIVER as _DRIVER
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 TOY = os.path.join(HERE, "toy")
+# the name PR 30 gave the cell (tests/ hold it in the metrics' lists); since
+# PR 40 its traffic is the re-cut, twice the requests at 7 : 1 short to long
 CELL = "serve-hybrid-mixed-open"
+TRAFFIC = "mixed-length-open-v2"
 
 DRIVER = _DRIVER.replace('"/BENCHMARK.json"', '"/BENCHMARK-hybrid.json"')
 assert DRIVER != _DRIVER
@@ -160,13 +163,26 @@ def test_the_real_spec_and_toy_spec_name_the_same_cell():
     with open(os.path.join(TOY, "BENCHMARK-hybrid.json")) as f:
         toy = json.load(f)
     cells = {w["name"]: w for w in real["workloads"]}
+    assert [w["name"] for w in toy["workloads"]] == [CELL]
+    assert cells[CELL]["traffic"] == TRAFFIC
+    assert len(real["workloads"]) == len(cells)     # a name is one cell
     for w in toy["workloads"]:
         assert cells[w["name"]]["traffic"] == w["traffic"]
-    listed = {m["name"] for m in real["per_layer"]
-              if CELL in m.get("workloads", [])}
-    assert listed == {m["name"] for m in toy["per_layer"]}
+        assert cells[w["name"]]["chips"] == w["chips"] == 1
+        # cell by cell, the toy spec lists what the real one lists
+        for kind in ("end_to_end", "per_layer"):
+            assert {m["name"] for m in real[kind]
+                    if w["name"] in m.get("workloads", [w["name"]])} == {
+                m["name"] for m in toy[kind]
+                if w["name"] in m.get("workloads", [w["name"]])}, (
+                w["name"], kind)
     for m in toy["per_layer"]:
         importlib.import_module(f"layer_metrics.{m['name']}")
+    # the first cut's traffic went with PR 40: no cell and no file
+    assert not any("mixed-length-open" == w["traffic"]
+                   for w in real["workloads"])
+    assert not os.path.exists(os.path.join(
+        ROOT, "benchmarks", "traffic", "mixed-length-open.json"))
 
 
 def test_arch_maps_every_key_and_refuses_the_rest():
@@ -289,23 +305,56 @@ def test_new_readers_say_nothing_where_there_is_nothing(name):
         assert reader(name)(ctx(dict(COUNTERS), no_kernels)) is None
 
 
-def test_the_listed_prompts_are_the_mixture_and_fit_the_generator():
+def test_the_prompts_are_the_mixture_and_fit_the_generator():
     from generators import open_trace
     with open(os.path.join(ROOT, "benchmarks", "traffic",
-                           "mixed-length-open.json")) as f:
+                           f"{TRAFFIC}.json")) as f:
         traffic = json.load(f)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         seconds = json.load(f)["run_seconds"]
     plan = open_trace.schedule(traffic, 2147483659, float(seconds), 19200)
     reqs = [r for r in plan["requests"] if r["window"]]
     n = round(traffic["rate_per_s"] * seconds)
-    assert len(reqs) == n == len(traffic["prompt_tokens"]["values"])
+    assert len(reqs) == n == 56 and traffic["rate_per_s"] * seconds == \
+        pytest.approx(n)
+    parts = traffic["prompt_tokens"]["parts"]
+    assert traffic["prompt_tokens"]["dist"] == "mixture"
+    assert [p["share"] for p in parts] == [0.875, 0.125]
     lens = sorted(len(r["prompt"]) for r in reqs)
     short = [x for x in lens if x <= 2048]
     long = [x for x in lens if x >= 4096]
     assert len(short) + len(long) == n
-    assert abs(len(long) / n - 0.25) <= 1.0 / n
+    assert abs(len(long) / n - 0.125) <= 1.0 / n
     assert 32 <= short[0] and long[-1] <= 15360
     assert all(len(r["prompt"]) + r["max_new"] <= 16384 for r in reqs)
     shapes = open_trace.shapes(traffic, float(seconds), 16)
     assert shapes["max_total"] <= 16384
+    # a sweep's rate is this file at another rate: twice the requests
+    # are the same mixture's quantiles, still 7 : 1
+    twice = dict(traffic, rate_per_s=2 * traffic["rate_per_s"])
+    lens2 = open_trace.cycle(twice, float(seconds))[0]
+    assert len(lens2) == 2 * n and (lens2 >= 4096).sum() == 2 * len(long)
+
+
+def test_the_recut_traffic_states_its_rate_and_its_warm_traffic():
+    with open(os.path.join(ROOT, "benchmarks", "traffic",
+                           f"{TRAFFIC}.json")) as f:
+        traffic = json.load(f)
+    assert traffic["generator"] == "open_trace" and traffic["loop"] == "open"
+    # what the first cut (mixed-length-open.json, gone with PR 40) had
+    # and the re-cut keeps; a new trace_seed
+    assert traffic["trace_seed"] == 20261001 != 20260928
+    assert traffic["output_tokens"] == {
+        "dist": "lognormal", "median": 192, "sigma": 0.8, "min": 32,
+        "max": 1024}
+    assert traffic["gaps"] == {"dist": "exponential"}
+    assert (traffic["shared_prefix"], traffic["client_threads"],
+            traffic["probe_tokens"], traffic["drain_seconds"]) == (
+        False, 96, 656, 30.0)
+    # a 1,024-token answer lives 11.5 s: the warm traffic outlasts it
+    assert traffic["warm_seconds"] == 20.0 > 1024 * 0.01123
+    assert "of the knee" in traffic["rate_note"]
+    with open(os.path.join(TOY, "traffic", f"{TRAFFIC}.json")) as f:
+        toy = json.load(f)
+    assert toy["prompt_tokens"]["dist"] == "mixture"
+    assert toy["trace_seed"] == traffic["trace_seed"]

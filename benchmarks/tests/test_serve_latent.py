@@ -107,7 +107,7 @@ def test_the_real_spec_and_toy_spec_name_the_same_cell():
     for m in real["end_to_end"]:
         assert m["name"] == "train_tokens_per_s_per_chip" or CELL in m.get(
             "workloads", [CELL])
-    assert len(real["workloads"]) == 8
+    assert [w["name"] for w in toy["workloads"]] == [CELL]
     assert sum(w["chips"] == 4 for w in real["workloads"]) == 1
 
 
@@ -194,6 +194,7 @@ COUNTERS = {
     "window_s": 45.0, "steps_per_sync": 4,
     "ssm_state_steps": 432_000, "ssm_state_steps_run": 432_000,
     "latent_tokens_live": 230_400_000, "latent_tokens_read": 276_480_000,
+    "latent_prefill_rows_live": 2_647_040, "latent_prefill_rows_read": 3_000_320,
     "trace_span_counters": {"ssm_state_steps": 38_400,
                             "latent_tokens_live": 20_480_000},
 }
@@ -205,7 +206,7 @@ TRACE = {"window_s": 4.0,
          "modules": {"jit__step_impl": {"count": 90, "total_s": 3.6}}}
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 NEW = ["kda_step_roofline", "latent_attention_roofline",
-       "kv_latent_read_ratio"]
+       "kv_latent_read_ratio", "latent_prefill_read_ratio"]
 
 
 def ctx(counters, trace):
@@ -221,6 +222,9 @@ def test_new_readers_on_a_recorded_counter_set():
     from archs import kimi_linear as arch
     c = ctx(dict(COUNTERS), TRACE)
     assert reader("kv_latent_read_ratio")(c) == pytest.approx(1.2)
+    # the window's counts of PERF.md section 5 (my chip run 3, PR 38)
+    assert reader("latent_prefill_read_ratio")(c) == pytest.approx(
+        1.1335, abs=1e-4)
     # 38,400 live (slot, step, layer) states of 2 MiB read and written
     # in the span, against the 0.4 s of the kda_step kernel alone
     flops, nbytes = arch.kda_step_min(conf_of(), 38_400)
@@ -262,7 +266,6 @@ def test_new_readers_say_nothing_where_there_is_nothing(name):
 
 
 def test_the_traffic_fits_the_engine_and_states_its_rate():
-    import chip_kimi_sweep as sweep
     from generators import open_trace
     with open(os.path.join(ROOT, "benchmarks", "traffic",
                            "reason-long-open.json")) as f:
@@ -273,9 +276,15 @@ def test_the_traffic_fits_the_engine_and_states_its_rate():
     n = round(traffic["rate_per_s"] * seconds)
     assert traffic["generator"] == "open_trace" and traffic["loop"] == "open"
     assert traffic["trace_seed"] == 20260930
-    assert traffic["prompt_tokens"]["dist"] == "listed"
-    values = traffic["prompt_tokens"]["values"]
-    assert values == sweep.mixture_quantiles(n)
+    assert traffic["prompt_tokens"]["dist"] == "mixture"
+    assert [p["share"] for p in traffic["prompt_tokens"]["parts"]] == [
+        0.95, 0.05]
+    values = sorted(int(v) for v in open_trace.cycle(traffic,
+                                                     float(seconds))[0])
+    # the list the file held until PR 40, value for value: its ends, its
+    # three documents and its sum
+    assert values[:3] == [128, 169, 204] and values[-4:] == [
+        4096, 8345, 12288, 18094] and sum(values) == 91145
     long = [v for v in values if v >= 8192]
     assert len(long) == n - round(0.95 * n) and len(long) < 0.1 * n
     assert min(values) >= 128 and max(values) <= 24576

@@ -87,6 +87,7 @@ def test_the_real_spec_and_toy_spec_name_the_same_cell():
         toy = json.load(f)
     cells = {w["name"]: w for w in real["workloads"]}
     assert cells[CELL]["config"] == CONFIG and cells[CELL]["chips"] == 1
+    assert [w["name"] for w in toy["workloads"]] == [CELL]
     for w in toy["workloads"]:
         assert cells[w["name"]]["traffic"] == w["traffic"]
     listed = {m["name"] for m in real["per_layer"]
@@ -96,8 +97,9 @@ def test_the_real_spec_and_toy_spec_name_the_same_cell():
         importlib.import_module(f"layer_metrics.{m['name']}")
     for m in real["per_layer"]:
         if m["name"].startswith("ssm_"):
-            assert m["workloads"] == [CELL]
-    assert len(real["workloads"]) == 7
+            # a later cell with layer state shares the state counters
+            # (PR 37's): this cell leads the list
+            assert m["workloads"][0] == CELL
     assert sum(w["chips"] == 4 for w in real["workloads"]) == 1
 
 
