@@ -201,6 +201,13 @@ class TransformerConfig:
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
     mla_rope: bool = False
+    # a low-rank query (``q_lora_rank``): q = W_qb RMSNorm(W_qa y) in
+    # place of one matrix; 0 = the one matrix
+    mla_q_rank: int = 0
+    # sandwich norms (``sandwich_norm``): a second RMSNorm on each
+    # branch's OUTPUT before the residual add, x + N2(Mixer(N1(x))) and
+    # x + N4(MLP(N3(x)))
+    post_norms: bool = False
     # -- four scalars (Granite's): on the embedding, on both residual
     # branches, the attention scale in place of 1/sqrt(head_dim) (0 =
     # that default), and a divisor of the logits
@@ -330,7 +337,9 @@ def _layer_matmul_params(cfg: TransformerConfig, experts: int,
         attn = (D * cfg.kda_proj_dim + 2 * cfg.kda_head_dim * cfg.kda_inner
                 + cfg.kda_inner * D)
     elif kind == "latent":
-        attn = (D * H * (cfg.mla_nope_dim + cfg.mla_rope_dim)
+        attn = (D * cfg.mla_q_rank
+                + (cfg.mla_q_rank or D) * H * (cfg.mla_nope_dim
+                                               + cfg.mla_rope_dim)
                 + D * cfg.mla_width
                 + cfg.mla_rank * H * (cfg.mla_nope_dim + cfg.mla_v_dim)
                 + H * cfg.mla_v_dim * D)
@@ -346,7 +355,8 @@ def param_count(cfg: TransformerConfig) -> int:
     """Parameter count of the config (embedding table included; with
     ``moe_held``, of the experts this device holds)."""
     D, V = cfg.embed_dim, cfg.vocab_size
-    norms = 2 * D
+    pre = (4 if cfg.post_norms else 2) * D      # a layer's block norms
+    norms = pre
     if cfg.qk_norm:
         norms += (2 * cfg.head_dim if cfg.qk_norm_per_head
                   else (cfg.num_heads + cfg.kv_heads) * cfg.head_dim)
@@ -360,11 +370,12 @@ def param_count(cfg: TransformerConfig) -> int:
                + (cfg.ssm_inner + cfg.ssm_conv_dim + cfg.ssm_heads
                   + D if cfg.ssm_proj_bias else 0))
     # a delta-rule layer: the convolution, dt_bias, A_log and the output
-    # norm's scale; a latent layer: the latent's norm
-    own = {"ssm": 2 * D + ssm_own,
-           "kda": (2 * D + 3 * cfg.kda_inner * cfg.kda_conv + cfg.kda_inner
+    # norm's scale; a latent layer: the latent's norm and a low-rank
+    # query's
+    own = {"ssm": pre + ssm_own,
+           "kda": (pre + 3 * cfg.kda_inner * cfg.kda_conv + cfg.kda_inner
                    + cfg.kda_heads + cfg.kda_head_dim),
-           "latent": 2 * D + cfg.mla_rank}
+           "latent": pre + cfg.mla_rank + cfg.mla_q_rank}
     return V * D + head + D + sum(
         _layer_matmul_params(cfg, held, i)
         + own.get(cfg.attn_kind(i), norms)
@@ -796,7 +807,8 @@ class LatentAttention(nn.Module):
     """A multi-head latent attention mixer (``ops/latent_attention.py``
     has the two paths and the kernels): with ``y`` the normed input,
 
-    ``q = y W_q`` -> ``[H, nope + rope]``; ``[c' | k_pe] = y W_kva``;
+    ``q = y W_q`` (with ``mla_q_rank`` ``W_qb RMSNorm(y W_qa)``) ->
+    ``[H, nope + rope]``; ``[c' | k_pe] = y W_kva``;
     ``c = RMSNorm(c')``; ``[k_nope | v]_h = W_kvb[:, h]^T c``; scores
     ``(q_nope . k_nope + q_pe . k_pe) / sqrt(nope + rope)``, causal,
     float32 softmax; ``W_o``.  ``q_pe`` and ``k_pe`` are rotated only
@@ -827,8 +839,14 @@ class LatentAttention(nn.Module):
             return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
                             param_dtype=jnp.float32, name=name)
 
-        q = dense(H * (nope + rope_d), "q_proj")(y).reshape(
-            B, L, H, nope + rope_d)
+        if cfg.mla_q_rank:
+            with jax.named_scope("attn/latent_q_lora"):
+                q = dense(H * (nope + rope_d), "q_b")(
+                    RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(
+                        dense(cfg.mla_q_rank, "q_a")(y)).astype(cfg.dtype))
+        else:
+            q = dense(H * (nope + rope_d), "q_proj")(y)
+        q = q.reshape(B, L, H, nope + rope_d)
         ckv = dense(cfg.mla_width, "kv_a")(y)
         c = RMSNorm(cfg.dtype, cfg.norm_eps, name="kv_norm")(
             ckv[..., :rank]).astype(cfg.dtype)
@@ -1189,6 +1207,10 @@ class Block(nn.Module):
             mixed = LatentAttention(cfg, name="mla")(y, positions, token_mask)
         else:
             mixed = self._attention(y, positions, token_mask, kind)
+        if cfg.post_norms:
+            with jax.named_scope("attn/post_norm"):
+                mixed = RMSNorm(cfg.dtype, cfg.norm_eps,
+                                name="attn_post_norm")(mixed).astype(cfg.dtype)
         x = _pin(cfg, _residual(cfg, x, mixed), "batch", "seq", None)
         y = RMSNorm(cfg.dtype, cfg.norm_eps, name="mlp_norm")(x)
         if cfg.mlp_kind(self.layer) == "sparse":
@@ -1205,17 +1227,28 @@ class Block(nn.Module):
                             shared_dim=cfg.moe_shared_dim,
                             held=cfg.moe_held, mesh=cfg.mesh,
                             name="moe")(y, token_mask)
-            return _pin(cfg, _residual(cfg, x, y), "batch", "seq", None), aux
+            return _pin(cfg, _residual(cfg, x, self._post_mlp(y)),
+                        "batch", "seq", None), aux
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                         param_dtype=jnp.float32, name="mlp_gate")(y)
         up = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                       param_dtype=jnp.float32, name="mlp_in")(y)
         y = nn.silu(_pin(cfg, gate, "batch", "seq", "mlp")) * _pin(
             cfg, up, "batch", "seq", "mlp")
-        x = _residual(cfg, x, nn.Dense(
+        x = _residual(cfg, x, self._post_mlp(nn.Dense(
             cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name="mlp_out")(y))
+            param_dtype=jnp.float32, name="mlp_out")(y)))
         return _pin(cfg, x, "batch", "seq", None), None
+
+    def _post_mlp(self, y):
+        """The MLP branch's output through its sandwich norm, where the
+        configuration has one."""
+        cfg = self.cfg
+        if not cfg.post_norms:
+            return y
+        with jax.named_scope("mlp/post_norm"):
+            return RMSNorm(cfg.dtype, cfg.norm_eps,
+                           name="mlp_post_norm")(y).astype(cfg.dtype)
 
 
 # What a remat block keeps for its backward pass: the matmuls' outputs,

@@ -626,6 +626,12 @@ class ContinuousBatcher:
         # call's end and the whole tiles its expanded path read of them
         self._latent_prefill_live = 0
         self._latent_prefill_read = 0
+        # the one-token calls behind ``_latent_live`` (a live slot, a
+        # token step and layer); the (query, visible row) pairs of the
+        # multi-token calls' real tokens a layer, and those tokens
+        self._latent_decode_calls = 0
+        self._latent_prefill_pairs = 0
+        self._latent_prefill_tokens = 0
         # state-space layers: (slot, token step, layer) states the step
         # programs updated for live slots, and all they read and wrote;
         # positions the prefill and chunk programs ran through the scan,
@@ -840,10 +846,19 @@ class ContinuousBatcher:
             out.append((session, self._kv.chain_tokens(chain), meta, blob))
         return out
 
-    def warm(self, prompt_len: int) -> None:
+    def warm(self, prompt_len: int, chain_blocks=None,
+             chunk_finals: bool = False) -> None:
         """Compile everything serving ``prompt_len``-class prompts can
         hit — the decode step and the prefill + insert pair at every
-        PREFILL_KS sub-batch size — BEFORE traffic arrives.  A compile
+        PREFILL_KS sub-batch size — BEFORE traffic arrives.
+        ``chain_blocks`` (an iterable of chain depths in blocks) limits
+        the reuse-prefill family to the padded depths those chains
+        reach, where the caller knows its traffic's (a document cell's
+        chains are hundreds of blocks deep and never eight); None warms
+        every admissible depth.  ``chunk_finals`` also compiles the
+        chunk lane's last-chunk program for EVERY bucket up to the chunk
+        size (a long prompt's remainder can land on any of them), not
+        only the one ``prompt_len`` lands on.  A compile
         inside the serving path stalls every live lane (minutes on a
         remote-compiler backend); call this after construction, before
         submitting.  Thread-safe only while no requests are in flight —
@@ -883,18 +898,26 @@ class ContinuousBatcher:
         if self._chunk_tokens:
             # the program every chunked admission starts with, whatever
             # prompt class this call warms
-            slab, drops = jax.block_until_ready(self._chunk_start())
+            jax.block_until_ready(self._chunk_start())
             # chunk ladder: the mid-chunk body plus the final suffix
             # bucket this prompt class lands on (same fit guard as
             # _maybe_start_chunk — an unfittable split falls back to
-            # the monolithic prefill warmed above)
+            # the monolithic prefill warmed above), with
+            # ``chunk_finals`` every bucket a remainder can land on
             C = self._chunk_tokens
             off = C * ((prompt_len - 1) // C)
+            finals = ({b for b in self._buckets if b <= self._bucket(C)}
+                      if chunk_finals else set())
             if (prompt_len > C and off + self._bucket(prompt_len - off)
                     <= self._dcfg.max_len):
+                finals.add(self._bucket(prompt_len - off))
+            for Pf in sorted(finals):
+                if ("chunkfin", Pf) in self._prefill_cache:
+                    continue            # an earlier call's
+                # as an admission runs it: a start, a chunk, the last one
+                slab, drops = self._chunk_start()
                 slab, drops = self._chunk_mid_fn(C)(
                     self._params, slab, jnp.zeros((1, C), jnp.int32), drops)
-                Pf = self._bucket(prompt_len - off)
                 slab, toks, *_ = self._chunk_final_fn(Pf)(
                     self._params, slab, jnp.zeros((1, Pf), jnp.int32),
                     jnp.ones((1,), jnp.int32), drops, key,
@@ -929,8 +952,9 @@ class ContinuousBatcher:
             max_blocks = cache_len // bs
             n_pads = sorted({
                 min(1 << max(0, (n - 1).bit_length()), max_blocks)
-                for n in range(1, max_blocks + 1)
-                if n * bs + self._buckets[0] <= cache_len})
+                for n in (range(1, max_blocks + 1) if chain_blocks is None
+                          else chain_blocks)
+                if 1 <= n and n * bs + self._buckets[0] <= cache_len})
             for n_pad in n_pads:
                 # shallowest real depth that pads to n_pad — combos no
                 # admissible chain can produce must not be compiled
@@ -1013,6 +1037,13 @@ class ContinuousBatcher:
                 # up to the call's end, and the whole tiles read of them
                 "latent_prefill_rows_live": self._latent_prefill_live,
                 "latent_prefill_rows_read": self._latent_prefill_read,
+                # the one-token calls that read ``latent_tokens_live``
+                # (live slot x token step x latent layer), and the
+                # (query, visible row) pairs of the multi-token calls'
+                # real tokens, a latent layer, and those tokens (once)
+                "latent_decode_calls": self._latent_decode_calls,
+                "latent_prefill_pairs": self._latent_prefill_pairs,
+                "latent_prefill_tokens": self._latent_prefill_tokens,
                 "ssm_state_steps": self._ssm_steps,
                 "ssm_state_steps_run": self._ssm_steps_run,
                 "ssm_prefill_positions": self._ssm_prefill_pos,
@@ -1481,12 +1512,13 @@ class ContinuousBatcher:
             self._dcfg.max_len, cfg.dtype)
 
     def _count_prefill(self, lanes: int, width: int, real: int,
-                       offset: int = 0) -> None:
+                       offset: int = 0, lens=None) -> None:
         """One prefill, chunk or reuse program ran ``lanes x width``
-        positions from ``offset`` on, ``real`` of them tokens, through
-        every state-space layer's scan and every latent layer's expanded
-        path (rows up to the call's end, read in whole tiles: the plan
-        the loop itself runs under, nothing read back from the device)."""
+        positions from ``offset`` on, ``real`` of them tokens (``lens``
+        a lane where there are several), through every state-space
+        layer's scan and every latent layer's expanded path (rows up to
+        the call's end, read in whole tiles: the plan the loop itself
+        runs under, nothing read back from the device)."""
         if not (self._state_layers or self._latent_layers):
             return
         calls, end = lanes * len(self._latent_layers), offset + width
@@ -1497,6 +1529,12 @@ class ContinuousBatcher:
                 self._ssm_prefill_pad += lanes * width - real
             self._latent_prefill_live += calls * end
             self._latent_prefill_read += calls * -(-end // tk) * tk
+            if self._latent_layers:
+                self._latent_prefill_tokens += real
+                # a real token at offset + i sees offset + i + 1 rows
+                self._latent_prefill_pairs += len(self._latent_layers) * sum(
+                    n * offset + n * (n + 1) // 2
+                    for n in ([real] if lens is None else lens))
 
     @staticmethod
     def _place(cache, slab, slots, true_lens):
@@ -2109,7 +2147,7 @@ class ContinuousBatcher:
             slab, toks, drops, snap = self._prefill_fn(P, K)(
                 self._params, jnp.asarray(ids), jnp.asarray(lens), key, ends)
             self._count_enqueue()
-            self._count_prefill(K, P, int(lens.sum()))
+            self._count_prefill(K, P, int(lens.sum()), lens=lens.tolist())
             dslab = (self._draft_prefill_fn(P, K)(
                 self._draft_params, jnp.asarray(ids), jnp.asarray(lens))
                 if self._spec_k else None)
@@ -2569,6 +2607,8 @@ class ContinuousBatcher:
             if self._latent_layers:
                 self._latent_live += kv_live * len(self._latent_layers)
                 self._latent_read += latent_read
+                self._latent_decode_calls += len(held) * len(
+                    self._latent_layers)
         for i, s in mine:         # live, so it had tokens left to read
             for t in range(T):
                 tok = int(toks[i, t])

@@ -385,6 +385,10 @@ def test_engine_lookahead_share_is_declared_for_the_serve_cells():
         "moves": "serve_tokens_per_s"}
     assert cells[:4] == ["serve-chat-open", "serve-doc-sessions",
                          "serve-moe-decode-open", "serve-hybrid-mixed-open"]
+    # and every serve cell the file has since, in its order
+    assert cells == next(m["workloads"] for m in spec["end_to_end"]
+                         if m["name"] == "serve_tokens_per_s")
+    assert cells[-2:] == ["serve-latent-reason-open", "serve-mla-docs-closed"]
     layers = {m["layer"] for m in spec["per_layer"]
               if m["name"] != "engine_lookahead_share"}
     assert entry["layer"] in layers       # a layer the file already names

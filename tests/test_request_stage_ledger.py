@@ -33,10 +33,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # benchmarks/ first on its path); last here, so it shadows nothing
 sys.path.append(os.path.join(ROOT, "benchmarks"))
 CAUSE_KEYS = tuple(f"queue_wait_cause_{c}_s" for c in engine_mod.WAIT_CAUSES)
-SERVE_CELLS = ["serve-chat-open", "serve-doc-sessions",
-               "serve-moe-decode-open", "serve-hybrid-mixed-open",
-               "serve-ssm-chat-open"]
-OPEN_CELLS = [c for c in SERVE_CELLS if c != "serve-doc-sessions"]
+
+
+def _serve_cells():
+    """The serve cells as ``BENCHMARK.json`` has them, in its order:
+    every cell that reports ``serve_tokens_per_s``, and of them the open
+    loops, which are the ones that report ``serve_latency_p50_s``."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        ends = {m["name"]: m.get("workloads", [])
+                for m in json.load(f)["end_to_end"]}
+    return (list(ends["serve_tokens_per_s"]),
+            list(ends["serve_latency_p50_s"]))
+
+
+SERVE_CELLS, OPEN_CELLS = _serve_cells()
 
 
 # -- the ledger alone ---------------------------------------------------------
@@ -576,9 +586,29 @@ def test_stage_reader_is_declared_for_its_cells(name, unit, layer, moves,
     assert entry == {"name": name, "unit": unit, "better": "lower",
                      "source": "program_counter", "layer": layer,
                      "moves": moves}
-    # a later cell may be appended: these are held, the list's end is not
-    assert listed[:len(cells)] == cells
+    # every serve cell the file has, in its order: a cell that a later
+    # PR adds is held from the day it is appended
+    assert listed == cells
     moved = next(m for m in spec["end_to_end"] if m["name"] == moves)
     assert set(listed) <= set(moved["workloads"])
     assert layer in {m["layer"] for m in spec["per_layer"]
                      if m["name"] != name}
+
+
+@pytest.mark.parametrize("cell,loop", [
+    ("serve-chat-open", "open"), ("serve-doc-sessions", "closed"),
+    ("serve-moe-decode-open", "open"), ("serve-hybrid-mixed-open", "open"),
+    ("serve-ssm-chat-open", "open"), ("serve-latent-reason-open", "open"),
+    ("serve-mla-docs-closed", "closed")])
+def test_a_serve_cell_is_held_by_the_stage_readers(cell, loop):
+    """The lists above are read from the file: each cell it has today is
+    among them, an open loop among the latency readers' too."""
+    assert cell in SERVE_CELLS
+    assert (cell in OPEN_CELLS) == (loop == "open")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    traffic = next(w["traffic"] for w in spec["workloads"]
+                   if w["name"] == cell)
+    with open(os.path.join(ROOT, "benchmarks", "traffic",
+                           f"{traffic}.json")) as f:
+        assert json.load(f)["loop"] == loop
