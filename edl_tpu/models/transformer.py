@@ -285,6 +285,15 @@ class TransformerConfig:
         ``mla_width`` in whole lane tiles."""
         return latent_attention.padded_width(self.mla_width)
 
+    def mla_tiled(self, L: int) -> bool:
+        """Whether a decode model's ``L``-token call runs a latent
+        layer's expanded path as the kernel (``ops/latent_attention.
+        expand_applies``): the one rule the model dispatches by and the
+        engine counts and prices by."""
+        return latent_attention.expand_applies(
+            L, self.mesh, self.max_len, self.mla_row, self.dtype,
+            self.mla_rank, self.mla_nope_dim, self.mla_v_dim)
+
     def __post_init__(self):
         for name, plan, kinds in (
                 ("layer_attn", self.layer_attn,
@@ -823,7 +832,8 @@ class LatentAttention(nn.Module):
     slots that ``token_mask`` marks free are neither written nor read);
     a multi-token call writes its rows at the (batch-uniform) index and
     attends the slot's rows up to its own last position on the expanded
-    path, in tiles (the rows past them are not read)."""
+    path, in tiles (the rows past them are not read; on the chip the
+    kernel ``latent_expand_tiled``, elsewhere its XLA loop)."""
 
     cfg: TransformerConfig
 
@@ -860,21 +870,21 @@ class LatentAttention(nn.Module):
         w_kvb = self.param("kv_b", nn.initializers.lecun_normal(),
                            (rank, H * (nope + vd)), jnp.float32
                            ).reshape(rank, H, nope + vd)
-        attend = functools.partial(latent_attention.expanded_attention,
-                                   rank=rank, nope=nope, scale=scale)
         # the call's own rows alone, causal: a model that is not decoding
+        # (the XLA loop on every backend: it is differentiated)
         causal = functools.partial(
-            attend, q, latent, w_kvb,
-            jnp.broadcast_to(jnp.arange(L), (B, L)), L)
+            latent_attention.expanded_attention, q, latent, w_kvb,
+            jnp.broadcast_to(jnp.arange(L), (B, L)), L, rank=rank,
+            nope=nope, scale=scale)
         with jax.named_scope("attn/latent"):
             if not cfg.decode:
                 out = causal()
             else:
-                out = self._cached(q, latent, w_kvb, token_mask, attend,
-                                   causal, scale)
+                out = self._cached(q, latent, w_kvb, token_mask, causal,
+                                   scale)
         return dense(cfg.embed_dim, "o_proj")(out.reshape(B, L, H * vd))
 
-    def _cached(self, q, latent, w_kvb, token_mask, attend, causal, scale):
+    def _cached(self, q, latent, w_kvb, token_mask, causal, scale):
         cfg = self.cfg
         B, L = q.shape[:2]
         T, row = cfg.max_len, cfg.mla_row
@@ -917,9 +927,12 @@ class LatentAttention(nn.Module):
         # a reuse suffix: ``Block._decode_attention``'s contract)
         cl.value = jax.lax.dynamic_update_slice(cl.value, rows,
                                                 (0, idx[0], 0))
+        attend = (latent_attention.latent_expand_tiled if cfg.mla_tiled(L)
+                  else latent_attention.expanded_attention)
         # the slot's rows up to the call's last position, and no further
         return attend(q, cl.value, w_kvb, idx[:, None] + jnp.arange(L),
-                      idx.max() + L)
+                      idx.max() + L, rank=cfg.mla_rank,
+                      nope=cfg.mla_nope_dim, scale=scale)
 
 
 class Block(nn.Module):

@@ -1,5 +1,5 @@
 """Multi-head latent attention over a head-less cache: the expanded path,
-the absorbed path, and the one-token kernels.
+the absorbed path, and the three kernels.
 
 A latent layer caches, a token, ONE row ``(c, k_pe)`` of ``rank + rope``
 values (``c`` the normed low-rank key/value latent, ``k_pe`` the few
@@ -13,16 +13,26 @@ dims): no head axis, keys and values the same bytes.  With ``W_kvb``
 
 Two ways to compute it, equal in exact arithmetic:
 
-- **expanded** (:func:`expanded_attention`, scope ``attn/latent_expand``)
-  - ``k_nope | v`` are computed from the latent rows and plain attention
-  runs over them: multi-token calls (a full forward, a prefill, a chunk
-  against the slot's rows and its own).  It runs flash-style over tiles
-  of rows, one softmax carried across them (running maximum, sum and a
-  float32 accumulator), up to the call's last position and no further:
-  the trip count follows the cache index, so a chunk against a long
-  slab reads the rows it can see; a tile (:func:`expand_block`) keeps
-  its float32 scores and expanded rows within ``_TILE_BYTES`` whatever
-  the slab's length is.
+- **expanded** (scope ``attn/latent_expand``) - ``k_nope | v`` are
+  computed from the latent rows and plain attention runs over them:
+  multi-token calls (a full forward, a prefill, a chunk against the
+  slot's rows and its own).  It runs flash-style over tiles of rows,
+  one softmax carried across them (running maximum, sum and a float32
+  accumulator), up to the call's last position and no further: the trip
+  count follows the cache index, so a chunk against a long slab reads
+  the rows it can see.  :func:`expanded_attention` is that as an XLA
+  loop, whose tile's float32 scores and expanded rows pass through HBM
+  (within ``_TILE_BYTES`` whatever the slab's length is): every other
+  backend's path, a mesh's, the differentiated forward's, and the
+  kernel's parity reference.  On the chip a decode model's multi-token
+  call takes :func:`latent_expand_tiled` (the ``pallas_call`` of that
+  name: the grid over lanes and blocks of heads, a block's queries and
+  its slice of ``W_kvb`` resident, a loop inside the kernel over the
+  tiles up to the scalar-prefetched limit; a tile of rows is copied as
+  it lies in the cache, expanded on the MXU inside the kernel for the
+  block's heads, met by ALL the call's queries once, and neither the
+  expanded rows nor the scores leave VMEM).  :func:`expand_block` says
+  how many rows a tile of the path that runs holds.
 - **absorbed** (:func:`absorb` / :func:`unabsorb`, scope
   ``attn/latent_absorb``) - ``W_K`` moves onto the query (``q~_h =
   W_K[:, h] q_nope_h``, ``rank`` wide) and ``W_V`` onto the output
@@ -38,11 +48,14 @@ Two ways to compute it, equal in exact arithmetic:
   is the same as einsums over the whole slab: every other backend's path
   and the kernels' parity reference.
 
-:func:`applies` is the dispatch rule, from what the caller can observe
-and nothing else.  Precision is the slabs' (``ops/decode_attention``):
-input-dtype matmuls accumulated in float32, scores and softmax
-statistics in float32, probabilities cast to the cache dtype before the
-second matmul.
+Which call takes which: a one-token step ``latent_append`` +
+``latent_attend`` where :func:`applies` holds; a decode model's
+multi-token call ``latent_expand_tiled`` where :func:`expand_applies`
+holds; everything else the einsums and the loop.  Both rules are
+functions of what the caller can observe and nothing else.  Precision is
+the slabs' (``ops/decode_attention``) on every path: input-dtype matmuls
+accumulated in float32, scores and softmax statistics in float32,
+probabilities cast to the cache dtype before the second matmul.
 """
 
 from __future__ import annotations
@@ -55,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from edl_tpu.ops import decode_attention
+from edl_tpu.ops.attention import _on_tpu
 from edl_tpu.ops.decode_attention import (
     _NEG, _VMEM_LIMIT, _fetch_plan, _interpret, _sublanes,
 )
@@ -63,8 +77,14 @@ _LANES = 128
 # bytes of one tile of latent rows of the attend kernel (double buffered)
 _BLOCK_BYTES = 1 << 20
 # float32 scores and expanded keys and values of one tile of the
-# expanded path
+# expanded path's XLA loop (in HBM)
 _TILE_BYTES = 48 << 20
+# of the expanded path's kernel (in VMEM): one head's float32 scores of
+# a tile, and one head block's float32 expansion of it, a block at
+# least ``_MIN_HEADS`` heads (a tile's copy and one matmul serve them)
+_SCORE_BYTES = 2 << 20
+_EXPAND_BYTES = 4 << 20
+_MIN_HEADS = 4
 
 
 def applies(L: int, mesh, max_len: int) -> bool:
@@ -72,6 +92,20 @@ def applies(L: int, mesh, max_len: int) -> bool:
     applies``: a one-token step on a TPU, no mesh, a lane-tiled time
     axis)."""
     return decode_attention.applies(L, mesh, max_len)
+
+
+def expand_applies(L: int, mesh, T: int, row: int, dtype, *widths) -> bool:
+    """Whether a multi-token call takes the kernel
+    (:func:`latent_expand_tiled`) and not the XLA loop
+    (:func:`expanded_attention`): on a TPU, no mesh, ``L`` whole sublane
+    tiles of ``dtype`` whose scores against one lane tile of rows fit
+    the kernel's budget, and the slab's length ``T``, its ``row`` and
+    every one of ``widths`` (the rank and the heads' nope and value
+    widths: the kernel slices rows and expansions there) whole lane
+    tiles."""
+    return (mesh is None and L > 1 and L % _sublanes(dtype) == 0
+            and 4 * L * _LANES <= _SCORE_BYTES
+            and not any(n % _LANES for n in (T, row, *widths)) and _on_tpu())
 
 
 def padded_width(width: int) -> int:
@@ -91,21 +125,48 @@ def cache_rows(latent, row: int, dtype):
 
 # -- expanded ---------------------------------------------------------------
 
-def expand_block(lanes: int, L: int, H: int, kv: int, T: int, dtype) -> int:
+def expand_block(lanes: int, L: int, H: int, kv: int, T: int, dtype,
+                 kernel: bool = False) -> int:
     """Rows one tile of the expanded path reads of a ``T``-row slab for
-    ``lanes`` calls of ``L`` queries and ``H`` heads: the largest
-    power-of-two multiple of 128 that divides ``T`` and keeps what the
-    tile holds - its ``[lanes, H, L, rows]`` float32 scores and the
-    rows' keys and values expanded for every head, ``kv = nope + v`` of
-    ``dtype`` a head - within ``_TILE_BYTES``; a slab that is not whole
-    lane tiles is one tile."""
+    ``lanes`` calls of ``L`` queries and ``H`` heads, on the path that
+    runs.  The XLA loop's: the largest power-of-two multiple of 128
+    that divides ``T`` and keeps what the tile holds - its ``[lanes, H,
+    L, rows]`` float32 scores and the rows' keys and values expanded for
+    every head, ``kv = nope + v`` of ``dtype`` a head - within
+    ``_TILE_BYTES``; a slab that is not whole lane tiles is one tile.
+    The ``kernel``'s (a lane and a block of heads at a time, so neither
+    counts): the largest such multiple that keeps one head's ``[L,
+    rows]`` float32 scores within ``_SCORE_BYTES`` and ``_MIN_HEADS``
+    heads' float32 expansion of the rows within ``_EXPAND_BYTES`` (1024
+    rows for a chunk of 256 or 512 queries: the chip's sweep, PERF.md
+    section 6, PR 42)."""
     if T % _LANES:
         return T
-    row = lanes * H * (4 * L + kv * jnp.dtype(dtype).itemsize)
+    if kernel:
+        def fits(tk):
+            return (4 * L * tk <= _SCORE_BYTES
+                    and 4 * _MIN_HEADS * kv * tk <= _EXPAND_BYTES)
+    else:
+        row = lanes * H * (4 * L + kv * jnp.dtype(dtype).itemsize)
+
+        def fits(tk):
+            return tk * row <= _TILE_BYTES
     tk = _LANES
-    while T % (2 * tk) == 0 and 2 * tk * row <= _TILE_BYTES:
+    while T % (2 * tk) == 0 and fits(2 * tk):
         tk *= 2
     return tk
+
+
+def expand_heads(H: int, kv: int, tk: int, L: int) -> int:
+    """Heads a grid step of the kernel holds: the largest power of two
+    that divides ``H`` and keeps the float32 expansion of a tile of
+    ``tk`` rows for them (one matmul), and what stays resident of them
+    across the tiles (``L`` queries a head and their accumulator),
+    within ``_EXPAND_BYTES``."""
+    hb = 1
+    while H % (2 * hb) == 0 and 2 * hb * max(tk, L) * kv * 4 <= _EXPAND_BYTES:
+        hb *= 2
+    return hb
 
 
 def expanded_attention(q, latent, w_kvb, q_pos, limit, *, rank: int,
@@ -157,6 +218,151 @@ def expanded_attention(q, latent, w_kvb, q_pos, limit, *, rank: int,
         _, l, acc = jax.lax.fori_loop(0, tiles, tile, carry)
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
     return jnp.moveaxis(out, 1, 2).astype(q.dtype)
+
+
+def _expand_kernel(lim_ref, q_ref, pos_ref, w_ref, lat_hbm, o_ref, buf, sem,
+                   m_ref, l_ref, acc_ref, kv_ref, *, tk: int, hb: int, rank: int,
+                   nope: int, rope: int, scale: float):
+    b = pl.program_id(0)
+    T = lat_hbm.shape[1]
+    kv = w_ref.shape[1] // hb
+    tiles = pl.cdiv(jnp.minimum(lim_ref[0], T), tk)
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def fetch(j, slot):
+        return pltpu.make_async_copy(
+            lat_hbm.at[b, pl.ds(pl.multiple_of(j * tk, tk), tk)],
+            buf.at[slot], sem.at[slot])
+
+    @pl.when(tiles > 0)
+    def _():
+        fetch(0, 0).start()
+
+    def tile(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < tiles)
+        def _():
+            fetch(j + 1, 1 - slot).start()
+
+        fetch(j, slot).wait()
+        rows = buf[slot]                                       # [tk, W]
+        # the tile's keys and values of the block's heads, one matmul,
+        # rounded as the loop's einsum rounds them
+        with jax.named_scope("attn/latent_expand"):
+            kvs = jnp.dot(rows[:, :rank], w_ref[...],
+                          preferred_element_type=jnp.float32
+                          ).astype(rows.dtype)                 # [tk, hb kv]
+        # head-major in scratch, so that ONE body serves the block's
+        # heads by a leading index (unrolled, the kernel's code and its
+        # compile grow with the block and it runs no faster)
+        for h in range(hb):
+            kv_ref[h] = kvs[:, h * kv:(h + 1) * kv]
+        # the rest of the row is k_pe and the row's padding: the
+        # queries' padding is zeros, and 0 x the padding must be 0
+        rest = rows[:, rank:]
+        k_pe = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, rest.shape, 1) < rope,
+            rest, jnp.zeros_like(rest))
+        pos = j * tk + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[1], tk), 1)
+        seen = pos <= pos_ref[...]                             # [L, tk]
+
+        def head(h, _):
+            kvh = kv_ref[h]                                    # [tk, kv]
+            k = jnp.concatenate([kvh[:, :nope], k_pe], axis=1)
+            s = jax.lax.dot_general(
+                q_ref[h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen, s, _NEG)
+            m_old = m_ref[h]
+            m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_old - m_new)
+            # every query sees row 0, so from the first tile on m_new is
+            # a real score and a masked row's exp(_NEG - m_new) is 0
+            p = jnp.exp(s - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(rows.dtype), kvh[:, nope:],
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+        jax.lax.fori_loop(0, hb, head, None)
+
+    jax.lax.fori_loop(0, tiles, tile, None)
+    v = kv - nope
+    for h in range(hb):
+        l = l_ref[h]
+        o_ref[:, h * v:(h + 1) * v] = (
+            acc_ref[h] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def latent_expand_tiled(q, latent, w_kvb, q_pos, limit, *, rank: int,
+                        nope: int, scale: float, interpret=None):
+    """:func:`expanded_attention` as one Pallas call (the same
+    arguments, the same contract): the grid over lanes and blocks of
+    heads (:func:`expand_heads`), a block's queries and its slice of
+    ``W_kvb`` resident while a loop inside the kernel walks the tiles of
+    rows (:func:`expand_block`) up to ``limit``, scalar-prefetched, and
+    no further: each tile is copied as it lies in the cache (two
+    buffers: the next tile's copy under this one's matmuls), expanded on
+    the MXU for the block's heads, and met by ALL ``L`` queries; the
+    expanded rows and the float32 scores never leave VMEM."""
+    B, L, H, _ = q.shape
+    kv = w_kvb.shape[-1]
+    tk = expand_block(B, L, H, kv, latent.shape[1], latent.dtype, True)
+    return _expand_call(q, latent, w_kvb, q_pos, limit, rank=rank, nope=nope,
+                        scale=scale, tk=tk, hb=expand_heads(H, kv, tk, L),
+                        interpret=_interpret(interpret))
+
+
+# jitted, so that a program's latent layers share ONE trace and ONE
+# Mosaic lowering of the kernel: un-jitted, five layers x thirty
+# programs traced and lowered it 150 times a process, a minute of every
+# warm set-up (PERF.md section 6, PR 42)
+@functools.partial(jax.jit, static_argnames=(
+    "rank", "nope", "scale", "tk", "hb", "interpret"))
+def _expand_call(q, latent, w_kvb, q_pos, limit, *, rank: int, nope: int,
+                 scale: float, tk: int, hb: int, interpret: bool):
+    B, L, H, Dq = q.shape
+    T, W = latent.shape[1:]
+    kv = w_kvb.shape[-1]
+    rope, dtype = Dq - nope, latent.dtype
+    assert T % tk == 0 and H % hb == 0 and W >= rank + rope, (T, tk, H, hb, W)
+    # head-major queries, zeros past q_pe up to the rest of a row
+    qh = jnp.pad(jnp.moveaxis(q, 2, 1).astype(dtype),
+                 ((0, 0), (0, 0), (0, 0), (0, W - rank - rope)))
+    w = w_kvb.astype(dtype).reshape(rank, H * kv)
+    lim = jnp.asarray(limit, jnp.int32).reshape(1)
+    out = pl.pallas_call(
+        functools.partial(_expand_kernel, tk=tk, hb=hb, rank=rank,
+                          nope=nope, rope=rope, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, H // hb),
+            in_specs=[
+                pl.BlockSpec((None, hb, L, qh.shape[-1]),
+                             lambda b, g, n: (b, g, 0, 0)),
+                pl.BlockSpec((None, L, 1), lambda b, g, n: (b, 0, 0)),
+                pl.BlockSpec((rank, hb * kv), lambda b, g, n: (0, g)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, L, hb * (kv - nope)),
+                                   lambda b, g, n: (b, 0, g)),
+            scratch_shapes=[pltpu.VMEM((2, tk, W), dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((hb, L, 1), jnp.float32),
+                            pltpu.VMEM((hb, L, 1), jnp.float32),
+                            pltpu.VMEM((hb, L, kv - nope), jnp.float32),
+                            pltpu.VMEM((hb, tk, kv), dtype)]),
+        out_shape=jax.ShapeDtypeStruct((B, L, H * (kv - nope)), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="latent_expand_tiled",
+    )(lim, qh, q_pos.astype(jnp.int32)[..., None], w, latent)
+    return out.reshape(B, L, H, kv - nope)
 
 
 # -- absorbed ---------------------------------------------------------------

@@ -626,6 +626,11 @@ class ContinuousBatcher:
         # call's end and the whole tiles its expanded path read of them
         self._latent_prefill_live = 0
         self._latent_prefill_read = 0
+        # those calls (a lane and layer), and the ones that ran the
+        # kernel ``latent_expand_tiled`` (the rule the program was built
+        # under: ``TransformerConfig.mla_tiled``)
+        self._latent_prefill_calls = 0
+        self._latent_prefill_kernel_calls = 0
         # the one-token calls behind ``_latent_live`` (a live slot, a
         # token step and layer); the (query, visible row) pairs of the
         # multi-token calls' real tokens a layer, and those tokens
@@ -1037,6 +1042,11 @@ class ContinuousBatcher:
                 # up to the call's end, and the whole tiles read of them
                 "latent_prefill_rows_live": self._latent_prefill_live,
                 "latent_prefill_rows_read": self._latent_prefill_read,
+                # those calls (lane x latent layer), and the ones whose
+                # expanded path was the kernel and not the XLA loop
+                "latent_prefill_calls": self._latent_prefill_calls,
+                "latent_prefill_kernel_calls":
+                    self._latent_prefill_kernel_calls,
                 # the one-token calls that read ``latent_tokens_live``
                 # (live slot x token step x latent layer), and the
                 # (query, visible row) pairs of the multi-token calls'
@@ -1336,12 +1346,19 @@ class ContinuousBatcher:
             """A lane's widest attention temporaries in a ``k``-lane
             call: one layer's float32 scores against the whole slab; of
             a latent layer's expanded path one tile of rows: its float32
-            scores and probabilities and its expanded keys and values."""
+            scores and probabilities and its expanded keys and values,
+            or, where the kernel runs it and keeps those on the chip,
+            the head-major queries, padded to a row's rest, and the
+            output."""
             if not kinds <= {"kda", "ssm", "latent"}:
                 return 4 * p_max * heads * cache_len
+            kv = cfg.mla_nope_dim + cfg.mla_v_dim
+            if self._dcfg.mla_tiled(p_max):
+                return p_max * heads * (
+                    kv + cfg.mla_row - cfg.mla_rank
+                ) * jnp.dtype(cfg.dtype).itemsize
             return self._latent_tile(k, p_max, heads) * heads * (
-                2 * 4 * p_max + (cfg.mla_nope_dim + cfg.mla_v_dim)
-                * jnp.dtype(cfg.dtype).itemsize)
+                2 * 4 * p_max + kv * jnp.dtype(cfg.dtype).itemsize)
 
         for i, k_max in enumerate(self.PREFILL_KS):
             prefill = k_max * (lane + scan + 4 * p_max * self.cfg.vocab_size
@@ -1504,12 +1521,13 @@ class ContinuousBatcher:
 
     def _latent_tile(self, lanes: int, width: int, heads: int) -> int:
         """Rows a tile of the latent layers' expanded path holds in a
-        ``lanes x width`` call (``ops/latent_attention.expand_block``)."""
+        ``lanes x width`` call, on the path that call runs
+        (``ops/latent_attention.expand_block``)."""
         from edl_tpu.ops import latent_attention
         cfg = self.cfg
         return latent_attention.expand_block(
             lanes, width, heads, cfg.mla_nope_dim + cfg.mla_v_dim,
-            self._dcfg.max_len, cfg.dtype)
+            self._dcfg.max_len, cfg.dtype, self._dcfg.mla_tiled(width))
 
     def _count_prefill(self, lanes: int, width: int, real: int,
                        offset: int = 0, lens=None) -> None:
@@ -1517,12 +1535,14 @@ class ContinuousBatcher:
         positions from ``offset`` on, ``real`` of them tokens (``lens``
         a lane where there are several), through every state-space
         layer's scan and every latent layer's expanded path (rows up to
-        the call's end, read in whole tiles: the plan the loop itself
-        runs under, nothing read back from the device)."""
+        the call's end, read in whole tiles of the path that ran, the
+        kernel or the loop: the rule and the plan the program was built
+        under, nothing read back from the device)."""
         if not (self._state_layers or self._latent_layers):
             return
         calls, end = lanes * len(self._latent_layers), offset + width
         tk = self._latent_tile(lanes, width, self.cfg.num_heads)
+        kernel = self._dcfg.mla_tiled(width)
         with self._stats_lock:
             if self._state_layers:
                 self._ssm_prefill_pos += lanes * width
@@ -1530,6 +1550,8 @@ class ContinuousBatcher:
             self._latent_prefill_live += calls * end
             self._latent_prefill_read += calls * -(-end // tk) * tk
             if self._latent_layers:
+                self._latent_prefill_calls += calls
+                self._latent_prefill_kernel_calls += calls * kernel
                 self._latent_prefill_tokens += real
                 # a real token at offset + i sees offset + i + 1 rows
                 self._latent_prefill_pairs += len(self._latent_layers) * sum(

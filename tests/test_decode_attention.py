@@ -548,6 +548,50 @@ def test_expanded_latent_path_for_v5e_holds_a_tile_of_scores(one_chip, lanes):
     assert loops and not any("known_trip_count" in ln for ln in loops)
 
 
+@pytest.mark.parametrize("lanes,L,H", [
+    (1, 512, 128),      # openPangu-Ultra-MoE's chunk
+    (8, 256, 128),      # and its widest bucketed prefill
+    (1, 256, 32),       # Kimi-Linear's chunk
+    (4, 256, 32),       # and its widest bucketed prefill
+])
+def test_expanded_latent_kernel_compiles_for_v5e_at_published_widths(
+        one_chip, lanes, L, H):
+    """``ops/latent_attention.latent_expand_tiled`` at the two served
+    latent configurations' multi-token shapes (32768 rows of 640
+    values, rank 512, heads of 128 + 64 key and 128 value dims),
+    compiled ahead of time for one v5e chip with a traced limit: the
+    Mosaic compiler takes the kernel (its VMEM budget, its aligned
+    slices), and what the program keeps in HBM beside its arguments is
+    the head-major queries and the output: no float32 scores, no
+    expanded rows, no loop."""
+    from edl_tpu.ops import latent_attention as la
+
+    T, W, kv = 32768, 640, 256
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(q, rows, w_kvb, idx):
+        return la.latent_expand_tiled(
+            q, rows, w_kvb, idx[:, None] + jnp.arange(L), idx.max() + L,
+            rank=512, nope=128, scale=192 ** -0.5, interpret=False)
+
+    with _no_compile_cache():
+        compiled = jax.jit(chunk).lower(
+            sds((lanes, L, H, 192)), sds((lanes, T, W)), sds((512, H, kv)),
+            sds((lanes,), jnp.int32)).compile()
+    tk = la.expand_block(lanes, L, H, kv, T, bf, True)
+    assert tk == 1024 and la.expand_heads(H, kv, tk, L) == 4
+    text = compiled.as_text()
+    assert "latent_expand_tiled" in text and "tpu_custom_call" in text
+    # the queries padded to a row's rest (256 wide) and the output, twice
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        2 * lanes * L * H * (256 + 128) * 2 + (1 << 20))
+    assert not re.search(r"f32\[[0-9,]*,(128|256|512|1024)\]\{", text)
+    assert not re.search(r"= .* while\(", text)
+
+
 @pytest.mark.parametrize("rows,M,H,E,chunks", [
     (320, 4096, 768, 36, (1024, 384)),    # granite-4.0-h-small, 32 x top-10
     (96, 2048, 1024, 64, (1024, 1024)),   # OLMoE, 12 slots x top-8

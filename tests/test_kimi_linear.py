@@ -433,7 +433,7 @@ def test_the_fit_prices_a_tile_only_where_the_attention_is_latent(
     finally:
         eng.stop()
     # the widest rung fits a device this large: one price asked, its own
-    want = [(rungs[0], 16, 2, 32, 512, jnp.float32)]
+    want = [(rungs[0], 16, 2, 32, 512, jnp.float32, False)]
     assert asked == (want if stack == "kda+latent" else [])
 
 
