@@ -42,6 +42,25 @@ Which path runs when:
     counted itself fetching, beside ``moe_stats``.  Off a TPU the kernel
     runs in Pallas interpret mode (the tier-1 parity tests); nothing
     selects it there.
+  - :func:`prefix_gmm`, a **multi-token ``decode`` call of a layer
+    that holds a share of its experts** (``held``: the chunk lane's
+    programs, bucketed and reuse prefill, the speculative verify), on a
+    TPU, with no mesh: what :func:`prefix_rows` tests, from the call's
+    shape.  Of the ``B*S*K`` sorted pairs only those on held experts
+    are live, about ``held / E`` of them, and they are the FRONT of the
+    sorted order.  ``ragged_dot`` lists its work per (expert, row tile)
+    and multiplies each tile whole, so its time follows the rows handed
+    in, not the live ones (16 experts x one 512-row tile for 256 live
+    rows: PERF.md section 6, PR 43).  This path gathers, runs and
+    unsorts the first ``Rb`` sorted pairs alone, through the same
+    kernel under the name ``moe_prefix_gmm`` (rows in and out held once
+    in VMEM); ``Rb`` is the most rows that fit there, and the path is
+    taken only where that is 1.25 times the live rows a uniform router
+    gives.  A call whose live pairs outgrow ``Rb`` runs the whole-rows
+    ``ragged_dot`` path instead, chosen on the device by a
+    ``jax.lax.cond`` on the count: nothing is ever dropped.  The layer
+    sows ``moe_prefix``, 1 where the call took the prefix and 0 where
+    it fell back.
 - **Capacity** (``capacity_factor > 0``, the default 1.25): the
   GShard-style path designed for the compiler rather than
   hand-scheduled all-to-alls - routing is expressed as dense
@@ -86,7 +105,9 @@ tokens, with ``held`` also ``pairs routed``: ``assignments`` are then
 the pairs that landed on held experts, and max load over mean load is
 over the held experts), and where the decode kernel ran
 ``moe_fetched`` (float32 scalar: the expert weight sets it counted
-itself fetching) - the serving engine sums all three into ``stats()``.
+itself fetching), and where a multi-token call may take the prefix
+kernel ``moe_prefix`` (float32 scalar: 1 where it did) - the serving
+engine sums all four into ``stats()``.
 """
 
 from __future__ import annotations
@@ -120,6 +141,16 @@ _ROW_BYTES = 24 << 20
 # what the kernel asks of VMEM: those rows, two chunks a projection and
 # the float32 hidden rows
 _VMEM_LIMIT = 64 << 20
+# what a multi-token call's kernel (moe_prefix_gmm) asks of the chip's
+# 128 MiB instead, and what of it the rows may take beside the weight
+# chunks and the matmuls' own temporaries: its one grid step has no
+# second block to fetch, so rows in and rows out are held once
+_PREFIX_VMEM_LIMIT = 96 << 20
+_PREFIX_ROW_BYTES = _PREFIX_VMEM_LIMIT - 4 * _CHUNK_BYTES - (8 << 20)
+# the prefix must hold this many times the pairs a uniform router would
+# land on the held experts (routers seen: up to 1.16x).  The kernel's time
+# does not follow the live rows, so only a fallback's rarity is at stake
+_PREFIX_MARGIN = 1.25
 
 
 def compute_routing(probs, top_k: int, capacity: int, valid=None,
@@ -223,10 +254,34 @@ def ragged_experts(rows, sizes, w_gate, w_in, w_out):
 def applies(S: int, mesh, rows: int, M: int, dtype) -> bool:
     """Whether a dropless call takes the decode kernel: one token a
     slot on a TPU with no mesh, the sorted rows small enough to stay in
-    VMEM.  Training, prefill, the chunk lane, the speculative verify, a
-    mesh engine and every other backend keep ``ragged_dot``."""
+    VMEM.  Training, a mesh engine and every other backend keep
+    ``ragged_dot``; so do prefill, the chunk lane and the speculative
+    verify but where :func:`prefix_rows` gives them a bound."""
     return (S == 1 and mesh is None and _gmm_row_bytes(rows, M, dtype)
             <= _ROW_BYTES and _on_tpu())
+
+
+def prefix_rows(T: int, K: int, held: int, E: int, M: int, H: int, dtype,
+                gated: bool = True, mesh=None, decode: bool = True):
+    """The rows ``Rb`` a multi-token ``decode`` call of ``T`` tokens
+    hands the kernel, or None where it stays on ``ragged_dot`` whole.
+    A layer that holds ``held`` of its ``E`` experts owns about ``T * K
+    * held / E`` of the ``T * K`` sorted pairs, all at their front: the
+    kernel runs over the first ``Rb`` of them, every row where they fit
+    VMEM (:data:`_PREFIX_ROW_BYTES`: rows in and out once, the float32
+    result and hidden rows), else the largest sublane-tile multiple that
+    does.  None where that is under :data:`_PREFIX_MARGIN` times the
+    expected live rows (no share held: every row is live), with a mesh,
+    off a TPU and outside ``decode`` (training differentiates the layer;
+    the kernel has no gradient).  From the call's shape alone."""
+    if not (held and decode and mesh is None and _on_tpu()):
+        return None
+    tile = _sublanes(dtype)
+    row = M * (2 * jnp.dtype(dtype).itemsize + 4) + (2 if gated else 1) * H * 4
+    fit = _PREFIX_ROW_BYTES // row               # rows as _gmm_rows pads them
+    Rb = T * K if _gmm_rows(T * K, dtype) <= fit else (
+        fit - _WINDOWS[-1]) // tile * tile + tile
+    return Rb if Rb >= _PREFIX_MARGIN * T * K * held / E else None
 
 
 def _gmm_rows(rows: int, dtype) -> int:
@@ -412,28 +467,50 @@ def decode_gmm(rows, sizes, w_gate, w_in, w_out, *, interpret=None):
         *gmm_chunks(M, H, w_gate is not None, rows.dtype))
 
 
+def prefix_gmm(rows, sizes, w_gate, w_in, w_out, *, bound=None,
+               interpret=None):
+    """:func:`decode_gmm` for a multi-token call's rows, as the kernel
+    ``moe_prefix_gmm``: the same walk over the touched experts, the
+    same chunks and windows, the same arithmetic; the rows in and the
+    rows out are whole in VMEM, once each, where the decode step's
+    blocks are the pipeline's two.  ``bound`` (:func:`prefix_rows`; the
+    rows handed in where None): no group reaches past it.  A window may
+    (:func:`_gmm_rows`), so the caller hands in the rows up to there
+    where it has them (they cost a gather, a pad costs a copy) and the
+    result comes back that long, ``[_gmm_rows(bound), M]``, zero past
+    the last group."""
+    M, H = w_in.shape[1:]
+    return _decode_gmm(
+        rows, sizes, w_gate, w_in, w_out, _interpret(interpret),
+        *gmm_chunks(M, H, w_gate is not None, rows.dtype),
+        rows.shape[0] if bound is None else bound)
+
+
 # a program's layers are alike: jitted, the kernel is traced and lowered
 # once a program, not once a layer
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
 def _decode_gmm(rows, sizes, w_gate, w_in, w_out, interpret: bool, tm: int,
-                th: int):
+                th: int, prefix: int = 0):
+    # prefix: 0 = the decode step's plan; else prefix_gmm's bound
     R, M = rows.shape
     H = w_in.shape[2]
     G = 1 if w_gate is None else 2
     na, nb = M // tm, H // th
-    Rp = _gmm_rows(R, rows.dtype)
+    Rp = _gmm_rows(prefix or R, rows.dtype)
     # a chunk's columns of every row together: [na, Rp, tm]
     x = jnp.pad(rows, ((0, Rp - R), (0, 0))).reshape(Rp, na, tm).swapaxes(
         0, 1)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out, count = pl.pallas_call(
         functools.partial(_gmm_kernel, gated=G == 2),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(1,),
-            in_specs=[pl.BlockSpec((na, Rp, tm), lambda *_: (0, 0, 0))]
-            + [hbm] * (G + 1),
-            out_specs=[pl.BlockSpec((Rp, M), lambda *_: (0, 0)),
-                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            in_specs=[whole if prefix else pl.BlockSpec(
+                (na, Rp, tm), lambda *_: (0, 0, 0))] + [hbm] * (G + 1),
+            out_specs=[whole if prefix else pl.BlockSpec(
+                (Rp, M), lambda *_: (0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM)],
             scratch_shapes=[
                 pltpu.VMEM((2, G, tm, H), rows.dtype),
                 pltpu.VMEM((2, th, M), rows.dtype),
@@ -445,16 +522,18 @@ def _decode_gmm(rows, sizes, w_gate, w_in, w_out, interpret: bool, tm: int,
                    jax.ShapeDtypeStruct((1,), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=_PREFIX_VMEM_LIMIT if prefix else _VMEM_LIMIT),
         interpret=interpret,
-        name="moe_decode_gmm",
+        name="moe_prefix_gmm" if prefix else "moe_decode_gmm",
     )(*_gmm_plan(sizes), x, *([] if w_gate is None else [w_gate]), w_in,
       w_out)
-    return out[:R], count[0].astype(jnp.float32) / (na + nb)
+    return (out if prefix else out[:R],
+            count[0].astype(jnp.float32) / (na + nb))
 
 
 def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
-                     held_only: bool = False, kernel: bool = False):
+                     held_only: bool = False, kernel: bool = False,
+                     prefix=None):
     """The dropless routed expert FFN over tokens ``x [T, M]`` with
     gates and expert indices ``[T, K]``: sort the ``T*K`` assignments
     by expert, the experts over the sorted rows, unsort, weight and sum
@@ -469,10 +548,32 @@ def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
     device does not hold (``MoEMLP.held``); its gate weighs nothing
     here.
 
-    Returns ``(y [T, M] in x's dtype, sizes [E] int32, fetched)`` -
-    ``sizes`` the real assignments each expert received, ``fetched`` the
-    expert weight sets the kernel counted itself fetching (None on the
-    ``ragged_dot`` path, whose reads are not the program's to count)."""
+    ``prefix`` (:func:`prefix_rows`, a multi-token call of a layer that
+    holds a share of its experts): the live pairs are the FRONT of the
+    sorted ones, so only the first ``prefix`` are gathered, run
+    (:func:`prefix_gmm`) and unsorted; a pair past them reads zero, as
+    its gate does.  A call whose live pairs outgrow ``prefix`` takes
+    the whole-rows ``ragged_dot`` path instead, chosen on the device
+    (``jax.lax.cond``): nothing is dropped either way.
+
+    Returns ``(y [T, M] in x's dtype, sizes [E] int32, fetched,
+    took_prefix)`` - ``sizes`` the real assignments each expert
+    received, ``fetched`` the expert weight sets the decode kernel
+    counted itself fetching (None on the other paths: ``ragged_dot``'s
+    reads are not the program's to count), ``took_prefix`` float32 1
+    where a call with ``prefix`` ran over it and 0 where it fell back
+    (None without ``prefix``)."""
+    if prefix is not None:
+        # a program's layers are alike, and this path carries both
+        # branches: traced and lowered once a program
+        return _prefix_experts(x, gates, idx, w_gate, w_in, w_out, valid,
+                               held_only=held_only, prefix=prefix)
+    return _dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid,
+                             held_only, kernel)
+
+
+def _dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid, held_only,
+                      kernel=False, prefix=None):
     T, K = idx.shape
     E = w_in.shape[0]
     with jax.named_scope("moe/route"):
@@ -484,26 +585,59 @@ def dropless_experts(x, gates, idx, w_gate, w_in, w_out, valid=None,
         # rows past the last group belong to pad tokens: what a grouped
         # matmul leaves there is unspecified, so they are zeroed
         in_group = jnp.arange(T * K) < sizes.sum()
-    with jax.named_scope("moe/experts"):
-        rows = x[order // K]                              # [T*K, M]
-        if kernel:
-            out, fetched = decode_gmm(rows, sizes, w_gate, w_in, w_out)
-        else:                                             # [T*K, M]
-            out, fetched = ragged_experts(rows, sizes, w_gate, w_in,
-                                          w_out), None
-    with jax.named_scope("moe/combine"):
-        out = jnp.where(in_group[:, None], out, 0)
-        # unsort with the inverse permutation (a gather, not a scatter-
-        # add) and sum each token's K rows in float32
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * K))
-        out = out[inverse].reshape(T, K, -1).astype(jnp.float32)
+
+    def weigh(out):
+        """The unsorted rows ``[T*K, M]``, each token's K weighted and
+        summed in float32."""
+        out = out.reshape(T, K, -1).astype(jnp.float32)
         w = gates.astype(jnp.float32)
         if valid is not None:
             w = w * valid[:, None]
         if held_only:
             w = w * (idx < E)
-        y = (out * w[..., None]).sum(axis=1)
-    return y.astype(x.dtype), sizes, fetched
+        return (out * w[..., None]).sum(axis=1).astype(x.dtype)
+
+    def whole():
+        with jax.named_scope("moe/experts"):
+            rows = x[order // K]                          # [T*K, M]
+            if kernel:
+                out, fetched = decode_gmm(rows, sizes, w_gate, w_in, w_out)
+            else:                                         # [T*K, M]
+                out, fetched = ragged_experts(rows, sizes, w_gate, w_in,
+                                              w_out), None
+        with jax.named_scope("moe/combine"):
+            out = jnp.where(in_group[:, None], out, 0)
+            # unsort with the inverse permutation (a gather, not a
+            # scatter-add) and sum each token's K rows in float32
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * K))
+            return weigh(out[inverse]), fetched
+
+    def front():
+        with jax.named_scope("moe/experts"):
+            # the prefix and what a window may reach past it
+            reach = min(T * K, _gmm_rows(prefix, x.dtype))
+            rows = x[order[:reach] // K]
+            # rows past the last group come back zero
+            out, _ = prefix_gmm(rows, sizes, w_gate, w_in, w_out,
+                                bound=prefix)
+        with jax.named_scope("moe/combine"):
+            # a pair past the prefix reads zero
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * K))
+            return weigh(jnp.take(out, inverse, axis=0, mode="fill",
+                                  fill_value=0))
+
+    if prefix is None:
+        y, fetched = whole()
+        return y, sizes, fetched, None
+    if prefix >= T * K:
+        return front(), sizes, None, jnp.ones((), jnp.float32)
+    fits = sizes.sum() <= prefix
+    y = jax.lax.cond(fits, front, lambda: whole()[0])
+    return y, sizes, None, fits.astype(jnp.float32)
+
+
+_prefix_experts = jax.jit(_dropless_experts,
+                          static_argnames=("held_only", "prefix"))
 
 
 class MoEMLP(nn.Module):
@@ -518,8 +652,10 @@ class MoEMLP(nn.Module):
     docstring) for every call: training, prefill, chunked prefill and
     decode run the same sort + grouped matmuls (a ``decode`` call of
     one token a slot runs them as one kernel where :func:`applies`
-    says so), nothing is dropped, and the layer's ``moe_stats`` are
-    sown into ``intermediates``.
+    says so, a multi-token one of a layer with ``held`` over the live
+    prefix of the sorted pairs where :func:`prefix_rows` gives a
+    bound), nothing is dropped, and the layer's ``moe_stats`` are sown
+    into ``intermediates``.
 
     ``capacity_factor > 0`` keeps the capacity path.  There
     ``decode=True`` (incremental generation) sends the single-token
@@ -602,15 +738,20 @@ class MoEMLP(nn.Module):
             xt = x.reshape(B * S, M).astype(dtype)
             kernel = self.decode and applies(S, self.mesh, B * S * self.top_k,
                                              M, dtype)
-            y, sizes, fetched = dropless_experts(
+            prefix = None if S == 1 else prefix_rows(
+                B * S, self.top_k, self.held, E, M, self.mlp_dim, dtype,
+                self.gated, self.mesh, self.decode)
+            y, sizes, fetched, took_prefix = dropless_experts(
                 xt, gates.reshape(B * S, -1), idx.reshape(B * S, -1),
                 None if w_gate is None else w_gate.astype(dtype),
                 w_in.astype(dtype), w_out.astype(dtype), valid,
-                held_only=bool(self.held), kernel=kernel)
-            if kernel:
-                self.sow("intermediates", "moe_fetched", fetched,
-                         init_fn=lambda: jnp.zeros((), jnp.float32),
-                         reduce_fn=lambda a, b: a + b)
+                held_only=bool(self.held), kernel=kernel, prefix=prefix)
+            for name, count in (("moe_fetched", fetched),
+                                ("moe_prefix", took_prefix)):
+                if count is not None:
+                    self.sow("intermediates", name, count,
+                             init_fn=lambda: jnp.zeros((), jnp.float32),
+                             reduce_fn=lambda a, b: a + b)
             total = sizes.sum().astype(jnp.float32)
             stats = [total, (sizes > 0).sum().astype(jnp.float32),
                      sizes.max() * Eh / jnp.maximum(total, 1.0)]

@@ -216,6 +216,15 @@ def _moe_fetched(intermediates) -> "jax.Array":
     return _sown_sum(intermediates, "moe_fetched")
 
 
+def _moe_prefix(intermediates) -> "jax.Array":
+    """Layer calls of a multi-token program whose expert FFN ran over
+    the live prefix of the sorted pairs (``MoEMLP`` sows ``moe_prefix``
+    where ``ops/moe.prefix_rows`` gives the call a bound: 1 where the
+    live pairs fit it, 0 where the call fell back to the whole rows),
+    summed over the layers."""
+    return _sown_sum(intermediates, "moe_prefix")
+
+
 def _zeros_of(shapes):
     """Zeros for a tree of ``ShapeDtypeStruct``s, traced or eager."""
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
@@ -454,6 +463,14 @@ class ContinuousBatcher:
         self._moe_acc_shape = (
             jax.ShapeDtypeStruct((6 if cfg.moe_held else 5,), jnp.float32)
             if self._moe_dropless else jax.ShapeDtypeStruct((), jnp.int32))
+        # a multi-token program of a stack that holds a share of its
+        # experts carries one entry more than the step's: the layer
+        # calls that ran over the live prefix of their sorted pairs
+        # (ops/moe.prefix_gmm; 0 where ops/moe.prefix_rows admits none)
+        self._moe_held = self._moe_dropless and bool(cfg.moe_held)
+        self._moe_prefill_acc_shape = (
+            jax.ShapeDtypeStruct((7,), jnp.float32) if self._moe_held
+            else self._moe_acc_shape)
         self._pending: "deque[_Request]" = deque()
         self._mesh = mesh
         if mesh is not None:
@@ -606,6 +623,7 @@ class ContinuousBatcher:
         self._moe_decode_experts_touched = 0
         self._moe_decode_experts_fetched = 0.0
         self._moe_prefill_groups = 0
+        self._moe_prefix_kernel_calls = 0
         self._moe_prefill_experts_touched = 0
         self._moe_prefill_max_load_sum = 0.0
         self._lane_steps = 0          # slot-steps actually dispatched
@@ -975,7 +993,8 @@ class ContinuousBatcher:
                         _, toks, *_ = self._chunk_final_fn(Pb)(
                             self._params, self._load_prefix_fn(n_pad)(
                                 *hit, self._kv.snap_arg(0)), ids, one,
-                            self._zeros(("acc",), self._moe_acc_shape, None),
+                            self._zeros(("acc",),
+                                        self._moe_prefill_acc_shape, None),
                             key, jnp.zeros((1,), jnp.int32))
                     else:
                         _, toks, *_ = self._reuse_prefill_fn(Pb, n_pad)(
@@ -1090,6 +1109,11 @@ class ContinuousBatcher:
                 "moe_decode_experts_fetched":
                     round(self._moe_decode_experts_fetched, 3),
                 "moe_prefill_groups": self._moe_prefill_groups,
+                # of those, the layer calls whose experts ran over the
+                # live prefix of the sorted pairs alone (ops/moe.
+                # prefix_gmm): a stack that holds a share of its
+                # experts, on the chip; 0 where ragged_dot runs them all
+                "moe_prefix_kernel_calls": self._moe_prefix_kernel_calls,
                 "moe_prefill_experts_touched":
                     self._moe_prefill_experts_touched,
                 "moe_prefill_max_load_sum":
@@ -1484,7 +1508,7 @@ class ContinuousBatcher:
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
             # MoE capacity overflow at prefill (0 for dense configs)
-            return (mut["cache"], toks, _moe_stats(mut.get("intermediates")),
+            return (mut["cache"], toks, self._prefill_moe(mut),
                     self._snap_of(mut))
 
         fn = jax.jit(prefill)
@@ -2235,7 +2259,7 @@ class ContinuousBatcher:
                   NamedSharding(self._mesh, PartitionSpec()))
         return self._zeros(
             ("chunk_start",),
-            (self._cache_shapes(1), self._moe_acc_shape), sh)
+            (self._cache_shapes(1), self._moe_prefill_acc_shape), sh)
 
     def _advance_chunk(self):
         """Dispatch ONE chunk of the in-flight chunked admission (no
@@ -2305,8 +2329,7 @@ class ContinuousBatcher:
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(C)[None, :],
                 mutable=["cache", "intermediates"])
-            return mut["cache"], drops_in + _moe_stats(
-                mut.get("intermediates"))
+            return mut["cache"], drops_in + self._prefill_moe(mut)
 
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -2337,7 +2360,7 @@ class ContinuousBatcher:
                 logits, (rel_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
             return (mut["cache"], toks,
-                    drops_in + _moe_stats(mut.get("intermediates")),
+                    drops_in + self._prefill_moe(mut),
                     self._snap_of(mut))
 
         if self._mesh is not None:
@@ -2440,7 +2463,8 @@ class ContinuousBatcher:
                 slab, toks, drops, snap = self._chunk_final_fn(P)(
                     self._params, self._load_prefix_fn(n_pad)(*hit, snap_id),
                     jnp.asarray(ids), n_real,
-                    self._zeros(("acc",), self._moe_acc_shape, None), key, at)
+                    self._zeros(("acc",), self._moe_prefill_acc_shape, None),
+                    key, at)
             else:
                 slab, toks, drops, snap = self._reuse_prefill_fn(P, n_pad)(
                     self._params, *hit, jnp.asarray(ids), n_real, key,
@@ -2489,7 +2513,7 @@ class ContinuousBatcher:
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
-            return (mut["cache"], toks, _moe_stats(mut.get("intermediates")),
+            return (mut["cache"], toks, self._prefill_moe(mut),
                     None)
 
         fn = jax.jit(prefill)
@@ -2569,18 +2593,33 @@ class ContinuousBatcher:
         live[active] = True
         return jnp.asarray(live)
 
+    def _prefill_moe(self, mut):
+        """A multi-token program's expert counters: ``_moe_stats`` and,
+        where the stack holds a share of its experts, last the layer
+        calls that ran over the live prefix of their sorted pairs
+        (``_moe_prefix``)."""
+        stats = _moe_stats(mut.get("intermediates"))
+        if self._moe_held:
+            stats = jnp.concatenate(
+                [stats, _moe_prefix(mut.get("intermediates"))[None]])
+        return stats
+
     def _count_moe(self, moe: np.ndarray, tokens: int, decode: bool,
                    fetched: float = 0.0) -> None:
         """Add one program's expert counters (generate._moe_stats: a
-        drop count alone, or the dropless path's vector) to stats(),
-        and beside them the ``tokens`` the host knows it routed;
-        ``fetched``: a step program's ``_moe_fetched``."""
+        drop count alone, or the dropless path's vector; a prefill
+        program's ``_prefill_moe``) to stats(), and beside them the
+        ``tokens`` the host knows it routed; ``fetched``: a step
+        program's ``_moe_fetched``."""
         with self._stats_lock:
             if self._dcfg.moe_experts:
                 self._moe_tokens += tokens
             if moe.ndim == 0:
                 self._moe_drops += int(moe)
                 return
+            if self._moe_held and not decode:
+                self._moe_prefix_kernel_calls += int(moe[-1])
+                moe = moe[:-1]
             drops, assigned, touched, load, *routed, calls = moe.tolist()
             self._moe_drops += int(drops)
             self._moe_assignments += int(assigned)

@@ -620,6 +620,35 @@ def test_expert_decode_kernel_compiles_for_v5e_at_published_widths(
     assert "moe_decode_gmm" in compiled.as_text()
 
 
+@pytest.mark.parametrize("T,K,held,E,M,H,bound", [
+    (512, 8, 16, 256, 7680, 2048, 848),     # openPangu-Ultra-MoE, a chunk
+    (256, 8, 16, 128, 6144, 2048, 1040),    # K-EXAONE, a chunk
+    (256, 8, 64, 256, 2304, 1024, 2048),    # Kimi-Linear: every row fits
+    (32, 10, 36, 72, 4096, 768, 320),       # granite, a bucket of 32 alone
+    (256, 10, 36, 72, 4096, 768, 1824),     # granite, a chunk: half live
+])
+def test_expert_prefix_kernel_compiles_for_v5e_at_published_widths(
+        one_chip, monkeypatch, T, K, held, E, M, H, bound):
+    """``ops/moe.prefix_gmm`` over the bound ``prefix_rows`` gives the
+    held configurations' multi-token calls, compiled ahead of time for
+    one v5e chip: the rows in and out whole in VMEM beside the float32
+    result, the hidden rows and the weight chunks, within what the
+    kernel asks of the chip's 128 MiB."""
+    from edl_tpu.ops import moe
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    assert moe.prefix_rows(T, K, held, E, M, H, jnp.bfloat16) == bound
+    with _no_compile_cache():
+        compiled = jax.jit(lambda *a: moe.prefix_gmm(
+            *a, interpret=False)).lower(
+                sds((bound, M)), sds((held,), jnp.int32), sds((held, M, H)),
+                sds((held, M, H)), sds((held, H, M))).compile()
+    assert "moe_prefix_gmm" in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def four_chips():
     from jax.experimental import topologies

@@ -292,9 +292,10 @@ def test_decode_kernel_equals_the_ragged_dot_path(case, monkeypatch):
     args = (x, gates, jnp.asarray(idx, jnp.int32), w[0] if gated else None,
             w[1], w[2], None if valid is None else jnp.asarray(valid))
     kw = dict(held_only=bool((idx >= E).any()))
-    want, sizes, unread = moe.dropless_experts(*args, **kw)
-    got, sizes_k, fetched = moe.dropless_experts(*args, kernel=True, **kw)
-    assert unread is None
+    want, sizes, unread, _ = moe.dropless_experts(*args, **kw)
+    got, sizes_k, fetched, took = moe.dropless_experts(*args, kernel=True,
+                                                       **kw)
+    assert unread is None and took is None
     assert got.dtype == want.dtype == x.dtype
     np.testing.assert_array_equal(np.asarray(sizes_k), np.asarray(sizes))
     want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
@@ -359,3 +360,154 @@ def test_moe_layer_takes_the_kernel_on_a_decode_shaped_call_only(
     assert not calls
     assert "moe_fetched" not in mut["intermediates"]
     assert "moe_fetched" not in wide["intermediates"]
+
+
+# -- a multi-token call's live prefix (ops/moe.prefix_gmm) ----------------
+
+def _front(T, K, router, held, live, seed):
+    """``idx [T, K]`` of a ``router`` wide router of which exactly
+    ``live`` pairs (None: as the draw falls) land on the ``held``
+    experts this device has."""
+    if live is None:
+        return _spread(T, K, router, seed)
+    rng = np.random.default_rng(seed)
+    flat = rng.integers(held, router, size=T * K)
+    at = rng.permutation(T * K)[:live]
+    flat[at] = rng.integers(0, held, size=live)
+    return flat.reshape(T, K)
+
+
+# name: (M, H, router, held, K, T, dtype, real tokens or None, live pairs
+# or None for the draw's, prefix).  The four held configurations' shares
+# and widths cut to test size: openPangu 16 of 256 at 7680 x 2048,
+# K-EXAONE 16 of 128 at 6144 x 2048, Kimi-Linear 64 of 256 at 2304 x
+# 1024, granite 36 of 72 at 4096 x 768 top-10.
+PREFIX_CASES = {
+    "pangu_sixteenth": (256, 64, 64, 4, 8, 32, "bfloat16", None, None, 48),
+    "exaone_eighth": (384, 128, 32, 4, 8, 16, "bfloat16", None, None, 32),
+    "kimi_quarter": (128, 64, 32, 8, 8, 16, "bfloat16", None, None, 64),
+    "granite_half_every_row": (
+        128, 24, 12, 6, 10, 8, "bfloat16", None, None, 80),
+    "padded_last_bucket": (
+        256, 64, 64, 4, 8, 32, "bfloat16", 19, None, 48),
+    "live_pairs_fill_the_prefix": (
+        128, 32, 32, 4, 4, 24, "bfloat16", None, 32, 32),
+    "one_pair_over_takes_the_whole_rows": (
+        128, 32, 32, 4, 4, 24, "bfloat16", None, 33, 32),
+    "no_live_pair": (128, 32, 32, 4, 4, 24, "bfloat16", None, 0, 32),
+    "verify_pass_k_plus_1": (
+        128, 32, 32, 4, 8, 3 * 5, "bfloat16", None, None, 3 * 5 * 8),
+    "float32_ungated_prefix_off_the_tile": (
+        64, 32, 16, 4, 2, 20, "float32", 17, None, 24),
+}
+
+
+@pytest.mark.parametrize("case", list(PREFIX_CASES))
+def test_prefix_kernel_equals_the_ragged_dot_path(case):
+    """``dropless_experts`` over the live prefix of the sorted pairs
+    (``moe_prefix_gmm``, interpret mode here) against the same call on
+    the whole rows and ``ragged_dot``: the output within the operands'
+    rounding, the sizes equal, pad tokens zero; a call whose live pairs
+    outgrow the prefix IS the whole-rows path, bit for bit, and says
+    so."""
+    from edl_tpu.ops import moe
+
+    M, H, router, held, K, T, dtype, real, live, Rb = PREFIX_CASES[case]
+    gated = "ungated" not in case
+    idx = _front(T, K, router, held, live, 21)
+    valid = None if real is None else jnp.arange(T) < real
+    k = jax.random.split(jax.random.key(12), 5)
+    x = jax.random.normal(k[0], (T, M), dtype)
+    gates = jax.nn.softmax(jax.random.normal(k[1], (T, K)), axis=-1)
+    w = [(jax.random.normal(kk, shape) * shape[1] ** -0.5).astype(dtype)
+         for kk, shape in zip(k[2:], [(held, M, H), (held, M, H),
+                                      (held, H, M)])]
+    args = (x, gates, jnp.asarray(idx, jnp.int32), w[0] if gated else None,
+            w[1], w[2], valid)
+    want, sizes, *_ = moe.dropless_experts(*args, held_only=True)
+    got, sizes_p, fetched, took = jax.jit(
+        lambda *a: moe.dropless_experts(*a, held_only=True, prefix=Rb))(*args)
+    assert fetched is None
+    np.testing.assert_array_equal(np.asarray(sizes_p), np.asarray(sizes))
+    pairs = int(np.asarray(sizes).sum())
+    if live is not None:
+        assert pairs == live
+    assert got.dtype == want.dtype == x.dtype
+    assert float(took) == (pairs <= Rb)
+    want, got = np.asarray(want, np.float32), np.asarray(got, np.float32)
+    if pairs > Rb:
+        np.testing.assert_array_equal(got, want)
+    else:
+        eps = float(jnp.finfo(dtype).eps)
+        assert np.abs(got - want).max() <= 4 * eps * max(
+            np.abs(want).max(), 1.0)
+        assert pairs == 0 or np.abs(got).max() > 0
+    if valid is not None:
+        assert not got[~np.asarray(valid)].any()
+
+
+def test_prefix_rule_is_shape_share_mesh_and_backend_only(monkeypatch):
+    """``prefix_rows`` from what the call can observe: a bound for a
+    chunk of the four held configurations (every row where they fit
+    VMEM), None where what fits is under the margin over the expected
+    live rows, where no share is held, with a mesh, outside ``decode``
+    and off a TPU."""
+    from edl_tpu.ops import moe
+
+    bf16 = jnp.bfloat16
+    pangu = (512, 8, 16, 256, 7680, 2048, bf16)
+    assert moe.prefix_rows(*pangu) is None              # this backend: no TPU
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    Rb = moe.prefix_rows(*pangu)
+    assert Rb % 16 == 0 and moe._PREFIX_MARGIN * 256 <= Rb < 4096
+    # rows in and out once, float32 result and hidden rows, as padded
+    assert (moe._gmm_rows(Rb, bf16) * (7680 * 8 + 2 * 2048 * 4)
+            <= moe._PREFIX_ROW_BYTES
+            < moe._gmm_rows(Rb + 16, bf16) * (7680 * 8 + 2 * 2048 * 4))
+    exaone = moe.prefix_rows(256, 8, 16, 128, 6144, 2048, bf16)
+    assert exaone % 16 == 0 and moe._PREFIX_MARGIN * 256 <= exaone < 2048
+    assert moe.prefix_rows(256, 8, 64, 256, 2304, 1024, bf16) == 2048  # Kimi
+    # granite: half of 2,560 rows live, 1.425 times that fit; of a chunk
+    # twice as long the same rows are 0.71 times the live ones
+    assert moe.prefix_rows(256, 10, 36, 72, 4096, 768, bf16) == 1824
+    assert moe.prefix_rows(512, 10, 36, 72, 4096, 768, bf16) is None
+    assert moe.prefix_rows(64, 10, 36, 72, 4096, 768, bf16) == 640
+    assert moe.prefix_rows(64, 10, 60, 72, 4096, 768, bf16) is None
+    assert moe.prefix_rows(5, 8, 16, 256, 7680, 2048, bf16) == 40  # k + 1
+    assert moe.prefix_rows(512, 8, 0, 256, 7680, 2048, bf16) is None
+    assert moe.prefix_rows(*pangu, mesh=object()) is None
+    assert moe.prefix_rows(*pangu, decode=False) is None
+    # an ungated layer has one hidden row a row: more rows fit
+    assert moe.prefix_rows(*pangu, gated=False) > Rb
+
+
+def test_held_layer_outside_decode_still_differentiates(monkeypatch):
+    """Training differentiates the layer: without ``decode`` a held
+    layer never takes the kernel, whatever the backend, and its
+    gradients reach every weight; the same call under ``decode`` takes
+    the prefix and sows that it did."""
+    from edl_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    kw = dict(num_experts=16, mlp_dim=32, top_k=4, capacity_factor=0.0,
+              dtype=jnp.float32, gated=True, held=4, router="sigmoid")
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 12, 16)),
+                    jnp.float32)
+    layer = MoEMLP(**kw)
+    params = layer.init(jax.random.key(0), x)["params"]
+
+    def loss(p):
+        (y, aux), mut = layer.apply({"params": p}, x,
+                                    mutable=["intermediates"])
+        assert "moe_prefix" not in mut["intermediates"]
+        return (y ** 2).mean() + 0.01 * aux
+
+    g = jax.grad(loss)(params)
+    for name in ("gate", "w_gate", "w_in", "w_out"):
+        assert float(jnp.abs(g[name]).max()) > 0, f"no grad through {name}"
+    (want, _), _ = layer.apply({"params": params}, x,
+                               mutable=["intermediates"])
+    (got, _), mut = MoEMLP(decode=True, **kw).apply(
+        {"params": params}, x, mutable=["intermediates"])
+    assert float(mut["intermediates"]["moe_prefix"]) == 1.0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -359,6 +359,39 @@ def test_the_counters_are_the_hosts_recount(params):
             == LAYERS * 41 * 42 // 2)
 
 
+def test_a_chunked_prompt_counts_the_prefix_kernels_calls(params,
+                                                          monkeypatch):
+    """A 41-token prompt is three multi-token programs (chunks of 16 and
+    16, a last bucket of 16 holding 9): where ``ops/moe.prefix_rows``
+    gives their expert layers a bound (forced here: this backend is no
+    TPU, the kernel runs in interpret mode) every sparse layer call
+    takes the prefix kernel and the engine says so; on the CPU's own
+    path none does.  The pairs counted and the tokens served are the
+    same either way."""
+    from edl_tpu.ops import moe
+
+    prompt = np.asarray(ids_of(41, seed=15))[0].tolist()
+    got = {}
+    for forced in (False, True):
+        if forced:
+            monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+        eng = engine(params, max_len=128)
+        try:
+            got[forced] = served(eng, prompt, 5), eng.stats()
+        finally:
+            eng.stop()
+    (toks, s), (toks_k, k) = got[False], got[True]
+    assert toks == toks_k == greedy(params, prompt, 5)
+    assert s["moe_prefill_groups"] == k["moe_prefill_groups"] == SPARSE * 3
+    assert s["moe_prefix_kernel_calls"] == 0
+    assert k["moe_prefix_kernel_calls"] == SPARSE * 3
+    for name in ("moe_assignments", "moe_assignments_routed", "moe_tokens",
+                 "moe_prefill_drops", "moe_prefill_experts_touched",
+                 "moe_decode_layer_steps", "moe_decode_experts_touched"):
+        assert s[name] == k[name], name
+    assert s["moe_prefill_drops"] == 0 < s["moe_assignments"]
+
+
 def test_an_all_latent_stack_builds_fits_and_warms(params, monkeypatch):
     """A stack with ONLY the latent class: the slot is rows alone,
     ``_require_fit`` (through a device that reports a limit) counts one
