@@ -15,6 +15,7 @@ top-k truncation and/or top-p nucleus.  Deterministic under a fixed
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 
 import jax
@@ -203,42 +204,49 @@ def generate(cfg: TransformerConfig, params, prompt, max_new_tokens: int,
     return (out, drops) if return_drops else out
 
 
+def sown(intermediates) -> dict:
+    """``{sown name: its leaves}`` of what a program's layers sowed
+    into ``intermediates``, a leaf a layer call."""
+    out = collections.defaultdict(list)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            intermediates or {}):
+        out[[k.key for k in path if hasattr(k, "key")][-1]].append(leaf)
+    return out
+
+
 def _sum_drops(intermediates) -> "jax.Array":
     """Total ``moe_drops`` over all layers (0 for dense configs)."""
-    import jax.numpy as jnp
-
-    total = jnp.zeros((), jnp.int32)
-    if not intermediates:
-        return total
-    for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
-        if any(getattr(k, "key", None) == "moe_drops" for k in path):
-            total = total + jnp.asarray(leaf, jnp.int32).sum()
-    return total
+    return sum((jnp.asarray(leaf, jnp.int32).sum()
+                for leaf in sown(intermediates).get("moe_drops", ())),
+               jnp.zeros((), jnp.int32))
 
 
-def _moe_stats(intermediates) -> "jax.Array":
-    """What the expert layers of one program sowed, for the host.
+def sown_layout(*intermediates) -> tuple:
+    """``((sown name, width), ...)`` of everything sown in any of
+    ``intermediates`` (shapes suffice), by name."""
+    return tuple(sorted({
+        name: leaves[0].shape[-1] if leaves[0].ndim else 1
+        for inter in intermediates
+        for name, leaves in sown(inter).items()}.items()))
 
-    Without a ``moe_stats`` leaf (dense configurations, and the capacity
-    path) this IS :func:`_sum_drops`, an int32 scalar - their programs
-    stay what they were.  With the dropless path it is a float32 vector
-    ``[drops, assignments, experts touched, load ratios, layer calls]``:
-    each layer call's ``moe_stats`` summed, and how many there were
-    (ops/moe.py; every entry is a count or a small ratio, exact in
-    float32 at serving sizes).  Layers that hold a share of their
-    experts sow a fourth entry, and the vector is ``[drops, assignments,
-    experts touched, load ratios, pairs routed, layer calls]``."""
-    import jax.numpy as jnp
 
-    leaves = [leaf for path, leaf in
-              jax.tree_util.tree_leaves_with_path(intermediates or {})
-              if any(getattr(k, "key", None) == "moe_stats" for k in path)]
-    drops = _sum_drops(intermediates)
-    if not leaves:
-        return drops
-    width = leaves[0].shape[-1]         # 3, or 4 with held experts
-    total = sum(jnp.asarray(leaf, jnp.float32).reshape(-1, width).sum(0)
-                for leaf in leaves)
-    calls = sum(leaf.size // width for leaf in leaves)
-    return jnp.concatenate([drops.astype(jnp.float32)[None], total,
-                            jnp.asarray([calls], jnp.float32)])
+def sown_vector(intermediates, layout: tuple) -> "jax.Array":
+    """What a program's layers sowed, for the host, as ONE vector: for
+    each ``(name, width)`` of ``layout`` the ``width`` entries summed
+    over the layer calls, then how many calls sowed it; float32 (every
+    entry is a count or a small ratio, exact at serving sizes), zeros
+    for a name this program did not sow, zero-width for an empty layout
+    (the serving engine's programs return it;
+    ``serving/model_counters.py`` books it)."""
+    got, parts = sown(intermediates), [jnp.zeros((0,), jnp.float32)]
+    if set(got) - {name for name, _ in layout}:
+        raise ValueError(
+            f"a program's layers sow {sorted(got)}; the layout found at "
+            f"construction holds {layout}")
+    for name, width in layout:
+        rows = [jnp.asarray(leaf, jnp.float32).reshape(-1, width)
+                for leaf in got.get(name, ())]
+        parts += [sum((r.sum(0) for r in rows),
+                      jnp.zeros((width,), jnp.float32)),
+                  jnp.full((1,), sum(len(r) for r in rows), jnp.float32)]
+    return jnp.concatenate(parts)

@@ -62,7 +62,7 @@ live slot and no chunk in flight: waiting for requests; time BETWEEN
 ticks), then inside a tick ``tasks`` (closures from other threads),
 ``admit`` (queue drain, prefix matching, prefill/chunk dispatches),
 ``dispatch`` (the decode step or speculative round and the inserts),
-``sync`` (the ``np.asarray`` reads of the PREVIOUS tick's programs:
+``sync`` (the one ``device_get`` of the PREVIOUS tick's programs:
 blocked on the device, which meanwhile holds this tick's), ``finish``
 (that tick's tokens to slots and futures) and, nested in it and
 deducted from it, ``kv_commit``.  ``lookahead_ticks`` counts the ticks
@@ -115,6 +115,7 @@ occupancy (``chunk_lane_busy_s``).  There is no switch.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -126,13 +127,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from edl_tpu.models.generate import (_moe_stats, _split_layer_params,
-                                     sample_logits)
+from edl_tpu.models.generate import (_split_layer_params, sample_logits,
+                                     sown_layout, sown_vector)
 from edl_tpu.models.transformer import TransformerConfig, TransformerLM
 from edl_tpu.obs import context as obs_context
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.obs import trace as obs_trace
 from edl_tpu.obs.ledger import RequestStageLedger, StepPhaseLedger
+from edl_tpu.serving import cache_layout, model_counters
+from edl_tpu.serving.kv_cache import PagedKVCache, pool_device_bytes
 from edl_tpu.utils import constants
 from edl_tpu.utils.logger import get_logger
 
@@ -182,52 +185,27 @@ _INTERTOKEN_SECONDS = obs_metrics.histogram(
     "(done - first token) / (tokens - 1)", buckets=_FINE_BUCKETS)
 
 
-def _sown_sum(intermediates, name: str) -> "jax.Array":
-    """Every leaf a program's layers sowed under ``name``, summed: a
-    float32 scalar (zero where no layer sowed it)."""
-    return sum((jnp.asarray(leaf, jnp.float32).sum() for path, leaf in
-                jax.tree_util.tree_leaves_with_path(intermediates or {})
-                if any(getattr(k, "key", None) == name for k in path)),
-               jnp.zeros((), jnp.float32))
-
-
-def _ssm_slots_run(intermediates) -> "jax.Array":
-    """Slot states the one-token updates of a program's state-space
-    layers read and wrote (``Mamba2Mixer`` sows ``ssm_slots_run``: on
-    the chip counted from the kernel's fetch plan), summed over the
-    layers."""
-    return _sown_sum(intermediates, "ssm_slots_run")
-
-
-def _latent_read(intermediates) -> "jax.Array":
-    """Positions the one-token reads of a program's latent attention
-    layers fetched of the slots' rows (``LatentAttention`` sows
-    ``latent_tokens_read``: on the chip counted from the kernel's fetch
-    plan), summed over the layers."""
-    return _sown_sum(intermediates, "latent_tokens_read")
-
-
-def _moe_fetched(intermediates) -> "jax.Array":
-    """Expert weight sets the decode kernel of a program's expert
-    layers fetched (``MoEMLP`` sows ``moe_fetched`` where ``ops/moe.
-    decode_gmm`` runs: the kernel's own count of the chunk fetches it
-    started), summed over the layers; zero on the ``ragged_dot`` path,
-    whose reads are not the program's to count."""
-    return _sown_sum(intermediates, "moe_fetched")
-
-
-def _moe_prefix(intermediates) -> "jax.Array":
-    """Layer calls of a multi-token program whose expert FFN ran over
-    the live prefix of the sorted pairs (``MoEMLP`` sows ``moe_prefix``
-    where ``ops/moe.prefix_rows`` gives the call a bound: 1 where the
-    live pairs fit it, 0 where the call fell back to the whole rows),
-    summed over the layers."""
-    return _sown_sum(intermediates, "moe_prefix")
-
-
 def _zeros_of(shapes):
     """Zeros for a tree of ``ShapeDtypeStruct``s, traced or eager."""
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
+
+# what a prefill program lets its layers write: the cache, what they
+# count, and a recurrent layer's state at ``snap_at``
+_PREFILL_MUTABLE = ["cache", "intermediates", "snap"]
+
+
+def _compiled(key):
+    """A program family: the method builds the program of its arguments,
+    and the engine keeps one a ``key(*args)`` (``_prefill_cache``)."""
+    def family(build):
+        def get(self, *args):
+            fn = self._prefill_cache.get(key(*args))
+            if fn is None:
+                fn = self._prefill_cache[key(*args)] = build(self, *args)
+            return fn
+        return functools.wraps(build)(get)
+    return family
 
 
 @dataclass
@@ -295,7 +273,7 @@ class _ChunkState:
     slot: int
     offset: int           # prompt tokens already prefilled
     slab: object          # one-lane decode cache, index == offset
-    drops: object         # device MoE-drop accumulator (traced through)
+    sown: object          # device counters accumulator (traced through)
     t_start: float = 0.0  # the lane is held from here (monotonic)
 
 
@@ -309,10 +287,7 @@ class _Tick:
 
     live: list
     dec: object = None
-    moe: object = None
-    fetched: object = None
-    ssm: object = None
-    latent: object = None
+    counters: object = None       # the step's sown_vector
     counts: object = None
     pres: list = dataclasses.field(default_factory=list)
     # programs enqueued up to the one this tick's read waits for: read,
@@ -389,91 +364,54 @@ class ContinuousBatcher:
         # where an EOS can end it early, the one program the lookahead
         # had already enqueued when the host read the EOS (_tick)
         self._overrun = self._T - 1 + (self._T if eos_id is not None else 0)
-        # -- window layers (transformer.Block._ring_attention): a slot
-        # holds a ring of the window and what a snapshot for the pool
-        # reads back after the last token: up to one KV block back to
-        # the block edge it ends at, and the overrun.  A
-        # window that tiles by lanes keeps a ring that does (the
-        # one-token kernels take it, ops/decode_attention.applies).
-        self._ring_layers = frozenset(
-            f"layer_{i}" for i in range(cfg.num_layers)
-            if cfg.attn_kind(i) == "window")
-        # -- state-space and delta-rule layers (transformer.Mamba2Mixer,
-        # KDAMixer): a slot holds a fixed-size recurrence, whatever
-        # max_len is
-        self._state_layers = frozenset(
-            f"layer_{i}" for i in range(cfg.num_layers)
-            if cfg.attn_kind(i) in ("ssm", "kda"))
-        # -- latent attention layers (transformer.LatentAttention): a
-        # slot holds one head-less row a token
-        self._latent_layers = frozenset(
-            f"layer_{i}" for i in range(cfg.num_layers)
-            if cfg.attn_kind(i) == "latent")
+        # what each layer keeps for a slot and how it pages (attention_impl
+        # "dense" never reads the mesh; the decode step does,
+        # ops/decode_attention.applies: a mesh engine's sharded slabs stay
+        # on the einsum path)
+        dcfg = dataclasses.replace(cfg, decode=True, attention_impl="dense",
+                                   mesh=mesh, max_len=cache_len)
+        self._classes = cache_layout.cache_classes(dcfg)
+        # the distinct classes, in layer order
+        kinds = self._kinds = list(dict.fromkeys(self._classes.values()))
         k = constants.SPEC_K if spec_k is None else int(spec_k)
-        if self._state_layers and k > 0:
-            raise ValueError(
-                "speculative decoding (spec_k > 0) does not serve a "
-                "state-space configuration: a rejected draft rewinds the "
-                "cache index, and a recurrent state that has taken the "
-                "rejected tokens in cannot be rewound")
-        if self._state_layers and mesh is not None:
-            raise ValueError(
-                "a mesh engine does not serve a state-space configuration: "
-                "the state layers' slot state and snapshot pool have no "
-                "sharding yet (serving/kv_cache.py)")
-        if self._latent_layers and k > 0:
-            raise ValueError(
-                "speculative decoding (spec_k > 0) does not serve a "
-                "latent attention configuration: the verify step writes "
-                "each slot's candidates at its own index, and the latent "
-                "layers' multi-token path writes at one")
-        if self._latent_layers and mesh is not None:
-            raise ValueError(
-                "a mesh engine does not serve a latent attention "
-                "configuration: a latent row has no head axis to shard "
-                "over tp (serving/kv_cache.py)")
-        ring = 0
-        if self._ring_layers:
-            if k > 0:
+        for cls in kinds:
+            if k > 0 and cls.no_rewind:
                 raise ValueError(
-                    "speculative decoding (spec_k > 0) does not serve a "
-                    "window configuration: a rejected draft rewinds the "
-                    "cache index, and a window layer's ring has already "
-                    "overwritten the positions the rewound window needs")
-            if mesh is not None:
+                    f"speculative decoding (spec_k > 0) does not serve a "
+                    f"{cls.noun} configuration: {cls.no_rewind}")
+        for cls in kinds:
+            if mesh is not None and cls.no_shard:
                 raise ValueError(
-                    "a mesh engine does not serve a window configuration: "
-                    "the window layers' snapshot pool has no sharded "
-                    "gather yet (serving/kv_cache.py)")
-            W = cfg.attn_window
+                    f"a mesh engine does not serve a {cls.noun} "
+                    f"configuration: {cls.no_shard} (serving/kv_cache.py)")
+        # snapshot policy is the engine's, keyed on what the classes
+        # say: some layer pages by snapshots; some snapshot can be read
+        # out of a slot after the fact (a ring's); some only where a
+        # prefill program is AT (a recurrent state's)
+        snapped = [c for c in kinds if c.snapshotted]
+        self._snapped = bool(snapped)
+        self._ringed = any(c.from_slot for c in snapped)
+        self._recurrent = any(not c.from_slot for c in snapped)
+        # a slot of a window class holds a ring of the window and what a
+        # snapshot for the pool reads back after the last token: up to
+        # one KV block back to the block edge it ends at, and the
+        # overrun.  A window that tiles by lanes keeps a ring that does
+        # (the one-token kernels take it)
+        ring, W = 0, max(c.window for c in kinds)
+        if W:
             lanes = 128 if W % 128 == 0 else 1
             ring = -(-(W + max(kv_block, 1) + self._overrun)
                      // lanes) * lanes
-        # attention_impl "dense" never reads the mesh; the decode step
-        # does (ops/decode_attention.applies): a mesh engine's sharded
-        # slabs stay on the einsum path
-        self._dcfg = dataclasses.replace(
-            cfg, decode=True, attention_impl="dense", mesh=mesh,
-            max_len=cache_len, window_ring=ring)
+        self._dcfg = dataclasses.replace(dcfg, window_ring=ring)
         self._model = TransformerLM(self._dcfg)
-        # dropless expert path (ops/moe.py): its programs carry the
-        # layers' moe_stats vector where the others carry a drop count
-        # (one entry longer when the layers hold a share of the experts)
-        self._moe_dropless = bool(cfg.moe_experts and cfg.moe_capacity <= 0)
-        self._moe_acc_shape = (
-            jax.ShapeDtypeStruct((6 if cfg.moe_held else 5,), jnp.float32)
-            if self._moe_dropless else jax.ShapeDtypeStruct((), jnp.int32))
-        # a multi-token program of a stack that holds a share of its
-        # experts carries one entry more than the step's: the layer
-        # calls that ran over the live prefix of their sorted pairs
-        # (ops/moe.prefix_gmm; 0 where ops/moe.prefix_rows admits none)
-        self._moe_held = self._moe_dropless and bool(cfg.moe_held)
-        self._moe_prefill_acc_shape = (
-            jax.ShapeDtypeStruct((7,), jnp.float32) if self._moe_held
-            else self._moe_acc_shape)
         self._pending: "deque[_Request]" = deque()
         self._mesh = mesh
+        # a replicated output's sharding; None off a mesh, where every
+        # program's out_shardings are jax.jit's "unspecified"
+        self._rep = None
         if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._rep = NamedSharding(mesh, PartitionSpec())
             from edl_tpu.models.generate import shard_split_params
             self._params = shard_split_params(params, mesh, cfg.num_layers,
                                               rules)
@@ -514,7 +452,7 @@ class ContinuousBatcher:
         self._chunk_tokens = max(0, int(chunk))
         # the cache's layout is fixed here: shape trees per lane count
         # (_cache_shapes) and every compiled program keyed by its shapes
-        self._shape_memo: dict[tuple[str, int], object] = {}
+        self._shape_memo: dict[str, object] = {}
         self._prefill_cache: dict[tuple, object] = {}
         sessions = (constants.KV_SESSIONS if kv_max_sessions is None
                     else kv_max_sessions)
@@ -524,40 +462,16 @@ class ContinuousBatcher:
         # granite-4.0-h-small's widths where a window's is 3): as many
         # of them as _require_fit finds room for, slots / 2 at least
         n_snaps = (sessions + 2 * slots + 1
-                   if (self._ring_layers or self._state_layers)
-                   and kv_block > 0 else 0)
+                   if self._snapped and kv_block > 0 else 0)
         n_snaps = self._require_fit(slots, kv_block, pool_blocks, n_snaps)
         one_lane = self._cache_shapes(1)
-
-        def cls_of(name):
-            return ("window" if name in self._ring_layers else
-                    "state" if name in self._state_layers else
-                    "latent" if name in self._latent_layers else "global")
-
-        self._slot_bytes = {
-            cls: sum(leaf.size * leaf.dtype.itemsize
-                     for name, node in one_lane.items() if cls_of(name) == cls
-                     for leaf in jax.tree.leaves(node) if leaf.ndim > 1)
-            for cls in ("window", "global", "state", "latent")}
-        # what one window layer's decode read fetches of a slot that
-        # holds n ring positions: whole attend blocks on the kernels'
-        # path, the ring on the einsum path
-        if self._ring_layers:
-            from edl_tpu.ops import decode_attention
-            R = self._dcfg.ring_len
-            tk = (decode_attention.attend_block(
-                      cfg.kv_heads, cfg.head_dim, R, cfg.dtype)
-                  if decode_attention.applies(1, mesh, R) else R)
-            self._ring_fetch = lambda n: -(-min(n, R) // tk) * tk
         self._cache = self._fresh_cache(slots)
         # last token per slot, ON THE DEVICE: each step returns it, each
         # insert places an admission's first token in it, the next step
         # takes it.  The host never reads it (_tick)
         self._toks = jnp.zeros((slots,), jnp.int32)
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            self._toks = jax.device_put(
-                self._toks, NamedSharding(mesh, PartitionSpec()))
+            self._toks = jax.device_put(self._toks, self._rep)
         # -- paged KV block pool + prefix-reuse index (kv_cache.py) --
         # kv_block=0 keeps the engine EXACTLY on the pre-paged path (no
         # pool, no index, no extra dispatches); with a block size, every
@@ -570,27 +484,9 @@ class ContinuousBatcher:
         self._kv = None
         self._reuse = bool(prefix_reuse)
         if kv_block > 0:
-            from edl_tpu.serving.kv_cache import PagedKVCache
             self._kv = PagedKVCache(
                 one_lane, kv_block, pool_blocks, sessions, mesh=mesh,
-                ring_layers=self._ring_layers, window=cfg.attn_window,
-                n_snaps=n_snaps, state_layers=self._state_layers,
-                latent_layers=self._latent_layers)
-        slab0 = max(jax.tree.leaves(self._cache), key=lambda x: x.ndim)
-        pool0 = (jax.tree.leaves(self._kv.pool)[0]
-                 if self._kv is not None else None)
-        logger.info(
-            "kv cache: %d slots x %d tokens (%s, slab sharding %s); pool "
-            "%d blocks of %d (sharding %s); a slot holds %d bytes in %d "
-            "window layers (ring %d), %d in global layers, %d in %d latent "
-            "layers and %d in %d state layers; %d layer-state snapshots",
-            slots, cache_len,
-            slab0.dtype.name, mesh and slab0.sharding.spec, pool_blocks,
-            kv_block, mesh and pool0 is not None and pool0.sharding.spec,
-            self._slot_bytes["window"], len(self._ring_layers), ring,
-            self._slot_bytes["global"], self._slot_bytes["latent"],
-            len(self._latent_layers), self._slot_bytes["state"],
-            len(self._state_layers), n_snaps)
+                classes=self._classes, n_snaps=n_snaps)
         self._kv_hits = 0
         self._kv_misses = 0
         self._prefill_tokens = 0
@@ -613,57 +509,30 @@ class ContinuousBatcher:
         self._submitted_requests = 0  # accepted submits (enqueue lock)
         self._failed_requests = 0     # futures failed while engine lives
         self._emitted_tokens = 0
-        self._moe_drops = 0       # MoE prefill capacity overflow (see stats)
-        # the dropless expert path's counters (ops/moe.py moe_stats),
-        # cumulative; they reach the host with the tick's own sync
-        self._moe_assignments = 0
-        self._moe_assignments_routed = 0
-        self._moe_tokens = 0      # the host's own count of what was routed
-        self._moe_decode_layer_steps = 0
-        self._moe_decode_experts_touched = 0
-        self._moe_decode_experts_fetched = 0.0
-        self._moe_prefill_groups = 0
-        self._moe_prefix_kernel_calls = 0
-        self._moe_prefill_experts_touched = 0
-        self._moe_prefill_max_load_sum = 0.0
+        # what the model's layers count (model_counters.py): every
+        # program returns one vector under this layout, read with the
+        # tick's own sync
+        self._counters = model_counters.ModelCounters(
+            self._dcfg, self._classes, one_lane, slots,
+            self._sown_layout(slots), self._stats_lock)
+        self._acc_shape = jax.ShapeDtypeStruct((self._counters.width,),
+                                               jnp.float32)
+        slab0 = max(jax.tree.leaves(self._cache), key=lambda x: x.ndim)
+        pool0 = (jax.tree.leaves(self._kv.pool)[0]
+                 if self._kv is not None else None)
+        logger.info(
+            "kv cache: %d slots x %d tokens (%s, slab sharding %s); pool "
+            "%d blocks of %d (sharding %s); layers by cache class %s (ring "
+            "%d); %d layer-state snapshots; the layers sow %s",
+            slots, cache_len, slab0.dtype.name,
+            mesh and slab0.sharding.spec, pool_blocks, kv_block,
+            mesh and pool0 is not None and pool0.sharding.spec,
+            [c.kind for c in self._classes.values()], ring, n_snaps,
+            self._counters.layout)
         self._lane_steps = 0          # slot-steps actually dispatched
         self._active_lane_steps = 0   # of those, slots with live requests
-        # KV positions the plain decode step had to read (live slots, up
-        # to their length) and the positions its slabs hold, per token step
-        self._kv_tokens_live = 0
-        self._kv_tokens_slab = 0
-        # per token step and live slot, of ONE window layer: positions
-        # its read fetched, and the positions its window holds
-        self._kv_window_read = 0
-        self._kv_window_need = 0
-        # latent layers: positions their decode reads needed (a live
-        # slot's length, a token step and layer) and fetched
-        self._latent_live = 0
-        self._latent_read = 0.0
-        # and, a multi-token call, lane and layer, the rows up to the
-        # call's end and the whole tiles its expanded path read of them
-        self._latent_prefill_live = 0
-        self._latent_prefill_read = 0
-        # those calls (a lane and layer), and the ones that ran the
-        # kernel ``latent_expand_tiled`` (the rule the program was built
-        # under: ``TransformerConfig.mla_tiled``)
-        self._latent_prefill_calls = 0
-        self._latent_prefill_kernel_calls = 0
-        # the one-token calls behind ``_latent_live`` (a live slot, a
-        # token step and layer); the (query, visible row) pairs of the
-        # multi-token calls' real tokens a layer, and those tokens
-        self._latent_decode_calls = 0
-        self._latent_prefill_pairs = 0
-        self._latent_prefill_tokens = 0
-        # state-space layers: (slot, token step, layer) states the step
-        # programs updated for live slots, and all they read and wrote;
-        # positions the prefill and chunk programs ran through the scan,
-        # and those of them that were padding; snapshots not taken; and
-        # pooled tokens prefilled again for want of a snapshot
-        self._ssm_steps = 0
-        self._ssm_steps_run = 0
-        self._ssm_prefill_pos = 0
-        self._ssm_prefill_pad = 0
+        # recurrent layers' prompt snapshots not taken, and pooled tokens
+        # prefilled again for want of a snapshot
         self._state_snap_skips = 0
         self._state_reprefill = 0
         self._prefill_stall_s = 0.0   # prefill dispatch time w/ lanes live
@@ -696,28 +565,20 @@ class ContinuousBatcher:
         self._device_enqueues = 0
         self._chunk_lane_busy_s = 0.0
         self._t0 = time.monotonic()
-        if mesh is not None:
-            # pin the pool cache's sharding on every step/insert output
-            # so the layout is stable from step 1 (inference-only
-            # propagation would re-specialise the jit once per layout
-            # change and thrash the donation)
-            sh = self._cache_shardings(slots)
-            from jax.sharding import NamedSharding, PartitionSpec
-            rep = NamedSharding(mesh, PartitionSpec())
-            self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,),
-                                     out_shardings=(sh, rep, rep))
-            self._insert_jit = jax.jit(self._insert_impl,
-                                       donate_argnums=(0,),
-                                       out_shardings=(sh, rep))
-        else:
-            self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,))
-            self._insert_jit = jax.jit(self._insert_impl, donate_argnums=(0,))
+        # on a mesh, pin the pool cache's sharding on every step/insert
+        # output so the layout is stable from step 1 (inference-only
+        # propagation would re-specialise the jit once per layout change
+        # and thrash the donation)
+        sh, rep = self._cache_shardings(slots), self._rep
+        self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,),
+                                 out_shardings=(sh, rep, rep, rep))
+        self._insert_jit = jax.jit(self._insert_impl, donate_argnums=(0,),
+                                   out_shardings=(sh, rep))
         # -- speculative decoding (draft-k / verify-once rounds) --
         self._spec_k = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._draft_cache = None
-        k = constants.SPEC_K if spec_k is None else int(spec_k)
         if k > 0:
             if draft_cfg is None or draft_params is None:
                 raise ValueError(
@@ -745,10 +606,7 @@ class ContinuousBatcher:
             if mesh is not None:
                 # the draft is small by contract: replicate it (and its
                 # cache) rather than threading a second sharding family
-                from jax.sharding import NamedSharding, PartitionSpec
-                rep = NamedSharding(mesh, PartitionSpec())
-                dsplit = jax.device_put(
-                    dsplit, jax.tree.map(lambda _: rep, dsplit))
+                dsplit = jax.device_put(dsplit, rep)
             self._draft_params = dsplit
             self._draft_cache = self._draft_fresh_cache(slots)
             # the verify model shares the target's params and cache
@@ -757,22 +615,11 @@ class ContinuousBatcher:
             # own position (transformer.TransformerConfig.decode_scatter)
             self._vmodel = TransformerLM(dataclasses.replace(
                 self._dcfg, decode_scatter=True))
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-                rep = NamedSharding(mesh, PartitionSpec())
-                dsh = jax.tree.map(lambda _: rep,
-                                   self._draft_cache_shapes(slots))
-                sh = self._cache_shardings(slots)
-                self._spec_jit = jax.jit(
-                    self._spec_impl, donate_argnums=(0, 1),
-                    out_shardings=(sh, dsh, rep, rep, rep))
-                self._draft_insert_jit = jax.jit(
-                    self._place, donate_argnums=(0,), out_shardings=dsh)
-            else:
-                self._spec_jit = jax.jit(self._spec_impl,
-                                         donate_argnums=(0, 1))
-                self._draft_insert_jit = jax.jit(self._place,
-                                                 donate_argnums=(0,))
+            self._spec_jit = jax.jit(
+                self._spec_impl, donate_argnums=(0, 1),
+                out_shardings=(sh, rep, rep, rep, rep))
+            self._draft_insert_jit = jax.jit(
+                self._place, donate_argnums=(0,), out_shardings=rep)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="continuous-batcher")
         self._thread.start()
@@ -904,7 +751,7 @@ class ContinuousBatcher:
         for K in self.PREFILL_KS:   # __init__ already filtered by slots
             ids = jnp.zeros((K, P), jnp.int32)
             lens = jnp.ones((K,), jnp.int32)
-            at = (jnp.zeros((K,), jnp.int32) if self._state_layers
+            at = (jnp.zeros((K,), jnp.int32) if self._recurrent
                   else None)
             slab, toks, _, snap = self._prefill_fn(P, K)(
                 self._params, ids, lens, key, at)
@@ -938,13 +785,13 @@ class ContinuousBatcher:
                 if ("chunkfin", Pf) in self._prefill_cache:
                     continue            # an earlier call's
                 # as an admission runs it: a start, a chunk, the last one
-                slab, drops = self._chunk_start()
-                slab, drops = self._chunk_mid_fn(C)(
-                    self._params, slab, jnp.zeros((1, C), jnp.int32), drops)
+                slab, sown = self._chunk_start()
+                slab, sown = self._chunk_mid_fn(C)(
+                    self._params, slab, jnp.zeros((1, C), jnp.int32), sown)
                 slab, toks, *_ = self._chunk_final_fn(Pf)(
                     self._params, slab, jnp.zeros((1, Pf), jnp.int32),
-                    jnp.ones((1,), jnp.int32), drops, key,
-                    jnp.zeros((1,), jnp.int32) if self._state_layers
+                    jnp.ones((1,), jnp.int32), sown, key,
+                    jnp.zeros((1,), jnp.int32) if self._recurrent
                     else None)
                 jax.block_until_ready(toks)
         if self._spec_k:
@@ -961,7 +808,7 @@ class ContinuousBatcher:
             self._spec_jit.lower(self._cache, self._draft_cache,
                                  self._toks, self._params,
                                  self._draft_params).compile()
-        if self._kv is not None and self._ring_layers:
+        if self._kv is not None and self._ringed:
             # the snapshot an admission takes at its prompt's end
             self._kv.store_blocks(self._cache, 0, 0, [], warm=True)
         if self._kv is not None and self._reuse:
@@ -974,7 +821,7 @@ class ContinuousBatcher:
             cache_len = self._dcfg.max_len
             max_blocks = cache_len // bs
             n_pads = sorted({
-                min(1 << max(0, (n - 1).bit_length()), max_blocks)
+                self._pad_blocks(n)
                 for n in (range(1, max_blocks + 1) if chain_blocks is None
                           else chain_blocks)
                 if 1 <= n and n * bs + self._buckets[0] <= cache_len})
@@ -989,12 +836,11 @@ class ContinuousBatcher:
                         continue
                     ids = jnp.zeros((1, Pb), jnp.int32)
                     one = jnp.ones((1,), jnp.int32)
-                    if self._state_layers:      # _dispatch_reuse's pair
+                    if self._recurrent:         # _dispatch_reuse's pair
                         _, toks, *_ = self._chunk_final_fn(Pb)(
                             self._params, self._load_prefix_fn(n_pad)(
                                 *hit, self._kv.snap_arg(0)), ids, one,
-                            self._zeros(("acc",),
-                                        self._moe_prefill_acc_shape, None),
+                            self._zeros(("acc",), self._acc_shape, None),
                             key, jnp.zeros((1,), jnp.int32))
                     else:
                         _, toks, *_ = self._reuse_prefill_fn(Pb, n_pad)(
@@ -1017,107 +863,9 @@ class ContinuousBatcher:
                 # fraction of dispatched lane-steps that served a live
                 # request (the rest is free-slot ballast)
                 "slot_utilization": round(self._active_lane_steps / lanes, 3),
-                # per token step of the plain decode program, summed:
-                # KV positions live slots hold (prompt + emitted: what
-                # the step has to read) and positions in the slabs
-                # (slots x max_len: what an unmasked read touches)
-                "decode_kv_tokens_live": self._kv_tokens_live,
-                "decode_kv_tokens_slab": self._kv_tokens_slab,
-                # window layers (0s without one), per token step and
-                # live slot of ONE window layer: ring positions the
-                # decode read fetched (whole attend blocks on the chip,
-                # the ring off it) and positions the window holds,
-                # min(length, window): read / need is 1 when a step
-                # reads the window and no more, max_len / window when
-                # it reads a slab.  And the bytes of one slot's state in
-                # the window layers' rings (independent of max_len) and
-                # in the global layers' slabs (linear in it)
-                "decode_kv_tokens_window_read": self._kv_window_read,
-                "decode_kv_tokens_window_need": self._kv_window_need,
-                "kv_slot_bytes_window": self._slot_bytes["window"],
-                "kv_slot_bytes_global": self._slot_bytes["global"],
-                # state-space layers (0s without one): the bytes of one
-                # slot's recurrent state (independent of max_len too);
-                # (slot, token step, layer) states the step programs
-                # updated for LIVE slots, and all they read and wrote
-                # (counted by the step program from the kernel's own
-                # fetch plan: equal when free slots cost nothing; slots
-                # x steps x layers on the einsum path);
-                # positions the prefill, chunk and reuse programs ran
-                # through the scan, and those that were padding (bucket
-                # padding, masked: a scan cannot skip them for free)
-                "kv_slot_bytes_state": self._slot_bytes["state"],
-                # latent attention layers (0s without one): the bytes of
-                # one slot's rows (one row a token a layer, no head
-                # axis); per token step, live slot and layer, positions
-                # the decode read needed (the slot's length) and
-                # positions it fetched (whole tiles up to the length on
-                # the chip, counted from the kernel's fetch plan; the
-                # slab of every slot on the einsum path)
-                "kv_slot_bytes_latent": self._slot_bytes["latent"],
-                "latent_tokens_live": self._latent_live,
-                "latent_tokens_read": self._latent_read,
-                # and for the multi-token programs' expanded path: rows
-                # up to the call's end, and the whole tiles read of them
-                "latent_prefill_rows_live": self._latent_prefill_live,
-                "latent_prefill_rows_read": self._latent_prefill_read,
-                # those calls (lane x latent layer), and the ones whose
-                # expanded path was the kernel and not the XLA loop
-                "latent_prefill_calls": self._latent_prefill_calls,
-                "latent_prefill_kernel_calls":
-                    self._latent_prefill_kernel_calls,
-                # the one-token calls that read ``latent_tokens_live``
-                # (live slot x token step x latent layer), and the
-                # (query, visible row) pairs of the multi-token calls'
-                # real tokens, a latent layer, and those tokens (once)
-                "latent_decode_calls": self._latent_decode_calls,
-                "latent_prefill_pairs": self._latent_prefill_pairs,
-                "latent_prefill_tokens": self._latent_prefill_tokens,
-                "ssm_state_steps": self._ssm_steps,
-                "ssm_state_steps_run": self._ssm_steps_run,
-                "ssm_prefill_positions": self._ssm_prefill_pos,
-                "ssm_prefill_positions_pad": self._ssm_prefill_pad,
-                # MoE prefill capacity overflow (always 0 for dense
-                # configs; nonzero = raise capacity_factor)
-                "moe_prefill_drops": self._moe_drops,
-                # real tokens the host sent through an expert model's
-                # programs (prompt tokens prefilled, live slots x token
-                # steps; 0 for dense configs): on the dropless path
-                # moe_assignments == top_k x layers x moe_tokens at
-                # every instant, and a path that drops reads less
-                "moe_tokens": self._moe_tokens,
-                # dropless expert path (0s otherwise): real (token,
-                # expert) pairs routed; layer calls of decode token
-                # steps and the distinct experts they touched (ratio:
-                # experts one layer of one step read); layer calls of
-                # prefill programs, the experts they touched and their
-                # max-over-mean expert load summed (ratio: the imbalance)
-                "moe_assignments": self._moe_assignments,
-                # with a share of the experts held (moe_held): the
-                # pairs the routers ROUTED, top_k x sparse layers x
-                # moe_tokens; moe_assignments are then the pairs that
-                # landed on held experts, the ones computed here.
-                # Without a share both count the same pairs
-                "moe_assignments_routed": self._moe_assignments_routed,
-                "moe_decode_layer_steps": self._moe_decode_layer_steps,
-                "moe_decode_experts_touched":
-                    self._moe_decode_experts_touched,
-                # expert weight sets the decode kernel fetched, as the
-                # kernel counted the fetches it started (ops/moe.
-                # decode_gmm): equal to the touched when an untouched
-                # expert costs nothing; 0 where ragged_dot runs
-                "moe_decode_experts_fetched":
-                    round(self._moe_decode_experts_fetched, 3),
-                "moe_prefill_groups": self._moe_prefill_groups,
-                # of those, the layer calls whose experts ran over the
-                # live prefix of the sorted pairs alone (ops/moe.
-                # prefix_gmm): a stack that holds a share of its
-                # experts, on the chip; 0 where ragged_dot runs them all
-                "moe_prefix_kernel_calls": self._moe_prefix_kernel_calls,
-                "moe_prefill_experts_touched":
-                    self._moe_prefill_experts_touched,
-                "moe_prefill_max_load_sum":
-                    round(self._moe_prefill_max_load_sum, 3),
+                # what the model's layers count, by cache class and by
+                # sown name (model_counters.KEYS says what each is)
+                **self._counters.totals(),
                 # host-side time spent dispatching prefill work while
                 # decode lanes were live — the upper bound on decode
                 # wall-time lost to admissions (device work still
@@ -1235,7 +983,7 @@ class ContinuousBatcher:
             # deep as the blocks (an answer's end is never snapshotted:
             # a session's next turn starts from its last PROMPT's edge)
             "kv_state_snapshots": (self._kv.snaps_used()
-                                   if self._state_layers else 0),
+                                   if self._recurrent else 0),
             "kv_state_snapshot_skips": self._state_snap_skips,
             "kv_state_reprefill_tokens": self._state_reprefill,
         }
@@ -1311,7 +1059,6 @@ class ContinuousBatcher:
         ``slots // 2`` (+ the scratch entry), the prefill ladder is
         fitted beside that, and what is left over goes to snapshots, up
         to one a slot."""
-        from edl_tpu.serving.kv_cache import pool_device_bytes
         dev = (self._mesh.devices.flat[0] if self._mesh is not None
                else jax.devices()[0])
         stats = dev.memory_stats() or {}
@@ -1321,20 +1068,20 @@ class ContinuousBatcher:
         tp = dict(self._mesh.shape).get("tp", 1) if self._mesh else 1
         one_lane = self._cache_shapes(1)
         wanted = n_snaps
-        if self._state_layers and n_snaps:
+        if self._recurrent and n_snaps:
             wanted = min(wanted, slots + 1)
             n_snaps = min(n_snaps, slots // 2 + 1)
-        # every cache leaf is [lanes, ...]: one lane's bytes, with
-        # _leaf_sharding's rule (KV heads over tp where they divide)
+        # every cache leaf is [lanes, ...]: one lane's bytes, split over
+        # tp where the layer's class shards (_cache_shardings' rule)
         lane = sum(s.size * s.dtype.itemsize
-                   // (tp if s.ndim >= 2 and s.shape[1] % tp == 0 else 1)
-                   for s in jax.tree.leaves(one_lane))
+                   // (tp if s.ndim >= 2
+                       and self._classes[name].sharded(node, tp) else 1)
+                   for name, node in one_lane.items()
+                   for s in jax.tree.leaves(node))
         cache_len = self._dcfg.max_len
         def pool_bytes(n):
             return (pool_device_bytes(one_lane, kv_block, pool_blocks, tp,
-                                      self._ring_layers, self.cfg.attn_window,
-                                      n, self._state_layers,
-                                      self._latent_layers)
+                                      self._classes, n)
                     if kv_block > 0 else 0)
 
         pool = pool_bytes(n_snaps)
@@ -1351,38 +1098,14 @@ class ContinuousBatcher:
         heads = self.cfg.num_heads // (tp if self.cfg.num_heads % tp == 0
                                        else 1)
         in_use = stats.get("bytes_in_use", 0)
-        # a state-space layer's prefill: the projections' float32 copies
-        # of a lane's tokens and the scan's [heads, chunk, chunk] blocks
-        cfg = self.cfg
-        scan = 0
-        kinds = {cfg.attn_kind(i) for i in range(cfg.num_layers)}
-        if "ssm" in kinds:
-            q = min(cfg.ssm_chunk, p_max)
-            scan = 4 * (4 * p_max * (cfg.ssm_inner + cfg.ssm_conv_dim)
-                        + 3 * cfg.ssm_heads * q * q)
-        if "kda" in kinds:
-            # the projections' float32 copies and the chunk's [heads,
-            # chunk, chunk, key] decay differences
-            q = min(cfg.kda_chunk, p_max)
-            scan = max(scan, 4 * (8 * p_max * 3 * cfg.kda_inner
-                                  + 3 * cfg.kda_inner * q * q))
+        # what the layers' classes say a lane's multi-token call costs
+        # beside its cache: the widest scan's temporaries, and the
+        # widest attention's in a k-lane call
+        scan = max(c.scan_bytes(p_max) for c in self._kinds)
+
         def scores(k):
-            """A lane's widest attention temporaries in a ``k``-lane
-            call: one layer's float32 scores against the whole slab; of
-            a latent layer's expanded path one tile of rows: its float32
-            scores and probabilities and its expanded keys and values,
-            or, where the kernel runs it and keeps those on the chip,
-            the head-major queries, padded to a row's rest, and the
-            output."""
-            if not kinds <= {"kda", "ssm", "latent"}:
-                return 4 * p_max * heads * cache_len
-            kv = cfg.mla_nope_dim + cfg.mla_v_dim
-            if self._dcfg.mla_tiled(p_max):
-                return p_max * heads * (
-                    kv + cfg.mla_row - cfg.mla_rank
-                ) * jnp.dtype(cfg.dtype).itemsize
-            return self._latent_tile(k, p_max, heads) * heads * (
-                2 * 4 * p_max + kv * jnp.dtype(cfg.dtype).itemsize)
+            return max(c.scores_bytes(k, p_max, heads, cache_len)
+                       for c in self._kinds)
 
         for i, k_max in enumerate(self.PREFILL_KS):
             prefill = k_max * (lane + scan + 4 * p_max * self.cfg.vocab_size
@@ -1406,33 +1129,59 @@ class ContinuousBatcher:
             f"engine does not fit {dev.device_kind}: "
             f"{in_use * gb:.2f} GiB already resident + "
             f"{slots * lane * gb:.2f} GiB slot slabs ({slots} slots x "
-            f"{cache_len} tokens; {len(self._ring_layers)} window "
+            f"{cache_len} tokens; "
+            f"{sum(c.window > 0 for c in self._classes.values())} window "
             f"layers hold a ring of {self._dcfg.ring_len}) + "
             f"{pool * gb:.2f} GiB block pool "
-            f"({pool_blocks} blocks of {kv_block}, {n_snaps} "
-            f"{'layer-state' if self._state_layers else 'window'} "
-            f"snapshots) + "
+            f"({pool_blocks} blocks of {kv_block}, {n_snaps} " + (
+                "layer-state snapshots" if self._recurrent
+                else "window snapshots") + ") + "
             f"{prefill * gb:.2f} GiB widest prefill ({k_max} lanes x "
             f"{p_max} tokens) = {need * gb:.2f} GiB > "
             f"{limit * gb:.2f} GiB limit; lower --slots/--max_len or "
             f"set --kv_pool_blocks")
 
     def _cache_shapes(self, B: int):
-        """Shape tree of a ``B``-lane decode cache.  A pure function of
-        the decode configuration and ``B``, so ``model.init`` is traced
-        once per ``B`` (the constructor asks for 1 and ``slots``) and
-        never again: re-tracing it cost a chunked admission 250 ms of
-        host Python with the device idle (PERF.md, PR 25)."""
-        return self._memo_shapes("target", self._model, B)
+        """Shape tree of a ``B``-lane decode cache (``_lanes``)."""
+        return self._lanes("target", self._model, B)
 
-    def _memo_shapes(self, which: str, model, B: int):
-        shapes = self._shape_memo.get((which, B))
-        if shapes is None:
-            shapes = self._shape_memo[(which, B)] = jax.eval_shape(
-                lambda: model.init(
-                    jax.random.key(0), jnp.zeros((B, 1), jnp.int32),
-                    positions=jnp.zeros((B, 1), jnp.int32)))["cache"]
-        return shapes
+    def _lanes(self, which: str, model, B: int):
+        """One lane's cache shapes with every leaf's first axis (its
+        lanes: what ``_place`` scatters on) ``B`` long.  ``model.init``
+        is traced ONCE, for one lane, and never again (re-tracing it
+        cost a chunked admission 250 ms of host Python with the device
+        idle: PERF.md, PR 25)."""
+        one = self._shape_memo.get(which)
+        if one is None:
+            ids = jnp.zeros((1, 1), jnp.int32)
+            one = self._shape_memo[which] = jax.eval_shape(
+                lambda: model.init(jax.random.key(0), ids,
+                                   positions=ids))["cache"]
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((B, *s.shape[1:]), s.dtype), one)
+
+    def _sown_layout(self, slots: int) -> tuple:
+        """What this configuration's layers sow
+        (``generate.sown_layout``), read off ``eval_shape``s of the
+        decode model's two calls: the step's one-token call and the
+        narrowest multi-token call (what a kernel sows only where a
+        call is small enough for it, ``ops/moe.prefix_rows``, the
+        narrowest call sows).  ``init`` cannot say: a layer's first
+        call has no cache yet and takes another branch.  A name these
+        two missed is refused where its program is traced
+        (``sown_vector``), in ``warm()``."""
+        def sown(lanes, width):
+            def call(params):
+                ids = jnp.zeros((lanes, width), jnp.int32)
+                _, mut = self._model.apply(
+                    {"params": params,
+                     "cache": _zeros_of(self._cache_shapes(lanes))},
+                    ids, positions=ids, token_mask=ids == 0,
+                    mutable=["cache", "intermediates"])
+                return mut.get("intermediates", {})
+            return jax.eval_shape(call, self._params)
+
+        return sown_layout(sown(slots, 1), sown(1, self._buckets[0]))
 
     def _zeros(self, key: tuple, shapes, shardings):
         """A fresh zeroed cache from ONE compiled program per ``key``,
@@ -1451,23 +1200,20 @@ class ContinuousBatcher:
         return self._zeros(("zeros", B), self._cache_shapes(B),
                            self._cache_shardings(B))
 
-    def _leaf_sharding(self, s):
-        """KV buffers shard over ``tp`` on the kv-head axis (axis 1 of
-        [B, Hk, ...]) when it divides; cache_index and non-divisible
-        shapes (e.g. MQA with Hk < tp) replicate — GSPMD still shards
-        the q-head compute from the param shardings either way."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        tp = dict(self._mesh.shape).get("tp", 1)
-        if s.ndim >= 2 and tp > 1 and s.shape[1] % tp == 0:
-            return NamedSharding(self._mesh, P(None, "tp"))
-        return NamedSharding(self._mesh, P())
-
     def _cache_shardings(self, B: int):
-        """``_leaf_sharding`` over a ``B``-lane cache; None off a mesh
-        (``jax.jit``'s "unspecified")."""
+        """A ``B``-lane cache's shardings; None off a mesh (``jax.jit``'s
+        "unspecified").  A layer's buffers shard over ``tp`` on the
+        kv-head axis (axis 1 of [B, Hk, ...]) where its class says they
+        do (``CacheClass.sharded``: when the heads divide); cache_index
+        and the others (e.g. MQA with Hk < tp) replicate — GSPMD still
+        shards the q-head compute from the param shardings either way."""
         if self._mesh is None:
             return None
-        return jax.tree.map(self._leaf_sharding, self._cache_shapes(B))
+        from jax.sharding import NamedSharding
+        return jax.tree.map(
+            lambda spec: NamedSharding(self._mesh, spec),
+            cache_layout.cache_specs(self._classes, self._cache_shapes(B),
+                                     dict(self._mesh.shape).get("tp", 1)))
 
     # -- jitted pieces -------------------------------------------------------
     def _sample(self, logits, key):
@@ -1476,13 +1222,10 @@ class ContinuousBatcher:
         return sample_logits(logits, key, temperature=self._temperature,
                              top_k=self._top_k, top_p=self._top_p)
 
-
+    @_compiled(lambda P, K: (P, K))
     def _prefill_fn(self, P: int, K: int):
         """Compiled per (prompt bucket, sub-batch size): fresh K-lane
         cache, prompt kv, one sampled next token per lane."""
-        cached = self._prefill_cache.get((P, K))
-        if cached is not None:
-            return cached
         model = self._model
 
         def prefill(params, ids, true_lens, key, snap_at=None):
@@ -1499,7 +1242,7 @@ class ContinuousBatcher:
                                            ids.shape),
                 token_mask=jnp.arange(ids.shape[1])[None, :]
                 < true_lens[:, None],
-                **self._snap_kw(snap_at))
+                snap_at=snap_at, mutable=_PREFILL_MUTABLE)
             # padded prompts: sample each lane at ITS last real
             # position; the pad queries wrote kv past true_len, which
             # insertion resets (cache_index := true_len) and masks
@@ -1507,80 +1250,35 @@ class ContinuousBatcher:
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
-            # MoE capacity overflow at prefill (0 for dense configs)
-            return (mut["cache"], toks, self._prefill_moe(mut),
-                    self._snap_of(mut))
+            return (mut["cache"], toks, self._sown(mut), self._snap_of(mut))
 
-        fn = jax.jit(prefill)
-        self._prefill_cache[(P, K)] = fn
-        return fn
+        return jax.jit(prefill)
 
-    def _snap_kw(self, snap_at) -> dict:
-        """A prefill program's ``mutable`` and, with state-space layers,
-        where each lane's snapshot is wanted and the ``snap`` collection
-        they sow it into.  Without them the programs are what they
-        were."""
-        if not self._state_layers:
-            return {"mutable": ["cache", "intermediates"]}
-        return {"snap_at": snap_at,
-                "mutable": ["cache", "intermediates", "snap"]}
-
-    def _snap_of(self, mut):
+    @staticmethod
+    def _snap_of(mut):
         """``{layer: {leaf: [lanes, ...]}}`` out of a prefill's ``snap``
-        collection, the leaves named as the pool names them
-        (``kv_cache.state_leaves``); None without state-space layers."""
-        if not self._state_layers:
+        collection (what its recurrent layers sowed at ``snap_at``), the
+        leaves named as the pool names them
+        (``cache_layout.state_leaves``); None where no layer sows one:
+        those programs are what they were."""
+        if "snap" not in mut:
             return None
-        from edl_tpu.serving.kv_cache import state_leaves
-        return {name: state_leaves(node)
+        return {name: cache_layout.state_leaves(node)
                 for name, node in mut["snap"].items()}
+
+    def _sown(self, mut) -> "jax.Array":
+        """What a program's layers sowed, as the one vector every
+        program returns (``generate.sown_vector`` under this
+        configuration's layout)."""
+        return sown_vector(mut.get("intermediates"), self._counters.layout)
 
     def _snap_end(self, req: "_Request") -> int:
         """Where a prompt is snapshotted for the pool: the deepest block
         edge the SAME prompt can match again (a hit leaves at least one
         token to prefill); 0 without layer-state snapshots."""
-        if self._kv is None or not (self._ring_layers or self._state_layers):
+        if self._kv is None or not self._snapped:
             return 0
         return (len(req.ids) - 1) // self._kv.block * self._kv.block
-
-    def _latent_tile(self, lanes: int, width: int, heads: int) -> int:
-        """Rows a tile of the latent layers' expanded path holds in a
-        ``lanes x width`` call, on the path that call runs
-        (``ops/latent_attention.expand_block``)."""
-        from edl_tpu.ops import latent_attention
-        cfg = self.cfg
-        return latent_attention.expand_block(
-            lanes, width, heads, cfg.mla_nope_dim + cfg.mla_v_dim,
-            self._dcfg.max_len, cfg.dtype, self._dcfg.mla_tiled(width))
-
-    def _count_prefill(self, lanes: int, width: int, real: int,
-                       offset: int = 0, lens=None) -> None:
-        """One prefill, chunk or reuse program ran ``lanes x width``
-        positions from ``offset`` on, ``real`` of them tokens (``lens``
-        a lane where there are several), through every state-space
-        layer's scan and every latent layer's expanded path (rows up to
-        the call's end, read in whole tiles of the path that ran, the
-        kernel or the loop: the rule and the plan the program was built
-        under, nothing read back from the device)."""
-        if not (self._state_layers or self._latent_layers):
-            return
-        calls, end = lanes * len(self._latent_layers), offset + width
-        tk = self._latent_tile(lanes, width, self.cfg.num_heads)
-        kernel = self._dcfg.mla_tiled(width)
-        with self._stats_lock:
-            if self._state_layers:
-                self._ssm_prefill_pos += lanes * width
-                self._ssm_prefill_pad += lanes * width - real
-            self._latent_prefill_live += calls * end
-            self._latent_prefill_read += calls * -(-end // tk) * tk
-            if self._latent_layers:
-                self._latent_prefill_calls += calls
-                self._latent_prefill_kernel_calls += calls * kernel
-                self._latent_prefill_tokens += real
-                # a real token at offset + i sees offset + i + 1 rows
-                self._latent_prefill_pairs += len(self._latent_layers) * sum(
-                    n * offset + n * (n + 1) // 2
-                    for n in ([real] if lens is None else lens))
 
     @staticmethod
     def _place(cache, slab, slots, true_lens):
@@ -1609,16 +1307,11 @@ class ContinuousBatcher:
         It reaches every layer as the step's ``token_mask``: on the
         chip the decode kernels neither write nor read a free slot's
         slab (transformer.Block._decode_attention), and the dropless
-        expert path routes free slots' ballast tokens nowhere.  With
-        that path the layers' ``moe_stats`` ride back beside the
-        tokens, and after them the expert weight sets the decode kernel
-        fetched (``_moe_fetched``), ``(cache, last, (tokens, stats,
-        fetched))``; otherwise ``(cache, last, tokens)``.  State-space
-        layers add the slot states their
-        one-token updates read and wrote (``_ssm_slots_run``: counted
-        on the device from the kernel's own plan) and latent layers
-        the positions their reads fetched (``_latent_read``), last in
-        that tuple.
+        expert path routes free slots' ballast tokens nowhere.  What
+        the layers sow rides back beside the tokens, summed over the
+        token steps: ``(cache, last, tokens [slots, T], counters)``,
+        ``counters`` the one vector of ``_sown`` (zero-width where the
+        configuration's layers sow nothing).
         ``last`` ([slots]) is what the final token step sampled: the
         next call's ``toks``, handed over on the device.
 
@@ -1628,34 +1321,21 @@ class ContinuousBatcher:
         every compile-cache key would then have to carry."""
         model = self._model
 
-        # what the layers sow for the host, each read by its own reader
-        readers = ([_moe_stats, _moe_fetched] if self._moe_dropless
-                   else []) + ([_ssm_slots_run] if self._state_layers else []
-                               ) + ([_latent_read] if self._latent_layers
-                                    else [])
-
         def one(carry, k):
-            cache, tok, *acc = carry
+            cache, tok, acc = carry
             # per-slot positions come from the cache itself
             pos = self._positions(cache)
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
                 positions=pos[:, None], token_mask=live[:, None],
-                mutable=["cache", "intermediates"] if readers else ["cache"])
+                mutable=["cache", "intermediates"])
             nxt = self._sample(logits[:, -1], k)
-            acc = [a + read(mut.get("intermediates"))
-                   for a, read in zip(acc, readers)]
-            return (mut["cache"], nxt, *acc), nxt
+            return (mut["cache"], nxt, acc + self._sown(mut)), nxt
 
-        keys = jax.random.split(key, self._T)
-        zero = jnp.zeros((), jnp.float32)
-        acc0 = ([_zeros_of(self._moe_acc_shape), zero] if self._moe_dropless
-                else []) + ([zero] if self._state_layers else []
-                            ) + ([zero] if self._latent_layers else [])
-        (cache, last, *acc), out = jax.lax.scan(
-            one, (cache, toks, *acc0), keys)
-        # [slots, T]
-        return cache, last, ((out.T, *acc) if acc else out.T)
+        (cache, last, acc), out = jax.lax.scan(
+            one, (cache, toks, _zeros_of(self._acc_shape)),
+            jax.random.split(key, self._T))
+        return cache, last, out.T, acc          # tokens [slots, T]
 
     @staticmethod
     def _positions(cache):
@@ -1666,30 +1346,21 @@ class ContinuousBatcher:
         raise AssertionError("no cache_index leaf found")
 
     # -- speculative decoding ------------------------------------------------
-    def _draft_cache_shapes(self, B: int):
-        return self._memo_shapes("draft", self._draft_model, B)
-
     def _draft_fresh_cache(self, B: int):
-        shapes = self._draft_cache_shapes(B)
-        sh = None
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            rep = NamedSharding(self._mesh, PartitionSpec())
-            sh = jax.tree.map(lambda _: rep, shapes)
-        return self._zeros(("draft_zeros", B), shapes, sh)
+        return self._zeros(("draft_zeros", B),
+                           self._lanes("draft", self._draft_model, B),
+                           self._rep)
 
+    @_compiled(lambda P, K: ("draft", P, K))
     def _draft_prefill_fn(self, P: int, K: int):
         """Compiled per (bucket, sub-batch): the draft's prompt prefill
         beside every target admission — same padded ids/lens, no
         sampling (the draft only ever continues from the target's last
         token)."""
-        cached = self._prefill_cache.get(("draft", P, K))
-        if cached is not None:
-            return cached
         draft = self._draft_model
 
         def dpre(params, ids, true_lens):
-            cache = _zeros_of(self._draft_cache_shapes(K))
+            cache = _zeros_of(self._lanes("draft", draft, K))
             _, mut = draft.apply(
                 {"params": params, "cache": cache}, ids,
                 positions=jnp.broadcast_to(jnp.arange(ids.shape[1]),
@@ -1699,15 +1370,7 @@ class ContinuousBatcher:
                 mutable=["cache"])
             return mut["cache"]
 
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            rep = NamedSharding(self._mesh, PartitionSpec())
-            fn = jax.jit(dpre, out_shardings=jax.tree.map(
-                lambda _: rep, self._draft_cache_shapes(K)))
-        else:
-            fn = jax.jit(dpre)
-        self._prefill_cache[("draft", P, K)] = fn
-        return fn
+        return jax.jit(dpre, out_shardings=self._rep)
 
     def _draft_slab_for(self, req: "_Request"):
         """One-lane draft prefill from the FULL prompt — used by the
@@ -1962,17 +1625,10 @@ class ContinuousBatcher:
                     self._params, self._draft_params)
             else:
                 self._rng, key = jax.random.split(self._rng)
-                self._cache, self._toks, tick.dec = self._step_jit(
+                (self._cache, self._toks, tick.dec,
+                 tick.counters) = self._step_jit(
                     self._cache, self._toks, key, self._params,
                     self._live_mask([i for i, _ in live]))
-                if isinstance(tick.dec, tuple):     # tokens, then counts
-                    tick.dec, *counts = tick.dec
-                    if self._moe_dropless:
-                        tick.moe, tick.fetched = counts.pop(0), counts.pop(0)
-                    if self._state_layers:
-                        tick.ssm = counts.pop(0)
-                    if self._latent_layers:
-                        tick.latent = counts.pop(0)
                 for i, _ in live:
                     s = self._slots[i]
                     s.owed = max(0, s.owed - self._T)
@@ -2000,29 +1656,20 @@ class ContinuousBatcher:
         if tick is None:
             return
         led = self._ledger
-        # single sync point for decode + every admission
+        # single sync point for decode + every admission: the tokens
+        # and, beside them, each program's counters vector
         with led.phase("sync"):
-            dec = np.asarray(tick.dec) if tick.dec is not None else None
-            moe = np.asarray(tick.moe) if tick.moe is not None else None
-            fetched = float(tick.fetched) if tick.moe is not None else 0.0
-            ssm = float(tick.ssm) if tick.ssm is not None else 0.0
-            latent = float(tick.latent) if tick.latent is not None else 0.0
-            counts = (np.asarray(tick.counts) if tick.counts is not None
-                      else None)
-            fins = [(p[3], p[4], np.asarray(p[1]), np.asarray(p[2]))
-                    for p in tick.pres]
+            dec, counters, counts, firsts = jax.device_get(
+                (tick.dec, tick.counters, tick.counts,
+                 [(p[1], p[2]) for p in tick.pres]))
         self._ran = max(self._ran, tick.mark)
         with led.phase("finish"):
-            if dec is not None:
-                if counts is not None:
-                    self._finish_spec(dec, counts, tick.live)
-                else:
-                    self._finish_decode(dec, tick.live, ssm, latent)
-                if moe is not None:
-                    self._count_moe(moe, len(tick.live) * self._T,
-                                    decode=True, fetched=fetched)
-            for slots, reqs, ptoks, drops in fins:
-                self._finish_prefill(slots, reqs, ptoks, drops)
+            if counts is not None:
+                self._finish_spec(dec, counts, tick.live)
+            elif dec is not None:
+                self._finish_decode(dec, tick.live, counters)
+            for pre, (ptoks, sown) in zip(tick.pres, firsts):
+                self._finish_prefill(pre[3], pre[4], ptoks, sown)
 
     def _admit(self, lanes_live: bool) -> list[tuple]:
         """This tick's admissions, dispatched and not synced: every
@@ -2094,8 +1741,7 @@ class ContinuousBatcher:
         the tick's one cold group took another bucket or its cap; else
         ``tick``.  Each pending request is charged to its cause from
         its mark on; only a change of cause moves the mark."""
-        if not any(s.free and i not in taken
-                   for i, s in enumerate(self._slots)):
+        if not self._free_slots(taken):
             cause = "slots"
         elif lane_held:
             cause = "lane"
@@ -2125,6 +1771,12 @@ class ContinuousBatcher:
     def _any_active(self) -> bool:
         return any(not s.free for s in self._slots)
 
+    def _free_slots(self, taken: set[int]) -> list[int]:
+        """Free slots but ``taken`` (the one a chunked admission holds
+        while its request is not in it yet)."""
+        return [i for i, s in enumerate(self._slots)
+                if s.free and i not in taken]
+
     def _bucket(self, n: int) -> int:
         """Smallest prefill bucket holding an n-token prompt (buckets
         extend to cache_len at construction, so any prompt submit()
@@ -2140,8 +1792,7 @@ class ContinuousBatcher:
         buckets × |PREFILL_KS|)."""
         if self._stopping or not self._pending:
             return None
-        free = [i for i, s in enumerate(self._slots)
-                if s.free and i not in taken]
+        free = self._free_slots(taken)
         if not free:
             return None
         P = self._bucket(len(self._pending[0].ids))
@@ -2189,15 +1840,16 @@ class ContinuousBatcher:
                 lens[i] = len(req.ids)
             self._rng, key = jax.random.split(self._rng)
             ends = (jnp.asarray([self._snap_end(r) for r in reqs], jnp.int32)
-                    if self._state_layers else None)
-            slab, toks, drops, snap = self._prefill_fn(P, K)(
+                    if self._recurrent else None)
+            slab, toks, sown, snap = self._prefill_fn(P, K)(
                 self._params, jnp.asarray(ids), jnp.asarray(lens), key, ends)
             self._count_enqueue()
-            self._count_prefill(K, P, int(lens.sum()), lens=lens.tolist())
+            self._counters.on_prefill(K, P, int(lens.sum()),
+                                      lens=lens.tolist())
             dslab = (self._draft_prefill_fn(P, K)(
                 self._draft_params, jnp.asarray(ids), jnp.asarray(lens))
                 if self._spec_k else None)
-            return slab, toks, drops, slots, reqs, lens, dslab, (snap, 0)
+            return slab, toks, sown, slots, reqs, lens, dslab, (snap, 0)
         except Exception as e:  # noqa: BLE001 — fail THIS group only
             logger.exception("prefill failed (bucket %d, %d reqs)", P, K)
             for req in reqs:
@@ -2229,11 +1881,10 @@ class ContinuousBatcher:
         off = C * ((n - 1) // C)
         if off + self._bucket(n - off) > self._dcfg.max_len:
             return
-        slot = next((i for i, s in enumerate(self._slots)
-                     if s.free and i not in taken), None)
-        if slot is None:
+        free = self._free_slots(taken)
+        if not free:
             return
-        req = self._pending.popleft()
+        slot, req = free[0], self._pending.popleft()
         self._stamp_admit([req], "chunk")
         if self._kv is not None:
             # one admission, counted once at start (the reuse matcher
@@ -2246,20 +1897,15 @@ class ContinuousBatcher:
             self._chunked_admissions += 1
 
     def _chunk_start(self):
-        """``(slab, drops)`` a chunked admission starts from: a zeroed
-        one-lane cache and its MoE-drop accumulator, out of one compiled
+        """``(slab, sown)`` a chunked admission starts from: a zeroed
+        one-lane cache and its counters accumulator, out of one compiled
         program — nothing traced, one dispatch.  On a mesh the
         accumulator is born where the chunk programs return it (after
         a host-made scalar, the first admission's second chunk re-traced
         and re-compiled ``mid`` in the middle of traffic)."""
-        sh = None
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            sh = (self._cache_shardings(1),
-                  NamedSharding(self._mesh, PartitionSpec()))
         return self._zeros(
-            ("chunk_start",),
-            (self._cache_shapes(1), self._moe_prefill_acc_shape), sh)
+            ("chunk_start",), (self._cache_shapes(1), self._acc_shape),
+            (self._cache_shardings(1), self._rep))
 
     def _advance_chunk(self):
         """Dispatch ONE chunk of the in-flight chunked admission (no
@@ -2277,10 +1923,10 @@ class ContinuousBatcher:
         try:
             if rest > C:
                 chunk = np.asarray(ids[st.offset:st.offset + C])[None, :]
-                st.slab, st.drops = self._chunk_mid_fn(C)(
-                    self._params, st.slab, jnp.asarray(chunk), st.drops)
+                st.slab, st.sown = self._chunk_mid_fn(C)(
+                    self._params, st.slab, jnp.asarray(chunk), st.sown)
                 self._count_enqueue()
-                self._count_prefill(1, C, C, st.offset)
+                self._counters.on_prefill(1, C, C, st.offset)
                 st.offset += C
                 with self._stats_lock:
                     self._prefill_chunks += 1
@@ -2290,19 +1936,19 @@ class ContinuousBatcher:
             tail[0, :rest] = ids[st.offset:]
             self._rng, key = jax.random.split(self._rng)
             at = (jnp.asarray([max(self._snap_end(st.req) - st.offset, 0)],
-                              jnp.int32) if self._state_layers else None)
-            slab, toks, drops, snap = self._chunk_final_fn(P)(
+                              jnp.int32) if self._recurrent else None)
+            slab, toks, sown, snap = self._chunk_final_fn(P)(
                 self._params, st.slab, jnp.asarray(tail),
-                jnp.asarray([rest], jnp.int32), st.drops, key, at)
+                jnp.asarray([rest], jnp.int32), st.sown, key, at)
             self._count_enqueue()
             self._chunking = None
-            self._count_prefill(1, P, rest, st.offset)
+            self._counters.on_prefill(1, P, rest, st.offset)
             with self._stats_lock:
                 self._prefill_chunks += 1
                 # the lane is free from its last chunk's dispatch on
                 self._chunk_lane_busy_s += time.monotonic() - st.t_start
             dslab = self._draft_slab_for(st.req) if self._spec_k else None
-            return (slab, toks, drops, [st.slot], [st.req], [len(ids)],
+            return (slab, toks, sown, [st.slot], [st.req], [len(ids)],
                     dslab, (snap, st.offset))
         except Exception as e:  # noqa: BLE001 — fail THIS request only
             logger.exception("chunked prefill failed (offset %d of %d)",
@@ -2314,65 +1960,45 @@ class ContinuousBatcher:
                 self._chunk_lane_busy_s += time.monotonic() - st.t_start
             return None
 
+    @_compiled(lambda C: ("chunk", C))
     def _chunk_mid_fn(self, C: int):
         """Compiled per chunk size: advance a one-lane prefill slab by
         C prompt tokens (every token real — the only padded chunk is
         the final one, which is a bucketed suffix prefill)."""
-        cached = self._prefill_cache.get(("chunk", C))
-        if cached is not None:
-            return cached
         model = self._model
 
-        def mid(params, slab, ids, drops_in):
+        def mid(params, slab, ids, sown_in):
             idx = self._positions(slab)           # == tokens prefilled
             _, mut = model.apply(
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(C)[None, :],
                 mutable=["cache", "intermediates"])
-            return mut["cache"], drops_in + self._prefill_moe(mut)
+            return mut["cache"], sown_in + self._sown(mut)
 
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            sh = self._cache_shardings(1)
-            rep = NamedSharding(self._mesh, PartitionSpec())
-            fn = jax.jit(mid, donate_argnums=(1,), out_shardings=(sh, rep))
-        else:
-            fn = jax.jit(mid, donate_argnums=(1,))
-        self._prefill_cache[("chunk", C)] = fn
-        return fn
+        return jax.jit(mid, donate_argnums=(1,), out_shardings=(
+            self._cache_shardings(1), self._rep))
 
+    @_compiled(lambda P: ("chunkfin", P))
     def _chunk_final_fn(self, P: int):
         """Compiled per suffix bucket: the last chunk — bucketed,
         token-masked, sampled at the prompt's true last position."""
-        cached = self._prefill_cache.get(("chunkfin", P))
-        if cached is not None:
-            return cached
         model = self._model
 
-        def fin(params, slab, ids, rel_lens, drops_in, key, snap_at=None):
+        def fin(params, slab, ids, rel_lens, sown_in, key, snap_at=None):
             idx = self._positions(slab)
             logits, mut = model.apply(
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(P)[None, :],
                 token_mask=jnp.arange(P)[None, :] < rel_lens[:, None],
-                **self._snap_kw(snap_at))
+                snap_at=snap_at, mutable=_PREFILL_MUTABLE)
             last = jnp.take_along_axis(
                 logits, (rel_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
-            return (mut["cache"], toks,
-                    drops_in + self._prefill_moe(mut),
+            return (mut["cache"], toks, sown_in + self._sown(mut),
                     self._snap_of(mut))
 
-        if self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            sh = self._cache_shardings(1)
-            rep = NamedSharding(self._mesh, PartitionSpec())
-            fn = jax.jit(fin, donate_argnums=(1,),
-                         out_shardings=(sh, rep, rep, None))
-        else:
-            fn = jax.jit(fin, donate_argnums=(1,))
-        self._prefill_cache[("chunkfin", P)] = fn
-        return fn
+        return jax.jit(fin, donate_argnums=(1,), out_shardings=(
+            self._cache_shardings(1), self._rep, self._rep, None))
 
     # -- prefix reuse (paged KV engines only) --------------------------------
     def _next_reuse(self, taken: set[int] = frozenset()
@@ -2385,13 +2011,12 @@ class ContinuousBatcher:
             return None
         if self._stopping or not self._pending:
             return None
-        free = next((i for i, s in enumerate(self._slots)
-                     if s.free and i not in taken), None)
-        if free is None:
+        free = self._free_slots(taken)
+        if not free:
             return None
         req0 = self._pending[0]
         chain = self._kv.match(req0.ids)
-        if self._state_layers:
+        if self._recurrent:
             req0.cut = self._kv.last_cut
         cache_len = self._dcfg.max_len
         while chain:
@@ -2409,7 +2034,13 @@ class ContinuousBatcher:
             return None
         req = self._pending.popleft()
         self._stamp_admit([req], "reuse")
-        return free, req, chain
+        return free[0], req, chain
+
+    def _pad_blocks(self, n: int) -> int:
+        """A chain of ``n`` blocks as the reuse programs take it: the
+        next power of two, capped at the blocks a cache holds."""
+        return min(1 << max(0, (n - 1).bit_length()),
+                   self._dcfg.max_len // self._kv.block)
 
     def _dispatch_reuse(self, slot: int, req: "_Request", chain: list):
         """Dispatch one prefix-hit admission: gather the chain's blocks
@@ -2436,10 +2067,7 @@ class ContinuousBatcher:
             # fresh XLA compile each turn.  The padded zeros land
             # beyond prefix_len and are overwritten or masked before
             # any query can attend them.
-            n_pad = 1
-            while n_pad < n:
-                n_pad *= 2
-            n_pad = min(n_pad, self._dcfg.max_len // self._kv.block)
+            n_pad = self._pad_blocks(n)
             block_ids = np.zeros((n_pad,), np.int32)
             block_ids[:n] = [nd.block_id for nd in chain]
             self._rng, key = jax.random.split(self._rng)
@@ -2447,7 +2075,7 @@ class ContinuousBatcher:
                    jnp.asarray(prefix_len, jnp.int32))
             n_real = jnp.asarray([len(suffix)], jnp.int32)
             snap_id = self._kv.snap_arg(chain[-1].snap)
-            if self._state_layers:
+            if self._recurrent:
                 # two programs where the others fuse them: the gather
                 # alone (small: one attention layer's blocks and the
                 # snapshot), then the chunk lane's last-chunk program,
@@ -2460,24 +2088,24 @@ class ContinuousBatcher:
                 # in four pairs of four (PERF.md section 6, PR 32)
                 at = jnp.asarray([max(self._snap_end(req) - prefix_len, 0)],
                                  jnp.int32)
-                slab, toks, drops, snap = self._chunk_final_fn(P)(
+                slab, toks, sown, snap = self._chunk_final_fn(P)(
                     self._params, self._load_prefix_fn(n_pad)(*hit, snap_id),
                     jnp.asarray(ids), n_real,
-                    self._zeros(("acc",), self._moe_prefill_acc_shape, None),
+                    self._zeros(("acc",), self._acc_shape, None),
                     key, at)
             else:
-                slab, toks, drops, snap = self._reuse_prefill_fn(P, n_pad)(
+                slab, toks, sown, snap = self._reuse_prefill_fn(P, n_pad)(
                     self._params, *hit, jnp.asarray(ids), n_real, key,
                     snap_id)
             self._count_enqueue()
-            self._count_prefill(1, P, len(suffix), prefix_len)
+            self._counters.on_prefill(1, P, len(suffix), prefix_len)
             # insert true_lens = the FULL prompt length: the slab's
             # cache_index already sits at prefix+suffix and the pool
             # lane must agree.  The draft has no pool: its slab is
             # rebuilt from the FULL prompt in one small-model pass
             # (draft state moves the accept rate, never correctness).
             dslab = self._draft_slab_for(req) if self._spec_k else None
-            return (slab, toks, drops, [slot], [req], [len(req.ids)], dslab,
+            return (slab, toks, sown, [slot], [req], [len(req.ids)], dslab,
                     (snap, prefix_len))
         except Exception as e:  # noqa: BLE001 — fail THIS request only
             logger.exception("reuse prefill failed (suffix bucket %d, "
@@ -2487,15 +2115,13 @@ class ContinuousBatcher:
                 self._failed_requests += 1
             return None
 
+    @_compiled(lambda P, n_pad: ("reuse", P, n_pad))
     def _reuse_prefill_fn(self, P: int, n_pad: int):
         """Compiled per (suffix bucket, PADDED chain length): fused
         gather-prefix + suffix prefill + sample.  ``prefix_len`` (the
         real chain length in tokens, <= ``n_pad * block``) rides as a
         traced scalar so every chain depth in a padding bucket shares
         one executable."""
-        cached = self._prefill_cache.get(("reuse", P, n_pad))
-        if cached is not None:
-            return cached
         model = self._model
         kv = self._kv
 
@@ -2513,41 +2139,37 @@ class ContinuousBatcher:
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             toks = self._sample(last, key)
-            return (mut["cache"], toks, self._prefill_moe(mut),
-                    None)
+            return mut["cache"], toks, self._sown(mut), None
 
-        fn = jax.jit(prefill)
-        self._prefill_cache[("reuse", P, n_pad)] = fn
-        return fn
+        return jax.jit(prefill)
 
+    @_compiled(lambda n_pad: ("load", n_pad))
     def _load_prefix_fn(self, n_pad: int):
         """Compiled per PADDED chain length: a fresh one-lane slab with
         a hit's blocks and layer-state snapshot in it, its index at the
         prefix's end (``PagedKVCache.load_prefix_into`` alone): what a
         state-space configuration's reuse admission runs before the
         last-chunk program (``_dispatch_reuse``)."""
-        cached = self._prefill_cache.get(("load", n_pad))
-        if cached is not None:
-            return cached
         kv = self._kv
 
         def load(pool, block_ids, prefix_len, snap_id):
             return kv.load_prefix_into(_zeros_of(self._cache_shapes(1)), pool,
                                        block_ids, n_pad, prefix_len, snap_id)
 
-        fn = self._prefill_cache[("load", n_pad)] = jax.jit(load)
-        return fn
+        return jax.jit(load)
 
     def _finish_prefill(self, slots: list[int], reqs: list[_Request],
-                        toks: np.ndarray, moe: np.ndarray) -> None:
+                        toks: np.ndarray, sown: np.ndarray) -> None:
+        """``sown``: what the admission's programs sowed (a chunked
+        one's, all its chunks)."""
         now = time.monotonic()
         with self._stats_lock:
             for req in reqs:
                 req.t_first = now     # its first token is on the host
                 self._stage(req, "prefill")
                 self._stage(req, "ttft")
-        self._count_moe(moe, sum(len(r.ids) - r.skipped for r in reqs),
-                        decode=False)
+            self._counters.read(
+                sown, sum(len(r.ids) - r.skipped for r in reqs), decode=False)
         for slot, tok in zip(slots, toks.tolist()):
             s = self._slots[slot]         # the request's since _admit
             s.emitted = [tok]
@@ -2572,18 +2194,18 @@ class ContinuousBatcher:
         computed it on its way (``snap``, lane ``lane``; the program
         began at position ``base``).  An edge before ``base`` was
         passed a program ago and is not snapshotted (counted)."""
-        if self._kv is None or not (self._ring_layers or self._state_layers):
+        if self._kv is None or not self._snapped:
             return
         end = self._snap_end(req)
         if end <= req.skipped:     # nothing new: the hit's own snapshot
             return
         sid = 0 if end < base else self._kv.snap_alloc()
         if not sid:
-            self._state_snap_skips += bool(self._state_layers)
+            self._state_snap_skips += self._recurrent
             return
-        if self._ring_layers:
+        if self._ringed:
             self._kv.store_blocks(self._cache, slot, 0, [], (sid, end))
-        if self._state_layers:
+        if self._recurrent:
             self._kv.store_state(snap, lane, sid)
         req.snap = (sid, end)
 
@@ -2593,57 +2215,13 @@ class ContinuousBatcher:
         live[active] = True
         return jnp.asarray(live)
 
-    def _prefill_moe(self, mut):
-        """A multi-token program's expert counters: ``_moe_stats`` and,
-        where the stack holds a share of its experts, last the layer
-        calls that ran over the live prefix of their sorted pairs
-        (``_moe_prefix``)."""
-        stats = _moe_stats(mut.get("intermediates"))
-        if self._moe_held:
-            stats = jnp.concatenate(
-                [stats, _moe_prefix(mut.get("intermediates"))[None]])
-        return stats
-
-    def _count_moe(self, moe: np.ndarray, tokens: int, decode: bool,
-                   fetched: float = 0.0) -> None:
-        """Add one program's expert counters (generate._moe_stats: a
-        drop count alone, or the dropless path's vector; a prefill
-        program's ``_prefill_moe``) to stats(), and beside them the
-        ``tokens`` the host knows it routed; ``fetched``: a step
-        program's ``_moe_fetched``."""
-        with self._stats_lock:
-            if self._dcfg.moe_experts:
-                self._moe_tokens += tokens
-            if moe.ndim == 0:
-                self._moe_drops += int(moe)
-                return
-            if self._moe_held and not decode:
-                self._moe_prefix_kernel_calls += int(moe[-1])
-                moe = moe[:-1]
-            drops, assigned, touched, load, *routed, calls = moe.tolist()
-            self._moe_drops += int(drops)
-            self._moe_assignments += int(assigned)
-            self._moe_assignments_routed += int(
-                routed[0] if routed else assigned)
-            if decode:
-                self._moe_decode_layer_steps += int(calls)
-                self._moe_decode_experts_touched += int(touched)
-                self._moe_decode_experts_fetched += fetched
-            else:
-                self._moe_prefill_groups += int(calls)
-                self._moe_prefill_experts_touched += int(touched)
-                self._moe_prefill_max_load_sum += load
-
     def _finish_decode(self, toks: np.ndarray, live: list,
-                       ssm_run: float = 0.0, latent_read: float = 0.0
-                       ) -> None:
+                       sown: np.ndarray) -> None:
         """Consume one decode chunk [slots, T] for the (slot, request)
         pairs that were ``live`` in it.  A slot that no longer holds
         its request ended at an EOS while this program was already
-        enqueued: its token steps are discarded.  ``ssm_run``: the slot
-        states the program's state-space layers read and wrote, as the
-        program counted them; ``latent_read``: the positions its latent
-        layers' reads fetched."""
+        enqueued: its token steps are discarded.  ``sown``: what the
+        program's layers sowed (``_sown``)."""
         T, cap = self._T, self._dcfg.max_len
         mine = [(i, self._slots[i]) for i, req in live
                 if self._slots[i].request is req]
@@ -2651,25 +2229,12 @@ class ContinuousBatcher:
         # appended: prompt + emitted so far + t positions
         held = [min(len(s.request.ids) + len(s.emitted) + t, cap)
                 for _, s in mine for t in range(T)]
-        kv_live = sum(held)
         with self._stats_lock:
             self._lane_steps += len(self._slots) * T
             self._active_lane_steps += len(live) * T
             self._lookahead_discarded += (len(live) - len(mine)) * T
-            self._kv_tokens_live += kv_live
-            self._kv_tokens_slab += len(self._slots) * cap * T
-            if self._ring_layers:
-                W = self._dcfg.attn_window
-                self._kv_window_read += sum(map(self._ring_fetch, held))
-                self._kv_window_need += sum(min(n, W) for n in held)
-            if self._state_layers:
-                self._ssm_steps += len(live) * T * len(self._state_layers)
-                self._ssm_steps_run += ssm_run
-            if self._latent_layers:
-                self._latent_live += kv_live * len(self._latent_layers)
-                self._latent_read += latent_read
-                self._latent_decode_calls += len(held) * len(
-                    self._latent_layers)
+            self._counters.on_decode(held, len(live), T)
+            self._counters.read(sown, len(live) * T, decode=True)
         for i, s in mine:         # live, so it had tokens left to read
             for t in range(T):
                 tok = int(toks[i, t])
@@ -2759,17 +2324,17 @@ class ContinuousBatcher:
         snap = (0, 0)
         self._state_reprefill += req.cut
         # a recurrence has moved past the tail's end and kept nothing
-        # of it: with a state-space layer an answer's end is never
+        # of it: with a recurrent layer an answer's end is never
         # snapshotted, and a session's next turn starts from the edge
         # its last prompt left (kv_state_reprefill_tokens)
-        if self._ring_layers and not self._state_layers and tail is not None:
+        if self._ringed and not self._recurrent and tail is not None:
             # the window layers' last window before the tail's end, as
             # the slot's rings still hold it: the slot may have run
             # self._overrun token steps past the request's end, each
             # overwriting the oldest ring position
             end = (start_block + len(new_ids)) * self._kv.block
             oldest = len(seq) + self._overrun - self._dcfg.ring_len
-            if max(0, end - self._dcfg.attn_window) >= oldest:
+            if max(0, end - self._kv.window) >= oldest:
                 snap = (self._kv.snap_for(tail), end)
         self._kv.store_blocks(self._cache, slot, start_block, new_ids, snap)
         if req.session is not None and tail is not None:

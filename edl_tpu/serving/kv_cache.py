@@ -47,53 +47,23 @@ Design (PagedAttention re-shaped for the engine's attention layout):
   them (doc/serving.md "Session KV migration");
 - **mesh-native** (ISSUE 20) — on a tp mesh the pool buffers shard
   over the KV-head axis, exactly like the engine's slot slabs
-  (``ContinuousBatcher._leaf_sharding``): every shard holds the SAME
+  (``ContinuousBatcher._cache_shardings``): every shard holds the SAME
   block ids for ITS heads, so the one host-side trie indexes all
   shards at once and block identity stays a host concept.  The
   gather/scatter/import jits lift through ``shard_map`` so every
   block move is shard-local by construction — no collective can
   appear in the pool path (doc/serving.md "Mesh-sharded paged KV").
 
-- **two allocation classes** — a stack may mix global layers (whole
-  context) with sliding-window layers, whose slot state is a ring of a
-  window and a little more (``transformer.Block._ring_attention``).
-  Global layers page by blocks as above.  A window layer keeps no
-  blocks: a block deep in a chain would need keys the ring dropped
-  long before the commit.  What a prefix hit needs of it is the LAST
-  WINDOW of the prefix and nothing else, so window layers page by
-  **snapshots**: ``window`` positions ending at a chain node's end,
-  taken from the slot's ring when a commit ends there and when a
-  prompt's prefill ends (at the deepest node the SAME prompt can match
-  again), owned by that node, in a pool of their own (``n_snaps``
-  entries, LRU among unpinned holders; entry 0 is scratch).  A chain is reusable as deep
-  as its deepest snapshot-bearing node (``match`` truncates to it); the
-  gather puts the snapshot back at ring slots ``position % ring`` and
-  the global blocks at the front of the slabs, in the same fused jit.
-  One trie, one LRU clock, one ``store_blocks`` dispatch for both.
-- **layer state** - the snapshot class is not the window layers' alone.
-  A state-space layer (``transformer.Mamba2Mixer``) keeps neither
-  blocks nor a ring: a slot's state there is a fixed-size recurrence
-  (``conv_state``, ``ssm_state``), and what a prefix hit needs of it is
-  THE state after the prefix's last token.  A snapshot entry holds that
-  for every state layer (and the last window for every window layer of
-  the same stack), owned by the chain node it ends at, out of the same
-  ``n_snaps`` entries under the same LRU.  A ring still holds a little
-  history and can be snapshotted when a commit ends; a recurrence can
-  be saved only at a position the program is AT, so a state layer's
-  snapshot comes out of the prefill itself (``store_state``: the state
-  the scan computed at the block edge), never out of a slot.  A
-  delta-rule layer (``transformer.KDAMixer``: ``conv_state``,
-  ``kda_state``) is a state layer like any other.
-- **latent rows** - a latent attention layer
-  (``transformer.LatentAttention``) caches ONE row a token, the normed
-  key/value latent and the key dims all heads share: no head axis, keys
-  and values the same bytes.  Its rows page by BLOCKS exactly as a
-  global layer's keys and values do (same trie, same block ids, same
-  LRU, same gather at a hit and scatter at a commit), but as one buffer
-  a layer, ``[n_blocks, block, row]``: storing them as keys and as
-  values would give back half of what the architecture saves.  A chain
-  exports its latent blocks beside the other layers' (and, in a stack
-  with state layers, the tail's snapshot).
+- **cache classes** - what a layer keeps for a slot and whether it
+  pages by blocks or by **snapshots** is its
+  :class:`~edl_tpu.serving.cache_layout.CacheClass`, handed in as
+  ``classes``; this module names no kind.  Snapshots live in a pool of
+  their own (``n_snaps`` entries, LRU among unpinned holders; entry 0 is
+  scratch), each owned by the chain node it ends at: one entry holds
+  every snapshotted layer's part.  A chain is reusable as deep as its
+  deepest snapshot-bearing node (``match`` truncates to it).  One trie,
+  one LRU clock, one ``store_blocks`` dispatch for every class; a chain
+  exports each layer's blocks or its tail's snapshot, in layer order.
 
 Thread model: single-writer — every mutating call runs on the engine
 thread (admission, finish-commit, import-task); ``export_chain`` runs
@@ -106,77 +76,36 @@ from __future__ import annotations
 import heapq
 from collections import OrderedDict
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from edl_tpu.serving.cache_layout import ROWS, cache_specs
 from edl_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
 
 
-def _pool_shapes(n_blocks: int, hk: int, d: int, block: int) -> dict:
-    """One layer's pool buffers: K blocks keep the slab's [D, tokens]
-    operand layout, V blocks its [tokens, D] one."""
-    return {"k": (n_blocks, hk, d, block), "v": (n_blocks, hk, block, d)}
-
-
-def _leaf_key(path) -> str:
-    """A cache leaf's place under its layer, as the pool names it
-    (``ssm/ssm_state``): the dictionary keys of its path."""
-    return "/".join(k.key for k in path if hasattr(k, "key"))
-
-
-def state_leaves(node) -> dict:
-    """A state layer's cache leaves a snapshot holds (all but the
-    index), by their place under the layer (``_leaf_key``)."""
-    import jax
-    return {_leaf_key(path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(node)[0]
-            if not _leaf_key(path).endswith("cache_index")}
-
-
-def latent_leaf(node) -> tuple:
-    """A latent layer's one cache buffer, ``(its place under the layer,
-    the leaf [lanes, max_len, row])``."""
-    (key, leaf), = state_leaves(node).items()
-    return key, leaf
-
-
 def pool_device_bytes(cache_shapes, block: int, n_blocks: int,
-                      tp: int = 1, ring_layers=(), window: int = 0,
-                      n_snaps: int = 0, state_layers=(),
-                      latent_layers=()) -> int:
+                      tp: int = 1, classes=None, n_snaps: int = 0) -> int:
     """Per-device HBM bytes of the pool :class:`PagedKVCache` would
-    allocate for this cache skeleton (KV heads split over ``tp`` where
-    they divide, as the constructor shards them): ``n_blocks`` blocks
-    of ``block`` tokens for every global layer, ``n_snaps`` snapshots
-    of ``window`` tokens for every layer in ``ring_layers`` and of one
-    lane's state for every layer in ``state_layers``; ``n_blocks``
-    blocks of ``block`` rows, once, for every layer in
-    ``latent_layers``.  Plain
+    allocate for this cache skeleton under ``classes`` (``{layer:
+    CacheClass}``; a layer it does not name keeps per-head rows):
+    ``n_blocks`` blocks of ``block`` tokens for a layer paged by blocks,
+    ``n_snaps`` snapshots for one paged by snapshots, split over ``tp``
+    where the class shards, as the constructor shards them.  Plain
     element counts: libtpu lays the 16-token minor dim of a K block out
     major-most rather than padding it to 128 lanes (measured on v5e:
     device bytes / nominal = 1.00 for both buffers, f32 and bf16)."""
     total = 0
     for name, node in cache_shapes.items():
-        if name in state_layers:
-            total += n_snaps * sum(
-                int(np.prod(leaf.shape[1:], dtype=np.int64))
-                * np.dtype(leaf.dtype).itemsize
-                for leaf in state_leaves(node).values())
-            continue
-        if name in latent_layers:
-            _, leaf = latent_leaf(node)
-            total += (n_blocks * block * leaf.shape[-1]
-                      * np.dtype(leaf.dtype).itemsize)
-            continue
-        _, hk, d, _ = node["cached_key"].shape
-        hk = hk // tp if tp > 1 and hk % tp == 0 else hk
-        item = np.dtype(node["cached_key"].dtype).itemsize
-        shapes = (_pool_shapes(n_snaps, hk, d, window)
-                  if name in ring_layers
-                  else _pool_shapes(n_blocks, hk, d, block))
-        total += sum(int(np.prod(shape, dtype=np.int64)) * item
-                     for shape in shapes.values())
+        cls = (classes or {}).get(name, ROWS)
+        total += sum(
+            int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize
+            for shape, dtype in cls.pool_shapes(
+                node, block, n_blocks, n_snaps).values()
+        ) // (tp if cls.sharded(node, tp) else 1)
     return total
 
 
@@ -197,16 +126,16 @@ class _Node:
 
 
 class PagedKVCache:
-    """Device block pools (one k + one v buffer per global layer, one
-    buffer of rows per latent layer, snapshot entries for window and
-    state layers) plus the host-side prefix trie, free list, session
-    pins and eviction policy.
+    """Device block and snapshot pools (each layer's buffers as its
+    cache class lays them out) plus the host-side prefix trie, free
+    list, session pins and eviction policy.
 
     ``cache_shapes`` is the engine's per-slot cache skeleton
-    (``{layer: {cached_key, cached_value, cache_index}}`` eval_shape
-    tree; a state or latent layer's node is what its mixer keeps) —
-    pool layouts are derived from it so the gather/scatter jits line up
-    with the slot slabs by construction.
+    (``{layer: what its mixer keeps}`` eval_shape tree) — pool layouts
+    are derived from it so the gather/scatter jits line up with the slot
+    slabs by construction.  ``classes`` is ``{layer: CacheClass}``
+    (``cache_layout.cache_classes``); a layer it does not name keeps
+    per-head key and value rows.
 
     ``mesh`` (optional) shards the pool buffers over the mesh's ``tp``
     axis on the KV-head dim, mirroring the engine's slot-slab sharding
@@ -215,12 +144,8 @@ class PagedKVCache:
     """
 
     def __init__(self, cache_shapes, block: int, n_blocks: int,
-                 max_sessions: int, mesh=None, ring_layers=(),
-                 window: int = 0, n_snaps: int = 0, state_layers=(),
-                 latent_layers=()):
-        import jax
-        import jax.numpy as jnp
-
+                 max_sessions: int, mesh=None, classes=None,
+                 n_snaps: int = 0):
         if block < 1:
             raise ValueError(f"kv block size must be >= 1, got {block}")
         if n_blocks < 1:
@@ -228,98 +153,41 @@ class PagedKVCache:
         self.block = int(block)
         self.n_blocks = int(n_blocks)
         self._layers: list[str] = sorted(cache_shapes)
-        # the window class (module docstring): layers whose slot state
-        # is a ring, the window a snapshot holds, and how many there are
-        self._ring = frozenset(ring_layers)
-        # the state class: layers whose slot state is a recurrence
-        self._state = frozenset(state_layers)
-        # the latent class: layers that cache one head-less row a token
-        self._latent = frozenset(latent_layers)
-        self._snapped = bool(self._ring or self._state)
-        self.window = int(window) if self._ring else 0
+        self._shapes = cache_shapes
+        self._cls = {name: (classes or {}).get(name, ROWS)
+                     for name in self._layers}
+        kinds = set(self._cls.values())
+        self._snapped = any(c.snapshotted for c in kinds)
+        # some class's snapshot comes out of the prefill alone: it sits
+        # where a chain's last PROMPT ended (pin_session)
+        self._prompt_snaps = any(c.snapshotted and not c.from_slot
+                                 for c in kinds)
+        self.window = max(c.window for c in kinds)
         self.n_snaps = int(n_snaps) if self._snapped else 0
-        if self._snapped and ((self._ring and self.window < 1)
-                              or self.n_snaps < 2):
+        if self._snapped and self.n_snaps < 2:
             raise ValueError(
-                f"window layers {sorted(self._ring)} and state layers "
-                f"{sorted(self._state)} need a window and at least 2 "
-                f"snapshots, got {window} and {n_snaps}")
-        if self._snapped and mesh is not None:
-            raise ValueError(
-                "a paged KV cache with window or state layers is not "
-                "sharded over a mesh: the snapshot pool has no sharded "
-                "gather yet")
-        if self._latent and mesh is not None:
-            raise ValueError(
-                "a paged KV cache with latent layers is not sharded over a "
-                "mesh: a latent row has no head axis to shard over tp")
-        self._layout: dict[str, tuple] = {}
-        # a latent layer's buffer: {layer: (leaf's place, row, dtype)}
-        self._latent_layout: dict[str, tuple] = {}
-        # a state layer's snapshot leaves: {leaf: (shape of a lane, dtype)}
-        self._state_layout: dict[str, dict] = {}
-        for name in self._layers:
-            node = cache_shapes[name]
-            if name in self._state:
-                self._state_layout[name] = {
-                    k: (tuple(v.shape[1:]), v.dtype)
-                    for k, v in sorted(state_leaves(node).items())}
-                continue
-            if name in self._latent:
-                key, leaf = latent_leaf(node)   # [slots, max_len, row]
-                if leaf.ndim != 3 or block > leaf.shape[1]:
-                    raise ValueError(
-                        f"latent layer {name} caches {leaf.shape}: not "
-                        f"[slots, max_len >= {block}, row]")
-                self._latent_layout[name] = (key, leaf.shape[-1], leaf.dtype)
-                continue
-            if set(node) != {"cached_key", "cached_value", "cache_index"}:
+                f"layers that page by snapshots need at least 2 of them, "
+                f"got {n_snaps}")
+        for cls in kinds:
+            if mesh is not None and (cls.no_shard or cls.snapshotted):
                 raise ValueError(
-                    f"paged KV cache requires plain per-layer "
-                    f"cached_key/cached_value/cache_index state; layer "
-                    f"{name} carries {sorted(node)} and was named neither "
-                    f"a state layer nor a latent layer")
-            k = node["cached_key"]          # [slots, Hk, D, max_len]
-            _, hk, d, length = k.shape
-            if name in self._ring:
-                if length < self.window:
-                    raise ValueError(
-                        f"layer {name}'s ring holds {length} positions, "
-                        f"fewer than the window {self.window}")
-            elif block > length:
-                raise ValueError(
-                    f"kv block {block} exceeds cache length {length}")
-            self._layout[name] = (hk, d, k.dtype)
+                    f"a paged KV cache with {cls.kind} layers is not "
+                    f"sharded over a mesh: " + (cls.no_shard or "the "
+                    "snapshot pool has no sharded gather yet"))
         self._mesh = mesh
         self._tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
-        # per-layer: shard the pool over ``tp`` on the KV-head axis
-        # exactly when the engine shards that layer's slot slabs
-        # (ContinuousBatcher._leaf_sharding: axis-1 divisible by tp) —
-        # per-shard pools with IDENTICAL block ids, so a block move
-        # never crosses shards and one host trie covers every shard
-        self._layer_sharded = {
-            name: self._tp > 1 and hk % self._tp == 0
-            for name, (hk, d, _) in self._layout.items()}
-        self._layer_sharded.update(
-            dict.fromkeys(self._state | self._latent, False))
         # block 0 is a reserved scratch block (never allocated) so a
         # zero-filled block-id vector can never alias live state
-        self.pool = {
-            name: {ax: jnp.zeros(shape, dtype) for ax, shape in (
-                _pool_shapes(self.n_snaps, hk, d, self.window)
-                if name in self._ring
-                else _pool_shapes(n_blocks, hk, d, block)).items()}
-            for name, (hk, d, dtype) in self._layout.items()
-        }
-        for name, leaves in self._state_layout.items():
-            self.pool[name] = {
-                k: jnp.zeros((self.n_snaps,) + shape, dtype)
-                for k, (shape, dtype) in leaves.items()}
-        for name, (_, row, dtype) in self._latent_layout.items():
-            self.pool[name] = {"c": jnp.zeros((n_blocks, block, row), dtype)}
+        self.pool = {}
+        for name, cls in self._cls.items():
+            try:
+                shapes = cls.pool_shapes(cache_shapes[name], self.block,
+                                         self.n_blocks, self.n_snaps)
+            except ValueError as e:
+                raise ValueError(f"layer {name}: {e}") from e
+            self.pool[name] = {ax: jnp.zeros(shape, dtype)
+                               for ax, (shape, dtype) in shapes.items()}
         if mesh is not None:
-            from jax.sharding import NamedSharding
-
             self.pool = jax.device_put(self.pool, {
                 name: {ax: NamedSharding(mesh, spec)
                        for ax, spec in node.items()}
@@ -345,8 +213,6 @@ class PagedKVCache:
         self._clock = 0
         self._jit_cache: dict[tuple, object] = {}
         self._zero = jnp.zeros((), jnp.int32)
-        self._jax = jax
-        self._jnp = jnp
         # -- counters (engine stats mirror these) --
         self.evictions = 0
         self.commit_skips = 0
@@ -545,10 +411,12 @@ class PagedKVCache:
         self.unpin_session(session)
         node.pins += 1
         self._sessions[session] = node
-        # with state layers the chain's snapshot sits where its last
-        # PROMPT ended, above the tail: the session holds that too
+        # a snapshot that only a prefill can take sits where the
+        # chain's last PROMPT ended, above the tail: the session holds
+        # that too
         holder = node
-        while self._state and holder is not None and not holder.snap:
+        while (self._prompt_snaps and holder is not None
+               and not holder.snap):
             holder = holder.parent
         if holder is not None and holder is not node and holder.snap:
             holder.pins += 1
@@ -597,195 +465,73 @@ class PagedKVCache:
 
     # -- mesh sharding -------------------------------------------------------
     def _pool_specs(self):
-        """Per-layer PartitionSpec tree for the pool's k/v buffers —
-        the shard_map in/out specs and the constructor's device_put.
+        """Per-layer PartitionSpec tree for the pool's buffers — the
+        shard_map in/out specs and the constructor's device_put.
         Blocks stay whole on every shard (axis 0 unsharded); only the
-        KV-head axis splits, and only for layers the engine shards."""
-        from jax.sharding import PartitionSpec as P
-
-        return {name: {"k": P(None, "tp") if self._layer_sharded[name]
-                       else P(),
-                       "v": P(None, "tp") if self._layer_sharded[name]
-                       else P()}
+        KV-head axis splits, exactly when the engine shards that
+        layer's slot slabs (``CacheClass.sharded``): per-shard pools
+        with IDENTICAL block ids, so a block move never crosses shards
+        and one host trie covers every shard."""
+        return {name: dict.fromkeys(
+                    self.pool[name],
+                    P(None, "tp") if self._cls[name].sharded(
+                        self._shapes[name], self._tp) else P())
                 for name in self._layers}
 
-    def _cache_specs(self):
-        """PartitionSpec tree for a full engine cache passed into the
-        scatter jit (slot slabs shard like the pool; indices are
-        replicated)."""
-        from jax.sharding import PartitionSpec as P
-
-        out = {}
-        for name in self._layers:
-            kv = P(None, "tp") if self._layer_sharded[name] else P()
-            out[name] = {"cached_key": kv, "cached_value": kv,
-                         "cache_index": P()}
-        return out
-
-    def _pool_jit(self, fn, in_specs, donate=()):
-        """jit ``fn`` over pool-shaped operands; on a mesh, lift it
-        through ``shard_map`` first so every block move is shard-local
-        by construction (per-shard pools, identical indices — the body
-        can never emit a collective).  ``check_vma=False``: the bodies
-        are all gathers/scatters by replicated indices, which the
-        replication checker cannot prove through."""
-        if self._mesh is None:
-            return self._jax.jit(fn, donate_argnums=donate)
-        wrapped = self._jax.shard_map(
-            fn, mesh=self._mesh, in_specs=in_specs,
-            out_specs=self._pool_specs(), check_vma=False)
-        return self._jax.jit(wrapped, donate_argnums=donate)
+    def _pool_jit(self, key, fn, in_specs, donate=()):
+        """jit ``fn`` over pool-shaped operands, once a ``key``; on a
+        mesh, lift it through ``shard_map`` first so every block move is
+        shard-local by construction (per-shard pools, identical indices
+        — the body can never emit a collective).  ``in_specs`` is made
+        on a mesh alone.  ``check_vma=False``: the bodies are all
+        gathers/scatters by replicated indices, which the replication
+        checker cannot prove through."""
+        jit = self._jit_cache.get(key)
+        if jit is None:
+            if self._mesh is not None:
+                fn = jax.shard_map(
+                    fn, mesh=self._mesh, in_specs=in_specs(),
+                    out_specs=self._pool_specs(), check_vma=False)
+            jit = self._jit_cache[key] = jax.jit(fn, donate_argnums=donate)
+        return jit
 
     # -- jitted device ops ---------------------------------------------------
-    def _ring_slots(self, end, ring: int):
-        """Ring slots of the ``window`` positions that end at ``end``
-        (traced): position p lives at slot ``p % ring``.  Positions
-        before 0 fall on slots the ring's own position mask never
-        reads."""
-        return (end - self.window + self._jnp.arange(self.window)) % ring
-
     def load_prefix_into(self, cache, pool, block_ids, n: int, prefix_len,
                          snap_id=0):
         """Pure helper traced INSIDE the engine's reuse-prefill jit
         (``pool`` is the traced argument — never read device state off
-        ``self`` under a trace): gather ``n`` (padded) blocks into the
-        front of a fresh one-lane cache and set its index to the traced
-        ``prefix_len`` (<= ``n * block``; the scratch-padded tail lands
-        beyond it and is overwritten or masked before any query can
-        attend it).  Window layers take snapshot ``snap_id`` (the
-        chain tail's) into the ring slots of the ``window`` positions
-        before ``prefix_len``: exactly the last window of the prefix.
-        State layers take the same entry whole: the recurrence as it
-        stood after token ``prefix_len - 1``.  Latent layers gather
-        their blocks of rows as global layers gather keys and values."""
-        jnp = self._jnp
-        bs = self.block
-        out = {}
-        for name in self._layers:
-            node = cache[name]
-            if name in self._state:
-                out[name] = self._jax.tree_util.tree_map_with_path(
-                    lambda path, v, name=name: (
-                        jnp.full_like(v, prefix_len)
-                        if path[-1].key == "cache_index" else
-                        pool[name][_leaf_key(path)][snap_id][None].astype(
-                            v.dtype)), node)
-                continue
-            if name in self._latent:
-                rows = pool[name]["c"][block_ids]         # [n, bs, row]
-                rows = rows.reshape(n * bs, rows.shape[-1])
-                out[name] = self._jax.tree_util.tree_map_with_path(
-                    lambda path, v, rows=rows: (
-                        jnp.full_like(v, prefix_len)
-                        if path[-1].key == "cache_index" else
-                        v.at[0, :n * bs].set(rows.astype(v.dtype))), node)
-                continue
-            if name in self._ring:
-                ring = node["cached_key"].shape[-1]
-                slots = self._ring_slots(prefix_len, ring)
-                k = pool[name]["k"][snap_id]          # [Hk, D, window]
-                v = pool[name]["v"][snap_id]          # [Hk, window, D]
-                out[name] = {
-                    "cached_key": node["cached_key"][0].at[:, :, slots].set(
-                        k.astype(node["cached_key"].dtype))[None],
-                    "cached_value": node["cached_value"][0].at[
-                        :, slots, :].set(
-                        v.astype(node["cached_value"].dtype))[None],
-                    "cache_index": jnp.full_like(node["cache_index"],
-                                                 prefix_len),
-                }
-                continue
-            k = pool[name]["k"][block_ids]            # [n, Hk, D, bs]
-            k = jnp.moveaxis(k, 0, 2).reshape(
-                k.shape[1], k.shape[2], n * bs)
-            v = pool[name]["v"][block_ids]            # [n, Hk, bs, D]
-            v = jnp.moveaxis(v, 0, 1).reshape(
-                v.shape[1], n * bs, v.shape[3])
-            out[name] = {
-                "cached_key": node["cached_key"].at[0, :, :, :n * bs].set(
-                    k.astype(node["cached_key"].dtype)),
-                "cached_value": node["cached_value"].at[0, :, :n * bs, :].set(
-                    v.astype(node["cached_value"].dtype)),
-                "cache_index": jnp.full_like(node["cache_index"],
-                                             prefix_len),
-            }
-        return out
+        ``self`` under a trace): a fresh one-lane cache with a hit's
+        prefix in it and its index at the traced ``prefix_len``, each
+        layer as its class loads it (``CacheClass.load``): ``n``
+        (padded) blocks at the front of a slab (``prefix_len <= n *
+        block``; the scratch-padded tail lands beyond it and is
+        overwritten or masked before any query can attend it), or
+        snapshot ``snap_id`` (the chain tail's): the last window of the
+        prefix back at its ring slots, a recurrence as it stood after
+        token ``prefix_len - 1``."""
+        return {name: self._cls[name].load(
+                    cache[name], pool[name], block_ids, n, self.block,
+                    prefix_len, snap_id)
+                for name in self._layers}
 
     def _scatter_fn(self, n: int):
         """jit per new-block count: copy ``n`` contiguous blocks of one
         slot's slab (starting at traced byte position ``start``) into
-        the pool at ``block_ids``.  The pool is donated — committing
-        never copies it."""
-        key = ("scatter", n)
-        fn = self._jit_cache.get(key)
-        if fn is not None:
-            return fn
-        jax, jnp = self._jax, self._jnp
-        bs = self.block
-        layers = self._layers
-
-        ring_layers = self._ring
-
+        the pool at ``block_ids``, and the snapshot that ends at
+        ``snap_end`` into entry ``snap_id``, each layer as its class
+        stores it (``CacheClass.store``).  The pool is donated —
+        committing never copies it."""
         def scatter(pool, cache, slot, start, block_ids, snap_id, snap_end):
-            out = {}
-            for name in layers:
-                if name in self._state:
-                    # a recurrence is saved where the prefill computed
-                    # it (store_state), never out of a slot
-                    out[name] = pool[name]
-                    continue
-                if name in ring_layers:
-                    # the window that ends at snap_end, out of the
-                    # slot's ring, into snapshot snap_id (0: scratch)
-                    k_lane = jnp.take(cache[name]["cached_key"], slot, axis=0)
-                    v_lane = jnp.take(cache[name]["cached_value"], slot,
-                                      axis=0)
-                    slots = self._ring_slots(snap_end, k_lane.shape[-1])
-                    out[name] = {
-                        "k": pool[name]["k"].at[snap_id].set(
-                            k_lane[:, :, slots]),
-                        "v": pool[name]["v"].at[snap_id].set(
-                            v_lane[:, slots, :]),
-                    }
-                    continue
-                if not n:
-                    out[name] = pool[name]
-                    continue
-                if name in self._latent:
-                    key, row, _ = self._latent_layout[name]
-                    lane = jnp.take(state_leaves(cache[name])[key], slot,
-                                    axis=0)               # [max_len, row]
-                    rows = jax.lax.dynamic_slice(lane, (start, 0),
-                                                 (n * bs, row))
-                    out[name] = {"c": pool[name]["c"].at[block_ids].set(
-                        rows.reshape(n, bs, row))}
-                    continue
-                # head/feature extents come from the OPERANDS, not the
-                # global layout: under shard_map this body sees the
-                # per-shard slice (hk/tp heads), and the slab/pool pair
-                # agree per shard by construction
-                k_lane = jnp.take(cache[name]["cached_key"], slot, axis=0)
-                hk, d = k_lane.shape[0], k_lane.shape[1]
-                k_sl = jax.lax.dynamic_slice(k_lane, (0, 0, start),
-                                             (hk, d, n * bs))
-                k_blocks = jnp.moveaxis(k_sl.reshape(hk, d, n, bs), 2, 0)
-                v_lane = jnp.take(cache[name]["cached_value"], slot, axis=0)
-                v_sl = jax.lax.dynamic_slice(v_lane, (0, start, 0),
-                                             (hk, n * bs, d))
-                v_blocks = jnp.moveaxis(v_sl.reshape(hk, n, bs, d), 1, 0)
-                out[name] = {
-                    "k": pool[name]["k"].at[block_ids].set(k_blocks),
-                    "v": pool[name]["v"].at[block_ids].set(v_blocks),
-                }
-            return out
+            return {name: self._cls[name].store(
+                        pool[name], cache[name], slot, start, block_ids, n,
+                        self.block, snap_id, snap_end)
+                    for name in self._layers}
 
-        from jax.sharding import PartitionSpec as P
-
-        fn = self._pool_jit(
-            scatter, (self._pool_specs(), self._cache_specs(),
-                      P(), P(), P(), P(), P()), donate=(0,))
-        self._jit_cache[key] = fn
-        return fn
+        return self._pool_jit(
+            ("scatter", n), scatter, lambda: (
+                self._pool_specs(),
+                cache_specs(self._cls, self._shapes, self._tp), *[P()] * 5),
+            donate=(0,))
 
     def store_blocks(self, cache, slot: int, start_block: int,
                      block_ids: list[int], snap: tuple[int, int] = (0, 0),
@@ -798,7 +544,6 @@ class PagedKVCache:
         scratch entries: ``ContinuousBatcher.warm``)."""
         if not block_ids and not snap[0] and not warm:
             return
-        jnp = self._jnp
         self.pool = self._scatter_fn(len(block_ids))(
             self.pool, cache, jnp.asarray(slot, jnp.int32),
             jnp.asarray(start_block * self.block, jnp.int32),
@@ -812,22 +557,20 @@ class PagedKVCache:
         program computed at its lanes' block edges
         (``transformer.Mamba2Mixer``'s ``snap`` collection).  One
         dispatch; the pool is donated."""
-        fn = self._jit_cache.get("store_state")
-        if fn is None:
-            def put(pool, snap, lane, sid):
-                out = dict(pool)
-                for name in self._state:
-                    out[name] = {
-                        k: pool[name][k].at[sid].set(
-                            self._jnp.take(snap[name][k], lane, axis=0
-                                           ).astype(pool[name][k].dtype))
-                        for k in pool[name]}
-                return out
+        def put(pool, snap, lane, sid):
+            out = dict(pool)
+            for name in snap:
+                out[name] = {
+                    k: pool[name][k].at[sid].set(
+                        jnp.take(snap[name][k], lane, axis=0).astype(
+                            pool[name][k].dtype))
+                    for k in pool[name]}
+            return out
 
-            fn = self._jit_cache["store_state"] = self._jax.jit(
-                put, donate_argnums=(0,))
-        self.pool = fn(self.pool, snap, self._jnp.asarray(lane, self._jnp.int32),
-                       self.snap_arg(sid))
+        # no mesh pages a class that snapshots (the constructor refuses)
+        self.pool = self._pool_jit("store_state", put, None, donate=(0,))(
+            self.pool, snap, jnp.asarray(lane, jnp.int32),
+            self.snap_arg(sid))
 
     def snap_arg(self, value: int):
         """A snapshot id or end position as the pool programs take it:
@@ -835,86 +578,52 @@ class PagedKVCache:
         without window layers passes (no transfer a commit there)."""
         if not value:
             return self._zero
-        return self._jnp.asarray(value, self._jnp.int32)
+        return jnp.asarray(value, jnp.int32)
 
     def _gather_fn(self, n: int):
-        key = ("gather", n)
-        fn = self._jit_cache.get(key)
-        if fn is not None:
-            return fn
-        layers = self._layers
-
-        ring_layers = self._ring
-
         def gather(pool, block_ids, snap_ids):
-            return {name: {ax: pool[name][ax][
-                snap_ids if name in ring_layers or name in self._state
-                else block_ids]
-                for ax in pool[name]} for name in layers}
+            return {name: {ax: buf[snap_ids if self._cls[name].snapshotted
+                                   else block_ids]
+                           for ax, buf in pool[name].items()}
+                    for name in self._layers}
 
-        from jax.sharding import PartitionSpec as P
-
-        fn = self._pool_jit(gather, (self._pool_specs(), P(), P()))
-        self._jit_cache[key] = fn
-        return fn
+        return self._pool_jit(("gather", n), gather, lambda: (
+            self._pool_specs(), P(), P()))
 
     # -- migration wire format ----------------------------------------------
+    def _grouped(self) -> dict:
+        """``{metadata key: its layers, sorted}`` of the classes that
+        have one; ``ring_layers`` always (an older importer reads it)."""
+        out = {"ring_layers": []}
+        for name in self._layers:
+            if self._cls[name].meta_key:
+                out.setdefault(self._cls[name].meta_key, []).append(name)
+        return out
+
     def export_chain(self, chain: list[_Node]) -> tuple[dict, bytes]:
-        """(meta, blob) for one chain: per layer (sorted), the k blocks
-        then the v blocks, raw ``tobytes()`` concatenated; for a window
-        layer the tail's one snapshot in their place.  ``meta`` carries
-        what the importer must agree on; tokens travel beside it (the
-        chain IS the token sequence).  With window layers the chain
-        must end at a snapshot-bearing node (``reusable``)."""
-        jnp = self._jnp
+        """(meta, blob) for one chain: per layer (sorted), its pool
+        buffers in order (a layer paged by blocks: the chain's blocks,
+        k then v; one paged by snapshots: the tail's one snapshot), raw
+        ``tobytes()`` concatenated.  ``meta`` carries what the importer
+        must agree on; tokens travel beside it (the chain IS the token
+        sequence).  With snapshotted layers the chain must end at a
+        snapshot-bearing node (``reusable``)."""
         if self._snapped and not (chain and chain[-1].snap):
             raise ValueError("chain tail owns no layer-state snapshot")
         ids = jnp.asarray([nd.block_id for nd in chain], jnp.int32)
         snaps = jnp.asarray([chain[-1].snap if self._snapped else 0],
                             jnp.int32)
         got = self._gather_fn(len(chain))(self.pool, ids, snaps)
-        parts: list[bytes] = []
-        for name in self._layers:
-            for ax in self._axes(name):
-                parts.append(np.asarray(got[name][ax]).tobytes())
-        blob = b"".join(parts)
+        blob = b"".join(np.asarray(got[name][ax]).tobytes()
+                        for name in self._layers for ax in self.pool[name])
+        groups = self._grouped()
         meta = {"block": self.block, "n": len(chain),
-                "layers": list(self._layers),
-                "window": self.window, "ring_layers": sorted(self._ring),
-                "layout": {name: [hk, d, str(np.dtype(dtype))]
-                           for name, (hk, d, dtype) in self._layout.items()}}
-        if self._latent:
-            meta["latent_layers"] = sorted(self._latent)
-            meta["layout"].update(
-                {name: [row, str(np.dtype(dtype))]
-                 for name, (_, row, dtype) in self._latent_layout.items()})
-        if self._state:
-            meta["state_layers"] = sorted(self._state)
-            meta["layout"].update(
-                {name: {k: [list(shape), str(np.dtype(dtype))]
-                        for k, (shape, dtype) in leaves.items()}
-                 for name, leaves in self._state_layout.items()})
+                "layers": list(self._layers), "window": self.window,
+                "ring_layers": groups.pop("ring_layers"),
+                "layout": {name: self._cls[name].meta(self._shapes[name])
+                           for name in self._layers},
+                **dict(sorted(groups.items()))}
         return meta, blob
-
-    def _axes(self, name: str) -> tuple:
-        """A layer's pool buffers in the blob's order."""
-        return (tuple(self._state_layout[name]) if name in self._state
-                else ("c",) if name in self._latent else ("k", "v"))
-
-    def _wire_shapes(self, name: str, n: int) -> dict:
-        """``{buffer: (shape, dtype)}`` of what a chain of ``n`` blocks
-        carries for layer ``name``: n blocks of a global layer, one
-        snapshot of a window or a state layer, n blocks of rows of a
-        latent layer."""
-        if name in self._state:
-            return {k: ((1,) + shape, dtype)
-                    for k, (shape, dtype) in self._state_layout[name].items()}
-        if name in self._latent:
-            _, row, dtype = self._latent_layout[name]
-            return {"c": ((n, self.block, row), dtype)}
-        hk, d, dtype = self._layout[name]
-        m, t = (1, self.window) if name in self._ring else (n, self.block)
-        return {"k": ((m, hk, d, t), dtype), "v": ((m, hk, t, d), dtype)}
 
     def import_chain(self, session: str, tokens: list[int], meta: dict,
                      blob: bytes) -> int:
@@ -924,7 +633,6 @@ class PagedKVCache:
         too full to hold the whole chain truncates the import (the
         session resumes from the shorter prefix — still warmer than a
         cold start)."""
-        jnp = self._jnp
         n = int(meta["n"])
         if int(meta["block"]) != self.block:
             raise ValueError(
@@ -932,24 +640,20 @@ class PagedKVCache:
                 f"{self.block}")
         if list(meta["layers"]) != self._layers:
             raise ValueError("kv import layer set mismatch")
-        if (int(meta.get("window", 0)) != self.window
-                or list(meta.get("ring_layers", [])) != sorted(self._ring)):
+        groups = self._grouped()
+        if int(meta.get("window", 0)) != self.window:
             raise ValueError("kv import window layers mismatch")
-        if list(meta.get("state_layers", [])) != sorted(self._state):
-            raise ValueError("kv import state layers mismatch")
-        if list(meta.get("latent_layers", [])) != sorted(self._latent):
-            raise ValueError("kv import latent layers mismatch")
-        for name, (_, row, dtype) in self._latent_layout.items():
-            if list(meta["layout"][name]) != [row, str(np.dtype(dtype))]:
-                raise ValueError(f"kv import layout mismatch at {name}")
-        for name, (hk, d, dtype) in self._layout.items():
-            if list(meta["layout"][name]) != [hk, d,
-                                              str(np.dtype(dtype))]:
-                raise ValueError(f"kv import layout mismatch at {name}")
-        for name, leaves in self._state_layout.items():
-            if meta["layout"][name] != {
-                    k: [list(shape), str(np.dtype(dtype))]
-                    for k, (shape, dtype) in leaves.items()}:
+        for key in sorted({*groups, *(k for k in meta
+                                      if k.endswith("_layers"))}):
+            mine = groups.get(key, [])
+            if list(meta.get(key, [])) != mine:
+                kind = (self._cls[mine[0]].kind if mine
+                        else key[:-len("_layers")])
+                raise ValueError(f"kv import {kind} layers mismatch")
+        for name in self._layers:
+            got = meta["layout"][name]
+            if (list(got) if isinstance(got, tuple) else got) != \
+                    self._cls[name].meta(self._shapes[name]):
                 raise ValueError(f"kv import layout mismatch at {name}")
         if len(tokens) < n * self.block:
             raise ValueError(
@@ -960,7 +664,9 @@ class PagedKVCache:
         off = 0
         for name in self._layers:
             arrays[name] = {}
-            for ax, (shape, dtype) in self._wire_shapes(name, n).items():
+            # what a chain of n blocks carries: n blocks, or one snapshot
+            for ax, (shape, dtype) in self._cls[name].pool_shapes(
+                    self._shapes[name], self.block, n, 1).items():
                 count = int(np.prod(shape, dtype=np.int64))
                 if off + count * np.dtype(dtype).itemsize > len(blob):
                     raise ValueError(
@@ -994,7 +700,8 @@ class PagedKVCache:
             idx = [i for i, _ in fresh]
             ids = jnp.asarray([b for _, b in fresh], jnp.int32)
             snaps = jnp.asarray([snap], jnp.int32)
-            snapped = self._ring | self._state
+            snapped = {name for name in self._layers
+                       if self._cls[name].snapshotted}
             upload = {
                 name: {ax: jnp.asarray(a if name in snapped else a[idx])
                        for ax, a in arrays[name].items()}
@@ -1006,20 +713,14 @@ class PagedKVCache:
                         upload[name][ax]) for ax in pool[name]}
                     for name in self._layers}
 
-            key = ("import", len(fresh))
-            fn = self._jit_cache.get(key)
-            if fn is None:
-                from jax.sharding import PartitionSpec as P
-
-                # the upload shards like the pool (jit reshards the
-                # host arrays on the way in), so each shard writes only
-                # ITS heads of every fresh block — shape-aligned with
-                # its pool slice by construction
-                fn = self._pool_jit(
-                    put, (self._pool_specs(), P(), P(), self._pool_specs()),
-                    donate=(0,))
-                self._jit_cache[key] = fn
-            self.pool = fn(self.pool, ids, snaps, upload)
+            # the upload shards like the pool (jit reshards the host
+            # arrays on the way in), so each shard writes only ITS heads
+            # of every fresh block — shape-aligned with its pool slice
+            # by construction
+            self.pool = self._pool_jit(
+                ("import", len(fresh)), put, lambda: (
+                    self._pool_specs(), P(), P(), self._pool_specs()),
+                donate=(0,))(self.pool, ids, snaps, upload)
         if node is self._root:
             # a pool too full for even the FIRST block adopted nothing:
             # raising lets the exporter try the next candidate instead

@@ -394,8 +394,9 @@ def test_step_program_for_v5e_holds_no_whole_slab_temporary(
 
     # the part of an engine the step reads, and nothing it would allocate
     engine = types.SimpleNamespace(
-        _model=model, _T=T, _moe_dropless=False, _moe_acc_shape=None,
-        _state_layers=(), _latent_layers=(),
+        _model=model, _T=T,
+        _acc_shape=jax.ShapeDtypeStruct((0,), jnp.float32),
+        _sown=lambda mut: jnp.zeros((0,), jnp.float32),
         _positions=ContinuousBatcher._positions,
         _sample=lambda logits, key: sample_logits(logits, key,
                                                   temperature=0.0))

@@ -4,7 +4,7 @@ mode: against the XLA loop it replaces on the chip
 (``expanded_attention``) and a dense one-shot reference; the dispatch
 rule (``expand_applies``), the tile of the path that runs
 (``expand_block`` / ``expand_heads``), and the engine's bookkeeping of
-both (``_latent_tile``, ``_require_fit``, ``stats()``).
+both (``LatentRows.tile``, ``_require_fit``, ``stats()``).
 
 Nothing selects the kernel on the CPU: every test that wants it calls
 it, or patches the rule, itself.  TOLERANCE: the loop's tests' (1e-5
@@ -372,7 +372,7 @@ def test_the_engine_counts_the_calls_that_took_the_kernel(
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["loop", "kernel"])
 def test_the_fit_prices_the_path_that_runs(monkeypatch, toy_params, kernel):
-    """``_latent_tile`` is ``expand_block`` of the path the call takes,
+    """``LatentRows.tile`` is ``expand_block`` of the path the call takes,
     and ``_require_fit`` (through a device that reports a limit) prices
     a lane's attention at it: the loop's tile of float32 scores,
     probabilities and expanded rows, or the kernel's head-major queries
@@ -406,11 +406,12 @@ def test_the_fit_prices_the_path_that_runs(monkeypatch, toy_params, kernel):
     try:
         rungs, p_max, kv = eng.PREFILL_KS, 16, NOPE + VD
         loop_limit = least_limit(eng)
-        assert eng._latent_tile(rungs[0], p_max, HEADS) == la.expand_block(
+        tile = eng._classes["layer_0"].tile
+        assert tile(rungs[0], p_max, HEADS) == la.expand_block(
             rungs[0], p_max, HEADS, kv, 256, jnp.float32) == 256
         if kernel:
             force_kernel(monkeypatch)
-            assert eng._latent_tile(rungs[0], p_max, HEADS) == la.expand_block(
+            assert tile(rungs[0], p_max, HEADS) == la.expand_block(
                 rungs[0], p_max, HEADS, kv, 256, jnp.float32, True)
             loop = 256 * HEADS * (2 * 4 * p_max + kv * 4)
             tiled = p_max * HEADS * (kv + ROW - RANK) * 4

@@ -409,7 +409,7 @@ def test_an_all_latent_stack_builds_fits_and_warms(params, monkeypatch):
         assert stats["kv_slot_bytes_latent"] == LAYERS * 128 * 128 * 4
         assert (stats["kv_slot_bytes_state"] == stats["kv_slot_bytes_global"]
                 == stats["kv_slot_bytes_window"] == 0)
-        assert eng._kv.n_snaps == 0 and not eng._state_layers
+        assert eng._kv.n_snaps == 0 and not eng._snapped
         monkeypatch.setattr(jax, "devices", lambda: [_Chip()])
         assert eng._require_fit(3, BLOCK, 64, 0) == 0
         monkeypatch.undo()

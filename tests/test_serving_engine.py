@@ -766,11 +766,11 @@ def test_failing_program_fails_both_ticks_and_the_engine_serves_on(small):
     armed, real = [], eng._step_jit
 
     def step(*args):
-        cache, toks, dec = real(*args)
+        cache, toks, dec, sown = real(*args)
         if armed:
             armed.clear()
-            return cache, toks, Poisoned()
-        return cache, toks, dec
+            return cache, toks, Poisoned(), sown
+        return cache, toks, dec, sown
 
     eng._step_jit = step
     try:

@@ -223,7 +223,7 @@ def test_chunked_admission_traces_no_model_init(small, mesh_or_none,
 def test_fresh_cache_matches_model_init(small, mesh_or_none, which):
     """The slab builder's output against the definition it memoises:
     leaf for leaf the shapes and dtypes of ``model.init``'s cache, all
-    zeros, placed as ``_leaf_sharding`` says (target) or replicated
+    zeros, placed as ``_cache_shardings`` says (target) or replicated
     (draft) on the mesh — and a new buffer on every call."""
     cfg, params = small
     dcfg = TransformerConfig(vocab_size=97, num_layers=1, embed_dim=16,
@@ -237,8 +237,8 @@ def test_fresh_cache_matches_model_init(small, mesh_or_none, which):
         model, fresh = ((eng._model, eng._fresh_cache) if which == "target"
                         else (eng._draft_model, eng._draft_fresh_cache))
         if which == "target":       # what _maybe_start_chunk calls
-            slab, drops = eng._chunk_start()
-            assert drops.shape == () and int(drops) == 0
+            slab, drops = eng._chunk_start()    # a dense stack sows nothing
+            assert drops.shape == (0,) and drops.dtype == jnp.float32
             assert (jax.tree.map(lambda x: (x.shape, x.dtype, x.sharding),
                                  slab)
                     == jax.tree.map(lambda x: (x.shape, x.dtype, x.sharding),
@@ -249,14 +249,22 @@ def test_fresh_cache_matches_model_init(small, mesh_or_none, which):
                 positions=jnp.zeros((B, 1), jnp.int32)))["cache"]
             got, again = fresh(B), fresh(B)
             assert jax.tree.structure(got) == jax.tree.structure(want)
-            for w, g, g2 in zip(*map(jax.tree.leaves, (want, got, again))):
+            rules = (eng._cache_shardings(B) if which == "target"
+                     and mesh_or_none is not None else want)
+            for w, g, g2, rule in zip(*map(jax.tree.leaves,
+                                           (want, got, again, rules))):
                 assert (g.shape, g.dtype) == (w.shape, w.dtype)
                 assert not np.asarray(g).any()
                 assert g is not g2
                 if mesh_or_none is not None:
                     from jax.sharding import NamedSharding, PartitionSpec
-                    rule = (eng._leaf_sharding(w) if which == "target" else
-                            NamedSharding(mesh_or_none, PartitionSpec()))
+                    if which == "target":   # kv heads over tp where they divide
+                        tp = dict(mesh_or_none.shape).get("tp", 1)
+                        assert rule.spec == PartitionSpec(
+                            *((None, "tp") if w.ndim >= 2 and tp > 1
+                              and w.shape[1] % tp == 0 else ()))
+                    else:
+                        rule = NamedSharding(mesh_or_none, PartitionSpec())
                     assert g.sharding.is_equivalent_to(rule, g.ndim), (
                         g.sharding, rule)
     finally:

@@ -501,7 +501,7 @@ def test_the_state_class_is_allocated_by_its_bytes(recurrent, monkeypatch):
         blocks = 2 * 64 * 4 * 8 * 4 * 4
         assert pool_device_bytes(
             one_lane, 4, 64, n_snaps=n,
-            state_layers=eng._state_layers) == blocks + n * 2 * _STATE_BYTES
+            classes=eng._classes) == blocks + n * 2 * _STATE_BYTES
 
         class _Chip:
             device_kind = "toy chip"
@@ -662,17 +662,17 @@ def test_the_latent_class_is_one_buffer_a_layer(latent):
         assert stats["kv_slot_bytes_global"] == 0
         one_lane = eng._cache_shapes(1)
         assert pool_device_bytes(
-            one_lane, 4, 64, n_snaps=n, state_layers=eng._state_layers,
-            latent_layers=eng._latent_layers
+            one_lane, 4, 64, n_snaps=n, classes=eng._classes
         ) == 64 * 4 * 128 * 4 + n * 2 * _KDA_BYTES
         # a layer that is named to no class is refused, and a mesh is
         with pytest.raises(ValueError, match="neither a state layer nor"):
-            PagedKVCache(one_lane, 4, 8, 2, n_snaps=3,
-                         state_layers=eng._state_layers)
+            PagedKVCache(one_lane, 4, 8, 2, n_snaps=3, classes={
+                name: cls for name, cls in eng._classes.items()
+                if cls.snapshotted})
         from jax.sharding import Mesh
         with pytest.raises(ValueError, match="no head axis"):
             PagedKVCache({"layer_1": one_lane["layer_1"]}, 4, 8, 2,
-                         latent_layers={"layer_1"},
+                         classes={"layer_1": eng._classes["layer_1"]},
                          mesh=Mesh(np.asarray(jax.devices()[:2]), ("tp",)))
     finally:
         eng.stop()
@@ -734,3 +734,91 @@ def test_export_import_of_a_latent_chain_on_a_second_pool(latent):
     finally:
         b.stop()
         cold.stop()
+
+
+# -- a class the pool has never heard of ----------------------------------
+
+
+def test_a_fifth_cache_class_pages_without_editing_the_pool():
+    """A cache class is handed to the pool, not written into it: a toy
+    class (a fixed ``[5, 3]`` memory a layer, snapshotted whole out of
+    the slot, under a metadata key of its own) beside a layer of
+    per-head rows commits, matches, loads back into a slab, exports and
+    imports into a second pool, through ``PagedKVCache``'s own methods
+    and nothing else."""
+    from edl_tpu.serving.cache_layout import CacheClass
+    from edl_tpu.serving.kv_cache import PagedKVCache
+
+    class Memory(CacheClass):
+        kind, meta_key, snapshotted = "memory", "memory_layers", True
+
+        def buffers(self, node):
+            return {"m": ("toy/memory", None)}
+
+        def meta(self, node):
+            return list(node["toy"]["memory"].shape[1:])
+
+    rng = np.random.default_rng(3)
+    slots, hk, d, length, block = 2, 2, 4, 32, 4
+
+    def cache_of(lanes, fill):
+        return {
+            "layer_0": {"cached_key": fill((lanes, hk, d, length)),
+                        "cached_value": fill((lanes, hk, length, d)),
+                        "cache_index": jnp.zeros((lanes,), jnp.int32)},
+            "layer_1": {"toy": {"memory": fill((lanes, 5, 3))},
+                        "cache_index": jnp.zeros((lanes,), jnp.int32)}}
+
+    cache = cache_of(slots, lambda shape: jnp.asarray(
+        rng.standard_normal(shape), jnp.float32))
+    one_lane = jax.eval_shape(lambda: cache_of(1, jnp.zeros))
+    classes = {"layer_1": Memory()}
+
+    def pool():
+        return PagedKVCache(one_lane, block, 16, 2, classes=classes,
+                            n_snaps=4)
+
+    def loaded(kv, chain, n_pad=4):
+        ids = np.zeros((n_pad,), np.int32)
+        ids[:len(chain)] = [nd.block_id for nd in chain]
+        return kv.load_prefix_into(
+            cache_of(1, jnp.zeros), kv.pool, jnp.asarray(ids), n_pad,
+            jnp.asarray(len(chain) * block, jnp.int32),
+            kv.snap_arg(chain[-1].snap))
+
+    def check(slab, n):
+        np.testing.assert_array_equal(
+            slab["layer_0"]["cached_key"][0, :, :, :n],
+            cache["layer_0"]["cached_key"][1, :, :, :n])
+        np.testing.assert_array_equal(
+            slab["layer_0"]["cached_value"][0, :, :n],
+            cache["layer_0"]["cached_value"][1, :, :n])
+        np.testing.assert_array_equal(slab["layer_1"]["toy"]["memory"][0],
+                                      cache["layer_1"]["toy"]["memory"][1])
+        assert int(slab["layer_1"]["cache_index"][0]) == n
+
+    a = pool()
+    assert {k: v.shape for k, v in a.pool["layer_1"].items()} == {
+        "m": (4, 5, 3)}
+    tokens = list(range(1, 14))                     # three full blocks
+    start, new_ids, tail = a.commit(tokens)
+    assert (start, len(new_ids)) == (0, 3)
+    a.store_blocks(cache, 1, start, new_ids, (a.snap_for(tail), 12))
+    chain = a.match(tokens + [99])
+    assert [nd.block_id for nd in chain] == new_ids and chain[-1].snap
+    check(loaded(a, chain), 12)
+    # a chain that ends where no snapshot was taken cannot be resumed
+    assert a.match(tokens[:9]) == []
+    a.pin_session("s", tail)
+    meta, blob = a.export_chain(a.chain_of("s"))
+    assert meta["memory_layers"] == ["layer_1"] and meta["ring_layers"] == []
+    assert meta["layout"]["layer_1"] == [5, 3]
+    assert len(blob) == 4 * (2 * 12 * hk * d + 5 * 3)
+
+    b = pool()
+    assert b.import_chain("s", tokens[:12], meta, blob) == 3
+    check(loaded(b, b.match(tokens)), 12)
+    with pytest.raises(ValueError, match="memory layers mismatch"):
+        PagedKVCache({"layer_0": one_lane["layer_0"]}, block, 16, 2
+                     ).import_chain("s", tokens[:12],
+                                    dict(meta, layers=["layer_0"]), blob)
