@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -29,7 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from edl_tpu.ops import decode_attention, kda, latent_attention, ssm
+from edl_tpu.ops import decode_attention, kda, latent_attention, mamba1, ssm
 from edl_tpu.ops.attention import (
     SPLASH_RESIDUALS, dot_product_attention, splash_partials_bytes,
 )
@@ -135,9 +136,11 @@ class TransformerConfig:
     # layer "window" when attn_window is set, else "global"
     attn_window: int = 0
     layer_attn: tuple = ()
-    # False: the global layers of a windowed plan do not rotate q and k
-    # (no positional embedding there); window layers always rotate
+    # False: the global layers of a plan do not rotate q and k (no
+    # positional embedding there); rope_window says the same of the
+    # window layers (both False: no positional embedding anywhere)
     rope_global: bool = True
+    rope_window: bool = True
     # qk_norm over each head's head_dim (scale [head_dim]) instead of
     # over the whole projection
     qk_norm_per_head: bool = False
@@ -215,6 +218,31 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     attn_scale: float = 0.0
     logits_scaling: float = 1.0
+    # -- layer_attn[i] == "mamba1" is a Mamba-1 layer (``Mamba1Mixer``,
+    # ``ops/mamba1.py``): m1_inner channels with a state of m1_state
+    # each, a decay a (channel, state) pair, B, C and a dt of rank
+    # m1_dt_rank read from the convolved x (a causal depthwise
+    # convolution over m1_conv positions); the state a decode model
+    # carries kept in ssm_state_dtype.  It also EMITS its scan output
+    # (with the D term, before the gate), which a later layer_attn[i] ==
+    # "gmu" (``GatedMemoryUnit``: no state, a gate of its own input on
+    # that memory of the same token) reads
+    m1_inner: int = 0
+    m1_state: int = 16
+    m1_conv: int = 4
+    m1_dt_rank: int = 0
+    # layer_attn[i] == "cross" is attention that projects a query only
+    # and reads the keys and values the latest "global" layer below it
+    # wrote (in a decode model: that layer's slab); it keeps no cache
+    # -- differential attention (every attention layer of the plan):
+    # heads pair by stripes, two softmaxes subtracted under a learned
+    # lambda, an RMSNorm over the pair (``Block._diff_combine``)
+    diff_attn: bool = False
+    # "layer": every block and final norm is LayerNorm with scale and
+    # bias in place of RMSNorm; attn_bias: biases on the attention
+    # projections
+    norm: str = "rms"
+    attn_bias: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -233,6 +261,25 @@ class TransformerConfig:
         if self.layer_mlp:
             return self.layer_mlp[layer]
         return "sparse" if self.moe_experts else "dense"
+
+    @property
+    def shares(self) -> bool:
+        """Some layer reads what another computed (a memory, another
+        layer's keys and values): the stack's loop carries it."""
+        return bool({"mamba1", "gmu", "cross"} & set(self.layer_attn))
+
+    @property
+    def tail_start(self) -> int:
+        """The first layer of the stack's TAIL: the last layers that
+        keep nothing a position ("gmu", "cross").  A multi-token call of
+        a decode model that samples at one row a lane runs them on that
+        row alone (``TransformerLM``'s ``last_at``); ``num_layers``
+        where the stack has no such tail."""
+        i = self.num_layers
+        while i and self.layer_attn and self.layer_attn[i - 1] in (
+                "gmu", "cross"):
+            i -= 1
+        return i
 
     @property
     def uniform(self) -> bool:
@@ -262,6 +309,14 @@ class TransformerConfig:
     @property
     def softmax_scale(self) -> float:
         return self.attn_scale or self.head_dim ** -0.5
+
+    @property
+    def sm_scale(self):
+        """``dot_product_attention``'s ``sm_scale``: None is its own
+        ``D ** -0.5`` of the rows it is given, which differential
+        attention widens to a pair (``Block._diff_queries``)."""
+        return self.softmax_scale if self.diff_attn else (
+            self.attn_scale or None)
 
     @property
     def kda_inner(self) -> int:
@@ -297,7 +352,8 @@ class TransformerConfig:
     def __post_init__(self):
         for name, plan, kinds in (
                 ("layer_attn", self.layer_attn,
-                 ("window", "global", "ssm", "kda", "latent")),
+                 ("window", "global", "ssm", "kda", "latent", "mamba1",
+                  "gmu", "cross")),
                 ("layer_mlp", self.layer_mlp, ("dense", "sparse"))):
             if plan and (len(plan) != self.num_layers
                          or set(plan) - set(kinds)):
@@ -324,6 +380,28 @@ class TransformerConfig:
             raise ValueError(
                 f"a latent attention layer needs mla_rank ({self.mla_rank}), "
                 f"mla_nope_dim, mla_v_dim and an even mla_rope_dim")
+        if "mamba1" in self.layer_attn and (
+                self.m1_inner < 1 or self.m1_state < 1 or self.m1_conv < 1
+                or self.m1_dt_rank < 1):
+            raise ValueError(
+                f"a Mamba-1 layer needs m1_inner ({self.m1_inner}), m1_state, "
+                f"m1_conv and m1_dt_rank ({self.m1_dt_rank})")
+        for i, kind in enumerate(self.layer_attn):
+            below = self.layer_attn[:i]
+            if kind == "gmu" and "mamba1" not in below:
+                raise ValueError(f"layer {i} is a gated memory unit with no "
+                                 f"Mamba-1 layer below it to read")
+            if kind == "cross" and "global" not in below:
+                raise ValueError(f"layer {i} is a cross layer with no global "
+                                 f"layer below it to read")
+        if self.diff_attn and (self.num_heads % 4 or self.kv_heads % 2
+                               or self.num_heads != 2 * self.kv_heads):
+            raise ValueError(
+                f"differential attention pairs heads by stripes: num_heads "
+                f"({self.num_heads}) twice kv_heads ({self.kv_heads}), both "
+                f"in whole pairs")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm {self.norm!r} is neither rms nor layer")
         if "sparse" in self.layer_mlp and not self.moe_experts:
             raise ValueError("a sparse layer needs moe_experts")
         if self.moe_held and not 0 < self.moe_held <= self.moe_experts:
@@ -345,6 +423,14 @@ def _layer_matmul_params(cfg: TransformerConfig, experts: int,
     elif kind == "kda":
         attn = (D * cfg.kda_proj_dim + 2 * cfg.kda_head_dim * cfg.kda_inner
                 + cfg.kda_inner * D)
+    elif kind == "mamba1":
+        attn = (D * 2 * cfg.m1_inner
+                + cfg.m1_inner * (cfg.m1_dt_rank + 2 * cfg.m1_state)
+                + cfg.m1_dt_rank * cfg.m1_inner + cfg.m1_inner * D)
+    elif kind == "gmu":
+        attn = 2 * D * cfg.m1_inner
+    elif kind == "cross":
+        attn = 2 * D * H * Dh
     elif kind == "latent":
         attn = (D * cfg.mla_q_rank
                 + (cfg.mla_q_rank or D) * H * (cfg.mla_nope_dim
@@ -364,7 +450,8 @@ def param_count(cfg: TransformerConfig) -> int:
     """Parameter count of the config (embedding table included; with
     ``moe_held``, of the experts this device holds)."""
     D, V = cfg.embed_dim, cfg.vocab_size
-    pre = (4 if cfg.post_norms else 2) * D      # a layer's block norms
+    # a layer's block norms (a LayerNorm has a bias too)
+    pre = (4 if cfg.post_norms else 2) * D * (2 if cfg.norm == "layer" else 1)
     norms = pre
     if cfg.qk_norm:
         norms += (2 * cfg.head_dim if cfg.qk_norm_per_head
@@ -384,8 +471,19 @@ def param_count(cfg: TransformerConfig) -> int:
     own = {"ssm": pre + ssm_own,
            "kda": (pre + 3 * cfg.kda_inner * cfg.kda_conv + cfg.kda_inner
                    + cfg.kda_heads + cfg.kda_head_dim),
-           "latent": pre + cfg.mla_rank + cfg.mla_q_rank}
-    return V * D + head + D + sum(
+           "latent": pre + cfg.mla_rank + cfg.mla_q_rank,
+           # a Mamba-1 layer: the convolution and its bias, dt's bias,
+           # A_log and D; a gated memory unit: nothing of its own
+           "mamba1": pre + cfg.m1_inner * (cfg.m1_conv + 3 + cfg.m1_state),
+           "gmu": pre}
+    # differential attention: four lambda vectors and the pair norm's
+    # scale a layer; biases on q (k, v) and the output
+    H, Hk, Dh = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    extra = 6 * Dh if cfg.diff_attn else 0
+    own["cross"] = pre + extra + (H * Dh + D if cfg.attn_bias else 0)
+    norms += extra + ((H + 2 * Hk) * Dh + D if cfg.attn_bias else 0)
+    final = D * (2 if cfg.norm == "layer" else 1)
+    return V * D + head + final + sum(
         _layer_matmul_params(cfg, held, i)
         + own.get(cfg.attn_kind(i), norms)
         + (bias if cfg.mlp_kind(i) == "sparse" else 0)
@@ -530,6 +628,33 @@ class RMSNorm(nn.Module):
                            jnp.float32)
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
         return (x * jax.lax.rsqrt(var + self.eps)).astype(self.dtype) * scale
+
+
+class LayerNorm(nn.Module):
+    """Mean and variance over the last axis in float32, a scale and a
+    bias; the output as ``RMSNorm`` gives it (the compute dtype, times a
+    float32 scale)."""
+
+    dtype: Any = jnp.bfloat16
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],),
+                          jnp.float32)
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, -1, keepdims=True)
+        var = jnp.mean(jnp.square(x), -1, keepdims=True)
+        return (x * jax.lax.rsqrt(var + self.eps)).astype(self.dtype
+                                                          ) * scale + bias
+
+
+def _norm(cfg: "TransformerConfig", name: str):
+    """A block's (or the final) norm: ``cfg.norm`` says which."""
+    kind = LayerNorm if cfg.norm == "layer" else RMSNorm
+    return kind(cfg.dtype, cfg.norm_eps, name=name)
 
 
 def _residual(cfg: "TransformerConfig", x, branch):
@@ -695,6 +820,131 @@ class Mamba2Mixer(nn.Module):
             return nn.Dense(cfg.embed_dim, use_bias=cfg.ssm_proj_bias,
                             dtype=cfg.dtype, param_dtype=f32,
                             name="out_proj")(u)
+
+
+class Mamba1Mixer(nn.Module):
+    """A Mamba-1 token mixer (``ops/mamba1.py`` has the recurrence): with
+    ``u`` the layer's normed input,
+
+    ``[x | z] = u W_in``; ``x`` through a causal depthwise convolution
+    over ``m1_conv`` positions with a bias and a SiLU; ``[dt_r | B | C]
+    = x W_x``; ``dt = softplus(dt_r W_dt + b_dt)``; ``A = -exp(A_log)``
+    ``[inner, N]``; the recurrence; ``y = y_ssm + D * x``;
+    ``W_out (y * silu(z))``.  Returns ``(out, y)``: ``y`` is the MEMORY
+    a gated memory unit further up reads.
+
+    In a decode model its ``cache`` is ``Mamba2Mixer``'s contract to the
+    letter: ``conv_state [B, m1_conv - 1, inner]`` (the compute dtype),
+    ``ssm_state [B, N, inner]`` (``ssm_state_dtype``; the wide axis on
+    the lanes) and ``cache_index``; one-token calls update in place
+    (``ops/mamba1.mamba1_step`` on the chip, free slots keep theirs),
+    multi-token calls run the scan FROM the cached state to each lane's
+    last REAL token, ``snap_at`` sows the state at one more position
+    into the ``snap`` collection under the cache's names."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, token_mask=None, snap_at=None):
+        cfg = self.cfg
+        f32 = jnp.float32
+        Di, N, K, R = cfg.m1_inner, cfg.m1_state, cfg.m1_conv, cfg.m1_dt_rank
+        B, L = u.shape[:2]
+
+        def dense(width, name, **kw):
+            return nn.Dense(width, dtype=cfg.dtype, param_dtype=f32,
+                            name=name, **{"use_bias": False, **kw})
+
+        with jax.named_scope("mamba1/proj_in"):
+            x, z = jnp.split(dense(2 * Di, "in_proj")(u), 2, axis=-1)
+        conv_w = self.param("conv_w", nn.initializers.normal(K ** -0.5),
+                            (K, Di), f32)
+        conv_b = self.param("conv_b", nn.initializers.zeros, (Di,), f32)
+        A = -jnp.exp(self.param("A_log", _a_log_init, (Di, N), f32)).T
+        D_skip = self.param("D", nn.initializers.ones, (Di,), f32)
+
+        cached = cfg.decode and self.has_variable("cache", "ssm_state")
+        kept = cfg.ssm_state_dtype
+        if cfg.decode:
+            conv_v = self.variable("cache", "conv_state", jnp.zeros,
+                                   (B, K - 1, Di), cfg.dtype)
+            ssm_v = self.variable("cache", "ssm_state", jnp.zeros,
+                                  (B, N, Di), kept)
+            ci = self.variable("cache", "cache_index",
+                               lambda: jnp.zeros((B,), jnp.int32))
+        conv0 = (conv_v.value if cached
+                 else jnp.zeros((B, K - 1, Di), cfg.dtype))
+        state0 = (ssm_v.value.astype(f32) if cached
+                  else jnp.zeros((B, N, Di), f32))
+        n_real = (token_mask.sum(-1).astype(jnp.int32)
+                  if cached and token_mask is not None else None)
+
+        with jax.named_scope("mamba1/conv"):
+            acc, xin, window = _short_conv(conv0, x, conv_w)
+            x = nn.silu(acc + conv_b).astype(cfg.dtype)
+        with jax.named_scope("mamba1/proj_x"):
+            dt, Bm, Cm = jnp.split(dense(R + 2 * N, "x_proj")(x),
+                                   [R, R + N], axis=-1)
+            dt = jax.nn.softplus(dense(
+                Di, "dt_proj", use_bias=True, bias_init=_dt_bias_init)(dt)
+                .astype(f32))
+
+        if cached and L == 1:
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            kernel = kept == f32 and mamba1.applies(L, cfg.mesh)
+            step = (mamba1.mamba1_step if kernel
+                    else mamba1.mamba1_step_reference)
+            with jax.named_scope("mamba1/step"):
+                ys, new = step(state0, x[:, 0], dt[:, 0], A, Bm[:, 0],
+                               Cm[:, 0], live)
+            # slot states this update read and wrote: the engine's
+            # ``ssm_state_steps_run`` counts any state layer's
+            self.sow("intermediates", "ssm_slots_run",
+                     mamba1.slots_fetched(live) if kernel
+                     else jnp.asarray(B, f32))
+            ys, ssm_v.value = ys[:, None], new.astype(kept)
+            conv_v.value = jnp.where(live[:, None, None], xin[:, 1:], conv0)
+        else:
+            ys, final, snap = mamba1.selective_scan(
+                x, dt, A, Bm, Cm, state0, lengths=n_real,
+                snap_at=snap_at if cached else None)
+            if cached:
+                ssm_v.value = final.astype(kept)
+                conv_v.value = window(
+                    jnp.full((B,), L, jnp.int32) if n_real is None
+                    else n_real)
+                if snap is not None:
+                    at = jnp.clip(snap_at.astype(jnp.int32), 0,
+                                  L if n_real is None else n_real)
+                    # named as the cache names them
+                    self.sow("snap", "ssm_state", snap.astype(kept))
+                    self.sow("snap", "conv_state", window(at))
+        if cached:
+            ci.value = ci.value + L
+        with jax.named_scope("mamba1/gate"):
+            y = ys + D_skip * x.astype(f32)
+            gated = (y * nn.silu(z.astype(f32))).astype(cfg.dtype)
+        with jax.named_scope("mamba1/proj_out"):
+            return dense(cfg.embed_dim, "out_proj")(gated), y.astype(cfg.dtype)
+
+
+class GatedMemoryUnit(nn.Module):
+    """A mixer with no state: ``W_out (m * silu(W_in u))`` with ``m`` the
+    memory a Mamba-1 layer below emitted FOR THE SAME TOKEN."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, memory):
+        cfg = self.cfg
+        with jax.named_scope("gmu"):
+            gate = nn.Dense(cfg.m1_inner, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, name="in_proj")(u)
+            gated = (memory.astype(jnp.float32)
+                     * nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype)
+            return nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, name="out_proj")(gated)
 
 
 class KDAMixer(nn.Module):
@@ -971,7 +1221,6 @@ class Block(nn.Module):
         W = cfg.attn_window
         B, L, H, Dh = q.shape
         Hk = k.shape[2]
-        G = H // Hk
         R = cfg.ring_len
         is_initialized = self.has_variable("cache", "cached_key")
         ck = self.variable("cache", "cached_key", jnp.zeros,
@@ -983,7 +1232,7 @@ class Block(nn.Module):
         if not is_initialized:      # init trace: shapes only
             return dot_product_attention(q, k, v, causal=True, impl="dense",
                                          window=W,
-                                         sm_scale=cfg.attn_scale or None)
+                                         sm_scale=cfg.sm_scale)
         idx = ci.value                                    # [B]
         if decode_attention.applies(L, cfg.mesh, R):
             live = (jnp.ones((B,), bool) if token_mask is None
@@ -1014,12 +1263,8 @@ class Block(nn.Module):
             [ck.value, k.transpose(0, 2, 3, 1).astype(cfg.dtype)], axis=-1)
         v_all = jnp.concatenate(
             [cv.value, v.transpose(0, 2, 1, 3).astype(cfg.dtype)], axis=2)
-        qg = q.reshape(B, L, Hk, G, Dh)
-        logits = jnp.einsum("blhgd,bhdk->bhglk", qg, k_all
-                            ).astype(jnp.float32) * cfg.softmax_scale
-        logits = jnp.where(seen[:, None, None], logits, -jnp.inf)
-        weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhglk,bhkd->blhgd", weights, v_all)
+        out = self._masked_attention(q, k_all, v_all, seen,
+                                     cfg.softmax_scale)
         # the ring takes the call's last R real tokens: slot r the
         # latest real position p in [idx, idx + n_real) with p % R == r
         n_real = (jnp.full((B,), L, jnp.int32) if token_mask is None
@@ -1035,7 +1280,7 @@ class Block(nn.Module):
         cv.value = jnp.where(take[:, None, :, None],
                              v_new.transpose(0, 2, 1, 3), cv.value)
         ci.value = idx + L
-        return out.reshape(B, L, H, Dh)
+        return out
 
     def _decode_attention(self, q, k, v, token_mask=None):
         """Incremental attention against a persistent KV cache.  First
@@ -1087,7 +1332,6 @@ class Block(nn.Module):
         cfg = self.cfg
         B, L, H, Dh = q.shape
         Hk = k.shape[2]
-        G = H // Hk
         is_initialized = self.has_variable("cache", "cached_key")
         ck = self.variable("cache", "cached_key", jnp.zeros,
                            (B, Hk, Dh, cfg.max_len), cfg.dtype)
@@ -1097,7 +1341,7 @@ class Block(nn.Module):
                            lambda: jnp.zeros((B,), jnp.int32))
         if not is_initialized:      # init trace: shapes only
             return dot_product_attention(q, k, v, causal=True, impl="dense",
-                                         sm_scale=cfg.attn_scale or None)
+                                         sm_scale=cfg.sm_scale)
         idx = ci.value                                    # [B]
         if decode_attention.applies(L, cfg.mesh, cfg.max_len):
             live = (jnp.ones((B,), bool) if token_mask is None
@@ -1142,32 +1386,52 @@ class Block(nn.Module):
         q_pos = idx[:, None] + jnp.arange(L)              # [B, L]
         mask = (jnp.arange(cfg.max_len)[None, None, :]
                 <= q_pos[:, :, None])                     # [B, L, max]
-        scale = cfg.softmax_scale
-        # precision recipe matches dense_attention exactly (input-dtype
-        # matmuls, f32 softmax) so cached decode stays bit-identical to
-        # the full-prefix forward in bf16 too
-        qg = q.reshape(B, L, Hk, G, Dh)
-        logits = jnp.einsum("blhgd,bhdk->bhglk", qg, ck.value
+        return self._masked_attention(q, ck.value, cv.value, mask,
+                                      cfg.softmax_scale)
+
+    @staticmethod
+    def _masked_attention(q, keys, values, mask, scale):
+        """``q [B, L, H, D]`` over keys ``[B, Hk, D, K]`` and values ``[B,
+        Hk, K, D]`` in the slabs' layouts under ``mask [B, L, K]``,
+        grouped (query head h reads head ``h // (H // Hk)``).  The
+        precision recipe matches dense_attention exactly (input-dtype
+        matmuls, f32 softmax) so cached decode stays bit-identical to
+        the full-prefix forward in bf16 too."""
+        B, L, H, D = q.shape
+        Hk = keys.shape[1]
+        qg = q.reshape(B, L, Hk, H // Hk, D)
+        logits = jnp.einsum("blhgd,bhdk->bhglk", qg, keys
                             ).astype(jnp.float32) * scale
         logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
         weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhglk,bhkd->blhgd", weights, cv.value)
-        return out.reshape(B, L, H, Dh)
+        return jnp.einsum("bhglk,bhkd->blhgd", weights, values
+                          ).reshape(B, L, H, D)
 
-    def _attention(self, y, positions, token_mask, kind):
+    def _attention(self, y, positions, token_mask, kind, shared=None):
         """The attention mixer on the normed input: projections, norms,
-        rotation, the layer's kind of attention, the output matrix."""
+        rotation, the layer's kind of attention, the output matrix.
+        Returns ``(out, shared)``: a "global" layer of a plan with cross
+        layers LENDS its keys and values (``shared["kv"]``: in a decode
+        model its slabs as this call left them, else the call's own
+        rows), a "cross" layer projects a query only and reads them."""
         cfg = self.cfg
         H, Dh = cfg.num_heads, cfg.head_dim
         Hk = cfg.kv_heads
         assert H % Hk == 0, f"num_heads {H} not divisible by kv heads {Hk}"
         window = cfg.attn_window if kind == "window" else 0
-        qkv = nn.DenseGeneral(((H + 2 * Hk) * Dh,), use_bias=False,
-                              dtype=cfg.dtype, param_dtype=jnp.float32,
-                              name="attn_qkv")(y)
-        qkv = _pin(cfg, qkv, "batch", "seq", "heads")
-        q, k, v = jnp.split(qkv, [H * Dh, (H + Hk) * Dh], axis=-1)
         B, L = y.shape[:2]
+        k = v = None
+        if kind == "cross":
+            q = nn.DenseGeneral((H * Dh,), use_bias=cfg.attn_bias,
+                                dtype=cfg.dtype, param_dtype=jnp.float32,
+                                name="attn_q")(y)
+        else:
+            qkv = nn.DenseGeneral(((H + 2 * Hk) * Dh,),
+                                  use_bias=cfg.attn_bias,
+                                  dtype=cfg.dtype, param_dtype=jnp.float32,
+                                  name="attn_qkv")(y)
+            qkv = _pin(cfg, qkv, "batch", "seq", "heads")
+            q, k, v = jnp.split(qkv, [H * Dh, (H + Hk) * Dh], axis=-1)
         if cfg.qk_norm and cfg.qk_norm_per_head:
             # each head over its own head_dim, one scale for all heads
             q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(
@@ -1181,17 +1445,31 @@ class Block(nn.Module):
                 cfg.dtype)
             k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k).astype(
                 cfg.dtype)
-        q, k = q.reshape(B, L, H, Dh), k.reshape(B, L, Hk, Dh)
-        if window or cfg.rope_global:
+        q = q.reshape(B, L, H, Dh)
+        rotate = cfg.rope_window if window else cfg.rope_global
+        if rotate:
             q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        v = v.reshape(B, L, Hk, Dh)
-        # the scopes name a mixed stack's two kinds in a trace; a stack
+        if k is not None:
+            k, v = k.reshape(B, L, Hk, Dh), v.reshape(B, L, Hk, Dh)
+            if rotate:
+                k = rope(k, positions, cfg.rope_theta)
+        if cfg.diff_attn:
+            q = self._diff_queries(q)
+            if k is not None:
+                k = k.reshape(B, L, Hk // 2, 2 * Dh)
+                v = v.reshape(B, L, Hk // 2, 2 * Dh)
+        lends = (shared is not None and kind == "global"
+                 and "cross" in cfg.layer_attn)
+        slabs = cfg.decode and self.has_variable("cache", "cached_key")
+        # the scopes name a mixed stack's kinds in a trace; a stack
         # without a window keeps the op names it always had
         with (jax.named_scope(f"attn/{kind}")
               if cfg.attn_window or "ssm" in cfg.layer_attn
               else contextlib.nullcontext()):
-            if cfg.decode and window:
+            if kind == "cross":
+                attn = self._cross_attention(q, positions, token_mask,
+                                             shared["kv"])
+            elif cfg.decode and window:
                 attn = self._ring_attention(q, k, v, token_mask)
             elif cfg.decode:
                 attn = self._decode_attention(q, k, v, token_mask)
@@ -1201,31 +1479,132 @@ class Block(nn.Module):
                 attn = dot_product_attention(q, k, v, causal=True,
                                              impl=cfg.attention_impl,
                                              mesh=cfg.mesh, window=window,
-                                             sm_scale=cfg.attn_scale or None)
+                                             sm_scale=cfg.sm_scale)
+        if lends:
+            shared = {**shared, "kv": (
+                ("slab", self.get_variable("cache", "cached_key"),
+                 self.get_variable("cache", "cached_value")) if slabs
+                else ("call", k, v))}
+        if cfg.diff_attn:
+            attn = self._diff_combine(attn)
         attn = _pin(cfg, attn.reshape(B, L, H * Dh), "batch", "seq", "heads")
-        return nn.DenseGeneral(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                               param_dtype=jnp.float32, name="attn_out")(attn)
+        return nn.DenseGeneral(cfg.embed_dim, use_bias=cfg.attn_bias,
+                               dtype=cfg.dtype, param_dtype=jnp.float32,
+                               name="attn_out")(attn), shared
+
+    def _diff_queries(self, q):
+        """Differential attention on the plain paths.  Query pair p
+        (heads 2p, 2p + 1) reads key / value pair r = p // 2 (heads 2r,
+        2r + 1), head s of a query pair against key s of the pair, and
+        BOTH value heads.  With a pair of keys side by side as one key
+        of twice the width (and so the values), and each query beside
+        zeros in the half its key does not lie in, that is grouped
+        attention of H queries over Hk / 2 keys, four to a key: ``[B, L,
+        H, Dh] -> [B, L, H, 2 Dh]``.  The cache holds the same bytes,
+        and rows of 128 where the published head is 64."""
+        B, L, H, Dh = q.shape
+        half = jax.nn.one_hot(jnp.arange(H) % 2, 2, dtype=q.dtype)   # [H, 2]
+        return (q[:, :, :, None, :] * half[None, None, :, :, None]
+                ).reshape(B, L, H, 2 * Dh)
+
+    def _diff_combine(self, attn):
+        """``[B, L, H, 2 Dh]`` (each head's softmax over BOTH values of
+        its pair) to the differential output ``[B, L, H, Dh]``: pair p
+        gives ``(head 2p) - lambda * (head 2p + 1)``, through an RMSNorm
+        over the pair's 2 Dh with one scale a layer, times ``1 -
+        lambda_init``; heads 2p and 2p + 1 take its halves.  ``lambda =
+        exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, ``lambda_init
+        = 0.8 - 0.6 exp(-0.3 layer)``."""
+        cfg = self.cfg
+        B, L, H, D2 = attn.shape
+        with jax.named_scope("attn/diff_combine"):
+            lam, lam0 = self._lambda(D2 // 2)
+            pair = attn.astype(jnp.float32).reshape(B, L, H // 2, 2, D2)
+            out = pair[:, :, :, 0] - lam * pair[:, :, :, 1]
+            out = RMSNorm(cfg.dtype, 1e-5, name="subln")(out) * (1.0 - lam0)
+            return out.astype(cfg.dtype).reshape(B, L, H, D2 // 2)
+
+    def _lambda(self, width: int):
+        """``(lambda, lambda_init)`` of this layer's differential
+        attention: four learned vectors of ``width``."""
+        lam0 = 0.8 - 0.6 * math.exp(-0.3 * self.layer)
+        lq1, lk1, lq2, lk2 = (
+            self.param(name, nn.initializers.normal(0.1), (width,),
+                       jnp.float32)
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+        return (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+                + lam0), lam0
+
+    def _cross_attention(self, q, positions, token_mask, lent):
+        """Attention of this layer's queries over keys and values ANOTHER
+        layer wrote (``lent``: ``("slab", keys [B, Hk, D, max_len],
+        values [B, Hk, max_len, D])`` of a decode model, the lender's
+        slabs with this call's rows already in them, or ``("call", k,
+        v)``: the call's own rows).  A query at position p sees rows 0
+        .. p.  Nothing is written.  One row a lane on the chip is the
+        slabs' own kernel (``ops/decode_attention.decode_attend``), which
+        reads live positions of live slots; everything else the einsums
+        of :meth:`_decode_attention`."""
+        cfg = self.cfg
+        how, k, v = lent
+        B, L, H, D = q.shape
+        with jax.named_scope("attn/cross"):
+            if how == "call":
+                return dot_product_attention(q, k, v, causal=True,
+                                             impl="dense",
+                                             sm_scale=cfg.softmax_scale)
+            Hk, T = k.shape[1], k.shape[-1]
+            kernel = decode_attention.applies(L, cfg.mesh, T)
+            if L == 1:
+                live = (jnp.ones((B,), bool) if token_mask is None
+                        else token_mask[:, 0])
+                lengths = jnp.where(
+                    live, jnp.minimum(positions[:, 0] + 1, T), 0)
+                # positions this read fetched of the lender's rows, for
+                # the engine's ``borrowed_kv_tokens_read``
+                self.sow("intermediates", "borrowed_rows_read",
+                         decode_attention.tokens_fetched(
+                             lengths, Hk, D, T, k.dtype, kernel))
+            if kernel:
+                return decode_attention.decode_attend(
+                    q[:, 0], k, v, lengths, scale=cfg.softmax_scale)[:, None]
+            mask = (jnp.arange(T)[None, None, :]
+                    <= positions[:, :, None])                 # [B, L, max]
+            return self._masked_attention(q, k, v, mask, cfg.softmax_scale)
 
     @nn.compact
-    def __call__(self, x, positions, token_mask=None, snap_at=None):
+    def __call__(self, x, positions, token_mask=None, snap_at=None,
+                 shared=None):
+        """``(x, aux)``; with ``shared`` (what crosses layers in a plan
+        that has such layers: ``memory``, a Mamba-1 layer's scan output,
+        and ``kv``, a global layer's keys and values) ``(x, aux,
+        shared)``."""
         cfg = self.cfg
         kind = cfg.attn_kind(self.layer)
         x = _pin(cfg, x, "batch", "seq", None)
-        y = RMSNorm(cfg.dtype, cfg.norm_eps, name="attn_norm")(x)
+        y = _norm(cfg, "attn_norm")(x)
         if kind == "ssm":
             mixed = Mamba2Mixer(cfg, name="ssm")(y, token_mask, snap_at)
         elif kind == "kda":
             mixed = KDAMixer(cfg, name="kda")(y, token_mask, snap_at)
         elif kind == "latent":
             mixed = LatentAttention(cfg, name="mla")(y, positions, token_mask)
+        elif kind == "mamba1":
+            mixed, memory = Mamba1Mixer(cfg, name="ssm")(y, token_mask,
+                                                         snap_at)
+            if shared is not None:
+                shared = {**shared, "memory": memory}
+        elif kind == "gmu":
+            mixed = GatedMemoryUnit(cfg, name="gmu")(y, shared["memory"])
         else:
-            mixed = self._attention(y, positions, token_mask, kind)
+            mixed, shared = self._attention(y, positions, token_mask, kind,
+                                            shared)
         if cfg.post_norms:
             with jax.named_scope("attn/post_norm"):
                 mixed = RMSNorm(cfg.dtype, cfg.norm_eps,
                                 name="attn_post_norm")(mixed).astype(cfg.dtype)
         x = _pin(cfg, _residual(cfg, x, mixed), "batch", "seq", None)
-        y = RMSNorm(cfg.dtype, cfg.norm_eps, name="mlp_norm")(x)
+        y = _norm(cfg, "mlp_norm")(x)
         if cfg.mlp_kind(self.layer) == "sparse":
             from edl_tpu.ops.moe import MoEMLP
             y, aux = MoEMLP(num_experts=cfg.moe_experts,
@@ -1240,8 +1619,9 @@ class Block(nn.Module):
                             shared_dim=cfg.moe_shared_dim,
                             held=cfg.moe_held, mesh=cfg.mesh,
                             name="moe")(y, token_mask)
-            return _pin(cfg, _residual(cfg, x, self._post_mlp(y)),
-                        "batch", "seq", None), aux
+            out = (_pin(cfg, _residual(cfg, x, self._post_mlp(y)),
+                        "batch", "seq", None), aux)
+            return out if shared is None else (*out, shared)
         gate = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
                         param_dtype=jnp.float32, name="mlp_gate")(y)
         up = nn.Dense(cfg.mlp_dim, use_bias=False, dtype=cfg.dtype,
@@ -1251,7 +1631,8 @@ class Block(nn.Module):
         x = _residual(cfg, x, self._post_mlp(nn.Dense(
             cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
             param_dtype=jnp.float32, name="mlp_out")(y)))
-        return _pin(cfg, x, "batch", "seq", None), None
+        out = (_pin(cfg, x, "batch", "seq", None), None)
+        return out if shared is None else (*out, shared)
 
     def _post_mlp(self, y):
         """The MLP branch's output through its sandwich norm, where the
@@ -1284,7 +1665,8 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, ids, positions=None, train: bool = True,
                  return_hidden: bool = False, with_aux: bool = False,
-                 token_mask=None, snap_at=None):
+                 token_mask=None, snap_at=None, last_at=None,
+                 tail: bool = True):
         """Logits [B, L, V] f32 — or, with ``return_hidden``, the
         final-norm hidden states [B, L, D] for the fused-CE loss path
         (:func:`lm_loss_fused`), which never materialises the logits.
@@ -1296,7 +1678,18 @@ class TransformerLM(nn.Module):
         decode model real tokens lead, and a state-space layer's state
         stops at the last of them.  ``snap_at`` ([B] int, decode models)
         is handed to the layers that keep a recurrence (``Mamba2Mixer``,
-        ``KDAMixer``)."""
+        ``KDAMixer``, ``Mamba1Mixer``).
+
+        The LAST-POSITION CUT (decode models whose stack ends in layers
+        that keep nothing a position, ``cfg.tail_start``): a multi-token
+        call that samples at one row a lane says which (``last_at`` [B]
+        int, an index into the call), and the tail runs on that row
+        alone: ``x`` and the memory gathered there after the last layer
+        below the tail, the cross layers' one query over the rows the
+        call has just written, logits ``[B, 1, V]``.  Exact: nothing
+        above the tail reads another row's tail output.  ``tail=False``
+        (a prefill chunk that samples nothing) leaves the tail out and
+        returns the hidden rows below it."""
         cfg = self.cfg
         del train
         if positions is None:
@@ -1316,18 +1709,35 @@ class TransformerLM(nn.Module):
             # generate() splits the trained stacked params to match
             # (models/generate.py _split_layer_params).
             aux = None
+            shared = {} if cfg.shares else None
             for i in range(cfg.num_layers):
-                x, _ = Block(cfg, i, name=f"layer_{i}")(x, positions,
-                                                        token_mask, snap_at)
+                if i == cfg.tail_start and i:
+                    if not tail:
+                        return x
+                    if last_at is not None and x.shape[1] > 1:
+                        at = last_at.astype(jnp.int32)[:, None]
+                        x = jnp.take_along_axis(x, at[..., None], axis=1)
+                        shared = {**shared, "memory": jnp.take_along_axis(
+                            shared["memory"], at[..., None], axis=1)}
+                        positions = jnp.take_along_axis(positions, at, axis=1)
+                        token_mask = (None if token_mask is None else
+                                      jnp.take_along_axis(token_mask, at,
+                                                          axis=1))
+                x, _, *rest = Block(cfg, i, name=f"layer_{i}")(
+                    x, positions, token_mask, snap_at, shared)
+                shared = rest[0] if rest else None
         elif not cfg.uniform:
             # layers that differ cannot be stacked (a dense layer has
             # no expert matrices): unrolled, ``layer_<i>`` parameters,
-            # the layout the decode model has
-            block = _remat(Block) if cfg.remat else Block
-            auxes = []
+            # the layout the decode model has.  What crosses layers
+            # (``cfg.shares``) is not threaded through remat
+            block = (_remat(Block) if cfg.remat and not cfg.shares
+                     else Block)
+            auxes, shared = [], ({} if cfg.shares else None)
             for i in range(cfg.num_layers):
-                x, a = block(cfg, i, name=f"layer_{i}")(x, positions,
-                                                        token_mask)
+                x, a, *rest = block(cfg, i, name=f"layer_{i}")(
+                    x, positions, token_mask, None, shared)
+                shared = rest[0] if rest else None
                 if a is not None:
                     auxes.append(a)
             aux = jnp.stack(auxes) if auxes else None
@@ -1339,8 +1749,7 @@ class TransformerLM(nn.Module):
                             in_axes=nn.broadcast, metadata_params={},
                             unroll=1 if cfg.scan_layers else cfg.num_layers)
             x, aux = Stack(cfg, name="layers")(x, positions, token_mask)
-        x = _pin(cfg, RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x),
-                 "batch", "seq", None)
+        x = _pin(cfg, _norm(cfg, "final_norm")(x), "batch", "seq", None)
         aux_total = (jnp.mean(aux) if aux is not None
                      else jnp.zeros((), jnp.float32))
         if return_hidden:

@@ -233,6 +233,19 @@ def attend_block(Hk: int, D: int, max_len: int, dtype) -> int:
     return tk
 
 
+def tokens_fetched(lengths, Hk: int, D: int, max_len: int, dtype,
+                   kernel: bool):
+    """Positions one attend call fetches of the slots' slabs, float32:
+    on the kernel's path whole blocks up to each live slot's length
+    (COUNTED from the fetch plan, as :func:`blocks_fetched` counts), on
+    the einsum path every slot's slab."""
+    if not kernel:
+        return jnp.asarray(lengths.shape[0] * max_len, jnp.float32)
+    tk = attend_block(Hk, D, max_len, dtype)
+    plan = _fetch_plan(lengths.astype(jnp.int32), tk)
+    return blocks_fetched(*plan, max_len // tk) * tk
+
+
 def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
                   block: int | None = None, interpret=None,
                   scale: float | None = None):

@@ -6,7 +6,7 @@ to know about them is one :class:`CacheClass` a layer, made here by
 :func:`cache_classes` and read by the engine (refusals, snapshot policy,
 the construction-time fit), the pool (``kv_cache.PagedKVCache``: its
 buffers, the gather at a hit, the scatter at a commit, the wire format
-of a migrated session) and the counters (``model_counters``).  Four
+of a migrated session) and the counters (``model_counters``).  Six
 classes:
 
 - :class:`HeadRows` - per-head key and value rows over the whole
@@ -23,12 +23,36 @@ classes:
   still holds a little history and can be snapshotted after the fact; a
   recurrence can be saved only at a position the program is AT, so its
   snapshot comes out of the prefill (``PagedKVCache.store_state``),
-  never out of a slot (:class:`SsmState` and :class:`KdaState` differ
-  in what their prefill's scan costs, nothing else);
+  never out of a slot (:class:`SsmState`, :class:`KdaState` and
+  :class:`Mamba1State` (``Mamba1Mixer``: ``conv_state``, ``ssm_state``)
+  differ in what their prefill's scan costs, nothing else);
 - :class:`LatentRows` - one head-less row a token
   (``LatentAttention``: the normed latent and the key dims all heads
   share), paged by blocks as ONE buffer a layer: storing it as keys and
   as values would give back half of what the architecture saves.
+
+- :class:`NoCache` - a layer that OWNS NOTHING (``GatedMemoryUnit``: it
+  reads what a layer below computed for the same token): no leaf in a
+  slot's cache, no buffer in the pool, nothing on the wire;
+- :class:`BorrowedRows` - a layer that owns nothing and READS ANOTHER
+  layer's rows (a "cross" layer over its ``lender``'s slab): the pool
+  pages the lender's rows once, and a hit that gathered them has
+  gathered what every borrower reads.
+
+The table, a mixer kind a row:
+
+==========  ====================  ==================  =================
+kind        class                 a slot keeps        the pool pages by
+==========  ====================  ==================  =================
+global      :class:`HeadRows`     K / V rows          blocks
+window      :class:`WindowRing`   a ring of K / V     ring snapshots
+ssm         :class:`SsmState`     conv + state        state snapshots
+kda         :class:`KdaState`     conv + state        state snapshots
+latent      :class:`LatentRows`   one row a token     blocks
+mamba1      :class:`Mamba1State`  conv + state        state snapshots
+gmu         :class:`NoCache`      nothing             nothing
+cross       :class:`BorrowedRows` nothing (lender's)  nothing
+==========  ====================  ==================  =================
 
 A new kind is one more class here (or one of these) and a row in
 :func:`cache_classes`; the pool's methods and the engine name none.
@@ -271,6 +295,44 @@ class KdaState(FixedState):
                     + 3 * cfg.kda_inner * q * q)
 
 
+class Mamba1State(FixedState):
+    def scan_bytes(self, width):
+        # the projections' and the scan's float32 rows of a lane's
+        # tokens: x, z, dt, dt * x, y over the inner width
+        return 4 * 6 * width * self._cfg.m1_inner
+
+
+class NoCache(CacheClass):
+    """A layer with no leaf in a slot's cache: nothing to page, to
+    shard, to rewind or to export."""
+
+    kind, noun = "empty", "stateless-mixer"
+
+    def buffers(self, node):
+        return {}
+
+    def meta(self, node):
+        return None
+
+
+class BorrowedRows(NoCache):
+    """A layer that reads ``lender``'s rows (a layer name) and keeps
+    none: its one-token step is one more read of that slab."""
+
+    kind, noun = "borrowed", "borrowed-rows"
+    no_rewind = ("the verify step writes each slot's candidates at its "
+                 "own index, and a borrowing layer's read follows one "
+                 "position a lane")
+    no_shard = "a borrowing layer's read of a sharded slab is not wired"
+
+    def __init__(self, lender: str):
+        self.lender = lender
+
+    def scores_bytes(self, lanes, width, heads, cache_len):
+        # it runs at a lane's last row alone (the last-position cut)
+        return 4 * heads * cache_len
+
+
 class LatentRows(CacheClass):
     kind, noun, meta_key = "latent", "latent attention", "latent_layers"
     no_rewind = ("the verify step writes each slot's candidates at its "
@@ -329,18 +391,27 @@ _KINDS = {
     "ssm": SsmState,
     "kda": KdaState,
     "latent": LatentRows,
+    "mamba1": Mamba1State,
+    "gmu": lambda cfg: NoCache(),
+    "cross": BorrowedRows,          # made of its lender's name
 }
 
 
 def cache_classes(cfg) -> dict:
     """``{layer name: CacheClass}`` of the decode configuration ``cfg``,
-    in layer order; the layers of one kind share one object."""
-    made, out = {}, {}
+    in layer order; the layers of one kind share one object (a "cross"
+    layer: those that borrow from one lender, the latest "global" layer
+    below it)."""
+    made, out, lender = {}, {}, None
     for i in range(cfg.num_layers):
-        kind = cfg.attn_kind(i)
-        if kind not in made:
-            made[kind] = _KINDS[kind](cfg)
-        out[f"layer_{i}"] = made[kind]
+        kind = key = cfg.attn_kind(i)
+        if kind == "global":
+            lender = f"layer_{i}"
+        if kind == "cross":
+            key = (kind, lender)
+        if key not in made:
+            made[key] = _KINDS[kind](lender if kind == "cross" else cfg)
+        out[f"layer_{i}"] = made[key]
     return out
 
 

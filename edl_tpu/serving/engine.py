@@ -404,6 +404,11 @@ class ContinuousBatcher:
                      // lanes) * lanes
         self._dcfg = dataclasses.replace(dcfg, window_ring=ring)
         self._model = TransformerLM(self._dcfg)
+        # the last-position cut: a stack whose last layers keep nothing
+        # a position runs them at the one row a lane a multi-token
+        # program samples at, and not at all in a chunk that samples
+        # nothing (``TransformerLM``: ``last_at``, ``tail``)
+        self._cut = self._dcfg.tail_start < self._dcfg.num_layers
         self._pending: "deque[_Request]" = deque()
         self._mesh = mesh
         # a replicated output's sharding; None off a mesh, where every
@@ -1107,8 +1112,11 @@ class ContinuousBatcher:
             return max(c.scores_bytes(k, p_max, heads, cache_len)
                        for c in self._kinds)
 
+        # the head's float32 logits: every row of a lane, or under the
+        # last-position cut the one it samples at
+        rows = 1 if self._cut else p_max
         for i, k_max in enumerate(self.PREFILL_KS):
-            prefill = k_max * (lane + scan + 4 * p_max * self.cfg.vocab_size
+            prefill = k_max * (lane + scan + 4 * rows * self.cfg.vocab_size
                                + scores(k_max))
             need = in_use + slots * lane + pool + prefill
             if need <= limit:
@@ -1177,7 +1185,8 @@ class ContinuousBatcher:
                     {"params": params,
                      "cache": _zeros_of(self._cache_shapes(lanes))},
                     ids, positions=ids, token_mask=ids == 0,
-                    mutable=["cache", "intermediates"])
+                    mutable=["cache", "intermediates"],
+                    **self._last(jnp.zeros((lanes,), jnp.int32)))
                 return mut.get("intermediates", {})
             return jax.eval_shape(call, self._params)
 
@@ -1242,17 +1251,30 @@ class ContinuousBatcher:
                                            ids.shape),
                 token_mask=jnp.arange(ids.shape[1])[None, :]
                 < true_lens[:, None],
-                snap_at=snap_at, mutable=_PREFILL_MUTABLE)
+                snap_at=snap_at, mutable=_PREFILL_MUTABLE,
+                **self._last(true_lens - 1))
             # padded prompts: sample each lane at ITS last real
             # position; the pad queries wrote kv past true_len, which
             # insertion resets (cache_index := true_len) and masks
             # never reach
-            last = jnp.take_along_axis(
-                logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
-            toks = self._sample(last, key)
+            toks = self._sample(self._last_row(logits, true_lens - 1), key)
             return (mut["cache"], toks, self._sown(mut), self._snap_of(mut))
 
         return jax.jit(prefill)
+
+    def _last(self, at) -> dict:
+        """What a multi-token program that samples at row ``at`` [lanes]
+        of its call hands the model beside its tokens: under the
+        last-position cut that row, else nothing."""
+        return {"last_at": at} if self._cut else {}
+
+    def _last_row(self, logits, at):
+        """``[lanes, vocab]``: each lane's logits at row ``at`` of the
+        call (under the last-position cut the one row the model
+        returned)."""
+        if self._cut:
+            return logits[:, 0]
+        return jnp.take_along_axis(logits, at[:, None, None], axis=1)[:, 0]
 
     @staticmethod
     def _snap_of(mut):
@@ -1926,7 +1948,7 @@ class ContinuousBatcher:
                 st.slab, st.sown = self._chunk_mid_fn(C)(
                     self._params, st.slab, jnp.asarray(chunk), st.sown)
                 self._count_enqueue()
-                self._counters.on_prefill(1, C, C, st.offset)
+                self._counters.on_prefill(1, C, C, st.offset, final=False)
                 st.offset += C
                 with self._stats_lock:
                     self._prefill_chunks += 1
@@ -1972,7 +1994,8 @@ class ContinuousBatcher:
             _, mut = model.apply(
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(C)[None, :],
-                mutable=["cache", "intermediates"])
+                mutable=["cache", "intermediates"],
+                **({"tail": False} if self._cut else {}))
             return mut["cache"], sown_in + self._sown(mut)
 
         return jax.jit(mid, donate_argnums=(1,), out_shardings=(
@@ -1990,10 +2013,9 @@ class ContinuousBatcher:
                 {"params": params, "cache": slab}, ids,
                 positions=idx[:, None] + jnp.arange(P)[None, :],
                 token_mask=jnp.arange(P)[None, :] < rel_lens[:, None],
-                snap_at=snap_at, mutable=_PREFILL_MUTABLE)
-            last = jnp.take_along_axis(
-                logits, (rel_lens - 1)[:, None, None], axis=1)[:, 0]
-            toks = self._sample(last, key)
+                snap_at=snap_at, mutable=_PREFILL_MUTABLE,
+                **self._last(rel_lens - 1))
+            toks = self._sample(self._last_row(logits, rel_lens - 1), key)
             return (mut["cache"], toks, sown_in + self._sown(mut),
                     self._snap_of(mut))
 
@@ -2135,10 +2157,9 @@ class ContinuousBatcher:
                 positions=prefix_len
                 + jnp.broadcast_to(jnp.arange(P), ids.shape),
                 token_mask=jnp.arange(P)[None, :] < true_lens[:, None],
-                mutable=["cache", "intermediates"])
-            last = jnp.take_along_axis(
-                logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
-            toks = self._sample(last, key)
+                mutable=["cache", "intermediates"],
+                **self._last(true_lens - 1))
+            toks = self._sample(self._last_row(logits, true_lens - 1), key)
             return mut["cache"], toks, self._sown(mut), None
 
         return jax.jit(prefill)

@@ -51,6 +51,25 @@ SOWN = {
     # slot (its whole slab) on the einsum path
     "ssm_slots_run": {"decode": ("ssm_state_steps_run",)},
     "latent_tokens_read": {"decode": ("latent_tokens_read",)},
+    # a borrowing layer's one-row read of its lender's slab: whole attend
+    # blocks on the chip, every slot's slab on the einsum path (booked
+    # for the step programs; a prefill's cut row reads it too, unbooked)
+    "borrowed_rows_read": {"decode": ("borrowed_kv_tokens_read",)},
+}
+
+# The scopes (``jax.named_scope``) a mixer kind's programs carry in a
+# trace, a row a kind that names its own (``models/transformer.py``):
+# what a reader of a device trace may look for beside the kernels' names
+SCOPES = {
+    "ssm": ("ssm/proj_in", "ssm/conv", "ssm/scan", "ssm/step",
+            "ssm/gate_norm", "ssm/proj_out"),
+    "kda": ("kda/proj_in", "kda/conv", "kda/step", "kda/gate_norm",
+            "kda/proj_out"),
+    "mamba1": ("mamba1/proj_in", "mamba1/conv", "mamba1/proj_x",
+               "mamba1/scan", "mamba1/step", "mamba1/gate",
+               "mamba1/proj_out"),
+    "gmu": ("gmu",),
+    "cross": ("attn/cross", "attn/diff_combine"),
 }
 
 # every model-counter key of stats(), in its order; 0.0: a float sum
@@ -94,6 +113,17 @@ KEYS = {
     # those that were padding (masked: a scan cannot skip them for free)
     "ssm_state_steps": 0, "ssm_state_steps_run": 0.0,
     "ssm_prefill_positions": 0, "ssm_prefill_positions_pad": 0,
+    # borrowing layers (0s without one), per token step, live slot and
+    # borrowing layer: positions of the lender's slab the read needed
+    # (the slot's length) and fetched (SOWN)
+    "borrowed_kv_tokens_live": 0, "borrowed_kv_tokens_read": 0.0,
+    # and what the sampling row of a multi-token program needed of them
+    # (a lane's rows up to its last real token, a borrowing layer)
+    "borrowed_kv_tokens_prefill": 0,
+    # the last-position cut (0s for a stack with no tail): (real token,
+    # layer) visits the multi-token programs ran, and those a full-depth
+    # prefill of the same tokens would have run beside them
+    "prefill_layer_visits": 0, "prefill_layer_visits_cut": 0,
     # MoE prefill capacity overflow (always 0 for dense configs;
     # nonzero = raise capacity_factor)
     "moe_prefill_drops": 0,
@@ -135,6 +165,8 @@ class ModelCounters:
         by = collections.Counter(c.kind for c in classes.values())
         self._n_ring, self._n_state, self._n_latent = (
             by["window"], by["state"], by["latent"])
+        self._n_borrowed = by["borrowed"]
+        self._tail = cfg.num_layers - cfg.tail_start
         self._latent = next((c for c in classes.values()
                              if c.kind == "latent"), None)
         for kind in ("window", "global", "state", "latent"):
@@ -160,18 +192,29 @@ class ModelCounters:
         self._routed_sown = widths.get("moe_stats", 0) > 3
 
     def on_prefill(self, lanes: int, width: int, real: int, offset: int = 0,
-                   lens=None) -> None:
+                   lens=None, final: bool = True) -> None:
         """One prefill, chunk or reuse program ran ``lanes x width``
         positions from ``offset`` on, ``real`` of them tokens (``lens``
         a lane where there are several), through every recurrent
         layer's scan and every latent layer's expanded path (rows up to
         the call's end, read in whole tiles of the path that ran, the
         kernel or the loop: the rule and the plan the program was built
-        under, nothing read back from the device)."""
-        if not (self._n_state or self._n_latent):
+        under, nothing read back from the device).  ``final``: the
+        program sampled (a stack with a tail ran it at a row a lane; a
+        chunk that samples nothing left it out)."""
+        if not (self._n_state or self._n_latent or self._tail):
             return
         t = self._t
         with self._lock:
+            if self._tail:
+                ran = (real * self._cfg.tail_start
+                       + (lanes * self._tail if final else 0))
+                t["prefill_layer_visits"] += ran
+                t["prefill_layer_visits_cut"] += (
+                    real * self._cfg.num_layers - ran)
+                if final:
+                    t["borrowed_kv_tokens_prefill"] += self._n_borrowed * sum(
+                        offset + n for n in ([real] if lens is None else lens))
             if self._n_state:
                 t["ssm_prefill_positions"] += lanes * width
                 t["ssm_prefill_positions_pad"] += lanes * width - real
@@ -205,6 +248,7 @@ class ModelCounters:
         t["ssm_state_steps"] += live * T * self._n_state
         t["latent_tokens_live"] += kv_live * self._n_latent
         t["latent_decode_calls"] += len(held) * self._n_latent
+        t["borrowed_kv_tokens_live"] += kv_live * self._n_borrowed
 
     def read(self, vector, tokens: int, decode: bool) -> None:
         """Book one program's ``sown_vector`` (on the host),

@@ -462,6 +462,33 @@ def test_ssm_step_kernel_compiles_for_v5e_at_published_widths(one_chip):
     assert "ssm_step" in compiled.as_text()
 
 
+def test_mamba1_step_kernel_compiles_for_v5e_at_published_widths(one_chip):
+    """``ops/mamba1.mamba1_step`` at Phi-4-mini-flash-reasoning's widths
+    (32 slots, inner 5120, state 16), compiled ahead of time for one v5e
+    chip from abstract shapes: the Mosaic compiler takes the kernel (a
+    ``[16, 1]`` column broadcast over the state's lanes, a ``[1, 5120]``
+    row over its sublanes), and the states are aliased in and out."""
+    from edl_tpu.ops import mamba1
+
+    B, N, Di = 32, 16, 5120
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with _no_compile_cache():
+        compiled = jax.jit(
+            lambda s, x, dt, A, b, c, live: mamba1.mamba1_step(
+                s, x, dt, A, b, c, live, interpret=False),
+            donate_argnums=(0,)).lower(
+                sds((B, N, Di)), sds((B, Di), jnp.bfloat16), sds((B, Di)),
+                sds((N, Di)), sds((B, N), jnp.bfloat16),
+                sds((B, N), jnp.bfloat16), sds((B,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == B * N * Di * 4
+    assert mem.temp_size_in_bytes < 2e6
+    assert "mamba1_step" in compiled.as_text()
+
+
 def test_kda_and_latent_kernels_compile_for_v5e_at_published_widths(
         one_chip):
     """``ops/kda.kda_step``, ``ops/latent_attention.latent_append`` and

@@ -26,6 +26,7 @@ from tests.test_granite_moe_hybrid import CFG as GRANITE
 from tests.test_kimi_linear import CFG as KIMI
 from tests.test_olmoe import CFG as OLMOE
 from tests.test_pangu_ultra_moe import CFG as PANGU
+from tests.test_phi4flash import CFG as PHI
 
 DENSE = TransformerConfig(vocab_size=64, num_layers=2, embed_dim=32,
                           num_heads=4, mlp_dim=64, max_len=96, remat=False,
@@ -54,17 +55,23 @@ CONFIGS = {
     "kimi_kda_latent": _cut(KIMI, range(4)),
     # latent x 3 with a low-rank query
     "pangu_latent": PANGU,
+    # mamba1, window, mamba1, window, mamba1 (emits), global (lends),
+    # gmu (owns nothing), cross (borrows): the last-position cut
+    "phi_borrowed": PHI,
 }
 
-MODEL_KEYS = ("moe_", "latent_", "ssm_", "decode_kv_tokens_",
+MODEL_KEYS = ("moe_", "latent_", "ssm_", "decode_kv_tokens_", "borrowed_",
+              "prefill_layer_",
               "kv_slot_bytes_", "kv_state_", "kv_window_", "kv_prefix_",
               "kv_prefill_tokens")
 
 # every stats() key of a paged engine without speculative decoding at PR
-# 43: the same set for all seven configurations (the benchmark's readers
+# 45 (PR 43's and the borrowing layers' and the last-position cut's): the same set for all seven configurations (the benchmark's readers
 # are the contract)
 ALL_KEYS = frozenset("""
-active_slots admitted chunk_lane_busy_s chunked_admissions
+active_slots admitted borrowed_kv_tokens_live borrowed_kv_tokens_prefill
+borrowed_kv_tokens_read chunk_lane_busy_s chunked_admissions
+prefill_layer_visits prefill_layer_visits_cut
 decode_kv_tokens_live decode_kv_tokens_slab decode_kv_tokens_window_need
 decode_kv_tokens_window_read decode_s_sum decode_tokens device_enqueues
 device_queue_programs_sum draining first_tokens idle_wait_s kv_block
@@ -134,9 +141,28 @@ COUNTER_KEYS = (
     'kv_prefix_hits', 'kv_prefix_misses', 'kv_prefill_tokens',
     'kv_prefill_tokens_skipped', 'kv_window_snapshots',
     'kv_window_snapshot_skips', 'kv_state_snapshots',
-    'kv_state_snapshot_skips', 'kv_state_reprefill_tokens')
+    'kv_state_snapshot_skips', 'kv_state_reprefill_tokens',
+    'borrowed_kv_tokens_live', 'borrowed_kv_tokens_read',
+    'borrowed_kv_tokens_prefill', 'prefill_layer_visits',
+    'prefill_layer_visits_cut')
 
 GOLDEN = {
+    # recorded by PR 45, which added the configuration and its keys
+    'phi_borrowed': {
+        'decode_kv_tokens_live': 850, 'decode_kv_tokens_slab': 5760,
+        'decode_kv_tokens_window_read': 532, 'decode_kv_tokens_window_need':
+        224, 'kv_slot_bytes_window': 4864, 'kv_slot_bytes_global': 12288,
+        'kv_slot_bytes_state': 5376, 'ssm_state_steps': 84,
+        'ssm_state_steps_run': 180.0, 'ssm_prefill_positions': 88,
+        'ssm_prefill_positions_pad': 16, 'borrowed_kv_tokens_live': 850,
+        'borrowed_kv_tokens_read': 5760.0, 'borrowed_kv_tokens_prefill': 112,
+        # 72 tokens below the tail (6 layers), the tail (2) at the four
+        # sampling lanes' rows
+        'prefill_layer_visits': 440, 'prefill_layer_visits_cut': 136,
+        'kv_prefix_hits': 1, 'kv_prefix_misses': 3, 'kv_prefill_tokens': 112,
+        'kv_prefill_tokens_skipped': 40, 'kv_window_snapshots': 3,
+        'kv_state_snapshots': 3
+    },
     'dense': {
         'decode_kv_tokens_live': 850, 'decode_kv_tokens_slab': 5760,
         'kv_slot_bytes_global': 49152, 'kv_prefix_hits': 1,
@@ -284,6 +310,7 @@ LAYOUTS = {
     "kimi_kda_latent": (("latent_tokens_read", 1), ("moe_stats", 4),
                         ("ssm_slots_run", 1)),
     "pangu_latent": (("latent_tokens_read", 1), ("moe_stats", 4)),
+    "phi_borrowed": (("borrowed_rows_read", 1), ("ssm_slots_run", 1)),
 }
 
 
@@ -299,7 +326,7 @@ def test_a_step_program_returns_one_counters_leaf(ran):
     assert leaf.shape == (sum(w + 1 for _, w in layout),)
 
 
-KINDS = {"global", "window", "ssm", "kda", "latent"}
+KINDS = {"global", "window", "ssm", "kda", "latent", "mamba1", "gmu", "cross"}
 
 
 def test_the_engine_names_no_mixer_kind():

@@ -75,6 +75,10 @@ def test_phases_tile_the_engine_thread():
         # compiles out of the way: the marks must fall on an idle engine
         for p in _prompts(0, (5, 12, 20)):
             eng.submit(p, 4).result(timeout=120)
+        # a group of two as well: its programs' compiles are seconds on a
+        # loaded host, inside ONE tick's admit phase
+        [f.result(timeout=120)
+         for f in [eng.submit(p, 3) for p in _prompts(4, (6, 7, 5))]]
         t_a, s_a = _mark(eng)
         time.sleep(0.3)                                   # idle stretch
         futs = [eng.submit(p, 6) for p in _prompts(1, (3, 7, 5, 6, 4, 8))]
@@ -106,7 +110,13 @@ def test_phases_tile_the_engine_thread():
     # the phases account for the ticks
     assert sum(d[k] for k in PHASE_KEYS) >= 0.95 * d["tick_s"], d
     assert sum(d[k] for k in PHASE_KEYS) <= d["tick_s"] * 1.0001, d
-    assert coverage >= 0.95
+    # the ledger's own self-check is an EMA over TICKS (weight 0.1): one
+    # of the last ticks, half a millisecond long here, descheduled
+    # between two phases on a loaded host reads 0.3 and takes the EMA
+    # from 0.99 under 0.95.  The sums above are the tiling's judge (one
+    # such tick moves them by a thousandth); the EMA is held to being
+    # the same kind of number
+    assert 0.75 <= coverage <= 1.0001
     # every phase that has work here saw some; kv_commit is nested in
     # finish and deducted from it, never counted twice
     for k in ("tick_admit_s", "tick_dispatch_s", "tick_sync_s",
@@ -388,7 +398,7 @@ def test_engine_lookahead_share_is_declared_for_the_serve_cells():
     # and every serve cell the file has since, in its order
     assert cells == next(m["workloads"] for m in spec["end_to_end"]
                          if m["name"] == "serve_tokens_per_s")
-    assert cells[-2:] == ["serve-latent-reason-open", "serve-mla-docs-closed"]
+    assert cells[5:7] == ["serve-latent-reason-open", "serve-mla-docs-closed"]
     layers = {m["layer"] for m in spec["per_layer"]
               if m["name"] != "engine_lookahead_share"}
     assert entry["layer"] in layers       # a layer the file already names

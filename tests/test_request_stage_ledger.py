@@ -135,6 +135,17 @@ def eng(small):
         # compiles out of the way
         for n in (3, 7, 30):
             engine.submit(_prompt(90 + n, n), 4).result(timeout=120)
+        # and those of a GROUP of two (its prefill, its insert, the
+        # lists' own tiny programs): the tests below compare one cause's
+        # wait with another's, a tick against a few ticks, and a compile
+        # that lands inside either is a hundred ticks long.  Which test
+        # met a group first, and paid for it, depended on how the tests
+        # fell to this worker
+        for n in (3, 7):
+            with _held(engine):
+                pair = [engine.submit(_prompt(80 + n + i, n), 3)
+                        for i in range(2)]
+            [f.result(timeout=120) for f in pair]
         yield engine
     finally:
         engine.stop()
