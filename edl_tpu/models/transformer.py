@@ -1560,8 +1560,11 @@ class Block(nn.Module):
                         else token_mask[:, 0])
                 lengths = jnp.where(
                     live, jnp.minimum(positions[:, 0] + 1, T), 0)
-                # positions this read fetched of the lender's rows, for
-                # the engine's ``borrowed_kv_tokens_read``
+                # positions this read fetched of the lender's rows (the
+                # kernel: the blocks its walk lists, whole, so each live
+                # slot's rows up to the end of its last block and nothing
+                # of a free slot; the einsums: every slot's slab), for the
+                # engine's ``borrowed_kv_tokens_read``
                 self.sow("intermediates", "borrowed_rows_read",
                          decode_attention.tokens_fetched(
                              lengths, Hk, D, T, k.dtype, kernel))

@@ -18,18 +18,22 @@ keys ``[B, Hk, D, max_len]`` and values ``[B, Hk, max_len, D]``:
   past ``max_len``, leave their tile as it was.
 - :func:`decode_attend` (``decode_attend``) is flash-decoding over the
   slabs as they lie (q ``[G, D]`` x K ``[D, tk]``, p ``[G, tk]`` x V
-  ``[tk, D]``: no transpose), heads inside the block, the grid over
-  (slots, ``max_len / tk``).  Lengths are scalar-prefetched and the
-  index maps clamp to each slot's last live block, so a block past the
-  length is neither fetched nor computed; a free slot (length 0) points
-  at the block its neighbour already holds and fetches nothing.
+  ``[tk, D]``: no transpose), heads inside the block, in ONE grid step:
+  ``q`` and the output whole in VMEM, the slabs left in HBM.  An XLA
+  prologue (:func:`_work_list`) lists the live ``(slot, block)`` pairs,
+  a slot's blocks ``0 .. (length - 1) // tk`` in order; the list and
+  the lengths are scalar-prefetched, and the kernel loops over the
+  list, copying each block of K and of V into one half of two buffers
+  while it computes on the other (``pltpu.make_async_copy``).  What is
+  on no list costs nothing: a block past a slot's length, a free slot
+  (length 0, whose output rows stay zero), an empty batch.
 
 A window layer's cache is a ring (``transformer.Block._ring_attention``:
 position p at slot ``p % T``).  The same two kernels serve it: the
 caller appends at ``index % T``, and :func:`decode_attend` with
 ``newest`` / ``visible`` attends the ``visible`` most recently written
-slots ending at ``newest``, wrapping: the mask is circular, the fetch
-plan is the slab's.  In a trace a window layer's two calls are named
+slots ending at ``newest``, wrapping: the mask is circular, the work
+list is the slab's.  In a trace a window layer's two calls are named
 ``window_append`` and ``window_attend``.
 
 Precision is the einsum path's: input-dtype matmuls accumulated in
@@ -53,9 +57,15 @@ from jax.experimental.pallas import tpu as pltpu
 from edl_tpu.ops.attention import _on_tpu
 
 _LANES = 128
-# bytes of one K (or V) block of the attend kernel: two slabs, double
-# buffered, is four of these in VMEM beside the f32 scores
+# bytes of one K (or V) block of the attend kernel at most: two slabs,
+# double buffered, is four of these in VMEM beside the f32 scores
 _BLOCK_BYTES = 2 << 20
+# and its time steps at most.  The walk pays nothing measurable an item
+# (PERF.md section 6, PR 46: 256 as fast as 512 and 1024 at long
+# lengths), and the shorter the block the less of a slot's last one lies
+# past its length; at 128 a K row's run is 256 B and the copies lose a
+# tenth of their pace
+_BLOCK_ROWS = 256
 _VMEM_LIMIT = 48 << 20
 _NEG = -1e30    # finite: a fully masked tail must not make inf - inf
 
@@ -138,26 +148,59 @@ def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
 
 # -- attend ----------------------------------------------------------------
 
-def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, *refs, tk: int,
+def _attend_kernel(len_ref, slot_ref, blk_ref, nw_ref, *refs, tk: int,
                    scale: float, ring: int = 0):
-    """``ring`` (the slab's length, static) adds two prefetched scalars
-    a slot, the newest ring slot and how many slots back are visible;
-    0 is the plain slab, masked by length."""
+    """The whole call: ``q`` and the output whole in VMEM, the slabs in
+    HBM.  A loop over the work list (:func:`_work_list`) copies each
+    live block of K ``[Hk, D, tk]`` and of V ``[Hk, tk, D]`` into one
+    half of two buffers a slab, the next item's copies in flight while
+    this one is computed; a slot's statistics start at its first block
+    and its output rows are written at its last.  ``ring`` (the slab's
+    length, static) adds two prefetched scalars a slot, the newest ring
+    slot and how many slots back are visible; 0 is the plain slab,
+    masked by length."""
     if ring:
         newest_ref, visible_ref, *refs = refs
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    b, j = pl.program_id(0), pl.program_id(1)
-    n = len_ref[b]
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, m_ref, l_ref,
+     acc_ref) = refs
+    nw = nw_ref[0]
 
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    def copies(i):
+        b = slot_ref[i]
+        at = pl.ds(pl.multiple_of(blk_ref[i] * tk, tk), tk)
+        return (pltpu.make_async_copy(k_hbm.at[b, :, :, at],
+                                      k_buf.at[i % 2], sem.at[0, i % 2]),
+                pltpu.make_async_copy(v_hbm.at[b, :, at, :],
+                                      v_buf.at[i % 2], sem.at[1, i % 2]))
 
-    @pl.when(j * tk < n)
+    def start(i):
+        for c in copies(i):
+            c.start()
+
+    # a free slot is on no list: its rows stay zero
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(nw > 0)
     def _():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        start(0)
+
+    def item(i, carry):
+        b, j = slot_ref[i], blk_ref[i]
+        n = len_ref[b]
+
+        @pl.when(i + 1 < nw)
+        def _():
+            start(i + 1)
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        for c in copies(i):
+            c.wait()
+        q, k, v = q_ref[b], k_buf[i % 2], v_buf[i % 2]
         # [Hk, Gp, D] x [Hk, D, tk] -> [Hk, Gp, tk], f32
         s = jax.lax.dot_general(
             q, k, (((2,), (1,)), ((0,), (0,))),
@@ -172,7 +215,7 @@ def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, *refs, tk: int,
         m_old = m_ref[...]
         m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_old - m_new)
-        # a block that runs holds a live position, so m_new is a real
+        # a listed block holds a live position, so m_new is a real
         # score and the masked tail's exp(_NEG - m_new) is exactly 0
         p = jnp.exp(s - m_new)
         if ring:
@@ -186,11 +229,33 @@ def _attend_kernel(len_ref, src_ref, lo_ref, hi_ref, *refs, tk: int,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        l = l_ref[...]      # 0 for a free slot, whose acc is 0 too
-        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
-            o_ref.dtype)
+        # the slot's last block: the list is in slot order
+        @pl.when((j + 1) * tk >= n)
+        def _():
+            l = l_ref[...]
+            o_ref[b] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+                o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, nw, item, 0)
+
+
+def _work_list(lengths, tk: int, nb: int):
+    """What the attend kernel walks: the live ``(slot, block)`` pairs in
+    slot order, each slot's blocks ``0 .. (len - 1) // tk`` ascending,
+    and how many there are (``[1]``).  The lists have room for every
+    block of every slot (``B * nb``); entries past the count are
+    unused."""
+    blocks = (lengths + tk - 1) // tk
+    ends = jnp.cumsum(blocks)
+    item = jnp.arange(lengths.shape[0] * nb, dtype=jnp.int32)
+    # the slots that end at or before an item are those before its own
+    before = item[:, None] >= ends[None, :]
+    slot = jnp.minimum(before.sum(axis=1), lengths.shape[0] - 1)
+    block = item - jnp.where(before, blocks[None, :], 0).sum(axis=1)
+    return (slot.astype(jnp.int32), block.astype(jnp.int32),
+            ends[-1:].astype(jnp.int32))
 
 
 def _fetch_plan(lengths, tk: int):
@@ -223,12 +288,13 @@ def blocks_fetched(src, lo, hi, nb: int):
 
 
 def attend_block(Hk: int, D: int, max_len: int, dtype) -> int:
-    """Time steps a grid step of the attend kernel reads: the largest
-    power-of-two multiple of 128 that divides ``max_len`` and keeps one
-    K block (all heads) within ``_BLOCK_BYTES``."""
+    """Time steps one item of the attend kernel's walk reads: the
+    largest power-of-two multiple of 128, ``_BLOCK_ROWS`` at most, that
+    divides ``max_len`` and keeps one K block (all heads) within
+    ``_BLOCK_BYTES``."""
     tk = _LANES
-    while (max_len % (2 * tk) == 0 and 2 * tk * Hk * D
-           * jnp.dtype(dtype).itemsize <= _BLOCK_BYTES):
+    while (2 * tk <= _BLOCK_ROWS and max_len % (2 * tk) == 0
+           and 2 * tk * Hk * D * jnp.dtype(dtype).itemsize <= _BLOCK_BYTES):
         tk *= 2
     return tk
 
@@ -237,13 +303,13 @@ def tokens_fetched(lengths, Hk: int, D: int, max_len: int, dtype,
                    kernel: bool):
     """Positions one attend call fetches of the slots' slabs, float32:
     on the kernel's path whole blocks up to each live slot's length
-    (COUNTED from the fetch plan, as :func:`blocks_fetched` counts), on
+    (the list the kernel walks, :func:`_work_list`, times the block), on
     the einsum path every slot's slab."""
     if not kernel:
         return jnp.asarray(lengths.shape[0] * max_len, jnp.float32)
     tk = attend_block(Hk, D, max_len, dtype)
-    plan = _fetch_plan(lengths.astype(jnp.int32), tk)
-    return blocks_fetched(*plan, max_len // tk) * tk
+    *_, nw = _work_list(lengths.astype(jnp.int32), tk, max_len // tk)
+    return nw[0].astype(jnp.float32) * tk
 
 
 def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
@@ -260,52 +326,51 @@ def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
     slot ``newest[b]``; ``lengths[b]`` is then how many ring slots have
     been written at all (what is fetched; 0 = a free slot), and the
     kernel is named ``window_attend``."""
+    assert (newest is None) == (visible is None)
+    _, Hk, D, T = k_slab.shape
+    tk = block or attend_block(Hk, D, T, k_slab.dtype)
+    assert T % tk == 0, (T, tk)
+    return _attend(q, k_slab, v_slab, lengths, newest, visible, tk,
+                   _interpret(interpret),
+                   D ** -0.5 if scale is None else scale)
+
+
+# a program's layers are alike: jitted, the kernel is traced and lowered
+# once a program, not once a layer (ops/moe._decode_gmm)
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _attend(q, k_slab, v_slab, lengths, newest, visible, tk: int,
+            interpret: bool, scale: float):
     ring = newest is not None
-    assert ring == (visible is not None)
     B, H, D = q.shape
     _, Hk, _, T = k_slab.shape
     G = H // Hk
-    tk = block or attend_block(Hk, D, T, k_slab.dtype)
-    assert T % tk == 0, (T, tk)
     # the group axis is the matmuls' row axis: pad it to a sublane tile
     Gp = -(-G // _sublanes(q.dtype)) * _sublanes(q.dtype)
     qg = jnp.pad(q.reshape(B, Hk, G, D), ((0, 0), (0, 0), (0, Gp - G),
                                           (0, 0)))
     lengths = lengths.astype(jnp.int32)
-    src, lo, hi = _fetch_plan(lengths, tk)
-
-    def fetched(b, j, n, src, lo, hi, *_):
-        return src[b], jnp.clip(j, lo[b], hi[b])
-
-    def k_index(*step):
-        slot, t = fetched(*step)
-        return slot, 0, 0, t
-
-    def v_index(*step):
-        slot, t = fetched(*step)
-        return slot, 0, t, 0
-
-    q_spec = pl.BlockSpec((None, Hk, Gp, D), lambda b, *_: (b, 0, 0, 0))
     extra = ((newest.astype(jnp.int32), visible.astype(jnp.int32))
              if ring else ())
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_attend_kernel, tk=tk,
-                          scale=D ** -0.5 if scale is None else scale,
+        functools.partial(_attend_kernel, tk=tk, scale=scale,
                           ring=T if ring else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4 + len(extra), grid=(B, T // tk),
-            in_specs=[q_spec,
-                      pl.BlockSpec((None, Hk, D, tk), k_index),
-                      pl.BlockSpec((None, Hk, tk, D), v_index)],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((Hk, Gp, 1), jnp.float32),
+            num_scalar_prefetch=4 + len(extra), grid=(1,),
+            in_specs=[whole, hbm, hbm],
+            out_specs=whole,
+            scratch_shapes=[pltpu.VMEM((2, Hk, D, tk), k_slab.dtype),
+                            pltpu.VMEM((2, Hk, tk, D), v_slab.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((Hk, Gp, 1), jnp.float32),
                             pltpu.VMEM((Hk, Gp, 1), jnp.float32),
                             pltpu.VMEM((Hk, Gp, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, Hk, Gp, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(interpret),
+        interpret=interpret,
         name="window_attend" if ring else "decode_attend",
-    )(lengths, src, lo, hi, *extra, qg, k_slab, v_slab)
+    )(lengths, *_work_list(lengths, tk, T // tk), *extra, qg, k_slab, v_slab)
     return out[:, :, :G].reshape(B, H, D)

@@ -30,6 +30,7 @@ from edl_tpu.ops import decode_attention
 from edl_tpu.serving import ContinuousBatcher
 
 MAX_LEN, BLOCK = 256, 128
+RING, WINDOW = 256, 128     # a window layer's ring, below
 # slot -> (cache_index before the step, live): a free slot first, in the
 # middle and last; lengths 1, one under / at / over a block edge,
 # max_len - 1, max_len, and an index past the end (write dropped)
@@ -152,14 +153,106 @@ def test_fetch_plan_points_free_slots_at_the_block_already_held(
 
 
 @pytest.mark.parametrize("Hk,max_len,dtype,want", [
-    (8, 8192, jnp.bfloat16, 1024),      # the Mistral serve cells
-    (16, 4096, jnp.bfloat16, 512),      # the OLMoE serve cell
+    (8, 8192, jnp.bfloat16, 256),       # the Mistral serve cells
+    (16, 4096, jnp.bfloat16, 256),      # the OLMoE serve cell
     (2, 384, jnp.float32, 128),         # 384 = 3 x 128: no larger divisor
-    (6, 2048, jnp.bfloat16, 1024),      # the 12 x 768 flagship
+    (10, 20480, jnp.bfloat16, 256),     # Phi-4-mini-flash's full layer
+    (10, 640, jnp.bfloat16, 128),       # and its rings: 640 = 5 x 128
+    (64, 8192, jnp.float32, 128),       # 256 steps of 64 heads: 4 MiB
 ])
 def test_attend_block_divides_max_len_within_the_vmem_budget(
         Hk, max_len, dtype, want):
     assert decode_attention.attend_block(Hk, 128, max_len, dtype) == want
+
+
+# -- the walk over the live blocks (PR 46) -----------------------------------
+# lengths a slot: 0 = free.  Two blocks of 128 a slot
+WALKS = {
+    "free_first_between_last": [0, 1, 0, BLOCK, BLOCK + 1, 0, MAX_LEN, 0],
+    "every_slot_live": [MAX_LEN, 1, BLOCK, BLOCK + 1, 7, MAX_LEN - 1, 2,
+                        BLOCK - 1],
+    "one_live_slot_at_the_last_index": [0, 0, 0, 0, 0, 0, 0, BLOCK + 1],
+    "one_live_slot_at_the_first_index": [1, 0, 0, 0, 0, 0, 0, 0],
+    "nothing_live": [0] * 8,
+}
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_work_list_holds_the_live_blocks_in_slot_order(case):
+    lengths = WALKS[case]
+    nb = MAX_LEN // BLOCK
+    slot, block, nw = (np.asarray(a) for a in decode_attention._work_list(
+        jnp.asarray(lengths, jnp.int32), BLOCK, nb))
+    want = [(b, j) for b, n in enumerate(lengths)
+            for j in range(-(-n // BLOCK))]
+    assert slot.shape == block.shape == (len(lengths) * nb,)
+    assert nw.tolist() == [len(want)]
+    assert list(zip(slot[:len(want)].tolist(),
+                    block[:len(want)].tolist())) == want
+    # what lies past the count names a slot all the same
+    assert ((slot >= 0) & (slot < len(lengths))).all()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(decode_attention, "attend_block", lambda *a: BLOCK)
+        fetched = decode_attention.tokens_fetched(
+            jnp.asarray(lengths), 2, 16, MAX_LEN, jnp.float32, True)
+    assert float(fetched) == len(want) * BLOCK
+    assert float(decode_attention.tokens_fetched(
+        jnp.asarray(lengths), 2, 16, MAX_LEN, jnp.float32,
+        False)) == len(lengths) * MAX_LEN
+
+
+@functools.lru_cache(maxsize=None)
+def _walked(ring: bool):
+    """``decode_attend`` jitted once a kind of slab, and its operands:
+    every case of a kind is another set of lengths of one program."""
+    B, H, Hk, D = 8, 4, 2, 16
+    ks = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(ks[0], (B, H, D))
+    k = jax.random.normal(ks[1], (B, Hk, D, MAX_LEN))
+    v = jax.random.normal(ks[2], (B, Hk, MAX_LEN, D))
+
+    def attend(written):
+        if not ring:
+            return decode_attention.decode_attend(q, k, v, written,
+                                                  block=BLOCK)
+        return decode_attention.decode_attend(
+            q, k, v, jnp.minimum(written, MAX_LEN), block=BLOCK,
+            newest=(written - 1) % MAX_LEN,
+            visible=jnp.minimum(written, WINDOW))
+
+    return jax.jit(attend), np.asarray(q), np.asarray(k), np.asarray(v)
+
+
+# positions written a slot so far (0 = free), into a ring of 256 with a
+# window of 128: inside the first window, the window sliding, the ring
+# just full, the append wrapped, the window across the seam, many laps
+RING_WALKS = {
+    "not_wrapped": [0, 1, 0, WINDOW, WINDOW + 1, 0, MAX_LEN, 0],
+    "wrapped": [MAX_LEN + 1, 0, MAX_LEN + 45, 2 * MAX_LEN, 0,
+                5 * MAX_LEN + 131, 3, MAX_LEN + WINDOW],
+    "one_live_slot_at_the_last_index": [0] * 7 + [MAX_LEN + 45],
+}
+
+
+@pytest.mark.parametrize("kind,case", [
+    *(("slab", c) for c in WALKS), *(("ring", c) for c in RING_WALKS)])
+def test_walk_matches_plain_attention_over_what_a_slot_sees(kind, case):
+    ring = kind == "ring"
+    written = (RING_WALKS if ring else WALKS)[case]
+    attend, q, k, v = _walked(ring)
+    got = np.asarray(attend(jnp.asarray(written, jnp.int32)))
+    for b, n in enumerate(written):
+        if n == 0:      # a free slot is on no list: zeros
+            np.testing.assert_array_equal(got[b], 0.0)
+            continue
+        seen = ((n - 1 - np.arange(min(n, WINDOW))) % MAX_LEN if ring
+                else np.arange(n))
+        for h in range(q.shape[1]):
+            s = q[b, h] @ k[b, h // 2][:, seen] * q.shape[-1] ** -0.5
+            p = np.exp(s - s.max())
+            np.testing.assert_allclose(
+                got[b, h], (p / p.sum()) @ v[b, h // 2][seen],
+                atol=2e-5, rtol=2e-5)
 
 
 def test_dispatch_rule_is_shape_mesh_and_backend_only(monkeypatch):
@@ -213,7 +306,6 @@ def test_toy_engine_gives_the_einsum_paths_tokens_through_a_readmission(
 # slot -> (cache_index before the step, live).  Position p lives at ring
 # slot p % 256; the step appends at index % 256 and attends the last
 # min(index + 1, 128) positions, wrapping
-RING, WINDOW = 256, 128
 RING_SLOTS = {
     "first_token": (0, True),
     "inside_the_first_window": (5, True),
@@ -432,6 +524,39 @@ def test_step_program_for_v5e_holds_no_whole_slab_temporary(
     text = compiled.as_text()
     assert "decode_append" in text and "decode_attend" in text
 
+
+@pytest.mark.parametrize("B,Hk,G,T,ring", [
+    (32, 10, 4, 20480, False),      # Phi-4-mini-flash's full layer
+    (32, 10, 4, 640, True),         # and a window layer's ring
+    (12, 8, 4, 8192, False),        # the Mistral serve cells
+])
+def test_attend_kernel_compiles_for_v5e_at_published_widths(
+        one_chip, B, Hk, G, T, ring):
+    """``ops/decode_attention.decode_attend`` at the served slabs,
+    compiled ahead of time for one v5e chip from abstract shapes: the
+    Mosaic compiler takes the walk (the loop over the list, the copies
+    of a block of K and of V out of slabs in HBM into two halves, the
+    dynamic slot of ``q`` and of the output), and the program keeps the
+    padded queries and the output beside it and no copy of a slab."""
+    D, bf = 128, jnp.bfloat16
+
+    def sds(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, k, v, n, newest, visible):
+        extra = dict(newest=newest, visible=visible) if ring else {}
+        return decode_attention.decode_attend(q, k, v, n, interpret=False,
+                                              **extra)
+
+    ints = sds((B,), jnp.int32)
+    with _no_compile_cache():
+        compiled = jax.jit(attend).lower(
+            sds((B, Hk * G, D)), sds((B, Hk, D, T)), sds((B, Hk, T, D)),
+            ints, ints, ints).compile()
+    text = compiled.as_text()
+    assert ("window_attend" if ring else "decode_attend") in text
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
 
 
 def test_ssm_step_kernel_compiles_for_v5e_at_published_widths(one_chip):
