@@ -34,7 +34,7 @@ from edl_tpu.ops import decode_attention, kda, latent_attention, mamba1, ssm
 from edl_tpu.ops.attention import (
     SPLASH_RESIDUALS, dot_product_attention, splash_partials_bytes,
 )
-from edl_tpu.parallel.sharding import logical_constraint
+from edl_tpu.parallel.sharding import logical_constraint, logical_sharding
 
 # param-path regex → logical axes (ElasticTrainer.create_state consumes)
 LOGICAL_RULES = [
@@ -618,6 +618,29 @@ def _pin(cfg: "TransformerConfig", x, *axes):
     return logical_constraint(x, axes, cfg.mesh)
 
 
+def _fences_norms(cfg: "TransformerConfig") -> bool:
+    """Whether a block's norms stand between two fences: in a model that
+    is not a decode model and whose layer weights lie whole on every
+    device (no mesh, a mesh of one device, a mesh that splits the batch
+    alone).  There each matmul beside a norm is one whole op and XLA
+    puts the norm's reductions into it (``mlp_out`` / ``attn_out``
+    forward carry the next norm's sum of squares, the backward of
+    ``mlp_gate`` / ``attn_qkv`` the norm's whole backward): on one v5e
+    chip those ran at 45-64% of the peak where their plain twins run at
+    80-88%, and the fence gave the step 2.9% (PERF.md section 6, PR 47;
+    a ``dp=4`` step compiled for ``v5e:2x2`` holds the same eight
+    fusions and loses them to the fence).  Where the weights are split
+    (``fsdp``, ``tp``, ``ep``) the weight gathers and ``_pin``'s
+    constraints already cut every layer matmul into pieces that carry no
+    reduce, and a fence only adds the norms' own passes: on four chips
+    under ``fsdp=4`` it cost the step 0.85% (same place).  A decode
+    model's matmuls are a few rows against whole weights."""
+    if cfg.decode:
+        return False
+    return cfg.mesh is None or logical_sharding(
+        ("embed", "mlp", "expert"), cfg.mesh).is_fully_replicated
+
+
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
     eps: float = 1e-6
@@ -652,9 +675,16 @@ class LayerNorm(nn.Module):
 
 
 def _norm(cfg: "TransformerConfig", name: str):
-    """A block's (or the final) norm: ``cfg.norm`` says which."""
+    """A block's (or the final) norm: ``cfg.norm`` says which.  Where
+    ``_fences_norms`` holds, its input and its output each pass
+    ``optimization_barrier`` (the identity, opaque to fusion): the norm
+    runs as passes of its own, forward and backward."""
     kind = LayerNorm if cfg.norm == "layer" else RMSNorm
-    return kind(cfg.dtype, cfg.norm_eps, name=name)
+    norm = kind(cfg.dtype, cfg.norm_eps, name=name)
+    if not _fences_norms(cfg):
+        return norm
+    fence = jax.lax.optimization_barrier
+    return lambda x: fence(norm(fence(x)))
 
 
 def _residual(cfg: "TransformerConfig", x, branch):

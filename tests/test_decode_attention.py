@@ -812,6 +812,57 @@ def four_chips():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+def _compile_train_step(devices, spec, batch, monkeypatch, **widths):
+    """``ElasticTrainer``'s own step (a ``TransformerLM`` of ``widths``,
+    4096 tokens a row, remat, unrolled, splash, fused CE in blocks of
+    4096, adamw) compiled ahead of time for the described ``devices``
+    from abstract shapes: ``(compiled, abstract state)``."""
+    import optax
+
+    from edl_tpu.models import transformer as tf_mod
+    from edl_tpu.models.logical import logical_axes_from_paths
+    from edl_tpu.ops import attention
+    from edl_tpu.parallel.sharding import logical_sharding
+    from edl_tpu.train import ElasticTrainer, TrainConfig
+
+    # what "auto" asks of the backend, answered for the described chips
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    seq, ce_block = 4096, 4096
+    cfg = TransformerConfig(
+        vocab_size=32768, num_layers=2, max_len=seq, rope_theta=1e6,
+        attention_impl="auto", remat=True, scan_layers=False, **widths)
+    lm = TransformerLM(cfg)
+
+    def loss_fn(params, extra, batch, rng):
+        h = lm.apply({"params": params}, batch["ids"][:, :-1],
+                     return_hidden=True)
+        return tf_mod.lm_loss_fused(params, h, batch["ids"][:, 1:], cfg,
+                                    block_size=ce_block), (extra, {})
+
+    def init():
+        return lm.init(jax.random.key(0),
+                       jnp.zeros((len(devices), 8), jnp.int32))["params"], None
+
+    trainer = ElasticTrainer(
+        loss_fn, TrainConfig(mesh_spec=spec, global_batch_size=batch,
+                             log_every=0), devices=devices)
+    logical = logical_axes_from_paths(jax.eval_shape(lambda: init()[0]),
+                                      tf_mod.LOGICAL_RULES)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with _no_compile_cache():
+        state = trainer._abstract_state(init, optax.adamw(3e-4), logical)
+        compiled = trainer.step_fn.lower(
+            state,
+            {"ids": jax.ShapeDtypeStruct(
+                (batch, seq + 1), jnp.int32,
+                sharding=logical_sharding(("batch", None), trainer.mesh))},
+            jax.ShapeDtypeStruct(
+                key.shape, key.dtype,
+                sharding=logical_sharding((), trainer.mesh)),
+        ).compile()
+    return compiled, state
+
+
 def test_fsdp_train_step_for_v5e_gathers_weights_not_activations(
         four_chips, monkeypatch):
     """``ElasticTrainer``'s own step at the Codestral widths of the
@@ -824,54 +875,16 @@ def test_fsdp_train_step_for_v5e_gathers_weights_not_activations(
     blocks in the CE loop, whole-batch all-gathers and all-to-alls.  Now
     no collective carries the whole batch, and the weights travel, in
     bf16 (PERF.md section 6, PR 29)."""
-    import optax
-
-    from edl_tpu.models import transformer as tf_mod
-    from edl_tpu.models.logical import logical_axes_from_paths
-    from edl_tpu.ops import attention
     from edl_tpu.parallel import MeshSpec, build_mesh
-    from edl_tpu.parallel.sharding import logical_sharding
-    from edl_tpu.train import ElasticTrainer, TrainConfig
     from tests.helpers.hlo import (collectives, squeezed,
                                    whole_batch_collectives)
 
-    # what "auto" asks of the backend, answered for the described chips
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    chips, seq, ce_block = len(four_chips), 4096, 4096
+    chips, seq = len(four_chips), 4096
     spec = MeshSpec(dp=1, fsdp=chips)
-    mesh = build_mesh(spec, four_chips)     # the trainer builds the same
-    cfg = TransformerConfig(
-        vocab_size=32768, num_layers=2, embed_dim=6144, num_heads=48,
-        num_kv_heads=8, mlp_dim=16384, max_len=seq, rope_theta=1e6,
-        attention_impl="auto", remat=True, scan_layers=False, mesh=mesh)
-    lm = TransformerLM(cfg)
-
-    def loss_fn(params, extra, batch, rng):
-        h = lm.apply({"params": params}, batch["ids"][:, :-1],
-                     return_hidden=True)
-        return tf_mod.lm_loss_fused(params, h, batch["ids"][:, 1:], cfg,
-                                    block_size=ce_block), (extra, {})
-
-    def init():
-        return lm.init(jax.random.key(0),
-                       jnp.zeros((chips, 8), jnp.int32))["params"], None
-
-    trainer = ElasticTrainer(
-        loss_fn, TrainConfig(mesh_spec=spec, global_batch_size=chips,
-                             log_every=0), devices=four_chips)
-    logical = logical_axes_from_paths(jax.eval_shape(lambda: init()[0]),
-                                      tf_mod.LOGICAL_RULES)
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    with _no_compile_cache():
-        state = trainer._abstract_state(init, optax.adamw(3e-4), logical)
-        compiled = trainer.step_fn.lower(
-            state,
-            {"ids": jax.ShapeDtypeStruct(
-                (chips, seq + 1), jnp.int32,
-                sharding=logical_sharding(("batch", None), mesh))},
-            jax.ShapeDtypeStruct(key.shape, key.dtype,
-                                 sharding=logical_sharding((), mesh)),
-        ).compile()
+    compiled, state = _compile_train_step(
+        four_chips, spec, chips, monkeypatch, embed_dim=6144, num_heads=48,
+        num_kv_heads=8, mlp_dim=16384,
+        mesh=build_mesh(spec, four_chips))      # the trainer builds the same
     text = compiled.as_text()
     # what one chip holds of each weight, a layer at a time
     shards = {squeezed(p.sharding.shard_shape(p.shape)[p.ndim - 2:])
@@ -885,3 +898,37 @@ def test_fsdp_train_step_for_v5e_gathers_weights_not_activations(
     # the next matmuls' weight windows in flight (the peak measured on
     # the chip did not move: 10.29 GB on both sides)
     assert compiled.memory_analysis().temp_size_in_bytes < 5.3e9
+
+
+def test_one_chip_train_step_for_v5e_keeps_norms_out_of_its_matmuls(
+        one_chip, monkeypatch):
+    """``ElasticTrainer``'s own step at the Mistral widths of the
+    one-chip training cell (two layers, 4 x 4096 tokens, remat,
+    unrolled, splash, fused CE in blocks of 4096, adamw), compiled ahead
+    of time for one v5e chip from abstract shapes.  Left to choose, XLA
+    put each norm's reductions into the matmuls beside it: on the parent
+    of PR 47 ``matmul_fusions_with_reduce`` names ``fusion.116`` /
+    ``.119`` (the backward of ``mlp_gate`` with the whole backward of
+    ``mlp_norm``: 22.0 ms each on the chip, 45% of the peak),
+    ``fusion.130`` / ``.140`` (``mlp_out`` with the next norm's sum of
+    squares: 15.4 ms, 64%), ``fusion.135`` / ``.143`` (the backward of
+    ``attn_qkv`` with that of ``attn_norm``) and ``fusion.136`` /
+    ``.144`` (``attn_out`` with ``mlp_norm``'s sum of squares), and
+    ``fusion.374``, the head block's matmul with the CE's row max, which
+    lies under neither ``layers/`` nor ``final_norm`` and stays.  With
+    the norms fenced (``transformer._fences_norms``) no layer matmul
+    carries a reduce (PERF.md section 6, PR 47)."""
+    from edl_tpu.parallel import MeshSpec
+    from tests.helpers.hlo import matmul_fusions_with_reduce
+
+    compiled, _ = _compile_train_step(
+        list(one_chip.device_set), MeshSpec(dp=1, fsdp=1), 4, monkeypatch,
+        embed_dim=4096, num_heads=32, num_kv_heads=8, mlp_dim=14336)
+    text = compiled.as_text()
+    assert matmul_fusions_with_reduce(
+        text, under=("layers/", "final_norm")) == []
+    # the helper still sees a matmul with a reduce where one is: the CE's
+    assert len(matmul_fusions_with_reduce(text)) == 1
+    # 6.806 GB on the parent, 6.865 GB fenced: the norms' outputs are
+    # whole arrays now (of the chip's 16.9 GB the state takes 8.456)
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9
