@@ -124,6 +124,26 @@ class TransformerConfig:
     # (serving/engine.py) — with out-of-bounds rows DROPPED, never
     # clamped (a clamp would smear the last position over live state).
     decode_scatter: bool = False
+    # generation by diffusion over blocks: position i sees j iff
+    # j // block_length <= i // block_length (block-causal; 0 = causal,
+    # every program what it was).  The mask is over ABSOLUTE positions in
+    # every forward; with ``decode_scatter`` a decode model's call of
+    # block_length positions is a PASS over a slot's open block: it
+    # writes the block's rows at the slot's index, every query reads
+    # [0, index + block_length), and whoever drives it (serving/
+    # engine.py) moves the index only where the block commits
+    block_length: int = 0
+    # the generation loop a block model is published with, beside its
+    # block length (the model does not read them; the serving engine
+    # does): denoising steps a block (0: block_length, one position a
+    # pass; a denoise pass unmasks ceil(block_length / steps) positions),
+    # how a pass chooses them ("static": that many, the surest;
+    # "dynamic": every one surer than block_threshold and at least the
+    # surest), and the vocabulary's row a masked position is fed as
+    block_steps: int = 0
+    block_remasking: str = "static"
+    block_threshold: float = 0.9
+    block_mask_id: int = 0
     # -- layers that differ (a published ``layer_types`` /
     # ``mlp_layer_types`` plan).  Every field below is off by default,
     # and a configuration that leaves them off builds the modules and
@@ -247,6 +267,14 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.embed_dim // self.num_heads
+
+    @property
+    def pass_tokens(self) -> int:
+        """Positions a slot a decode-shaped call of this model has: the
+        block of a block pass (``block_length`` with ``decode_scatter``),
+        else one token."""
+        return (self.block_length if self.decode and self.decode_scatter
+                and self.block_length else 1)
 
     @property
     def kv_heads(self) -> int:
@@ -407,6 +435,27 @@ class TransformerConfig:
         if self.moe_held and not 0 < self.moe_held <= self.moe_experts:
             raise ValueError(
                 f"moe_held {self.moe_held} of {self.moe_experts} experts")
+        if self.block_length < 0:
+            raise ValueError(f"block_length {self.block_length} < 0")
+        if self.block_length and (
+                self.attn_window or self.diff_attn
+                or set(self.layer_attn) - {"global"}):
+            raise ValueError(
+                "block_length > 0 (block-causal attention) is written for a "
+                "stack of plain global attention layers: no window, no "
+                "differential attention, no recurrent, latent or borrowing "
+                f"layer (layer_attn {self.layer_attn!r}, attn_window "
+                f"{self.attn_window})")
+        if self.block_length:
+            if self.block_remasking not in ("static", "dynamic"):
+                raise ValueError(f"block_remasking {self.block_remasking!r} "
+                                 f"is neither static nor dynamic")
+            if not 0 <= self.block_mask_id < self.vocab_size:
+                raise ValueError(f"block_mask_id {self.block_mask_id} is no "
+                                 f"row of the vocabulary ({self.vocab_size})")
+            if not 0 <= self.block_steps <= self.block_length:
+                raise ValueError(f"block_steps {self.block_steps} outside "
+                                 f"1..{self.block_length}")
 
 
 def _layer_matmul_params(cfg: TransformerConfig, experts: int,
@@ -1373,6 +1422,27 @@ class Block(nn.Module):
             return dot_product_attention(q, k, v, causal=True, impl="dense",
                                          sm_scale=cfg.sm_scale)
         idx = ci.value                                    # [B]
+        if L > 1 and L == cfg.pass_tokens and decode_attention.block_applies(
+                L, cfg.mesh, cfg.max_len, cfg.dtype):
+            # a block pass on the chip: the block's L rows through one
+            # aliased tile of each slab, then the one-token kernel's walk
+            # with L * G query rows a KV head.  No query of the block is
+            # masked from another, so no mask enters the kernel
+            live = (jnp.ones((B,), bool) if token_mask is None
+                    else token_mask[:, 0])
+            G = H // Hk
+            with jax.named_scope("attn/block_pass"):
+                ck.value, cv.value = decode_attention.block_append(
+                    ck.value, cv.value, k, v, idx, live)
+                ci.value = idx + L
+                lengths = jnp.where(live, jnp.minimum(idx + L, cfg.max_len),
+                                    0)
+                out = decode_attention.decode_attend(
+                    q.reshape(B, L, Hk, G, Dh).transpose(0, 2, 1, 3, 4)
+                    .reshape(B, Hk * L * G, Dh), ck.value, cv.value, lengths,
+                    scale=cfg.softmax_scale, name="block_attend")
+            return (out.reshape(B, Hk, L, G, Dh).transpose(0, 2, 1, 3, 4)
+                    .reshape(B, L, H, Dh))
         if decode_attention.applies(L, cfg.mesh, cfg.max_len):
             live = (jnp.ones((B,), bool) if token_mask is None
                     else token_mask[:, 0])
@@ -1414,6 +1484,9 @@ class Block(nn.Module):
                 (0, 0, idx[0], 0))
         ci.value = idx + L
         q_pos = idx[:, None] + jnp.arange(L)              # [B, L]
+        if cfg.block_length:
+            # block-causal: a query sees up to its own block's last row
+            q_pos = (q_pos // cfg.block_length + 1) * cfg.block_length - 1
         mask = (jnp.arange(cfg.max_len)[None, None, :]
                 <= q_pos[:, :, None])                     # [B, L, max]
         return self._masked_attention(q, ck.value, cv.value, mask,
@@ -1503,6 +1576,14 @@ class Block(nn.Module):
                 attn = self._ring_attention(q, k, v, token_mask)
             elif cfg.decode:
                 attn = self._decode_attention(q, k, v, token_mask)
+            elif cfg.block_length:
+                # block-causal over the call's own positions: the dense
+                # path under the mask (the kernels are causal)
+                blk = positions // cfg.block_length           # [B, L]
+                attn = dot_product_attention(
+                    q, k, v, causal=False, impl="dense",
+                    mask=(blk[:, None, None, :] <= blk[:, None, :, None]),
+                    sm_scale=cfg.sm_scale)
             else:
                 # GQA is handled by the dispatch: dense attends grouped
                 # K/V without materialising repeats; kernels expand inside
@@ -1651,6 +1732,7 @@ class Block(nn.Module):
                             routed_scale=cfg.moe_routed_scale,
                             shared_dim=cfg.moe_shared_dim,
                             held=cfg.moe_held, mesh=cfg.mesh,
+                            pass_tokens=cfg.pass_tokens,
                             name="moe")(y, token_mask)
             out = (_pin(cfg, _residual(cfg, x, self._post_mlp(y)),
                         "batch", "seq", None), aux)

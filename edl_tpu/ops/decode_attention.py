@@ -79,6 +79,16 @@ def applies(L: int, mesh, max_len: int) -> bool:
             and _on_tpu())
 
 
+def block_applies(L: int, mesh, max_len: int, dtype) -> bool:
+    """Whether a block pass (``L`` positions a slot, written at an index
+    that is a multiple of ``L``: ``TransformerConfig.block_length``)
+    takes the kernels: where a one-token step does, and the block lies
+    within one tile of each slab (``L`` divides a lane tile and the
+    values' sublane tile)."""
+    return (L > 1 and _LANES % L == 0 and _sublanes(dtype) % L == 0
+            and applies(1, mesh, max_len))
+
+
 def _sublanes(dtype) -> int:
     """Rows of one native tile: 8 at 32 bits, 16 at 16."""
     return 32 // jnp.dtype(dtype).itemsize
@@ -91,14 +101,22 @@ def _interpret(interpret) -> bool:
 # -- append ----------------------------------------------------------------
 
 def _append_kernel(idx_ref, on_ref, k_ref, v_ref, kn_ref, vn_ref,
-                   ko_ref, vo_ref, *, rows: int):
+                   ko_ref, vo_ref, *, rows: int, width: int = 1):
+    """``width`` positions from ``idx`` on (1: a token step's; more: a
+    block pass's, which lie in one tile and arrive tiled over it, so
+    that position p's column is at lane ``p % width`` of every run)."""
     b = pl.program_id(0)
     at, on = idx_ref[b], on_ref[b] != 0
+
+    def within(i, start):
+        return (i == start) if width == 1 else (
+            (i >= start) & (i < start + width))
+
     lane = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 2)
-    ko_ref[...] = jnp.where((lane == at % _LANES) & on, kn_ref[...],
+    ko_ref[...] = jnp.where(within(lane, at % _LANES) & on, kn_ref[...],
                             k_ref[...])
     row = jax.lax.broadcasted_iota(jnp.int32, v_ref.shape, 1)
-    vo_ref[...] = jnp.where((row == at % rows) & on,
+    vo_ref[...] = jnp.where(within(row, at % rows) & on,
                             jnp.broadcast_to(vn_ref[...], v_ref.shape),
                             v_ref[...])
 
@@ -112,7 +130,6 @@ def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
     ``ring`` changes the kernel's name and nothing else
     (``window_append``: a window layer's call, told apart in a trace)."""
     B, Hk, D, T = k_slab.shape
-    rows = _sublanes(v_slab.dtype)
     on = (live & (index < T)).astype(jnp.int32)
     at = jnp.clip(index, 0, T - 1).astype(jnp.int32)
     # Mosaic cannot move the [Hk, D] column onto the lane axis itself;
@@ -120,19 +137,53 @@ def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
     kn = jnp.broadcast_to(k_new.astype(k_slab.dtype)[..., None],
                           (B, Hk, D, _LANES))
     vn = v_new.astype(v_slab.dtype)[:, :, None, :]
+    return _append(k_slab, v_slab, kn, vn, at, on, 1,
+                   "window_append" if ring else "decode_append", interpret)
+
+
+def block_append(k_slab, v_slab, k_new, v_new, index, live, *,
+                 interpret=None):
+    """:func:`decode_append` for a block pass (``block_append``): write
+    ``k_new`` / ``v_new`` ``[B, L, Hk, D]`` at positions ``index[b] ..
+    index[b] + L - 1`` of slot b's slabs, in place.  ``index[b]`` is a
+    multiple of ``L`` and ``L`` divides both tiles
+    (:func:`block_applies`), so the block lies in one tile of each slab.
+    Slots with ``live[b]`` false, or whose block would reach past
+    ``max_len``, are rewritten with what they held."""
+    B, Hk, D, T = k_slab.shape
+    L = k_new.shape[1]
+    rows = _sublanes(v_slab.dtype)
+    on = (live & (index + L <= T)).astype(jnp.int32)
+    at = jnp.clip(index, 0, T - L).astype(jnp.int32)
+    # the block tiled over the tile: position p's column at every lane
+    # (row) congruent to p, of which the kernel keeps the run at `at`
+    kn = jnp.tile(k_new.astype(k_slab.dtype).transpose(0, 2, 3, 1),
+                  (1, 1, 1, _LANES // L))
+    vn = jnp.tile(v_new.astype(v_slab.dtype).transpose(0, 2, 1, 3),
+                  (1, 1, rows // L, 1))
+    return _append(k_slab, v_slab, kn, vn, at, on, L, "block_append",
+                   interpret)
+
+
+def _append(k_slab, v_slab, kn, vn, at, on, width: int, name: str,
+            interpret):
+    """The append ``pallas_call``: ``kn [B, Hk, D, 128]`` and ``vn [B,
+    Hk, 1 or rows, D]`` through the tiles ``at`` names."""
+    _, Hk, D, _ = k_slab.shape
+    rows = _sublanes(v_slab.dtype)
     k_spec = pl.BlockSpec((None, Hk, D, _LANES),
                           lambda b, at, on: (b, 0, 0, at[b] // _LANES))
     v_spec = pl.BlockSpec((None, Hk, rows, D),
                           lambda b, at, on: (b, 0, at[b] // rows, 0))
     return pl.pallas_call(
-        functools.partial(_append_kernel, rows=rows),
+        functools.partial(_append_kernel, rows=rows, width=width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B,),
+            num_scalar_prefetch=2, grid=(k_slab.shape[0],),
             in_specs=[
                 k_spec, v_spec,
                 pl.BlockSpec((None, Hk, D, _LANES),
                              lambda b, at, on: (b, 0, 0, 0)),
-                pl.BlockSpec((None, Hk, 1, D),
+                pl.BlockSpec((None, Hk, vn.shape[2], D),
                              lambda b, at, on: (b, 0, 0, 0))],
             out_specs=[k_spec, v_spec]),
         out_shape=[jax.ShapeDtypeStruct(k_slab.shape, k_slab.dtype),
@@ -142,7 +193,7 @@ def decode_append(k_slab, v_slab, k_new, v_new, index, live, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(interpret),
-        name="window_append" if ring else "decode_append",
+        name=name,
     )(at, on, k_slab, v_slab, kn, vn)
 
 
@@ -314,7 +365,7 @@ def tokens_fetched(lengths, Hk: int, D: int, max_len: int, dtype,
 
 def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
                   block: int | None = None, interpret=None,
-                  scale: float | None = None):
+                  scale: float | None = None, name: str | None = None):
     """Attention of one query per head, ``q [B, H, D]``, against the
     first ``lengths[b]`` positions of slot b's slabs; ``[B, H, D]`` in
     ``q``'s dtype.  Query head h reads KV head ``h // (H // Hk)``.  A
@@ -325,21 +376,26 @@ def decode_attend(q, k_slab, v_slab, lengths, *, newest=None, visible=None,
     slot b attends the ``visible[b]`` slots that end, wrapping, at ring
     slot ``newest[b]``; ``lengths[b]`` is then how many ring slots have
     been written at all (what is fetched; 0 = a free slot), and the
-    kernel is named ``window_attend``."""
+    kernel is named ``window_attend``.  ``name`` names the kernel
+    otherwise (a block pass's call, ``block_attend``: ``q`` then holds a
+    KV head's ``L * G`` query rows side by side, all against one key
+    set)."""
     assert (newest is None) == (visible is None)
     _, Hk, D, T = k_slab.shape
     tk = block or attend_block(Hk, D, T, k_slab.dtype)
     assert T % tk == 0, (T, tk)
     return _attend(q, k_slab, v_slab, lengths, newest, visible, tk,
                    _interpret(interpret),
-                   D ** -0.5 if scale is None else scale)
+                   D ** -0.5 if scale is None else scale,
+                   name or ("decode_attend" if newest is None
+                            else "window_attend"))
 
 
 # a program's layers are alike: jitted, the kernel is traced and lowered
 # once a program, not once a layer (ops/moe._decode_gmm)
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
 def _attend(q, k_slab, v_slab, lengths, newest, visible, tk: int,
-            interpret: bool, scale: float):
+            interpret: bool, scale: float, name: str):
     ring = newest is not None
     B, H, D = q.shape
     _, Hk, _, T = k_slab.shape
@@ -371,6 +427,6 @@ def _attend(q, k_slab, v_slab, lengths, newest, visible, tk: int,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-        name="window_attend" if ring else "decode_attend",
+        name=name,
     )(lengths, *_work_list(lengths, tk, T // tk), *extra, qg, k_slab, v_slab)
     return out[:, :, :G].reshape(B, H, D)

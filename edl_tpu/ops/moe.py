@@ -687,6 +687,10 @@ class MoEMLP(nn.Module):
     # the mesh the caller's arrays are sharded over, if any: what
     # :func:`applies` reads beside the call's shape
     mesh: Any = None
+    # positions a slot a decode-shaped call has: 1, or the block of a
+    # block pass (``TransformerConfig.pass_tokens``), whose ``B x S``
+    # tokens are as few rows an expert as a token step's
+    pass_tokens: int = 1
 
     @nn.compact
     def __call__(self, x, token_mask=None):
@@ -736,8 +740,9 @@ class MoEMLP(nn.Module):
                     gates = gates * self.routed_scale
             valid = None if token_mask is None else token_mask.reshape(B * S)
             xt = x.reshape(B * S, M).astype(dtype)
-            kernel = self.decode and applies(S, self.mesh, B * S * self.top_k,
-                                             M, dtype)
+            kernel = self.decode and applies(
+                1 if S == self.pass_tokens else S, self.mesh,
+                B * S * self.top_k, M, dtype)
             prefix = None if S == 1 else prefix_rows(
                 B * S, self.top_k, self.held, E, M, self.mlp_dim, dtype,
                 self.gated, self.mesh, self.decode)

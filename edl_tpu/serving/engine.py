@@ -43,7 +43,19 @@ pool of ``slots`` decode lanes over ONE persistent KV cache:
   k+1 tokens per target dispatch;
 - a finished slot (token budget or ``eos_id``) frees when the host
   reads its last token and the next queued request takes it in the
-  next tick — no convoy behind the longest generation in a batch.
+  next tick — no convoy behind the longest generation in a batch;
+- **generation by diffusion over blocks** (``cfg.block_length = L >
+  0``): a dispatch is ``steps_per_sync`` PASSES (``_pass_impl``), not
+  token steps.  A slot's open block (``L`` tokens and a masked flag a
+  position, on the device) goes through the model whole each pass, over
+  the slot's committed rows; a pass with a masked position unmasks some
+  (a denoise pass, whose K/V no later pass reads), a pass with none
+  COMMITS: the index moves ``L`` rows and the block's tokens are the
+  pass's output.  So a pass yields 0 or ``L`` tokens a slot
+  (``_finish_blocks`` reads them raggedly), prompts prefill
+  ``floor(P / L) * L`` rows under the block-causal mask and hand their
+  tail to the first block, and rows enter the pool at commit only
+  (doc/serving.md, "Block passes").
 
 Per-slot independence rests on the transformer's per-example
 ``cache_index`` contract (transformer.Block._decode_attention): each
@@ -143,6 +155,18 @@ logger = get_logger(__name__)
 
 DEFAULT_PREFILL_BUCKETS = (32, 64, 128, 256, 512)
 
+# a block engine's own counters (stats(), cumulative): pass programs'
+# iterations dispatched; (slot, pass) pairs that were live in them, the
+# blocks they committed (the commit passes, a slot each) and the
+# positions they unmasked, as the programs counted them; the first
+# blocks' positions that prompts gave; tokens the commits delivered to
+# requests (blocks x L less the given positions and the cut ends).  The
+# rows the live pairs attended (a pair's committed rows and its block)
+# are ``decode_kv_tokens_live``, as a token step's are
+_BLOCK_KEYS = ("blockdiff_passes", "blockdiff_slot_passes",
+               "blockdiff_blocks_committed", "blockdiff_tokens_unmasked",
+               "blockdiff_given_tokens", "blockdiff_tokens_delivered")
+
 # the engine thread's time, tiled (module docstring); idle_wait is the
 # time between ticks, kv_commit nests in finish
 TICK_PHASES = ("idle_wait", "tasks", "admit", "dispatch", "sync", "finish",
@@ -216,6 +240,13 @@ class _Slot:
     # decode tokens no dispatched program covers yet: what the host
     # schedules from.  A slot is live in the next step iff owed > 0
     owed: int = 0
+    # block passes: tokens of the committed blocks (whole: what the
+    # slot's committed rows hold past the prefill), first-block
+    # positions that were the prompt's, and masked positions of the open
+    # block as the host schedules them (static remasking)
+    blocks: list[int] = dataclasses.field(default_factory=list)
+    skip: int = 0
+    masked: int = 0
 
     @property
     def free(self) -> bool:
@@ -225,11 +256,17 @@ class _Slot:
 class _Request:
     __slots__ = ("ids", "max_new", "future", "session", "ctx", "skipped",
                  "snap", "cut", "t_submit", "t_admit", "t_first", "t_done",
-                 "lane", "chunks", "cause", "t_mark", "waits")
+                 "lane", "chunks", "cause", "t_mark", "waits", "given",
+                 "n_first")
 
     def __init__(self, ids: np.ndarray, max_new: int,
                  session: str | None = None):
         self.ids = ids
+        # block passes: the prompt's tail that opens the first block
+        # (``ids`` is then the rows prefilled), and the tokens the first
+        # read delivered (a token step's first token is one)
+        self.given = ids[:0]
+        self.n_first = 1
         self.max_new = max_new
         self.session = session
         self.future: Future = Future()
@@ -289,6 +326,7 @@ class _Tick:
     dec: object = None
     counters: object = None       # the step's sown_vector
     counts: object = None
+    passes: object = None         # a pass program's own counts
     pres: list = dataclasses.field(default_factory=list)
     # programs enqueued up to the one this tick's read waits for: read,
     # they have all run (the device runs them in order)
@@ -374,6 +412,9 @@ class ContinuousBatcher:
         # the distinct classes, in layer order
         kinds = self._kinds = list(dict.fromkeys(self._classes.values()))
         k = constants.SPEC_K if spec_k is None else int(spec_k)
+        self._block = L = int(cfg.block_length)
+        if L:
+            self._init_block(L, k, mesh, cache_len, kv_block, prefill_chunk)
         for cls in kinds:
             if k > 0 and cls.no_rewind:
                 raise ValueError(
@@ -404,6 +445,10 @@ class ContinuousBatcher:
                      // lanes) * lanes
         self._dcfg = dataclasses.replace(dcfg, window_ring=ring)
         self._model = TransformerLM(self._dcfg)
+        # the pass model scatters a slot's block at the slot's own index
+        # (TransformerConfig.pass_tokens)
+        self._pmodel = (TransformerLM(dataclasses.replace(
+            self._dcfg, decode_scatter=True)) if self._block else None)
         # the last-position cut: a stack whose last layers keep nothing
         # a position runs them at the one row a lane a multi-token
         # program samples at, and not at all in a chunk that samples
@@ -474,7 +519,8 @@ class ContinuousBatcher:
         # last token per slot, ON THE DEVICE: each step returns it, each
         # insert places an admission's first token in it, the next step
         # takes it.  The host never reads it (_tick)
-        self._toks = jnp.zeros((slots,), jnp.int32)
+        self._toks = (self._block_state(slots) if self._block
+                      else jnp.zeros((slots,), jnp.int32))
         if mesh is not None:
             self._toks = jax.device_put(self._toks, self._rep)
         # -- paged KV block pool + prefix-reuse index (kv_cache.py) --
@@ -579,7 +625,14 @@ class ContinuousBatcher:
                                  out_shardings=(sh, rep, rep, rep))
         self._insert_jit = jax.jit(self._insert_impl, donate_argnums=(0,),
                                    out_shardings=(sh, rep))
+        if self._block:
+            self._pass_jit = jax.jit(self._pass_impl, donate_argnums=(0,))
         # -- speculative decoding (draft-k / verify-once rounds) --
+        # a tick whose progress only the device knows is read before the
+        # next is enqueued: a speculative round, a pass under dynamic
+        # remasking (_tick)
+        self._reads_own = k > 0 or (self._block > 0
+                                    and self._remasking == "dynamic")
         self._spec_k = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -653,6 +706,11 @@ class ContinuousBatcher:
                 f"prompt {len(ids)} + new {max_new_tokens} exceeds "
                 f"max_len {cache_len}")
         req = _Request(ids, max_new_tokens, session)
+        if self._block:
+            # whole blocks of the prompt are prefilled; what is left
+            # over opens the first block as given tokens
+            P0 = len(ids) // self._block * self._block
+            req.ids, req.given = ids[:P0], ids[P0:]
         with self._enqueue_lock:
             if self._stopping:
                 raise RuntimeError("engine stopping")
@@ -764,12 +822,14 @@ class ContinuousBatcher:
                 # the snapshot an admission takes of its state layers
                 self._kv.store_state(snap, 0, 0)
             # lower+compile only: executing would donate the live cache
+            first = self._block_state(K) if self._block else toks
             self._insert_jit.lower(self._cache, self._toks, slab,
                                    jnp.zeros((K,), jnp.int32),
-                                   lens, toks).compile()
+                                   lens, first).compile()
             jax.block_until_ready(toks)
-        self._step_jit.lower(self._cache, self._toks, key,
-                             self._params, self._live_mask([])).compile()
+        (self._pass_jit if self._block else self._step_jit).lower(
+            self._cache, self._toks, key, self._params,
+            self._live_mask([])).compile()
         if self._chunk_tokens:
             # the program every chunked admission starts with, whatever
             # prompt class this call warms
@@ -887,6 +947,7 @@ class ContinuousBatcher:
                 **self._tick_stats(),
                 **self._kv_stats(),
                 **self._spec_stats(),
+                **self._block_stats(),
             }
 
     def _tick_stats(self) -> dict:
@@ -1114,7 +1175,7 @@ class ContinuousBatcher:
 
         # the head's float32 logits: every row of a lane, or under the
         # last-position cut the one it samples at
-        rows = 1 if self._cut else p_max
+        rows = 0 if self._block else 1 if self._cut else p_max
         for i, k_max in enumerate(self.PREFILL_KS):
             prefill = k_max * (lane + scan + 4 * rows * self.cfg.vocab_size
                                + scores(k_max))
@@ -1190,6 +1251,17 @@ class ContinuousBatcher:
                 return mut.get("intermediates", {})
             return jax.eval_shape(call, self._params)
 
+        if self._block:       # the step is a pass, of the pass model
+            def a_pass(params):
+                ids = jnp.zeros((slots, self._block), jnp.int32)
+                _, mut = self._pmodel.apply(
+                    {"params": params,
+                     "cache": _zeros_of(self._cache_shapes(slots))},
+                    ids, positions=ids, token_mask=ids == 0,
+                    mutable=["cache", "intermediates"])
+                return mut.get("intermediates", {})
+            return sown_layout(jax.eval_shape(a_pass, self._params),
+                               sown(1, self._buckets[0]))
         return sown_layout(sown(slots, 1), sown(1, self._buckets[0]))
 
     def _zeros(self, key: tuple, shapes, shardings):
@@ -1257,7 +1329,7 @@ class ContinuousBatcher:
             # position; the pad queries wrote kv past true_len, which
             # insertion resets (cache_index := true_len) and masks
             # never reach
-            toks = self._sample(self._last_row(logits, true_lens - 1), key)
+            toks = self._first(logits, true_lens - 1, key)
             return (mut["cache"], toks, self._sown(mut), self._snap_of(mut))
 
         return jax.jit(prefill)
@@ -1266,7 +1338,18 @@ class ContinuousBatcher:
         """What a multi-token program that samples at row ``at`` [lanes]
         of its call hands the model beside its tokens: under the
         last-position cut that row, else nothing."""
+        if self._block:       # a block engine's prefill samples nothing
+            return {"return_hidden": True}
         return {"last_at": at} if self._cut else {}
+
+    def _first(self, logits, at, key):
+        """``[lanes]``: the first token a multi-token program samples
+        for each lane, at row ``at`` of the call.  A block engine's
+        first tokens come out of the first block's passes: zeros, which
+        nothing reads."""
+        if self._block:
+            return jnp.zeros((logits.shape[0],), jnp.int32)
+        return self._sample(self._last_row(logits, at), key)
 
     def _last_row(self, logits, at):
         """``[lanes, vocab]``: each lane's logits at row ``at`` of the
@@ -1318,9 +1401,10 @@ class ContinuousBatcher:
         """:meth:`_place` the admission's slab, and its ``first``
         sampled tokens ([K]) into the slots' entries of ``toks``, the
         vector the next step feeds: the tokens never visit the host on
-        their way there."""
+        their way there.  (A block engine's ``toks`` is the slots'
+        block state, a tree; ``first`` the admissions' rows of it.)"""
         return (ContinuousBatcher._place(cache, slab, slots, true_lens),
-                toks.at[slots].set(first))
+                jax.tree.map(lambda t, f: t.at[slots].set(f), toks, first))
 
     def _step_impl(self, cache, toks, key, params, live):
         """Advance every slot ``self._T`` tokens (one dispatch).
@@ -1366,6 +1450,248 @@ class ContinuousBatcher:
             if leaf.ndim == 1:
                 return leaf
         raise AssertionError("no cache_index leaf found")
+
+    # -- generation by diffusion over blocks ----------------------------------
+    def _init_block(self, L: int, spec_k: int, mesh, cache_len: int,
+                    kv_block: int, prefill_chunk) -> None:
+        """What a block engine (``cfg.block_length = L``) refuses, and
+        its unmask rule (the configuration's ``block_steps``,
+        ``block_remasking``, ``block_threshold``, ``block_mask_id``)."""
+        if spec_k > 0:
+            raise ValueError(
+                f"speculative decoding (spec_k > 0) does not serve a "
+                f"block_length = {L} configuration: a pass yields a whole "
+                f"block or nothing, there is no next token for a draft to "
+                f"guess and no rewind of a committed block")
+        if mesh is not None:
+            raise ValueError(
+                f"a mesh engine does not serve a block_length = {L} "
+                f"configuration: the pass program's append and attend are "
+                f"written for slabs no mesh shards")
+        chunk = (constants.PREFILL_CHUNK if prefill_chunk is None
+                 else prefill_chunk)
+        for name, n in (("max_len", cache_len), ("kv_block", kv_block),
+                        ("prefill_chunk", chunk)):
+            if n % L:
+                raise ValueError(
+                    f"block_length {L} must divide {name} ({n}): blocks, "
+                    f"pool blocks and prompt chunks start at its multiples")
+        cfg = self.cfg
+        self._remasking = cfg.block_remasking
+        self._threshold = float(cfg.block_threshold)
+        self._mask_id = int(cfg.block_mask_id)
+        # positions a denoise pass unmasks (static): a constant of the
+        # pass program until ``submit`` takes a step count a request
+        self._unmask = -(-L // (int(cfg.block_steps) or L))
+        self._blk = dict.fromkeys(_BLOCK_KEYS, 0)
+        # a dict here (set by whoever wants the unmask order: the
+        # benchmark's checks, a test) receives, under each request's
+        # future, one record a pass the request was live in: the block
+        # as the pass found it (``tok``, ``masked``), whether the pass
+        # committed it, and how many slots its dispatch had live
+        self.pass_log: dict[Future, list] | None = None
+
+    def _block_state(self, lanes: int, reqs=()):
+        """``lanes`` slots' open blocks, what the pass program carries
+        in place of a last token: ``tok [lanes, L]``, ``masked [lanes,
+        L]`` (a flag, not a comparison with the mask id: a prompt may
+        hold that id) and ``left [lanes]``, block positions the slot has
+        yet to commit (0: a free slot).  With ``reqs`` the first blocks
+        of those admissions: the prompt's tail given, the rest masked."""
+        L = self._block
+        tok = np.full((lanes, L), self._mask_id, np.int32)
+        masked = np.ones((lanes, L), bool)
+        left = np.zeros((lanes,), np.int32)
+        for i, req in enumerate(reqs):
+            r = len(req.given)
+            tok[i, :r], masked[i, :r] = req.given, False
+            left[i] = r + req.max_new
+        return {"tok": jnp.asarray(tok), "masked": jnp.asarray(masked),
+                "left": jnp.asarray(left)}
+
+    def _first_blocks(self, reqs: list):
+        with self._stats_lock:
+            self._blk["blockdiff_given_tokens"] += sum(
+                len(r.given) for r in reqs)
+        return self._block_state(len(reqs), reqs)
+
+    def _pass_impl(self, cache, state, key, params, live):
+        """``self._T`` PASSES of every live slot's open block (one
+        dispatch): what :meth:`_step_impl` is to a token step.  One
+        program for denoise and commit, the same forward in both: the
+        block's ``L`` positions (a masked one as the mask id) written at
+        the slot's index and attended over ``[0, index + L)``.  A slot
+        with a masked position unmasks ``self._unmask`` of them, those the
+        model is surest of (``static``), or every one surer than the
+        threshold and at least the surest (``dynamic``); its index
+        stays, so the pass's K/V is overwritten by the next.  A slot
+        with none COMMITS: the index moves ``L`` rows, the block's
+        tokens are the pass's output and the next block opens masked.
+        A slot is in a pass while the host says it is ``live`` and it
+        has positions ``left``: a budget that ends inside the program
+        stops its slot there.
+
+        Returns ``(cache, state, (out, flags) [T, slots, L] (each pass's
+        block as the pass FOUND it, tokens and masked flags), counts [T,
+        slots] in {0, L}, counters, passes)``: ``counters`` the layers'
+        ``_sown``, ``passes`` what the program counted itself, ``[live
+        (slot, pass) pairs, commits, positions unmasked]``."""
+        L = self._block
+
+        def one(carry, k):
+            cache, st, acc, cnt = carry
+            idx = self._positions(cache)
+            on = live & (st["left"] > 0)
+            masked = st["masked"]
+            commit = on & ~masked.any(axis=1)
+            logits, mut = self._pass_forward(params, cache, st["tok"],
+                                             masked, on)
+            with jax.named_scope("block_unmask"):
+                x0 = self._sample(logits.reshape(-1, logits.shape[-1]),
+                                  k).reshape(masked.shape)
+                # softmax(logits)[x0], without the softmax's array
+                conf = jnp.exp(
+                    jnp.take_along_axis(logits, x0[..., None], -1)[..., 0]
+                    - jax.nn.logsumexp(logits, axis=-1))
+                conf = jnp.where(masked, conf, -1.0)
+                order = jnp.argsort(-conf, axis=1, stable=True)
+                rank = jnp.argsort(order, axis=1, stable=True)
+                if self._remasking == "dynamic":
+                    chosen = (conf > self._threshold) | (rank == 0)
+                else:
+                    chosen = rank < self._unmask
+                chosen &= masked & (on & ~commit)[:, None]
+            new_idx = jnp.where(commit, idx + L, idx)
+            cache = jax.tree.map(
+                lambda leaf: new_idx if leaf.ndim == 1 else leaf,
+                mut["cache"])
+            nxt = {
+                "tok": jnp.where(commit[:, None], self._mask_id,
+                                 jnp.where(chosen, x0, st["tok"])),
+                "masked": jnp.where(commit[:, None], True, masked & ~chosen),
+                "left": jnp.where(commit, st["left"] - L, st["left"])}
+            cnt = cnt + jnp.stack([on.sum(), commit.sum(),
+                                   chosen.sum()]).astype(jnp.float32)
+            return ((cache, nxt, acc + self._sown(mut), cnt),
+                    (st["tok"], masked, jnp.where(commit, L, 0)))
+
+        (cache, state, acc, cnt), (out, flags, counts) = jax.lax.scan(
+            one, (cache, state, _zeros_of(self._acc_shape),
+                  jnp.zeros((3,), jnp.float32)),
+            jax.random.split(key, self._T))
+        return cache, state, (out, flags), counts, acc, cnt
+
+    def _pass_forward(self, params, cache, tok, masked, on):
+        """The forward of one pass: ``tok [lanes, L]`` (a ``masked``
+        position as the mask id) written at each lane's index and
+        attended over ``[0, index + L)``, for the lanes ``on``.
+        ``(logits [lanes, L, V], the mutated collections)``; the
+        caches' indices come back ``L`` further, for the caller to set
+        (``_pass_impl`` moves them where a block commits)."""
+        L = self._block
+        with jax.named_scope("block_pass"):
+            return self._pmodel.apply(
+                {"params": params, "cache": cache},
+                jnp.where(masked, self._mask_id, tok),
+                positions=(self._positions(cache)[:, None]
+                           + jnp.arange(L)[None, :]),
+                token_mask=jnp.broadcast_to(on[:, None], masked.shape),
+                mutable=["cache", "intermediates"])
+
+    def _schedule_passes(self, s: "_Slot") -> None:
+        """The budget of a slot the dispatch just covered, after it: the
+        host runs the static unmask rule ahead of the device
+        (``_pass_impl``: a block with ``m`` masked positions takes
+        ``ceil(m / unmask)`` denoise passes and a commit), so the next
+        tick knows who is live without a read."""
+        left, m, L = s.owed, s.masked, self._block
+        for _ in range(self._T):
+            if left <= 0:
+                break
+            if m > 0:
+                m -= min(self._unmask, m)
+            else:
+                left, m = left - L, L
+        s.owed, s.masked = max(left, 0), m
+
+    def _finish_blocks(self, out: np.ndarray, counts: np.ndarray,
+                       live: list, sown: np.ndarray,
+                       passes: np.ndarray) -> None:
+        """Consume one dispatch of passes: ``out [T, slots, L]`` with
+        ``counts[t, i]`` in ``{0, L}``, the block slot ``i`` committed
+        in pass ``t``.  :meth:`_finish_decode`'s contract, ragged as
+        :meth:`_finish_spec`'s: of the first block the positions the
+        prompt gave are not the answer's, and the last block is cut at
+        the request's budget.  A lane step here is a (slot, pass)."""
+        T, L = counts.shape[0], self._block
+        out, flags = out
+        mine = [(i, self._slots[i]) for i, req in live
+                if self._slots[i].request is req]
+        pairs, commits, unmasked = (int(v) for v in passes)
+        # pass t read a live slot up to its open block's end: the rows
+        # prefilled, the blocks committed before it, the block (the
+        # pass after the last commit of a budget finds a dead slot)
+        held, logs = [], self.pass_log      # read once: its owner may
+        for i, s in mine:                   # switch it off meanwhile
+            rows = len(s.request.ids) + len(s.blocks)
+            budget = s.skip + s.remaining
+            log = None if logs is None else logs.setdefault(
+                s.request.future, [])
+            for t in range(T):
+                if budget <= 0:
+                    break
+                held.append(rows + L)
+                if log is not None:
+                    log.append({"tok": out[t, i].tolist(),
+                                "masked": flags[t, i].tolist(),
+                                "commit": bool(counts[t, i]),
+                                "live": len(mine)})
+                if counts[t, i]:
+                    rows, budget = rows + L, budget - L
+        with self._stats_lock:
+            self._lane_steps += len(self._slots) * T
+            self._active_lane_steps += pairs
+            self._lookahead_discarded += (len(live) - len(mine)) * T
+            b = self._blk
+            b["blockdiff_passes"] += T
+            b["blockdiff_slot_passes"] += pairs
+            b["blockdiff_blocks_committed"] += commits
+            b["blockdiff_tokens_unmasked"] += unmasked
+            self._counters.on_decode(held, len(live), T)
+            self._counters.read(sown, pairs * L, decode=True)
+        now = time.monotonic()
+        for i, s in mine:
+            for t in range(T):
+                if not counts[t, i]:
+                    continue
+                block = out[t, i].tolist()
+                s.blocks.extend(block)
+                new, s.skip = block[s.skip:][:s.remaining], 0
+                req = s.request
+                if req.t_first is None:
+                    req.t_first, req.n_first = now, len(new)
+                    with self._stats_lock:
+                        self._stage(req, "prefill")
+                        self._stage(req, "ttft")
+                if self._eos is not None and self._eos in new:
+                    new = new[:new.index(self._eos) + 1]
+                    s.remaining = len(new)
+                s.emitted.extend(new)
+                s.remaining -= len(new)
+                with self._stats_lock:
+                    self._blk["blockdiff_tokens_delivered"] += len(new)
+                if s.remaining <= 0:
+                    self._finish(i)
+                    break
+            else:
+                if self._reads_own:
+                    # how far the passes got is the device's answer
+                    s.owed = s.skip + s.remaining
+
+    def _block_stats(self) -> dict:
+        """Block-pass counters (empty for a causal configuration, so
+        stats() consumers see the plain shape unchanged)."""
+        return dict(self._blk) if self._block else {}
 
     # -- speculative decoding ------------------------------------------------
     def _draft_fresh_cache(self, B: int):
@@ -1624,7 +1950,7 @@ class ContinuousBatcher:
             pres = self._admit(bool(live))
         with led.phase("dispatch", ahead=self._enqueued - self._ran):
             tick = self._dispatch(live, pres)
-        if not self._spec_k:
+        if not self._reads_own:
             tick, self._inflight = self._inflight, tick
         self._read(tick)
 
@@ -1645,6 +1971,17 @@ class ContinuousBatcher:
                  tick.counts) = self._spec_jit(
                     self._cache, self._draft_cache, self._toks,
                     self._params, self._draft_params)
+            elif self._block:
+                self._rng, key = jax.random.split(self._rng)
+                with obs_trace.annotation("engine/block_pass",
+                                          live=len(live)):
+                    (self._cache, self._toks, tick.dec, tick.counts,
+                     tick.counters, tick.passes) = self._pass_jit(
+                        self._cache, self._toks, key, self._params,
+                        self._live_mask([i for i, _ in live]))
+                if not self._reads_own:
+                    for i, _ in live:
+                        self._schedule_passes(self._slots[i])
             else:
                 self._rng, key = jax.random.split(self._rng)
                 (self._cache, self._toks, tick.dec,
@@ -1681,12 +2018,14 @@ class ContinuousBatcher:
         # single sync point for decode + every admission: the tokens
         # and, beside them, each program's counters vector
         with led.phase("sync"):
-            dec, counters, counts, firsts = jax.device_get(
-                (tick.dec, tick.counters, tick.counts,
+            dec, counters, counts, passes, firsts = jax.device_get(
+                (tick.dec, tick.counters, tick.counts, tick.passes,
                  [(p[1], p[2]) for p in tick.pres]))
         self._ran = max(self._ran, tick.mark)
         with led.phase("finish"):
-            if counts is not None:
+            if passes is not None:
+                self._finish_blocks(dec, counts, tick.live, counters, passes)
+            elif counts is not None:
                 self._finish_spec(dec, counts, tick.live)
             elif dec is not None:
                 self._finish_decode(dec, tick.live, counters)
@@ -1704,12 +2043,22 @@ class ContinuousBatcher:
         pres: list[tuple] = []
 
         def take(pre):
+            if self._block:
+                # what the insert places beside the slab: the first
+                # blocks, the prompts' tails in them
+                pre = (pre[0], self._first_blocks(pre[4]), *pre[2:])
             pres.append(pre)
             for slot, req in zip(pre[3], pre[4]):
                 s = self._slots[slot]
                 s.request, s.emitted = req, []
                 # the prefill samples the first token; steps owe the rest
                 s.remaining, s.owed = req.max_new, req.max_new - 1
+                if self._block:
+                    # every token comes out of a pass; ``owed`` counts
+                    # block positions, the given ones with them
+                    s.blocks, s.skip = [], len(req.given)
+                    s.owed = s.skip + req.max_new
+                    s.masked = self._block - s.skip
 
         t0 = time.monotonic()
         # the slot a chunked admission holds while its request is not
@@ -2015,7 +2364,7 @@ class ContinuousBatcher:
                 token_mask=jnp.arange(P)[None, :] < rel_lens[:, None],
                 snap_at=snap_at, mutable=_PREFILL_MUTABLE,
                 **self._last(rel_lens - 1))
-            toks = self._sample(self._last_row(logits, rel_lens - 1), key)
+            toks = self._first(logits, rel_lens - 1, key)
             return (mut["cache"], toks, sown_in + self._sown(mut),
                     self._snap_of(mut))
 
@@ -2159,7 +2508,7 @@ class ContinuousBatcher:
                 token_mask=jnp.arange(P)[None, :] < true_lens[:, None],
                 mutable=["cache", "intermediates"],
                 **self._last(true_lens - 1))
-            toks = self._sample(self._last_row(logits, true_lens - 1), key)
+            toks = self._first(logits, true_lens - 1, key)
             return mut["cache"], toks, self._sown(mut), None
 
         return jax.jit(prefill)
@@ -2185,12 +2534,14 @@ class ContinuousBatcher:
         one's, all its chunks)."""
         now = time.monotonic()
         with self._stats_lock:
-            for req in reqs:
+            for req in reqs if not self._block else ():
                 req.t_first = now     # its first token is on the host
                 self._stage(req, "prefill")
                 self._stage(req, "ttft")
             self._counters.read(
                 sown, sum(len(r.ids) - r.skipped for r in reqs), decode=False)
+        if self._block:       # the first block's commit brings the first
+            return
         for slot, tok in zip(slots, toks.tolist()):
             s = self._slots[slot]         # the request's since _admit
             s.emitted = [tok]
@@ -2282,15 +2633,17 @@ class ContinuousBatcher:
         req.t_done = time.monotonic()
         n_out = len(out)
         decode_s = req.stage_s("decode")
-        # a one-token answer has no gap between tokens
-        if n_out > 1:
-            _INTERTOKEN_SECONDS.observe(decode_s / (n_out - 1))
+        # an answer whose first read brought all of it (one token; one
+        # block) has no gap between tokens
+        later = n_out - req.n_first
+        if later > 0:
+            _INTERTOKEN_SECONDS.observe(decode_s / later)
         with self._stats_lock:
             self._done_requests += 1
             self._emitted_tokens += n_out
             self._stage(req, "decode")
-            if n_out > 1:
-                self._decode_tokens += n_out - 1
+            if later > 0:
+                self._decode_tokens += later
                 self._decode_s += decode_s
         s.request, s.owed = None, 0
         s.emitted = []
@@ -2324,7 +2677,7 @@ class ContinuousBatcher:
             **{k: round(v, 6) for k, v in tiled.items()},
             lane=req.lane, chunks=req.chunks,
             **{f"wait_{c}": round(s, 6) for c, s in req.waits.items()},
-            n_prompt=len(req.ids), n_out=n_out,
+            n_prompt=len(req.ids) + len(req.given), n_out=n_out,
             prefix_tokens_skipped=req.skipped, **ids)
 
     def _kv_commit(self, slot: int, req: "_Request",
@@ -2337,6 +2690,11 @@ class ContinuousBatcher:
         re-embedded; its KV does not exist)."""
         seq = np.concatenate([req.ids,
                               np.asarray(emitted[:-1], np.int32)])
+        if self._block:
+            # rows enter a slot at a commit pass only: the prefilled rows
+            # and every committed block, its cut end with it
+            seq = np.concatenate([req.ids, np.asarray(
+                self._slots[slot].blocks, np.int32)])
         start_block, new_ids, tail = self._kv.commit(seq)
         sid, at = req.snap
         chain, depth = self._kv.committed, at // self._kv.block

@@ -70,6 +70,10 @@ SCOPES = {
                "mamba1/proj_out"),
     "gmu": ("gmu",),
     "cross": ("attn/cross", "attn/diff_combine"),
+    # not a mixer kind: a block engine's pass program (block_length > 0)
+    # and, on the chip, its kernels' names
+    "block": ("block_pass", "attn/block_pass", "block_unmask",
+              "block_append", "block_attend"),
 }
 
 # every model-counter key of stats(), in its order; 0.0: a float sum
