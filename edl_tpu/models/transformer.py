@@ -128,10 +128,13 @@ class TransformerConfig:
     # j // block_length <= i // block_length (block-causal; 0 = causal,
     # every program what it was).  The mask is over ABSOLUTE positions in
     # every forward; with ``decode_scatter`` a decode model's call of
-    # block_length positions is a PASS over a slot's open block: it
-    # writes the block's rows at the slot's index, every query reads
-    # [0, index + block_length), and whoever drives it (serving/
-    # engine.py) moves the index only where the block commits
+    # 2 * block_length positions is a PASS over a slot's blocks
+    # (``Block._pass_attention``): the finished block a slot commits, if
+    # any, at the slot's index, and its open block at the index or behind
+    # the committed one; every query reads up to its own block's end, the
+    # logits are the open block's, and the index moves only where a block
+    # commits (serving/engine.py drives it); a call of block_length
+    # positions is a pass of the open block alone
     block_length: int = 0
     # the generation loop a block model is published with, beside its
     # block length (the model does not read them; the serving engine
@@ -271,10 +274,16 @@ class TransformerConfig:
     @property
     def pass_tokens(self) -> int:
         """Positions a slot a decode-shaped call of this model has: the
-        block of a block pass (``block_length`` with ``decode_scatter``),
-        else one token."""
-        return (self.block_length if self.decode and self.decode_scatter
+        two blocks of a block pass (``block_length`` with
+        ``decode_scatter``: a commit half and an open half), else one
+        token."""
+        return (2 * self.block_length if self.decode and self.decode_scatter
                 and self.block_length else 1)
+
+    def is_pass(self, positions: int) -> bool:
+        """Whether a call of ``positions`` a slot is a block pass: of
+        both halves, or of the open half alone (no slot commits)."""
+        return 1 < self.pass_tokens in (positions, 2 * positions)
 
     @property
     def kv_heads(self) -> int:
@@ -1422,27 +1431,8 @@ class Block(nn.Module):
             return dot_product_attention(q, k, v, causal=True, impl="dense",
                                          sm_scale=cfg.sm_scale)
         idx = ci.value                                    # [B]
-        if L > 1 and L == cfg.pass_tokens and decode_attention.block_applies(
-                L, cfg.mesh, cfg.max_len, cfg.dtype):
-            # a block pass on the chip: the block's L rows through one
-            # aliased tile of each slab, then the one-token kernel's walk
-            # with L * G query rows a KV head.  No query of the block is
-            # masked from another, so no mask enters the kernel
-            live = (jnp.ones((B,), bool) if token_mask is None
-                    else token_mask[:, 0])
-            G = H // Hk
-            with jax.named_scope("attn/block_pass"):
-                ck.value, cv.value = decode_attention.block_append(
-                    ck.value, cv.value, k, v, idx, live)
-                ci.value = idx + L
-                lengths = jnp.where(live, jnp.minimum(idx + L, cfg.max_len),
-                                    0)
-                out = decode_attention.decode_attend(
-                    q.reshape(B, L, Hk, G, Dh).transpose(0, 2, 1, 3, 4)
-                    .reshape(B, Hk * L * G, Dh), ck.value, cv.value, lengths,
-                    scale=cfg.softmax_scale, name="block_attend")
-            return (out.reshape(B, Hk, L, G, Dh).transpose(0, 2, 1, 3, 4)
-                    .reshape(B, L, H, Dh))
+        if cfg.is_pass(L):
+            return self._pass_attention(q, k, v, token_mask, ck, cv, ci)
         if decode_attention.applies(L, cfg.mesh, cfg.max_len):
             live = (jnp.ones((B,), bool) if token_mask is None
                     else token_mask[:, 0])
@@ -1489,6 +1479,77 @@ class Block(nn.Module):
             q_pos = (q_pos // cfg.block_length + 1) * cfg.block_length - 1
         mask = (jnp.arange(cfg.max_len)[None, None, :]
                 <= q_pos[:, :, None])                     # [B, L, max]
+        return self._masked_attention(q, ck.value, cv.value, mask,
+                                      cfg.softmax_scale)
+
+    def _pass_attention(self, q, k, v, token_mask, ck, cv, ci):
+        """A block pass: ``2 L`` rows a slot (``L = cfg.block_length``),
+        a COMMIT half and an OPEN half, each live or dead as a whole
+        (``token_mask``'s first row of the half), or ``L`` rows, the
+        open half alone (a pass in which no slot commits: the commit
+        half's kernels and rows are not in the program at all, where a
+        dead half still costs its calls).  The commit half, live
+        in a slot that commits in this pass, is the slot's finished
+        block, written at the index and attended over ``[0, index +
+        L)``: it never sees the open half.  The open half is the slot's
+        open block: at the index, or behind a live commit half at
+        ``index + L`` (the next block), attended up to its own end.  A
+        dead half is neither written nor read.  The index comes back at
+        the open half's first row: ``L`` further where a block
+        committed.
+
+        On the chip each half goes through the one-token kernels as a
+        pass of ``L`` rows always has: ``block_append`` (one aliased tile
+        of each slab), then the one-token kernel's walk with ``L * G``
+        query rows a KV head under the name ``block_attend``; a dead
+        half has length 0 and reads nothing.  No query of a block is
+        masked from another, so no mask enters the kernel.  Everywhere
+        else the scatter and the einsums under the block-causal mask."""
+        cfg = self.cfg
+        B, S, H, Dh = q.shape
+        Hk, L = k.shape[2], cfg.block_length
+        idx = ci.value
+        live = jnp.ones((B, S), bool) if token_mask is None else token_mask
+        # the open half is the call's last L rows
+        starts, lives = (idx,), (live[:, 0],)
+        if S == 2 * L:
+            starts += (idx + jnp.where(live[:, 0], L, 0),)
+            lives += (live[:, L],)
+        ci.value = starts[-1]
+        halves = [(slice(h * L, (h + 1) * L), at, on)
+                  for h, (at, on) in enumerate(zip(starts, lives))]
+        if decode_attention.block_applies(L, cfg.mesh, cfg.max_len,
+                                          cfg.dtype):
+            G = H // Hk
+            outs = []
+            with jax.named_scope("attn/block_pass"):
+                for rows, at, on in halves:
+                    ck.value, cv.value = decode_attention.block_append(
+                        ck.value, cv.value, k[:, rows], v[:, rows], at, on)
+                for rows, at, on in halves:
+                    lengths = jnp.where(
+                        on, jnp.minimum(at + L, cfg.max_len), 0)
+                    out = decode_attention.decode_attend(
+                        q[:, rows].reshape(B, L, Hk, G, Dh)
+                        .transpose(0, 2, 1, 3, 4).reshape(B, Hk * L * G, Dh),
+                        ck.value, cv.value, lengths,
+                        scale=cfg.softmax_scale, name="block_attend")
+                    outs.append(out.reshape(B, Hk, L, G, Dh)
+                                .transpose(0, 2, 1, 3, 4)
+                                .reshape(B, L, H, Dh))
+            return jnp.concatenate(outs, axis=1)
+        pos = jnp.concatenate([at[:, None] + jnp.arange(L)
+                               for _, at, _ in halves], axis=1)   # [B, S]
+        # a dead row lands past the slab and is dropped
+        to = jnp.where(live, pos, cfg.max_len)
+        bi = jnp.arange(B)[:, None]
+        ck.value = ck.value.at[bi, :, :, to].set(k.astype(cfg.dtype),
+                                                 mode="drop")
+        cv.value = cv.value.at[bi, :, to, :].set(v.astype(cfg.dtype),
+                                                 mode="drop")
+        # block-causal: a query sees up to its own block's last row
+        mask = (jnp.arange(cfg.max_len)[None, None, :]
+                <= ((pos // L + 1) * L - 1)[:, :, None])
         return self._masked_attention(q, ck.value, cv.value, mask,
                                       cfg.softmax_scale)
 
@@ -1804,7 +1865,13 @@ class TransformerLM(nn.Module):
         call has just written, logits ``[B, 1, V]``.  Exact: nothing
         above the tail reads another row's tail output.  ``tail=False``
         (a prefill chunk that samples nothing) leaves the tail out and
-        returns the hidden rows below it."""
+        returns the hidden rows below it.
+
+        A BLOCK PASS (a call of ``cfg.pass_tokens`` = ``2 *
+        block_length`` positions of a ``decode_scatter`` model, or of
+        ``block_length``, the open half alone: ``cfg.is_pass``,
+        ``Block._pass_attention``) returns its open half's logits, ``[B,
+        block_length, V]``: nobody samples a block that is committed."""
         cfg = self.cfg
         del train
         if positions is None:
@@ -1864,6 +1931,9 @@ class TransformerLM(nn.Module):
                             in_axes=nn.broadcast, metadata_params={},
                             unroll=1 if cfg.scan_layers else cfg.num_layers)
             x, aux = Stack(cfg, name="layers")(x, positions, token_mask)
+        if 1 < cfg.pass_tokens == x.shape[1]:
+            # a block pass of both halves samples its open half alone
+            x = x[:, cfg.block_length:]
         x = _pin(cfg, _norm(cfg, "final_norm")(x), "batch", "seq", None)
         aux_total = (jnp.mean(aux) if aux is not None
                      else jnp.zeros((), jnp.float32))

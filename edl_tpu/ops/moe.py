@@ -136,10 +136,14 @@ _WINDOWS = (16, 32, 128)
 _CHUNK_BYTES = 4 << 20
 # bytes the sorted rows may take in VMEM (rows in, rows out, both double
 # buffered, and the float32 accumulator): a call with more rows than
-# that stays on ragged_dot
-_ROW_BYTES = 24 << 20
-# what the kernel asks of VMEM: those rows, two chunks a projection and
-# the float32 hidden rows
+# that stays on ragged_dot.  The widest served call is a block pass of
+# 16 slots x 8 positions x 8 experts: 1,136 rows of 2048 = 26.6 MiB
+_ROW_BYTES = 28 << 20
+# what the kernel asks of VMEM: those rows (28 MiB at most), two chunks
+# a projection (16 MiB at most) and the float32 hidden rows (a quarter
+# of the rows' bytes where an expert's two input projections are three
+# quarters of the model's width, as in that pass: 7 MiB), so 51 MiB and
+# the matmuls' own temporaries
 _VMEM_LIMIT = 64 << 20
 # what a multi-token call's kernel (moe_prefix_gmm) asks of the chip's
 # 128 MiB instead, and what of it the rows may take beside the weight
@@ -687,9 +691,10 @@ class MoEMLP(nn.Module):
     # the mesh the caller's arrays are sharded over, if any: what
     # :func:`applies` reads beside the call's shape
     mesh: Any = None
-    # positions a slot a decode-shaped call has: 1, or the block of a
-    # block pass (``TransformerConfig.pass_tokens``), whose ``B x S``
-    # tokens are as few rows an expert as a token step's
+    # positions a slot a decode-shaped call has: 1, or the two blocks of
+    # a block pass (``TransformerConfig.pass_tokens``; half of them in a
+    # pass of the open block alone), whose ``B x S`` tokens are as few
+    # rows an expert as a token step's
     pass_tokens: int = 1
 
     @nn.compact
@@ -741,7 +746,7 @@ class MoEMLP(nn.Module):
             valid = None if token_mask is None else token_mask.reshape(B * S)
             xt = x.reshape(B * S, M).astype(dtype)
             kernel = self.decode and applies(
-                1 if S == self.pass_tokens else S, self.mesh,
+                1 if self.pass_tokens in (S, 2 * S) else S, self.mesh,
                 B * S * self.top_k, M, dtype)
             prefix = None if S == 1 else prefix_rows(
                 B * S, self.top_k, self.held, E, M, self.mlp_dim, dtype,

@@ -50,8 +50,10 @@ pool of ``slots`` decode lanes over ONE persistent KV cache:
   position, on the device) goes through the model whole each pass, over
   the slot's committed rows; a pass with a masked position unmasks some
   (a denoise pass, whose K/V no later pass reads), a pass with none
-  COMMITS: the index moves ``L`` rows and the block's tokens are the
-  pass's output.  So a pass yields 0 or ``L`` tokens a slot
+  COMMITS: the index moves ``L`` rows, the block's tokens are the
+  pass's output, and the same forward (``2 L`` rows a slot, a commit
+  half and an open half) opens the next block and unmasks its first
+  position(s).  So a pass yields 0 or ``L`` tokens a slot
   (``_finish_blocks`` reads them raggedly), prompts prefill
   ``floor(P / L) * L`` rows under the block-causal mask and hand their
   tail to the first block, and rows enter the pool at commit only
@@ -164,7 +166,8 @@ DEFAULT_PREFILL_BUCKETS = (32, 64, 128, 256, 512)
 # rows the live pairs attended (a pair's committed rows and its block)
 # are ``decode_kv_tokens_live``, as a token step's are
 _BLOCK_KEYS = ("blockdiff_passes", "blockdiff_slot_passes",
-               "blockdiff_blocks_committed", "blockdiff_tokens_unmasked",
+               "blockdiff_blocks_committed", "blockdiff_commits_fused",
+               "blockdiff_tokens_unmasked",
                "blockdiff_given_tokens", "blockdiff_tokens_delivered")
 
 # the engine thread's time, tiled (module docstring); idle_wait is the
@@ -247,6 +250,10 @@ class _Slot:
     blocks: list[int] = dataclasses.field(default_factory=list)
     skip: int = 0
     masked: int = 0
+    # passes the slot sits out before its first (``_start_wait``): as
+    # the host schedules them, and as it reads them
+    wait: int = 0
+    idle: int = 0
 
     @property
     def free(self) -> bool:
@@ -257,7 +264,7 @@ class _Request:
     __slots__ = ("ids", "max_new", "future", "session", "ctx", "skipped",
                  "snap", "cut", "t_submit", "t_admit", "t_first", "t_done",
                  "lane", "chunks", "cause", "t_mark", "waits", "given",
-                 "n_first")
+                 "n_first", "wait")
 
     def __init__(self, ids: np.ndarray, max_new: int,
                  session: str | None = None):
@@ -267,6 +274,7 @@ class _Request:
         # read delivered (a token step's first token is one)
         self.given = ids[:0]
         self.n_first = 1
+        self.wait = 0       # block passes: ``_start_wait``
         self.max_new = max_new
         self.session = session
         self.future: Future = Future()
@@ -1253,7 +1261,7 @@ class ContinuousBatcher:
 
         if self._block:       # the step is a pass, of the pass model
             def a_pass(params):
-                ids = jnp.zeros((slots, self._block), jnp.int32)
+                ids = jnp.zeros((slots, 2 * self._block), jnp.int32)
                 _, mut = self._pmodel.apply(
                     {"params": params,
                      "cache": _zeros_of(self._cache_shapes(slots))},
@@ -1483,69 +1491,119 @@ class ContinuousBatcher:
         # positions a denoise pass unmasks (static): a constant of the
         # pass program until ``submit`` takes a step count a request
         self._unmask = -(-L // (int(cfg.block_steps) or L))
+        # passes enqueued so far: the clock the slots' blocks keep step
+        # by (``_start_wait``)
+        self._passes_enqueued = 0
         self._blk = dict.fromkeys(_BLOCK_KEYS, 0)
         # a dict here (set by whoever wants the unmask order: the
         # benchmark's checks, a test) receives, under each request's
         # future, one record a pass the request was live in: the block
         # as the pass found it (``tok``, ``masked``), whether the pass
-        # committed it, and how many slots its dispatch had live
+        # committed it, how many slots its dispatch had live, and which
+        # pass of the engine's it was (``at``)
         self.pass_log: dict[Future, list] | None = None
 
     def _block_state(self, lanes: int, reqs=()):
         """``lanes`` slots' open blocks, what the pass program carries
         in place of a last token: ``tok [lanes, L]``, ``masked [lanes,
         L]`` (a flag, not a comparison with the mask id: a prompt may
-        hold that id) and ``left [lanes]``, block positions the slot has
-        yet to commit (0: a free slot).  With ``reqs`` the first blocks
-        of those admissions: the prompt's tail given, the rest masked."""
+        hold that id), ``left [lanes]``, block positions the slot has
+        yet to commit (0: a free slot), and ``wait [lanes]``, passes the
+        slot sits out before its first (``_start_wait``).  With ``reqs``
+        the first blocks of those admissions: the prompt's tail given,
+        the rest masked."""
         L = self._block
         tok = np.full((lanes, L), self._mask_id, np.int32)
         masked = np.ones((lanes, L), bool)
-        left = np.zeros((lanes,), np.int32)
+        left, wait = (np.zeros((lanes,), np.int32) for _ in range(2))
         for i, req in enumerate(reqs):
             r = len(req.given)
             tok[i, :r], masked[i, :r] = req.given, False
-            left[i] = r + req.max_new
+            left[i], wait[i] = r + req.max_new, req.wait
         return {"tok": jnp.asarray(tok), "masked": jnp.asarray(masked),
-                "left": jnp.asarray(left)}
+                "left": jnp.asarray(left), "wait": jnp.asarray(wait)}
 
-    def _first_blocks(self, reqs: list):
+    def _first_blocks(self, reqs: list, lanes_live: bool):
+        """The block state of admissions whose first pass is the next
+        dispatch's first, each with its ``_start_wait``."""
+        for r in reqs:
+            r.wait = self._start_wait(len(r.given), lanes_live)
         with self._stats_lock:
             self._blk["blockdiff_given_tokens"] += sum(
                 len(r.given) for r in reqs)
         return self._block_state(len(reqs), reqs)
 
+    def _start_wait(self, given: int, lanes_live: bool) -> int:
+        """Passes a slot admitted in this tick sits out so that its
+        commits fall in the passes every other slot's fall in: under the
+        static rule a block takes ``ceil(L / unmask)`` passes, the first
+        of which carries the commit of the block before it, and only a
+        pass in which some slot commits runs the forward of ``2 L`` rows
+        (``_pass_impl``).  With the commits in step three passes of four
+        (``L`` = steps = 4) are a forward of ``L`` rows.  The slot's
+        first pass is the first of the next dispatch, this tick's passes
+        (if a slot is live) before it; ``dynamic`` remasking keeps no
+        step and waits for nothing."""
+        if self._reads_own:
+            return 0
+        period = -(-self._block // self._unmask)
+        first = -(-(self._block - given) // self._unmask)
+        start = self._passes_enqueued + (self._T if lanes_live else 0)
+        return -(start + first) % period
+
     def _pass_impl(self, cache, state, key, params, live):
         """``self._T`` PASSES of every live slot's open block (one
         dispatch): what :meth:`_step_impl` is to a token step.  One
-        program for denoise and commit, the same forward in both: the
-        block's ``L`` positions (a masked one as the mask id) written at
-        the slot's index and attended over ``[0, index + L)``.  A slot
-        with a masked position unmasks ``self._unmask`` of them, those the
-        model is surest of (``static``), or every one surer than the
-        threshold and at least the surest (``dynamic``); its index
-        stays, so the pass's K/V is overwritten by the next.  A slot
-        with none COMMITS: the index moves ``L`` rows, the block's
-        tokens are the pass's output and the next block opens masked.
-        A slot is in a pass while the host says it is ``live`` and it
-        has positions ``left``: a budget that ends inside the program
-        stops its slot there.
+        program; a pass in which some slot commits is one forward of ``2
+        L`` rows a slot (:meth:`_pass_forward`): an OPEN half, the
+        slot's open block (a masked position as the mask id) attended
+        up to its own end, and a COMMIT half, live only in a slot that
+        commits in this pass; a pass in which none does is the open half
+        alone, ``L`` rows (a ``jax.lax.cond``: the commits of slots
+        that keep step, ``_start_wait``, fall in the same passes).  A
+        slot with a masked position unmasks ``self._unmask`` of them,
+        those the model is surest of (``static``), or every one surer
+        than the threshold and at least the surest (``dynamic``); its
+        index stays, so the pass's K/V is overwritten by the next.  A
+        slot with none COMMITS: its commit half writes the finished
+        block's rows at the index, the index moves ``L`` rows, the
+        block's tokens are the pass's output, and the SAME forward opens
+        the next block behind it, all masked, and unmasks its first
+        position(s): the commit rides the next block's first pass.
+        Where the budget ends with the commit the open half is dead.  A
+        slot is in a pass while the host says it is ``live``, it has
+        positions ``left`` (a budget that ends inside the program stops
+        its slot there) and it has sat out its ``wait``.
 
         Returns ``(cache, state, (out, flags) [T, slots, L] (each pass's
         block as the pass FOUND it, tokens and masked flags), counts [T,
         slots] in {0, L}, counters, passes)``: ``counters`` the layers'
         ``_sown``, ``passes`` what the program counted itself, ``[live
-        (slot, pass) pairs, commits, positions unmasked]``."""
+        (slot, pass) pairs, commits, positions unmasked, commits whose
+        pass also opened the next block]``."""
         L = self._block
 
         def one(carry, k):
             cache, st, acc, cnt = carry
-            idx = self._positions(cache)
-            on = live & (st["left"] > 0)
-            masked = st["masked"]
-            commit = on & ~masked.any(axis=1)
-            logits, mut = self._pass_forward(params, cache, st["tok"],
-                                             masked, on)
+            held = live & (st["left"] > 0)
+            on = held & (st["wait"] == 0)
+            commit = on & ~st["masked"].any(axis=1)
+            opens = on & ~(commit & (st["left"] <= L))
+
+            def forward(cache, on):
+                logits, mut = self._pass_forward(params, cache, st["tok"],
+                                                 st["masked"], on)
+                return logits, mut["cache"], self._sown(mut)
+
+            # both halves only in a pass in which some slot commits: a
+            # dead half still costs its rows' way through every layer
+            logits, cache, sown = jax.lax.cond(
+                commit.any(),
+                lambda c: forward(c, opens.astype(jnp.int32) + 2 * commit),
+                lambda c: forward(c, opens), cache)
+            # the open block: behind a commit the next one, all masked
+            tok = jnp.where(commit[:, None], self._mask_id, st["tok"])
+            masked = st["masked"] | commit[:, None]
             with jax.named_scope("block_unmask"):
                 x0 = self._sample(logits.reshape(-1, logits.shape[-1]),
                                   k).reshape(masked.shape)
@@ -1560,58 +1618,76 @@ class ContinuousBatcher:
                     chosen = (conf > self._threshold) | (rank == 0)
                 else:
                     chosen = rank < self._unmask
-                chosen &= masked & (on & ~commit)[:, None]
-            new_idx = jnp.where(commit, idx + L, idx)
-            cache = jax.tree.map(
-                lambda leaf: new_idx if leaf.ndim == 1 else leaf,
-                mut["cache"])
+                chosen &= masked & opens[:, None]
             nxt = {
-                "tok": jnp.where(commit[:, None], self._mask_id,
-                                 jnp.where(chosen, x0, st["tok"])),
-                "masked": jnp.where(commit[:, None], True, masked & ~chosen),
-                "left": jnp.where(commit, st["left"] - L, st["left"])}
-            cnt = cnt + jnp.stack([on.sum(), commit.sum(),
-                                   chosen.sum()]).astype(jnp.float32)
-            return ((cache, nxt, acc + self._sown(mut), cnt),
-                    (st["tok"], masked, jnp.where(commit, L, 0)))
+                "tok": jnp.where(chosen, x0, tok),
+                "masked": masked & ~chosen,
+                "left": jnp.where(commit, st["left"] - L, st["left"]),
+                "wait": jnp.where(held, jnp.maximum(st["wait"] - 1, 0),
+                                  st["wait"])}
+            cnt = cnt + jnp.stack([
+                on.sum(), commit.sum(), chosen.sum(),
+                (commit & opens).sum()]).astype(jnp.float32)
+            return ((cache, nxt, acc + sown, cnt),
+                    (st["tok"], st["masked"], jnp.where(commit, L, 0)))
 
         (cache, state, acc, cnt), (out, flags, counts) = jax.lax.scan(
             one, (cache, state, _zeros_of(self._acc_shape),
-                  jnp.zeros((3,), jnp.float32)),
+                  jnp.zeros((4,), jnp.float32)),
             jax.random.split(key, self._T))
         return cache, state, (out, flags), counts, acc, cnt
 
     def _pass_forward(self, params, cache, tok, masked, on):
-        """The forward of one pass: ``tok [lanes, L]`` (a ``masked``
-        position as the mask id) written at each lane's index and
-        attended over ``[0, index + L)``, for the lanes ``on``.
-        ``(logits [lanes, L, V], the mutated collections)``; the
-        caches' indices come back ``L`` further, for the caller to set
-        (``_pass_impl`` moves them where a block commits)."""
+        """The forward of one pass.  With ``on [lanes]`` bool, ``L``
+        rows a lane, the OPEN half alone: ``tok [lanes, L]`` (a
+        ``masked`` position as the mask id) written at each lane's
+        index and attended over ``[0, index + L)``, for the lanes
+        ``on``.  With ``on`` int, ``2 L`` rows a lane: bit 0 the open
+        half live, bit 1 the COMMIT half.  In a lane that commits
+        ``tok`` is a finished block: the commit half writes it at the
+        index and attends over ``[0, index + L)``, and the open half is
+        the NEXT block, all masked, ``L`` rows further.  ``(the open
+        half's logits [lanes, L, V], the mutated collections)``; the
+        caches' indices come back moved ``L`` where a block committed
+        (``Block._pass_attention``)."""
         L = self._block
+        idx = self._positions(cache)
+        ids = jnp.where(masked, self._mask_id, tok)
+        at, live = idx[:, None], on[:, None]
+        if on.dtype != jnp.bool_:
+            commit, on = (on & 2) > 0, (on & 1) > 0
+            ids = jnp.concatenate(
+                [tok, jnp.where(commit[:, None], self._mask_id, ids)], axis=1)
+            at = jnp.stack([idx, idx + jnp.where(commit, L, 0)], axis=1)
+            live = jnp.stack([commit, on], axis=1)
         with jax.named_scope("block_pass"):
             return self._pmodel.apply(
-                {"params": params, "cache": cache},
-                jnp.where(masked, self._mask_id, tok),
-                positions=(self._positions(cache)[:, None]
-                           + jnp.arange(L)[None, :]),
-                token_mask=jnp.broadcast_to(on[:, None], masked.shape),
+                {"params": params, "cache": cache}, ids,
+                positions=(at[:, :, None] + jnp.arange(L)).reshape(
+                    ids.shape),
+                token_mask=jnp.repeat(live, L, axis=1),
                 mutable=["cache", "intermediates"])
 
     def _schedule_passes(self, s: "_Slot") -> None:
         """The budget of a slot the dispatch just covered, after it: the
         host runs the static unmask rule ahead of the device
         (``_pass_impl``: a block with ``m`` masked positions takes
-        ``ceil(m / unmask)`` denoise passes and a commit), so the next
-        tick knows who is live without a read."""
+        ``ceil(m / unmask)`` passes, the first of which, after a
+        slot's first block, IS the commit of the block before it; the
+        last block of a budget a pass more, its commit, which opens
+        nothing), so the next tick knows who is live without a read."""
         left, m, L = s.owed, s.masked, self._block
         for _ in range(self._T):
             if left <= 0:
                 break
-            if m > 0:
-                m -= min(self._unmask, m)
-            else:
+            if s.wait:
+                s.wait -= 1
+                continue
+            if m == 0:
                 left, m = left - L, L
+                if left <= 0:
+                    break
+            m -= min(self._unmask, m)
         s.owed, s.masked = max(left, 0), m
 
     def _finish_blocks(self, out: np.ndarray, counts: np.ndarray,
@@ -1622,43 +1698,61 @@ class ContinuousBatcher:
         in pass ``t``.  :meth:`_finish_decode`'s contract, ragged as
         :meth:`_finish_spec`'s: of the first block the positions the
         prompt gave are not the answer's, and the last block is cut at
-        the request's budget.  A lane step here is a (slot, pass)."""
+        the request's budget.  A lane step here is a (slot, pass); a
+        pair whose commit also opened the next block (``fused``) put
+        TWO blocks through the model, and ``blockdiff_slot_passes``
+        counts blocks through the model: a denoise pass of a block and
+        its commit one each, whichever forward carried them."""
         T, L = counts.shape[0], self._block
         out, flags = out
         mine = [(i, self._slots[i]) for i, req in live
                 if self._slots[i].request is req]
-        pairs, commits, unmasked = (int(v) for v in passes)
+        pairs, commits, unmasked, fused = (int(v) for v in passes)
         # pass t read a live slot up to its open block's end: the rows
         # prefilled, the blocks committed before it, the block (the
-        # pass after the last commit of a budget finds a dead slot)
+        # pass after the last commit of a budget finds a dead slot); a
+        # pass that commits reads up to the finished block's end and,
+        # where budget is left, once more up to the end of the block it
+        # opens behind it
         held, logs = [], self.pass_log      # read once: its owner may
-        for i, s in mine:                   # switch it off meanwhile
+        first = self._blk["blockdiff_passes"]   # switch it off meanwhile
+        for i, s in mine:
             rows = len(s.request.ids) + len(s.blocks)
             budget = s.skip + s.remaining
             log = None if logs is None else logs.setdefault(
                 s.request.future, [])
+
+            def record(t, tok, masked, commit):
+                held.append(rows + L)
+                if log is not None:
+                    log.append({"tok": tok, "masked": masked,
+                                "commit": commit, "live": len(mine),
+                                "at": first + t})
+
             for t in range(T):
                 if budget <= 0:
                     break
-                held.append(rows + L)
-                if log is not None:
-                    log.append({"tok": out[t, i].tolist(),
-                                "masked": flags[t, i].tolist(),
-                                "commit": bool(counts[t, i]),
-                                "live": len(mine)})
+                if s.idle:          # a pass the slot sat out
+                    s.idle -= 1
+                    continue
+                record(t, out[t, i].tolist(), flags[t, i].tolist(),
+                       bool(counts[t, i]))
                 if counts[t, i]:
                     rows, budget = rows + L, budget - L
+                    if budget > 0:      # the block that forward opened
+                        record(t, [self._mask_id] * L, [True] * L, False)
         with self._stats_lock:
             self._lane_steps += len(self._slots) * T
             self._active_lane_steps += pairs
             self._lookahead_discarded += (len(live) - len(mine)) * T
             b = self._blk
             b["blockdiff_passes"] += T
-            b["blockdiff_slot_passes"] += pairs
+            b["blockdiff_slot_passes"] += pairs + fused
             b["blockdiff_blocks_committed"] += commits
+            b["blockdiff_commits_fused"] += fused
             b["blockdiff_tokens_unmasked"] += unmasked
             self._counters.on_decode(held, len(live), T)
-            self._counters.read(sown, pairs * L, decode=True)
+            self._counters.read(sown, (pairs + fused) * L, decode=True)
         now = time.monotonic()
         for i, s in mine:
             for t in range(T):
@@ -1979,6 +2073,7 @@ class ContinuousBatcher:
                      tick.counters, tick.passes) = self._pass_jit(
                         self._cache, self._toks, key, self._params,
                         self._live_mask([i for i, _ in live]))
+                self._passes_enqueued += self._T
                 if not self._reads_own:
                     for i, _ in live:
                         self._schedule_passes(self._slots[i])
@@ -2046,7 +2141,8 @@ class ContinuousBatcher:
             if self._block:
                 # what the insert places beside the slab: the first
                 # blocks, the prompts' tails in them
-                pre = (pre[0], self._first_blocks(pre[4]), *pre[2:])
+                pre = (pre[0], self._first_blocks(pre[4], lanes_live),
+                       *pre[2:])
             pres.append(pre)
             for slot, req in zip(pre[3], pre[4]):
                 s = self._slots[slot]
@@ -2059,6 +2155,7 @@ class ContinuousBatcher:
                     s.blocks, s.skip = [], len(req.given)
                     s.owed = s.skip + req.max_new
                     s.masked = self._block - s.skip
+                    s.wait = s.idle = req.wait
 
         t0 = time.monotonic()
         # the slot a chunked admission holds while its request is not

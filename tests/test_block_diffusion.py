@@ -121,8 +121,9 @@ def test_block_length_zero_builds_the_modules_it_always_did():
     cfg = TransformerConfig(vocab_size=64, num_layers=1, embed_dim=32,
                             num_heads=2, mlp_dim=64, max_len=32)
     assert cfg.block_length == 0 and cfg.pass_tokens == 1
+    # a pass carries a commit half and an open half
     assert dataclasses.replace(cfg, decode=True, decode_scatter=True,
-                               block_length=4).pass_tokens == 4
+                               block_length=4).pass_tokens == 8
 
 
 # -- through the cache -------------------------------------------------------
@@ -208,7 +209,7 @@ def test_engine_tokens_equal_block_diffusion_generate(engines, name, P, N):
     if name not in engines:
         engines[name] = engine(conf, cfg, params)
     eng, prompt = engines[name], prompt_of(P)
-    before = eng.stats()
+    before, lane_steps = eng.stats(), eng._active_lane_steps
     got = eng.generate(prompt, N, timeout=300).tolist()
     after = eng.stats()
     trace = []
@@ -221,15 +222,25 @@ def test_engine_tokens_equal_block_diffusion_generate(engines, name, P, N):
     assert d["blockdiff_tokens_delivered"] == N
     assert d["blockdiff_given_tokens"] == P % 4
     assert d["blockdiff_tokens_unmasked"] == 4 * blocks - P % 4
-    # a denoise pass a reference pass, and a commit a block
+    # a block through the model a reference pass or a commit, whichever
+    # forward carried it; every commit but the budget's last rode the
+    # next block's first pass, so the live (slot, pass) pairs are the
+    # reference's passes and ONE more
     assert d["blockdiff_slot_passes"] == len(trace) + blocks
+    assert d["blockdiff_commits_fused"] == blocks - 1
+    pairs = d["blockdiff_slot_passes"] - d["blockdiff_commits_fused"]
+    assert pairs == len(trace) + 1 == eng._active_lane_steps - lane_steps
     if name == "static4" and P % 4 == 0:
-        assert d["blockdiff_slot_passes"] == 5 * blocks
+        assert pairs == 4 * blocks + 1
+    if name == "static1":
+        # a pass a block, and the first block's own
+        assert pairs == blocks + 1
     # a pass yields 0 or L tokens a slot: the gap is the time after the
     # first commit over the tokens that came after it, and a lane step
     # is a (slot, pass) pair
     first = min(N, 4 - P % 4)
     assert after["decode_tokens"] - before["decode_tokens"] == N - first
+    # the commit halves' rows are routed with the open halves'
     assert after["moe_tokens"] - before["moe_tokens"] == (
         P // 4 * 4 + 4 * d["blockdiff_slot_passes"])
     assert after["moe_assignments"] - before["moe_assignments"] == 2 * 2 * (
@@ -273,6 +284,11 @@ def test_slots_live_together_serve_what_each_would_alone(monkeypatch,
     # the first three were live together, and the fourth beside two
     assert max(e["live"] for e in logs[0]) == 3
     assert max(e["live"] for e in logs[3]) >= 2
+    # and the slots keep step: every commit, of every request, in a pass
+    # of the engine's that is a multiple of 4 (``_start_wait``), so that
+    # three passes of four carry no commit half
+    commits = {e["at"] for log in logs for e in log if e["commit"]}
+    assert commits and all(at % 4 == 0 for at in commits)
 
 
 @pytest.mark.parametrize("mixed", [False, True], ids=["honest", "mixed"])
@@ -316,21 +332,140 @@ def test_a_block_that_reaches_another_slots_request_is_seen(mixed):
 
 
 def test_lane_steps_count_slot_passes(served):
+    """Two whole blocks: ``4 n + 1`` = 9 live (slot, pass) pairs, the
+    fifth of which commits the first block and opens the second; the
+    budget ends at the second commit, which opens nothing and frees the
+    slot."""
     conf, cfg, params, eng = served
-    before = eng.stats()
-    eng.generate(prompt_of(8), 8, timeout=300)
+    before, lane_steps = eng.stats(), eng._active_lane_steps
+    # the idle engine's clock says how many passes the slot sits out so
+    # that its commits fall where every slot's do: 0..3, in no pair
+    wait = eng._start_wait(0, False)
+    eng.pass_log = logs = {}
+    try:
+        fut = eng.submit(prompt_of(8), 8)
+        fut.result(timeout=300)
+    finally:
+        eng.pass_log = None
     after = eng.stats()
-    passes = after["blockdiff_passes"] - before["blockdiff_passes"]
-    assert passes == 10 and eng._lane_steps % (3 * 5) == 0
-    assert (after["blockdiff_slot_passes"]
-            - before["blockdiff_slot_passes"]) == 10
+    d = {k: after[k] - before[k] for k in after if k.startswith("blockdiff_")}
+    assert d["blockdiff_passes"] == 5 * -(-(9 + wait) // 5)
+    assert eng._lane_steps % (3 * 5) == 0
+    assert eng._active_lane_steps - lane_steps == 9
+    assert (d["blockdiff_slot_passes"], d["blockdiff_blocks_committed"],
+            d["blockdiff_commits_fused"]) == (10, 2, 1)
     # one slot of three live in every pass
     assert after["slot_utilization"] == pytest.approx(
         eng._active_lane_steps / eng._lane_steps, abs=1e-3)
-    assert eng._active_lane_steps == after["blockdiff_slot_passes"]
-    # rows the live pairs saw are what decode_kv_live_share reads
+    assert eng._active_lane_steps == (after["blockdiff_slot_passes"]
+                                      - after["blockdiff_commits_fused"])
+    # rows the live pairs saw are what decode_kv_live_share reads: the
+    # pass that commits the first block reads up to its end and, behind
+    # it, up to the second's
     assert (after["decode_kv_tokens_live"] - before["decode_kv_tokens_live"]
             == 5 * 12 + 5 * 16)     # 8 rows prefilled, then two blocks
+    # the log: a record a block through the model, the second block's
+    # first as the fused forward found it; the last commit opened nothing
+    log = logs[fut]
+    assert [e["commit"] for e in log] == [False] * 4 + [True] + \
+        [False] * 4 + [True]
+    assert log[5] == {"tok": [MASK] * 4, "masked": [True] * 4,
+                      "commit": False, "live": 1, "at": log[4]["at"]}
+    assert [e["at"] - log[0]["at"] for e in log] == [0, 1, 2, 3, 4, 4, 5, 6,
+                                                    7, 8]
+    assert sum(log[6]["masked"]) == 3
+    # and the slot is free, on the host and on the device
+    assert all(s.request is None for s in eng._slots)
+    assert (np.asarray(eng._toks["left"]) <= 0).all()
+
+
+@pytest.mark.parametrize("given", [0, 1, 2, 3])
+@pytest.mark.parametrize("name", ["static4", "static2", "static1",
+                                  "dynamic"])
+def test_a_slot_waits_so_that_its_commits_fall_where_every_slots_do(
+        engines, name, given):
+    """``_start_wait``: whatever the engine's clock reads and whatever
+    the prompt gave of the first block, the slot's first commit falls in
+    a pass that is a multiple of the passes a block takes; ``dynamic``
+    remasking keeps no step."""
+    conf, cfg, params = model(**GEN[name])
+    if name not in engines:
+        engines[name] = engine(conf, cfg, params)
+    eng = engines[name]
+    period = {"static4": 4, "static2": 2, "static1": 1}.get(name)
+    first = -(-(4 - given) // eng._unmask)
+    was = eng._passes_enqueued
+    try:
+        for clock in range(9):
+            eng._passes_enqueued = clock
+            for live in (False, True):
+                wait = eng._start_wait(given, live)
+                if period is None:
+                    assert wait == 0
+                    continue
+                start = clock + (eng._T if live else 0)
+                assert 0 <= wait < period
+                assert (start + wait + first) % period == 0
+    finally:
+        eng._passes_enqueued = was
+
+
+def test_a_fused_pass_leaves_the_cache_a_commit_and_an_opening_pass_would(
+        served):
+    """One forward that commits slot 0's block AND opens its next,
+    beside a slot in mid-block and a dead one, against the same tokens
+    through a commit pass and an opening pass run apart: the slabs row
+    for row, the indices, and the open halves' logits."""
+    conf, cfg, params, eng = served
+    L, rng = 4, np.random.default_rng(11)
+    index = np.asarray([8, 4, 12], np.int32)
+    keys = iter(jax.random.split(jax.random.key(9), 64))
+
+    def slabs():
+        return jax.tree.map(
+            lambda a: jnp.asarray(index) if a.ndim == 1 else
+            jax.random.normal(next(keys), a.shape, a.dtype),
+            eng._fresh_cache(3))
+
+    def moved(cache, by):
+        return jax.tree.map(
+            lambda a: a + jnp.asarray(by, a.dtype) if a.ndim == 1 else a,
+            cache)
+
+    start = slabs()
+    tok = jnp.asarray(rng.integers(1, MASK, (3, L)), jnp.int32)
+    masked = jnp.asarray([[False] * 4, [False, True, False, True],
+                          [True] * 4])
+    forward = jax.jit(eng._pass_forward)
+    # bit 1: slot 0's commit half is live beside its open half
+    fused, mut = forward(eng._params, start, tok, masked,
+                         jnp.asarray([3, 1, 0]))
+    # apart: slot 0's commit in a pass of its own, the index moved by
+    # hand, then the opening pass of its next block beside slot 1's
+    _, first = forward(eng._params, start, tok, masked,
+                       jnp.asarray([1, 0, 0]))
+    assert [int(a) for a in eng._positions(first["cache"])] == [8, 4, 12]
+    apart, second = forward(
+        eng._params, moved(first["cache"], [L, 0, 0]),
+        tok.at[0].set(MASK), masked.at[0].set(True), jnp.asarray([1, 1, 0]))
+    assert [int(a) for a in eng._positions(mut["cache"])] == [12, 4, 12]
+    for got, want, was in zip(jax.tree.leaves(mut["cache"]),
+                              jax.tree.leaves(second["cache"]),
+                              jax.tree.leaves(start)):
+        got, want, was = (np.asarray(a) for a in (got, want, was))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        if got.ndim > 1:
+            assert (got[2] == was[2]).all()         # the dead slot's slab
+            assert (got[0] != was[0]).any()
+    close(fused[:2], apart[:2])
+    # the commit half never saw the open half: the finished block's rows
+    # are what a commit alone writes (keys [slots, Hk, D, rows])
+    for got, want in zip(jax.tree.leaves(mut["cache"]),
+                         jax.tree.leaves(first["cache"])):
+        if got.ndim == 4 and got.shape[-1] == 128:
+            np.testing.assert_allclose(
+                np.asarray(got)[0, ..., 8:12], np.asarray(want)[0, ..., 8:12],
+                rtol=1e-5, atol=1e-6)
 
 
 def test_a_chunked_prompt_and_a_pooled_prefix_serve_what_a_plain_prefill_does(
@@ -421,14 +556,11 @@ def test_a_slot_freed_mid_block_serves_its_next_request_as_a_fresh_engine():
 
 
 # -- the kernels -------------------------------------------------------------
-def test_block_kernels_in_interpret_mode_equal_the_einsum_path(monkeypatch):
-    conf, cfg, params = model()
+def test_block_kernels_in_interpret_mode_equal_the_einsum_path(monkeypatch,
+                                                              served):
+    conf, cfg, params, plain = served
     prompt = prompt_of(11)
-    plain = engine(conf, cfg, params)
-    try:
-        want = plain.generate(prompt, 12, timeout=300).tolist()
-    finally:
-        plain.stop()
+    want = plain.generate(prompt, 12, timeout=300).tolist()
     calls = []
     real = decode_attention.block_append
     monkeypatch.setattr(decode_attention, "block_applies",
@@ -500,9 +632,10 @@ def test_pass_program_for_v5e_runs_its_kernels_and_moves_no_slab(
     of the six, 16 slots x 4096 rows, two passes), compiled ahead of
     time for one v5e chip from abstract shapes: the Mosaic compiler
     takes ``block_append`` and ``block_attend`` (``L x G`` = 32 query
-    rows a KV head) and the expert FFN of ``16 x 4 x 8`` pairs goes
-    through ``moe_decode_gmm``, not ``ragged_dot``; no op but the
-    aliased append holds a whole slab."""
+    rows a KV head), one of each a half, and the expert FFN of ``16 x
+    8 x 8`` pairs (both halves' rows) goes through ``moe_decode_gmm``
+    once a layer, not ``ragged_dot``; the head runs over the open half
+    alone; no op but the aliased appends holds a whole slab."""
     import re
     import types
 
@@ -529,7 +662,7 @@ def test_pass_program_for_v5e_runs_its_kernels_and_moves_no_slab(
                                     sharding=one_chip)
 
     def sown_of(params):
-        ids = jnp.zeros((B, L), jnp.int32)
+        ids = jnp.zeros((B, 2 * L), jnp.int32)
         _, mut = pmodel.apply(
             {"params": params, "cache": jax.tree.map(
                 lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])},
@@ -551,7 +684,8 @@ def test_pass_program_for_v5e_runs_its_kernels_and_moves_no_slab(
     eng._pass_forward = lambda *a: ContinuousBatcher._pass_forward(eng, *a)
     state = {"tok": on_chip(jax.ShapeDtypeStruct((B, L), jnp.int32)),
              "masked": on_chip(jax.ShapeDtypeStruct((B, L), jnp.bool_)),
-             "left": on_chip(jax.ShapeDtypeStruct((B,), jnp.int32))}
+             "left": on_chip(jax.ShapeDtypeStruct((B,), jnp.int32)),
+             "wait": on_chip(jax.ShapeDtypeStruct((B,), jnp.int32))}
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -566,8 +700,14 @@ def test_pass_program_for_v5e_runs_its_kernels_and_moves_no_slab(
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
     text = compiled.as_text()
-    assert "block_append" in text and "block_attend" in text
-    assert "moe_decode_gmm" in text and "ragged" not in text
+    calls = re.findall(r"^\s*%?(block_append|block_attend|moe_decode_gmm)"
+                       r"[.\d]* = .*? custom-call\(", text, re.M)
+    # a pass in which some slot commits, and a pass in which none does
+    assert sorted(calls) == ["block_append"] * 3 + ["block_attend"] * 3 + [
+        "moe_decode_gmm"] * 2, calls
+    assert "ragged" not in text
+    # the logits are the open halves'
+    assert "f32[16,4,151936]" in text and "[16,8,151936]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
     slab = re.compile(r"\[16,4,(128,4096|4096,128)\]")
     moved = []
@@ -578,7 +718,7 @@ def test_pass_program_for_v5e_runs_its_kernels_and_moves_no_slab(
             continue
         name, op = m.group(1), m.group(3)
         if op in ("parameter", "get-tuple-element", "tuple", "while",
-                  "bitcast") or (op == "custom-call"
+                  "conditional", "bitcast") or (op == "custom-call"
                                  and name.startswith("block_append")):
             continue
         moved.append(f"{op} {name}")
@@ -637,7 +777,8 @@ def test_an_engine_over_a_block_configuration_is_a_block_engine():
                             prefill_chunk=16, temperature=0.0)
     try:
         assert (eng._block, eng._unmask, eng._mask_id) == (4, 2, MASK)
-        assert sorted(eng._block_state(2)) == ["left", "masked", "tok"]
+        assert sorted(eng._block_state(2)) == ["left", "masked", "tok",
+                                               "wait"]
     finally:
         eng.stop()
 

@@ -327,6 +327,31 @@ def test_decode_kernel_dispatch_rule_is_shape_mesh_and_backend_only(
     assert moe.gmm_chunks(6144, 2048, False, jnp.float32) == (512, 128)
 
 
+# (pairs, width): the served decode-shaped calls, by the kernel's own op
+# names in PERF_LEDGER.jsonl's breakdowns (rows x width as _gmm_rows pads
+# them), and the block pass of 16 slots x 2 L = 8 positions x 8 experts
+@pytest.mark.parametrize("pairs,width,rows,takes", [
+    (96, 2048, 208, True), (96, 6144, 208, True), (320, 4096, 432, True),
+    (256, 2304, 368, True), (96, 7680, 208, True),
+    (512, 2048, 624, True),         # a pass of L rows a slot: 14.6 MiB
+    (1024, 2048, 1136, True),       # a pass of 2 L rows a slot: 26.6 MiB
+    (1104, 2048, 1216, False),      # 28.5 MiB: past the bound
+    (512, 6144, 624, False)])
+def test_decode_kernel_takes_the_rows_that_fit_its_vmem(monkeypatch, pairs,
+                                                        width, rows, takes):
+    """``_ROW_BYTES``: rows in and out, double buffered, and the float32
+    accumulator, 12 B an element at bfloat16, beside 16 MiB of weight
+    chunks and the float32 hidden rows in ``_VMEM_LIMIT``."""
+    from edl_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    assert moe._gmm_rows(pairs, jnp.bfloat16) == rows
+    assert moe._gmm_row_bytes(pairs, width, jnp.bfloat16) == rows * width * 12
+    assert moe.applies(1, None, pairs, width, jnp.bfloat16) is takes
+    assert moe._ROW_BYTES + 4 * moe._CHUNK_BYTES + moe._ROW_BYTES // 4 \
+        < moe._VMEM_LIMIT
+
+
 @pytest.mark.parametrize("held", [0, 4])
 def test_moe_layer_takes_the_kernel_on_a_decode_shaped_call_only(
         held, monkeypatch):

@@ -37,6 +37,24 @@ def _engine(cfg, params, **kw):
     return ContinuousBatcher(cfg, params, **kw)
 
 
+_generate = {}
+
+
+def _want(cfg, params, p, n):
+    """``generate()``'s greedy tokens for prompt ``p``, the parity
+    reference of this file, under ``jax.jit`` as its docstring asks (one
+    program a configuration, prompt length and ``n``: called eagerly it
+    compiles its prefill op by op and its scan again every call, 3 s a
+    call against 1 s; D29)."""
+    fn = _generate.get(cfg)
+    if fn is None:
+        fn = _generate[cfg] = jax.jit(
+            lambda params, prompt, n: generate(cfg, params, prompt, n,
+                                               temperature=0.0),
+            static_argnums=2)
+    return np.asarray(fn(params, jnp.asarray(p[None]), n))[0]
+
+
 def test_greedy_parity_vs_generate(small):
     cfg, params = small
     rng = np.random.default_rng(0)
@@ -50,8 +68,7 @@ def test_greedy_parity_vs_generate(small):
     finally:
         eng.stop()
     for p, n, out in zip(prompts, news, got):
-        want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), n,
-                                   temperature=0.0))[0]
+        want = _want(cfg, params, p, n)
         np.testing.assert_array_equal(out, want)
 
 
@@ -130,8 +147,7 @@ def test_tp_sharded_engine_greedy_parity(small):
     finally:
         eng.stop()
     for p, n, out in zip(prompts, news, got):
-        want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), n,
-                                   temperature=0.0))[0]
+        want = _want(cfg, params, p, n)
         np.testing.assert_array_equal(out, want)
 
 
@@ -176,8 +192,7 @@ def test_moe_engine_greedy_parity():
     finally:
         eng.stop()
     for p, out in zip(prompts, got):
-        want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 6,
-                                   temperature=0.0))[0]
+        want = _want(cfg, params, p, 6)
         np.testing.assert_array_equal(out, want)
 
 
@@ -199,8 +214,7 @@ def test_gqa_engine_greedy_parity(small):
     finally:
         eng.stop()
     for p, out in zip(prompts, got):
-        want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 6,
-                                   temperature=0.0))[0]
+        want = _want(cfg, params, p, 6)
         np.testing.assert_array_equal(out, want)
 
 
@@ -208,8 +222,7 @@ def test_eos_truncates(small):
     cfg, params = small
     # eos = whatever greedy emits second -> output must stop there
     p = np.asarray([5, 9, 2], np.int32)
-    ref = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 8,
-                              temperature=0.0))[0]
+    ref = _want(cfg, params, p, 8)
     eos = int(ref[1])
     eng = _engine(cfg, params, eos_id=eos)
     try:
@@ -245,8 +258,7 @@ def test_prompt_longer_than_configured_buckets(small):
         out = eng.generate(p, 5, timeout=120)
     finally:
         eng.stop()
-    want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 5,
-                               temperature=0.0))[0]
+    want = _want(cfg, params, p, 5)
     np.testing.assert_array_equal(out, want)
 
 
@@ -266,8 +278,7 @@ def test_600_token_prompt_1024_cache():
         out = eng.generate(p, 6, timeout=300)
     finally:
         eng.stop()
-    want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 6,
-                               temperature=0.0))[0]
+    want = _want(cfg, params, p, 6)
     np.testing.assert_array_equal(out, want)
 
 
@@ -334,8 +345,7 @@ def test_warm_then_serve(small):
         out = eng.generate(p, 5, timeout=120)
     finally:
         eng.stop()
-    want = np.asarray(generate(cfg, params, jnp.asarray(p[None]), 5,
-                               temperature=0.0))[0]
+    want = _want(cfg, params, p, 5)
     np.testing.assert_array_equal(out, want)
 
 
@@ -414,11 +424,6 @@ def test_drain_timeout_falls_back_to_hard_stop(small):
 # already runs through it; these cases pin what it rests on: budgets the
 # host can count, an EOS read one tick late, a flush wherever host and
 # device have to agree.
-
-def _want(cfg, params, p, n):
-    return np.asarray(generate(cfg, params, jnp.asarray(p[None]), n,
-                               temperature=0.0))[0]
-
 
 def _first_time(ref, k0):
     """The first index >= k0 whose token did not occur before it in

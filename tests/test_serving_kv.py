@@ -40,9 +40,21 @@ def _engine(cfg, params, **kw):
     return ContinuousBatcher(cfg, params, **kw)
 
 
+_generate = {}
+
+
 def _want(cfg, params, p, n):
-    return np.asarray(generate(cfg, params, jnp.asarray(p[None]), n,
-                               temperature=0.0))[0]
+    """``generate()``'s greedy tokens under ``jax.jit`` (one program a
+    configuration, prompt length and ``n``; eager it compiles its
+    prefill op by op and its scan again every call: D29, as
+    ``tests/test_serving_engine.py`` does)."""
+    fn = _generate.get(cfg)
+    if fn is None:
+        fn = _generate[cfg] = jax.jit(
+            lambda params, prompt, n: generate(cfg, params, prompt, n,
+                                               temperature=0.0),
+            static_argnums=2)
+    return np.asarray(fn(params, jnp.asarray(p[None]), n))[0]
 
 
 def test_paged_engine_greedy_parity_and_prefix_hits(small):
