@@ -71,18 +71,42 @@ def write_launcher_half(store, job_id: str, stage: str, pod_id: str,
     _observe_phases(stage, times, LAUNCHER_PHASES)
 
 
+# what the trainer built between ``restored`` and ``first_step``, from
+# the program-build ledger (obs/ledger.py): whether
+# restored_to_first_step was Python (trace + lower), XLA (compile: a
+# persistent-cache miss compiles, a hit reads) or the step itself
+BUILD_FIELDS = ("build_trace_lower_s", "build_compile_s",
+                "build_cache_hits", "build_cache_misses")
+
+
+def build_fields(before: dict, after: dict) -> dict:
+    """:data:`BUILD_FIELDS` from two ``ProgramBuildLedger.totals()``
+    (flat ``<kind>/<component>/<family>/<field>`` keys), every label."""
+    def grown(*fields):
+        return sum(v - before.get(k, 0) for k, v in after.items()
+                   if k.rsplit("/", 1)[-1] in fields)
+    return {"build_trace_lower_s": round(grown("trace_s", "lower_s"), 3),
+            "build_compile_s": round(grown("compile_s"), 3),
+            "build_cache_hits": int(grown("cache_hits")),
+            "build_cache_misses": int(grown("cache_misses"))}
+
+
 def write_trainer_half(store, job_id: str, stage: str, pod_id: str,
                        restored: float, first_step: float,
-                       restore_source: str | None = None) -> None:
+                       restore_source: str | None = None,
+                       builds: dict | None = None) -> None:
     """Trainer half (checkpoint restored / first post-resize step) —
     same unified write path as :func:`write_launcher_half`.
     ``restore_source`` records where the state came from:
     ``"peer"`` (memstate in-RAM cache) or ``"storage"`` (Orbax) — the
     cache-vs-storage split is the thing the memstate subsystem exists
-    to move, so it lives in the same record as the phase timings."""
+    to move, so it lives in the same record as the phase timings.
+    ``builds`` (:func:`build_fields`) says what of the interval was
+    building programs."""
     times = {"restored": restored, "first_step": first_step}
     if restore_source is not None:
         times["restore_source"] = restore_source
+    times.update(builds or {})
     store.put(
         paths.key(job_id, constants.ETCD_RECOVERY,
                   f"{stage}/trainer/{pod_id}"),
@@ -109,7 +133,10 @@ def summarize_recovery(store, job_id: str,
     Phases (seconds): ``detect_to_kill`` (terminate old trainers),
     ``kill_to_barrier`` (membership re-agreement), ``barrier_to_spawn``
     (respawn), ``spawn_to_restored`` (jax + checkpoint restore),
-    ``restored_to_first_step`` (recompile + first step), ``total`` =
+    ``restored_to_first_step`` (recompile + first step; the
+    :data:`BUILD_FIELDS` of the trainer that closed the resize say how
+    much of it was tracing and lowering, how much compiling, and
+    whether the compile cache hit), ``total`` =
     detect → first post-resize step.  With ``kill_time`` (the harness's
     SIGKILL timestamp) also ``kill_to_detect`` (lease TTL + generator +
     watcher latency) and ``total_from_kill``."""
@@ -155,6 +182,9 @@ def summarize_recovery(store, job_id: str,
                     tt["first_step"] - tt["restored"], 3),
                 "total": round(tt["first_step"] - lt["detect"], 3),
             })
+            # what that trainer was building in it (absent from an
+            # older trainer's half)
+            entry.update({f: tt[f] for f in BUILD_FIELDS if f in tt})
             # "peer"/"delta" only when EVERY pod restored from the
             # cache — one storage fallback means the resize still paid
             # storage

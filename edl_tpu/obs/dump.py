@@ -39,7 +39,7 @@ import json
 import os
 import sys
 
-from edl_tpu.cluster.recovery import summarize_recovery
+from edl_tpu.cluster.recovery import BUILD_FIELDS, summarize_recovery
 from edl_tpu.obs.collector import collect_row
 
 # render order: the chronological phase chain, then the totals —
@@ -84,6 +84,14 @@ def render_report(report: dict) -> str:
         for phase in PHASE_ORDER:
             if phase in s:
                 lines.append(f"    {phase:<24} {s[phase]:>9.3f}s")
+            if phase == "restored_to_first_step" and all(
+                    f in s for f in BUILD_FIELDS):
+                # what of it was building programs (obs/ledger.py)
+                lines.append(
+                    f"      trace+lower {s['build_trace_lower_s']:.3f}s"
+                    f"  compile {s['build_compile_s']:.3f}s"
+                    f"  compile cache {s['build_cache_hits']} hit(s) / "
+                    f"{s['build_cache_misses']} miss(es)")
     if not resizes:
         lines.append("  (no resize records)")
     return "\n".join(lines)

@@ -63,11 +63,28 @@ as flat numeric keys, so whoever differences ``stats()`` at two
 instants gets the window's percentiles with no span handed over
 (``benchmarks/layer_metrics/engine_queue_wait_p90_s.py`` is such a
 reader).
+
+**The program-scoped sibling** (:class:`ProgramBuildLedger`,
+process-wide as :data:`PROGRAM_BUILDS`).  A step has phases, a request
+has stages, a PROGRAM has a BUILD: ``trace`` (Python to a jaxpr),
+``lower`` (jaxpr to MLIR: a ``pallas_call`` is lowered here, once a
+call site), ``compile`` (XLA on a persistent-cache miss, reading and
+deserialising on a hit) and ``run`` (the first execution).  The clock
+of the first three is JAX's own: ``jax.monitoring`` calls
+:meth:`ProgramBuildLedger.on_duration` / :meth:`~ProgramBuildLedger.on_event`
+on the compiling thread (``utils/compile_cache.enable_compile_cache``
+registers them, once), and each event is booked to the span that
+thread has open, ``with PROGRAM_BUILDS.build(component, family)``, or
+to component ``other`` under the program's own name where none is.
+Nothing fires once a program is built: the steady state pays nothing.
 """
 
 from __future__ import annotations
 
 import math
+import re
+import sys
+import threading
 import time
 from bisect import bisect_left
 from contextlib import contextmanager
@@ -346,3 +363,278 @@ class RequestStageLedger:
         for stage, counts in self._counts.items():
             out.update(zip(self._le[stage], accumulate(counts)))
         return out
+
+
+# -- program builds ----------------------------------------------------------
+
+BUILD_STAGES = ("trace", "lower", "compile", "run")
+# jax.monitoring's duration events, by the stage each is (jax 0.9:
+# jax/_src/dispatch.py).  ``backend_compile_duration`` spans the
+# persistent cache's lookup too, so on a hit it IS the retrieval.
+_JAX_STAGE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+# jax counts ``cache_misses`` only where it then WRITES the entry (a
+# compile under its size and time thresholds is never kept): a request
+# that did not hit is the miss here
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+UNLABELLED = "other"
+_ROW_FIELDS = ("builds", *(f"{s}_s" for s in BUILD_STAGES), "cache_hits",
+               "cache_misses")
+# events a thread keeps to find what a later, enclosing one contains
+_LOOSE_EVENTS = 4096
+_WRAPPED_NAME = re.compile(r"^\w+\((.*)\)$")
+
+_BUILD_SECONDS = obs_metrics.counter(
+    "edl_program_build_seconds_total",
+    "Seconds this process spent building its programs, by component "
+    "(engine / kv / train / other = no span owned the compile) and stage: "
+    "trace / lower / compile (XLA on a persistent-cache miss, retrieval "
+    "on a hit) / run (a built program's first execution, state placement)",
+    ("component", "stage"))
+_BUILDS_TOTAL = obs_metrics.counter(
+    "edl_program_builds_total",
+    "Programs this process asked XLA for, by component and what the "
+    "persistent compile cache said: hit / miss / none (not asked)",
+    ("component", "cache"))
+
+
+class _BuildFrame:
+    """One open span of one thread; or, with no ``row``, what a thread
+    with no span open has gathered since its last compile."""
+
+    __slots__ = ("row", "key", "inner_s", "stage_s", "events", "programs",
+                 "hits", "requests", "retrieve_s")
+
+    def __init__(self, row: tuple, key):
+        self.row, self.key = row, key
+        self.inner_s = 0.0          # spans closed inside this one
+        self.stage_s = dict.fromkeys(BUILD_STAGES[:3], 0.0)
+        self.events: list[tuple[float, float]] = []
+        self.programs = self.hits = self.requests = 0
+        self.retrieve_s = 0.0
+
+
+class _FirstCall:
+    """A jitted program until its first call has run: that call runs
+    inside the program's build span and waits for its outputs, then the
+    place that held this object (``holder[name]`` or ``holder.name``,
+    if it still does) holds the bare program again.  Whoever kept a
+    reference meanwhile gets a plain forward.  Everything else
+    (``lower``, ``trace``) is the program's own."""
+
+    def __init__(self, ledger, fn, component, family, key, holder, name):
+        self.fn = fn
+        self._span = (ledger, component, family, key)
+        self._held = (holder, name)
+
+    def __call__(self, *args, **kwargs):
+        if self._span is None:
+            return self.fn(*args, **kwargs)
+        ledger, component, family, key = self._span
+        with ledger.build(component, family, key=key):
+            out = self.fn(*args, **kwargs)
+            jax = sys.modules.get("jax")
+            if jax is not None:
+                jax.block_until_ready(out)
+        (holder, name), self._span, self._held = self._held, None, None
+        if isinstance(holder, dict):
+            if holder.get(name) is self:
+                holder[name] = self.fn
+        elif getattr(holder, name, None) is self:
+            setattr(holder, name, self.fn)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+
+class ProgramBuildLedger:
+    """Where a start goes: cumulative rows by (kind, component, family)
+    of programs built, seconds by stage and persistent-cache hits and
+    misses.  ``kind`` is ``build`` (a program: traced, lowered,
+    compiled or retrieved, run once) or ``setup`` (state placed:
+    weights cast, caches and pools allocated, a restore; the programs
+    that takes are booked inside it).
+
+    Thread-safe.  The open spans are held per THREAD (a background
+    compile is booked to its own label, not to whatever the loop has
+    open) and gather their events without a lock; rows and the
+    per-thread sums are written under one lock when a span closes (or
+    a compile no span owns ends), so :meth:`totals` holds closed spans
+    only."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self._rows: dict[tuple, dict] = {}
+        self._by_thread: dict[int, list] = {}
+
+    # -- spans ---------------------------------------------------------------
+    @contextmanager
+    def build(self, component: str, family: str, key=None,
+              kind: str = "build"):
+        """Open ``<kind>/<component>/<family>`` on this thread: a
+        profiler annotation (in a ``/profile`` capture of a starting
+        process it sits on the device's clock) and, when it closes, a
+        trace event with ``key``, the seconds by stage and ``cache``
+        (hit / miss / none).  The block's wall seconds less the JAX
+        stages booked inside it are its ``run`` stage.  A ``build``
+        span in which no program was compiled or retrieved (a first
+        call that found its program built) books nothing; a ``setup``
+        span always books.  A span inside another is deducted from it."""
+        frames = getattr(self._tls, "frames", None)
+        if frames is None:
+            frames = self._tls.frames = []
+        frame = _BuildFrame((kind, component, family), key)
+        frames.append(frame)
+        t_wall = time.time()
+        t0 = time.perf_counter()
+        try:
+            with obs_trace.annotation(f"{kind}/{component}/{family}"):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            frames.pop()
+            if frames:
+                frames[-1].inner_s += dt
+            if frame.programs or kind != "build":
+                self._close(frame, dt, t_wall)
+
+    def setup(self, component: str, family: str = "state", key=None):
+        """``setup/<component>/<family>``: state placed, not a program."""
+        return self.build(component, family, key=key, kind="setup")
+
+    def first_call(self, fn, component: str, family: str, key, holder, name):
+        """``fn`` wrapped so that its FIRST call is its build span
+        (:class:`_FirstCall`); put the result where ``holder[name]`` /
+        ``holder.name`` says, and that place holds ``fn`` itself from
+        the first call on: nothing stays on the caller's path."""
+        return _FirstCall(self, fn, component, family, key, holder, name)
+
+    def _close(self, frame: _BuildFrame, dt: float, t_wall: float) -> None:
+        own = max(0.0, dt - frame.inner_s)
+        run = max(0.0, own - sum(frame.stage_s.values()))
+        self._book(frame.row, frame, run)
+        if obs_trace.active():
+            cache = ("none" if not frame.requests else
+                     "hit" if frame.hits == frame.requests else "miss")
+            obs_trace.emit(
+                "/".join(frame.row), dur=dt, at=t_wall, key=repr(frame.key),
+                programs=frame.programs, cache=cache, run_s=round(run, 6),
+                retrieve_s=round(frame.retrieve_s, 6),
+                **{f"{s}_s": round(v, 6) for s, v in frame.stage_s.items()})
+
+    def _book(self, row: tuple, frame: _BuildFrame, run: float) -> None:
+        """What ``frame`` gathered, into ``row``, this thread's sums and
+        the registry: one lock a span (or a compile no span owns), not
+        one an event."""
+        misses = frame.requests - frame.hits
+        with self._lock:
+            got = self._rows.get(row)
+            if got is None:
+                got = self._rows[row] = dict.fromkeys(_ROW_FIELDS, 0.0)
+            for stage, secs in frame.stage_s.items():
+                got[f"{stage}_s"] += secs
+            got["run_s"] += run
+            got["builds"] += frame.programs
+            got["cache_hits"] += frame.hits
+            got["cache_misses"] += misses
+            sums = getattr(self._tls, "sums", None)
+            if sums is None:
+                # a new thread starts from nothing, whatever a dead one
+                # with its ident left
+                sums = self._tls.sums = [0, 0.0]
+                self._by_thread[threading.get_ident()] = sums
+            sums[0] += frame.programs
+            sums[1] += run + sum(frame.stage_s.values())
+        component = row[1]
+        for stage, secs in (*frame.stage_s.items(), ("run", run)):
+            if secs:
+                _BUILD_SECONDS.labels(component, stage).inc(secs)
+        for cache, n in (("hit", frame.hits), ("miss", misses),
+                         ("none", frame.programs - frame.requests)):
+            if n:
+                _BUILDS_TOTAL.labels(component, cache).inc(n)
+
+    # -- jax.monitoring's side -----------------------------------------------
+    def on_duration(self, event: str, seconds: float, **kw) -> None:
+        """A ``jax.monitoring`` duration, on the thread that compiled:
+        into the span that thread has open, booked when it closes; with
+        none open, gathered until the program's compile event and
+        booked under ``other`` and the compiled function's name.  The
+        events nest (a trace contains its callees' traces, an eager
+        constant compiles inside a trace) and each reports its whole
+        length at its end: what a later event contains is deducted
+        from it, so the stages add up to no more than the wall."""
+        tls = self._tls
+        frames = getattr(tls, "frames", None)
+        if frames:
+            frame = frames[-1]
+        else:
+            frame = getattr(tls, "loose", None)
+            if frame is None:
+                frame = tls.loose = _BuildFrame(None, None)
+        stage = _JAX_STAGE.get(event)
+        if stage is None:
+            if event == _CACHE_RETRIEVAL:
+                frame.retrieve_s += seconds
+            return
+        events = frame.events
+        start = time.perf_counter() - seconds
+        inner = 0.0
+        while events and events[-1][0] >= start:
+            inner += events.pop()[1]
+        events.append((start, seconds))
+        frame.stage_s[stage] += max(0.0, seconds - inner)
+        if stage != "compile":
+            return
+        # what the cache said of THIS program: a request since the last
+        # compile on this thread, and whether it hit
+        req, tls.request = getattr(tls, "request", None), None
+        frame.programs += 1
+        frame.requests += req is not None
+        frame.hits += req == "hit"
+        if frame.row is None:
+            name = str(kw.get("fun_name", "?"))
+            wrapped = _WRAPPED_NAME.match(name)     # jit(f) is f's
+            self._book(("build", UNLABELLED,
+                        wrapped.group(1) if wrapped else name), frame, 0.0)
+            # the events stay: a later one may contain them
+            del events[:-_LOOSE_EVENTS]
+            tls.loose = fresh = _BuildFrame(None, None)
+            fresh.events = events
+
+    def on_event(self, event: str, **_kw) -> None:
+        """A ``jax.monitoring`` event: the persistent cache was asked
+        for the program this thread is compiling, and it hit."""
+        if event == _CACHE_REQUEST:
+            self._tls.request = "miss"
+        elif event == _CACHE_HIT:
+            self._tls.request = "hit"
+
+    # -- reading -------------------------------------------------------------
+    def totals(self) -> dict:
+        """Since the process started, flat and numeric:
+        ``<kind>/<component>/<family>/<field>`` with the fields
+        ``builds`` (programs XLA was asked for), ``trace_s`` /
+        ``lower_s`` / ``compile_s`` / ``run_s`` and ``cache_hits`` /
+        ``cache_misses``.  Cumulative: a reader differences two calls,
+        or takes one at the end of a set-up."""
+        with self._lock:
+            return {"/".join((*row, f)): v for row, got in self._rows.items()
+                    for f, v in got.items()}
+
+    def thread_totals(self, ident: int) -> tuple[int, float]:
+        """``(programs, seconds)`` that thread ``ident`` has built
+        since the process started, spans and unlabelled compiles alike."""
+        with self._lock:
+            n, s = self._by_thread.get(ident, (0, 0.0))
+        return int(n), float(s)
+
+
+PROGRAM_BUILDS = ProgramBuildLedger()

@@ -147,7 +147,8 @@ from edl_tpu.models.transformer import TransformerConfig, TransformerLM
 from edl_tpu.obs import context as obs_context
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.obs import trace as obs_trace
-from edl_tpu.obs.ledger import RequestStageLedger, StepPhaseLedger
+from edl_tpu.obs.ledger import (PROGRAM_BUILDS, RequestStageLedger,
+                                StepPhaseLedger)
 from edl_tpu.serving import cache_layout, model_counters
 from edl_tpu.serving.kv_cache import PagedKVCache, pool_device_bytes
 from edl_tpu.utils import constants
@@ -222,17 +223,34 @@ def _zeros_of(shapes):
 _PREFILL_MUTABLE = ["cache", "intermediates", "snap"]
 
 
-def _compiled(key):
+def _compiled(family, key):
     """A program family: the method builds the program of its arguments,
-    and the engine keeps one a ``key(*args)`` (``_prefill_cache``)."""
-    def family(build):
+    and the engine keeps one a ``key(*args)`` (``_prefill_cache``).  A
+    program's first call is its span ``build/engine/<family>`` in the
+    program-build ledger (``obs/ledger.py``); from then on the memo
+    holds the bare jitted function."""
+    def programs(build):
         def get(self, *args):
-            fn = self._prefill_cache.get(key(*args))
+            k = key(*args)
+            fn = self._prefill_cache.get(k)
             if fn is None:
-                fn = self._prefill_cache[key(*args)] = build(self, *args)
+                fn = self._prefill_cache[k] = PROGRAM_BUILDS.first_call(
+                    build(self, *args), "engine", family, k,
+                    self._prefill_cache, k)
             return fn
         return functools.wraps(build)(get)
-    return family
+    return programs
+
+
+def _setup_span(init):
+    """``ContinuousBatcher.__init__`` as ``setup/engine/state``: casts,
+    the slot cache and the pool allocated (and whatever eager programs
+    that takes)."""
+    @functools.wraps(init)
+    def construct(self, *args, **kwargs):
+        with PROGRAM_BUILDS.setup("engine"):
+            init(self, *args, **kwargs)
+    return construct
 
 
 @dataclass
@@ -389,6 +407,7 @@ class ContinuousBatcher:
     provably identical to plain decode.
     """
 
+    @_setup_span
     def __init__(self, cfg: TransformerConfig, params, *, slots: int = 8,
                  max_len: int | None = None,
                  prefill_buckets: tuple[int, ...] = DEFAULT_PREFILL_BUCKETS,
@@ -629,12 +648,14 @@ class ContinuousBatcher:
         # propagation would re-specialise the jit once per layout change
         # and thrash the donation)
         sh, rep = self._cache_shardings(slots), self._rep
-        self._step_jit = jax.jit(self._step_impl, donate_argnums=(0,),
-                                 out_shardings=(sh, rep, rep, rep))
-        self._insert_jit = jax.jit(self._insert_impl, donate_argnums=(0,),
-                                   out_shardings=(sh, rep))
+        self._program("step", jax.jit(
+            self._step_impl, donate_argnums=(0,),
+            out_shardings=(sh, rep, rep, rep)))
+        self._program("insert", jax.jit(
+            self._insert_impl, donate_argnums=(0,), out_shardings=(sh, rep)))
         if self._block:
-            self._pass_jit = jax.jit(self._pass_impl, donate_argnums=(0,))
+            self._program("pass", jax.jit(self._pass_impl,
+                                          donate_argnums=(0,)))
         # -- speculative decoding (draft-k / verify-once rounds) --
         # a tick whose progress only the device knows is read before the
         # next is enqueued: a speculative round, a pass under dynamic
@@ -681,14 +702,24 @@ class ContinuousBatcher:
             # own position (transformer.TransformerConfig.decode_scatter)
             self._vmodel = TransformerLM(dataclasses.replace(
                 self._dcfg, decode_scatter=True))
-            self._spec_jit = jax.jit(
+            self._program("spec", jax.jit(
                 self._spec_impl, donate_argnums=(0, 1),
-                out_shardings=(sh, rep, rep, rep, rep))
-            self._draft_insert_jit = jax.jit(
-                self._place, donate_argnums=(0,), out_shardings=rep)
+                out_shardings=(sh, rep, rep, rep, rep)))
+            self._program("draft_insert", jax.jit(
+                self._place, donate_argnums=(0,), out_shardings=rep))
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="continuous-batcher")
         self._thread.start()
+
+    def _program(self, family: str, jitted) -> None:
+        """``self._<family>_jit``: a program built here, compiled by
+        :meth:`warm` and first called from a tick.  Both are spans
+        ``build/engine/<family>`` (a program lowered twice shows twice
+        under one name); after its first call the attribute is the bare
+        jitted function."""
+        name = f"_{family}_jit"
+        setattr(self, name, PROGRAM_BUILDS.first_call(
+            jitted, "engine", family, None, self, name))
 
     # -- public --------------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
@@ -831,13 +862,16 @@ class ContinuousBatcher:
                 self._kv.store_state(snap, 0, 0)
             # lower+compile only: executing would donate the live cache
             first = self._block_state(K) if self._block else toks
-            self._insert_jit.lower(self._cache, self._toks, slab,
-                                   jnp.zeros((K,), jnp.int32),
-                                   lens, first).compile()
+            with PROGRAM_BUILDS.build("engine", "insert", key=K):
+                self._insert_jit.lower(self._cache, self._toks, slab,
+                                       jnp.zeros((K,), jnp.int32),
+                                       lens, first).compile()
             jax.block_until_ready(toks)
-        (self._pass_jit if self._block else self._step_jit).lower(
-            self._cache, self._toks, key, self._params,
-            self._live_mask([])).compile()
+        with PROGRAM_BUILDS.build("engine",
+                                  "pass" if self._block else "step"):
+            (self._pass_jit if self._block else self._step_jit).lower(
+                self._cache, self._toks, key, self._params,
+                self._live_mask([])).compile()
         if self._chunk_tokens:
             # the program every chunked admission starts with, whatever
             # prompt class this call warms
@@ -872,15 +906,17 @@ class ContinuousBatcher:
                 dslab = self._draft_prefill_fn(P, K)(
                     self._draft_params, jnp.zeros((K, P), jnp.int32),
                     jnp.ones((K,), jnp.int32))
-                self._draft_insert_jit.lower(
-                    self._draft_cache, dslab,
-                    jnp.zeros((K,), jnp.int32),
-                    jnp.ones((K,), jnp.int32)).compile()
+                with PROGRAM_BUILDS.build("engine", "draft_insert", key=K):
+                    self._draft_insert_jit.lower(
+                        self._draft_cache, dslab,
+                        jnp.zeros((K,), jnp.int32),
+                        jnp.ones((K,), jnp.int32)).compile()
                 jax.block_until_ready(jax.tree.leaves(dslab)[0])
             # lower+compile only: executing would donate the live caches
-            self._spec_jit.lower(self._cache, self._draft_cache,
-                                 self._toks, self._params,
-                                 self._draft_params).compile()
+            with PROGRAM_BUILDS.build("engine", "spec"):
+                self._spec_jit.lower(self._cache, self._draft_cache,
+                                     self._toks, self._params,
+                                     self._draft_params).compile()
         if self._kv is not None and self._ringed:
             # the snapshot an admission takes at its prompt's end
             self._kv.store_blocks(self._cache, 0, 0, [], warm=True)
@@ -952,11 +988,21 @@ class ContinuousBatcher:
                 "prefill_chunk": self._chunk_tokens,
                 "prefill_chunks": self._prefill_chunks,
                 "chunked_admissions": self._chunked_admissions,
+                **self._build_stats(),
                 **self._tick_stats(),
                 **self._kv_stats(),
                 **self._spec_stats(),
                 **self._block_stats(),
             }
+
+    def _build_stats(self) -> dict:
+        """What the ENGINE THREAD built since start, from the
+        program-build ledger: programs and seconds, spans and
+        unlabelled compiles alike.  ``warm()`` builds on its caller's
+        thread, so differenced over a window of traffic these are the
+        compiles that stalled the tick loop inside it."""
+        n, s = PROGRAM_BUILDS.thread_totals(self._thread.ident or 0)
+        return {"program_builds": n, "program_build_s": s}
 
     def _tick_stats(self) -> dict:
         """The tick ledger and the request-stage ledger, cumulative (a
@@ -1281,8 +1327,9 @@ class ContinuousBatcher:
             def zeros():
                 return _zeros_of(shapes)
 
-            fn = self._prefill_cache[key] = jax.jit(
-                zeros, out_shardings=shardings)
+            fn = self._prefill_cache[key] = PROGRAM_BUILDS.first_call(
+                jax.jit(zeros, out_shardings=shardings), "engine", key[0],
+                key, self._prefill_cache, key)
         return fn()
 
     def _fresh_cache(self, B: int):
@@ -1311,7 +1358,7 @@ class ContinuousBatcher:
         return sample_logits(logits, key, temperature=self._temperature,
                              top_k=self._top_k, top_p=self._top_p)
 
-    @_compiled(lambda P, K: (P, K))
+    @_compiled("prefill", lambda P, K: (P, K))
     def _prefill_fn(self, P: int, K: int):
         """Compiled per (prompt bucket, sub-batch size): fresh K-lane
         cache, prompt kv, one sampled next token per lane."""
@@ -1793,7 +1840,7 @@ class ContinuousBatcher:
                            self._lanes("draft", self._draft_model, B),
                            self._rep)
 
-    @_compiled(lambda P, K: ("draft", P, K))
+    @_compiled("draft", lambda P, K: ("draft", P, K))
     def _draft_prefill_fn(self, P: int, K: int):
         """Compiled per (bucket, sub-batch): the draft's prompt prefill
         beside every target admission — same padded ids/lens, no
@@ -2428,7 +2475,7 @@ class ContinuousBatcher:
                 self._chunk_lane_busy_s += time.monotonic() - st.t_start
             return None
 
-    @_compiled(lambda C: ("chunk", C))
+    @_compiled("chunk", lambda C: ("chunk", C))
     def _chunk_mid_fn(self, C: int):
         """Compiled per chunk size: advance a one-lane prefill slab by
         C prompt tokens (every token real — the only padded chunk is
@@ -2447,7 +2494,7 @@ class ContinuousBatcher:
         return jax.jit(mid, donate_argnums=(1,), out_shardings=(
             self._cache_shardings(1), self._rep))
 
-    @_compiled(lambda P: ("chunkfin", P))
+    @_compiled("chunkfin", lambda P: ("chunkfin", P))
     def _chunk_final_fn(self, P: int):
         """Compiled per suffix bucket: the last chunk — bucketed,
         token-masked, sampled at the prompt's true last position."""
@@ -2583,7 +2630,7 @@ class ContinuousBatcher:
                 self._failed_requests += 1
             return None
 
-    @_compiled(lambda P, n_pad: ("reuse", P, n_pad))
+    @_compiled("reuse", lambda P, n_pad: ("reuse", P, n_pad))
     def _reuse_prefill_fn(self, P: int, n_pad: int):
         """Compiled per (suffix bucket, PADDED chain length): fused
         gather-prefix + suffix prefill + sample.  ``prefix_len`` (the
@@ -2610,7 +2657,7 @@ class ContinuousBatcher:
 
         return jax.jit(prefill)
 
-    @_compiled(lambda n_pad: ("load", n_pad))
+    @_compiled("load", lambda n_pad: ("load", n_pad))
     def _load_prefix_fn(self, n_pad: int):
         """Compiled per PADDED chain length: a fresh one-lane slab with
         a hit's blocks and layer-state snapshot in it, its index at the

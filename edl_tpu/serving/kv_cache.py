@@ -81,10 +81,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from edl_tpu.obs.ledger import PROGRAM_BUILDS
 from edl_tpu.serving.cache_layout import ROWS, cache_specs
 from edl_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
+
+# the pool programs' names in the program-build ledger, where the memo's
+# key says less: one commit program a block count, the state snapshot
+_BUILD_FAMILY = {"scatter": "pool_commit", "store_state": "snapshot"}
 
 
 def pool_device_bytes(cache_shapes, block: int, n_blocks: int,
@@ -485,14 +490,20 @@ class PagedKVCache:
         — the body can never emit a collective).  ``in_specs`` is made
         on a mesh alone.  ``check_vma=False``: the bodies are all
         gathers/scatters by replicated indices, which the replication
-        checker cannot prove through."""
+        checker cannot prove through.  A program's first call is its
+        span ``build/kv/<family>`` in the program-build ledger
+        (``obs/ledger.py``); the memo then holds the bare jitted
+        function."""
         jit = self._jit_cache.get(key)
         if jit is None:
             if self._mesh is not None:
                 fn = jax.shard_map(
                     fn, mesh=self._mesh, in_specs=in_specs(),
                     out_specs=self._pool_specs(), check_vma=False)
-            jit = self._jit_cache[key] = jax.jit(fn, donate_argnums=donate)
+            name = key if isinstance(key, str) else key[0]
+            jit = self._jit_cache[key] = PROGRAM_BUILDS.first_call(
+                jax.jit(fn, donate_argnums=donate), "kv",
+                _BUILD_FAMILY.get(name, name), key, self._jit_cache, key)
         return jit
 
     # -- jitted device ops ---------------------------------------------------

@@ -55,6 +55,7 @@ from edl_tpu.train.state import TrainState, abstract_like
 from edl_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
+_BUILDS = obs_ledger.PROGRAM_BUILDS
 
 # step latency is the wall time between completed-step observations:
 # steps dispatch asynchronously, but with a bounded dispatch queue the
@@ -195,6 +196,7 @@ class ElasticTrainer:
         self.ckpt = self._build_ckpt()
         self._step_fn = None
         self._t_restored: float | None = None  # recovery instrumentation
+        self._builds_at_restored: dict = {}
         self._restore_source: str | None = None  # "peer"|"storage"|"delta"
         # delta-resize machinery (EDL_TPU_RESIZE_DELTA): the launcher's
         # resize flag is polled on the preempt cadence; _state_spec is
@@ -301,8 +303,12 @@ class ElasticTrainer:
         reference's DP layout).  Sharding is constrained *inside* the
         jitted init so ``tx.init`` inherits it and the optimizer state
         (momenta) comes out sharded like its parameters — the FSDP
-        memory win falls out of propagation, not bookkeeping."""
-        return jax.jit(self._build_fn(init_fn, tx, param_logical))()
+        memory win falls out of propagation, not bookkeeping.  The
+        program-build ledger's ``setup/train/state`` (``obs/ledger.py``),
+        waited for, so that the span holds the init's run."""
+        with _BUILDS.setup("train", key="create"):
+            return jax.block_until_ready(
+                jax.jit(self._build_fn(init_fn, tx, param_logical))())
 
     def _abstract_state(self, init_fn, tx, param_logical) -> TrainState:
         """Shape/dtype/sharding skeleton WITHOUT materialising arrays, so
@@ -327,23 +333,30 @@ class ElasticTrainer:
         if self.ckpt is None or self.ckpt.latest_step() is None:
             return self.create_state(init_fn, tx, param_logical), meta
         latest = self.ckpt.latest_step()
-        abstract = self._abstract_state(init_fn, tx, param_logical)
-        state, saved_meta = self._cache_first_restore(abstract, latest)
-        if state is None:
-            from edl_tpu.memstate.restore import RESTORE_SECONDS
-            t0 = time.perf_counter()
-            with obs_trace.get_tracer().span("train/restore", step=latest):
-                restored = self.ckpt.restore(abstract)
-            assert restored is not None
-            state, saved_meta = restored
-            self._restore_source = "storage"
-            RESTORE_SECONDS.labels(source="storage").observe(
-                time.perf_counter() - t0)
-            logger.info("restored step %d from storage (restore_source="
-                        "storage, %.1fs)", latest, time.perf_counter() - t0)
+        with _BUILDS.setup("train", key="restore"):
+            abstract = self._abstract_state(init_fn, tx, param_logical)
+            state, saved_meta = self._cache_first_restore(abstract, latest)
+            if state is None:
+                from edl_tpu.memstate.restore import RESTORE_SECONDS
+                t0 = time.perf_counter()
+                with obs_trace.get_tracer().span("train/restore",
+                                                 step=latest):
+                    restored = self.ckpt.restore(abstract)
+                assert restored is not None
+                state, saved_meta = restored
+                self._restore_source = "storage"
+                RESTORE_SECONDS.labels(source="storage").observe(
+                    time.perf_counter() - t0)
+                logger.info("restored step %d from storage (restore_source="
+                            "storage, %.1fs)", latest,
+                            time.perf_counter() - t0)
         if saved_meta is not None:
             meta = saved_meta
-        self._t_restored = time.time()  # recovery-time instrumentation
+        # recovery-time instrumentation: the ``restored`` stamp of the
+        # resize record and what the program-build ledger stood at then
+        # (_report_recovery hands over what was built up to the first step)
+        self._t_restored = time.time()
+        self._builds_at_restored = _BUILDS.totals()
         old_world = _last_world(meta)
         new_world = self.world_size
         if old_world and old_world != new_world:
@@ -496,13 +509,17 @@ class ElasticTrainer:
             metrics["loss"] = loss
             return new_state, metrics
 
-        return jax.jit(step, donate_argnums=(0,))
+        # the first call is the span build/train/step of the
+        # program-build ledger; _step_fn is the bare jitted step after it
+        return _BUILDS.first_call(jax.jit(step, donate_argnums=(0,)),
+                                  "train", "step", None, self, "_step_fn")
 
     @property
     def step_fn(self):
         if self._step_fn is None:
             self._step_fn = self._make_step()
         return self._step_fn
+
 
     @property
     def world_size(self) -> int:
@@ -791,7 +808,9 @@ class ElasticTrainer:
                 self.store, self.tenv.job_id, self.tenv.cluster_stage,
                 self.tenv.pod_id, restored=t_restored,
                 first_step=time.time(),
-                restore_source=self._restore_source)
+                restore_source=self._restore_source,
+                builds=recovery.build_fields(self._builds_at_restored,
+                                             _BUILDS.totals()))
         except Exception:  # noqa: BLE001 — metrics must never fail a job
             logger.exception("recovery record write failed")
 
@@ -864,7 +883,9 @@ class ElasticTrainer:
             return
 
         def run():
-            flops = obs_flops.xla_cost_flops(jitted, *args)
+            # booked to THIS thread's span, not to what the loop has open
+            with _BUILDS.build("train", "step_flops"):
+                flops = obs_flops.xla_cost_flops(jitted, *args)
             try:
                 denom = (obs_flops.peak_tflops(jax.devices()[0]),
                          jax.device_count())
@@ -1303,6 +1324,7 @@ class ElasticTrainer:
 
         t0 = time.monotonic()
         t_detect = time.time()
+        builds_at_detect = _BUILDS.totals()
         old_stage = self.tenv.cluster_stage
         old_world = self.tenv.world_size
         # drop every executable/compiled reference into the old backend
@@ -1346,7 +1368,8 @@ class ElasticTrainer:
         # 2. re-form the world in this process (leaks the old one —
         # see train/distributed.py's teardown contract), rebuild mesh
         with obs_trace.get_tracer().span("train/reshard",
-                                         mode=payload.mode):
+                                         mode=payload.mode), \
+                _BUILDS.setup("train", key="reshard"):
             # the OLD checkpoint manager is abandoned, never closed:
             # its close path can barrier against a world that no longer
             # exists (a dead peer on shrink).  Kept referenced so GC
@@ -1451,7 +1474,10 @@ class ElasticTrainer:
         self._preempt_seen = False
         self._preempt_next_check = None
         self._last_step_t = None
+        # a live reshard's record runs from the detection: so does what
+        # the program-build ledger says was built for it
         self._t_restored = t_detect
+        self._builds_at_restored = builds_at_detect
         self._restore_source = source
         ms_restore.RESTORE_SECONDS.labels(source=source).observe(
             time.monotonic() - t0)

@@ -67,8 +67,10 @@ MODEL_KEYS = ("moe_", "latent_", "ssm_", "decode_kv_tokens_", "borrowed_",
 
 # every stats() key of a paged engine without speculative decoding at PR
 # 45 (PR 43's and the borrowing layers' and the last-position cut's): the same set for all seven configurations (the benchmark's readers
-# are the contract)
+# are the contract); since PR 50 the two keys of the program-build
+# ledger (what the engine thread built) beside them
 ALL_KEYS = frozenset("""
+program_builds program_build_s
 active_slots admitted borrowed_kv_tokens_live borrowed_kv_tokens_prefill
 borrowed_kv_tokens_read chunk_lane_busy_s chunked_admissions
 prefill_layer_visits prefill_layer_visits_cut

@@ -274,7 +274,12 @@ def test_engine_phases_are_profiler_annotations(small, monkeypatch):
         eng.run_on_engine(lambda: None)
     finally:
         eng.stop()
-    assert names == {f"engine/{p}" for p in engine_mod.TICK_PHASES}
+    # beside them the program-build ledger's spans (ISSUE 50): the
+    # engine's state and what its first request builds
+    builds = {n for n in names if n.startswith(("build/", "setup/"))}
+    assert names - builds == {f"engine/{p}" for p in engine_mod.TICK_PHASES}
+    assert {"setup/engine/state", "build/engine/prefill",
+            "build/engine/step", "build/kv/pool_commit"} <= builds
 
 
 # -- the one-tick lookahead's counters (ISSUE 31) ----------------------------

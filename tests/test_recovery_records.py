@@ -101,3 +101,57 @@ def test_earliest_detector_and_last_finisher_win(memkv):
     s = summarize_recovery(memkv, "j2")[0]
     assert s["detect_at"] == t0
     assert s["total"] == 9.0  # earliest detect -> last first_step
+
+
+def test_trainer_half_carries_what_it_built(memkv):
+    """The trainer's half says how much of restored_to_first_step was
+    tracing and lowering, how much compiling, and whether the compile
+    cache hit (the program-build ledger's totals, differenced)."""
+    from edl_tpu.cluster.recovery import (BUILD_FIELDS, build_fields,
+                                          write_trainer_half)
+    from edl_tpu.obs.dump import render_report
+    before = {"build/train/step/trace_s": 1.0, "build/train/step/lower_s": 0.5,
+              "build/train/step/compile_s": 2.0,
+              "build/train/step/cache_hits": 1, "build/train/step/builds": 1}
+    after = {"build/train/step/trace_s": 1.5, "build/train/step/lower_s": 1.0,
+             "build/train/step/compile_s": 6.0,
+             "build/train/step/cache_hits": 1,
+             "build/train/step/cache_misses": 2,
+             "build/train/step/builds": 3,
+             # a row that did not exist at ``restored``
+             "build/other/add/lower_s": 0.25, "build/other/add/run_s": 9.0}
+    built = build_fields(before, after)
+    assert built == {"build_trace_lower_s": 1.25, "build_compile_s": 4.0,
+                     "build_cache_hits": 0, "build_cache_misses": 2}
+    assert tuple(built) == BUILD_FIELDS
+    put(memkv, "jb", "s1", "launcher", "podA",
+        {"detect": 10.0, "killed": 11.0, "barrier": 11.5, "spawn": 12.0})
+    write_trainer_half(memkv, "jb", "s1", "podA", restored=14.0,
+                       first_step=20.0, restore_source="peer", builds=built)
+    (s,) = summarize_recovery(memkv, "jb")
+    assert s["restored_to_first_step"] == 6.0
+    assert {f: s[f] for f in BUILD_FIELDS} == built
+    text = render_report({"job": dict.fromkeys(
+        ("job_id", "job_status", "stage", "pods_running", "cluster_pods",
+         "live_pods", "world_size", "train_status", "resizes"), 0),
+        "resizes": [s]})
+    assert "trace+lower 1.250s  compile 4.000s" in text
+    assert "0 hit(s) / 2 miss(es)" in text
+
+
+def test_a_trainer_half_without_build_fields_still_merges(memkv):
+    """An older trainer's half has no ``build_*`` fields: the record
+    merges as before and the summary simply lacks them."""
+    from edl_tpu.cluster.recovery import BUILD_FIELDS, write_trainer_half
+    put(memkv, "jo", "s1", "launcher", "podA",
+        {"detect": 10.0, "killed": 11.0, "barrier": 11.5, "spawn": 12.0})
+    put(memkv, "jo", "s1", "trainer", "podA",
+        {"restored": 14.0, "first_step": 15.0})
+    # and a newer one beside it that finished first
+    write_trainer_half(memkv, "jo", "s1", "podB", restored=13.0,
+                       first_step=14.5, builds={
+                           "build_trace_lower_s": 0.1, "build_compile_s": 0.2,
+                           "build_cache_hits": 3, "build_cache_misses": 0})
+    (s,) = summarize_recovery(memkv, "jo")
+    assert s["restored_to_first_step"] == 1.0 and s["total"] == 5.0
+    assert not [f for f in BUILD_FIELDS if f in s]
