@@ -1967,14 +1967,33 @@ def lm_loss(logits, targets, mask=None):
     return _masked_mean(nll, mask)
 
 
+def _head_is_whole(cfg: TransformerConfig) -> bool:
+    """Whether the head's ``[D, V]`` weight lies whole on every device: no
+    mesh, or a mesh none of whose axes splits ``embed`` or ``vocab``
+    (``_fences_norms``' rule, for the head)."""
+    return cfg.mesh is None or logical_sharding(
+        ("embed", "vocab"), cfg.mesh).is_fully_replicated
+
+
 def lm_loss_fused(params, hidden, targets, cfg: TransformerConfig,
                   mask=None, block_size: int = 4096):
     """Next-token CE from ``apply(..., return_hidden=True)`` hidden
-    states, via the blockwise fused kernel (edl_tpu/ops/ce.py) — the
-    [B, L, V] logits (~1 GiB at the flagship config) are never
-    materialised.  Numerically equivalent to ``lm_loss`` of the dense
-    head: the same bf16-cast matmul with f32 accumulation."""
-    from edl_tpu.ops.ce import blockwise_cross_entropy
+    states: ``lm_loss`` of the dense head (the same bf16-cast matmul with
+    f32 accumulation, f32 softmax) without its ``[B, L, V]`` logits, by
+    one of the two paths of ``edl_tpu/ops/ce.py``.
+
+    Where the head's weight lies whole on every device (``_head_is_whole``:
+    no mesh, a mesh of one, a mesh that splits the batch or the sequence
+    alone) one sweep over blocks of tokens, which under differentiation
+    forms the gradients while a block's logits are at hand: three
+    head-sized matmuls a step, and a whole float32 ``[D, V]`` gradient
+    resident beside the head.  Where the head is split (``fsdp``, ``tp``)
+    the loop over blocks of ``block_size`` columns of the vocabulary,
+    which never holds the head whole and pays for that with a fourth
+    matmul, the logits computed again in the backward.  The sweep took
+    33 ms off a 491 ms step on one v5e chip (PERF.md section 6, PR 51).
+    ``block_size`` is the loop's alone; the sweep sizes its own blocks."""
+    from edl_tpu.ops.ce import blockwise_cross_entropy, sweep_cross_entropy
 
     if cfg.tie_embeddings:
         w = params["tok_embed"]["embedding"].T
@@ -1985,6 +2004,15 @@ def lm_loss_fused(params, hidden, targets, cfg: TransformerConfig,
         # head's divided by logits_scaling
         hidden = (hidden.astype(jnp.float32) / cfg.logits_scaling).astype(
             hidden.dtype)
-    nll = blockwise_cross_entropy(hidden, w.astype(hidden.dtype), targets,
-                                  block_size=block_size)
+    w = w.astype(hidden.dtype)
+    if _head_is_whole(cfg):
+        # the mean's weights are known before the sweep, so the sweep's
+        # cotangent is one number and its backward only scales
+        if mask is None:
+            token_weight = jnp.full(targets.shape, 1.0 / targets.size)
+        else:
+            token_weight = mask / jnp.maximum(mask.sum(), 1)
+        return sweep_cross_entropy(hidden, w, targets, token_weight,
+                                   mesh=cfg.mesh)
+    nll = blockwise_cross_entropy(hidden, w, targets, block_size=block_size)
     return _masked_mean(nll, mask)

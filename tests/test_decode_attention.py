@@ -893,6 +893,9 @@ def test_fsdp_train_step_for_v5e_gathers_weights_not_activations(
     moved = [dtype for _, dtype, dims in collectives(text)
              if squeezed(dims) in shards]
     assert len(moved) >= 10 and set(moved) == {"bf16"}
+    # the head is split, so the loss keeps the loop over blocks of the
+    # vocabulary, which gathers one block at a time (PR 51)
+    assert "ce/vocab_blocks" in text and "ce/sweep" not in text
     # the parent's step kept 4.57 GB of temporaries at this depth.  This
     # one does not keep fewer, as ISSUE 29 expected: the scheduler holds
     # the next matmuls' weight windows in flight (the peak measured on
@@ -929,6 +932,17 @@ def test_one_chip_train_step_for_v5e_keeps_norms_out_of_its_matmuls(
         text, under=("layers/", "final_norm")) == []
     # the helper still sees a matmul with a reduce where one is: the CE's
     assert len(matmul_fusions_with_reduce(text)) == 1
+    # the head is whole: one sweep over blocks of tokens, which multiplies
+    # by it three times (logits, dhidden, dW) and leaves the backward no
+    # matmul (PERF.md section 6, PR 51)
+    head = [line.split('op_name="')[1].split('"')[0]
+            for line in text.splitlines()
+            if " convolution(" in line and "ce/" in line]
+    assert len(head) == 3 and all(
+        name.startswith("jit(step)/jvp(ce/sweep)/while/body/")
+        for name in head)
+    assert "ce/vocab_blocks" not in text
     # 6.806 GB on the parent, 6.865 GB fenced: the norms' outputs are
-    # whole arrays now (of the chip's 16.9 GB the state takes 8.456)
+    # whole arrays now; 6.873 GB with the head swept (of the chip's
+    # 16.9 GB the state takes 8.456)
     assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9

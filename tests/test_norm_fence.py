@@ -5,7 +5,6 @@ The compiled one-chip train step that it is for is held in
 """
 
 import dataclasses
-import math
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +14,7 @@ import pytest
 from edl_tpu.models import transformer as tf_mod
 from edl_tpu.models.transformer import (TransformerConfig, TransformerLM,
                                         lm_loss_fused)
-from edl_tpu.parallel import MeshSpec, build_mesh
+from tests.helpers.meshes import mesh_of as _mesh
 
 CFG = TransformerConfig(vocab_size=64, num_layers=2, embed_dim=32,
                         num_heads=4, num_kv_heads=2, mlp_dim=64, max_len=16,
@@ -44,11 +43,6 @@ def _barriers(cfg) -> int:
     return str(jax.make_jaxpr(
         lambda v: model.apply(v, ids, mutable=mutable))(variables)).count(
             "optimization_barrier")
-
-
-def _mesh(**axes):
-    n = math.prod(axes.values())
-    return build_mesh(MeshSpec(**{"dp": 1, **axes}), jax.devices()[:n])
 
 
 def _loss_and_grads(cfg):
