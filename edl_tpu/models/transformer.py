@@ -164,6 +164,10 @@ class TransformerConfig:
     # window layers (both False: no positional embedding anywhere)
     rope_global: bool = True
     rope_window: bool = True
+    # an output gate on the "global" layers' attention (a published
+    # ``use_gqa_gate``): the heads' output times sigmoid(y W_gate)
+    # elementwise, y the layer's normed input, before the output matrix
+    attn_gate: bool = False
     # qk_norm over each head's head_dim (scale [head_dim]) instead of
     # over the whole projection
     qk_norm_per_head: bool = False
@@ -216,6 +220,10 @@ class TransformerConfig:
     kda_conv: int = 4
     kda_chunk: int = 64
     kda_state_dtype: Any = jnp.float32
+    # beta = 2 sigmoid(b) in place of sigmoid(b) (a published
+    # ``kda_allow_neg_eigval``): the transition I - beta k k^T then has
+    # an eigenvalue in [-1, 1] and not in [0, 1]
+    kda_neg_eigval: bool = False
     # -- layer_attn[i] == "latent" is a multi-head latent attention layer
     # (``LatentAttention``, ``ops/latent_attention.py``): num_heads
     # heads, keys and values through one latent of mla_rank
@@ -498,6 +506,8 @@ def _layer_matmul_params(cfg: TransformerConfig, experts: int,
                 + H * cfg.mla_v_dim * D)
     else:
         attn = D * (H + 2 * Hk) * Dh + H * Dh * D
+        if cfg.attn_gate and kind == "global":
+            attn += D * H * Dh
     if cfg.mlp_kind(layer) == "dense":
         return attn + 3 * D * cfg.mlp_dim
     return (attn + D * cfg.moe_experts + 3 * D * cfg.moe_shared_dim
@@ -1046,8 +1056,11 @@ class KDAMixer(nn.Module):
     depthwise convolution over ``kda_conv`` positions (no bias) and a
     SiLU; ``q``, ``k`` L2-normalised a head and ``q`` scaled by
     ``R ** -0.5``; ``g = -exp(A_log) * softplus(f W_f + dt_bias)`` a key
-    channel, ``beta = sigmoid(b)``; the recurrence;
-    ``RMSNorm_head(o) * sigmoid(z W_g)``; ``W_out``.
+    channel, ``beta = sigmoid(b)`` in [0, 1], or ``2 sigmoid(b)`` in [0,
+    2] under ``kda_neg_eigval`` (the benchmark's
+    ``solar-open2-250b-serve-ep8`` doubles it; ``kimi-linear-48b-a3b-
+    serve-ep4`` does not); the recurrence; ``RMSNorm_head(o) *
+    sigmoid(z W_g)``; ``W_out``.
 
     In a decode model its ``cache`` is ``conv_state [B, kda_conv - 1, 3
     H R]`` (time-major, the compute dtype), ``kda_state [B, H, R, R]``
@@ -1055,8 +1068,10 @@ class KDAMixer(nn.Module):
     contract to the letter (one-token calls update in place,
     ``ops/kda.kda_step`` on the chip, free slots keep theirs; multi-token
     calls run the chunked form FROM the cached state to each lane's last
-    REAL token; ``snap_at`` sows the state at one more position into the
-    ``snap`` collection under the cache's names)."""
+    REAL token, the lanes one after another where one lane's blocks are
+    large, ``ops/kda.lanes_mapped``; ``snap_at`` sows the state at one
+    more position into the ``snap`` collection under the cache's
+    names)."""
 
     cfg: TransformerConfig
 
@@ -1109,6 +1124,8 @@ class KDAMixer(nn.Module):
         g = (jax.nn.softplus(f.astype(f32) + dt_bias).reshape(B, L, H, R)
              * A[:, None])
         beta = jax.nn.sigmoid(b.astype(f32))                     # [B, L, H]
+        if cfg.kda_neg_eigval:
+            beta = 2.0 * beta
 
         if cached and L == 1:
             live = (jnp.ones((B,), bool) if token_mask is None
@@ -1416,7 +1433,13 @@ class Block(nn.Module):
         program's entry and again at its exit (PERF.md section 6,
         PR 27): the reason the kernels exist.  ``token_mask`` is not
         used there: free slots write and read ballast, as they always
-        did."""
+        did.
+
+        A multi-token call whose dense scores ``[H, L, max_len]`` could
+        not exist (``ops/decode_attention.prefix_tiled``, from the
+        shapes alone) writes its rows the same way and then attends the
+        slot's LIVE PREFIX in tiles, one softmax carried across them
+        (``prefix_chunk_attention``, scope ``attn/prefix_chunk``)."""
         cfg = self.cfg
         B, L, H, Dh = q.shape
         Hk = k.shape[2]
@@ -1477,6 +1500,11 @@ class Block(nn.Module):
         if cfg.block_length:
             # block-causal: a query sees up to its own block's last row
             q_pos = (q_pos // cfg.block_length + 1) * cfg.block_length - 1
+        if decode_attention.prefix_tiled(L, H, cfg.max_len):
+            # dense scores that fit nowhere: the live prefix in tiles
+            return decode_attention.prefix_chunk_attention(
+                q, ck.value, cv.value, q_pos, jnp.max(idx) + L,
+                scale=cfg.softmax_scale)
         mask = (jnp.arange(cfg.max_len)[None, None, :]
                 <= q_pos[:, :, None])                     # [B, L, max]
         return self._masked_attention(q, ck.value, cv.value, mask,
@@ -1660,6 +1688,14 @@ class Block(nn.Module):
         if cfg.diff_attn:
             attn = self._diff_combine(attn)
         attn = _pin(cfg, attn.reshape(B, L, H * Dh), "batch", "seq", "heads")
+        if cfg.attn_gate and kind == "global":
+            with jax.named_scope("attn/out_gate"):
+                gate = nn.DenseGeneral((H * Dh,), use_bias=False,
+                                       dtype=cfg.dtype,
+                                       param_dtype=jnp.float32,
+                                       name="attn_gate")(y)
+                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
         return nn.DenseGeneral(cfg.embed_dim, use_bias=cfg.attn_bias,
                                dtype=cfg.dtype, param_dtype=jnp.float32,
                                name="attn_out")(attn), shared

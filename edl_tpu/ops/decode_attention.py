@@ -40,6 +40,19 @@ Precision is the einsum path's: input-dtype matmuls accumulated in
 float32, scores and softmax statistics in float32, probabilities cast
 to the cache dtype before the second matmul.
 
+A MULTI-token call (a chunk, a bucketed or reuse prefill) attends the
+whole slab under its mask on the einsum path: ``[H, L, max_len]``
+float32 scores a lane, whatever the prefix holds.  Where those cannot
+exist (:func:`prefix_tiled`: one lane's are past ``_DENSE_SCORES``; 64
+heads, 256 queries and a slab of 114,688 rows are 7.5 GB)
+:func:`prefix_chunk_attention` reads the slabs as they lie in tiles of
+rows UP TO THE CALL'S LAST POSITION with one softmax carried across
+them: an XLA loop with a dynamic trip count (``ops/latent_attention.
+expanded_attention``'s pattern, PR 38), under the scope
+``attn/prefix_chunk``.  A Pallas kernel was not written: the loop's
+tiles are MXU-sized matmuls already, and the call is rare beside the
+token steps it is timed with (PERF.md section 6, PR 52).
+
 :func:`applies` is the dispatch rule, from what the caller can observe
 and nothing else.  Off a TPU the kernels run in Pallas interpret mode
 (the tier-1 parity tests); nothing selects them there.
@@ -77,6 +90,90 @@ def applies(L: int, mesh, max_len: int) -> bool:
     engine, any other backend) stays on the einsum path."""
     return (L == 1 and mesh is None and max_len % _LANES == 0
             and _on_tpu())
+
+
+# One lane's dense float32 scores past which a multi-token call cannot
+# attend the whole slab under a mask: an eighth of a v5e chip's memory,
+# more than an engine that also holds weights, slots and a pool finds
+# beside them for ONE temporary (``serving/engine._require_fit``).  The
+# benchmark's other served shapes lie under it by half at least (64
+# heads x 256 queries x 16,384 rows: 1.07 GB) and keep the dense path,
+# program for program
+_DENSE_SCORES = 2 << 30
+# what one tile of :func:`prefix_chunk_attention` holds at most: its
+# float32 scores and its probabilities in the cache dtype
+_TILE_BYTES = 48 << 20
+
+
+def prefix_tiled(L: int, H: int, max_len: int) -> bool:
+    """Whether a decode model's ``L``-token call of ``H`` query heads
+    against slabs of ``max_len`` rows attends the live prefix in tiles
+    (:func:`prefix_chunk_attention`): where one lane's dense scores
+    ``[H, L, max_len]`` in float32 are past ``_DENSE_SCORES``.  From the
+    shapes alone: the model dispatches by it, the engine prices
+    (``cache_layout.HeadRows.scores_bytes``) and counts
+    (``model_counters``) by it, and no setting reads into it."""
+    return (L > 1 and max_len % _LANES == 0
+            and 4 * H * L * max_len > _DENSE_SCORES)
+
+
+def prefix_block(lanes: int, L: int, H: int, max_len: int,
+                 itemsize: int = 2) -> int:
+    """Rows one tile of :func:`prefix_chunk_attention` reads: the
+    largest power-of-two multiple of 128 that divides ``max_len`` and
+    keeps the tile's ``[lanes, H, L, rows]`` float32 scores and its
+    probabilities within ``_TILE_BYTES`` (512 rows for one lane of 256
+    queries at 64 heads, 128 for 1024)."""
+    row = lanes * H * L * (4 + itemsize)
+    tk = _LANES
+    while max_len % (2 * tk) == 0 and 2 * tk * row <= _TILE_BYTES:
+        tk *= 2
+    return tk
+
+
+def prefix_chunk_attention(q, keys, values, q_pos, limit, *, scale: float):
+    """``q [B, L, H, D]`` against the slabs as they lie, keys ``[B, Hk,
+    D, T]`` and values ``[B, Hk, T, D]`` (the call's own rows already in
+    them), query l of lane b seeing rows ``<= q_pos[b, l]``; ``limit``
+    the number of leading rows that can matter (``max(q_pos) + 1``,
+    traced: rows past its last tile are not read).  Grouped as the
+    einsum path (query head h on head ``h // (H // Hk)``), its
+    precision recipe: input-dtype matmuls accumulated in float32,
+    float32 statistics, probabilities in the cache dtype.  Returns ``[B,
+    L, H, D]`` in ``q``'s dtype."""
+    B, L, H, D = q.shape
+    Hk, T = keys.shape[1], keys.shape[3]
+    tk = prefix_block(B, L, H, T, jnp.dtype(values.dtype).itemsize)
+    qg = jnp.moveaxis(q.reshape(B, L, Hk, H // Hk, D), 1, 3)  # [B,Hk,G,L,D]
+    f32 = jnp.float32
+    see = q_pos[:, None, None, :, None]
+
+    def tile(j, carry):
+        m, l, acc = carry
+        k = jax.lax.dynamic_slice_in_dim(keys, j * tk, tk, axis=3)
+        v = jax.lax.dynamic_slice_in_dim(values, j * tk, tk, axis=2)
+        s = jnp.einsum("bhgld,bhdt->bhglt", qg, k,
+                       preferred_element_type=f32) * scale
+        s = jnp.where(j * tk + jnp.arange(tk) <= see, s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        # every query sees row 0, so from the first tile on m_new is a
+        # real score and a masked row's exp(_NEG - m_new) is exactly 0
+        p = jnp.exp(s - m_new[..., None])
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bhglt,bhtd->bhgld", p.astype(values.dtype), v,
+            preferred_element_type=f32)
+        return m_new, alpha * l + p.sum(-1), acc
+
+    G = H // Hk
+    carry = (jnp.full((B, Hk, G, L), _NEG, f32),
+             jnp.zeros((B, Hk, G, L), f32),
+             jnp.zeros((B, Hk, G, L, D), f32))
+    with jax.named_scope("attn/prefix_chunk"):
+        tiles = -(-jnp.minimum(limit, T) // tk)
+        _, l, acc = jax.lax.fori_loop(0, tiles, tile, carry)
+        out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+        return jnp.moveaxis(out, 3, 1).reshape(B, L, H, D).astype(q.dtype)
 
 
 def block_applies(L: int, mesh, max_len: int, dtype) -> bool:

@@ -6,8 +6,20 @@ width, ``V`` the value width)::
 
     S'  = Diag(exp(g_t)) S_{t-1}           g_t <= 0, one log decay a key channel
     u_t = v_t - S'^T k_t                   the delta correction
-    S_t = S' + beta_t k_t u_t^T            0 <= beta_t <= 1
+    S_t = S' + beta_t k_t u_t^T            0 <= beta_t <= 2
     o_t = S_t^T q_t
+
+``beta_t`` is the caller's: ``sigmoid(b)`` in [0, 1], or ``2 sigmoid(b)``
+in [0, 2] under ``TransformerConfig.kda_neg_eigval`` (a published
+``kda_allow_neg_eigval``; the benchmark's ``solar-open2-250b-serve-ep8``
+doubles it, ``kimi-linear-48b-a3b-serve-ep4`` does not).  With ``k_t`` of
+unit norm the transition ``I - beta_t k_t k_t^T`` then has an eigenvalue
+``1 - beta_t`` in [-1, 1] and not in [0, 1]: a state can flip along a
+key, not only fade.  Nothing below assumes the smaller range; the chunked
+form's unit triangular solve is exact either way and worse conditioned
+(its off-diagonal entries double), which is what its parity limits are
+read for (``tests/test_solar_open2.py`` with betas in (1, 2]; on the
+chip PERF.md section 6, PR 52).
 
 (the convolutions, the L2 norms, the scale on ``q``, the gate and the
 output norm are the caller's: ``transformer.KDAMixer``).  Three ways to
@@ -26,7 +38,10 @@ run it, shaped like ``ops/ssm.py``:
   ``s <= t`` (never ``exp(G_t) / exp(G_s)``: a channel that has decayed
   to nothing would divide by zero).  Between chunks the recurrence runs
   over one state a chunk (``lax.scan``), so the blocks are ``[B, H,
-  chunk, chunk(, K)]`` float32 whatever ``L`` is.  It returns the
+  chunk, chunk(, K)]`` float32 whatever ``L`` is, and ``[H, chunk,
+  chunk, K]`` whatever ``B`` is where one lane's reaches ``_LANE_BLOCK``
+  (:func:`lanes_mapped`: the lanes then run one after another).  It
+  returns the
   outputs, the state after each lane's last REAL token, and the state at
   one more position a lane (``snap_at``: where the serving engine
   snapshots a prompt for its prefix pool).  ``lengths`` masks positions
@@ -86,6 +101,33 @@ def slots_fetched(live, H: int, K: int, V: int):
 
 # -- the chunked form ---------------------------------------------------------
 
+# One lane's decay block ``[H, chunk, chunk, K]`` float32 from which a
+# call's lanes run one after another: 128 MiB, 64 heads of 128 at a
+# chunk of 64 (32 heads are 64 MiB and keep their lanes side by side).
+_LANE_BLOCK = 128 << 20
+
+
+def lanes_mapped(H: int, K: int, chunk: int) -> bool:
+    """Whether a multi-lane :func:`kda_chunked` call runs its lanes one
+    after another (``lax.map``) and not side by side: where ONE lane's
+    ``[H, chunk, chunk, K]`` float32 decay block reaches ``_LANE_BLOCK``.
+    From the widths alone, whatever the call's length, so a layer's
+    multi-lane programs are all of one form.  Two grounds.  A scan step
+    of one such lane already holds several blocks of 128 MiB and fills
+    the chip: two lanes x 512 tokens mapped take twice one lane's 18 ms
+    on a v5e (PR 52), so side by side buys nothing.  And side by side the
+    chip's compiler has built a program that NEVER RETURNS: two lanes x
+    8 chunks x 64 heads through two such layers in a row (one layer, one
+    lane of 16 chunks, two lanes of 4 chunks, 32 heads all return;
+    ``scripts/chip_kda_two_lane_prefill.py`` runs both forms).  The
+    fault is below this file; a lane at a time, each inner program is
+    the one-lane program that runs.  (Every caller's lanes: a training
+    batch at these widths is mapped too, and one SHARDED over a mesh
+    would serialise its shards; no configuration here trains this
+    stack or serves it on a mesh, which the engine refuses by name.)"""
+    return 4 * H * chunk * chunk * K >= _LANE_BLOCK
+
+
 def kda_chunked(q, k, v, g, beta, state, *, chunk: int = 64, lengths=None,
                 snap_at=None):
     """``q`` / ``k [B, L, H, K]`` (normalised, ``q`` scaled), ``v [B, L,
@@ -96,8 +138,22 @@ def kda_chunked(q, k, v, g, beta, state, *, chunk: int = 64, lengths=None,
     with ``snap_at`` ``[B]`` int, the state after token ``snap_at[b] -
     1`` (0 = the initial state; clipped to ``[0, lengths]``), else
     None."""
+    B, L, H, K = q.shape
     with jax.named_scope("kda/chunk"):
-        return _kda_chunked(q, k, v, g, beta, state, chunk, lengths, snap_at)
+        if B == 1 or not lanes_mapped(H, K, chunk):
+            return _kda_chunked(q, k, v, g, beta, state, chunk, lengths,
+                                snap_at)
+        n = (jnp.full((B,), L, jnp.int32) if lengths is None else lengths)
+        at = jnp.zeros((B,), jnp.int32) if snap_at is None else snap_at
+
+        def lane(args):
+            o, final, snap = _kda_chunked(*(a[None] for a in args[:6]),
+                                          chunk, args[6][None],
+                                          args[7][None])
+            return o[0], final[0], snap[0]
+
+        o, final, snap = jax.lax.map(lane, (q, k, v, g, beta, state, n, at))
+        return o, final, (None if snap_at is None else snap)
 
 
 def _kda_chunked(q, k, v, g, beta, state, chunk, lengths, snap_at):

@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from edl_tpu.ops import latent_attention
+from edl_tpu.ops import decode_attention, latent_attention
 
 
 def _leaf_key(path) -> str:
@@ -238,7 +238,14 @@ class HeadRows(CacheClass):
 
     def scores_bytes(self, lanes, width, heads, cache_len):
         # a multi-token call attends the whole slab under its mask: one
-        # layer's [heads, width, cache_len] float32 scores
+        # layer's [heads, width, cache_len] float32 scores; where those
+        # could not exist (ops/decode_attention.prefix_tiled) a tile of
+        # them at a time, with the probabilities and the carried
+        # float32 output
+        if decode_attention.prefix_tiled(width, heads, cache_len):
+            tile = decode_attention.prefix_block(lanes, width, heads,
+                                                 cache_len)
+            return width * heads * (8 * tile + 8 * 128)
         return 4 * width * heads * cache_len
 
 

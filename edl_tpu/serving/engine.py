@@ -2586,10 +2586,18 @@ class ContinuousBatcher:
             block_ids = np.zeros((n_pad,), np.int32)
             block_ids[:n] = [nd.block_id for nd in chain]
             self._rng, key = jax.random.split(self._rng)
-            hit = (self._kv.pool, jnp.asarray(block_ids),
-                   jnp.asarray(prefix_len, jnp.int32))
             n_real = jnp.asarray([len(suffix)], jnp.int32)
             snap_id = self._kv.snap_arg(chain[-1].snap)
+            hit = (self._kv.pool, jnp.asarray(block_ids),
+                   jnp.asarray(prefix_len, jnp.int32))
+            # what the hit brings back into a lane: the chain's blocks
+            # and, for a state class, one snapshot (the span is the
+            # host's enqueue; the device's part is the ``load`` program,
+            # or the fused prefill's gather)
+            reattach = obs_trace.annotation(
+                "engine/reattach", blocks=n, padded=n_pad,
+                bytes=n * self._kv.block_bytes,
+                snapshot=int(bool(chain[-1].snap)))
             if self._recurrent:
                 # two programs where the others fuse them: the gather
                 # alone (small: one attention layer's blocks and the
@@ -2603,15 +2611,17 @@ class ContinuousBatcher:
                 # in four pairs of four (PERF.md section 6, PR 32)
                 at = jnp.asarray([max(self._snap_end(req) - prefix_len, 0)],
                                  jnp.int32)
+                with reattach:
+                    lane = self._load_prefix_fn(n_pad)(*hit, snap_id)
                 slab, toks, sown, snap = self._chunk_final_fn(P)(
-                    self._params, self._load_prefix_fn(n_pad)(*hit, snap_id),
-                    jnp.asarray(ids), n_real,
+                    self._params, lane, jnp.asarray(ids), n_real,
                     self._zeros(("acc",), self._acc_shape, None),
                     key, at)
             else:
-                slab, toks, sown, snap = self._reuse_prefill_fn(P, n_pad)(
-                    self._params, *hit, jnp.asarray(ids), n_real, key,
-                    snap_id)
+                with reattach:
+                    slab, toks, sown, snap = self._reuse_prefill_fn(
+                        P, n_pad)(self._params, *hit, jnp.asarray(ids),
+                                  n_real, key, snap_id)
             self._count_enqueue()
             self._counters.on_prefill(1, P, len(suffix), prefix_len)
             # insert true_lens = the FULL prompt length: the slab's
@@ -2715,7 +2725,7 @@ class ContinuousBatcher:
         end = self._snap_end(req)
         if end <= req.skipped:     # nothing new: the hit's own snapshot
             return
-        sid = 0 if end < base else self._kv.snap_alloc()
+        sid = 0 if end < base else self._kv.snap_alloc(req.session)
         if not sid:
             self._state_snap_skips += self._recurrent
             return

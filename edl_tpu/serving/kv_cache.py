@@ -273,19 +273,37 @@ class PagedKVCache:
         return self.reusable(chain[:-1])
 
     # -- window snapshots ----------------------------------------------------
-    def snap_alloc(self) -> int:
+    def snap_alloc(self, session: str | None = None) -> int:
         """A free snapshot entry for the caller to write
         (``store_blocks``) and then ``snap_attach`` or ``snap_release``:
         0 when no layer keeps snapshots or every entry belongs to a
-        pinned chain (counted)."""
+        pinned chain (counted).  A turn of ``session`` takes the entry
+        of the session's OWN last prompt snapshot (where nothing else
+        pins it; the turn is about to write a deeper one) when the
+        entries are short: before a free one where the free entries are
+        fewer than the pinned sessions, each of which asks for one more
+        on its next turn, and in any case before it is refused.  One
+        entry a session at a time is what lets six forty-turn sessions
+        live on eight entries: with an entry a pinned session and one
+        more a live turn, a session whose first turn found them all
+        pinned went without for good and re-prefilled its whole context
+        every turn (PERF.md section 6, PR 52)."""
         if not self._snapped:
             return 0
-        if self._snap_free:
-            return self._snap_free.pop()
-        victim = next((nd for nd in self._snap_lru if not nd.pins), None)
+        own = self._session_snaps.get(session)
+        if own is not None and own.pins != 1:
+            own = None
+        victim = None
+        if own is None or len(self._snap_free) >= len(self._sessions):
+            if self._snap_free:
+                return self._snap_free.pop()
+            victim = next((nd for nd in self._snap_lru if not nd.pins), None)
         if victim is None:
-            self.snap_skips += 1
-            return 0
+            if own is None:
+                self.snap_skips += 1
+                return 0
+            self._unpin(self._session_snaps.pop(session))
+            victim = own
         return self._drop_snap(victim)
 
     def snap_attach(self, node: "_Node | None", sid: int) -> None:
@@ -462,6 +480,14 @@ class PagedKVCache:
         return [t for nd in chain for t in nd.chunk]
 
     # -- stats ---------------------------------------------------------------
+    @property
+    def block_bytes(self) -> int:
+        """Bytes one block holds over every layer paged by blocks."""
+        return sum(int(np.prod(buf.shape[1:])) * buf.dtype.itemsize
+                   for name, node in self.pool.items()
+                   if not self._cls[name].snapshotted
+                   for buf in node.values())
+
     def blocks_used(self) -> int:
         return self.n_blocks - 1 - len(self._free)
 

@@ -70,6 +70,10 @@ SCOPES = {
                "mamba1/proj_out"),
     "gmu": ("gmu",),
     "cross": ("attn/cross", "attn/diff_combine"),
+    # a "global" layer's own: its multi-token call over the live prefix
+    # in tiles (where ops/decode_attention.prefix_tiled holds) and its
+    # output gate (TransformerConfig.attn_gate)
+    "global": ("attn/prefix_chunk", "attn/out_gate"),
     # not a mixer kind: a block engine's pass program (block_length > 0)
     # and, on the chip, its kernels' names
     "block": ("block_pass", "attn/block_pass", "block_unmask",
@@ -89,6 +93,16 @@ KEYS = {
     # window holds, min(length, window): read / need is 1 when a step
     # reads the window and no more, max_len / window when it reads a slab
     "decode_kv_tokens_window_read": 0, "decode_kv_tokens_window_need": 0,
+    # head-row layers (a "global" layer's K / V slabs), per multi-token
+    # call (prefill, chunk, reuse), lane and layer: rows up to the
+    # call's last position, and the rows its attention read of the slab
+    # (whole tiles up to there on the tiled path, the slab under its
+    # mask on the dense one: read / live is 1 for a path that stops at
+    # the slot's length, max_len / live for one that does not)
+    "kv_prefill_rows_live": 0, "kv_prefill_rows_read": 0,
+    # and the (query, visible row) pairs of those calls' real tokens, a
+    # head-row layer (a real token at position p sees p + 1 rows)
+    "kv_prefill_pairs": 0,
     # the bytes of one slot's state by cache class: the window layers'
     # rings, a recurrent state and (no key) nothing else are independent
     # of max_len; the global layers' slabs and the latent layers' rows
@@ -170,6 +184,7 @@ class ModelCounters:
         self._n_ring, self._n_state, self._n_latent = (
             by["window"], by["state"], by["latent"])
         self._n_borrowed = by["borrowed"]
+        self._n_rows = by["global"]
         self._tail = cfg.num_layers - cfg.tail_start
         self._latent = next((c for c in classes.values()
                              if c.kind == "latent"), None)
@@ -200,16 +215,31 @@ class ModelCounters:
         """One prefill, chunk or reuse program ran ``lanes x width``
         positions from ``offset`` on, ``real`` of them tokens (``lens``
         a lane where there are several), through every recurrent
-        layer's scan and every latent layer's expanded path (rows up to
+        layer's scan, every head-row layer's attention over its slab
+        and every latent layer's expanded path (rows up to
         the call's end, read in whole tiles of the path that ran, the
         kernel or the loop: the rule and the plan the program was built
         under, nothing read back from the device).  ``final``: the
         program sampled (a stack with a tail ran it at a row a lane; a
         chunk that samples nothing left it out)."""
-        if not (self._n_state or self._n_latent or self._tail):
+        if not (self._n_state or self._n_latent or self._tail
+                or self._n_rows):
             return
         t = self._t
         with self._lock:
+            if self._n_rows:
+                cfg, end = self._cfg, offset + width
+                read = cfg.max_len
+                if decode_attention.prefix_tiled(width, cfg.num_heads,
+                                                 cfg.max_len):
+                    tk = decode_attention.prefix_block(
+                        lanes, width, cfg.num_heads, cfg.max_len)
+                    read = -(-end // tk) * tk
+                t["kv_prefill_rows_live"] += lanes * self._n_rows * end
+                t["kv_prefill_rows_read"] += lanes * self._n_rows * read
+                t["kv_prefill_pairs"] += self._n_rows * sum(
+                    n * offset + n * (n + 1) // 2
+                    for n in ([real] if lens is None else lens))
             if self._tail:
                 ran = (real * self._cfg.tail_start
                        + (lanes * self._tail if final else 0))

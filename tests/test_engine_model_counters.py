@@ -77,7 +77,8 @@ prefill_layer_visits prefill_layer_visits_cut
 decode_kv_tokens_live decode_kv_tokens_slab decode_kv_tokens_window_need
 decode_kv_tokens_window_read decode_s_sum decode_tokens device_enqueues
 device_queue_programs_sum draining first_tokens idle_wait_s kv_block
-kv_blocks_free kv_blocks_used kv_commit_skips kv_evictions kv_prefill_tokens
+kv_blocks_free kv_blocks_used kv_commit_skips kv_evictions kv_prefill_pairs
+kv_prefill_rows_live kv_prefill_rows_read kv_prefill_tokens
 kv_prefill_tokens_skipped kv_prefix_hits kv_prefix_misses kv_sessions
 kv_slot_bytes_global kv_slot_bytes_latent kv_slot_bytes_state
 kv_slot_bytes_window kv_state_reprefill_tokens kv_state_snapshot_skips
