@@ -34,7 +34,10 @@ pool of ``slots`` decode lanes over ONE persistent KV cache:
   ONE chunk per tick, interleaved with the decode dispatches, so an
   8k-token prompt costs live lanes one chunk of stall per tick instead
   of one monolithic prefill — the final chunk rides the shared
-  insert/finish path like any other admission;
+  insert/finish path like any other admission.  The lane holds up to
+  TWO such admissions (ISSUE 54), each with its own slab and at its own
+  offset: a tick's token steps then carry two chunks where they carried
+  one, and the second joins whenever it reaches the queue's front;
 - **speculative decoding** (``spec_k`` + a draft model, ISSUE 20): each
   tick runs draft-k/verify-once rounds — the draft proposes k tokens
   per slot, the target checks all k+1 positions in ONE multi-token
@@ -102,15 +105,15 @@ together and ``deliver`` what the replica adds after that (the answer's
 way out, ``ReplicaServer.serve_release`` through :meth:`ContinuousBatcher.
 observe_stage`).  Every request is admitted down one of three LANES:
 ``cold`` (a same-bucket group prefilled in one program), ``chunk`` (a
-prompt over ``prefill_chunk`` tokens, one chunk a tick, one prompt at a
-time) or ``reuse`` (a prefix hit: the suffix alone).  While it waits it
-is charged, tick by tick, to the CAUSE that kept the queue's head
-where it was: ``slots`` (no free slot), ``lane`` (a slot was free but
-the chunk lane was held, which takes the tick's one cold group and bars
-the next long prompt), ``group`` (the tick's one cold group took another
-bucket or its lane cap) or ``tick`` (it arrived after the tick's
-admission ran: the granularity of the loop itself).  The four sum to
-the queue wait, per request and in ``stats()``.  All of it is in
+prompt over ``prefill_chunk`` tokens, one chunk a tick, two prompts at
+most at a time) or ``reuse`` (a prefix hit: the suffix alone).  While it
+waits it is charged, tick by tick, to the CAUSE that kept the queue's
+head where it was: ``slots`` (no free slot), ``lane`` (a slot was free
+but the chunk lane was held, which takes the tick's one cold group and,
+once full, bars the next long prompt), ``group`` (the tick's one cold
+group took another bucket or its lane cap) or ``tick`` (it arrived after
+the tick's admission ran: the granularity of the loop itself).  The four
+sum to the queue wait, per request and in ``stats()``.  All of it is in
 ``stats()`` as flat cumulative keys (``stage_<stage>_sum_s`` / ``_n``,
 the same by lane for ``queue_wait`` and ``prefill``, cumulative bucket
 counts ``stage_<stage>_le_<edge>`` on one geometric ladder for
@@ -123,7 +126,8 @@ the ``edl_engine_queue_wait_seconds`` / ``edl_engine_ttft_seconds`` /
 them the device's queue as the host knows it (``device_enqueues``,
 ``device_queue_programs_sum``: programs enqueued and not yet proven
 run by a read, summed at every enqueue) and the chunk lane's
-occupancy (``chunk_lane_busy_s``).  There is no switch.
+occupancy (``chunk_lane_busy_s``: the time it held one admission or
+two).  There is no switch.
 """
 
 from __future__ import annotations
@@ -337,7 +341,6 @@ class _ChunkState:
     offset: int           # prompt tokens already prefilled
     slab: object          # one-lane decode cache, index == offset
     sown: object          # device counters accumulator (traced through)
-    t_start: float = 0.0  # the lane is held from here (monotonic)
 
 
 @dataclass
@@ -570,8 +573,13 @@ class ContinuousBatcher:
         self._prefill_tokens = 0
         self._prefill_tokens_skipped = 0
         # -- chunked prefill (long admissions interleave with decode) --
-        self._chunking: "_ChunkState | None" = None
+        # the lane's admissions, ``_chunk_lanes()`` at most, each with
+        # its own one-lane slab; ``_lane_t0`` is when the first of those
+        # it holds entered it (monotonic)
+        self._chunking: list[_ChunkState] = []
+        self._lane_t0 = 0.0
         self._prefill_chunks = 0
+        self._chunk_pair_dispatches = 0
         self._chunked_admissions = 0
         self._tasks: "deque[_Task]" = deque()
         self._queue: queue.Queue[_Request | _Task | None] = queue.Queue()
@@ -987,6 +995,8 @@ class ContinuousBatcher:
                 # off or no prompt ever exceeded the chunk size)
                 "prefill_chunk": self._chunk_tokens,
                 "prefill_chunks": self._prefill_chunks,
+                # ticks in which the lane advanced two prompts
+                "chunk_pair_dispatches": self._chunk_pair_dispatches,
                 "chunked_admissions": self._chunked_admissions,
                 **self._build_stats(),
                 **self._tick_stats(),
@@ -1146,10 +1156,10 @@ class ContinuousBatcher:
                 s.request.future.set_exception(
                     RuntimeError("engine stopped mid-generation"))
                 s.request = None
-        if self._chunking is not None:     # mid-chunk admission in flight
-            self._chunking.req.future.set_exception(
+        for st in self._chunking:          # mid-chunk admissions in flight
+            st.req.future.set_exception(
                 RuntimeError("engine stopped mid-prefill"))
-            self._chunking = None
+        self._chunking = []
         while self._pending:      # engine thread joined: safe to touch
             self._pending.popleft().future.set_exception(
                 RuntimeError("engine stopped"))
@@ -1997,7 +2007,7 @@ class ContinuousBatcher:
             # even with no active slots and an empty queue — never
             # block on the queue then: idle_wait begins flushed
             block = (self._inflight is None and not self._any_active()
-                     and self._chunking is None)
+                     and not self._chunking)
             waiting = block and not self._pending and not self._tasks
             with led.phase("idle_wait" if waiting else "admit"):
                 self._drain(block=block)
@@ -2079,13 +2089,13 @@ class ContinuousBatcher:
                         task.future.set_result(task.fn())
                     except BaseException as e:  # noqa: BLE001 — must resolve
                         task.future.set_exception(e)
-        st = self._chunking
         # the head's cause as this tick's admission begins: what the
         # tick before left it waiting for ("tick" for a new arrival)
         with led.phase("admit", pending=len(self._pending),
                        cause=(self._pending[0].cause if self._pending
                               else "none"),
-                       lane_offset=-1 if st is None else st.offset):
+                       lane_offset=(self._chunking[0].offset
+                                    if self._chunking else -1)):
             live = [(i, s.request) for i, s in enumerate(self._slots)
                     if s.owed > 0]
             pres = self._admit(bool(live))
@@ -2177,7 +2187,7 @@ class ContinuousBatcher:
     def _admit(self, lanes_live: bool) -> list[tuple]:
         """This tick's admissions, dispatched and not synced: every
         consecutive prefix hit at the queue front, then one chunk of
-        the chunked admission in flight or else one cold group.
+        each chunked admission in flight or else one cold group.
         Returns the in-flight tuples the tick inserts and, a tick
         later, finishes; their requests hold their slots from here on
         (``_fail_all`` and ``stop()`` find them there), with the budget
@@ -2205,32 +2215,38 @@ class ContinuousBatcher:
                     s.wait = s.idle = req.wait
 
         t0 = time.monotonic()
-        # the slot a chunked admission holds while its request is not
-        # in it yet
-        taken: set[int] = set()
-        if self._chunking is not None:
-            taken.add(self._chunking.slot)
-        while True:
-            # drain consecutive front-of-queue prefix hits first — each
-            # is a cheap one-lane suffix prefill, and a shared-prefix
-            # burst (the cache's own target traffic) must not serialize
-            # to one admission per tick
-            reuse = self._next_reuse(taken)
-            if reuse is None:
-                break
-            pre = self._dispatch_reuse(*reuse)
-            if pre is not None:
-                take(pre)
-        # long-prompt path: at most one chunked admission in flight; it
-        # advances ONE chunk per tick (the final chunk lands in pres and
-        # rides the shared insert/finish path), displacing this tick's
-        # cold-group slot in the dispatch budget
-        if self._chunking is None:
-            self._maybe_start_chunk(taken)
-        lane_held, group = self._chunking is not None, None
+        # the slots chunked admissions hold while their requests are
+        # not in them yet
+        taken: set[int] = {st.slot for st in self._chunking}
+
+        def hits():
+            # drain consecutive front-of-queue prefix hits — each is a
+            # cheap one-lane suffix prefill, and a shared-prefix burst
+            # (the cache's own target traffic) must not serialize to
+            # one admission per tick
+            while True:
+                reuse = self._next_reuse(taken)
+                if reuse is None:
+                    return
+                pre = self._dispatch_reuse(*reuse)
+                if pre is not None:
+                    take(pre)
+
+        hits()
+        # long-prompt path: the chunked admissions in flight, which a
+        # long prompt at the front joins while the lane has room (after
+        # the hits between it and the one before it); each advances ONE
+        # chunk per tick (a final chunk lands in pres and rides the
+        # shared insert/finish path), displacing this tick's cold-group
+        # slot in the dispatch budget
+        lanes = self._chunk_lanes()
+        while (len(self._chunking) < lanes
+               and self._maybe_start_chunk(taken)):
+            if len(self._chunking) < lanes:
+                hits()
+        lane_held, group = bool(self._chunking), None
         if lane_held:
-            pre = self._advance_chunk()
-            if pre is not None:
+            for pre in self._advance_chunks():
                 take(pre)
         else:
             group = self._next_group(taken)
@@ -2252,7 +2268,7 @@ class ContinuousBatcher:
         those behind it (the queue is FIFO).  ``slots``: no slot was
         free, so no policy of lanes would have admitted it; else
         ``lane``: the chunk lane was held this tick, which displaced
-        the cold group and bars the next long prompt; else ``group``:
+        the cold group and, full, bars the next long prompt; else ``group``:
         the tick's one cold group took another bucket or its cap; else
         ``tick``.  Each pending request is charged to its cause from
         its mark on; only a change of cause moves the mark."""
@@ -2276,10 +2292,10 @@ class ContinuousBatcher:
                 s.request.future.set_exception(e)
                 s.request, s.owed = None, 0
                 n += 1
-        if self._chunking is not None:
-            self._chunking.req.future.set_exception(e)
-            self._chunking = None
+        for st in self._chunking:
+            st.req.future.set_exception(e)
             n += 1
+        self._chunking = []
         with self._stats_lock:
             self._failed_requests += n
 
@@ -2287,8 +2303,8 @@ class ContinuousBatcher:
         return any(not s.free for s in self._slots)
 
     def _free_slots(self, taken: set[int]) -> list[int]:
-        """Free slots but ``taken`` (the one a chunked admission holds
-        while its request is not in it yet)."""
+        """Free slots but ``taken`` (those chunked admissions hold
+        while their requests are not in them yet)."""
         return [i for i, s in enumerate(self._slots)
                 if s.free and i not in taken]
 
@@ -2374,19 +2390,30 @@ class ContinuousBatcher:
             return None
 
     # -- chunked prefill (long admissions) -----------------------------------
-    def _maybe_start_chunk(self, taken: set) -> None:
+    def _chunk_lanes(self) -> int:
+        """How many chunked admissions the lane holds at once: two where
+        the prefill ladder the fit left (``_require_fit``) has room for
+        two lanes of a chunk's length, else one.  On the chip a chunk of
+        256 rows is bound by its arithmetic, so two prompts' chunks cost
+        the device two chunks whatever program runs them; what two in
+        the lane share is the tick, whose token steps carry 512 prompt
+        tokens where they carried 256 (PERF.md section 6, PR 54)."""
+        return min(2, self.PREFILL_KS[0])
+
+    def _maybe_start_chunk(self, taken: set) -> bool:
         """Claim the front pending request as a CHUNKED admission when
         its prompt exceeds the chunk size: the prompt prefills
         ``prefill_chunk`` tokens per tick into a private one-lane slab,
         interleaved with every decode dispatch, so a long admission
         costs live lanes one chunk of stall per tick instead of one
-        monolithic prefill (doc/serving.md "Chunked prefill")."""
+        monolithic prefill (doc/serving.md "Chunked prefill").  Its slot
+        joins ``taken``; whether one was claimed."""
         C = self._chunk_tokens
         if not C or self._stopping or not self._pending:
-            return
+            return False
         n = len(self._pending[0].ids)
         if n <= C:
-            return
+            return False
         # the final chunk pads to its suffix bucket and its cache write
         # is a CLAMPED dynamic_update_slice (transformer.py) — if
         # offset + bucket overhangs the cache it would shift backwards
@@ -2395,21 +2422,25 @@ class ContinuousBatcher:
         # fits by submit()'s bound.
         off = C * ((n - 1) // C)
         if off + self._bucket(n - off) > self._dcfg.max_len:
-            return
+            return False
         free = self._free_slots(taken)
         if not free:
-            return
+            return False
         slot, req = free[0], self._pending.popleft()
+        taken.add(slot)
         self._stamp_admit([req], "chunk")
         if self._kv is not None:
             # one admission, counted once at start (the reuse matcher
             # already passed on it — this is the cold long-prompt path)
             self._kv_misses += 1
             self._prefill_tokens += len(req.ids)
-        self._chunking = _ChunkState(req, slot, 0, *self._chunk_start(),
-                                     t_start=req.t_admit)
+        if not self._chunking:
+            self._lane_t0 = req.t_admit
+        self._chunking.append(
+            _ChunkState(req, slot, 0, *self._chunk_start()))
         with self._stats_lock:
             self._chunked_admissions += 1
+        return True
 
     def _chunk_start(self):
         """``(slab, sown)`` a chunked admission starts from: a zeroed
@@ -2422,16 +2453,33 @@ class ContinuousBatcher:
             ("chunk_start",), (self._cache_shapes(1), self._acc_shape),
             (self._cache_shardings(1), self._rep))
 
-    def _advance_chunk(self):
-        """Dispatch ONE chunk of the in-flight chunked admission (no
+    def _advance_chunks(self) -> list[tuple]:
+        """Dispatch ONE chunk of every chunked admission in flight (no
+        sync); the in-flight tuples of those whose chunk was their
+        last."""
+        pres = [self._advance_chunk(st) for st in list(self._chunking)]
+        if len(pres) == 2:
+            with self._stats_lock:
+                self._chunk_pair_dispatches += 1
+        return [pre for pre in pres if pre is not None]
+
+    def _leave_lane(self, st: _ChunkState) -> None:
+        """``st``'s last chunk is dispatched, or it failed: the lane is
+        free of it from here on, and its held time ends with the last
+        admission in it."""
+        self._chunking.remove(st)
+        if not self._chunking:
+            with self._stats_lock:
+                self._chunk_lane_busy_s += time.monotonic() - self._lane_t0
+
+    def _advance_chunk(self, st: _ChunkState):
+        """Dispatch ONE chunk of the chunked admission ``st`` (no
         sync).  Mid chunks write straight into the private slab — the
         slab's own cache_index tracks the offset, so every mid chunk of
         one size shares one executable.  The final chunk pads to its
         suffix bucket, samples the first token, and returns the same
         in-flight tuple as :meth:`_dispatch_prefill`, so insert/finish/
         commit are the shared path."""
-        st = self._chunking
-        assert st is not None
         ids, C = st.req.ids, self._chunk_tokens
         rest = len(ids) - st.offset
         st.req.chunks += 1
@@ -2456,12 +2504,10 @@ class ContinuousBatcher:
                 self._params, st.slab, jnp.asarray(tail),
                 jnp.asarray([rest], jnp.int32), st.sown, key, at)
             self._count_enqueue()
-            self._chunking = None
+            self._leave_lane(st)
             self._counters.on_prefill(1, P, rest, st.offset)
             with self._stats_lock:
                 self._prefill_chunks += 1
-                # the lane is free from its last chunk's dispatch on
-                self._chunk_lane_busy_s += time.monotonic() - st.t_start
             dslab = self._draft_slab_for(st.req) if self._spec_k else None
             return (slab, toks, sown, [st.slot], [st.req], [len(ids)],
                     dslab, (snap, st.offset))
@@ -2469,10 +2515,10 @@ class ContinuousBatcher:
             logger.exception("chunked prefill failed (offset %d of %d)",
                              st.offset, len(ids))
             st.req.future.set_exception(e)
-            self._chunking = None
+            if st in self._chunking:
+                self._leave_lane(st)
             with self._stats_lock:
                 self._failed_requests += 1
-                self._chunk_lane_busy_s += time.monotonic() - st.t_start
             return None
 
     @_compiled("chunk", lambda C: ("chunk", C))

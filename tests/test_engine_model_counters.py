@@ -72,7 +72,8 @@ MODEL_KEYS = ("moe_", "latent_", "ssm_", "decode_kv_tokens_", "borrowed_",
 ALL_KEYS = frozenset("""
 program_builds program_build_s
 active_slots admitted borrowed_kv_tokens_live borrowed_kv_tokens_prefill
-borrowed_kv_tokens_read chunk_lane_busy_s chunked_admissions
+borrowed_kv_tokens_read chunk_lane_busy_s chunk_pair_dispatches
+chunked_admissions
 prefill_layer_visits prefill_layer_visits_cut
 decode_kv_tokens_live decode_kv_tokens_slab decode_kv_tokens_window_need
 decode_kv_tokens_window_read decode_s_sum decode_tokens device_enqueues

@@ -239,13 +239,18 @@ def test_a_short_prompt_under_a_long_one_waits_for_the_lane(eng, events):
         ev[30]["prefill"], abs=1e-5)
 
 
-def test_a_long_prompt_behind_a_long_one_waits_for_the_lane_too(eng, events):
+def test_a_long_prompt_behind_two_waits_for_the_lane_too(eng, events):
+    """The lane holds two long prompts (PR 54); the third finds it full
+    and waits for it, slots free."""
     with _held(eng):
-        futs = [eng.submit(_prompt(4, 29), 2), eng.submit(_prompt(5, 27), 2)]
+        futs = [eng.submit(_prompt(4, 29), 2), eng.submit(_prompt(5, 27), 2),
+                eng.submit(_prompt(6, 25), 2)]
     [f.result(timeout=120) for f in futs]
     ev = events()
-    assert ev[29]["lane"] == ev[27]["lane"] == "chunk"
-    assert _waits(ev[27])["lane"] > 0 and _waits(ev[29])["lane"] == 0
+    assert ev[29]["lane"] == ev[27]["lane"] == ev[25]["lane"] == "chunk"
+    assert _waits(ev[29])["lane"] == _waits(ev[27])["lane"] == 0
+    w = _waits(ev[25])
+    assert w["lane"] > 0 and w["slots"] == 0
 
 
 def test_more_requests_than_slots_wait_for_slots(eng, events):
